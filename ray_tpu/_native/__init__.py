@@ -96,7 +96,7 @@ def load_arena_lib() -> Optional[ctypes.CDLL]:
         if _lib is not None:
             return _lib
         # Source hash, not mtime, decides staleness: git checkouts give
-        # source and binary arbitrary mtime order, and a committed .so from
+        # source and binary arbitrary mtime order, and a .so left over from
         # a drifted source must rebuild regardless of timestamps.
         if not _binary_is_current(
             _LIB_PATH, ARENA_HASH_MARKER, os.path.join(_SRC_DIR, "shm_arena.cpp")

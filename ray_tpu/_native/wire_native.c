@@ -36,7 +36,7 @@
 /* Source-hash stamp: the build flow (_native/__init__.py) passes
  * -DWIRE_SRC_SHA256="<hex>" with the sha256 of THIS file, exported both as a
  * module constant (SOURCE_HASH) and as a greppable marker string inside the
- * binary, so a checked-in .so that no longer matches its source is
+ * binary, so a built .so that no longer matches its source is
  * detectable without loading it (tools/check.sh stale-binary guard). */
 #ifndef WIRE_SRC_SHA256
 #define WIRE_SRC_SHA256 "unknown"

@@ -367,6 +367,13 @@ class NodeDaemon:
                     popen.kill()
                 except ProcessLookupError:
                     pass
+            # This node's chips are free only when its workers are gone: do
+            # not exit (and report the node down) before they are reaped.
+            for popen in procs:
+                try:
+                    popen.wait(timeout=60)
+                except subprocess.TimeoutExpired:
+                    pass
 
     def _reconnect(self) -> bool:
         """Try to rejoin a (re)started head at the same address for up to

@@ -34,6 +34,7 @@ from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 from ray_tpu._private import failpoints, lifecycle, serialization, session_monitor
+from ray_tpu._private.accelerators import tpu as tpu_accel
 from ray_tpu._private.batching import approx_msg_nbytes as _approx_msg_nbytes
 from ray_tpu._private.concurrency import any_thread, loop_thread_only
 from ray_tpu._private.config import Config
@@ -52,6 +53,10 @@ from ray_tpu._private.protocol import ExecRequest, FunctionDescriptor, TaskSpec
 from ray_tpu._private.worker_main import WorkerArgs, worker_loop
 
 _mp = multiprocessing.get_context("spawn")
+
+
+# How long shutdown waits for a killed chip-holding worker to be reaped.
+_CHIP_RELEASE_TIMEOUT_S = 60.0
 
 
 class _Proc:
@@ -204,6 +209,9 @@ class WorkerHandle:
     # process couldn't answer) — surfaced on the node's worker entries in
     # get_nodes so a postmortem doesn't start with log spelunking.
     flight_recorder: Optional[dict] = None
+    # Chip indices of the node this process was granted at spawn (its libtpu
+    # environment lets it open these and no others); () = pinned off the chip.
+    tpu_chips: Tuple[int, ...] = ()
 
     def send(self, msg) -> bool:
         if failpoints.ENABLED:
@@ -266,6 +274,32 @@ class NodeState:
     # WorkerHandle.flight_recorder); carried into the node's postmortem
     # entry if it is later declared DEAD.
     flight_recorder: Optional[dict] = None
+    # Chip ownership: indices 0..TPU-1 of this host, each held by at most one
+    # worker process. `tpu_free` is what a new grant may take; a dead
+    # worker's chips wait in `tpu_draining` until its process is really gone
+    # (a SIGKILLed process owns its chip until the kernel has torn it down).
+    tpu_free: List[int] = field(default_factory=list)
+    tpu_draining: List[Tuple[Any, Tuple[int, ...]]] = field(default_factory=list)
+
+    def __post_init__(self):
+        self.tpu_free = list(range(int(self.resources.get("TPU", 0))))
+
+    def take_chips(self, n: int) -> Optional[Tuple[int, ...]]:
+        """Grant an aligned block of `n` free chips, or None (the caller
+        leaves the request pending — chips are never oversubscribed)."""
+        draining = []
+        for process, chips in self.tpu_draining:
+            if process.is_alive():
+                draining.append((process, chips))
+            else:
+                self.tpu_free.extend(chips)
+        self.tpu_draining = draining
+        return tpu_accel.take_chips(self.tpu_free, n)
+
+    def return_chips(self, wh: "WorkerHandle") -> None:
+        if wh.tpu_chips:
+            self.tpu_draining.append((wh.process, wh.tpu_chips))
+            wh.tpu_chips = ()
 
     def utilization(self) -> float:
         """Critical-resource utilization: the max used-fraction over resource
@@ -1093,7 +1127,9 @@ class Scheduler:
     def stop(self):
         fut = self.call("_stop", None)
         try:
-            fut.result(timeout=5)
+            # _shutdown_workers outlasts its 2 s grace only while a killed
+            # chip-holding worker is still being torn down.
+            fut.result(timeout=5 + _CHIP_RELEASE_TIMEOUT_S)
         except Exception:
             pass
         self._stopped.set()
@@ -1627,12 +1663,28 @@ class Scheduler:
             for wh in list(node.workers.values()):
                 wh.send(("shutdown",))
         deadline = time.time() + 2.0
+        holders = []
         for node in self.nodes.values():
             for wh in list(node.workers.values()):
                 t = max(0.0, deadline - time.time())
                 wh.process.join(timeout=t)
                 if wh.process.is_alive():
                     wh.process.terminate()
+                if wh.tpu_chips:
+                    holders.append(wh)
+        # shutdown() promises the chips back: the next process to want them
+        # (a second init() in this driver, bench.py's bare subprocess) starts
+        # the moment it returns, and a SIGKILLed holder of gigabytes of HBM
+        # owns its chip until the kernel has finished tearing it down.
+        for wh in holders:
+            wh.process.join(timeout=_CHIP_RELEASE_TIMEOUT_S)
+            if wh.process.is_alive():
+                print(
+                    f"ray_tpu: worker pid {wh.os_pid or wh.process.pid} still "
+                    f"holds TPU chips {list(wh.tpu_chips)} "
+                    f"{_CHIP_RELEASE_TIMEOUT_S:.0f}s after SIGKILL",
+                    file=sys.stderr,
+                )
 
     # ------------------------------------------------------------------ nodes
     def _cmd_add_node(self, payload) -> NodeID:
@@ -1748,9 +1800,15 @@ class Scheduler:
     # ------------------------------------------------------------------ workers
     def _spawn_worker(self, node: NodeState, actor_id: Optional[ActorID] = None,
                       env_vars: Optional[Dict[str, str]] = None,
-                      runtime_env: Optional[Dict] = None) -> WorkerHandle:
+                      runtime_env: Optional[Dict] = None,
+                      tpu_chips: Tuple[int, ...] = ()) -> WorkerHandle:
+        """`tpu_chips` is the worker's chip grant (NodeState.take_chips). On a
+        host with chips every worker gets one, the empty grant included: the
+        worker applies it to its own environment before any user code runs
+        (worker_main.worker_loop), so it can open those chips and no others."""
         if node.daemon is not None:
-            return self._spawn_remote_worker(node, actor_id, env_vars, runtime_env)
+            return self._spawn_remote_worker(
+                node, actor_id, env_vars, runtime_env, tpu_chips)
         worker_id = WorkerID.from_random()
         args = WorkerArgs(
             worker_id_hex=worker_id.hex(),
@@ -1762,6 +1820,7 @@ class Scheduler:
             is_actor_worker=actor_id is not None,
             runtime_env=runtime_env,
             head_address=f"{self.tcp_address[0]}:{self.tcp_address[1]}",
+            tpu_chips=tpu_chips if node.resources.get("TPU") else None,
         )
         log_dir = os.path.join(self.session_dir, "logs")
         os.makedirs(log_dir, exist_ok=True)
@@ -1799,6 +1858,7 @@ class Scheduler:
             state="idle" if actor_id is None else "busy",
             actor_id=actor_id,
             env_hash=_renv_hash(runtime_env),
+            tpu_chips=tpu_chips,
         )
         node.workers[worker_id] = wh
         self._workers_by_id[worker_id.hex()] = wh
@@ -1808,7 +1868,8 @@ class Scheduler:
 
     def _spawn_remote_worker(self, node: NodeState, actor_id: Optional[ActorID],
                              env_vars: Optional[Dict[str, str]],
-                             runtime_env: Optional[Dict] = None) -> WorkerHandle:
+                             runtime_env: Optional[Dict] = None,
+                             tpu_chips: Tuple[int, ...] = ()) -> WorkerHandle:
         """Lease a worker on a daemon-managed node: the daemon execs the worker
         process, which dials back over TCP (reference: raylet WorkerPool start,
         `/root/reference/src/ray/raylet/worker_pool.h:77`)."""
@@ -1825,6 +1886,7 @@ class Scheduler:
             is_actor_worker=actor_id is not None,
             runtime_env=runtime_env,
             head_address=f"{self.tcp_address[0]}:{self.tcp_address[1]}",
+            tpu_chips=tpu_chips if node.resources.get("TPU") else None,
         )
         wh = WorkerHandle(
             worker_id=worker_id,
@@ -1833,6 +1895,7 @@ class Scheduler:
             state="idle" if actor_id is None else "busy",
             actor_id=actor_id,
             env_hash=_renv_hash(runtime_env),
+            tpu_chips=tpu_chips,
         )
         node.workers[worker_id] = wh
         self._workers_by_id[worker_id.hex()] = wh
@@ -1871,6 +1934,7 @@ class Scheduler:
             node.workers.pop(wh.worker_id, None)
             if wh.worker_id in node.idle:
                 node.idle.remove(wh.worker_id)
+            node.return_chips(wh)
         self._workers_by_id.pop(wh.worker_id.hex(), None)
         if wh.conn is not None:
             self._conn_to_worker.pop(wh.conn, None)
@@ -2677,6 +2741,13 @@ class Scheduler:
             self._release_actor_creation_pins(ar)
             self._drop_detached(ar.actor_id)
             self._drop_actor_name(ar.actor_id)
+            # The dedicated worker has no actor to host and still owns the
+            # chips it was granted: tear it down so they return.
+            node = self.nodes.get(ar.node)
+            wh = node.workers.get(ar.worker) if node else None
+            if wh is not None:
+                wh.process.terminate()
+                self._on_worker_death(wh)
 
     # ------------------------------------------------------------------ generator streams
     # Reference semantics: `num_returns="dynamic"` / streaming generator tasks
@@ -5536,6 +5607,16 @@ class Scheduler:
         node = self._pick_node(rec)
         if node is None:
             return False
+        # One process for each chip (the analogue of the reference's
+        # CUDA_VISIBLE_DEVICES assignment): the actor's worker is granted chip
+        # indices of this host, not a count. No free block -> stays pending.
+        tpu_chips: Tuple[int, ...] = ()
+        num_tpus = int(rec.spec.resources.get("TPU", 0))
+        if num_tpus:
+            tpu_chips = node.take_chips(num_tpus)
+            if tpu_chips is None:
+                rec.acquired_pg = None
+                return False
         if rec.acquired_pg is not None:
             pg = self.pgs[rec.acquired_pg[0]]
             bundle = pg.bundles[rec.acquired_pg[1]]
@@ -5551,15 +5632,9 @@ class Scheduler:
                 ar.actor_id, ar.acquired.get("CPU", 0.0), time.time()
             )
         node.last_active = time.time()
-        env_vars = dict(rec.spec.env_vars)
-        # TPU visibility: give the actor its chip share (analogue of
-        # CUDA_VISIBLE_DEVICES assignment in the reference's resource allocator).
-        num_tpus = rec.spec.resources.get("TPU", 0)
-        if num_tpus:
-            env_vars.setdefault("TPU_CHIPS", str(int(num_tpus)))
         wh = self._spawn_worker(
-            node, actor_id=ar.actor_id, env_vars=env_vars,
-            runtime_env=rec.spec.runtime_env,
+            node, actor_id=ar.actor_id, env_vars=dict(rec.spec.env_vars),
+            runtime_env=rec.spec.runtime_env, tpu_chips=tpu_chips,
         )
         ar.worker = wh.worker_id
         ar.node = node.node_id

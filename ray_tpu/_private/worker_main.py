@@ -19,7 +19,7 @@ import threading
 import time
 import traceback
 from dataclasses import dataclass
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, Optional, Tuple
 
 from ray_tpu._private import failpoints, serialization, session_monitor
 from ray_tpu._private.config import Config, set_config
@@ -45,6 +45,10 @@ class WorkerArgs:
     # subprocesses a task launches (e.g. job-submission entrypoints) can join
     # the cluster as client drivers.
     head_address: Optional[str] = None
+    # Chip grant from the scheduler (NodeState.take_chips): indices of this
+    # host's TPU chips, () for a worker that must stay off them, None on a
+    # host without chips (environment left alone).
+    tpu_chips: Optional[Tuple[int, ...]] = None
 
 
 # Hard-close for the failpoint "close" action and send-failure cleanup: the
@@ -948,6 +952,14 @@ def worker_loop(conn, args: WorkerArgs):
     set_config(args.config)
     for k, v in args.env_vars.items():
         os.environ.setdefault(k, v)
+    if args.tpu_chips is not None:
+        # Before any user code can import jax: this process opens the chips
+        # it was granted and no others, whatever it inherited.
+        from ray_tpu._private.accelerators import tpu as tpu_accel
+
+        for var in tpu_accel.CHIP_ENV_VARS:
+            os.environ.pop(var, None)
+        os.environ.update(tpu_accel.chip_env(args.tpu_chips))
     if args.head_address:
         os.environ.setdefault("RAY_TPU_ADDRESS", args.head_address)
     wc = WorkerConnection(conn)

@@ -225,7 +225,7 @@ class ActorClass:
             self._function_id = worker_mod.function_id_of(self._blob)
         actor_id = ActorID.of(global_worker.job_id)
         task_id = global_worker.next_task_id()
-        resources = _resources_from_options(opts, default_cpus=0.0)
+        resources = _resources_from_options(opts, default_cpus=0.0, actor=True)
         renv = dict(opts.get("runtime_env") or {})
         spec = TaskSpec(
             task_id=task_id,

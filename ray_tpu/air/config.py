@@ -21,14 +21,21 @@ class ScalingConfig:
     """How to scale training: worker gang size, resources, and mesh layout."""
 
     num_workers: int = 1
+    # Each worker is granted `tpus_per_worker` chips (default ONE, whatever
+    # the host holds) and can open those chips and no others: the scheduler
+    # assigns chip indices and the worker's libtpu environment is confined to
+    # them. A one-worker job on a four-chip host therefore sees one device;
+    # ask for the host with tpus_per_worker=4, or for four one-chip workers
+    # joined into one mesh with num_workers=4.
     use_tpu: bool = False
     resources_per_worker: Optional[Dict[str, float]] = None
     placement_strategy: str = "PACK"
     # TPU-native: SPMD mesh layout for the training step. Either a MeshSpec or
     # a dict of axis sizes, e.g. {"data": 8} or {"data": 2, "tensor": 4}.
     mesh: Optional[Union[Dict[str, int], Any]] = None
-    # Chips each worker process owns (TPU hosts have 4 or 8 local chips).
-    tpus_per_worker: Optional[float] = None
+    # Chips each worker process owns: 1 (default), 2, 4 or 8 — the blocks
+    # libtpu can present to one process (TPU hosts have 4 or 8 chips).
+    tpus_per_worker: Optional[int] = None
     # Elastic gang membership (ISSUE 19): on a worker/node loss the gang
     # drains survivors at a step boundary and re-forms at the new world size
     # instead of failing the run (resizes do NOT consume FailureConfig's

@@ -23,8 +23,8 @@ rt-lint; shared parsed-AST cache in devtools.astutil):
                 unchecked PyMem_Malloc/Realloc, owned references leaked on
                 error-return paths, length fields used in memcpy/allocation
                 without a preceding bounds check
-  stale      -- the checked-in .so binaries must embed the sha256 of the
-                source they were built from (drift fails the run)
+  stale      -- a built .so (built on demand, git-ignored) must embed the
+                sha256 of the source next to it (drift fails the run)
 
 Dynamic verification (same CLI):
 

@@ -1,16 +1,17 @@
-"""Stale-binary guard: checked-in native binaries must match their source.
+"""Stale-binary guard: a built native binary must match its source.
 
-PR 7 committed built `.so`s next to their sources (fast cold start: no
-compile on first import). Nothing detected drift: edit the .c, ship the old
-.so, and every toolchain-less host silently runs the previous decoder. The
-build flow now stamps each binary with the sha256 of the source it was
-built from (`-D*_SRC_SHA256`, exported as a greppable
+The `.so`s are not in git: they are built on demand, next to their sources,
+on first use (`ray_tpu/_native/__init__.py`) and git-ignored. A binary left
+in a working tree from an older source is the drift this catches: edit the
+.c, keep the old .so, and a host whose rebuild fails silently runs the
+previous decoder. The build flow stamps each binary with the sha256 of the
+source it was built from (`-D*_SRC_SHA256`, exported as a greppable
 ``RAY_TPU_*_SRC_SHA256=<hex>`` marker string); this pass re-hashes the
 source and compares — pure file reads, no dlopen, no runtime import.
 
-A missing binary is NOT a violation (they build on demand); a binary
-without a stamp is (it predates the guard — rebuild it), and a stamp
-mismatch is the exact failure this exists for.
+A missing binary is NOT a violation (a fresh checkout has none); a binary
+without a stamp is, and a stamp mismatch is the exact failure this exists
+for.
 """
 
 from __future__ import annotations
@@ -43,7 +44,7 @@ def run(pkg=None, native_dir: Optional[str] = None) -> List[Violation]:
         so_path = os.path.join(d, so_name)
         src_path = os.path.join(d, src_name)
         if not os.path.exists(so_path) or not os.path.exists(src_path):
-            continue  # binaries build on demand; nothing checked in to drift
+            continue  # binaries build on demand; nothing built yet to drift
         src_hash = source_sha256(src_path)
         got = embedded_source_hash(so_path, marker)
         if got is None:
@@ -51,14 +52,14 @@ def run(pkg=None, native_dir: Optional[str] = None) -> List[Violation]:
                 "stale", so_path, 0,
                 make_key("stale", so_path, "unstamped"),
                 f"{so_name} carries no {marker.decode()!r} source stamp — "
-                f"it predates the stale-binary guard; rebuild and recommit",
+                f"it predates the stale-binary guard; delete it and let it rebuild",
             ))
         elif got != src_hash:
             violations.append(Violation(
                 "stale", so_path, 0,
                 make_key("stale", so_path, "drift"),
                 f"{so_name} was built from source {got[:12]}… but "
-                f"{src_name} now hashes {src_hash[:12]}… — the checked-in "
-                f"binary is stale; rebuild and recommit",
+                f"{src_name} now hashes {src_hash[:12]}… — the built "
+                f"binary is stale; delete it and let it rebuild",
             ))
     return violations

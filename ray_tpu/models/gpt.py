@@ -9,8 +9,9 @@ Design choices for the MXU/XLA:
    over them (`lax.scan`): compile time is O(1) in depth, and remat
    (`jax.checkpoint`) wraps the scanned block to trade FLOPs for HBM.
  - activations/matmuls in bfloat16, params & softmax/logits in float32.
- - attention: pallas flash kernel on TPU, plain XLA elsewhere, ring attention
-   (context parallelism) injectable via `attention_fn`.
+ - attention: pallas flash kernel on TPU (partitioned over the mesh's batch and
+   head axes), plain XLA elsewhere, ring attention (context parallelism)
+   injectable via `attention_fn`.
  - vocab padded to a multiple of 128 so the logits matmul tiles the MXU.
 
 The reference has no model code (it is the distributed substrate); the
@@ -215,7 +216,8 @@ def _dropout(x, rate: float, rng):
     return jnp.where(keep, x / (1.0 - rate), 0).astype(x.dtype)
 
 
-def _block(x, layer, config: GPTConfig, attention_fn, drop_rng=None, sub_remat=False):
+def _block(x, layer, config: GPTConfig, attention_fn, drop_rng=None, sub_remat=False,
+           mesh=None):
     """One transformer block. x: (B, S, D) in config.dtype.
     Returns (x, aux) — aux is the MoE load-balance loss (0.0 when dense).
 
@@ -266,7 +268,7 @@ def _block(x, layer, config: GPTConfig, attention_fn, drop_rng=None, sub_remat=F
     q, k, v = qkv_part(x, layer)
     from ray_tpu.models.stack import resolve_attention
 
-    o = resolve_attention(q, k, v, config.attention, attention_fn)  # (B, nh, S, hd)
+    o = resolve_attention(q, k, v, config.attention, attention_fn, mesh)  # (B, nh, S, hd)
     return out_mlp_part(x, o, layer)
 
 
@@ -314,7 +316,7 @@ def forward(
                 if mb_idx is not None:
                     # Independent dropout mask per microbatch under PP.
                     rng = jax.random.fold_in(rng, mb_idx)
-            x, aux = _block(x, layer, config, attn, rng, sub_remat=save_attn)
+            x, aux = _block(x, layer, config, attn, rng, sub_remat=save_attn, mesh=mesh)
             return x, aux
 
         if config.remat and not save_attn:

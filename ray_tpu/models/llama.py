@@ -174,7 +174,8 @@ def _rope(x, cos, sin):
 
 
 
-def _block(x, layer, config: LlamaConfig, attention_fn, cos, sin, sub_remat=False):
+def _block(x, layer, config: LlamaConfig, attention_fn, cos, sin, sub_remat=False,
+           mesh=None):
     """One Llama block. x: (B, S, D). Returns (x, aux=0).
 
     With sub_remat ("save_attn" policy), the qkv/rope and wo/MLP halves are
@@ -214,7 +215,7 @@ def _block(x, layer, config: LlamaConfig, attention_fn, cos, sin, sub_remat=Fals
     q, k, v = qkv_part(x, layer)
     from ray_tpu.models.stack import resolve_attention
 
-    o = resolve_attention(q, k, v, config.attention, attention_fn)  # (B, nh, S, hd)
+    o = resolve_attention(q, k, v, config.attention, attention_fn, mesh)  # (B, nh, S, hd)
     return out_mlp_part(x, o, layer)
 
 
@@ -251,7 +252,9 @@ def forward(
 
         def block_fn(x, xs):
             layer, _idx = xs
-            return _block(x, layer, config, attn, cos_s, sin_s, sub_remat=save_attn)
+            return _block(
+                x, layer, config, attn, cos_s, sin_s, sub_remat=save_attn, mesh=mesh
+            )
 
         if remat_cfg and not save_attn:
             block_fn = jax.checkpoint(block_fn, prevent_cse=False, policy=remat_policy)

@@ -74,19 +74,24 @@ def apply_stack(
     return x, jnp.sum(auxs)
 
 
-def resolve_attention(q, k, v, attention_mode: str, attention_fn: Optional[Callable]):
+def resolve_attention(q, k, v, attention_mode: str, attention_fn: Optional[Callable],
+                      mesh=None):
     """One attention-backend dispatch for every model family: caller-injected
-    fn (ring/Ulysses wrappers) wins, else pallas flash on TPU / plain XLA."""
+    fn (ring/Ulysses wrappers) wins; "xla" forces the plain-XLA form; "auto"
+    and "flash" take `flash_attention`'s own choice for this platform and
+    shape (`ops.flash_attention.select_backend` says which), with the kernel
+    partitioned over `mesh`."""
     if attention_fn is not None:
         return attention_fn(q, k, v)
     from ray_tpu.ops.flash_attention import flash_attention, xla_attention
 
-    mode = attention_mode
-    if mode == "auto":
-        mode = "flash" if jax.default_backend() == "tpu" else "xla"
-    if mode == "flash":
-        return flash_attention(q, k, v, causal=True)
-    return xla_attention(q, k, v, causal=True)
+    if attention_mode == "xla":
+        return xla_attention(q, k, v, causal=True)
+    if mesh is not None and int(mesh.shape.get("pipeline", 1)) > 1:
+        # Inside the pipeline's manual region a second shard_map cannot
+        # reopen the mesh; the kernel runs unpartitioned there.
+        mesh = None
+    return flash_attention(q, k, v, causal=True, mesh=mesh)
 
 
 def causal_lm_loss(logits, targets):

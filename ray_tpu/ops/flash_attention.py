@@ -1,5 +1,5 @@
 """Flash attention for TPU as Pallas kernels (forward + backward), with an XLA
-fallback for non-TPU backends.
+form for non-TPU backends (`select_backend` says which one a call runs).
 
 Design (pallas_guide.md playbook):
  - forward: grid over (batch*heads, q_blocks); K/V rows for the (b,h) pair live
@@ -39,7 +39,7 @@ DEFAULT_BLOCK_K = 1024
 NEG_INF = -1e30
 
 
-# --------------------------------------------------------------------------- XLA fallback
+# --------------------------------------------------------------------------- XLA form
 def xla_attention(q, k, v, causal: bool = True, sm_scale: Optional[float] = None):
     """Plain-XLA attention (fused well by the compiler; O(S^2) memory)."""
     if sm_scale is None:
@@ -336,6 +336,35 @@ def _flash_bwd_rule(causal, sm_scale, block_q, block_k, interpret, res, g):
 _flash_bhsd.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 
 
+def _kernel_blocks(seq: int, block_q: int, block_k: int):
+    """Cap blocks to seq_len, then shrink to a divisor (gcd keeps the largest
+    power-of-two factor) so defaults work for any seq that has one — e.g.
+    S=1536 uses 512-blocks."""
+    return math.gcd(min(block_q, seq), seq), math.gcd(min(block_k, seq), seq)
+
+
+def select_backend(shape, platform: Optional[str] = None,
+                   block_q: int = DEFAULT_BLOCK_Q, block_k: int = DEFAULT_BLOCK_K) -> str:
+    """The implementation `flash_attention(backend=None)` runs for q/k/v of
+    `shape` (batch, heads, seq, head_dim): "pallas" | "blockwise" | "xla".
+
+    The choice is by platform and shape only, and this function is the one
+    place it is made, so a caller can say which path its step compiled
+    without reading the HLO: off-TPU the XLA form; on TPU the kernel while
+    K/V for one (batch, head) fit in VMEM (~2*S*D bytes in bf16: up to ~8k
+    tokens at d=64), the blockwise scan beyond that, and the XLA form for a
+    sequence with no block of at least 128 dividing it.
+    """
+    if (platform or jax.default_backend()) != "tpu":
+        return "xla"
+    _, _, s, d = shape
+    if s * d > 8192 * 64:
+        return "blockwise"
+    if min(_kernel_blocks(s, block_q, block_k)) < 128:
+        return "xla"
+    return "pallas"
+
+
 def flash_attention(
     q,
     k,
@@ -346,33 +375,52 @@ def flash_attention(
     block_k: int = DEFAULT_BLOCK_K,
     backend: Optional[str] = None,
     interpret: bool = False,
+    mesh=None,
 ):
     """Multi-head attention, (batch, heads, seq, head_dim) layout.
 
-    backend: "pallas" | "xla" | "blockwise" | None (auto: pallas on TPU up to
-    the VMEM-resident K/V limit, blockwise beyond it, xla off-TPU).
+    backend: "pallas" | "xla" | "blockwise" | None (`select_backend`).
+    mesh: the jax.sharding.Mesh the surrounding jit shards over. XLA cannot
+      partition a Mosaic call by itself, so on more than one device the
+      kernel runs inside a shard_map with batch over (data, fsdp) and heads
+      over tensor — attention is independent per (batch, head), so no
+      collective is needed and each device runs the kernel on its own block.
     """
     if sm_scale is None:
         sm_scale = q.shape[-1] ** -0.5
     if backend is None:
-        if jax.default_backend() == "tpu":
-            # Pallas kernels keep full-seq K/V in VMEM: ~2*S*D bytes (bf16)
-            # per (b,h); beyond ~8k at d=64 switch to the blockwise scan.
-            backend = "pallas" if q.shape[2] * q.shape[3] <= 8192 * 64 else "blockwise"
-        else:
-            backend = "xla"
+        # The platform the computation is compiled for: the mesh's when there
+        # is one (also true when compiling ahead of time for a chip this
+        # process does not hold), else this process's default.
+        platform = mesh.devices.flat[0].platform if mesh is not None else None
+        backend = select_backend(q.shape, platform, block_q, block_k)
     if backend == "xla":
         return xla_attention(q, k, v, causal=causal, sm_scale=sm_scale)
     if backend == "blockwise":
         return blockwise_attention(q, k, v, causal=causal, sm_scale=sm_scale)
-    b, h, s, d = q.shape
-    # Cap blocks to seq_len, then shrink to a divisor (gcd keeps the largest
-    # power-of-two factor) so defaults work for any seq that has one — e.g.
-    # S=1536 uses 512-blocks. Odd/indivisible lengths fall back to XLA.
-    block_q = math.gcd(min(block_q, s), s)
-    block_k = math.gcd(min(block_k, s), s)
+    block_q, block_k = _kernel_blocks(q.shape[2], block_q, block_k)
     if min(block_q, block_k) < 128:
-        return xla_attention(q, k, v, causal=causal, sm_scale=sm_scale)
-    flat = lambda x: x.reshape(b * h, s, d)
-    o = _flash_bhsd(flat(q), flat(k), flat(v), causal, sm_scale, block_q, block_k, interpret)
-    return o.reshape(b, h, s, d)
+        raise ValueError(
+            f"flash_attention(backend='pallas'): seq_len {q.shape[2]} has no "
+            "block of at least 128 dividing it; the kernel cannot tile it"
+        )
+
+    def kernel(q, k, v):
+        b, h, s, d = q.shape
+        flat = lambda x: x.reshape(b * h, s, d)
+        o = _flash_bhsd(flat(q), flat(k), flat(v), causal, sm_scale, block_q, block_k, interpret)
+        return o.reshape(b, h, s, d)
+
+    if mesh is not None and mesh.size > 1:
+        from ray_tpu.parallel import ShardingRules
+
+        # The rules drop a mesh axis that does not divide its dimension
+        # (12 heads on tensor=8 stay replicated), as they do for parameters.
+        spec = ShardingRules().mesh_axes(
+            ("batch", "heads", None, None), mesh=mesh, shape=q.shape
+        )
+        kernel = jax.shard_map(
+            kernel, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
+            check_vma=False,
+        )
+    return kernel(q, k, v)

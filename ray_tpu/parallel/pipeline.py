@@ -28,7 +28,6 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from ray_tpu._private.jax_compat import shard_map as _shard_map
 
 
 def pipeline_apply(
@@ -131,7 +130,7 @@ def pipeline_apply(
         # shard their leading (position) dim the same way.
         x_spec = P(None, None, "context", None)
         stream_spec = P("context")
-    sharded = _shard_map(
+    sharded = jax.shard_map(
         per_rank,
         mesh=mesh,
         in_specs=(P("pipeline"), x_spec) + (stream_spec,) * len(seq_streams),

@@ -20,7 +20,6 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from ray_tpu._private.jax_compat import shard_map as _shard_map
 
 NEG_INF = -1e30
 
@@ -111,7 +110,7 @@ def ring_attention_sharded(mesh, q, k, v, causal: bool = True, sm_scale: Optiona
 
     axis_size = mesh.shape["context"]
     spec = P(("data", "fsdp"), None, "context", None)
-    fn = _shard_map(
+    fn = jax.shard_map(
         functools.partial(
             ring_attention,
             axis_name="context",

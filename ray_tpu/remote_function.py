@@ -66,7 +66,12 @@ def _resolve_backpressure(opts, num_returns):
     return val
 
 
-def _resources_from_options(opts: Dict[str, Any], default_cpus: float) -> Dict[str, float]:
+def _resources_from_options(
+    opts: Dict[str, Any], default_cpus: float, actor: bool = False
+) -> Dict[str, float]:
+    """Resource map of a task or actor. TPU chips are owned by one process
+    for that process's lifetime, so only an actor can hold them, and only in
+    the whole-chip blocks libtpu can present to a process."""
     res: Dict[str, float] = {}
     num_cpus = opts.get("num_cpus")
     res["CPU"] = float(num_cpus) if num_cpus is not None else default_cpus
@@ -78,6 +83,23 @@ def _resources_from_options(opts: Dict[str, Any], default_cpus: float) -> Dict[s
         res[k] = float(v)
     if res.get("CPU") == 0:
         res.pop("CPU")
+    tpus = res.get("TPU")
+    if tpus:
+        from ray_tpu._private.accelerators import tpu as tpu_accel
+
+        if not actor:
+            raise ValueError(
+                "a plain task cannot hold TPU chips: a chip belongs to one "
+                "process from the moment jax opens it until that process "
+                "exits, and tasks share pooled worker processes (which are "
+                "pinned off the chip). Hold chips with an actor: "
+                "@ray_tpu.remote(num_tpus=...) on a class."
+            )
+        if not tpu_accel.valid_chip_count(tpus):
+            raise ValueError(
+                f"num_tpus={tpus:g}: an actor holds 1, 2, 4 or 8 whole chips "
+                "(the blocks libtpu can give one process)"
+            )
     return res
 
 

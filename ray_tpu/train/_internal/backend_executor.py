@@ -77,6 +77,22 @@ def _rendezvous_wait_total() -> float:
     return float(rendezvous._WAIT_STATS["wait_s"])
 
 
+def _tpu_shortfall(bundles: List[Dict[str, float]]) -> str:
+    """Why a gang that wants chips could not be placed, when the reason is
+    that the cluster does not have them: names what chip detection saw."""
+    need = sum(b.get("TPU", 0) for b in bundles)
+    have = ray_tpu.cluster_resources().get("TPU", 0)
+    if need <= have:
+        return ""
+    from ray_tpu._private.accelerators import tpu as tpu_accel
+
+    return (
+        f": it asks for {need:g} TPU chip(s) and the cluster has {have:g}. "
+        f"Chips are detected per host at init() ({tpu_accel.detection_report()}"
+        " on this host); pass init(num_tpus=...) or RAY_TPU_NUM_CHIPS to override."
+    )
+
+
 class BackendExecutor:
     def __init__(
         self,
@@ -134,6 +150,7 @@ class BackendExecutor:
                 self._pg = None
                 raise TrainingWorkerError(
                     f"placement group {bundles} not schedulable on this cluster"
+                    + _tpu_shortfall(bundles)
                 )
             try:
                 self.worker_group = WorkerGroup(

@@ -17,35 +17,116 @@ from __future__ import annotations
 
 import functools
 from dataclasses import dataclass
-from typing import Callable, Dict, Optional
+from typing import Any, Callable, Dict, List, Optional
 
 import ray_tpu
 from ray_tpu.air.config import ScalingConfig
 from ray_tpu.train.backend import Backend, BackendConfig
 
 
-def _init_jax_distributed(coordinator: str, num_processes: int, process_id: int):
-    import jax
+# libtpu's default port for the first process of a host; process k listens on
+# base + k (its chip index).
+_TPU_PROCESS_PORT_BASE = 8476
 
-    if num_processes <= 1:
-        return len(jax.devices())
-    # initialize() blocks until every process joins — a gang rendezvous.
-    # Account the blocked time so the goodput ledger's rendezvous_wait bucket
-    # covers jax bring-up, not just the collective KV waits.
-    import time
 
-    from ray_tpu.util.collective import rendezvous
+def _chip_grant() -> Dict[str, Any]:
+    """This worker's host and the chips of it the scheduler granted."""
+    import os
+    import socket
 
-    t0 = time.perf_counter()
-    try:
-        jax.distributed.initialize(
-            coordinator_address=coordinator,
-            num_processes=num_processes,
-            process_id=process_id,
-        )
-    finally:
-        rendezvous.note_wait(time.perf_counter() - t0)
-    return len(jax.devices())
+    from ray_tpu._private.accelerators import tpu as tpu_accel
+
+    visible = os.environ.get("TPU_VISIBLE_CHIPS", "")
+    return {
+        "host": socket.gethostname(),
+        "chips": [int(c) for c in visible.split(",") if c],
+        "host_chips": tpu_accel.detect_num_tpu_chips(),
+    }
+
+
+def _tpu_process_envs(grants: List[Dict[str, Any]]) -> List[Dict[str, str]]:
+    """Per-worker libtpu environment that lets one-chip processes sharing a
+    host form ONE topology (the multi-host model in miniature).
+
+    A worker that is alone on its host needs nothing: it drives its chips as
+    a host of its own and, across hosts, libtpu joins the slice from the TPU
+    VM's metadata. Workers that share a host must tell libtpu: each is one
+    process of a `host_chips`-process grid, reachable on its own port. The
+    formation verified on the v5e host with libtpu 0.0.34 is every chip of
+    the host, one per worker (4 x 1 on the 2x2 host); others are refused
+    rather than guessed.
+    """
+    from ray_tpu._private.accelerators import tpu as tpu_accel
+
+    by_host: Dict[str, List[int]] = {}
+    for i, g in enumerate(grants):
+        by_host.setdefault(g["host"], []).append(i)
+    envs: List[Dict[str, str]] = [{} for _ in grants]
+    for host, members in by_host.items():
+        if len(members) == 1:
+            continue
+        host_chips = grants[members[0]]["host_chips"]
+        held = sorted(c for i in members for c in grants[i]["chips"])
+        if (
+            len(by_host) > 1
+            or any(len(grants[i]["chips"]) != 1 for i in members)
+            or held != list(range(host_chips))
+        ):
+            raise RuntimeError(
+                f"TPU gang formation not supported: {len(members)} workers on "
+                f"host {host} hold chips {held} of {host_chips}. Workers that "
+                "share a host must be one host's worth of one-chip workers "
+                "(num_workers == chips of the host, tpus_per_worker=1); "
+                "otherwise give each host one worker with "
+                "tpus_per_worker=<chips of the host>."
+            )
+        ports = {i: _TPU_PROCESS_PORT_BASE + grants[i]["chips"][0] for i in members}
+        addresses = ",".join(f"localhost:{ports[i]}" for i in sorted(members, key=ports.get))
+        for i in members:
+            envs[i] = {
+                "TPU_PROCESS_BOUNDS": tpu_accel.process_bounds(host_chips),
+                "TPU_PROCESS_ADDRESSES": addresses,
+                "TPU_PROCESS_PORT": str(ports[i]),
+                "CLOUD_TPU_TASK_ID": str(grants[i]["chips"][0]),
+            }
+    return envs
+
+
+def _start_jax(coordinator: Optional[str], num_processes: int, process_id: int,
+               granted_chips: int, tpu_env: Dict[str, str]) -> Dict[str, Any]:
+    """Runs on every worker before any user code: compile cache, gang join,
+    and — for a worker granted chips — proof that jax came up on them."""
+    import os
+
+    from ray_tpu._private.accelerators import jax_process
+
+    os.environ.update(tpu_env)
+    jax_process.configure_compile_cache()
+    if coordinator is not None:
+        import time
+
+        import jax
+
+        from ray_tpu.util.collective import rendezvous
+
+        # initialize() blocks until every process joins — a gang rendezvous.
+        # Account the blocked time so the goodput ledger's rendezvous_wait
+        # bucket covers jax bring-up, not just the collective KV waits.
+        t0 = time.perf_counter()
+        try:
+            jax.distributed.initialize(
+                coordinator_address=coordinator,
+                num_processes=num_processes,
+                process_id=process_id,
+            )
+        finally:
+            rendezvous.note_wait(time.perf_counter() - t0)
+    if granted_chips:
+        return jax_process.require_granted_chips(granted_chips)
+    if coordinator is not None:
+        return jax_process.device_report()
+    # A lone CPU worker: leave the backend to the user loop.
+    return {}
 
 
 def _shutdown_jax_distributed():
@@ -109,25 +190,33 @@ class _JaxBackend(Backend):
             if backend_config.distributed is not None
             else n > 1
         )
-        if not distributed:
-            return
-        # Rank 0's node hosts the jax coordination service.
+        granted = int(executor._scaling._resources.get("TPU", 0))
+        tpu_envs: List[Dict[str, str]] = [{} for _ in range(n)]
+        if granted and distributed:
+            tpu_envs = _tpu_process_envs(wg.execute(_chip_grant))
+        coordinator = None
         rank_of = executor.ranks
-        rank0_index = rank_of.index(0)
-        meta = wg._metadata or wg.fetch_metadata()
-        port = wg.execute_single(rank0_index, _free_port_fn)
-        coordinator = f"{meta[rank0_index].node_ip}:{port}"
+        if distributed:
+            # Rank 0's node hosts the jax coordination service.
+            rank0_index = rank_of.index(0)
+            meta = wg._metadata or wg.fetch_metadata()
+            port = wg.execute_single(rank0_index, _free_port_fn)
+            coordinator = f"{meta[rank0_index].node_ip}:{port}"
         # All workers must enter initialize() together: fire async, then gather.
-        refs = []
-        for i, w in enumerate(wg.workers):
-            refs.append(
-                w.execute.remote(_init_jax_distributed, coordinator, n, rank_of[i])
+        reports = ray_tpu.get([
+            w.execute.remote(
+                _start_jax, coordinator, n, rank_of[i], granted, tpu_envs[i]
             )
-        device_counts = ray_tpu.get(refs)
-        if len(set(device_counts)) != 1:
-            raise RuntimeError(
-                f"workers disagree on global device count: {device_counts}"
-            )
+            for i, w in enumerate(wg.workers)
+        ])
+        if distributed:
+            counts = [r["global_devices"] for r in reports]
+            want = n * granted if granted else counts[0]
+            if set(counts) != {want}:
+                raise RuntimeError(
+                    f"workers disagree on the global device count: {counts} "
+                    f"(expected {want} from {n} workers)"
+                )
 
     def on_shutdown(self, executor, backend_config: JaxConfig):
         if executor.worker_group is not None:

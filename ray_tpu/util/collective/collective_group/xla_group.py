@@ -30,7 +30,6 @@ import numpy as np
 from ray_tpu.util.collective.collective_group.base_group import BaseGroup
 from ray_tpu.util.collective.rendezvous import clear, publish, wait_for
 from ray_tpu.util.collective.types import ReduceOp
-from ray_tpu._private.jax_compat import shard_map as _shard_map
 
 
 def _psum_like(op: ReduceOp, axis: str):
@@ -90,9 +89,7 @@ class XLAGroup(BaseGroup):
 
         # Probe WITHOUT touching the backend: jax.process_count() would
         # initialize XLA and make distributed.initialize() impossible.
-        from ray_tpu._private.jax_compat import distributed_is_initialized
-
-        if distributed_is_initialized():
+        if jax.distributed.is_initialized():
             if jax.process_count() != self.world_size:
                 raise RuntimeError(
                     f"jax.distributed already initialized with "
@@ -163,7 +160,7 @@ class XLAGroup(BaseGroup):
         else:
             raise ValueError(kind)
 
-        smapped = _shard_map(
+        smapped = jax.shard_map(
             body, mesh=mesh, in_specs=in_spec, out_specs=out_spec, check_vma=False
         )
         return jax.jit(smapped)
@@ -294,7 +291,7 @@ class XLAGroup(BaseGroup):
             # Per-device block keeps a leading length-1 stack dim; drop it so the
             # result has each contribution's own shape.
             fn = jax.jit(
-                _shard_map(
+                jax.shard_map(
                     lambda x: red(x)[0], mesh=self.mesh, in_specs=P("local"),
                     out_specs=P(), check_vma=False,
                 )
@@ -311,7 +308,7 @@ class XLAGroup(BaseGroup):
         fn = self._cache.get(("ag_md", arr.shape, str(arr.dtype)))
         if fn is None:
             fn = jax.jit(
-                _shard_map(
+                jax.shard_map(
                     lambda x: jax.lax.all_gather(x, "local", axis=0, tiled=True),
                     mesh=self.mesh,
                     in_specs=P("local"),
@@ -331,7 +328,7 @@ class XLAGroup(BaseGroup):
         fn = self._cache.get(("rs_md", op, arr.shape, str(arr.dtype)))
         if fn is None:
             fn = jax.jit(
-                _shard_map(
+                jax.shard_map(
                     # x is (1, *shape): drop the stack dim, then scatter the
                     # contribution's own leading dim across devices.
                     lambda x: jax.lax.psum_scatter(x[0], "local", scatter_dimension=0, tiled=True),

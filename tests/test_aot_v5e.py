@@ -1,0 +1,150 @@
+"""Compile for the v5e without holding one.
+
+libtpu is installed, so `jax.experimental.topologies.get_topology_desc` hands
+out the devices of a `v5e:2x2` host under JAX_PLATFORMS=cpu and a jitted
+function can be lowered and compiled for them. This is how the chip path is
+checked on every PR from a sandbox with no chip: the Mosaic kernels must
+compile, and the flagship step must lower on more than one device (XLA cannot
+partition a Mosaic call; before the kernel ran inside a shard_map the
+four-device step died here with "Mosaic kernels cannot be automatically
+partitioned").
+
+The cases run in one subprocess (`python tests/test_aot_v5e.py <cases>`): libtpu
+start-up is kept out of the pytest process and its 8-device CPU backend.
+"""
+
+import json
+import os
+import subprocess
+import sys
+
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+# memory_stats()["bytes_limit"] of one v5e chip (chip run, PR 21).
+V5E_HBM_BYTES = 16_909_336_064
+B, S = 16, 1024  # the flagship cell: gpt2_small, batch 16 x seq 1024
+
+
+def _kernel_case(topo):
+    """Forward + fused backward kernel at GPT-2 shapes, one device."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.ops.flash_attention import flash_attention
+
+    x = jax.ShapeDtypeStruct(
+        (B, 12, S, 64), jnp.bfloat16,
+        sharding=jax.sharding.SingleDeviceSharding(topo.devices[0]),
+    )
+    loss = lambda q, k, v: flash_attention(q, k, v, backend="pallas").astype(jnp.float32).sum()
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(x, x, x).compile()
+    return {"mosaic_calls": compiled.as_text().count("tpu_custom_call")}
+
+
+def _step_case(topo, axes, compile_it):
+    """`make_train_step` on gpt2_small over `axes`, from abstract inputs laid
+    out as `create_train_state` / `shard_batch` lay out real ones."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from ray_tpu.models import GPTConfig, default_optimizer, gpt, make_train_step
+    from ray_tpu.models.training import TrainState, param_shardings
+    from ray_tpu.parallel import MeshSpec, ShardingRules, batch_spec
+
+    spec = MeshSpec(**axes)
+    mesh = spec.build(topo.devices[: spec.num_devices])
+    cfg = GPTConfig.gpt2_small()
+    opt = default_optimizer(learning_rate=3e-4)
+    shapes = jax.eval_shape(lambda: gpt.init_params(cfg, jax.random.PRNGKey(0)))
+    shardings = param_shardings(cfg, mesh, ShardingRules())
+    replicated = NamedSharding(mesh, P())
+    by_shape = dict(zip(
+        (s.shape for s in jax.tree.leaves(shapes)), jax.tree.leaves(shardings)))
+
+    def abstract(s, sharding):
+        return jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sharding)
+
+    state = TrainState(
+        params=jax.tree.map(abstract, shapes, shardings),
+        # Adam moments are laid out like their parameter; counters replicate.
+        opt_state=jax.tree.map(
+            lambda s: abstract(s, by_shape.get(s.shape, replicated)),
+            jax.eval_shape(opt.init, shapes)),
+        step=jax.ShapeDtypeStruct((), jnp.int32, sharding=replicated),
+    )
+    batch = {"tokens": jax.ShapeDtypeStruct(
+        (B, S + 1), jnp.int32, sharding=NamedSharding(mesh, batch_spec()))}
+    lowered = make_train_step(cfg, opt, mesh=mesh).lower(state, batch)
+    out = {"mosaic_calls": lowered.as_text().count("tpu_custom_call")}
+    if compile_it:
+        compiled = lowered.compile()
+        mem = compiled.memory_analysis()
+        out["mosaic_calls_compiled"] = compiled.as_text().count("tpu_custom_call")
+        out["device_bytes"] = (
+            mem.argument_size_in_bytes + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes + mem.temp_size_in_bytes
+        )
+    return out
+
+
+_MESHES = {"d1": {"data": 1}, "d4": {"data": 4}, "d2t2": {"data": 2, "tensor": 2}}
+
+
+def _main(cases):
+    sys.path.insert(0, REPO)
+    from jax.experimental import topologies
+
+    topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    results = {"device_kind": topo.devices[0].device_kind}
+    for case in cases:
+        if case == "kernel":
+            results[case] = _kernel_case(topo)
+        else:
+            verb, mesh = case.split(":")
+            results[case] = _step_case(topo, _MESHES[mesh], verb == "compile")
+    print("AOT_RESULT " + json.dumps(results))
+
+
+def _run(cases):
+    proc = subprocess.run(
+        [sys.executable, os.path.abspath(__file__), *cases],
+        env={**os.environ, "JAX_PLATFORMS": "cpu"},
+        capture_output=True, text=True, timeout=600,
+    )
+    lines = [l for l in proc.stdout.splitlines() if l.startswith("AOT_RESULT ")]
+    assert proc.returncode == 0 and lines, proc.stdout[-2000:] + proc.stderr[-4000:]
+    return json.loads(lines[-1][len("AOT_RESULT "):])
+
+
+@pytest.fixture(scope="module")
+def aot():
+    return _run(["kernel", "lower:d4", "lower:d2t2"])
+
+
+def test_topology_is_the_v5e(aot):
+    assert aot["device_kind"] == "TPU v5 lite"
+
+
+def test_flash_kernels_compile_for_v5e_at_gpt2_shapes(aot):
+    assert aot["kernel"]["mosaic_calls"] == 2  # forward, fused backward
+
+
+@pytest.mark.parametrize("mesh", ["d4", "d2t2"])
+def test_gpt2_small_step_lowers_on_four_chips_with_the_kernel(aot, mesh):
+    assert aot[f"lower:{mesh}"]["mosaic_calls"] == 2
+
+
+@pytest.mark.slow
+def test_gpt2_small_step_compiles_and_fits_hbm_on_one_and_four_chips():
+    out = _run(["compile:d1", "compile:d4", "compile:d2t2"])
+    for case, r in out.items():
+        if case == "device_kind":
+            continue
+        assert r["mosaic_calls_compiled"] == 2, (case, r)
+        assert 0 < r["device_bytes"] < V5E_HBM_BYTES, (case, r)
+
+
+if __name__ == "__main__":
+    _main(sys.argv[1:])

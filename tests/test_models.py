@@ -119,9 +119,7 @@ def test_ulysses_attention_matches_full():
     b, h, s, d = 2, 4, 64, 16
     q, k, v = (jax.random.normal(kk, (b, h, s, d), jnp.float32) for kk in jax.random.split(key, 3))
     spec = P(None, None, "context", None)
-    from ray_tpu._private.jax_compat import shard_map
-
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(ulysses_attention, axis_name="context", axis_size=2),
         mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec, check_vma=False,
     )

@@ -46,17 +46,28 @@ def test_flash_backward_matches_reference(qkv):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-4)
 
 
-def test_flash_misaligned_seq_falls_back_to_xla():
-    """Seq lens with no usable power-of-two block divisor (e.g. 100) silently
-    use the XLA path instead of raising; seq lens divisible by 512 but not by
-    the 1024 default shrink the block via gcd and stay on pallas."""
+def test_misaligned_seq_selection_is_visible_not_silent():
+    """A seq len with no block of >=128 dividing it (e.g. 100) cannot run the
+    kernel: asked for by name that is an error, and the automatic choice says
+    "xla" through select_backend instead of switching silently. Seq lens
+    divisible by 512 but not by the 1024 default shrink the block via gcd and
+    stay on pallas."""
+    from ray_tpu.ops.flash_attention import select_backend
+
     rng = np.random.default_rng(0)
     q = jnp.asarray(rng.standard_normal((1, 2, 100, 64)), jnp.float32)
-    out = flash_attention(q, q, q, backend="pallas", interpret=True, block_q=64, block_k=64)
+    with pytest.raises(ValueError, match="no block of at least 128"):
+        flash_attention(q, q, q, backend="pallas", interpret=True, block_q=64, block_k=64)
+    assert select_backend(q.shape, platform="tpu") == "xla"
+    assert select_backend((16, 12, 1024, 64), platform="tpu") == "pallas"
+    assert select_backend((1, 8, 8192, 128), platform="tpu") == "blockwise"
+    assert select_backend((16, 12, 1024, 64), platform="cpu") == "xla"
+    out = flash_attention(q, q, q)  # this process is on CPU: the XLA form
     ref = xla_attention(q, q, q, causal=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5)
 
     q2 = jnp.asarray(rng.standard_normal((1, 1, 1536, 64)), jnp.float32)
+    assert select_backend(q2.shape, platform="tpu") == "pallas"
     out2 = flash_attention(q2, q2, q2, backend="pallas", interpret=True)  # gcd -> 512
     ref2 = xla_attention(q2, q2, q2, causal=True)
     np.testing.assert_allclose(np.asarray(out2), np.asarray(ref2), atol=2e-4)

@@ -92,14 +92,25 @@ def test_tpu_slice_pg_on_fake_hosts(ray_start_cluster):
     pg = tpu_slice_placement_group(num_hosts=2, chips_per_host=4, cpus_per_host=1)
     assert pg.ready(timeout=10)
 
+    # Chips are held by actors (one process owns a chip for its lifetime);
+    # each host worker is granted all four chips of its host.
     @ray_tpu.remote(num_cpus=1, num_tpus=4)
-    def host_task(i):
-        return i
+    class HostWorker:
+        def chips(self):
+            import os
+
+            return os.environ["TPU_VISIBLE_CHIPS"]
 
     strategy = PlacementGroupSchedulingStrategy(pg)
-    assert sorted(
-        ray_tpu.get([host_task.options(scheduling_strategy=strategy).remote(i) for i in range(2)], timeout=30)
-    ) == [0, 1]
+    hosts = [HostWorker.options(scheduling_strategy=strategy).remote() for _ in range(2)]
+    assert ray_tpu.get([h.chips.remote() for h in hosts], timeout=30) == ["0,1,2,3"] * 2
+
+    @ray_tpu.remote(num_tpus=4)
+    def host_task():
+        return 0
+
+    with pytest.raises(ValueError, match="plain task cannot hold TPU chips"):
+        host_task.remote()
 
 
 def test_invalid_bundles_rejected(ray_start_regular):

@@ -951,9 +951,10 @@ def test_stale_binary_guard(tmp_path):
     assert stale.run(native_dir=str(tmp_path)) == []
 
 
-def test_checked_in_binaries_match_their_sources():
-    # The live stale check: the committed .so files embed the sha256 of the
-    # exact sources they were built from.
+def test_built_binaries_match_their_sources():
+    # The live stale check: whatever .so this checkout has built (none are
+    # in git; a fresh clone builds them on first use) embeds the sha256 of
+    # the exact source next to it.
     from ray_tpu.devtools.verify import stale
 
     violations = stale.run()

@@ -69,6 +69,15 @@ echo "== elastic smoke (4-worker gang, seeded kill -> resize-in-place at world 3
 timeout -k 10 240 env JAX_PLATFORMS=cpu python tools/elastic_smoke.py
 
 echo
+echo "== v5e ahead-of-time compile (no chip: gpt2_small step on 1 and 4 devices fits HBM, kernels in it) =="
+timeout -k 10 300 env JAX_PLATFORMS=cpu python -m pytest tests/test_aot_v5e.py -q -m slow \
+    -p no:cacheprovider
+
+echo
+echo "== chip smoke rehearsal (every phase of chip_smoke.py at toy size on CPU; not a chip result) =="
+timeout -k 10 300 env JAX_PLATFORMS=cpu python chip_smoke.py --rehearse-cpu
+
+echo
 echo "== tier-1 tests =="
 rm -f /tmp/_t1.log
 timeout -k 10 870 env JAX_PLATFORMS=cpu \
