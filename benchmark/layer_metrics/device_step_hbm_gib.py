@@ -1,0 +1,16 @@
+"""The compiled step's `memory_analysis()` per device: arguments +
+temporaries + outputs - aliased. Says whether a batch still fits; moves no
+end-to-end metric by itself."""
+
+META = {
+    "name": "device.step_hbm_gib",
+    "unit": "GiB",
+    "better": "lower",
+    "source": "program_counter",
+    "layer": "device",
+    "moves": "tokens_per_s_per_chip"
+}
+
+
+def read(run):
+    return run["summary"]["compiled_step"]["step_bytes"] / 2 ** 30
