@@ -265,11 +265,16 @@ def _block(x, layer, config: GPTConfig, attention_fn, drop_rng=None, sub_remat=F
         qkv_part = jax.checkpoint(qkv_part, prevent_cse=False)
         out_mlp_part = jax.checkpoint(out_mlp_part, prevent_cse=False)
 
-    q, k, v = qkv_part(x, layer)
     from ray_tpu.models.stack import resolve_attention
 
-    o = resolve_attention(q, k, v, config.attention, attention_fn, mesh)  # (B, nh, S, hd)
-    return out_mlp_part(x, o, layer)
+    # Scope names are read from the compiled program's `op_name`s by whoever
+    # splits a device trace by part of the step (PERF.md, "names").
+    with jax.named_scope("qkv"):
+        q, k, v = qkv_part(x, layer)
+    with jax.named_scope("attention"):
+        o = resolve_attention(q, k, v, config.attention, attention_fn, mesh)  # (B, nh, S, hd)
+    with jax.named_scope("out_mlp"):
+        return out_mlp_part(x, o, layer)
 
 
 def forward(
@@ -293,7 +298,8 @@ def forward(
     pipeline axis — they are a small fraction of the FLOPs)."""
     B, S = tokens.shape
     cdt = config.dtype
-    x = params["wte"].astype(cdt)[tokens] + params["wpe"].astype(cdt)[:S][None]
+    with jax.named_scope("embed"):
+        x = params["wte"].astype(cdt)[tokens] + params["wpe"].astype(cdt)[:S][None]
     use_dropout = dropout_rng is not None and config.dropout > 0
     layers_rng = None
     if use_dropout:
@@ -335,16 +341,17 @@ def forward(
         num_microbatches=num_microbatches,
     )
 
-    x = _layer_norm(x, params["lnf_scale"], params["lnf_bias"])
-    # Tied LM head: bf16 operands on the MXU, f32 accumulation — an f32×f32
-    # matmul here would run at a fraction of MXU rate and this matmul is ~30%
-    # of GPT-2-small's FLOPs.
-    logits = jnp.einsum(
-        "bsd,vd->bsv",
-        x.astype(cdt),
-        params["wte"].astype(cdt),
-        preferred_element_type=jnp.float32,
-    )
+    with jax.named_scope("head"):
+        x = _layer_norm(x, params["lnf_scale"], params["lnf_bias"])
+        # Tied LM head: bf16 operands on the MXU, f32 accumulation — an f32×f32
+        # matmul here would run at a fraction of MXU rate and this matmul is ~30%
+        # of GPT-2-small's FLOPs.
+        logits = jnp.einsum(
+            "bsd,vd->bsv",
+            x.astype(cdt),
+            params["wte"].astype(cdt),
+            preferred_element_type=jnp.float32,
+        )
     if return_aux:
         return logits, moe_aux
     return logits

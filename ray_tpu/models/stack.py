@@ -61,17 +61,19 @@ def apply_stack(
             return xm, jnp.sum(auxs)
 
         M = num_microbatches or (2 * n_pipeline if B % (2 * n_pipeline) == 0 else n_pipeline)
-        return pipeline_apply(
-            mesh, to_stages(blocks, n_pipeline), x, stack_fn, M,
-            context_manual=context_manual,
-            seq_streams=seq_streams,
+        with jax.named_scope("blocks"):
+            return pipeline_apply(
+                mesh, to_stages(blocks, n_pipeline), x, stack_fn, M,
+                context_manual=context_manual,
+                seq_streams=seq_streams,
+            )
+    with jax.named_scope("blocks"):
+        x, auxs = jax.lax.scan(
+            make_block_fn(0, attention_fn, None, seq_streams),
+            x,
+            (blocks, jnp.arange(n_layer)),
         )
-    x, auxs = jax.lax.scan(
-        make_block_fn(0, attention_fn, None, seq_streams),
-        x,
-        (blocks, jnp.arange(n_layer)),
-    )
-    return x, jnp.sum(auxs)
+        return x, jnp.sum(auxs)
 
 
 def resolve_attention(q, k, v, attention_mode: str, attention_fn: Optional[Callable],
@@ -98,6 +100,7 @@ def causal_lm_loss(logits, targets):
     """Fused cross entropy: logsumexp - logit[target], one reduction over V
     instead of materializing the (B, S, V) log-softmax (saves ~2x V-sized HBM
     traffic)."""
-    lse = jax.scipy.special.logsumexp(logits, axis=-1)
-    at_target = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
-    return (lse - at_target).mean()
+    with jax.named_scope("loss"):
+        lse = jax.scipy.special.logsumexp(logits, axis=-1)
+        at_target = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
+        return (lse - at_target).mean()

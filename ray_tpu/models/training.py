@@ -20,6 +20,7 @@ import jax.numpy as jnp
 
 from ray_tpu.parallel import ShardingRules, batch_spec
 from ray_tpu.models import gpt
+from ray_tpu.util.tracing import annotate
 
 
 def model_for(config):
@@ -99,15 +100,17 @@ def make_train_step(
                 p, batch, config, attention_fn, dropout_rng, mesh=mesh
             )
 
-        loss, grads = jax.value_and_grad(loss_of)(state.params)
-        updates, new_opt = optimizer.update(grads, state.opt_state, state.params)
         import optax
 
-        new_params = optax.apply_updates(state.params, updates)
+        loss, grads = jax.value_and_grad(loss_of)(state.params)
+        with jax.named_scope("optimizer"):
+            updates, new_opt = optimizer.update(grads, state.opt_state, state.params)
+            new_params = optax.apply_updates(state.params, updates)
         new_state = TrainState(
             params=new_params, opt_state=new_opt, step=state.step + 1
         )
-        gnorm = optax.global_norm(grads)
+        with jax.named_scope("grad_norm"):
+            gnorm = optax.global_norm(grads)
         return new_state, {"loss": loss, "grad_norm": gnorm, "step": new_state.step}
 
     return jax.jit(step_fn, donate_argnums=(0,) if donate else ())
@@ -128,7 +131,9 @@ def shard_batch(batch: Dict[str, Any], mesh):
             spec = P(("data", "fsdp"))
         return jax.device_put(x, NamedSharding(mesh, spec))
 
-    return jax.tree.map(put, batch)
+    nbytes = sum(getattr(x, "nbytes", 0) for x in jax.tree.leaves(batch))
+    with annotate("ray_tpu.train.shard_batch", bytes=nbytes):
+        return jax.tree.map(put, batch)
 
 
 def default_optimizer(

@@ -30,10 +30,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# Swept through the full GPT-2 train step on v5e: 1024x1024 > 512x512 by ~2%
-# end-to-end (fewer grid steps and loop iterations; more MXU work per step
-# amortizes the online-softmax vector ops). Blocks are capped to seq_len at
-# call time, so short sequences still get valid (smaller) blocks.
+# One 1024x1024 block per (batch, head) at GPT-2's sequence length: fewer grid
+# steps and loop iterations, more MXU work per step to amortize the
+# online-softmax vector ops. Against 512x512 through the full train step on
+# v5e: not measured (no driver record; PERF.md Finding 6). Blocks are capped
+# to seq_len at call time, so short sequences still get valid (smaller) blocks.
 DEFAULT_BLOCK_Q = 1024
 DEFAULT_BLOCK_K = 1024
 NEG_INF = -1e30
@@ -144,6 +145,7 @@ def _fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret):
         ],
         out_shape=out_shape,
         interpret=interpret,
+        name="flash_fwd",
         compiler_params=None if interpret else pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"),
         ),
@@ -264,6 +266,7 @@ def _bwd(causal, sm_scale, block_q, block_k, interpret, res, g):
         ],
         scratch_shapes=[pltpu.VMEM((seq, d), jnp.float32)],
         interpret=interpret,
+        name="flash_bwd",
         compiler_params=None if interpret else pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),

@@ -22,6 +22,8 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from ray_tpu.util.tracing import annotate
+
 AXIS_ORDER = ("data", "fsdp", "pipeline", "expert", "context", "tensor")
 
 
@@ -204,7 +206,8 @@ def host_local_to_global(mesh, spec, array):
     import jax
     from jax.sharding import NamedSharding
 
-    return jax.make_array_from_process_local_data(NamedSharding(mesh, spec), array)
+    with annotate("ray_tpu.parallel.host_local_to_global", bytes=getattr(array, "nbytes", 0)):
+        return jax.make_array_from_process_local_data(NamedSharding(mesh, spec), array)
 
 
 def global_to_host_local(garr) -> np.ndarray:

@@ -20,6 +20,7 @@ from typing import Any, Callable, Dict, Optional
 
 from ray_tpu.air import session as air_session
 from ray_tpu.air.checkpoint import Checkpoint
+from ray_tpu.util.tracing import annotate
 
 REPORT = "report"
 DONE = "done"
@@ -152,6 +153,10 @@ class _TrainSession:
             self._clock.mark(phase)
 
     def report(self, metrics: Dict[str, Any], *, checkpoint: Optional[Checkpoint] = None):
+        with annotate("ray_tpu.train.report", checkpoint=int(checkpoint is not None)):
+            self._report(metrics, checkpoint)
+
+    def _report(self, metrics: Dict[str, Any], checkpoint: Optional[Checkpoint]) -> None:
         from ray_tpu._private import failpoints
 
         if self._stop.is_set():
@@ -167,7 +172,8 @@ class _TrainSession:
         )
         clock = self._clock
         if clock is None:
-            self._q.put(result)
+            with annotate("ray_tpu.train.report.put"):
+                self._q.put(result)
             self._reported_steps += 1
             if self._stop.is_set():
                 raise SessionDrained()
@@ -179,7 +185,8 @@ class _TrainSession:
         # report (or checkpoint) phase of the step now opening.
         clock.mark("checkpoint" if checkpoint is not None else "report")
         try:
-            self._q.put(result)
+            with annotate("ray_tpu.train.report.put"):
+                self._q.put(result)
         finally:
             clock.mark("step_exec")
         self._reported_steps += 1

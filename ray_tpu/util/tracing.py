@@ -35,6 +35,7 @@ from __future__ import annotations
 import contextvars
 import json
 import os
+import sys
 import threading
 import time
 from typing import Any, Callable, Dict, List, Optional
@@ -371,6 +372,46 @@ class span:
 
     def __exit__(self, exc_type, _exc, _tb):
         end_span(self._span, "ERROR" if exc_type else "OK")
+        return False
+
+
+class annotate:
+    """A seam of the framework, named for whoever is looking: `with
+    annotate("ray_tpu.train.report", checkpoint=0): ...`.
+
+    While a `jax.profiler` session runs (the benchmark's `--trace 1`, an
+    operator's own `start_trace`) the block is a `TraceAnnotation` on the
+    profiler's clock, beside the device's `XLA Ops`, with `stats` as its
+    arguments; with no session it costs the profiler's one flag test. A
+    process that never imported jax gets nothing and is not made to import it
+    (the data path). When span recording is on and a span is current on this
+    thread, the same interval is also recorded as that span's child, so the
+    span timeline and the profiler see one set of seams."""
+
+    __slots__ = ("_name", "_stats", "_inner", "_ctx", "_start")
+
+    def __init__(self, name: str, **stats: Any):
+        self._name = name
+        self._stats = stats
+
+    def __enter__(self):
+        profiler = sys.modules.get("jax.profiler")
+        make = getattr(profiler, "TraceAnnotation", None)
+        self._inner = make(self._name, **self._stats) if make is not None else None
+        self._ctx = current_trace_context() if is_enabled() else None
+        if self._ctx is not None:
+            self._start = time.time()
+        if self._inner is not None:
+            self._inner.__enter__()
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        if self._inner is not None:
+            self._inner.__exit__(exc_type, exc, tb)
+        if self._ctx is not None:
+            record_span(self._name, "annotation", self._start, time.time(),
+                        trace_context=self._ctx, attributes=dict(self._stats),
+                        status="ERROR" if exc_type else "OK")
         return False
 
 
