@@ -46,6 +46,9 @@ import time
 
 STEPS = 8
 BATCH, SEQ = 16, 1024
+# (batch, heads, seq, head_dim) a chip sees in benchmark/configs: gpt2-medium
+# (8 rows x 16 heads) and gpt2-xl-fsdp4 (16 rows over 4 chips x 25 heads).
+BENCHMARK_ATTENTION_SHAPES = ((8, 16, 1024, 64), (4, 25, 1024, 64))
 PHASE_TIMEOUT_S = 420.0
 
 
@@ -65,7 +68,7 @@ def train_loop(config):
         make_train_step, shard_batch,
     )
     from ray_tpu.ops.flash_attention import (
-        flash_attention, select_backend, xla_attention,
+        flash_attention, kernel_plan, select_backend, xla_attention,
     )
 
     rehearse = config["rehearse"]
@@ -117,35 +120,41 @@ def train_loop(config):
     mesh = session.get_mesh()
     out = {"device": device, "mesh": {k: int(v) for k, v in mesh.shape.items() if v > 1}}
 
-    # ---- the kernels against the reference, at the training shape
+    # ---- the kernels against the reference, at the training shape and at the
+    # per-chip attention shapes of both benchmark configurations
     qshape = (batch_size, cfg.n_head, seq, cfg.head_dim)
     out["attention_path"] = select_backend(qshape, dev.platform)
     if config["check_kernel"] and not rehearse:
         check(out["attention_path"] == "pallas",
               f"attention path for {qshape} is {out['attention_path']!r}, not the kernel")
-        keys = jax.random.split(jax.random.PRNGKey(1), 4)
-        q, k, v, do = (
-            jax.random.normal(kk, qshape, jnp.float32).astype(cfg.dtype) for kk in keys
-        )
 
         def grads_of(attn):
-            def f(q, k, v):
+            def f(q, k, v, do):
                 o = attn(q, k, v)
                 return (o.astype(jnp.float32) * do.astype(jnp.float32)).sum(), o
             return jax.jit(jax.grad(f, argnums=(0, 1, 2), has_aux=True))
 
-        kernel = lambda q, k, v: flash_attention(q, k, v, causal=True, backend="pallas")
-        (dq, dk, dv), o = grads_of(kernel)(q, k, v)
-        (rq, rk, rv), ro = grads_of(lambda q, k, v: xla_attention(q, k, v, causal=True))(q, k, v)
-        errs = {}
-        for name, got, ref in (("o", o, ro), ("dq", dq, rq), ("dk", dk, rk), ("dv", dv, rv)):
-            got, ref = np.asarray(got, np.float32), np.asarray(ref, np.float32)
-            check(np.isfinite(got).all(), f"kernel {name} not finite")
-            err, scale = float(np.abs(got - ref).max()), float(np.abs(ref).max())
-            errs[name] = [err, scale]
-            # bf16 keeps 8 bits: two roundings of values up to `scale`.
-            check(err <= 2.0 ** -6 * scale, f"kernel {name} off by {err} (max |ref| {scale})")
-        out["kernel_vs_xla_max_abs_err"] = errs
+        kernel = grads_of(lambda q, k, v: flash_attention(q, k, v, causal=True, backend="pallas"))
+        reference = grads_of(lambda q, k, v: xla_attention(q, k, v, causal=True))
+        out["kernel_plan"], out["kernel_vs_xla_max_abs_err"] = {}, {}
+        for shape in (qshape, *BENCHMARK_ATTENTION_SHAPES):
+            label = "x".join(map(str, shape))
+            out["kernel_plan"][label] = kernel_plan(shape, causal=True)._asdict()
+            keys = jax.random.split(jax.random.PRNGKey(1), 4)
+            q, k, v, do = (
+                jax.random.normal(kk, shape, jnp.float32).astype(cfg.dtype) for kk in keys
+            )
+            (dq, dk, dv), o = kernel(q, k, v, do)
+            (rq, rk, rv), ro = reference(q, k, v, do)
+            errs = out["kernel_vs_xla_max_abs_err"][label] = {}
+            for name, got, ref in (("o", o, ro), ("dq", dq, rq), ("dk", dk, rk), ("dv", dv, rv)):
+                got, ref = np.asarray(got, np.float32), np.asarray(ref, np.float32)
+                check(np.isfinite(got).all(), f"kernel {name} not finite at {shape}")
+                err, scale = float(np.abs(got - ref).max()), float(np.abs(ref).max())
+                errs[name] = [err, scale]
+                # bf16 keeps 8 bits: two roundings of values up to `scale`.
+                check(err <= 2.0 ** -6 * scale,
+                      f"kernel {name} at {shape} off by {err} (max |ref| {scale})")
 
     # ---- the step
     opt = default_optimizer(learning_rate=3e-4)
@@ -330,6 +339,8 @@ def run_trainer(name, rehearse, storage, *, num_workers=1, tpus_per_worker=None,
           f"step: {s['mosaic_calls_in_step']}; kernel vs xla_attention [max abs err, max |ref|]: "
           f"{s.get('kernel_vs_xla_max_abs_err')}; loss kernel/xla-attention: "
           f"{s.get('loss_kernel_vs_xla_attention')}")
+    for shape, plan in (s.get("kernel_plan") or {}).items():
+        print(f"[{name}] kernel_plan {shape}: {plan}")
     print(f"[{name}] compile: step {s['step_compile_s']:.2f}s ({s['step_compile_cache']}), "
           f"all {s['compiles']} compiles {s['compile_s_total']:.2f}s, cache hits "
           f"{s['cache_hits']} misses {s['cache_misses']}, cache at {s['device']['cache_dir']}")

@@ -2,15 +2,21 @@
 form for non-TPU backends (`select_backend` says which one a call runs).
 
 Design (pallas_guide.md playbook):
- - forward: grid over (batch*heads, q_blocks); K/V rows for the (b,h) pair live
-   in VMEM; online-softmax accumulation in fp32 over K blocks (fori_loop, no
-   dynamic Python control flow); causal masking prunes future K blocks via the
-   loop bound, and the diagonal block via broadcasted_iota row/col ids.
- - backward: ONE fused kernel per (batch*heads) computing dk/dv blockwise and
-   accumulating dq in a VMEM scratch across the sequential K-block grid dim —
-   s/p are recomputed once per (q,k) block pair instead of twice (the classic
-   two-kernel split recomputes them in both the dq and dkv kernels).
-   O(seq) memory, the point of flash attention.
+ - both kernels walk a schedule of (Q tile, K tile) pairs over the score
+   matrix of one (batch, head) (`kernel_plan` says which). Causal: only the
+   tiles on or under the diagonal are visited, and only those the diagonal
+   crosses pay the mask; the rest of the square is never computed.
+ - a short causal schedule is unrolled at trace time inside one program per
+   (batch, head) that holds the whole head in VMEM: every slice static, the
+   diagonal known to the compiler. Otherwise a program is one outer tile
+   (grid axis 1) and walks the inner tiles in `fori_loop`s; one body serves
+   both forms.
+ - forward: online softmax in f32 over the K tiles of each Q tile; row
+   statistics stay (tile_q, 1), the layout of the refs they are stored to.
+ - backward: ONE fused kernel computing dk/dv per K tile and accumulating dq
+   in a VMEM scratch: s/p are recomputed once per tile pair instead of twice
+   (the classic two-kernel split recomputes them in both the dq and dkv
+   kernels). O(seq) memory, the point of flash attention.
  - matmuls run on the MXU with preferred_element_type=float32; inputs can be
    bfloat16.
 
@@ -23,20 +29,40 @@ from __future__ import annotations
 
 import functools
 import math
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# One 1024x1024 block per (batch, head) at GPT-2's sequence length: fewer grid
-# steps and loop iterations, more MXU work per step to amortize the
-# online-softmax vector ops. Against 512x512 through the full train step on
-# v5e: not measured (no driver record; PERF.md Finding 6). Blocks are capped
-# to seq_len at call time, so short sequences still get valid (smaller) blocks.
-DEFAULT_BLOCK_Q = 1024
-DEFAULT_BLOCK_K = 1024
+# Tile sizes, from the kernel-alone sweep on the v5e (tools/flash_bench.py;
+# PERF.md section 6, PR 26; device time of one call, forward / backward, us):
+#   (128, 1024, 64) causal: 512-tiles 333 / 754, 256-tiles 325 / 758,
+#     128-tiles 349 / 1194, one 1024-tile with nothing skipped 416 / 946; what
+#     this replaced, one masked 1024 x 1024 block: 633 / 1044.
+#   (32, 2048, 128) causal: 512-tiles 265 / 572, 256-tiles 243 / 681; the
+#     loop form at 1024-tiles 527 / 818.
+#   In the loop form a tile is dear: (8, 4096, 128) 1024-tiles 393 / 649,
+#     512 371 / 684, 256 630 / 1225. Not causal there is nothing to skip:
+#     (128, 1024, 64) 1024 450 / 951, 512 633 / 1228.
+# So: a causal schedule that unrolls walks 512-tiles (256-tiles skip more of
+# the square and are 0.4 % faster at most, and every unrolled tile is traced
+# and lowered again at each lowering of a step), and everything else walks
+# the largest tile in the loop form, as it always did.
+CAUSAL_TILE = 512
+FULL_TILE = 1024
+# A schedule is unrolled while its tiles are no larger than CAUSAL_TILE and it
+# is at most this many tile pairs (the triangle of an 8 x 8 schedule) covering
+# at most this many scores: unrolled code keeps every tile's temporaries on
+# the kernel's VMEM stack ...
+MAX_UNROLLED_TILES = 36
+MAX_UNROLLED_SCORES = 2048 * 2048
+# ... and its program holds whole heads (seven operands in the backward pass,
+# double-buffered) under Mosaic's default 16 MiB, no `vmem_limit_bytes` asked
+# for: 2048 x 128 in bf16 compiles for the v5e, larger heads do not. The loop
+# form (a tile a program) compiles every shape it compiled before.
+MAX_UNROLLED_HEAD_BYTES = 2048 * 128 * 2
 NEG_INF = -1e30
 
 
@@ -56,221 +82,268 @@ def xla_attention(q, k, v, causal: bool = True, sm_scale: Optional[float] = None
     return jnp.einsum("bhqk,bhkd->bhqd", p, v, preferred_element_type=jnp.float32).astype(q.dtype)
 
 
+# --------------------------------------------------------------------------- tile schedule
+def _diag_and_end(t, size, other, n_other, static):
+    """For tile `t` (rows `[t*size, (t+1)*size)`) of one side of the score
+    matrix, the tiles of the other side (size `other`, `n_other` of them) that
+    the causal diagonal crosses: `[diag, end)`. The forward kernel asks with a
+    Q tile and gets K tiles (those before `diag` lie wholly under the diagonal,
+    those from `end` on wholly above it); the backward asks with a K tile and
+    gets Q tiles (`[diag, end)` masked, `[end, n)` wholly under). Python ints in
+    the unrolled schedule, traced values in the loop form."""
+    diag = (t * size) // other
+    end = ((t + 1) * size + other - 1) // other
+    return diag, (min(end, n_other) if static else jnp.minimum(end, n_other))
+
+
+def _for(lo, hi, body, carry, static):
+    """`fori_loop`, or the same loop unrolled at trace time when its bounds
+    are Python ints: every slice in `body` is then static."""
+    if not static:
+        return jax.lax.fori_loop(lo, hi, body, carry)
+    for t in range(lo, hi):
+        carry = body(t, carry)
+    return carry
+
+
+def _tile(t, size, static):
+    return pl.ds(t * size, size) if static else pl.ds(pl.multiple_of(t * size, size), size)
+
+
+def _causal_mask(tile_q, tile_k):
+    """`keep(i, j)`: which scores of tile (i, j) lie on or under the diagonal.
+    `row - col` inside a tile is one constant per program; a tile adds only
+    its offset, and in a square unrolled schedule every diagonal tile has
+    offset 0, so they all share one `row >= col` pattern."""
+    diff = (jax.lax.broadcasted_iota(jnp.int32, (tile_q, tile_k), 0)
+            - jax.lax.broadcasted_iota(jnp.int32, (tile_q, tile_k), 1))
+    cache = {}
+
+    def keep(i, j):
+        offset = j * tile_k - i * tile_q
+        if not isinstance(offset, int):
+            return diff >= offset
+        if offset not in cache:
+            cache[offset] = diff >= offset
+        return cache[offset]
+
+    return keep
+
+
 # --------------------------------------------------------------------------- forward kernel
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, sm_scale, causal, block_q, block_k, seq_len):
-    qi = pl.program_id(1)
-    # Matmul operands stay in their input dtype (bf16 in training): f32x f32
-    # dots run the MXU at a fraction of its bf16 rate; accumulation is f32 via
-    # preferred_element_type either way. sm_scale folds into q once (block_q x d)
-    # instead of rescaling every (block_q x block_k) score matrix.
-    q = (q_ref[0].astype(jnp.float32) * sm_scale).astype(q_ref.dtype)  # (block_q, d)
+def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, sm_scale, causal, tile_q, tile_k, static):
+    """Per Q tile the online softmax walks the K tiles on or under the
+    diagonal, unmasked ones first. Unrolled, one program holds a whole
+    (batch, head) and visits its Q tiles in turn; in the loop form a program
+    is one Q tile (grid axis 1) and `q_ref`, `o_ref`, `lse_ref` are that tile."""
+    seq, d = k_ref.shape[1], k_ref.shape[2]
+    n_q, n_k = seq // tile_q, seq // tile_k
+    keep = _causal_mask(tile_q, tile_k) if causal else None
 
-    num_k_blocks = pl.cdiv(seq_len, block_k)
-    if causal:
-        # Future K blocks contribute nothing: stop after the diagonal block.
-        hi = jax.lax.div((qi + 1) * block_q + block_k - 1, block_k)
-        hi = jnp.minimum(hi, num_k_blocks)
+    def q_tile(i, rows):
+        # Matmul operands stay in their input dtype (bf16 in training): f32 x f32
+        # dots run the MXU at a fraction of its bf16 rate; accumulation is f32 via
+        # preferred_element_type either way. sm_scale folds into q once per Q
+        # tile instead of rescaling every (tile_q x tile_k) score matrix.
+        q = (q_ref[0, rows, :].astype(jnp.float32) * sm_scale).astype(q_ref.dtype)
+
+        def k_tile(masked):
+            def body(j, carry):
+                # Row statistics stay (tile_q, 1), as the refs are: no
+                # relayout between a reduction and the broadcast that uses it.
+                m_prev, l_prev, acc = carry
+                cols = _tile(j, tile_k, static)
+                k = k_ref[0, cols, :]
+                v = v_ref[0, cols, :]
+                s = jax.lax.dot_general(
+                    q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+                )  # (tile_q, tile_k)
+                if masked:
+                    s = jnp.where(keep(i, j), s, NEG_INF)
+                m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+                p = jnp.exp(s - m_new)
+                alpha = jnp.exp(m_prev - m_new)
+                l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
+                acc = acc * alpha + jax.lax.dot_general(
+                    p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                )
+                return m_new, l_new, acc
+
+            return body
+
+        carry = (jnp.full((tile_q, 1), NEG_INF, jnp.float32),
+                 jnp.zeros((tile_q, 1), jnp.float32),
+                 jnp.zeros((tile_q, d), jnp.float32))
+        if causal:
+            diag, end = _diag_and_end(i, tile_q, tile_k, n_k, static)
+            carry = _for(0, diag, k_tile(False), carry, static)
+            carry = _for(diag, end, k_tile(True), carry, static)
+        else:
+            carry = _for(0, n_k, k_tile(False), carry, static)
+        m, l, acc = carry
+        l = jnp.maximum(l, 1e-30)
+        o_ref[0, rows, :] = (acc / l).astype(o_ref.dtype)
+        lse_ref[0, rows, :] = m + jnp.log(l)
+
+    if static:
+        for i in range(n_q):
+            q_tile(i, _tile(i, tile_q, static))
     else:
-        hi = num_k_blocks
-
-    m0 = jnp.full((block_q,), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((block_q,), jnp.float32)
-    acc0 = jnp.zeros((block_q, q.shape[-1]), jnp.float32)
-
-    def make_body(masked):
-        def body(j, carry):
-            m_prev, l_prev, acc = carry
-            k = k_ref[0, pl.ds(j * block_k, block_k), :]
-            v = v_ref[0, pl.ds(j * block_k, block_k), :]
-            s = jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-            )  # (block_q, block_k)
-            if masked:
-                row = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
-                col = j * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-                s = jnp.where(row >= col, s, NEG_INF)
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-            p = jnp.exp(s - m_new[:, None])
-            alpha = jnp.exp(m_prev - m_new)
-            l_new = l_prev * alpha + jnp.sum(p, axis=-1)
-            acc = acc * alpha[:, None] + jax.lax.dot_general(
-                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-            return m_new, l_new, acc
-
-        return body
-
-    if causal:
-        # K blocks strictly below the diagonal need no mask (row >= col always
-        # holds); only blocks intersecting the diagonal pay the iota/where.
-        lo_diag = jax.lax.div(qi * block_q, block_k)  # first block that may mask
-        carry = jax.lax.fori_loop(0, lo_diag, make_body(False), (m0, l0, acc0))
-        m, l, acc = jax.lax.fori_loop(lo_diag, hi, make_body(True), carry)
-    else:
-        m, l, acc = jax.lax.fori_loop(0, hi, make_body(False), (m0, l0, acc0))
-    l = jnp.maximum(l, 1e-30)
-    o_ref[0] = (acc / l[:, None]).astype(o_ref.dtype)
-    lse_ref[0] = (m + jnp.log(l))[:, None]
+        q_tile(pl.program_id(1), slice(None))
 
 
-def _fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret):
+def _specs(seq, plan, tile):
+    """(grid axes after batch*heads, spec of a whole head, spec of the tile a
+    program owns). Unrolled: one program per (batch, head), and what it owns
+    is the whole head too. Loop form: one program per tile of size `tile`."""
+    head = lambda width: pl.BlockSpec((1, seq, width), lambda b, *_: (b, 0, 0))
+    if plan.unrolled:
+        return (), head, head
+    return (seq // tile,), head, lambda width: pl.BlockSpec((1, tile, width), lambda b, t: (b, t, 0))
+
+
+def _compiler_params(interpret, *semantics):
+    # No `vmem_limit_bytes`: a limit on one call makes XLA set that VMEM aside
+    # in every instruction of the program (PERF.md section 7).
+    return None if interpret else pltpu.CompilerParams(dimension_semantics=semantics)
+
+
+def _fwd(q, k, v, causal, sm_scale, plan, interpret):
     bh, seq, d = q.shape
-    grid = (bh, pl.cdiv(seq, block_q))
-    out_shape = [
-        jax.ShapeDtypeStruct((bh, seq, d), q.dtype),
-        # (bh, seq, 1): TPU block specs constrain the last two dims, so the
-        # per-row stats carry a trailing unit dim to stay tileable.
-        jax.ShapeDtypeStruct((bh, seq, 1), jnp.float32),
-    ]
     kernel = functools.partial(
-        _fwd_kernel,
-        sm_scale=sm_scale,
-        causal=causal,
-        block_q=block_q,
-        block_k=block_k,
-        seq_len=seq,
+        _fwd_kernel, sm_scale=sm_scale, causal=causal,
+        tile_q=plan.tile_q, tile_k=plan.tile_k, static=plan.unrolled,
     )
-    o, lse = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, seq, d), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, seq, d), lambda b, i: (b, 0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda b, i: (b, i, 0)),
-        ],
-        out_shape=out_shape,
-        interpret=interpret,
-        name="flash_fwd",
-        compiler_params=None if interpret else pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel"),
-        ),
-        cost_estimate=pl.CostEstimate(
-            flops=4 * seq * seq * d,
-            bytes_accessed=3 * seq * d * q.dtype.itemsize + seq * d * q.dtype.itemsize,
-            transcendentals=seq * seq,
-        ),
-    )(q, k, v)
+    tiles, head, mine = _specs(seq, plan, plan.tile_q)
+    with jax.named_scope(plan.scope):
+        o, lse = pl.pallas_call(
+            kernel,
+            grid=(bh, *tiles),
+            in_specs=[mine(d), head(d), head(d)],
+            # (bh, seq, 1): TPU block specs constrain the last two dims, so the
+            # per-row stats carry a trailing unit dim to stay tileable.
+            out_specs=[mine(d), mine(1)],
+            out_shape=[
+                jax.ShapeDtypeStruct((bh, seq, d), q.dtype),
+                jax.ShapeDtypeStruct((bh, seq, 1), jnp.float32),
+            ],
+            interpret=interpret,
+            name="flash_fwd",
+            compiler_params=_compiler_params(interpret, "parallel", *["parallel"] * len(tiles)),
+            # As XLA has always been told (one head's full square). What it is
+            # told steers its schedule round the call, on four chips visibly,
+            # so it changes in a PR of its own (PERF.md section 7).
+            cost_estimate=pl.CostEstimate(
+                flops=4 * seq * seq * d,
+                bytes_accessed=3 * seq * d * q.dtype.itemsize + seq * d * q.dtype.itemsize,
+                transcendentals=seq * seq,
+            ),
+        )(q, k, v)
     return o, lse
 
 
 # --------------------------------------------------------------------------- backward kernel
-def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                      dq_ref, dk_ref, dv_ref, dq_acc, *,
-                      sm_scale, causal, block_q, block_k, seq_len):
-    """Grid (bh, kj) with kj sequential: per K block, loop Q blocks computing
-    dk/dv directly; dq contributions accumulate in the f32 VMEM scratch
-    (seq, d) that lives across the kj steps of one (b,h) pair."""
-    kj = pl.program_id(1)
-    num_k_blocks = pl.cdiv(seq_len, block_k)
-    k = k_ref[0]  # (block_k, d)
-    v = v_ref[0]
+def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                dq_ref, dk_ref, dv_ref, dq_acc, *,
+                sm_scale, causal, tile_q, tile_k, static):
+    """ONE fused kernel. Per K tile, dk/dv accumulate over the Q tiles on or
+    under the diagonal and each pair adds its dq contribution to the f32 VMEM
+    scratch (seq, d); s/p are recomputed once per tile pair. Unrolled, one
+    program walks the K tiles of a whole (batch, head); in the loop form a
+    program is one K tile (grid axis 1, sequential: the scratch lives across
+    the K tiles of a head) and `k_ref`, `v_ref`, `dk_ref`, `dv_ref` are that tile."""
+    seq = q_ref.shape[1]
+    n_q, n_k = seq // tile_q, seq // tile_k
+    keep = _causal_mask(tile_q, tile_k) if causal else None
 
-    @pl.when(kj == 0)
-    def _zero():
+    def k_tile(j, cols):
+        k = k_ref[0, cols, :]
+        v = v_ref[0, cols, :]
+
+        def q_tile(masked):
+            def body(i, carry):
+                dk, dv = carry
+                rows = _tile(i, tile_q, static)
+                q = q_ref[0, rows, :]
+                do = do_ref[0, rows, :]
+                qs = (q.astype(jnp.float32) * sm_scale).astype(q.dtype)
+                s = jax.lax.dot_general(
+                    qs, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+                )  # (tile_q, tile_k)
+                if masked:
+                    s = jnp.where(keep(i, j), s, NEG_INF)
+                p = jnp.exp(s - lse_ref[0, rows, :])
+                dv = dv + jax.lax.dot_general(
+                    p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                )
+                dp = jax.lax.dot_general(
+                    do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+                )
+                ds = (p * (dp - delta_ref[0, rows, :]) * sm_scale).astype(q.dtype)
+                dk = dk + jax.lax.dot_general(
+                    ds, q, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
+                )
+                dq_acc[rows, :] += jax.lax.dot_general(
+                    ds, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+                )
+                return dk, dv
+
+            return body
+
+        carry = (jnp.zeros(k.shape, jnp.float32), jnp.zeros(v.shape, jnp.float32))
+        if causal:
+            diag, end = _diag_and_end(j, tile_k, tile_q, n_q, static)
+            carry = _for(diag, end, q_tile(True), carry, static)
+            carry = _for(end, n_q, q_tile(False), carry, static)
+        else:
+            carry = _for(0, n_q, q_tile(False), carry, static)
+        dk, dv = carry
+        dk_ref[0, cols, :] = dk.astype(dk_ref.dtype)
+        dv_ref[0, cols, :] = dv.astype(dv_ref.dtype)
+
+    def zero():
         dq_acc[...] = jnp.zeros_like(dq_acc)
 
-    num_q_blocks = pl.cdiv(seq_len, block_q)
-    lo = jax.lax.div(kj * block_k, block_q) if causal else 0
-
-    def make_body(masked):
-        def body(i, carry):
-            dk, dv = carry
-            q = q_ref[0, pl.ds(i * block_q, block_q), :]
-            do = do_ref[0, pl.ds(i * block_q, block_q), :]
-            lse = lse_ref[0, pl.ds(i * block_q, block_q), 0]
-            delta = delta_ref[0, pl.ds(i * block_q, block_q), 0]
-            qs = (q.astype(jnp.float32) * sm_scale).astype(q.dtype)
-            s = jax.lax.dot_general(
-                qs, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-            )  # (block_q, block_k)
-            if masked:
-                row = i * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
-                col = kj * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-                s = jnp.where(row >= col, s, NEG_INF)
-            p = jnp.exp(s - lse[:, None])
-            dv = dv + jax.lax.dot_general(
-                p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-            dp = jax.lax.dot_general(
-                do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-            )
-            ds = (p * (dp - delta[:, None]) * sm_scale).astype(q.dtype)
-            dk = dk + jax.lax.dot_general(
-                ds, q, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-            )
-            sl = pl.ds(i * block_q, block_q)
-            dq_acc[sl, :] = dq_acc[sl, :] + jax.lax.dot_general(
-                ds, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-            )
-            return dk, dv
-
-        return body
-
-    dk0 = jnp.zeros((block_k, k.shape[-1]), jnp.float32)
-    dv0 = jnp.zeros((block_k, v.shape[-1]), jnp.float32)
-    if causal:
-        # Q blocks past the diagonal band see this K block in full (row >= col
-        # for every pair): no mask needed there.
-        hi_diag = jnp.minimum(
-            jax.lax.div((kj + 1) * block_k + block_q - 1, block_q), num_q_blocks
-        )
-        dk, dv = jax.lax.fori_loop(lo, hi_diag, make_body(True), (dk0, dv0))
-        dk, dv = jax.lax.fori_loop(hi_diag, num_q_blocks, make_body(False), (dk, dv))
-    else:
-        dk, dv = jax.lax.fori_loop(lo, num_q_blocks, make_body(False), (dk0, dv0))
-    dk_ref[0] = dk.astype(dk_ref.dtype)
-    dv_ref[0] = dv.astype(dv_ref.dtype)
-
-    @pl.when(kj == num_k_blocks - 1)
-    def _flush_dq():
+    def flush():
         dq_ref[0] = dq_acc[...].astype(dq_ref.dtype)
 
+    if static:
+        zero()
+        for j in range(n_k):
+            k_tile(j, _tile(j, tile_k, static))
+        flush()
+    else:
+        j = pl.program_id(1)
+        pl.when(j == 0)(zero)
+        k_tile(j, slice(None))
+        pl.when(j == n_k - 1)(flush)
 
-def _bwd(causal, sm_scale, block_q, block_k, interpret, res, g):
+
+def _bwd(causal, sm_scale, plan, interpret, res, g):
     q, k, v, o, lse = res
     do = g
     bh, seq, d = q.shape
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)[..., None]  # (bh, seq, 1)
-
-    dq, dk, dv = pl.pallas_call(
-        functools.partial(
-            _bwd_fused_kernel, sm_scale=sm_scale, causal=causal,
-            block_q=block_q, block_k=block_k, seq_len=seq,
-        ),
-        grid=(bh, pl.cdiv(seq, block_k)),
-        in_specs=[
-            pl.BlockSpec((1, seq, d), lambda b, j: (b, 0, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, j: (b, j, 0)),
-            pl.BlockSpec((1, seq, d), lambda b, j: (b, 0, 0)),
-            pl.BlockSpec((1, seq, 1), lambda b, j: (b, 0, 0)),
-            pl.BlockSpec((1, seq, 1), lambda b, j: (b, 0, 0)),
-        ],
-        out_specs=[
-            # dq is revisited every kj step (index map constant in j) and
-            # flushed once per (b,h) when the grid moves on.
-            pl.BlockSpec((1, seq, d), lambda b, j: (b, 0, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, j: (b, j, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((bh, seq, d), q.dtype),
-            jax.ShapeDtypeStruct((bh, seq, d), q.dtype),
-            jax.ShapeDtypeStruct((bh, seq, d), q.dtype),
-        ],
-        scratch_shapes=[pltpu.VMEM((seq, d), jnp.float32)],
-        interpret=interpret,
-        name="flash_bwd",
-        compiler_params=None if interpret else pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
-        ),
-    )(q, k, v, do, lse, delta)
+    tiles, head, mine = _specs(seq, plan, plan.tile_k)
+    with jax.named_scope(plan.scope):
+        dq, dk, dv = pl.pallas_call(
+            functools.partial(
+                _bwd_kernel, sm_scale=sm_scale, causal=causal,
+                tile_q=plan.tile_q, tile_k=plan.tile_k, static=plan.unrolled,
+            ),
+            grid=(bh, *tiles),
+            in_specs=[head(d), mine(d), mine(d), head(d), head(1), head(1)],
+            # In the loop form dq is revisited by every K tile of a head (its
+            # index map ignores the tile) and written back when the grid moves on.
+            out_specs=[head(d), mine(d), mine(d)],
+            out_shape=[jax.ShapeDtypeStruct((bh, seq, d), q.dtype)] * 3,
+            scratch_shapes=[pltpu.VMEM((seq, d), jnp.float32)],
+            interpret=interpret,
+            name="flash_bwd",
+            compiler_params=_compiler_params(interpret, "parallel", *["arbitrary"] * len(tiles)),
+        )(q, k, v, do, lse, delta)
     return dq, dk, dv
 
 
@@ -321,33 +394,83 @@ def blockwise_attention(q, k, v, causal: bool = True, sm_scale: Optional[float] 
 
 
 # --------------------------------------------------------------------------- public entry
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash_bhsd(q, k, v, causal, sm_scale, block_q, block_k, interpret):
-    o, _ = _fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _flash_bhsd(q, k, v, causal, sm_scale, plan, interpret):
+    o, _ = _fwd(q, k, v, causal, sm_scale, plan, interpret)
     return o
 
 
-def _flash_fwd_rule(q, k, v, causal, sm_scale, block_q, block_k, interpret):
-    o, lse = _fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret)
+def _flash_fwd_rule(q, k, v, causal, sm_scale, plan, interpret):
+    o, lse = _fwd(q, k, v, causal, sm_scale, plan, interpret)
     return o, (q, k, v, o, lse)
 
 
-def _flash_bwd_rule(causal, sm_scale, block_q, block_k, interpret, res, g):
-    return _bwd(causal, sm_scale, block_q, block_k, interpret, res, g)
+def _flash_bwd_rule(causal, sm_scale, plan, interpret, res, g):
+    return _bwd(causal, sm_scale, plan, interpret, res, g)
 
 
 _flash_bhsd.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 
 
-def _kernel_blocks(seq: int, block_q: int, block_k: int):
-    """Cap blocks to seq_len, then shrink to a divisor (gcd keeps the largest
-    power-of-two factor) so defaults work for any seq that has one — e.g.
-    S=1536 uses 512-blocks."""
-    return math.gcd(min(block_q, seq), seq), math.gcd(min(block_k, seq), seq)
+class KernelPlan(NamedTuple):
+    """The tile schedule one (batch, head) program of both kernels walks."""
+
+    tile_q: int
+    tile_k: int
+    tiles_visited: int  # tile pairs computed: on or under the diagonal when causal
+    tiles_masked: int  # of those, the ones the diagonal crosses (they pay the mask)
+    tiles_total: int  # the whole square
+    unrolled: bool  # straight-line code with static slices, else fori_loops
+
+    @property
+    def scope(self) -> str:
+        """The `jax.named_scope` round each `pallas_call`, so a kernel event's
+        `op_name` in a trace says which schedule it ran."""
+        return f"tiles_{self.tiles_visited}of{self.tiles_total}"
+
+
+def _kernel_blocks(seq: int, head_dim: int, causal: bool,
+                   block_q: Optional[int] = None, block_k: Optional[int] = None,
+                   itemsize: int = 2) -> KernelPlan:
+    """The one place tile sizes and the form of the schedule are chosen, from
+    what the call observes: `seq`, `head_dim`, `causal`, and `block_q` /
+    `block_k` where the caller passes them. Tiles are capped to seq and shrunk
+    to a divisor (gcd keeps the largest power-of-two factor), so a default
+    works for any seq that has one: S=1536 walks 3 x 3 tiles of 512."""
+
+    def plan(default):
+        tile_q = math.gcd(min(block_q or default, seq), seq)
+        tile_k = math.gcd(min(block_k or default, seq), seq)
+        n_q, n_k = seq // tile_q, seq // tile_k
+        visited = masked = 0
+        for i in range(n_q):
+            diag, end = _diag_and_end(i, tile_q, tile_k, n_k, True) if causal else (n_k, n_k)
+            visited += end
+            masked += end - diag
+        unrolled = (causal and max(tile_q, tile_k) <= CAUSAL_TILE
+                    and visited <= MAX_UNROLLED_TILES
+                    and visited * tile_q * tile_k <= MAX_UNROLLED_SCORES
+                    and seq * head_dim * itemsize <= MAX_UNROLLED_HEAD_BYTES)
+        return KernelPlan(tile_q, tile_k, visited, masked, n_q * n_k, unrolled)
+
+    if causal:
+        small = plan(CAUSAL_TILE)
+        if small.unrolled:
+            return small
+    return plan(FULL_TILE)
+
+
+def kernel_plan(shape, causal: bool = True,
+                block_q: Optional[int] = None, block_k: Optional[int] = None,
+                dtype=jnp.bfloat16) -> KernelPlan:
+    """The schedule the kernels run for q/k/v of `shape` (batch, heads, seq,
+    head_dim): static, so asking costs nothing per step."""
+    _, _, s, d = shape
+    return _kernel_blocks(s, d, causal, block_q, block_k, jnp.dtype(dtype).itemsize)
 
 
 def select_backend(shape, platform: Optional[str] = None,
-                   block_q: int = DEFAULT_BLOCK_Q, block_k: int = DEFAULT_BLOCK_K) -> str:
+                   block_q: Optional[int] = None, block_k: Optional[int] = None) -> str:
     """The implementation `flash_attention(backend=None)` runs for q/k/v of
     `shape` (batch, heads, seq, head_dim): "pallas" | "blockwise" | "xla".
 
@@ -363,7 +486,8 @@ def select_backend(shape, platform: Optional[str] = None,
     _, _, s, d = shape
     if s * d > 8192 * 64:
         return "blockwise"
-    if min(_kernel_blocks(s, block_q, block_k)) < 128:
+    plan = kernel_plan(shape, True, block_q, block_k)
+    if min(plan.tile_q, plan.tile_k) < 128:
         return "xla"
     return "pallas"
 
@@ -374,8 +498,8 @@ def flash_attention(
     v,
     causal: bool = True,
     sm_scale: Optional[float] = None,
-    block_q: int = DEFAULT_BLOCK_Q,
-    block_k: int = DEFAULT_BLOCK_K,
+    block_q: Optional[int] = None,
+    block_k: Optional[int] = None,
     backend: Optional[str] = None,
     interpret: bool = False,
     mesh=None,
@@ -383,6 +507,8 @@ def flash_attention(
     """Multi-head attention, (batch, heads, seq, head_dim) layout.
 
     backend: "pallas" | "xla" | "blockwise" | None (`select_backend`).
+    block_q, block_k: the kernel's tile sizes; None lets `kernel_plan` pick
+      them from the shape.
     mesh: the jax.sharding.Mesh the surrounding jit shards over. XLA cannot
       partition a Mosaic call by itself, so on more than one device the
       kernel runs inside a shard_map with batch over (data, fsdp) and heads
@@ -401,8 +527,8 @@ def flash_attention(
         return xla_attention(q, k, v, causal=causal, sm_scale=sm_scale)
     if backend == "blockwise":
         return blockwise_attention(q, k, v, causal=causal, sm_scale=sm_scale)
-    block_q, block_k = _kernel_blocks(q.shape[2], block_q, block_k)
-    if min(block_q, block_k) < 128:
+    plan = kernel_plan(q.shape, causal, block_q, block_k, q.dtype)
+    if min(plan.tile_q, plan.tile_k) < 128:
         raise ValueError(
             f"flash_attention(backend='pallas'): seq_len {q.shape[2]} has no "
             "block of at least 128 dividing it; the kernel cannot tile it"
@@ -411,7 +537,7 @@ def flash_attention(
     def kernel(q, k, v):
         b, h, s, d = q.shape
         flat = lambda x: x.reshape(b * h, s, d)
-        o = _flash_bhsd(flat(q), flat(k), flat(v), causal, sm_scale, block_q, block_k, interpret)
+        o = _flash_bhsd(flat(q), flat(k), flat(v), causal, sm_scale, plan, interpret)
         return o.reshape(b, h, s, d)
 
     if mesh is not None and mesh.size > 1:

@@ -178,6 +178,8 @@ def test_one_kernel_under_flash_fwd_one_under_flash_bwd_and_all_phases(aot, cell
     fwd, bwd = sorted(aot[cell]["mosaic_scopes"], key=lambda n: "flash_bwd" in n)
     assert "flash_fwd" in fwd.split("/") and phase(fwd) == "forward"
     assert "flash_bwd" in bwd.split("/") and phase(bwd) == "backward"
+    # ... each inside the scope that says which tile schedule it runs.
+    assert "tiles_3of4" in fwd.split("/") and "tiles_3of4" in bwd.split("/")
     assert len(aot[cell]["mosaic_scopes"]) == 2
     assert aot[cell]["phases"] == sorted(PHASES)
 

@@ -5,7 +5,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ray_tpu.ops.flash_attention import flash_attention, xla_attention
+from ray_tpu.ops.flash_attention import flash_attention, kernel_plan, xla_attention
 
 
 @pytest.fixture(scope="module")
@@ -44,6 +44,91 @@ def test_flash_backward_matches_reference(qkv):
     g_ref = jax.grad(f_ref, argnums=(0, 1, 2))(q, k, v)
     for a, b in zip(g_flash, g_ref):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-4)
+
+
+def _kernel_against_xla(seq, head_dim, causal, dtype, **blocks):
+    """Output and all three gradients of the kernel (interpret mode) against
+    `xla_attention`, as the largest error over the largest reference value."""
+    keys = jax.random.split(jax.random.PRNGKey(seq + head_dim), 4)
+    q, k, v, do = (jax.random.normal(kk, (1, 2, seq, head_dim), jnp.float32).astype(dtype)
+                   for kk in keys)
+
+    def grads_of(attn):
+        def f(q, k, v):
+            o = attn(q, k, v)
+            return (o.astype(jnp.float32) * do.astype(jnp.float32)).sum(), o
+        return jax.jit(jax.grad(f, argnums=(0, 1, 2), has_aux=True))
+
+    (dq, dk, dv), o = grads_of(lambda q, k, v: flash_attention(
+        q, k, v, causal=causal, backend="pallas", interpret=True, **blocks))(q, k, v)
+    (rq, rk, rv), ro = grads_of(lambda q, k, v: xla_attention(q, k, v, causal=causal))(q, k, v)
+    errs = {}
+    for name, got, ref in (("o", o, ro), ("dq", dq, rq), ("dk", dk, rk), ("dv", dv, rv)):
+        got, ref = np.asarray(got, np.float32), np.asarray(ref, np.float32)
+        assert np.isfinite(got).all(), name
+        errs[name] = float(np.abs(got - ref).max() / np.abs(ref).max())
+    return errs
+
+
+# f32: summation order only. bf16: two roundings of values up to the largest,
+# the bound chip_smoke.py holds the chip to.
+TOLERANCE = {jnp.float32: 1e-5, jnp.bfloat16: 2.0 ** -6}
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+@pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
+@pytest.mark.parametrize("head_dim", [64, 128])
+@pytest.mark.parametrize("seq", [512, 1024, 1536, 2048])
+def test_kernel_matches_xla_over_many_tiles(seq, head_dim, causal, dtype):
+    """The schedule the shape picks: from 1024 on several tiles, some skipped,
+    some masked, some not (seq 256, the other tests' size, is a single tile)."""
+    errs = _kernel_against_xla(seq, head_dim, causal, dtype)
+    assert max(errs.values()) <= TOLERANCE[dtype], errs
+
+
+@pytest.mark.parametrize("seq,causal,block_q,block_k,unrolled", [
+    (2048, True, 128, 128, False),  # 136 of 256 tiles: too many to unroll, the loop form
+    (1024, False, 128, 128, False),  # 64 of 64, loop form, nothing masked
+    (1024, True, 256, 512, True),  # tiles that are not square: 6 of 8, 4 masked
+    (1024, True, 512, 128, True),
+    (2048, True, 256, 128, False),  # not square in the loop form: 72 of 128
+    (1024, True, 1024, 1024, False),  # one masked tile a head: tiles above 512 keep the loop form
+    (2048, True, 1024, 512, False),  # 6 of 8, a Q tile a program, two K tiles masked in each
+])
+def test_kernel_matches_xla_in_both_forms_of_the_schedule(seq, causal, block_q, block_k, unrolled):
+    plan = kernel_plan((1, 2, seq, 64), causal, block_q, block_k, dtype=jnp.float32)
+    assert (plan.tile_q, plan.tile_k, plan.unrolled) == (block_q, block_k, unrolled)
+    errs = _kernel_against_xla(seq, 64, causal, jnp.float32, block_q=block_q, block_k=block_k)
+    assert max(errs.values()) <= TOLERANCE[jnp.float32], (plan, errs)
+
+
+def test_kernel_plan_counts_the_triangle():
+    # A square causal schedule of n x n tiles visits n(n+1)/2 and masks n.
+    for seq, tile in ((1024, 256), (1024, 128), (2048, 256), (512, 512), (4096, 256)):
+        n = seq // tile
+        plan = kernel_plan((2, 4, seq, 64), True, tile, tile)
+        assert (plan.tiles_visited, plan.tiles_masked, plan.tiles_total) == (n * (n + 1) // 2, n, n * n)
+        full = kernel_plan((2, 4, seq, 64), False, tile, tile)
+        assert (full.tiles_visited, full.tiles_masked, full.tiles_total) == (n * n, 0, n * n)
+    # Not square: tile (i, j) is visited when its first column is not past the
+    # tile's last row, masked when its last column is past the first row.
+    plan = kernel_plan((1, 1, 1024, 64), True, 256, 512)
+    assert (plan.tiles_visited, plan.tiles_masked, plan.tiles_total) == (6, 4, 8)
+    # The plan PERF.md records for both benchmark configurations (8 rows x 16
+    # heads, 4 rows x 25 heads a chip): 512-tiles, 3 of 4, unrolled.
+    for shape in ((8, 16, 1024, 64), (4, 25, 1024, 64)):
+        plan = kernel_plan(shape, True)
+        assert plan == (512, 512, 3, 2, 4, True) and plan.scope == "tiles_3of4"
+    # What the shape decides (ops/flash_attention.py cites the sweep): a
+    # triangle too long to unroll, a head too large to hold whole in VMEM, and
+    # every non-causal call, walk the largest tile in the loop form as before.
+    assert kernel_plan((1, 32, 2048, 128), True) == kernel_plan((1, 8, 2048, 64), True)
+    assert kernel_plan((1, 8, 2048, 64), True) == (512, 512, 10, 4, 16, True)
+    assert kernel_plan((1, 8, 4096, 64), True) == (1024, 1024, 10, 4, 16, False)
+    assert kernel_plan((1, 8, 2048, 128), True, dtype=jnp.float32) == (1024, 1024, 3, 2, 4, False)
+    assert kernel_plan((1, 8, 2048, 128), True, 1024, 1024) == (1024, 1024, 3, 2, 4, False)
+    assert kernel_plan((8, 16, 1024, 64), False) == (1024, 1024, 1, 0, 1, False)
+    assert kernel_plan((1, 1, 1536, 64), True) == (512, 512, 6, 3, 9, True)
 
 
 def test_misaligned_seq_selection_is_visible_not_silent():
