@@ -16,6 +16,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 SOURCES = ("device_trace", "program_span", "program_counter", "host_clock")
+MAX_CELLS = 24
 
 
 class Manifest:
@@ -62,6 +63,38 @@ class Manifest:
         return readers
 
 
+def reduced_problems(entry: Dict[str, Any], config: Dict[str, Any]) -> List[str]:
+    """How a configuration cut to the chip writes the cut down, held to by the
+    contract check and the tests alike. `reduced`, in the entry of
+    `BENCHMARK.json` and in the configuration's file, is the same list of the
+    keys changed from the source, by name. For each, the file holds the value
+    as it is run under the key and the source's under `published`, so that
+    `key: published -> here` is there to read. A cut configuration names its
+    public source, the same in both places, and says in `layout.deployment`
+    what deployment it stands for. Which keys may be cut at all (depth, never
+    a width) is the `model-configs` guide's and the driver's to hold, not
+    guessed here from the key's spelling."""
+    out = []
+    reduced = entry.get("reduced")
+    if not isinstance(reduced, list) or reduced != config.get("reduced"):
+        return [f"`reduced` is {reduced!r} in BENCHMARK.json and "
+                f"{config.get('reduced')!r} in its file"]
+    published = config.get("published", {})
+    for key in reduced:
+        if not isinstance(key, str) or not NAME.match(key):
+            out.append(f"`reduced` names {key!r}: not a key's name")
+        elif key not in config or key not in published or published[key] == config[key]:
+            out.append(f"reduced key {key!r}: the file holds no `{key}` beside a different "
+                       f"`published.{key}`")
+    if reduced:
+        source = entry.get("source", "")
+        if not source.startswith(("http://", "https://")) or config.get("source") != source:
+            out.append("cut, and its `source` is no URL or differs between BENCHMARK.json and its file")
+        if not str(config.get("layout", {}).get("deployment", "")).strip():
+            out.append("cut, and its file has no `layout.deployment` sentence")
+    return out
+
+
 def problems(m: Manifest) -> List[str]:
     """What the contract's checks that need no chip would refuse."""
     d, out = m.data, []
@@ -103,8 +136,10 @@ def problems(m: Manifest) -> List[str]:
     for c in d["configs"]:
         if not NAME.match(c["name"]) or not os.path.isfile(os.path.join(m.root, c["file"])):
             out.append(f"config {c['name']!r}: bad name or missing file {c['file']!r}")
+            continue
         if not any(c["file"].startswith(p + "/") for p in d["paths"]):
             out.append(f"config {c['name']!r}: file outside paths")
+        out += [f"config {c['name']!r}: {what}" for what in reduced_problems(c, m.config(c["name"]))]
     pairs = set()
     for w in d["workloads"]:
         if set(w) != {"name", "config", "traffic", "chips", "why"}:
@@ -128,6 +163,8 @@ def problems(m: Manifest) -> List[str]:
     four = sum(w["chips"] == 4 for w in d["workloads"])
     if four > max(1, len(d["workloads"]) // 4):
         out.append(f"{four} four-chip cells")
+    if not 1 <= len(d["workloads"]) <= MAX_CELLS:
+        out.append(f"{len(d['workloads'])} cells: 1 to {MAX_CELLS}")
     if not 1 <= d["run_seconds"] <= 51 or len(json.dumps(d)) > 64 * 1024:
         out.append("run_seconds or size outside the contract")
     return out

@@ -41,6 +41,8 @@ from benchmark.harness import xplane
 PHASES = ("forward", "recompute", "backward", "optimizer", "other")
 PROGRAM_PREFIX = "ray_tpu."
 FLASH_FWD, FLASH_BWD = "flash_fwd", "flash_bwd"
+FLASH_PREFIX = "flash_"  # every kernel of `ops/flash_attention.py` is named so
+UNNAMED_KERNEL = "closed_call"  # what stands before `pallas_call` where the kernel was given no name
 
 
 # ------------------------------------------------------------------- scopes
@@ -280,6 +282,31 @@ def kernel_ms(trace: xplane.Trace, scopes: Dict[str, str], kernel: str) -> Optio
     return median(runs) / 1e6 if runs and max(runs) > 0 else None
 
 
+def flash_ms(trace: xplane.Trace, scopes: Dict[str, str]) -> Optional[float]:
+    """Median over the traced steps of the device time of the flash kernels:
+    the Mosaic calls whose scope has a component that starts with `flash_`,
+    summed inside each step before the median is taken; where every Mosaic
+    call is one, that is `Trace.mosaic_ms()` to the last bit. A Mosaic kernel
+    of another name (`pl.pallas_call(name=...)`, the component before
+    `pallas_call`) is its own reader's, and where kernels carry names and none
+    is a flash kernel there is nothing to read. Only where no Mosaic call
+    carries a name (a tree before PR 24: `closed_call/pallas_call`) does every
+    one count."""
+    if not trace.devices:
+        return None
+    dev = trace.devices[0]
+    parts = {op[0]: scopes.get(op[0], "").split("/")
+             for op in dev["ops"] if op[2] == xplane.MOSAIC_TARGET}
+    flash = {name for name, path in parts.items()
+             if any(part.startswith(FLASH_PREFIX) for part in path)}
+    if not flash and any(len(path) > 1 and path[-1] == "pallas_call" and path[-2] != UNNAMED_KERNEL
+                         for path in parts.values()):
+        return None  # the program names its kernels, and none of them is a flash kernel
+    runs = trace.per_step(dev, lambda op: op[2] == xplane.MOSAIC_TARGET and (
+        not flash or op[0] in flash))
+    return median(runs) / 1e6 if runs else None
+
+
 def exposed_collectives_ms_by_phase(trace: xplane.Trace,
                                     scopes: Dict[str, str]) -> Dict[str, float]:
     """`collectives.exposed_ms` by the phase each collective's scope puts it
@@ -369,6 +396,15 @@ def raw_trace_path(run: Dict[str, Any]) -> Optional[str]:
     paths = glob.glob(os.path.join(out_dir, "trace", stem + ".rank0", "**", "*.xplane.pb*"),
                       recursive=True)
     return paths[0] if paths else None
+
+
+def flash_ms_of(run: Dict[str, Any]) -> Optional[float]:
+    """What `kernels.flash_ms` and `kernels.flash_roofline` divide by: the
+    run's `flash_ms` by the names in its raw trace. Without the raw trace the
+    Mosaic calls cannot be told apart, and nothing is read (as for
+    `kernels.flash_fwd_ms` and `kernels.flash_bwd_ms`)."""
+    program = of(run)
+    return flash_ms(program.trace, program.scopes) if program else None
 
 
 def of(run: Dict[str, Any]) -> Optional[ProgramTrace]:

@@ -1,7 +1,11 @@
 """The least time the chip could take for one step's attention, the larger of
 FLOPs / peak and bytes / peak HBM bandwidth (both from shapes, by the model
-file; causal: half the square), over the time the kernels took. `bound(run)`
-says which of the two binds."""
+file; causal: half the square), over the time the flash kernels took: the
+`took` of `kernels.flash_ms` (`program_trace.flash_ms`: the Mosaic calls named
+`flash_*`, and no Mosaic kernel of another name). `bound(run)` says which of
+the two binds."""
+
+from benchmark.harness import program_trace
 
 META = {
     "name": "kernels.flash_roofline",
@@ -14,8 +18,7 @@ META = {
 
 
 def read(run):
-    trace = run["device_trace"]
-    took = trace.mosaic_ms() if trace else None
+    took = program_trace.flash_ms_of(run)
     if not took or run["peaks"] is None:
         return None
     return 100.0 * max(_floors(run)) * 1e3 / took

@@ -1,5 +1,8 @@
 """BENCHMARK.json against the contract's checks that need no chip, and the
-proof that the harness is driven by data."""
+proof that the harness is driven by data. The tests that take `manifest_root`
+state the contract and not the contents: each runs on the live manifest and
+on a copy widened by new files and appended entries (`widened_manifest.py`), so what
+a later PR appends passes them without an edit here."""
 
 import json
 import os
@@ -10,11 +13,13 @@ import sys
 import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-sys.path.insert(0, REPO)
+sys.path[:0] = [REPO, os.path.dirname(os.path.abspath(__file__))]
 
 from benchmark.harness import peaks  # noqa: E402
-from benchmark.harness.manifest import Manifest, problems  # noqa: E402
+from benchmark.harness.manifest import Manifest, problems, reduced_problems  # noqa: E402
+from widened_manifest import manifest_root, widened  # noqa: E402,F401  (fixtures)
 
+SEED_CELLS = {"gpt2-medium.resident": 1, "gpt2-medium.fed": 1, "gpt2-xl-fsdp4.fed": 4}  # PR 22's
 CONTRACT_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
 
 
@@ -27,31 +32,40 @@ def run_benchmark(root, *args, env=None, timeout=300):
     return proc, lines[-1] if lines else ""
 
 
-def test_manifest_meets_the_contract():
-    assert problems(Manifest()) == []
+def test_manifest_meets_the_contract(manifest_root):
+    assert problems(Manifest(manifest_root)) == []
 
 
-def test_cells_are_the_three_of_the_issue_and_one_takes_four_chips():
-    cells = {w["name"]: w["chips"] for w in Manifest().data["workloads"]}
-    assert cells == {"gpt2-medium.resident": 1, "gpt2-medium.fed": 1, "gpt2-xl-fsdp4.fed": 4}
+def test_the_seeds_cells_are_there_and_four_chip_cells_keep_to_their_share(manifest_root):
+    cells = {w["name"]: w["chips"] for w in Manifest(manifest_root).data["workloads"]}
+    assert SEED_CELLS.items() <= cells.items()
+    assert sum(chips == 4 for chips in cells.values()) <= max(1, len(cells) // 4)
+    assert len(cells) <= 24
 
 
-def test_every_cell_resolves_to_files_and_reports_both_levels():
-    m = Manifest()
+def test_every_cell_resolves_to_files_and_reports_both_levels(manifest_root):
+    m = Manifest(manifest_root)
     readers = m.layer_readers()
+    entries = {c["name"]: c for c in m.data["configs"]}
     for w in m.data["workloads"]:
-        config, mix = m.config(w["config"]), m.traffic(w["traffic"])
-        assert config["reduced"] == [] and config["layout"]["num_workers"] * config["layout"][
-            "tpus_per_worker"] == w["chips"]
+        config, mix, entry = m.config(w["config"]), m.traffic(w["traffic"]), entries[w["config"]]
+        # A cut is written down the same in both places: each key, what the source has and
+        # what is run here, and for what deployment. The rule is `problems()`'s own.
+        assert reduced_problems(entry, config) == []
+        assert config["layout"]["num_workers"] * config["layout"]["tpus_per_worker"] == w["chips"]
         assert os.path.isfile(os.path.join(m.dir, "loops", mix["loop"] + ".py"))
         assert os.path.isfile(os.path.join(m.dir, "models", config["model"] + ".py"))
-        assert {e["name"] for e in m.metrics_for(w["name"], "end_to_end")} == {
-            "tokens_per_s_per_chip", "setup_s"}
+        reports = {e["name"] for e in m.metrics_for(w["name"], "end_to_end")}
+        assert "setup_s" in reports and len(reports) >= 2
+        if w["name"] in SEED_CELLS:
+            assert reports == {"tokens_per_s_per_chip", "setup_s"}
         for e in m.metrics_for(w["name"], "per_layer"):
             assert callable(readers[e["name"]].read)
+    for name in ("gpt2-medium", "gpt2-xl-fsdp4"):  # full depth and width, by name
+        assert m.config(name)["reduced"] == entries[name]["reduced"] == []
     collective = {e["name"] for e in m.metrics_for("gpt2-xl-fsdp4.fed", "per_layer")} - {
         e["name"] for e in m.metrics_for("gpt2-medium.fed", "per_layer")}
-    assert collective == {"collectives.total_ms", "collectives.exposed_ms"}
+    assert collective >= {"collectives.total_ms", "collectives.exposed_ms"}
 
 
 def test_configs_keep_the_published_widths():
@@ -90,50 +104,31 @@ def test_alone_with_its_manifest_the_benchmark_fails(tmp_path):
     assert proc.returncode != 0 and not (lines and lines[-1].startswith("{"))
 
 
-def test_a_config_a_mix_a_metric_and_a_cell_are_added_as_files_and_appended_entries(tmp_path):
-    """A later PR's whole change to the benchmark: three new files and
-    entries appended to BENCHMARK.json. No file that is there is edited, and
-    the new cell runs and reports the new metric."""
-    shutil.copytree(os.path.join(REPO, "benchmark"), tmp_path / "benchmark",
-                    ignore=shutil.ignore_patterns("out", "__pycache__"))
-    before = {p: open(p, "rb").read() for p in _files(tmp_path / "benchmark")}
-    bench = tmp_path / "benchmark"
-    config = json.load(open(bench / "configs" / "gpt2-nano.json"))
-    config.update(name="throwaway-nano", n_head=4, batch={"global_rows": 4, "seq": 32})
-    json.dump(config, open(bench / "configs" / "throwaway-nano.json", "w"))
-    mix = json.load(open(bench / "traffic" / "fed.json"))
-    mix.update(name="throwaway-short-docs", block_rows=16)
-    mix["documents"].update(median_tokens=20, max_tokens=200)
-    json.dump(mix, open(bench / "traffic" / "throwaway-short-docs.json", "w"))
-    (bench / "layer_metrics" / "throwaway_steps.py").write_text(
-        'META = {"name": "throwaway.steps", "unit": "steps", "better": "higher",\n'
-        '        "source": "program_counter", "layer": "step", "moves": "tokens_per_s_per_chip"}\n\n\n'
-        'def read(run):\n    return run["summary"]["completed"]\n')
-    manifest = json.load(open(os.path.join(REPO, "BENCHMARK.json")))
-    manifest["configs"].append({
-        "name": "throwaway-nano", "source": "none", "file": "benchmark/configs/throwaway-nano.json",
-        "reduced": [], "why": "test"})
-    manifest["workloads"].append({
-        "name": "throwaway-nano.short", "config": "throwaway-nano",
-        "traffic": "throwaway-short-docs", "chips": 1, "why": "test"})
-    manifest["per_layer"].append({
-        "name": "throwaway.steps", "unit": "steps", "better": "higher", "source": "program_counter",
-        "layer": "step", "moves": "tokens_per_s_per_chip", "workloads": ["throwaway-nano.short"]})
-    json.dump(manifest, open(tmp_path / "BENCHMARK.json", "w"))
-
-    assert problems(Manifest(str(tmp_path))) == []
+def test_a_config_a_mix_a_metric_and_a_cell_are_added_as_files_and_appended_entries(widened):
+    """A later PR's whole change to the benchmark: new files, and entries
+    appended to BENCHMARK.json (`widened_manifest.widen`: a model module, a cut
+    configuration, a mix, a cell, its metrics). No file that is there is
+    edited, and the new cell runs, from the copy's own `benchmark/`, and
+    reports the new metrics."""
+    assert problems(Manifest(widened.root)) == []
+    # A checkout holds the program beside the benchmark and the workers import both from
+    # its root: so here, through the copy's link to `ray_tpu`, and no `PYTHONPATH`.
     proc, last = run_benchmark(
-        str(tmp_path), "--workload", "throwaway-nano.short", "--seed", "5", "--seconds", "2",
-        "--trace", "1", "--rehearse-cpu", env={"PYTHONPATH": REPO})
+        widened.root, "--workload", widened.cell, "--seed", "2147483659", "--seconds", "2",
+        "--trace", "1", "--rehearse-cpu", env={"PYTHONPATH": ""})
+    # The worker built its system from the copy's model module, which the repo has not.
     assert proc.returncode == 0, proc.stdout[-3000:] + proc.stderr[-3000:]
     line = json.loads(last)
     assert set(line) == CONTRACT_KEYS | {"breakdown"} and line["correct"] is True
-    assert line["device"]["platform"] == "cpu"
-    assert line["metrics"]["rehearsal.throwaway.steps"]["value"] == line["attempted"] > 0
+    assert line["failed"] == 0 and line["device"]["platform"] == "cpu"
+    steps, kernel = widened.metrics[:2]
+    assert line["metrics"][f"rehearsal.{steps}"]["value"] == line["attempted"] > 0
+    # No Mosaic kernel runs on the CPU: the reader finds nothing and the line leaves it out.
+    assert f"rehearsal.{kernel}" not in line["metrics"]
+    # What the entries that list the fed cells read there, this fed cell reads under its own names.
+    for name in ("data.wait_ms", "host.h2d_ms", "host.report_ms"):
+        assert line["metrics"][f"rehearsal.{name}.{widened.config}"]["value"] > 0
+        assert f"rehearsal.{name}" not in line["metrics"]
     # The metric of the four-chip cell alone is not this cell's.
     assert "rehearsal.collectives.total_ms" not in line["metrics"]
-    assert {p: open(p, "rb").read() for p in before} == before
-
-
-def _files(root):
-    return [os.path.join(d, f) for d, _, fs in os.walk(root) for f in fs]
+    assert {p: open(p, "rb").read() for p in widened.before} == widened.before

@@ -5,8 +5,6 @@ benchmark's own fed loop over a 2-layer, 128-wide GPT, batch 4 x 256, blocks
 of 8 rows, eight traced steps on one TPU v5 lite, with the names in), and on
 PR 22's recorded trace, which has none."""
 
-import gzip
-import json
 import os
 import struct
 import sys
@@ -14,13 +12,14 @@ import sys
 import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-sys.path.insert(0, REPO)
+sys.path[:0] = [REPO, os.path.dirname(os.path.abspath(__file__))]
 
 from benchmark.harness import program_trace as pt  # noqa: E402
 from benchmark.harness import xplane  # noqa: E402
 from benchmark.harness.manifest import Manifest, problems  # noqa: E402
+from widened_manifest import manifest_root, named_run, unnamed_run, widened  # noqa: E402,F401  (fixtures)
 
-TESTDATA = os.path.join(REPO, "benchmark", "testdata")
+TESTDATA = os.path.join(REPO, "benchmark", "testdata")   # `named_run`, `unnamed_run`: widened_manifest.py
 NAMED = os.path.join(TESTDATA, "tiny_gpt_named_v5e.xplane.pb.gz")
 UNNAMED = os.path.join(TESTDATA, "tiny_gpt_3_steps_v5e.xplane.pb.gz")
 NEW_METRICS = {
@@ -29,30 +28,6 @@ NEW_METRICS = {
     "data.fetch_block_ms": ["gpt2-medium.fed"],
     "host.report_put_ms": ["gpt2-medium.fed", "gpt2-xl-fsdp4.fed"],
 }
-
-
-def _as_run(tmp, recorded, stem="cell.7"):
-    """What `driver.result_line` hands a reader, from a recorded trace: the
-    table `WorkerRun.finish` writes, the raw trace where the tracer left it."""
-    raw_dir = tmp / "trace" / (stem + ".rank0") / "plugins" / "profile" / "x"
-    raw_dir.mkdir(parents=True)
-    raw = raw_dir / "host.xplane.pb"
-    with gzip.open(recorded, "rb") as src:
-        raw.write_bytes(src.read())
-    table = xplane.extract(str(raw))
-    (tmp / (stem + ".trace.json")).write_text(json.dumps(table))
-    return {"summary": {"trace_table": str(tmp / (stem + ".trace.json"))},
-            "device_trace": xplane.Trace(table)}
-
-
-@pytest.fixture(scope="module")
-def named_run(tmp_path_factory):
-    return _as_run(tmp_path_factory.mktemp("named"), NAMED)
-
-
-@pytest.fixture(scope="module")
-def unnamed_run(tmp_path_factory):
-    return _as_run(tmp_path_factory.mktemp("unnamed"), UNNAMED, stem="old.3")
 
 
 @pytest.fixture(scope="module")
@@ -275,8 +250,9 @@ def test_a_program_without_names_gives_nothing_and_raises_nothing(unnamed_run, r
     assert readers["step.device_ms"].read(unnamed_run) == pytest.approx(0.146264)
 
 
-def test_the_manifest_gained_eight_entries_at_the_end_and_nothing_else():
-    m = Manifest()
+def test_the_manifests_first_23_entries_are_the_seeds_and_pr_24s_in_their_order(manifest_root):
+    """What PR 22 and PR 24 put there stays where it is; what later PRs append follows."""
+    m = Manifest(manifest_root)
     assert problems(m) == []
     names = [e["name"] for e in m.data["per_layer"]]
     assert names[:15] == [
@@ -284,8 +260,8 @@ def test_the_manifest_gained_eight_entries_at_the_end_and_nothing_else():
         "host.cpu_ms", "host.stall_pct", "step.device_ms", "step.mfu_pct", "kernels.flash_ms",
         "kernels.flash_roofline", "collectives.total_ms", "collectives.exposed_ms", "device.idle_pct",
         "device.step_hbm_gib"]
-    assert names[15:] == list(NEW_METRICS)
-    for entry in m.data["per_layer"][15:]:
+    assert names[15:23] == list(NEW_METRICS) and len(names) == len(set(names)) <= 128
+    for entry in m.data["per_layer"][15:23]:
         assert entry.get("workloads") == NEW_METRICS[entry["name"]]
         assert entry["moves"] == "tokens_per_s_per_chip" and entry["better"] == "lower"
     assert os.path.getsize(NAMED) + os.path.getsize(UNNAMED) < 1 << 20
