@@ -8,6 +8,7 @@ from ray_tpu.models.gpt import (
     train_flops_per_token,
 )
 from ray_tpu.models.llama import LlamaConfig
+from ray_tpu.models.olmoe import OLMoEConfig
 from ray_tpu.models.resnet import ResNetConfig
 from ray_tpu.models.training import (
     TrainState,
@@ -21,6 +22,7 @@ from ray_tpu.models.training import (
 __all__ = [
     "GPTConfig",
     "LlamaConfig",
+    "OLMoEConfig",
     "ResNetConfig",
     "TrainState",
     "create_train_state",

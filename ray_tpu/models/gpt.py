@@ -52,10 +52,10 @@ class GPTConfig:
     # passed to forward()/loss_fn (GPT-2 used 0.1; modern pretraining uses 0).
     dropout: float = 0.0
     # Mixture-of-experts: >0 replaces every block's dense MLP with a Switch
-    # (top-1) MoE of this many experts, sharded over the `expert` mesh axis
-    # (models/moe.py). 0 = dense.
+    # (top-1, dropless) layer of this many SwiGLU experts, sharded over the
+    # `expert` mesh axis (models/moe.py; models/olmoe.py is the top-k model).
+    # 0 = dense.
     moe_experts: int = 0
-    moe_capacity_factor: float = 1.25
     moe_aux_weight: float = 0.01
 
     @property
@@ -96,7 +96,7 @@ def num_params(config: GPTConfig) -> int:
     d, L, V, F = config.d_model, config.n_layer, config.vocab_size, config.ff_dim
     E = config.moe_experts
     if E:
-        mlp = d * E + E * (d * F + F + F * d + d)  # router + per-expert FFNs
+        mlp = d * E + E * 3 * d * F  # router + per-expert SwiGLU (gate, up, down)
     else:
         mlp = d * F + F + F * d + d
     per_layer = (
@@ -249,12 +249,10 @@ def _block(x, layer, config: GPTConfig, attention_fn, drop_rng=None, sub_remat=F
             from ray_tpu.models.moe import moe_mlp
 
             moe = layer["moe"]
-            h, aux = moe_mlp(
-                h,
-                moe["router_w"], moe["fc_w"], moe["fc_b"],
-                moe["proj_w"], moe["proj_b"],
-                capacity_factor=config.moe_capacity_factor,
+            h, moe_aux = moe_mlp(
+                h, moe["router_w"], moe["w_gate"], moe["w_up"], moe["w_down"], k=1
             )
+            aux = moe_aux["load_balance"]
         else:
             h = jnp.einsum("bsd,df->bsf", h, layer["fc_w"].astype(cdt)) + layer["fc_b"].astype(cdt)
             h = jax.nn.gelu(h)

@@ -145,7 +145,7 @@ def param_logical_axes(config: LlamaConfig) -> Dict[str, Any]:
 
 
 # --------------------------------------------------------------------------- forward
-def _rms_norm(x, scale, eps):
+def rms_norm(x, scale, eps):
     xf = x.astype(jnp.float32)
     rms = jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
     return xf * rms * scale
@@ -163,7 +163,7 @@ def rope_tables(seq_len: int, head_dim: int, theta: float):
     return jnp.cos(angles), jnp.sin(angles)
 
 
-def _rope(x, cos, sin):
+def apply_rope(x, cos, sin):
     """Apply rotary embeddings. x: (B, H, S_local, hd); cos/sin: (S_local, hd/2)."""
     half = x.shape[-1] // 2
     x1, x2 = x[..., :half], x[..., half:]
@@ -185,12 +185,12 @@ def _block(x, layer, config: LlamaConfig, attention_fn, cos, sin, sub_remat=Fals
     g = config.group_size
 
     def qkv_part(x, layer):
-        h = _rms_norm(x, layer["attn_norm"], config.norm_eps).astype(cdt)
+        h = rms_norm(x, layer["attn_norm"], config.norm_eps).astype(cdt)
         q = jnp.einsum("bsd,dnh->bnsh", h, layer["wq"].astype(cdt))
         k = jnp.einsum("bsd,dnh->bnsh", h, layer["wk"].astype(cdt))
         v = jnp.einsum("bsd,dnh->bnsh", h, layer["wv"].astype(cdt))
-        q = _rope(q, cos, sin)
-        k = _rope(k, cos, sin)
+        q = apply_rope(q, cos, sin)
+        k = apply_rope(k, cos, sin)
         if g > 1:
             # GQA: each kv head serves `group_size` query heads.
             k = jnp.repeat(k, g, axis=1)
@@ -201,7 +201,7 @@ def _block(x, layer, config: LlamaConfig, attention_fn, cos, sin, sub_remat=Fals
         o = jnp.einsum("bnsh,nhd->bsd", o.astype(cdt), layer["wo"].astype(cdt))
         x = x + o
 
-        h = _rms_norm(x, layer["mlp_norm"], config.norm_eps).astype(cdt)
+        h = rms_norm(x, layer["mlp_norm"], config.norm_eps).astype(cdt)
         gate = jnp.einsum("bsd,df->bsf", h, layer["w_gate"].astype(cdt))
         up = jnp.einsum("bsd,df->bsf", h, layer["w_up"].astype(cdt))
         h = jax.nn.silu(gate) * up
@@ -273,7 +273,7 @@ def forward(
         seq_streams=(cos, sin),
     )
 
-    x = _rms_norm(x, params["final_norm"], config.norm_eps)
+    x = rms_norm(x, params["final_norm"], config.norm_eps)
     logits = jnp.einsum(
         "bsd,vd->bsv",
         x.astype(cdt),

@@ -1,114 +1,168 @@
-"""Mixture-of-Experts MLP with expert parallelism over the `expert` mesh axis.
+"""Mixture-of-experts feed-forward layer: token-choice top-k routing, dropless.
 
-No reference equivalent (SURVEY.md §2: EP "NO") — designed TPU-first in the
-GShard/Switch style: routing is expressed as DENSE one-hot dispatch/combine
-einsums with a static capacity, so the whole layer is three large matmuls the
-MXU loves, and sharding the expert dim over the `expert` axis makes XLA insert
-the token all-to-all automatically (no ragged transfers, no dynamic shapes).
+Every token picks its `k` experts by the router's softmax; the `tokens * k`
+(token, expert) pairs are sorted by expert, the rows are gathered into expert
+order, and the SwiGLU experts run as three grouped matmuls over the ragged
+groups of rows (`ops/grouped_matmul.py`). Results are weighted by the router's
+probabilities and summed back per token. There is no capacity and no dropped
+token, and no tensor with both a token and an expert-slot axis: work and
+memory are linear in tokens (the Switch layer this replaces went through
+dense `(B, S, E, C)` one-hots, three times the experts' own FLOPs at 64
+experts).
 
-Top-1 (Switch) routing with capacity factor: tokens over an expert's capacity
-are dropped to the residual path (standard Switch behavior; static shapes are
-what keeps this jit-compilable). The auxiliary load-balancing loss
-(mean(router_prob) . mean(assignment) * E) pushes the router toward uniform
-expert usage.
+Expert weights carry the `expert` logical axis, so a mesh with an `expert`
+axis shards them; the sorted form is partitioned by XLA from the sharding
+annotations alone (correct on any mesh; an explicit all-to-all for a
+many-chip expert layout is ROADMAP B2's).
 """
 
 from __future__ import annotations
 
+import functools
+import math
 from typing import Any, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
 
+from ray_tpu.ops.grouped_matmul import grouped_matmul
 
-def moe_capacity(num_tokens: int, num_experts: int, capacity_factor: float) -> int:
-    import math
 
-    return max(math.ceil(num_tokens * capacity_factor / num_experts), 1)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _gather_rows(x, order, inverse, k: int):
+    """Row `order[i] // k` of `x` for every i: the tokens in expert order.
+    `order` is a permutation of the `tokens * k` pairs and `inverse` undoes
+    it, so the gradient is a gather too (`_sum_rows`), where the transpose
+    jax would derive is a scatter-add of `tokens * k` rows."""
+    return x[order // k]
+
+
+def _gather_rows_fwd(x, order, inverse, k):
+    return x[order // k], (order, inverse)
+
+
+def _gather_rows_bwd(k, res, g):
+    order, inverse = res
+    return _sum_rows(g, order, inverse, k), None, None
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _sum_rows(rows, order, inverse, k: int):
+    """The transpose of `_gather_rows`: rows back in token order, each
+    token's `k` rows summed (in float32)."""
+    by_token = rows[inverse].reshape(-1, k, rows.shape[-1])
+    return by_token.astype(jnp.float32).sum(axis=1).astype(rows.dtype)
+
+
+def _sum_rows_fwd(rows, order, inverse, k):
+    return _sum_rows(rows, order, inverse, k), (order, inverse)
+
+
+def _sum_rows_bwd(k, res, g):
+    order, inverse = res
+    return _gather_rows(g, order, inverse, k), None, None
+
+
+_gather_rows.defvjp(_gather_rows_fwd, _gather_rows_bwd)
+_sum_rows.defvjp(_sum_rows_fwd, _sum_rows_bwd)
+
+
+def route(x, router_w, k: int, norm_topk_prob: bool = False):
+    """x: (T, D). Returns (weights (T, k) f32, experts (T, k) int32, aux).
+
+    Logits and softmax in float32; the weights of the chosen experts are the
+    softmax's own (renormalised to sum to one only with `norm_topk_prob`).
+    `aux` holds the two auxiliary terms of Muennighoff et al. 2024 and the
+    load they are computed from (what a caller does not use of it, the
+    compiler drops): `load_balance` = E * sum_e f_e * P_e with
+    f_e the tokens routed to expert e over the number of tokens (so the f_e
+    sum to k) and P_e the mean router probability of e; `z` = mean of
+    logsumexp(logits)^2; `tokens_per_expert` (E,) int32."""
+    n_experts = router_w.shape[-1]
+    logits = jnp.einsum("td,de->te", x.astype(jnp.float32), router_w.astype(jnp.float32))
+    probs = jax.nn.softmax(logits, axis=-1)
+    weights, experts = jax.lax.top_k(probs, k)
+    if norm_topk_prob:
+        weights = weights / weights.sum(axis=-1, keepdims=True)
+    counts = jnp.zeros((n_experts,), jnp.int32).at[experts.reshape(-1)].add(1)
+    aux = {
+        "load_balance": n_experts * jnp.sum(
+            counts.astype(jnp.float32) / x.shape[0] * probs.mean(axis=0)),
+        "z": jnp.mean(jax.scipy.special.logsumexp(logits, axis=-1) ** 2),
+        "tokens_per_expert": counts,
+    }
+    return weights, experts, aux
+
+
+def expert_order(experts):
+    """(order, inverse) for `experts` (T, k): `order` lists the `T * k`
+    (token, slot) pairs by expert (stable, so by token inside an expert),
+    `inverse` is where each pair went."""
+    order = jnp.argsort(experts.reshape(-1), stable=True)
+    inverse = jnp.zeros_like(order).at[order].set(jnp.arange(order.shape[0], dtype=order.dtype))
+    return order, inverse
 
 
 def moe_mlp(
     x,  # (B, S, D) activations, config.dtype
-    router_w,  # (D, E) f32
-    fc_w,  # (E, D, F)
-    fc_b,  # (E, F)
-    proj_w,  # (E, F, D)
-    proj_b,  # (E, D)
-    capacity_factor: float = 1.25,
-) -> Tuple[Any, Any]:
-    """Returns (out (B,S,D), aux_loss scalar).
-
-    GShard-style GROUPED routing: each batch row is a routing group with its
-    own per-expert capacity C = ceil(S/E * factor). The dispatch/combine
-    tensors are (B, S, E, C) — linear in tokens (E*C ~ S), not the quadratic
-    (N, E, N/E) a global top-1 would produce — and the capacity cumsum runs
-    per group, so with batch sharded over `data` it never serializes across
-    shards. Expert buffers are (E, B*C, D) with the expert dim sharded over
-    the `expert` axis; XLA inserts the token all-to-alls around the per-expert
-    matmuls."""
+    router_w,  # (D, E)
+    w_gate,  # (E, D, F)
+    w_up,  # (E, D, F)
+    w_down,  # (E, F, D)
+    *,
+    k: int,
+    norm_topk_prob: bool = False,
+) -> Tuple[Any, Dict[str, Any]]:
+    """Returns (out (B, S, D), aux): `out = sum over the token's k experts of
+    p_e * W_down,e (silu(W_gate,e h) * W_up,e h)`, `aux` as `route` gives it
+    plus `experts` (tokens, k), each token's choices, and `rows_processed`,
+    the rows of the sorted form that their own expert takes.
+    The scopes are read from a device trace by the benchmark's `moe.*_ms`."""
     B, S, D = x.shape
-    E = router_w.shape[1]
-    C = moe_capacity(S, E, capacity_factor)
     cdt = x.dtype
-
-    # Router in f32 for stable softmax.
-    logits = jnp.einsum("bsd,de->bse", x.astype(jnp.float32), router_w.astype(jnp.float32))
-    probs = jax.nn.softmax(logits, axis=-1)  # (B, S, E)
-    expert_idx = jnp.argmax(probs, axis=-1)  # (B, S) top-1 (Switch)
-    gate = jnp.take_along_axis(probs, expert_idx[..., None], axis=-1)[..., 0]  # (B, S)
-
-    # Per-group capacity bucketing: token's slot in its expert's queue.
-    onehot = jax.nn.one_hot(expert_idx, E, dtype=jnp.int32)  # (B, S, E)
-    position = jnp.cumsum(onehot, axis=1) * onehot  # 1-based slot within group
-    within_cap = (position > 0) & (position <= C)
-    slot = jnp.sum((position - 1) * onehot, axis=-1)  # (B, S)
-    keep = jnp.any(within_cap, axis=-1)  # (B, S)
-
-    # Dense dispatch/combine (B, S, E, C): linear in tokens.
-    dispatch = (
-        jax.nn.one_hot(expert_idx, E, dtype=cdt)[..., None]
-        * jax.nn.one_hot(slot, C, dtype=cdt)[..., None, :]
-        * keep[..., None, None].astype(cdt)
-    )
-    combine = dispatch * gate.astype(cdt)[..., None, None]
-
-    expert_in = jnp.einsum("bsec,bsd->ebcd", dispatch, x)  # all-to-all under EP
-    expert_in = expert_in.reshape(E, B * C, D)
-    h = jnp.einsum("egd,edf->egf", expert_in, fc_w.astype(cdt)) + fc_b.astype(cdt)[:, None, :]
-    h = jax.nn.gelu(h)
-    h = jnp.einsum("egf,efd->egd", h, proj_w.astype(cdt)) + proj_b.astype(cdt)[:, None, :]
-    h = h.reshape(E, B, C, D)
-    out = jnp.einsum("bsec,ebcd->bsd", combine, h)  # all-to-all back
-
-    # Switch aux loss: E * sum_e mean_tokens(assignment_e) * mean_tokens(prob_e).
-    assign_frac = jnp.mean(onehot.astype(jnp.float32), axis=(0, 1))  # (E,)
-    prob_frac = jnp.mean(probs, axis=(0, 1))  # (E,)
-    aux = E * jnp.sum(assign_frac * prob_frac)
-
-    return out, aux
+    tokens = x.reshape(B * S, D)
+    with jax.named_scope("router"):
+        weights, experts, aux = route(tokens, router_w, k, norm_topk_prob)
+        aux["experts"] = experts
+    with jax.named_scope("dispatch"):
+        order, inverse = expert_order(experts)
+        rows = _gather_rows(tokens, order, inverse, k)  # (T * k, D), expert order
+        sizes = aux["tokens_per_expert"]
+        # A grouped matmul gives row i to the group the running sum of `sizes`
+        # puts it in: a row is processed where that is its own expert.
+        group = jnp.searchsorted(jnp.cumsum(sizes), jnp.arange(order.shape[0]), side="right")
+        aux["rows_processed"] = jnp.sum(group == experts.reshape(-1)[order])
+    with jax.named_scope("experts"):
+        gate = grouped_matmul(rows, w_gate.astype(cdt), sizes)
+        up = grouped_matmul(rows, w_up.astype(cdt), sizes)
+        rows = grouped_matmul(jax.nn.silu(gate) * up, w_down.astype(cdt), sizes)
+    with jax.named_scope("combine"):
+        rows = (rows.astype(jnp.float32) * weights.reshape(-1)[order][:, None]).astype(cdt)
+        out = _sum_rows(rows, order, inverse, k)
+    return out.reshape(B, S, D), aux
 
 
 def init_moe_params(key, n_layer: int, d_model: int, ff_dim: int, n_experts: int, param_dtype):
-    """Stacked per-layer MoE params: router + per-expert FFN weights."""
-    import math
-
-    k1, k2, k3 = jax.random.split(key, 3)
+    """Stacked per-layer MoE params: router + per-expert SwiGLU weights, no biases."""
+    k1, k2, k3, k4 = jax.random.split(key, 4)
     std = 0.02
-    proj_std = std / math.sqrt(2 * n_layer)
+    down_std = std / math.sqrt(2 * n_layer)
+
+    def norm(key, shape, s):
+        return (jax.random.normal(key, shape) * s).astype(param_dtype)
+
     return {
-        "router_w": (jax.random.normal(k1, (n_layer, d_model, n_experts)) * std).astype(param_dtype),
-        "fc_w": (jax.random.normal(k2, (n_layer, n_experts, d_model, ff_dim)) * std).astype(param_dtype),
-        "fc_b": jnp.zeros((n_layer, n_experts, ff_dim), param_dtype),
-        "proj_w": (jax.random.normal(k3, (n_layer, n_experts, ff_dim, d_model)) * proj_std).astype(param_dtype),
-        "proj_b": jnp.zeros((n_layer, n_experts, d_model), param_dtype),
+        "router_w": norm(k1, (n_layer, d_model, n_experts), std),
+        "w_gate": norm(k2, (n_layer, n_experts, d_model, ff_dim), std),
+        "w_up": norm(k3, (n_layer, n_experts, d_model, ff_dim), std),
+        "w_down": norm(k4, (n_layer, n_experts, ff_dim, d_model), down_std),
     }
 
 
 def moe_param_logical_axes() -> Dict[str, Tuple]:
     return {
         "router_w": ("layers", "embed", None),
-        "fc_w": ("layers", "expert", "embed", "mlp"),
-        "fc_b": ("layers", "expert", "mlp"),
-        "proj_w": ("layers", "expert", "mlp", "embed"),
-        "proj_b": ("layers", "expert", "embed"),
+        "w_gate": ("layers", "expert", "embed", "mlp"),
+        "w_up": ("layers", "expert", "embed", "mlp"),
+        "w_down": ("layers", "expert", "mlp", "embed"),
     }

@@ -26,13 +26,10 @@ from ray_tpu.util.tracing import annotate
 def model_for(config):
     """Dispatch a config dataclass to its model module (gpt, llama, resnet,
     ...), so one TrainState/step factory serves the whole zoo."""
-    from ray_tpu.models import llama, resnet
+    from ray_tpu.models import llama, olmoe, resnet
 
-    if isinstance(config, llama.LlamaConfig):
-        return llama
-    if isinstance(config, resnet.ResNetConfig):
-        return resnet
-    return gpt
+    modules = {llama.LlamaConfig: llama, olmoe.OLMoEConfig: olmoe, resnet.ResNetConfig: resnet}
+    return next((m for cls, m in modules.items() if isinstance(config, cls)), gpt)
 
 
 @jax.tree_util.register_dataclass

@@ -63,7 +63,18 @@ MAX_UNROLLED_SCORES = 2048 * 2048
 # for: 2048 x 128 in bf16 compiles for the v5e, larger heads do not. The loop
 # form (a tile a program) compiles every shape it compiled before.
 MAX_UNROLLED_HEAD_BYTES = 2048 * 128 * 2
+# The loop form's backward program holds four whole-head operands and its dq
+# scratch beside its tile: from heads of 4096 x 128 in bf16 on, 1024-tiles no
+# longer fit under the 16 MiB (`(2, 16, 4096, 128)` misses by 308 KB in an
+# ahead-of-time v5e compile, PR 28; `(1, 8, 4096, 128)` fits), 512-tiles do.
+LONG_HEAD_SEQ = 4096
+LONG_HEAD_BYTES = 4096 * 128 * 2
+LONG_HEAD_TILE = 512
 NEG_INF = -1e30
+
+
+def _long_head(seq: int, head_dim: int, itemsize: int) -> bool:
+    return seq >= LONG_HEAD_SEQ and seq * head_dim * itemsize >= LONG_HEAD_BYTES
 
 
 # --------------------------------------------------------------------------- XLA form
@@ -327,6 +338,16 @@ def _bwd(causal, sm_scale, plan, interpret, res, g):
     bh, seq, d = q.shape
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)[..., None]  # (bh, seq, 1)
     tiles, head, mine = _specs(seq, plan, plan.tile_k)
+    whole, stats = head(d), head(1)
+    if not plan.unrolled and _long_head(seq, d, q.dtype.itemsize):
+        # A head's q, do and row statistics change only when the grid moves to
+        # the next head; (seq, 1) in f32 takes a whole lane tile a row in
+        # VMEM, 2 MiB at 4,096. One buffer each instead of two keeps the
+        # program under the 16 MiB whatever XLA fuses into its operands
+        # (two buffers: 16.2-16.8 MB inside a step, PR 28).
+        once = lambda width: pl.BlockSpec((1, seq, width), lambda b, *_: (b, 0, 0),
+                                          pipeline_mode=pl.Buffered(1))
+        whole, stats = once(d), once(1)
     with jax.named_scope(plan.scope):
         dq, dk, dv = pl.pallas_call(
             functools.partial(
@@ -334,7 +355,7 @@ def _bwd(causal, sm_scale, plan, interpret, res, g):
                 tile_q=plan.tile_q, tile_k=plan.tile_k, static=plan.unrolled,
             ),
             grid=(bh, *tiles),
-            in_specs=[head(d), mine(d), mine(d), head(d), head(1), head(1)],
+            in_specs=[whole, mine(d), mine(d), whole, stats, stats],
             # In the loop form dq is revisited by every K tile of a head (its
             # index map ignores the tile) and written back when the grid moves on.
             out_specs=[head(d), mine(d), mine(d)],
@@ -457,7 +478,7 @@ def _kernel_blocks(seq: int, head_dim: int, causal: bool,
         small = plan(CAUSAL_TILE)
         if small.unrolled:
             return small
-    return plan(FULL_TILE)
+    return plan(LONG_HEAD_TILE if _long_head(seq, head_dim, itemsize) else FULL_TILE)
 
 
 def kernel_plan(shape, causal: bool = True,
@@ -478,7 +499,7 @@ def select_backend(shape, platform: Optional[str] = None,
     place it is made, so a caller can say which path its step compiled
     without reading the HLO: off-TPU the XLA form; on TPU the kernel while
     K/V for one (batch, head) fit in VMEM (~2*S*D bytes in bf16: up to ~8k
-    tokens at d=64), the blockwise scan beyond that, and the XLA form for a
+    tokens at d=64, 4k at d=128), the blockwise scan beyond that, and the XLA form for a
     sequence with no block of at least 128 dividing it.
     """
     if (platform or jax.default_backend()) != "tpu":

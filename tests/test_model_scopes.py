@@ -95,6 +95,38 @@ def test_names_change_no_instruction_and_no_byte_of_the_nano_step(monkeypatch):
         assert getattr(a, key) == getattr(b, key), key
 
 
+OLMOE_SCOPES = ("embed", "blocks", "qkv", "attention", "attn_out", "moe", "router", "dispatch",
+                "experts", "combine", "head", "loss", "optimizer")
+
+
+def test_what_the_olmoe_nano_step_names_falls_into_the_phases():
+    """`models/olmoe.py` and `models/moe.py` name their work as `gpt.py` does:
+    of the instructions that carry an `op_name`, under 5 % are in no named
+    phase, and every scope of the block and of the expert layer shows."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models import OLMoEConfig, create_train_state, default_optimizer, make_train_step
+
+    cfg = OLMoEConfig.nano()
+    opt = default_optimizer()
+    state = jax.eval_shape(lambda: create_train_state(cfg, jax.random.PRNGKey(0), opt))
+    batch = {"tokens": jax.ShapeDtypeStruct((2, 65), jnp.int32)}
+    scopes = scope_map(make_train_step(cfg, opt).lower(state, batch).compile().as_text())
+    assert len(scopes) > 1500
+    count = {p: 0 for p in PHASES}
+    for op_name in scopes.values():
+        count[phase(op_name)] += 1
+    assert count["other"] < 0.05 * len(scopes), count
+    assert min(count[p] for p in ("forward", "recompute", "backward", "optimizer")) > 100, count
+    parts = {part for op_name in scopes.values() for part in re.split(r"[/()]", op_name)}
+    assert set(OLMOE_SCOPES) <= parts, set(OLMOE_SCOPES) - parts
+    # Under `save_attn` attention stays out of what runs again; the rest of the block is in it.
+    again = {part for n in scopes.values() if "rematted_computation" in n.split("/")
+             for part in n.split("/")}
+    assert {"qkv", "experts", "router"} <= again and "attention" not in again
+
+
 # ------------------------------------------------- ahead of time, for the v5e
 def _aot_main(cells):
     """In a subprocess of its own (libtpu's start-up stays out of pytest's

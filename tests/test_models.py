@@ -203,7 +203,7 @@ def test_moe_expert_parallel_training(nano):
     mesh = MeshSpec(data=2, expert=4).build()
     opt = default_optimizer(learning_rate=1e-2)
     state = create_train_state(cfg, jax.random.PRNGKey(0), opt, mesh=mesh)
-    assert "expert" in str(state.params["blocks"]["moe"]["fc_w"].sharding.spec)
+    assert "expert" in str(state.params["blocks"]["moe"]["w_gate"].sharding.spec)
     step = make_train_step(cfg, opt, mesh=mesh)
     rng = np.random.default_rng(0)
     first = None
