@@ -9,6 +9,15 @@ Design choices for the MXU/XLA:
    over them (`lax.scan`): compile time is O(1) in depth, and remat
    (`jax.checkpoint`) wraps the scanned block to trade FLOPs for HBM.
  - activations/matmuls in bfloat16, params & softmax/logits in float32.
+ - the attention weights are stored as the matrices they are, in HF's own
+   orientation: `qkv_w` (L, d, 3*nh*hd) with columns q heads | k heads | v heads
+   (`c_attn.weight`), `out_w` (L, nh*hd, d) (`c_proj.weight`); `qkv_b` is
+   (L, 3, nh, hd). A minor dimension that is a multiple of 128 is what the
+   cast, the FSDP all-gather and the optimizer move as dense tiles: on several
+   devices the block asks for the matrix whole and only then views it per head
+   (`_whole_over`); on one it multiplies `qkv_w` as stored. A tree saved with
+   the older (L, d, 3, nh, hd) / (L, nh, hd, d) shapes loads through
+   `stored_form`.
  - attention: pallas flash kernel on TPU (partitioned over the mesh's batch and
    head axes), plain XLA elsewhere, ring attention (context parallelism)
    injectable via `attention_fn`.
@@ -133,9 +142,9 @@ def init_params(config: GPTConfig, key) -> Dict[str, Any]:
     blocks = {
         "ln1_scale": jnp.ones((L, d), pd),
         "ln1_bias": jnp.zeros((L, d), pd),
-        "qkv_w": norm(next(k), (L, d, 3, nh, hd), std),
+        "qkv_w": norm(next(k), (L, d, 3 * nh * hd), std),
         "qkv_b": jnp.zeros((L, 3, nh, hd), pd),
-        "out_w": norm(next(k), (L, nh, hd, d), proj_std),
+        "out_w": norm(next(k), (L, nh * hd, d), proj_std),
         "out_b": jnp.zeros((L, d), pd),
         "ln2_scale": jnp.ones((L, d), pd),
         "ln2_bias": jnp.zeros((L, d), pd),
@@ -170,9 +179,9 @@ def param_logical_axes(config: GPTConfig) -> Dict[str, Any]:
     blocks = {
         "ln1_scale": ("layers", None),
         "ln1_bias": ("layers", None),
-        "qkv_w": ("layers", "embed", None, "heads", None),
+        "qkv_w": ("layers", "embed", None),
         "qkv_b": ("layers", None, "heads", None),
-        "out_w": ("layers", "heads", None, "embed"),
+        "out_w": ("layers", "heads", "embed"),
         "out_b": ("layers", None),
         "ln2_scale": ("layers", None),
         "ln2_bias": ("layers", None),
@@ -199,6 +208,21 @@ def param_logical_axes(config: GPTConfig) -> Dict[str, Any]:
     }
 
 
+def stored_form(tree: Dict[str, Any]) -> Dict[str, Any]:
+    """`tree` (parameters, or anything laid out like them: gradients, AdamW
+    moments) with the attention weights as `init_params` stores them. A tree
+    saved before PR 30 holds `qkv_w` (L, d, 3, nh, hd) and `out_w`
+    (L, nh, hd, d): the same numbers in the same order, so loading is a
+    reshape, decided from the array's rank alone. Anything else passes through."""
+    blocks = dict(tree["blocks"])
+    qkv_w, out_w = blocks["qkv_w"], blocks["out_w"]
+    if qkv_w.ndim == 5:
+        blocks["qkv_w"] = qkv_w.reshape(*qkv_w.shape[:2], -1)
+    if out_w.ndim == 4:
+        blocks["out_w"] = out_w.reshape(out_w.shape[0], -1, out_w.shape[-1])
+    return {**tree, "blocks": blocks}
+
+
 # --------------------------------------------------------------------------- forward
 def _layer_norm(x, scale, bias, eps=1e-5):
     x = x.astype(jnp.float32)
@@ -216,6 +240,27 @@ def _dropout(x, rate: float, rng):
     return jnp.where(keep, x / (1.0 - rate), 0).astype(x.dtype)
 
 
+def _gathers(mesh) -> bool:
+    """Whether a block's weights may have to be gathered: several devices, and
+    not the pipeline's manual region (as `resolve_attention`)."""
+    return mesh is not None and mesh.size > 1 and int(mesh.shape.get("pipeline", 1)) == 1
+
+
+def _whole_over(w, dim: int, mesh):
+    """A block's weight matrix, cast to the compute dtype, with dimension `dim`
+    (the one FSDP shards) whole on every device and the other left to the
+    partitioner. Asked for on the matrix, before the heads are split out of
+    it, this is where XLA puts the all-gather: the tiles that cross ICI are
+    the matrix's own, 128-aligned and dense. Left to itself XLA gathers after
+    the split, with head_dim (64: half of every (8, 128) tile is padding) as
+    the minor dimension (PERF.md, PR 30)."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    spec = [P.UNCONSTRAINED] * w.ndim
+    spec[dim] = None
+    return jax.lax.with_sharding_constraint(w, NamedSharding(mesh, P(*spec)))
+
+
 def _block(x, layer, config: GPTConfig, attention_fn, drop_rng=None, sub_remat=False,
            mesh=None):
     """One transformer block. x: (B, S, D) in config.dtype.
@@ -226,21 +271,33 @@ def _block(x, layer, config: GPTConfig, attention_fn, drop_rng=None, sub_remat=F
     between them is not: its residuals (q/k/v/o and the kernel's lse) are
     saved, so the backward pass never re-runs the attention kernel."""
     cdt = config.dtype
+    nh, hd = config.n_head, config.head_dim
     r1 = r2 = None
     if drop_rng is not None and config.dropout > 0:
         r1, r2 = jax.random.split(drop_rng)
 
     def qkv_part(x, layer):
         h = _layer_norm(x, layer["ln1_scale"], layer["ln1_bias"]).astype(cdt)
-        qkv = jnp.einsum("bsd,dcnh->bscnh", h, layer["qkv_w"].astype(cdt)) + layer[
-            "qkv_b"
-        ].astype(cdt)
+        qkv_w = layer["qkv_w"].astype(cdt)
+        if _gathers(mesh):
+            # The matrix whole, then the product per head: q, k, v cross the
+            # scan in the dense transposed layout XLA picks for this form.
+            qkv_w = _whole_over(qkv_w, 0, mesh).reshape(-1, 3, nh, hd)
+            qkv = jnp.einsum("bsd,dcnh->bscnh", h, qkv_w)
+        else:
+            # Nothing to gather: the matrix as it is stored. Viewed per head
+            # it would be transposed every step (0.14 ms a layer on
+            # gpt2-medium), which the per-head storage had for free.
+            qkv = jnp.einsum("bsd,de->bse", h, qkv_w).reshape(*h.shape[:2], 3, nh, hd)
+        qkv = qkv + layer["qkv_b"].astype(cdt)
         return tuple(jnp.moveaxis(qkv[:, :, i], 2, 1) for i in range(3))  # (B, nh, S, hd)
 
     def out_mlp_part(x, o, layer):
-        o = jnp.einsum(
-            "bnsh,nhd->bsd", o.astype(cdt), layer["out_w"].astype(cdt)
-        ) + layer["out_b"].astype(cdt)
+        out_w = layer["out_w"].astype(cdt)
+        if _gathers(mesh):
+            out_w = _whole_over(out_w, 1, mesh)
+        out_w = out_w.reshape(nh, hd, -1)  # rows are head-major: a view
+        o = jnp.einsum("bnsh,nhd->bsd", o.astype(cdt), out_w) + layer["out_b"].astype(cdt)
         x = x + _dropout(o, config.dropout, r1)
 
         h = _layer_norm(x, layer["ln2_scale"], layer["ln2_bias"]).astype(cdt)

@@ -8,7 +8,9 @@ pytree, and continue training/fine-tuning under any mesh the zoo supports.
 
 Conversion notes:
  - HF Conv1D stores weights (in, out) — already our einsum orientation.
- - c_attn packs q|k|v along the output dim: (d, 3d) -> (d, 3, nh, hd).
+ - c_attn packs q|k|v along the output dim, heads inside each: (d, 3d) is
+   `qkv_w`'s own form, as (d, d) of c_proj is `out_w`'s; only the bias is
+   viewed per head, (3d,) -> (3, nh, hd).
  - per-layer tensors stack on a leading `layers` dim (scan-over-layers).
  - the vocab pads up to a multiple of 128 (MXU tiling); padded embedding
    rows are zero and their logits sit at 0 — harmless for fine-tuning (they
@@ -82,9 +84,9 @@ def load_hf_gpt2(model, **config_overrides) -> Tuple[GPTConfig, Dict[str, Any]]:
     blocks = {
         "ln1_scale": stack("transformer.h.{}.ln_1.weight"),
         "ln1_bias": stack("transformer.h.{}.ln_1.bias"),
-        "qkv_w": stack("transformer.h.{}.attn.c_attn.weight", (d, 3, nh, hd)),
+        "qkv_w": stack("transformer.h.{}.attn.c_attn.weight"),
         "qkv_b": stack("transformer.h.{}.attn.c_attn.bias", (3, nh, hd)),
-        "out_w": stack("transformer.h.{}.attn.c_proj.weight", (nh, hd, d)),
+        "out_w": stack("transformer.h.{}.attn.c_proj.weight"),
         "out_b": stack("transformer.h.{}.attn.c_proj.bias"),
         "ln2_scale": stack("transformer.h.{}.ln_2.weight"),
         "ln2_bias": stack("transformer.h.{}.ln_2.bias"),
