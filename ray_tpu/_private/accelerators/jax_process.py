@@ -22,8 +22,8 @@ _DEFAULT_CACHE_DIR = os.path.join(
 
 def configure_compile_cache() -> str:
     """Point jax's persistent compilation cache at its one place and return
-    it. Called in every process that compiles (train workers, `bench.py
-    --bare`), before the first jit.
+    it. Called in every process that compiles (train workers,
+    `benchmark/harness/worker.py`), before the first jit.
 
     `JAX_COMPILATION_CACHE_DIR`, when set, is read by jax itself and is left
     alone: no directory is set in code. Otherwise the cache lives at the

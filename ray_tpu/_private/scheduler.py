@@ -1673,7 +1673,7 @@ class Scheduler:
                 if wh.tpu_chips:
                     holders.append(wh)
         # shutdown() promises the chips back: the next process to want them
-        # (a second init() in this driver, bench.py's bare subprocess) starts
+        # (a second init() in this driver: chip_smoke.py's warm phase) starts
         # the moment it returns, and a SIGKILLed holder of gigabytes of HBM
         # owns its chip until the kernel has finished tearing it down.
         for wh in holders:

@@ -31,11 +31,14 @@ equivalent user-facing artifact is its GPT-2 release benchmark
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Any, Callable, Dict, Optional
 
 import jax
 import jax.numpy as jnp
+
+from ray_tpu.models.stack import apply_stack, lm_head, lm_loss
 
 
 @dataclasses.dataclass(frozen=True)
@@ -261,20 +264,12 @@ def _whole_over(w, dim: int, mesh):
     return jax.lax.with_sharding_constraint(w, NamedSharding(mesh, P(*spec)))
 
 
-def _block(x, layer, config: GPTConfig, attention_fn, drop_rng=None, sub_remat=False,
-           mesh=None):
-    """One transformer block. x: (B, S, D) in config.dtype.
-    Returns (x, aux) — aux is the MoE load-balance loss (0.0 when dense).
-
-    With sub_remat ("save_attn" policy), the qkv-projection and the
-    outproj/MLP halves are individually remat'ed while the attention call
-    between them is not: its residuals (q/k/v/o and the kernel's lse) are
-    saved, so the backward pass never re-runs the attention kernel."""
+def _parts(config: GPTConfig, mesh):
+    """The two halves of one block on either side of attention, as
+    `stack.apply_stack` takes them. x: (B, S, D) in config.dtype; `out_mlp_part`
+    returns (x, aux), aux the MoE load-balance loss (0.0 when dense)."""
     cdt = config.dtype
     nh, hd = config.n_head, config.head_dim
-    r1 = r2 = None
-    if drop_rng is not None and config.dropout > 0:
-        r1, r2 = jax.random.split(drop_rng)
 
     def qkv_part(x, layer):
         h = _layer_norm(x, layer["ln1_scale"], layer["ln1_bias"]).astype(cdt)
@@ -292,44 +287,33 @@ def _block(x, layer, config: GPTConfig, attention_fn, drop_rng=None, sub_remat=F
         qkv = qkv + layer["qkv_b"].astype(cdt)
         return tuple(jnp.moveaxis(qkv[:, :, i], 2, 1) for i in range(3))  # (B, nh, S, hd)
 
-    def out_mlp_part(x, o, layer):
-        out_w = layer["out_w"].astype(cdt)
-        if _gathers(mesh):
-            out_w = _whole_over(out_w, 1, mesh)
-        out_w = out_w.reshape(nh, hd, -1)  # rows are head-major: a view
-        o = jnp.einsum("bnsh,nhd->bsd", o.astype(cdt), out_w) + layer["out_b"].astype(cdt)
-        x = x + _dropout(o, config.dropout, r1)
+    def out_mlp_part(x, o, layer, rng):
+        with jax.named_scope("out_mlp"):
+            r1, r2 = (None, None) if rng is None else jax.random.split(rng)
+            out_w = layer["out_w"].astype(cdt)
+            if _gathers(mesh):
+                out_w = _whole_over(out_w, 1, mesh)
+            out_w = out_w.reshape(nh, hd, -1)  # rows are head-major: a view
+            o = jnp.einsum("bnsh,nhd->bsd", o.astype(cdt), out_w) + layer["out_b"].astype(cdt)
+            x = x + _dropout(o, config.dropout, r1)
 
-        h = _layer_norm(x, layer["ln2_scale"], layer["ln2_bias"]).astype(cdt)
-        aux = jnp.zeros((), jnp.float32)
-        if config.moe_experts:
-            from ray_tpu.models.moe import moe_mlp
+            h = _layer_norm(x, layer["ln2_scale"], layer["ln2_bias"]).astype(cdt)
+            aux = jnp.zeros((), jnp.float32)
+            if config.moe_experts:
+                from ray_tpu.models.moe import moe_mlp
 
-            moe = layer["moe"]
-            h, moe_aux = moe_mlp(
-                h, moe["router_w"], moe["w_gate"], moe["w_up"], moe["w_down"], k=1
-            )
-            aux = moe_aux["load_balance"]
-        else:
-            h = jnp.einsum("bsd,df->bsf", h, layer["fc_w"].astype(cdt)) + layer["fc_b"].astype(cdt)
-            h = jax.nn.gelu(h)
-            h = jnp.einsum("bsf,fd->bsd", h, layer["proj_w"].astype(cdt)) + layer["proj_b"].astype(cdt)
-        return x + _dropout(h, config.dropout, r2), aux
+                moe = layer["moe"]
+                h, moe_aux = moe_mlp(
+                    h, moe["router_w"], moe["w_gate"], moe["w_up"], moe["w_down"], k=1
+                )
+                aux = moe_aux["load_balance"]
+            else:
+                h = jnp.einsum("bsd,df->bsf", h, layer["fc_w"].astype(cdt)) + layer["fc_b"].astype(cdt)
+                h = jax.nn.gelu(h)
+                h = jnp.einsum("bsf,fd->bsd", h, layer["proj_w"].astype(cdt)) + layer["proj_b"].astype(cdt)
+            return x + _dropout(h, config.dropout, r2), aux
 
-    if sub_remat:
-        qkv_part = jax.checkpoint(qkv_part, prevent_cse=False)
-        out_mlp_part = jax.checkpoint(out_mlp_part, prevent_cse=False)
-
-    from ray_tpu.models.stack import resolve_attention
-
-    # Scope names are read from the compiled program's `op_name`s by whoever
-    # splits a device trace by part of the step (PERF.md, "names").
-    with jax.named_scope("qkv"):
-        q, k, v = qkv_part(x, layer)
-    with jax.named_scope("attention"):
-        o = resolve_attention(q, k, v, config.attention, attention_fn, mesh)  # (B, nh, S, hd)
-    with jax.named_scope("out_mlp"):
-        return out_mlp_part(x, o, layer)
+    return qkv_part, out_mlp_part
 
 
 def forward(
@@ -343,8 +327,9 @@ def forward(
     return_aux: bool = False,
 ):
     """Returns logits (B, S, vocab) in float32 (with `return_aux`, a
-    (logits, moe_aux_loss) pair). Pass dropout_rng to enable dropout
-    (training); omit it for deterministic eval.
+    (logits, aux) pair: the weighted MoE load-balance loss, None when dense).
+    Pass dropout_rng to enable dropout (training); omit it for deterministic
+    eval.
 
     With a mesh whose `pipeline` axis is >1, the layer stack runs as a GPipe
     microbatch pipeline (`parallel.pipeline`): each stage group holds
@@ -355,85 +340,30 @@ def forward(
     cdt = config.dtype
     with jax.named_scope("embed"):
         x = params["wte"].astype(cdt)[tokens] + params["wpe"].astype(cdt)[:S][None]
-    use_dropout = dropout_rng is not None and config.dropout > 0
     layers_rng = None
-    if use_dropout:
+    if dropout_rng is not None and config.dropout > 0:
         emb_rng, layers_rng = jax.random.split(dropout_rng)
         x = _dropout(x, config.dropout, emb_rng)
-
-    remat_policy = (
-        jax.checkpoint_policies.dots_with_no_batch_dims_saveable
-        if config.remat_policy == "dots"
-        else None
-    )
-    save_attn = config.remat and config.remat_policy == "save_attn"
-
-    def make_block_fn(first_layer, attn, mb_idx=None, seq_streams=()):
-        def block_fn(x, xs):
-            layer, idx = xs
-            rng = None
-            if use_dropout:
-                rng = jax.random.fold_in(layers_rng, first_layer + idx)
-                if mb_idx is not None:
-                    # Independent dropout mask per microbatch under PP.
-                    rng = jax.random.fold_in(rng, mb_idx)
-            x, aux = _block(x, layer, config, attn, rng, sub_remat=save_attn, mesh=mesh)
-            return x, aux
-
-        if config.remat and not save_attn:
-            block_fn = jax.checkpoint(block_fn, prevent_cse=False, policy=remat_policy)
-        return block_fn
-
-    from ray_tpu.models.stack import apply_stack
 
     x, moe_aux = apply_stack(
         params["blocks"],
         x,
-        make_block_fn,
-        n_layer=config.n_layer,
+        config,
+        *_parts(config, mesh),
         attention_fn=attention_fn,
         mesh=mesh,
         num_microbatches=num_microbatches,
+        layers_rng=layers_rng,
     )
-
-    with jax.named_scope("head"):
-        x = _layer_norm(x, params["lnf_scale"], params["lnf_bias"])
-        # Tied LM head: bf16 operands on the MXU, f32 accumulation — an f32×f32
-        # matmul here would run at a fraction of MXU rate and this matmul is ~30%
-        # of GPT-2-small's FLOPs.
-        logits = jnp.einsum(
-            "bsd,vd->bsv",
-            x.astype(cdt),
-            params["wte"].astype(cdt),
-            preferred_element_type=jnp.float32,
-        )
+    # Tied LM head.
+    logits = lm_head(
+        x, lambda x: _layer_norm(x, params["lnf_scale"], params["lnf_bias"]), params["wte"], cdt
+    )
     if return_aux:
-        return logits, moe_aux
+        return logits, (config.moe_aux_weight * moe_aux if config.moe_experts else None)
     return logits
 
 
-def loss_fn(
-    params: Dict[str, Any],
-    batch: Dict[str, Any],  # {"tokens": (B, S+1)} or {"inputs","targets"}
-    config: GPTConfig,
-    attention_fn: Optional[Callable] = None,
-    dropout_rng=None,
-    mesh=None,
-    num_microbatches: Optional[int] = None,
-):
-    """Causal LM cross entropy (mean over tokens)."""
-    if "inputs" in batch:
-        inputs, targets = batch["inputs"], batch["targets"]
-    else:
-        tokens = batch["tokens"]
-        inputs, targets = tokens[:, :-1], tokens[:, 1:]
-    logits, moe_aux = forward(
-        params, inputs, config, attention_fn, dropout_rng, mesh, num_microbatches,
-        return_aux=True,
-    )
-    from ray_tpu.models.stack import causal_lm_loss
-
-    loss = causal_lm_loss(logits, targets)
-    if config.moe_experts:
-        loss = loss + config.moe_aux_weight * moe_aux
-    return loss
+# Causal LM cross entropy (mean over tokens), plus the weighted MoE load-balance
+# loss under `moe_experts`: `stack.lm_loss`'s arguments after `forward`.
+loss_fn = functools.partial(lm_loss, forward)

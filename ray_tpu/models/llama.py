@@ -14,11 +14,14 @@ workloads in `release/air_tests/air_benchmarks/` (e.g. Llama fine-tunes).
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Any, Callable, Dict, Optional
 
 import jax
 import jax.numpy as jnp
+
+from ray_tpu.models.stack import apply_stack, lm_head, lm_loss
 
 
 @dataclasses.dataclass(frozen=True)
@@ -172,19 +175,14 @@ def apply_rope(x, cos, sin):
     return jnp.concatenate([rx1, rx2], axis=-1).astype(x.dtype)
 
 
-
-
-def _block(x, layer, config: LlamaConfig, attention_fn, cos, sin, sub_remat=False,
-           mesh=None):
-    """One Llama block. x: (B, S, D). Returns (x, aux=0).
-
-    With sub_remat ("save_attn" policy), the qkv/rope and wo/MLP halves are
-    individually remat'ed while attention between them is not — same policy
-    as gpt._block."""
+def _parts(config: LlamaConfig):
+    """The two halves of one block on either side of attention, as
+    `stack.apply_stack` takes them. x: (B, S, D); cos/sin: this rank's rows
+    of the rotary tables."""
     cdt = config.dtype
     g = config.group_size
 
-    def qkv_part(x, layer):
+    def qkv_part(x, layer, cos, sin):
         h = rms_norm(x, layer["attn_norm"], config.norm_eps).astype(cdt)
         q = jnp.einsum("bsd,dnh->bnsh", h, layer["wq"].astype(cdt))
         k = jnp.einsum("bsd,dnh->bnsh", h, layer["wk"].astype(cdt))
@@ -197,26 +195,20 @@ def _block(x, layer, config: LlamaConfig, attention_fn, cos, sin, sub_remat=Fals
             v = jnp.repeat(v, g, axis=1)
         return q, k, v
 
-    def out_mlp_part(x, o, layer):
-        o = jnp.einsum("bnsh,nhd->bsd", o.astype(cdt), layer["wo"].astype(cdt))
-        x = x + o
+    def out_mlp_part(x, o, layer, rng):
+        del rng  # no dropout
+        with jax.named_scope("out_mlp"):
+            o = jnp.einsum("bnsh,nhd->bsd", o.astype(cdt), layer["wo"].astype(cdt))
+            x = x + o
 
-        h = rms_norm(x, layer["mlp_norm"], config.norm_eps).astype(cdt)
-        gate = jnp.einsum("bsd,df->bsf", h, layer["w_gate"].astype(cdt))
-        up = jnp.einsum("bsd,df->bsf", h, layer["w_up"].astype(cdt))
-        h = jax.nn.silu(gate) * up
-        h = jnp.einsum("bsf,fd->bsd", h, layer["w_down"].astype(cdt))
-        return x + h, jnp.zeros((), jnp.float32)
+            h = rms_norm(x, layer["mlp_norm"], config.norm_eps).astype(cdt)
+            gate = jnp.einsum("bsd,df->bsf", h, layer["w_gate"].astype(cdt))
+            up = jnp.einsum("bsd,df->bsf", h, layer["w_up"].astype(cdt))
+            h = jax.nn.silu(gate) * up
+            h = jnp.einsum("bsf,fd->bsd", h, layer["w_down"].astype(cdt))
+            return x + h, jnp.zeros((), jnp.float32)
 
-    if sub_remat:
-        qkv_part = jax.checkpoint(qkv_part, prevent_cse=False)
-        out_mlp_part = jax.checkpoint(out_mlp_part, prevent_cse=False)
-
-    q, k, v = qkv_part(x, layer)
-    from ray_tpu.models.stack import resolve_attention
-
-    o = resolve_attention(q, k, v, config.attention, attention_fn, mesh)  # (B, nh, S, hd)
-    return out_mlp_part(x, o, layer)
+    return qkv_part, out_mlp_part
 
 
 def forward(
@@ -229,79 +221,30 @@ def forward(
     num_microbatches: Optional[int] = None,
     return_aux: bool = False,
 ):
-    """Logits (B, S, vocab) f32; pipelines over the `pipeline` mesh axis like
-    GPT (shared stack scaffolding)."""
+    """Logits (B, S, vocab) f32 (with `return_aux`, a (logits, None) pair: no
+    auxiliary loss); pipelines over the `pipeline` mesh axis like GPT (shared
+    stack scaffolding)."""
     del dropout_rng
     cdt = config.dtype
-    S = tokens.shape[1]
-    x = params["embed"].astype(cdt)[tokens]
-    cos, sin = rope_tables(S, config.head_dim, config.rope_theta)
-
-    remat_cfg = config.remat
-    policy_name = getattr(config, "remat_policy", None)
-    save_attn = remat_cfg and policy_name == "save_attn"
-    remat_policy = (
-        jax.checkpoint_policies.dots_with_no_batch_dims_saveable
-        if policy_name == "dots"
-        else None
-    )
-
-    def make_block_fn(first_layer, attn, mb_idx=None, seq_streams=()):
-        del first_layer, mb_idx  # no per-layer RNG (no dropout)
-        cos_s, sin_s = seq_streams  # context-sharded slices under PPxCP
-
-        def block_fn(x, xs):
-            layer, _idx = xs
-            return _block(
-                x, layer, config, attn, cos_s, sin_s, sub_remat=save_attn, mesh=mesh
-            )
-
-        if remat_cfg and not save_attn:
-            block_fn = jax.checkpoint(block_fn, prevent_cse=False, policy=remat_policy)
-        return block_fn
-
-    from ray_tpu.models.stack import apply_stack
-
-    x, aux = apply_stack(
+    with jax.named_scope("embed"):
+        x = params["embed"].astype(cdt)[tokens]
+    x, _ = apply_stack(
         params["blocks"],
         x,
-        make_block_fn,
-        n_layer=config.n_layer,
+        config,
+        *_parts(config),
         attention_fn=attention_fn,
         mesh=mesh,
         num_microbatches=num_microbatches,
-        seq_streams=(cos, sin),
+        seq_streams=rope_tables(tokens.shape[1], config.head_dim, config.rope_theta),
     )
-
-    x = rms_norm(x, params["final_norm"], config.norm_eps)
-    logits = jnp.einsum(
-        "bsd,vd->bsv",
-        x.astype(cdt),
-        params["lm_head"].astype(cdt),
-        preferred_element_type=jnp.float32,
+    logits = lm_head(
+        x, lambda x: rms_norm(x, params["final_norm"], config.norm_eps), params["lm_head"], cdt
     )
     if return_aux:
-        return logits, aux
+        return logits, None
     return logits
 
 
-def loss_fn(
-    params: Dict[str, Any],
-    batch: Dict[str, Any],
-    config: LlamaConfig,
-    attention_fn: Optional[Callable] = None,
-    dropout_rng=None,
-    mesh=None,
-    num_microbatches: Optional[int] = None,
-):
-    if "inputs" in batch:
-        inputs, targets = batch["inputs"], batch["targets"]
-    else:
-        tokens = batch["tokens"]
-        inputs, targets = tokens[:, :-1], tokens[:, 1:]
-    logits = forward(
-        params, inputs, config, attention_fn, dropout_rng, mesh, num_microbatches
-    )
-    from ray_tpu.models.stack import causal_lm_loss
-
-    return causal_lm_loss(logits, targets)
+# Causal LM cross entropy (mean over tokens): `stack.lm_loss`'s arguments after `forward`.
+loss_fn = functools.partial(lm_loss, forward)

@@ -4,15 +4,15 @@ experts, with rotary positions, RMSNorm, and q and k RMS-normed over the whole
 projection before the split into heads.
 
 Built from what the zoo has: RMSNorm and the rotary tables are `llama.py`'s,
-the layers run under `stack.apply_stack`, attention goes through
-`stack.resolve_attention`, the loss through `stack.causal_lm_loss`, and the
-expert layer is `moe.moe_mlp`. The load-balancing loss and the router z-loss
+the block's skeleton (remat, attention dispatch, scan, head, loss) is
+`stack.py`'s, and the expert layer is `moe.moe_mlp`. The load-balancing loss and the router z-loss
 of the paper are added to the loss with `aux_loss_weight` and `z_loss_weight`.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Any, Callable, Dict, Optional
 
@@ -21,6 +21,7 @@ import jax.numpy as jnp
 
 from ray_tpu.models.llama import apply_rope, rms_norm, rope_tables
 from ray_tpu.models.moe import init_moe_params, moe_mlp, moe_param_logical_axes
+from ray_tpu.models.stack import apply_stack, block, lm_head, lm_loss
 
 
 @dataclasses.dataclass(frozen=True)
@@ -138,15 +139,16 @@ def param_logical_axes(config: OLMoEConfig) -> Dict[str, Any]:
 
 
 # --------------------------------------------------------------------------- forward
-def _block(x, layer, config: OLMoEConfig, attention_fn, cos, sin, sub_remat=False, mesh=None):
-    """One OLMoE block. x: (B, S, D). Returns (x, aux): aux is what `route`
-    reports for this layer. The scope names are read from the compiled
-    program's `op_name`s (PERF.md, "names")."""
+def _parts(config: OLMoEConfig):
+    """The two halves of one block on either side of attention. x: (B, S, D);
+    cos/sin: this rank's rows of the rotary tables. `out_moe_part` returns
+    (x, aux), aux what `route` reports for this layer. The scope names are
+    read from the compiled program's `op_name`s (PERF.md, "names")."""
     cdt = config.dtype
-    B, S, _ = x.shape
     nh, nkv, hd = config.n_head, config.n_kv_head, config.head_dim
 
-    def qkv_part(x, layer):
+    def qkv_part(x, layer, cos, sin):
+        B, S, _ = x.shape
         h = rms_norm(x, layer["attn_norm"], config.norm_eps).astype(cdt)
         q = jnp.einsum("bsd,dnh->bsnh", h, layer["wq"].astype(cdt)).reshape(B, S, nh * hd)
         k = jnp.einsum("bsd,dnh->bsnh", h, layer["wk"].astype(cdt)).reshape(B, S, nkv * hd)
@@ -161,7 +163,8 @@ def _block(x, layer, config: OLMoEConfig, attention_fn, cos, sin, sub_remat=Fals
             v = jnp.repeat(v, config.group_size, axis=1)
         return q, k, v
 
-    def out_moe_part(x, o, layer):
+    def out_moe_part(x, o, layer, rng):
+        del rng  # no dropout
         with jax.named_scope("attn_out"):
             x = x + jnp.einsum("bnsh,nhd->bsd", o.astype(cdt), layer["wo"].astype(cdt))
         with jax.named_scope("moe"):
@@ -173,17 +176,7 @@ def _block(x, layer, config: OLMoEConfig, attention_fn, cos, sin, sub_remat=Fals
             )
             return x + h, aux
 
-    if sub_remat:
-        qkv_part = jax.checkpoint(qkv_part, prevent_cse=False)
-        out_moe_part = jax.checkpoint(out_moe_part, prevent_cse=False)
-
-    from ray_tpu.models.stack import resolve_attention
-
-    with jax.named_scope("qkv"):
-        q, k, v = qkv_part(x, layer)
-    with jax.named_scope("attention"):
-        o = resolve_attention(q, k, v, config.attention, attention_fn, mesh)  # (B, nh, S, hd)
-    return out_moe_part(x, o, layer)
+    return qkv_part, out_moe_part
 
 
 def _aux_loss(aux, config: OLMoEConfig):
@@ -206,74 +199,34 @@ def forward(
     cdt = config.dtype
     with jax.named_scope("embed"):
         x = params["embed"].astype(cdt)[tokens]
-    cos, sin = rope_tables(tokens.shape[1], config.head_dim, config.rope_theta)
-    save_attn = config.remat and config.remat_policy == "save_attn"
-    remat_policy = (
-        jax.checkpoint_policies.dots_with_no_batch_dims_saveable
-        if config.remat_policy == "dots"
-        else None
-    )
+    qkv_part, out_moe_part = _parts(config)
 
-    def make_block_fn(first_layer, attn, mb_idx=None, seq_streams=()):
-        del first_layer, mb_idx  # no per-layer RNG (no dropout)
-        cos_s, sin_s = seq_streams  # context-sharded slices under PPxCP
-
-        def block_fn(x, xs):
-            layer, _idx = xs
-            x, aux = _block(x, layer, config, attn, cos_s, sin_s, sub_remat=save_attn, mesh=mesh)
-            return x, _aux_loss(aux, config)
-
-        if config.remat and not save_attn:
-            block_fn = jax.checkpoint(block_fn, prevent_cse=False, policy=remat_policy)
-        return block_fn
-
-    from ray_tpu.models.stack import apply_stack
+    def out_part(x, o, layer, rng):
+        x, aux = out_moe_part(x, o, layer, rng)
+        return x, _aux_loss(aux, config)
 
     x, aux = apply_stack(
         params["blocks"],
         x,
-        make_block_fn,
-        n_layer=config.n_layer,
+        config,
+        qkv_part,
+        out_part,
         attention_fn=attention_fn,
         mesh=mesh,
         num_microbatches=num_microbatches,
-        seq_streams=(cos, sin),
+        seq_streams=rope_tables(tokens.shape[1], config.head_dim, config.rope_theta),
     )
-    with jax.named_scope("head"):
-        x = rms_norm(x, params["final_norm"], config.norm_eps)
-        logits = jnp.einsum(
-            "bsd,vd->bsv",
-            x.astype(cdt),
-            params["lm_head"].astype(cdt),
-            preferred_element_type=jnp.float32,
-        )
+    logits = lm_head(
+        x, lambda x: rms_norm(x, params["final_norm"], config.norm_eps), params["lm_head"], cdt
+    )
     if return_aux:
         return logits, aux
     return logits
 
 
-def loss_fn(
-    params: Dict[str, Any],
-    batch: Dict[str, Any],  # {"tokens": (B, S+1)} or {"inputs","targets"}
-    config: OLMoEConfig,
-    attention_fn: Optional[Callable] = None,
-    dropout_rng=None,
-    mesh=None,
-    num_microbatches: Optional[int] = None,
-):
-    """Mean next-token cross entropy plus the two auxiliary losses of every layer."""
-    if "inputs" in batch:
-        inputs, targets = batch["inputs"], batch["targets"]
-    else:
-        tokens = batch["tokens"]
-        inputs, targets = tokens[:, :-1], tokens[:, 1:]
-    logits, aux = forward(
-        params, inputs, config, attention_fn, dropout_rng, mesh, num_microbatches,
-        return_aux=True,
-    )
-    from ray_tpu.models.stack import causal_lm_loss
-
-    return causal_lm_loss(logits, targets) + aux
+# Mean next-token cross entropy plus the two auxiliary losses of every layer:
+# `stack.lm_loss`'s arguments after `forward`.
+loss_fn = functools.partial(lm_loss, forward)
 
 
 def routing_stats(params: Dict[str, Any], tokens, config: OLMoEConfig) -> Dict[str, Any]:
@@ -285,11 +238,12 @@ def routing_stats(params: Dict[str, Any], tokens, config: OLMoEConfig) -> Dict[s
     (`moe_mlp`'s count). The layer is dropless, so `dropped` is 0; it is
     counted, not assumed."""
     x = params["embed"].astype(config.dtype)[tokens]
-    cos, sin = rope_tables(tokens.shape[1], config.head_dim, config.rope_theta)
+    streams = rope_tables(tokens.shape[1], config.head_dim, config.rope_theta)
     pairs = tokens.size * config.experts_per_token
+    parts = _parts(config)
 
     def layer_stats(x, layer):
-        x, aux = _block(x, layer, config, None, cos, sin)
+        x, aux = block(x, layer, config, *parts, streams=streams)
         counts = aux["tokens_per_expert"]
         return x, {
             "experts": aux["experts"],

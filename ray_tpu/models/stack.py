@@ -1,39 +1,89 @@
-"""Shared transformer-stack scaffolding: scan-over-layers with remat, and the
-pipeline-parallel path — one implementation for every model family (GPT,
-Llama, ...), so parallelism semantics cannot drift between models.
+"""The transformer block's skeleton, once for every model family (GPT, Llama,
+OLMoE, ...), so neither parallelism semantics nor the names the benchmark
+reads can drift between models.
 
-A model supplies `block_fn(x, (layer_params, idx)) -> (x, aux)`; this module
-handles: lax.scan over stacked layer params, jax.checkpoint remat, and — when
-the mesh has pipeline > 1 — the GPipe microbatch schedule with optional
-in-region ring attention (parallel/pipeline.py).
+A model supplies its two halves of a block as pure functions,
+`qkv_part(x, layer, *streams) -> (q, k, v)` in (B, nh, S, hd) and
+`out_part(x, o, layer, rng) -> (x, aux)`, and this module does the rest:
+the remat decision (`config.remat`, `config.remat_policy`), the attention
+dispatch between the halves, the scopes `blocks`, `qkv`, `attention` and
+`head`, the per-layer dropout key, lax.scan over stacked layer params and,
+when the mesh has pipeline > 1, the GPipe microbatch schedule with optional
+in-region ring attention (parallel/pipeline.py); then the head's product and
+the causal LM loss.
 """
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Callable, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
 
+def block(x, layer, config, qkv_part: Callable, out_part: Callable,
+          attention_fn: Optional[Callable] = None, mesh=None, streams: tuple = (), rng=None):
+    """One block on x (B, S, D): `out_part`'s (x, aux), under the remat the
+    config asks for. `remat_policy` None recomputes everything in the block;
+    "dots" saves matmul outputs across the remat boundary (less recompute,
+    more memory); under "save_attn" the two parts are remat'ed each on its own
+    while the attention call between them is not: its residuals (q/k/v/o and
+    the kernel's lse) are saved, so the backward pass never re-runs the
+    attention kernel, the most expensive op per byte saved.
+
+    Scope names are read from the compiled program's `op_name`s by whoever
+    splits a device trace by part of the step (PERF.md, "names")."""
+    save_attn = config.remat and config.remat_policy == "save_attn"
+    if save_attn:
+        qkv_part = jax.checkpoint(qkv_part, prevent_cse=False)
+        out_part = jax.checkpoint(out_part, prevent_cse=False)
+
+    def parts(x, layer, streams, rng):
+        with jax.named_scope("qkv"):
+            q, k, v = qkv_part(x, layer, *streams)
+        with jax.named_scope("attention"):
+            o = resolve_attention(q, k, v, config.attention, attention_fn, mesh)  # (B, nh, S, hd)
+        return out_part(x, o, layer, rng)
+
+    if config.remat and not save_attn:
+        dots = jax.checkpoint_policies.dots_with_no_batch_dims_saveable
+        parts = jax.checkpoint(parts, prevent_cse=False,
+                               policy=dots if config.remat_policy == "dots" else None)
+    return parts(x, layer, streams, rng)
+
+
 def apply_stack(
-    blocks,  # stacked per-layer params, leading dim n_layer
+    blocks,  # stacked per-layer params, leading dim config.n_layer
     x,  # (B, S, D)
-    make_block_fn: Callable,  # (first_layer, attention_fn, mb_idx, seq_streams) -> block_fn
+    config,  # of any model: n_layer, remat, remat_policy, attention
+    qkv_part: Callable,
+    out_part: Callable,
     *,
-    n_layer: int,
     attention_fn: Optional[Callable],
     mesh=None,
     num_microbatches: Optional[int] = None,
     seq_streams: tuple = (),
+    layers_rng=None,
 ) -> Tuple[Any, Any]:
-    """Returns (activations, aux_sum). `make_block_fn` mirrors the model's
-    per-block computation (dropout RNG handling included) and must already
-    wrap remat if the config asks for it. `seq_streams` are per-position
-    arrays (leading dim S, e.g. RoPE cos/sin tables) that shard with the
-    sequence under context parallelism — inside the pipeline's manual region
-    each rank receives its own slice, so global positions stay correct."""
-    B = x.shape[0]
+    """Returns (activations, aux_sum): `block` over every layer, `out_part`'s
+    scalar aux summed. `seq_streams` are per-position arrays (leading dim S,
+    e.g. RoPE cos/sin tables) handed to `qkv_part`; they shard with the
+    sequence under context parallelism: inside the pipeline's manual region
+    each rank receives its own slice, so global positions stay correct. With
+    `layers_rng` (a model with dropout, training), `out_part` gets a key of
+    its own for every layer, and under the pipeline for every microbatch."""
+    def block_fn(first_layer, attn, mb_idx, streams, x, xs):
+        """The scan's body over (layer_params, idx), once the first four are bound."""
+        layer, idx = xs
+        rng = None
+        if layers_rng is not None:
+            rng = jax.random.fold_in(layers_rng, first_layer + idx)
+            if mb_idx is not None:
+                # Independent dropout mask per microbatch under PP.
+                rng = jax.random.fold_in(rng, mb_idx)
+        return block(x, layer, config, qkv_part, out_part, attn, mesh, streams, rng)
+
     n_pipeline = int(mesh.shape.get("pipeline", 1)) if mesh is not None else 1
     if n_pipeline > 1:
         from ray_tpu.parallel.pipeline import pipeline_apply, to_stages
@@ -45,21 +95,20 @@ def apply_stack(
         context_manual = n_context > 1
         inner_attn = attention_fn
         if context_manual:
-            import functools
-
             from ray_tpu.parallel.ring_attention import ring_attention
 
             inner_attn = functools.partial(ring_attention, axis_name="context")
 
         def stack_fn(stage_local, xm, first_layer, mb_idx, streams):
-            n_local = n_layer // n_pipeline
+            n_local = config.n_layer // n_pipeline
             xm, auxs = jax.lax.scan(
-                make_block_fn(first_layer, inner_attn, mb_idx, streams),
+                functools.partial(block_fn, first_layer, inner_attn, mb_idx, streams),
                 xm,
                 (stage_local, jnp.arange(n_local)),
             )
             return xm, jnp.sum(auxs)
 
+        B = x.shape[0]
         M = num_microbatches or (2 * n_pipeline if B % (2 * n_pipeline) == 0 else n_pipeline)
         with jax.named_scope("blocks"):
             return pipeline_apply(
@@ -69,9 +118,9 @@ def apply_stack(
             )
     with jax.named_scope("blocks"):
         x, auxs = jax.lax.scan(
-            make_block_fn(0, attention_fn, None, seq_streams),
+            functools.partial(block_fn, 0, attention_fn, None, seq_streams),
             x,
-            (blocks, jnp.arange(n_layer)),
+            (blocks, jnp.arange(config.n_layer)),
         )
         return x, jnp.sum(auxs)
 
@@ -96,6 +145,21 @@ def resolve_attention(q, k, v, attention_mode: str, attention_fn: Optional[Calla
     return flash_attention(q, k, v, causal=True, mesh=mesh)
 
 
+def lm_head(x, norm: Callable, table, dtype):
+    """Logits (B, S, V) in float32 of the final activations x (B, S, D) against
+    `table` (V, D), the tied embedding or a head of its own, after the model's
+    final `norm`: bf16 operands on the MXU, f32 accumulation. An f32 x f32
+    matmul here would run at a fraction of MXU rate, and this matmul is ~30%
+    of GPT-2-small's FLOPs."""
+    with jax.named_scope("head"):
+        return jnp.einsum(
+            "bsd,vd->bsv",
+            norm(x).astype(dtype),
+            table.astype(dtype),
+            preferred_element_type=jnp.float32,
+        )
+
+
 def causal_lm_loss(logits, targets):
     """Fused cross entropy: logsumexp - logit[target], one reduction over V
     instead of materializing the (B, S, V) log-softmax (saves ~2x V-sized HBM
@@ -104,3 +168,22 @@ def causal_lm_loss(logits, targets):
         lse = jax.scipy.special.logsumexp(logits, axis=-1)
         at_target = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
         return (lse - at_target).mean()
+
+
+def lm_loss(forward: Callable, params, batch, config, attention_fn=None, dropout_rng=None,
+            mesh=None, num_microbatches=None):
+    """Causal LM cross entropy (mean over tokens) of a model's `forward` on
+    `batch`, {"tokens": (B, S+1)} or {"inputs", "targets"}, plus the auxiliary
+    loss `forward(..., return_aux=True)` returns beside the logits: a scalar
+    the model has already weighted, or None where it has none."""
+    if "inputs" in batch:
+        inputs, targets = batch["inputs"], batch["targets"]
+    else:
+        tokens = batch["tokens"]
+        inputs, targets = tokens[:, :-1], tokens[:, 1:]
+    logits, aux = forward(
+        params, inputs, config, attention_fn, dropout_rng, mesh, num_microbatches,
+        return_aux=True,
+    )
+    loss = causal_lm_loss(logits, targets)
+    return loss if aux is None else loss + aux

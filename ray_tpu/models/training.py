@@ -13,23 +13,28 @@ dispatches (the reference's "Ray adds ~0% overhead over DDP" property).
 from __future__ import annotations
 
 import dataclasses
+import sys
 from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from ray_tpu.parallel import ShardingRules, batch_spec
-from ray_tpu.models import gpt
 from ray_tpu.util.tracing import annotate
 
 
 def model_for(config):
-    """Dispatch a config dataclass to its model module (gpt, llama, resnet,
-    ...), so one TrainState/step factory serves the whole zoo."""
-    from ray_tpu.models import llama, olmoe, resnet
-
-    modules = {llama.LlamaConfig: llama, olmoe.OLMoEConfig: olmoe, resnet.ResNetConfig: resnet}
-    return next((m for cls, m in modules.items() if isinstance(config, cls)), gpt)
+    """A configuration's model: the module that defines its class (gpt, llama,
+    olmoe, resnet, ... or a user's own) and, beside it, what one
+    TrainState/step factory needs of any model."""
+    module = sys.modules[type(config).__module__]
+    missing = [name for name in ("init_params", "param_logical_axes", "loss_fn")
+               if not callable(getattr(module, name, None))]
+    if missing:
+        raise TypeError(
+            f"{type(config).__qualname__} is no model's configuration: "
+            f"its module {module.__name__} defines no {', '.join(missing)}")
+    return module
 
 
 @jax.tree_util.register_dataclass

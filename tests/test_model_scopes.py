@@ -1,12 +1,13 @@
 """The train step names its own work, and the names cost nothing.
 
-`jax.named_scope`s in `models/gpt.py`, `stack.py`, `training.py` and `name=`
-on the two `pl.pallas_call`s end up in every instruction's `op_name` in the
-compiled program. Here: the CPU compile of the nano step puts what carries a
-name into the four named phases, and the ahead-of-time `v5e:2x2` compile of
-the benchmark's two configurations is the pinned program: same instruction
-count, same `memory_analysis()`, two Mosaic calls, and every block weight
-gathered over ICI in dense tiles.
+`jax.named_scope`s in `models/stack.py` (for every model: `blocks`, `qkv`,
+`attention`, `head`, `loss`), in each model's own parts, in `training.py`, and
+`name=` on the `pl.pallas_call`s end up in every instruction's `op_name` in the
+compiled program. Here: the CPU compile of each model's nano step puts what
+carries a name into the four named phases, and the ahead-of-time `v5e:2x2`
+compile of the benchmark's three configurations is the pinned program: same
+instruction count, same `memory_analysis()`, the same Mosaic calls, and every
+block weight gathered over ICI in dense tiles.
 
 What reads the names is `benchmark/harness/program_trace.py`; its `phase`
 rules are used here, so the model's names and their reader cannot drift.
@@ -26,7 +27,14 @@ sys.path.insert(0, REPO)
 
 from benchmark.harness.program_trace import PHASES, phase, scope_map  # noqa: E402
 
-SCOPES = ("embed", "blocks", "qkv", "attention", "out_mlp", "head", "loss", "optimizer", "grad_norm")
+# Every model's step carries the names `stack.py` and `training.py` open, and beside them those of
+# its own parts. HALVES are the parts of a block on either side of `attention`, with what they hold.
+SHARED = ("embed", "blocks", "qkv", "attention", "head", "loss", "optimizer")
+OWN = {"gpt": ("out_mlp",), "llama": ("out_mlp",),
+       "olmoe": ("attn_out", "moe", "router", "dispatch", "experts", "combine")}
+HALVES = {"gpt": {"qkv", "out_mlp"}, "llama": {"qkv", "out_mlp"},
+          "olmoe": {"qkv", "attn_out", "moe", "router", "experts"}}
+SCOPES = SHARED + OWN["gpt"] + ("grad_norm",)
 # This tree's programs (ahead-of-time compile for v5e:2x2 on this installation,
 # pinned at PR 30, which stored the attention weights as matrices): instructions
 # of the compiled text and `memory_analysis()`. A PR that means to change
@@ -38,6 +46,17 @@ PARENT = {
                     "output": 4259343360, "alias": 4259341312},
     "gpt2-xl-fsdp4": {"instructions": 3710, "argument": 4714580992, "temp": 9007949312,
                       "output": 4714564608, "alias": 4714562560},
+    # Pinned at PR 31 from its parent's tree, before the block moved into `stack.py`.
+    "olmoe-1b-7b-l1": {"instructions": 5329, "argument": 7507437568, "temp": 4008547328,
+                       "output": 7507405824, "alias": 7507403776},
+}
+# What each cell's step hands to Mosaic: the tile schedule its two flash kernels run under
+# (head_dim 64 at 1,024 positions; head_dim 128 at 4,096), and the grouped-matmul kernels
+# beside them (three products, each forward and for both gradients).
+KERNELS = {
+    "gpt2-medium": {"tiles": "tiles_3of4", "gmm": {}},
+    "gpt2-xl-fsdp4": {"tiles": "tiles_3of4", "gmm": {}},
+    "olmoe-1b-7b-l1": {"tiles": "tiles_36of64", "gmm": {"gmm_fwd": 3, "gmm_dlhs": 3, "gmm_drhs": 3}},
 }
 # Temporaries of the steps before PR 30. gpt2-xl-fsdp4 must stay under its own
 # (a cold run peaks 219 MiB from the chip's limit: PERF.md section 7); the
@@ -66,28 +85,33 @@ def block_weight_gathers(text, scopes):
     return minor, copies
 
 
-def _nano_step(remat_policy):
+def _nano_step(model, remat_policy):
     import jax
     import jax.numpy as jnp
 
-    from ray_tpu.models import GPTConfig, create_train_state, default_optimizer, make_train_step
+    from ray_tpu import models
 
-    cfg = GPTConfig.nano(remat=remat_policy != "off",
-                         remat_policy=None if remat_policy == "off" else remat_policy)
-    opt = default_optimizer()
-    state = jax.eval_shape(lambda: create_train_state(cfg, jax.random.PRNGKey(0), opt))
+    config = {"gpt": models.GPTConfig, "llama": models.LlamaConfig, "olmoe": models.OLMoEConfig}
+    cfg = config[model].nano(remat=remat_policy != "off",
+                             remat_policy=None if remat_policy == "off" else remat_policy)
+    opt = models.default_optimizer()
+    state = jax.eval_shape(lambda: models.create_train_state(cfg, jax.random.PRNGKey(0), opt))
     batch = {"tokens": jax.ShapeDtypeStruct((4, 65), jnp.int32)}
-    return make_train_step(cfg, opt).lower(state, batch).compile()
+    return models.make_train_step(cfg, opt).lower(state, batch).compile()
 
 
-@pytest.mark.parametrize("remat_policy", ["save_attn", "dots", "off"])
-def test_what_the_nano_step_names_falls_into_the_phases(remat_policy):
+@pytest.mark.parametrize("model, remat_policy", [
+    ("gpt", "save_attn"), ("gpt", "dots"), ("gpt", "off"),
+    # `llama.py` named nothing before PR 31: its step fell into no phase.
+    ("llama", "save_attn"), ("olmoe", "save_attn")])
+def test_what_the_nano_step_names_falls_into_the_phases(model, remat_policy):
     """Of the compiled instructions that carry an `op_name` (on the CPU four
     in ten carry none: converts, constants and fusions the compiler made),
-    under 5 % are in no named phase, every scope of the model shows, and
+    under 5 % are in no named phase, every scope of the model shows (the
+    block's, and for OLMoE the expert layer's from `models/moe.py`), and
     recompute exists exactly where `jax.checkpoint` does."""
-    scopes = scope_map(_nano_step(remat_policy).as_text())
-    assert len(scopes) > 1500
+    scopes = scope_map(_nano_step(model, remat_policy).as_text())
+    assert len(scopes) > 1000
     count = {p: 0 for p in PHASES}
     for op_name in scopes.values():
         count[phase(op_name)] += 1
@@ -97,62 +121,31 @@ def test_what_the_nano_step_names_falls_into_the_phases(remat_policy):
     parts = {part for op_name in scopes.values() for part in re.split(r"[/()]", op_name)}
     # `grad_norm` computes what `clip_by_global_norm` already did under
     # `optimizer`: XLA keeps one of the two, so either name may be all that is left.
-    assert set(SCOPES) - {"grad_norm"} <= parts
+    assert set(SHARED + OWN[model]) <= parts, set(SHARED + OWN[model]) - parts
     # What runs again keeps the name of the part it belongs to, under the
-    # region's: which parts are recomputed is the policy's to say.
+    # region's: which parts are recomputed is the policy's to say. Under
+    # `save_attn` attention stays out of it; the rest of the block is in it.
     again = [n for n in scopes.values() if "rematted_computation" in n.split("/")]
-    want = {"save_attn": {"qkv", "out_mlp"}, "dots": {"qkv", "attention", "out_mlp"}, "off": set()}
     inside = {part for n in again for part in n.split("/")}
-    assert inside & {"qkv", "attention", "out_mlp"} == want[remat_policy]
+    want = {"save_attn": HALVES[model], "dots": HALVES[model] | {"attention"}, "off": set()}
+    assert inside & (HALVES[model] | {"attention"}) == want[remat_policy]
 
 
 def test_names_change_no_instruction_and_no_byte_of_the_nano_step(monkeypatch):
     import jax
     from jax.experimental import pallas as pl
 
-    named = _nano_step("save_attn")
+    named = _nano_step("gpt", "save_attn")
     monkeypatch.setattr(jax, "named_scope", lambda name: contextlib.nullcontext())
     real = pl.pallas_call
     monkeypatch.setattr(pl, "pallas_call", lambda *a, name=None, **kw: real(*a, **kw))
-    bare = _nano_step("save_attn")
+    bare = _nano_step("gpt", "save_attn")
     assert not set(SCOPES) & {p for n in scope_map(bare.as_text()).values() for p in n.split("/")}
     assert len(INSTRUCTION.findall(named.as_text())) == len(INSTRUCTION.findall(bare.as_text()))
     a, b = named.memory_analysis(), bare.memory_analysis()
     for key in ("argument_size_in_bytes", "temp_size_in_bytes", "output_size_in_bytes",
                 "alias_size_in_bytes"):
         assert getattr(a, key) == getattr(b, key), key
-
-
-OLMOE_SCOPES = ("embed", "blocks", "qkv", "attention", "attn_out", "moe", "router", "dispatch",
-                "experts", "combine", "head", "loss", "optimizer")
-
-
-def test_what_the_olmoe_nano_step_names_falls_into_the_phases():
-    """`models/olmoe.py` and `models/moe.py` name their work as `gpt.py` does:
-    of the instructions that carry an `op_name`, under 5 % are in no named
-    phase, and every scope of the block and of the expert layer shows."""
-    import jax
-    import jax.numpy as jnp
-
-    from ray_tpu.models import OLMoEConfig, create_train_state, default_optimizer, make_train_step
-
-    cfg = OLMoEConfig.nano()
-    opt = default_optimizer()
-    state = jax.eval_shape(lambda: create_train_state(cfg, jax.random.PRNGKey(0), opt))
-    batch = {"tokens": jax.ShapeDtypeStruct((2, 65), jnp.int32)}
-    scopes = scope_map(make_train_step(cfg, opt).lower(state, batch).compile().as_text())
-    assert len(scopes) > 1500
-    count = {p: 0 for p in PHASES}
-    for op_name in scopes.values():
-        count[phase(op_name)] += 1
-    assert count["other"] < 0.05 * len(scopes), count
-    assert min(count[p] for p in ("forward", "recompute", "backward", "optimizer")) > 100, count
-    parts = {part for op_name in scopes.values() for part in re.split(r"[/()]", op_name)}
-    assert set(OLMOE_SCOPES) <= parts, set(OLMOE_SCOPES) - parts
-    # Under `save_attn` attention stays out of what runs again; the rest of the block is in it.
-    again = {part for n in scopes.values() if "rematted_computation" in n.split("/")
-             for part in n.split("/")}
-    assert {"qkv", "experts", "router"} <= again and "attention" not in again
 
 
 # ------------------------------------------------- ahead of time, for the v5e
@@ -165,9 +158,10 @@ def _aot_main(cells):
     from jax.experimental import topologies
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from benchmark.models import gpt2
-    from ray_tpu.models import default_optimizer, gpt, make_train_step
-    from ray_tpu.models.training import TrainState, param_shardings
+    import importlib
+
+    from ray_tpu.models import default_optimizer, make_train_step
+    from ray_tpu.models.training import TrainState, model_for, param_shardings
     from ray_tpu.parallel import MeshSpec, ShardingRules, batch_spec
 
     jax.config.update("jax_enable_compilation_cache", False)
@@ -178,9 +172,13 @@ def _aot_main(cells):
             c = json.load(fh)
         spec = MeshSpec(**(c["layout"]["mesh"] or {"data": 1}))
         mesh = spec.build(topo.devices[: spec.num_devices])
-        cfg = gpt2.gpt_config(c)
+        # The cell's configuration as the harness builds it: the benchmark's module for
+        # `c["model"]` has one `<family>_config(c)` (its `build` wants a device).
+        model = importlib.import_module("benchmark.models." + c["model"])
+        (to_config,) = [f for name, f in vars(model).items() if name.endswith("_config")]
+        cfg = to_config(c)
         opt = default_optimizer(learning_rate=c["learning_rate"])
-        shapes = jax.eval_shape(lambda: gpt.init_params(cfg, jax.random.PRNGKey(0)))
+        shapes = jax.eval_shape(lambda: model_for(cfg).init_params(cfg, jax.random.PRNGKey(0)))
         shardings = param_shardings(cfg, mesh, ShardingRules())
         replicated = NamedSharding(mesh, P())
         by_shape = dict(zip((s.shape for s in jax.tree.leaves(shapes)), jax.tree.leaves(shardings)))
@@ -230,7 +228,7 @@ def aot():
 def test_the_v5e_program_is_the_pinned_one_and_needs_no_more_memory(aot, cell):
     got = aot[cell]
     assert {k: got[k] for k in PARENT[cell]} == PARENT[cell]
-    assert got["temp"] <= TEMP_BEFORE_PR30[cell]
+    assert got["temp"] <= TEMP_BEFORE_PR30.get(cell, PARENT[cell]["temp"])
     with open(os.path.join(REPO, "benchmark", "configs", cell + ".json")) as fh:
         recorded = json.load(fh)["memory_analysis_v5e_bytes"]
     assert got["argument"] <= recorded["arguments"]
@@ -253,12 +251,17 @@ def test_every_block_weight_crosses_ici_in_dense_tiles(aot):
 
 @pytest.mark.parametrize("cell", sorted(PARENT))
 def test_one_kernel_under_flash_fwd_one_under_flash_bwd_and_all_phases(aot, cell):
-    fwd, bwd = sorted(aot[cell]["mosaic_scopes"], key=lambda n: "flash_bwd" in n)
+    kernel = {n: n.split("/")[-2] for n in aot[cell]["mosaic_scopes"]}  # .../<name>/pallas_call
+    fwd, bwd = sorted((n for n, name in kernel.items() if not name.startswith("gmm_")),
+                      key=lambda n: "flash_bwd" in n)
     assert "flash_fwd" in fwd.split("/") and phase(fwd) == "forward"
     assert "flash_bwd" in bwd.split("/") and phase(bwd) == "backward"
     # ... each inside the scope that says which tile schedule it runs.
-    assert "tiles_3of4" in fwd.split("/") and "tiles_3of4" in bwd.split("/")
-    assert len(aot[cell]["mosaic_scopes"]) == 2
+    tiles = KERNELS[cell]["tiles"]
+    assert tiles in fwd.split("/") and tiles in bwd.split("/")
+    gmm = [kernel[n] for n in aot[cell]["mosaic_scopes"] if kernel[n].startswith("gmm_")]
+    assert {name: gmm.count(name) for name in gmm} == KERNELS[cell]["gmm"]
+    assert len(aot[cell]["mosaic_scopes"]) == 2 + len(gmm)
     assert aot[cell]["phases"] == sorted(PHASES)
 
 

@@ -444,3 +444,45 @@ def test_resnet50_param_count():
 
     n = resnet.num_params(ResNetConfig.resnet50())
     assert 25_000_000 < n < 26_100_000, n
+
+
+def test_model_for_finds_the_module_that_defines_the_configuration():
+    """No registry: a configuration's model is the module its class lives in,
+    and an object that is no model's configuration is an error, not GPT."""
+    from ray_tpu.models import LlamaConfig, OLMoEConfig, ResNetConfig, gpt, llama, olmoe, resnet
+    from ray_tpu.models.training import model_for
+
+    assert model_for(GPTConfig.nano()) is gpt
+    assert model_for(LlamaConfig.nano()) is llama
+    assert model_for(OLMoEConfig.nano()) is olmoe
+    assert model_for(ResNetConfig()) is resnet
+    for stranger in ({"n_layer": 2}, object(), MeshSpec(data=1)):
+        with pytest.raises(TypeError, match="no model's configuration"):
+            model_for(stranger)
+    with pytest.raises(TypeError, match="no model's configuration"):
+        create_train_state({"n_layer": 2}, jax.random.PRNGKey(0), default_optimizer())
+
+
+@pytest.mark.parametrize("config", ["GPTConfig", "LlamaConfig", "OLMoEConfig"])
+def test_the_remat_policy_changes_no_loss_and_no_gradient(config):
+    """What `stack.block` recomputes is `config.remat` / `remat_policy`'s to
+    say, for every model alike; what it computes is not: in f32 the loss and
+    every gradient agree to rounding whichever parts run again."""
+    from ray_tpu import models
+    from ray_tpu.models.training import model_for
+
+    tokens = jnp.asarray(_batch(np.random.default_rng(0), batch=2, seq=32)["tokens"])
+    got = {}
+    for policy in ("save_attn", "dots", "off"):
+        cfg = getattr(models, config).nano(dtype=jnp.float32, attention="xla", remat=policy != "off",
+                                           remat_policy=None if policy == "off" else policy)
+        model = model_for(cfg)
+        params = model.init_params(cfg, jax.random.PRNGKey(0))
+        got[policy] = jax.jit(jax.value_and_grad(
+            lambda p: model.loss_fn(p, {"tokens": tokens}, cfg)))(params)  # noqa: B023
+    loss, grads = got["off"]
+    assert np.isfinite(float(loss)) and float(loss) > 0
+    for policy in ("save_attn", "dots"):
+        np.testing.assert_allclose(got[policy][0], loss, rtol=1e-6)
+        for a, b in zip(jax.tree.leaves(got[policy][1]), jax.tree.leaves(grads)):
+            np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-6)

@@ -20,7 +20,7 @@ reference flash attention and splash attention, default block sizes): the
 summed device time of all their Mosaic calls, forward, and backward as
 (forward + backward) less forward.
 
-Runs on TPU chips only: anything else is a failed run, as in `bench.py`. No
+Runs on TPU chips only: anything else is a failed run, as in `benchmark/run.py`. No
 benchmark cell and no test runs this; it is how a kernel change re-measures
 the table in PERF.md (section 6, PR 26) before it touches a train step. It imports
 only `flash_attention` (and `kernel_plan` where the tree has it), so a copy
