@@ -3,12 +3,15 @@
 Every token picks its `k` experts by the router's softmax; the `tokens * k`
 (token, expert) pairs are sorted by expert, the rows are gathered into expert
 order, and the SwiGLU experts run as three grouped matmuls over the ragged
-groups of rows (`ops/grouped_matmul.py`). Results are weighted by the router's
-probabilities and summed back per token. There is no capacity and no dropped
-token, and no tensor with both a token and an expert-slot axis: work and
-memory are linear in tokens (the Switch layer this replaces went through
-dense `(B, S, E, C)` one-hots, three times the experts' own FLOPs at 64
-experts).
+groups of rows (`ops/grouped_matmul.py`). The router's probability of each
+pair is applied to its row where SwiGLU's output is written, the operand of
+the down projection (`w * (a @ W) = (w * a) @ W`, row by row), so the results
+are only put back in token order and summed per token: no pass over the
+`tokens * k` rows exists for the weighting alone, forward or backward. There
+is no capacity and no dropped token, and no tensor with both a token and an
+expert-slot axis: work and memory are linear in tokens (the Switch layer this
+replaces went through dense `(B, S, E, C)` one-hots, three times the experts'
+own FLOPs at 64 experts).
 
 Expert weights carry the `expert` logical axis, so a mesh with an `expert`
 axis shards them; the sorted form is partitioned by XLA from the sharding
@@ -63,8 +66,25 @@ def _sum_rows_bwd(k, res, g):
     return _gather_rows(g, order, inverse, k), None, None
 
 
+@jax.custom_vjp
+def _sort_weights(weights, order, inverse):
+    """The `tokens * k` weights, one per pair, in the order of the sorted
+    rows. The gradient is a gather by `inverse`, where the transpose jax
+    would derive is a scatter-add of `tokens * k` updates."""
+    return weights[order]
+
+
+def _sort_weights_fwd(weights, order, inverse):
+    return weights[order], inverse
+
+
+def _sort_weights_bwd(inverse, g):
+    return g[inverse], None, None
+
+
 _gather_rows.defvjp(_gather_rows_fwd, _gather_rows_bwd)
 _sum_rows.defvjp(_sum_rows_fwd, _sum_rows_bwd)
+_sort_weights.defvjp(_sort_weights_fwd, _sort_weights_bwd)
 
 
 def route(x, router_w, k: int, norm_topk_prob: bool = False):
@@ -127,6 +147,7 @@ def moe_mlp(
     with jax.named_scope("dispatch"):
         order, inverse = expert_order(experts)
         rows = _gather_rows(tokens, order, inverse, k)  # (T * k, D), expert order
+        row_weights = _sort_weights(weights.reshape(-1), order, inverse)  # (T * k,) f32
         sizes = aux["tokens_per_expert"]
         # A grouped matmul gives row i to the group the running sum of `sizes`
         # puts it in: a row is processed where that is its own expert.
@@ -135,9 +156,10 @@ def moe_mlp(
     with jax.named_scope("experts"):
         gate = grouped_matmul(rows, w_gate.astype(cdt), sizes)
         up = grouped_matmul(rows, w_up.astype(cdt), sizes)
-        rows = grouped_matmul(jax.nn.silu(gate) * up, w_down.astype(cdt), sizes)
+        # The weighting rides in SwiGLU's own pass, in float32, rounded once.
+        act = jax.nn.silu(gate.astype(jnp.float32)) * up.astype(jnp.float32) * row_weights[:, None]
+        rows = grouped_matmul(act.astype(cdt), w_down.astype(cdt), sizes)
     with jax.named_scope("combine"):
-        rows = (rows.astype(jnp.float32) * weights.reshape(-1)[order][:, None]).astype(cdt)
         out = _sum_rows(rows, order, inverse, k)
     return out.reshape(B, S, D), aux
 

@@ -6,8 +6,9 @@
 compiled program. Here: the CPU compile of each model's nano step puts what
 carries a name into the four named phases, and the ahead-of-time `v5e:2x2`
 compile of the benchmark's three configurations is the pinned program: same
-instruction count, same `memory_analysis()`, the same Mosaic calls, and every
-block weight gathered over ICI in dense tiles.
+instruction count, same `memory_analysis()`, the same Mosaic calls, every
+block weight gathered over ICI in dense tiles, and the expert layer moving
+its `tokens * k` sorted rows only to permute or to sum them.
 
 What reads the names is `benchmark/harness/program_trace.py`; its `phase`
 rules are used here, so the model's names and their reader cannot drift.
@@ -46,8 +47,9 @@ PARENT = {
                     "output": 4259343360, "alias": 4259341312},
     "gpt2-xl-fsdp4": {"instructions": 3710, "argument": 4714580992, "temp": 9007949312,
                       "output": 4714564608, "alias": 4714562560},
-    # Pinned at PR 31 from its parent's tree, before the block moved into `stack.py`.
-    "olmoe-1b-7b-l1": {"instructions": 5329, "argument": 7507437568, "temp": 4008547328,
+    # Pinned at PR 32, which applies the router's weight inside SwiGLU's fusion: one
+    # `[65536, 2048]` bf16 residual fewer than the 5,329 instructions / 4,008,547,328 B before it.
+    "olmoe-1b-7b-l1": {"instructions": 5324, "argument": 7507437568, "temp": 3740107776,
                        "output": 7507405824, "alias": 7507403776},
 }
 # What each cell's step hands to Mosaic: the tile schedule its two flash kernels run under
@@ -68,6 +70,41 @@ INSTRUCTION = re.compile(r"^\s+(?:ROOT )?%?([\w.\-]+) = ", re.M)
 # minor-to-major order. An asynchronous gather is the same instruction inside the computation that
 # its `async-collective-start` wraps.
 ALL_GATHER = re.compile(r"^\s+(?:ROOT )?%?([\w.\-]+) = \w+\[([\d,]+)\]\{([\d,]+)[^ ]* all-gather\(", re.M)
+# `%fusion.9 = bf16[65536,2048]{1,0:T(8,128)(2,1)} fusion(%gmm_fwd.24, %fusion.278), kind=kLoop, ...`:
+# name, result (a tuple for a fusion with several), opcode, operands. A computation's own line has no ` = `.
+RESULT = re.compile(r"^\s+(?:ROOT )?%?([\w.\-]+) = (\(.*?\)|\S+) ([\w\-]+)\((.*?)\)(?:, |$)", re.M)
+MOVES_NOTHING = ("get-tuple-element", "tuple", "bitcast")
+FUSED = re.compile(r" fusion\(.*? calls=%?([\w.\-]+)|to_apply=%?([\w.\-]+)")
+
+
+def sorted_row_traffic(text, scopes, rows, width):
+    """Of a compiled step's text: every instruction that runs by itself (not
+    inside a fusion or a reducer) under the expert layer's `dispatch` or
+    `combine` and reads or writes a `[rows, width]` array, by what its
+    `op_name` ends in (`gather`, `reduce_sum`, ...); and the `scatter-add`s of
+    the layer's backward pass outside `router` (the router's own is the
+    gradient of `top_k`, 8,192 x 64)."""
+    shape = f"[{rows},{width}]"
+    inside = {name for pair in FUSED.findall(text) for name in pair if name}
+    result, runs, computation = {}, [], None
+    for line in text.splitlines():
+        if line.endswith("{") and " = " not in line:
+            computation = line.split()[1 if line.startswith("ENTRY") else 0].lstrip("%")
+        m = RESULT.match(line)
+        if m:
+            result[m.group(1)] = m.group(2)
+            if computation not in inside and m.group(3) not in MOVES_NOTHING:
+                runs.append((m.group(1), re.findall(r"%([\w.\-]+)", m.group(4))))
+    moved, scatter_adds = {}, []
+    for name, operands in runs:
+        parts = re.split(r"[/()]", scopes.get(name, ""))
+        if {"dispatch", "combine"} & set(parts) and any(
+                shape in result.get(n, "") for n in [name, *operands]):
+            moved.setdefault(parts[-1], []).append(name)
+        if ("moe" in parts and "transpose" in parts and "router" not in parts
+                and parts[-1] == "scatter-add"):
+            scatter_adds.append(name)
+    return moved, scatter_adds
 
 
 def block_weight_gathers(text, scopes):
@@ -207,6 +244,9 @@ def _aot_main(cells):
         }
         out[cell]["gather_minor_dims"], out[cell]["gathered_weight_copies"] = (
             block_weight_gathers(text, scopes))
+        if "num_experts_per_tok" in c:
+            out[cell]["sorted_rows_moved"], out[cell]["backward_scatter_adds"] = sorted_row_traffic(
+                text, scopes, rows * seq * c["num_experts_per_tok"], c["hidden_size"])
     print("AOT_RESULT " + json.dumps(out))
 
 
@@ -263,6 +303,25 @@ def test_one_kernel_under_flash_fwd_one_under_flash_bwd_and_all_phases(aot, cell
     assert {name: gmm.count(name) for name in gmm} == KERNELS[cell]["gmm"]
     assert len(aot[cell]["mosaic_scopes"]) == 2 + len(gmm)
     assert aot[cell]["phases"] == sorted(PHASES)
+
+
+@pytest.mark.parametrize("what, count", [
+    ("gather", 4), ("reduce_sum", 2), ("anything else", 0), ("backward scatter-add", 0)])
+def test_the_sorted_rows_are_only_permuted_and_summed(aot, what, count):
+    """The 65,536 x 2,048 sorted rows of the OLMoE cell cross memory under
+    `dispatch` / `combine` in four gathers (tokens into expert order and
+    results back, each with its gradient) and two sums of a token's 8 rows,
+    and in nothing else: the router's weight is applied where SwiGLU's output
+    is written (`models/moe.py`), so no pass exists for the weighting, and the
+    weight's gradient goes back to `(tokens, k)` by a gather, not by a
+    scatter-add of 65,536 updates. Before PR 32: a `convert_element_type`
+    pass forward, `reduce_sum` three times, one `scatter-add`."""
+    moved, scatter_adds = (aot["olmoe-1b-7b-l1"][key]
+                           for key in ("sorted_rows_moved", "backward_scatter_adds"))
+    got = {"anything else": [n for kind, names in moved.items()
+                             if kind not in ("gather", "reduce_sum") for n in names],
+           "backward scatter-add": scatter_adds}.get(what, moved.get(what, []))
+    assert len(got) == count, (what, moved, scatter_adds)
 
 
 if __name__ == "__main__":

@@ -1,0 +1,133 @@
+"""`models/moe.py moe_mlp` against an expert layer spelled out in float32:
+every expert on every token, and the router's weight applied *after* the down
+projection, where the definition puts it. `moe_mlp` applies it one product
+earlier, to the rows SwiGLU writes, and sends it through the layer's own
+permutation; output and every gradient must not know the difference, for one
+expert a token and for eight, with and without renormalised weights, and
+through `GPTConfig.moe_experts`."""
+
+import functools
+import os
+import sys
+
+import numpy as np
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, REPO)
+
+TOKENS, D, F, E = (2, 24), 16, 32, 16
+WEIGHTS = ("router_w", "w_gate", "w_up", "w_down")
+
+
+def spelled_out(x, router_w, w_gate, w_up, w_down, *, k, norm_topk_prob=False):
+    """(out, aux) as `moe_mlp` gives them, the dense way, all in float32."""
+    import jax
+    import jax.numpy as jnp
+
+    tokens = x.reshape(-1, x.shape[-1]).astype(jnp.float32)
+    probs = jax.nn.softmax(tokens @ router_w.astype(jnp.float32), axis=-1)
+    weights, experts = jax.lax.top_k(probs, k)
+    if norm_topk_prob:
+        weights = weights / weights.sum(axis=-1, keepdims=True)
+    chosen = jax.nn.one_hot(experts, probs.shape[-1], dtype=jnp.float32)  # (T, k, E)
+    per_expert = jnp.einsum("tk,tke->te", weights, chosen)
+    hidden = (jax.nn.silu(jnp.einsum("td,edf->tef", tokens, w_gate.astype(jnp.float32)))
+              * jnp.einsum("td,edf->tef", tokens, w_up.astype(jnp.float32)))
+    results = jnp.einsum("tef,efd->ted", hidden, w_down.astype(jnp.float32))
+    out = jnp.einsum("te,ted->td", per_expert, results)
+    load = chosen.sum(axis=(0, 1)) / tokens.shape[0]
+    aux = {"load_balance": probs.shape[-1] * jnp.sum(load * probs.mean(axis=0))}
+    return out.reshape(x.shape).astype(x.dtype), aux
+
+
+@functools.lru_cache(maxsize=None)
+def _both(k, norm_topk_prob):
+    """{name: (moe_mlp's, the spelled-out layer's)} for the output and for the
+    gradient, by each weight, of the output against one fixed cotangent."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models.moe import moe_mlp
+
+    keys = jax.random.split(jax.random.PRNGKey(k), 6)
+    x = jax.random.normal(keys[0], (*TOKENS, D))
+    cotangent = jax.random.normal(keys[1], (*TOKENS, D))
+    params = {"router_w": jax.random.normal(keys[2], (D, E)),
+              "w_gate": jax.random.normal(keys[3], (E, D, F)) / np.sqrt(D),
+              "w_up": jax.random.normal(keys[4], (E, D, F)) / np.sqrt(D),
+              "w_down": jax.random.normal(keys[5], (E, F, D)) / np.sqrt(F)}
+
+    def run(layer):
+        def scalar(p):
+            out, _ = layer(x, *(p[name] for name in WEIGHTS), k=k, norm_topk_prob=norm_topk_prob)
+            return jnp.sum(out * cotangent), out
+
+        (_, out), grads = jax.jit(jax.value_and_grad(scalar, has_aux=True))(params)
+        return {"out": out, **grads}
+
+    got, want = run(moe_mlp), run(spelled_out)
+    return {name: (np.asarray(got[name]), np.asarray(want[name])) for name in got}
+
+
+@pytest.mark.parametrize("what", ("out",) + WEIGHTS)
+@pytest.mark.parametrize("norm_topk_prob", (False, True), ids=("softmax_weights", "renormalised"))
+@pytest.mark.parametrize("k", (1, 8))
+def test_moe_mlp_is_the_layer_with_the_weight_after_the_down_projection(k, norm_topk_prob, what):
+    got, want = _both(k, norm_topk_prob)[what]
+    assert np.abs(want).max() > (0 if what == "router_w" and k == 1 and norm_topk_prob else 1e-2)
+    np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-5)
+
+
+def test_the_router_learns_only_through_the_weights_it_gave():
+    """One renormalised expert a token weighs 1 whatever the router says: no
+    gradient reaches `router_w`, in either form. With the softmax's own
+    weight it does, and that is the path through `_sort_weights`."""
+    assert max(np.abs(g).max() for g in _both(1, True)["router_w"]) < 1e-6  # w / w, to rounding
+    assert np.abs(_both(1, False)["router_w"][0]).max() > 1e-2
+
+
+def test_in_bf16_the_weighting_costs_no_rounding_of_its_own():
+    """bf16 rows, float32 weighting: the layer stays within bf16's rounding of
+    the products (2^-8 of values of order 1, three products deep) of the
+    float32 layer on the same bf16 inputs."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models.moe import moe_mlp
+
+    keys = jax.random.split(jax.random.PRNGKey(0), 5)
+    x = jax.random.normal(keys[0], (*TOKENS, D)).astype(jnp.bfloat16)
+    weights = (jax.random.normal(keys[1], (D, E)), jax.random.normal(keys[2], (E, D, F)) / np.sqrt(D),
+               jax.random.normal(keys[3], (E, D, F)) / np.sqrt(D),
+               jax.random.normal(keys[4], (E, F, D)) / np.sqrt(F))
+    got, _ = moe_mlp(x, *weights, k=8)
+    want, _ = spelled_out(x.astype(jnp.float32), weights[0],
+                          *(w.astype(jnp.bfloat16) for w in weights[1:]), k=8)
+    assert got.dtype == jnp.bfloat16
+    np.testing.assert_allclose(np.asarray(got, np.float32), np.asarray(want), atol=3e-2)
+
+
+def test_through_gpt_config_moe_experts_loss_and_gradients_agree(monkeypatch):
+    """`models/gpt.py` runs the same `moe_mlp` with one expert a token: the
+    nano model's loss (auxiliary term included) and the gradient of every
+    parameter, with the layer as it is and with the spelled-out one in its place."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models import GPTConfig, gpt, moe
+
+    cfg = GPTConfig.nano(dtype=jnp.float32, moe_experts=4, remat=False)
+    params = gpt.init_params(cfg, jax.random.PRNGKey(0))
+    batch = {"tokens": jnp.asarray(np.random.default_rng(0).integers(0, 255, (2, 33), np.int32))}
+
+    def loss_and_grads():
+        return jax.value_and_grad(lambda p: gpt.loss_fn(p, batch, cfg))(params)
+
+    loss, grads = loss_and_grads()
+    monkeypatch.setattr(moe, "moe_mlp", spelled_out)
+    want_loss, want_grads = loss_and_grads()
+    np.testing.assert_allclose(loss, want_loss, rtol=1e-6)
+    assert float(jnp.abs(want_grads["blocks"]["moe"]["router_w"]).max()) > 1e-4
+    jax.tree.map(lambda a, b: np.testing.assert_allclose(a, b, rtol=2e-4, atol=1e-6),
+                 grads, want_grads)
