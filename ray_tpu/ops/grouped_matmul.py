@@ -11,22 +11,36 @@ no trace can say what they belong to (PERF.md section 6, PR 28):
 
 - `gmm_fwd`: one program per (tile of n, visit). A visit is a (group, tile of
   rows) pair: a row tile that straddles a group boundary is visited once for
-  each group it holds rows of, and each visit stores only its group's rows.
-  The whole contraction dimension is one block, so a group's matrix is
-  fetched once and stays in VMEM while the grid walks the group's row tiles.
+  each group it holds rows of. A visit multiplies the window of `SUB_ROWS`-row
+  blocks of its tile that hold rows of its group, and no other: the whole
+  tile where the tile lies inside the group, one to three blocks at a
+  dynamic start where the group begins or ends inside it, one product of a
+  static size either way (`_for_live_window`), and it stores only its group's
+  rows. The whole contraction dimension is one block. A group's matrix is
+  not a pipelined operand: the kernel copies it from HBM into one of two VMEM
+  slots itself, starting at the second visit of the group before (a whole
+  group ahead; the pipeline would start at the group's last visit, which at a
+  boundary is a short one, and the next group's first visit waited for 4 MiB).
 - `gmm_dlhs`: the gradient for the rows, the same kernel against the
   transposed matrices (`dout @ rhs[group]^T`, contracting the last dimension
   of both).
 - `gmm_drhs`: the gradient for the matrices, per group `lhs_rows^T @
-  dout_rows`, accumulated in float32 in VMEM over the group's visits and
-  written when the grid leaves the group. A group with no rows gets one
-  visit with nothing in it, so its gradient is written as zeros.
+  dout_rows`, accumulated in float32 in VMEM over the group's visits, each
+  contracting over the window of live `DRHS_SUB_ROWS`-row blocks of its tile
+  in one product and one update of the accumulator, with the rows of other
+  groups in the window zeroed. The result is stored at the visit after the
+  group's last (its block stays the output block for that one visit more),
+  so that its write-back runs beside a whole visit and not beside the next
+  group's short first one. A group with no rows gets one visit with no
+  product in it, so its gradient is written as zeros.
 
 Which visits there are is computed from `group_sizes` by a few XLA operations
-(`_visits`) and handed to the kernels as scalar-prefetch arguments; the grid
-has the static upper bound of `m / tile + g` visits, and the ones past the
-last real visit repeat its block indices and do nothing. Tile sizes follow
-from the shapes alone (`_tiles`).
+(`_visits`, `_matrix_slots`) and handed to the kernels as scalar-prefetch
+arguments; the grid has the static upper bound of `m / tile + g` visits (at
+least one more than there are), and the ones past the last real visit repeat
+its block indices and multiply nothing. Tile sizes follow from the shapes
+alone (`_tiles`). `issued_rows` counts the rows of products a call issues:
+at most `m + g * sub`, where multiplying whole tiles issued `m + g * tile`.
 """
 
 from __future__ import annotations
@@ -36,6 +50,7 @@ from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -43,9 +58,14 @@ from jax.experimental.pallas import tpu as pltpu
 # the other) may take this much of VMEM, twice for its two buffers; with the
 # row tiles and the result that keeps a program under Mosaic's default 16 MiB.
 RHS_BLOCK_BYTES = 4 * 1024 * 1024
-ROW_TILES = (256, 128)  # forward and dlhs: rows a visit multiplies
-DRHS_ROW_TILES = (512, 256, 128)  # drhs: rows a visit contracts over
+ROW_TILES = (256, 128)  # forward and dlhs: rows of a visit's tile
+DRHS_ROW_TILES = (512, 256, 128)  # drhs: rows of a visit's tile
 DRHS_TILE = 1024  # drhs: the float32 accumulator is at most DRHS_TILE^2
+# The blocks a visit's window is made of. On a v5e at OLMoE's shapes (PERF.md
+# section 6, PR 33): 64 rows read 4 % under 128 in `gmm_fwd` / `gmm_dlhs` and 32
+# another 1 %; in `gmm_drhs`, where the rows are the contraction, 64 read as 128.
+SUB_ROWS = 64
+DRHS_SUB_ROWS = 128
 
 
 class Tiles(NamedTuple):
@@ -78,6 +98,9 @@ def _tiles(m: int, k: int, n: int, itemsize: int) -> Optional[Tiles]:
     return Tiles(rows, out_fwd, out_dlhs, drhs_rows, drhs_k, drhs_n)
 
 
+# `inline=True`: traced once for its arguments' shapes and then replayed into
+# the caller's trace, with no call of its own in the program.
+@functools.partial(jax.jit, static_argnums=(1, 2, 3), inline=True)
 def _visits(group_sizes, m: int, tile: int, visit_empty: bool):
     """The (group, row tile) pairs a kernel walks, in order: `group_ids` and
     `tile_ids` (each `m / tile + g` long; entries past `num` repeat the last
@@ -100,82 +123,166 @@ def _visits(group_sizes, m: int, tile: int, visit_empty: bool):
     return group_ids, tile_ids.astype(jnp.int32), starts, ends, num.reshape(1)
 
 
-def _row_mask(tile_id, start, end, rows: int):
-    """(rows, 1): which rows of row tile `tile_id` lie in [start, end)."""
-    row = tile_id * rows + jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
-    return (row >= start) & (row < end)
+@functools.partial(jax.jit, inline=True)
+def _matrix_slots(group_sizes):
+    """For `_gmm_kernel`'s own copies of the groups' matrices, by group: the
+    VMEM slot (0 / 1, alternating over the groups that have rows) and the
+    next group that has rows (g where there is none)."""
+    g = group_sizes.shape[0]
+    visited = group_sizes > 0
+    slots = (jnp.cumsum(visited) - 1) % 2
+    later = jax.lax.cummin(jnp.where(visited, jnp.arange(g), g), reverse=True)
+    return slots.astype(jnp.int32), jnp.append(later[1:], g).astype(jnp.int32)
+
+
+def issued_rows(group_sizes, sub: int) -> int:
+    """The rows of products a kernel issues for concrete `group_sizes` when
+    its visits multiply windows of `sub`-row blocks: each group pays for the
+    blocks that hold a row of it, so a block two groups share is paid twice.
+    At most `sum(group_sizes) + g * sub`."""
+    sizes = np.asarray(group_sizes, np.int64)
+    ends = np.cumsum(sizes)
+    blocks = -(-ends // sub) - (ends - sizes) // sub
+    return int(blocks[sizes > 0].sum()) * sub
+
+
+def _live_window(tile_id, start, end, rows: int, sub: int):
+    """Of row tile `tile_id`, what belongs to the group of rows [start, end):
+    its first and one-past-last row, counted from the tile's first, and the
+    `sub`-row blocks that hold them: the first one and how many (none for a
+    group with no rows in the tile)."""
+    lo = jnp.clip(start - tile_id * rows, 0, rows)
+    hi = jnp.clip(end - tile_id * rows, lo, rows)
+    first = lo // sub
+    return lo, hi, first, jnp.where(hi > lo, (hi + sub - 1) // sub - first, 0)
+
+
+def _for_live_window(tile_id, start, end, rows: int, sub: int, body):
+    """Runs `body(window, mine)` on the `_live_window` of a visit: `window`
+    indexes the tile's rows (the whole tile, or `n * sub` rows from a block
+    edge, `n` static), `mine` (rows of the window, 1) says which of them are
+    the group's. Nothing runs for a group with no rows in the tile."""
+    lo, hi, first, count = _live_window(tile_id, start, end, rows, sub)
+    for n in range(1, rows // sub + 1):
+        @pl.when(count == n)
+        def _(n=n):
+            whole = n * sub == rows  # then first == 0
+            offset = 0 if whole else pl.multiple_of(first * sub, sub)
+            row = offset + jax.lax.broadcasted_iota(jnp.int32, (n * sub, 1), 0)
+            body(slice(None) if whole else pl.ds(offset, n * sub), (row >= lo) & (row < hi))
 
 
 # ------------------------------------------------------------------ forward, dlhs
-def _gmm_kernel(group_ids, tile_ids, starts, ends, num, lhs_ref, rhs_ref, out_ref, *,
-                rows: int, transpose_rhs: bool):
-    v = pl.program_id(1)
+def _gmm_kernel(group_ids, tile_ids, starts, ends, num, slots, nexts, lhs_ref, rhs_hbm, out_ref,
+                rhs_buf, sem, *, rows: int, out_tile: int, transpose_rhs: bool):
+    j, v = pl.program_id(0), pl.program_id(1)
+    last_visit = num[0] - 1
 
-    @pl.when(v < num[0])
+    def matrix_copy(group, slot):
+        block = pl.ds(pl.multiple_of(j * out_tile, out_tile), out_tile)
+        src = rhs_hbm.at[group, block, :] if transpose_rhs else rhs_hbm.at[group, :, block]
+        return pltpu.make_async_copy(src, rhs_buf.at[slot], sem.at[slot])
+
+    @pl.when(v <= last_visit)
     def _():
         group = group_ids[v]
-        contract = (((1,), (1,)), ((), ())) if transpose_rhs else (((1,), (0,)), ((), ()))
-        acc = jax.lax.dot_general(lhs_ref[...], rhs_ref[0], contract,
-                                  preferred_element_type=jnp.float32)
-        mine = _row_mask(tile_ids[v], starts[group], ends[group], rows)
-        # The rows of other groups in this tile were stored by the visits
-        # before this one, or will be by the ones after it.
-        out_ref[...] = jnp.where(mine, acc.astype(out_ref.dtype), out_ref[...])
+        slot = slots[group]
+        first = (v == 0) | (group_ids[jnp.maximum(v - 1, 0)] != group)
+        second = jnp.logical_not(first) & ((v == 1) | (group_ids[jnp.maximum(v - 2, 0)] != group))
+        only = first & ((v == last_visit) | (group_ids[jnp.minimum(v + 1, last_visit)] != group))
+
+        @pl.when(v == 0)
+        def _():
+            matrix_copy(group, slot).start()
+
+        @pl.when(first)
+        def _():
+            matrix_copy(group, slot).wait()
+
+        # The other slot is free from this group's first visit on. Not there: it is
+        # a short one where the group starts inside a tile, and the copy would
+        # delay the next tile's rows.
+        @pl.when((second | only) & (nexts[group] < rhs_hbm.shape[0]))
+        def _():
+            matrix_copy(nexts[group], 1 - slot).start()
+
+        def multiply(window, mine):
+            contract = (((1,), (1,)), ((), ())) if transpose_rhs else (((1,), (0,)), ((), ()))
+            acc = jax.lax.dot_general(lhs_ref[window, :], rhs_buf[slot], contract,
+                                      preferred_element_type=jnp.float32)
+            # The rows of other groups in this tile were stored by the visits
+            # before this one, or will be by the ones after it.
+            out_ref[window, :] = jnp.where(mine, acc.astype(out_ref.dtype), out_ref[window, :])
+
+        _for_live_window(tile_ids[v], starts[group], ends[group], rows, SUB_ROWS, multiply)
 
 
-def _gmm(lhs, rhs, group_sizes, rows: int, out_tile: int, transpose_rhs: bool, interpret: bool):
-    m, contraction = lhs.shape
-    g = rhs.shape[0]
-    out_dim = rhs.shape[1] if transpose_rhs else rhs.shape[2]
-    if transpose_rhs:
-        rhs_spec = pl.BlockSpec((1, out_tile, contraction), lambda j, v, gi, ti, *_: (gi[v], j, 0))
-    else:
-        rhs_spec = pl.BlockSpec((1, contraction, out_tile), lambda j, v, gi, ti, *_: (gi[v], 0, j))
+@functools.lru_cache(maxsize=None)
+def _gmm_call(m: int, contraction: int, g: int, out_dim: int, dtype, rows: int, out_tile: int,
+              transpose_rhs: bool, interpret: bool):
+    """The `pallas_call` for these shapes, made once a process: a call of the same
+    object again reuses the kernel's traced body, where a new `pallas_call` traces
+    all its windows' products anew at each of a step's nine sites, every time a
+    run lowers the step (PERF.md section 6, PR 33: 5.5 s of a warm set-up)."""
+    matrix_block = (out_tile, contraction) if transpose_rhs else (contraction, out_tile)
     return pl.pallas_call(
-        functools.partial(_gmm_kernel, rows=rows, transpose_rhs=transpose_rhs),
+        functools.partial(_gmm_kernel, rows=rows, out_tile=out_tile, transpose_rhs=transpose_rhs),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=5,
+            num_scalar_prefetch=7,
             grid=(out_dim // out_tile, m // rows + g),
             in_specs=[
                 pl.BlockSpec((rows, contraction), lambda j, v, gi, ti, *_: (ti[v], 0)),
-                rhs_spec,
+                pl.BlockSpec(memory_space=pl.ANY),
             ],
             out_specs=pl.BlockSpec((rows, out_tile), lambda j, v, gi, ti, *_: (ti[v], j)),
+            scratch_shapes=[pltpu.VMEM((2, *matrix_block), dtype), pltpu.SemaphoreType.DMA((2,))],
         ),
-        out_shape=jax.ShapeDtypeStruct((m, out_dim), lhs.dtype),
+        out_shape=jax.ShapeDtypeStruct((m, out_dim), dtype),
         interpret=interpret,
         name="gmm_dlhs" if transpose_rhs else "gmm_fwd",
         compiler_params=None if interpret else pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
-    )(*_visits(group_sizes, m, rows, visit_empty=False), lhs, rhs)
+    )
+
+
+def _gmm(lhs, rhs, group_sizes, rows: int, out_tile: int, transpose_rhs: bool, interpret: bool):
+    call = _gmm_call(*lhs.shape, rhs.shape[0], rhs.shape[1 if transpose_rhs else 2], lhs.dtype,
+                     rows, out_tile, transpose_rhs, interpret)
+    return call(*_visits(group_sizes, lhs.shape[0], rows, False), *_matrix_slots(group_sizes), lhs, rhs)
 
 
 # --------------------------------------------------------------------------- drhs
 def _drhs_kernel(group_ids, tile_ids, starts, ends, num, lhs_ref, dout_ref, out_ref, acc, *,
                  rows: int):
     v = pl.program_id(2)
-    last_visit = num[0] - 1
     group = group_ids[v]
+    new_group = (v == 0) | (group_ids[jnp.maximum(v - 1, 0)] != group)
 
-    @pl.when(v <= last_visit)
+    # `out_ref` is the block of the visit before (`_drhs`): that group is complete
+    # where this visit starts another, or is the first past the last real one.
+    @pl.when((v > 0) & (new_group | (v == num[0])))
     def _():
-        @pl.when((v == 0) | (group_ids[jnp.maximum(v - 1, 0)] != group))
+        out_ref[0] = acc[...].astype(out_ref.dtype)
+
+    @pl.when(v < num[0])
+    def _():
+        @pl.when(new_group)
         def _():
             acc[...] = jnp.zeros_like(acc)
 
-        mine = _row_mask(tile_ids[v], starts[group], ends[group], rows)
-        lhs = jnp.where(mine, lhs_ref[...], jnp.zeros_like(lhs_ref[...]))
-        acc[...] += jax.lax.dot_general(lhs, dout_ref[...], (((0,), (0,)), ((), ())),
-                                        preferred_element_type=jnp.float32)
+        def contract(window, mine):
+            lhs = lhs_ref[window, :]
+            acc[...] += jax.lax.dot_general(
+                jnp.where(mine, lhs, jnp.zeros_like(lhs)), dout_ref[window, :],
+                (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
 
-        @pl.when((v == last_visit) | (group_ids[jnp.minimum(v + 1, last_visit)] != group))
-        def _():
-            out_ref[0] = acc[...].astype(out_ref.dtype)
+        _for_live_window(tile_ids[v], starts[group], ends[group], rows, DRHS_SUB_ROWS, contract)
 
 
-def _drhs(lhs, dout, group_sizes, g: int, rows: int, tile_k: int, tile_n: int, interpret: bool):
-    m, k = lhs.shape
-    n = dout.shape[1]
+@functools.lru_cache(maxsize=None)
+def _drhs_call(m: int, k: int, n: int, g: int, dtype, rows: int, tile_k: int, tile_n: int,
+               interpret: bool):
+    """As `_gmm_call`: one `pallas_call` object for these shapes."""
     return pl.pallas_call(
         functools.partial(_drhs_kernel, rows=rows),
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -185,15 +292,22 @@ def _drhs(lhs, dout, group_sizes, g: int, rows: int, tile_k: int, tile_n: int, i
                 pl.BlockSpec((rows, tile_k), lambda i, j, v, gi, ti, *_: (ti[v], i)),
                 pl.BlockSpec((rows, tile_n), lambda i, j, v, gi, ti, *_: (ti[v], j)),
             ],
-            out_specs=pl.BlockSpec((1, tile_k, tile_n), lambda i, j, v, gi, ti, *_: (gi[v], i, j)),
+            # One visit late: a group's block is written back after the visit that follows its last.
+            out_specs=pl.BlockSpec((1, tile_k, tile_n),
+                                   lambda i, j, v, gi, ti, *_: (gi[jnp.maximum(v - 1, 0)], i, j)),
             scratch_shapes=[pltpu.VMEM((tile_k, tile_n), jnp.float32)],
         ),
-        out_shape=jax.ShapeDtypeStruct((g, k, n), lhs.dtype),
+        out_shape=jax.ShapeDtypeStruct((g, k, n), dtype),
         interpret=interpret,
         name="gmm_drhs",
         compiler_params=None if interpret else pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-    )(*_visits(group_sizes, m, rows, visit_empty=True), lhs, dout)
+    )
+
+
+def _drhs(lhs, dout, group_sizes, g: int, rows: int, tile_k: int, tile_n: int, interpret: bool):
+    call = _drhs_call(*lhs.shape, dout.shape[1], g, lhs.dtype, rows, tile_k, tile_n, interpret)
+    return call(*_visits(group_sizes, lhs.shape[0], rows, True), lhs, dout)
 
 
 # --------------------------------------------------------------------- the product

@@ -47,9 +47,12 @@ PARENT = {
                     "output": 4259343360, "alias": 4259341312},
     "gpt2-xl-fsdp4": {"instructions": 3710, "argument": 4714580992, "temp": 9007949312,
                       "output": 4714564608, "alias": 4714562560},
-    # Pinned at PR 32, which applies the router's weight inside SwiGLU's fusion: one
-    # `[65536, 2048]` bf16 residual fewer than the 5,329 instructions / 4,008,547,328 B before it.
-    "olmoe-1b-7b-l1": {"instructions": 5324, "argument": 7507437568, "temp": 3740107776,
+    # Pinned at PR 33. PR 32 (the router's weight inside SwiGLU's fusion) left 5,324 instructions /
+    # 3,740,107,776 B; since PR 33 `gmm_fwd` / `gmm_dlhs` copy their groups' matrices themselves and take
+    # two more scalar arrays for it (`grouped_matmul._matrix_slots`: a cumsum, a reverse cummin and
+    # what XLA makes of them, for each of the six calls): + 85 instructions, + 387,072 B (0.0004 GiB)
+    # of temporaries. The nine Mosaic calls are the same nine (KERNELS).
+    "olmoe-1b-7b-l1": {"instructions": 5409, "argument": 7507437568, "temp": 3740494848,
                        "output": 7507405824, "alias": 7507403776},
 }
 # What each cell's step hands to Mosaic: the tile schedule its two flash kernels run under

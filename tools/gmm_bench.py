@@ -11,7 +11,12 @@ the transposed weights) and `drhs` (the gradient for the weights: per group,
 rows^T x rows), each through `ray_tpu.ops.grouped_matmul` (the three Pallas
 kernels `ray_tpu/models/moe.py` runs) and through `jax.lax.ragged_dot` with its
 own transpose rules (the form the kernels replaced). `pct_of_peak` is 2 * rows * k * n
-FLOPs over the time, as a share of the chip's bf16 peak. Group sizes are a
+FLOPs over the time, as a share of the chip's bf16 peak. For the repo's kernels
+`issued_over_needed` is the rows of products a call issues over `rows` (a block
+of rows that two groups share is multiplied once for each: `issued_rows`; on a
+tree before PR 33 a visit multiplied its whole row tile, and the same count is
+taken at the tile's size), and `pct_of_peak_issued` the share of the peak on
+that issued work: what the MXU does with what it is given. Group sizes are a
 seeded multinomial draw (`even`: what random routing gives) and one with a
 fifth of the rows on one expert (`skewed`). `--references` adds jax's own
 Pallas grouped matmul (`jax.experimental.pallas.ops.tpu.megablox`, a few tile
@@ -20,7 +25,8 @@ own could reach. One JSON line per shape, product and implementation, on
 stdout and in `chiprun_out/gmm_bench.jsonl`.
 
 Runs on TPU chips only. No benchmark cell and no test runs this; it is how the
-table in PERF.md (section 6, PR 28) is measured again.
+table in PERF.md (section 6, PR 28 and PR 33) is measured again. A copy of the
+file dropped into an older checkout measures that checkout.
 """
 
 from __future__ import annotations
@@ -47,6 +53,19 @@ def group_sizes(rows: int, groups: int, skewed: bool, seed: int = 0):
     if skewed:
         p[0] = 0.25 * (groups - 1)  # a fifth of all rows
     return np.random.default_rng(seed).multinomial(rows, p / p.sum()).astype(np.int32)
+
+
+def issued_over_needed(product: str, sizes, rows: int, k: int, n: int) -> float:
+    """Rows of products `grouped_matmul`'s kernel for `product` issues, over `rows`."""
+    from ray_tpu.ops import grouped_matmul as gm
+
+    if hasattr(gm, "issued_rows"):
+        return gm.issued_rows(sizes, gm.DRHS_SUB_ROWS if product == "drhs" else gm.SUB_ROWS) / rows
+    # Before PR 33: every (group, row tile) pair multiplied the whole tile.
+    tiles = gm._tiles(rows, k, n, 2)
+    tile = tiles.drhs_rows if product == "drhs" else tiles.rows
+    ends = sizes.cumsum()
+    return float((-(-ends // tile) - (ends - sizes) // tile)[sizes > 0].sum()) * tile / rows
 
 
 def main(argv=None):
@@ -117,7 +136,8 @@ def main(argv=None):
         for name, gmm in implementations():
             for product, fn in products(gmm).items():
                 for routing in ("even", "skewed"):
-                    sizes = jnp.asarray(group_sizes(rows, groups, routing == "skewed"))
+                    sizes_np = group_sizes(rows, groups, routing == "skewed")
+                    sizes = jnp.asarray(sizes_np)
                     xs = (lhs, rhs, sizes) + ((dout,) if product != "fwd" else ())
                     line = {"shape": [rows, k, n, groups], "dtype": "bfloat16", "product": product,
                             "implementation": name, "routing": routing,
@@ -126,6 +146,10 @@ def main(argv=None):
                         us = timed(fn, *xs)
                         line.update(us=round(us, 1), pct_of_peak=round(100 * flops / (us * 1e-6) / peak, 1),
                                     rounds=args.rounds, calls=args.calls, device=device)
+                        if name == "grouped_matmul":
+                            issued = issued_over_needed(product, sizes_np, rows, k, n)
+                            line.update(issued_over_needed=round(issued, 4),
+                                        pct_of_peak_issued=round(line["pct_of_peak"] * issued, 1))
                     except Exception as e:  # a tile size the compiler refuses: say so, go on
                         line["error"] = f"{type(e).__name__}: {e}"[:300]
                     emit(line)
