@@ -6,8 +6,10 @@ order, and the SwiGLU experts run as three grouped matmuls over the ragged
 groups of rows (`ops/grouped_matmul.py`). The router's probability of each
 pair is applied to its row where SwiGLU's output is written, the operand of
 the down projection (`w * (a @ W) = (w * a) @ W`, row by row), so the results
-are only put back in token order and summed per token: no pass over the
-`tokens * k` rows exists for the weighting alone, forward or backward. There
+are only summed per token (`ops/sum_rows.py`: on the TPU a kernel that reads
+each token's rows where the sort left them and never writes them in token
+order): no pass over the `tokens * k` rows exists for the weighting alone,
+forward or backward. There
 is no capacity and no dropped token, and no tensor with both a token and an
 expert-slot axis: work and memory are linear in tokens (the Switch layer this
 replaces went through dense `(B, S, E, C)` one-hots, three times the experts'
@@ -29,41 +31,40 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.ops.grouped_matmul import grouped_matmul
+from ray_tpu.ops.sum_rows import sorted_runs, sum_rows
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _gather_rows(x, order, inverse, k: int):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _gather_rows(x, order, inverse, runs, k: int):
     """Row `order[i] // k` of `x` for every i: the tokens in expert order.
     `order` is a permutation of the `tokens * k` pairs and `inverse` undoes
-    it, so the gradient is a gather too (`_sum_rows`), where the transpose
-    jax would derive is a scatter-add of `tokens * k` rows."""
+    it, so the gradient sums each token's `k` rows where they lie
+    (`_sum_rows`; `runs` says where, `ops/sum_rows.py sorted_runs`), where the
+    transpose jax would derive is a scatter-add of `tokens * k` rows."""
     return x[order // k]
 
 
-def _gather_rows_fwd(x, order, inverse, k):
-    return x[order // k], (order, inverse)
+def _gather_rows_fwd(x, order, inverse, runs, k):
+    return x[order // k], (order, inverse, runs)
 
 
 def _gather_rows_bwd(k, res, g):
-    order, inverse = res
-    return _sum_rows(g, order, inverse, k), None, None
+    return _sum_rows(g, *res, k), None, None, None
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _sum_rows(rows, order, inverse, k: int):
-    """The transpose of `_gather_rows`: rows back in token order, each
-    token's `k` rows summed (in float32)."""
-    by_token = rows[inverse].reshape(-1, k, rows.shape[-1])
-    return by_token.astype(jnp.float32).sum(axis=1).astype(rows.dtype)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _sum_rows(rows, order, inverse, runs, k: int):
+    """The transpose of `_gather_rows`: each token's `k` rows summed (in
+    float32), the sums in token order."""
+    return sum_rows(rows, inverse, runs, k)
 
 
-def _sum_rows_fwd(rows, order, inverse, k):
-    return _sum_rows(rows, order, inverse, k), (order, inverse)
+def _sum_rows_fwd(rows, order, inverse, runs, k):
+    return sum_rows(rows, inverse, runs, k), (order, inverse, runs)
 
 
 def _sum_rows_bwd(k, res, g):
-    order, inverse = res
-    return _gather_rows(g, order, inverse, k), None, None
+    return _gather_rows(g, *res, k), None, None, None
 
 
 @jax.custom_vjp
@@ -146,7 +147,8 @@ def moe_mlp(
         aux["experts"] = experts
     with jax.named_scope("dispatch"):
         order, inverse = expert_order(experts)
-        rows = _gather_rows(tokens, order, inverse, k)  # (T * k, D), expert order
+        runs = sorted_runs(experts, router_w.shape[-1])  # where each block of tokens' rows lie
+        rows = _gather_rows(tokens, order, inverse, runs, k)  # (T * k, D), expert order
         row_weights = _sort_weights(weights.reshape(-1), order, inverse)  # (T * k,) f32
         sizes = aux["tokens_per_expert"]
         # A grouped matmul gives row i to the group the running sum of `sizes`
@@ -160,7 +162,7 @@ def moe_mlp(
         act = jax.nn.silu(gate.astype(jnp.float32)) * up.astype(jnp.float32) * row_weights[:, None]
         rows = grouped_matmul(act.astype(cdt), w_down.astype(cdt), sizes)
     with jax.named_scope("combine"):
-        out = _sum_rows(rows, order, inverse, k)
+        out = _sum_rows(rows, order, inverse, runs, k)
     return out.reshape(B, S, D), aux
 
 

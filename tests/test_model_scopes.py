@@ -8,7 +8,7 @@ carries a name into the four named phases, and the ahead-of-time `v5e:2x2`
 compile of the benchmark's three configurations is the pinned program: same
 instruction count, same `memory_analysis()`, the same Mosaic calls, every
 block weight gathered over ICI in dense tiles, and the expert layer moving
-its `tokens * k` sorted rows only to permute or to sum them.
+its `tokens * k` sorted rows only to permute them or, in a kernel, to sum them.
 
 What reads the names is `benchmark/harness/program_trace.py`; its `phase`
 rules are used here, so the model's names and their reader cannot drift.
@@ -51,17 +51,23 @@ PARENT = {
     # 3,740,107,776 B; since PR 33 `gmm_fwd` / `gmm_dlhs` copy their groups' matrices themselves and take
     # two more scalar arrays for it (`grouped_matmul._matrix_slots`: a cumsum, a reverse cummin and
     # what XLA makes of them, for each of the six calls): + 85 instructions, + 387,072 B (0.0004 GiB)
-    # of temporaries. The nine Mosaic calls are the same nine (KERNELS).
-    "olmoe-1b-7b-l1": {"instructions": 5409, "argument": 7507437568, "temp": 3740494848,
+    # of temporaries, 5,409 / 3,740,494,848 B. Pinned again at PR 34: the two `rows[inverse]` gathers
+    # and the two sums of a token's 8 rows are two calls of the `sum_rows` kernel; what it walks
+    # (`ops/sum_rows.py sorted_runs`, a dozen small operations on the routing, once a step) and the
+    # two `(k, tokens)` transposes of `inverse` are + 195 instructions and + 781,312 B (0.0007 GiB)
+    # of temporaries: the 268 MB token-order copy is gone, but the step's peak is not where it was.
+    "olmoe-1b-7b-l1": {"instructions": 5604, "argument": 7507437568, "temp": 3741276160,
                        "output": 7507405824, "alias": 7507403776},
 }
 # What each cell's step hands to Mosaic: the tile schedule its two flash kernels run under
-# (head_dim 64 at 1,024 positions; head_dim 128 at 4,096), and the grouped-matmul kernels
-# beside them (three products, each forward and for both gradients).
+# (head_dim 64 at 1,024 positions; head_dim 128 at 4,096), and the expert layer's kernels
+# beside them: the grouped matmuls (three products, each forward and for both gradients) and,
+# since PR 34, `sum_rows` (`combine` forward, and backward as the gradient of `dispatch`).
 KERNELS = {
-    "gpt2-medium": {"tiles": "tiles_3of4", "gmm": {}},
-    "gpt2-xl-fsdp4": {"tiles": "tiles_3of4", "gmm": {}},
-    "olmoe-1b-7b-l1": {"tiles": "tiles_36of64", "gmm": {"gmm_fwd": 3, "gmm_dlhs": 3, "gmm_drhs": 3}},
+    "gpt2-medium": {"tiles": "tiles_3of4", "moe": {}},
+    "gpt2-xl-fsdp4": {"tiles": "tiles_3of4", "moe": {}},
+    "olmoe-1b-7b-l1": {"tiles": "tiles_36of64",
+                       "moe": {"gmm_fwd": 3, "gmm_dlhs": 3, "gmm_drhs": 3, "sum_rows": 2}},
 }
 # Temporaries of the steps before PR 30. gpt2-xl-fsdp4 must stay under its own
 # (a cold run peaks 219 MiB from the chip's limit: PERF.md section 7); the
@@ -294,35 +300,43 @@ def test_every_block_weight_crosses_ici_in_dense_tiles(aot):
 
 @pytest.mark.parametrize("cell", sorted(PARENT))
 def test_one_kernel_under_flash_fwd_one_under_flash_bwd_and_all_phases(aot, cell):
-    kernel = {n: n.split("/")[-2] for n in aot[cell]["mosaic_scopes"]}  # .../<name>/pallas_call
-    fwd, bwd = sorted((n for n, name in kernel.items() if not name.startswith("gmm_")),
-                      key=lambda n: "flash_bwd" in n)
-    assert "flash_fwd" in fwd.split("/") and phase(fwd) == "forward"
-    assert "flash_bwd" in bwd.split("/") and phase(bwd) == "backward"
+    scopes = aot[cell]["mosaic_scopes"]
+    kernel = [n.split("/")[-2] for n in scopes]  # .../<name>/pallas_call
+    (fwd,), (bwd,) = ([n for n, name in zip(scopes, kernel) if name == flash]
+                      for flash in ("flash_fwd", "flash_bwd"))
+    assert phase(fwd) == "forward" and phase(bwd) == "backward"
     # ... each inside the scope that says which tile schedule it runs.
     tiles = KERNELS[cell]["tiles"]
     assert tiles in fwd.split("/") and tiles in bwd.split("/")
-    gmm = [kernel[n] for n in aot[cell]["mosaic_scopes"] if kernel[n].startswith("gmm_")]
-    assert {name: gmm.count(name) for name in gmm} == KERNELS[cell]["gmm"]
-    assert len(aot[cell]["mosaic_scopes"]) == 2 + len(gmm)
+    moe = [name for name in kernel if not name.startswith("flash_")]
+    assert {name: moe.count(name) for name in moe} == KERNELS[cell]["moe"]
+    # `sum_rows` runs once forward, as `combine`, and once backward, as `dispatch`'s gradient.
+    summing = sorted((phase(n), {"dispatch", "combine"} & set(re.split(r"[/()]", n)))
+                     for n, name in zip(scopes, kernel) if name == "sum_rows")
+    assert summing == ([("backward", {"dispatch"}), ("forward", {"combine"})] if moe else [])
     assert aot[cell]["phases"] == sorted(PHASES)
 
 
 @pytest.mark.parametrize("what, count", [
-    ("gather", 4), ("reduce_sum", 2), ("anything else", 0), ("backward scatter-add", 0)])
+    ("gather", 2), ("reduce_sum", 0), ("pallas_call", 2), ("anything else", 0),
+    ("backward scatter-add", 0)])
 def test_the_sorted_rows_are_only_permuted_and_summed(aot, what, count):
     """The 65,536 x 2,048 sorted rows of the OLMoE cell cross memory under
-    `dispatch` / `combine` in four gathers (tokens into expert order and
-    results back, each with its gradient) and two sums of a token's 8 rows,
-    and in nothing else: the router's weight is applied where SwiGLU's output
-    is written (`models/moe.py`), so no pass exists for the weighting, and the
-    weight's gradient goes back to `(tokens, k)` by a gather, not by a
-    scatter-add of 65,536 updates. Before PR 32: a `convert_element_type`
-    pass forward, `reduce_sum` three times, one `scatter-add`."""
+    `dispatch` / `combine` in two gathers (tokens into expert order, and the
+    gradient of the results likewise) and two calls of the `sum_rows` kernel
+    (the results' sums per token, and the gradient of the tokens), and in
+    nothing else. Until PR 34 the sums were a gather by `inverse` that wrote
+    the 65,536 rows in token order and a `reduce_sum` over a token's 8 that
+    read them again: four gathers, two `reduce_sum`. The router's weight is
+    applied where SwiGLU's output is written (`models/moe.py`), so no pass
+    exists for the weighting, and the weight's gradient goes back to
+    `(tokens, k)` by a gather, not by a scatter-add of 65,536 updates. Before
+    PR 32: a `convert_element_type` pass forward, `reduce_sum` three times,
+    one `scatter-add`."""
     moved, scatter_adds = (aot["olmoe-1b-7b-l1"][key]
                            for key in ("sorted_rows_moved", "backward_scatter_adds"))
     got = {"anything else": [n for kind, names in moved.items()
-                             if kind not in ("gather", "reduce_sum") for n in names],
+                             if kind not in ("gather", "reduce_sum", "pallas_call") for n in names],
            "backward scatter-add": scatter_adds}.get(what, moved.get(what, []))
     assert len(got) == count, (what, moved, scatter_adds)
 

@@ -1,0 +1,145 @@
+"""The `sum_rows` kernel of `ops/sum_rows.py` in interpret mode against its XLA
+form (a gather by `inverse`, a float32 sum of each token's k rows): every k and
+routing, the pieces `sorted_runs` hands it, what it does with rows that are not
+a token's own, the rows its copies move, and `moe_mlp`'s gradients through it."""
+
+import functools
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.experimental.pallas import tpu as pltpu
+
+from ray_tpu.models import moe
+from ray_tpu.ops import sum_rows as sr
+
+TOKENS, WIDTH, EXPERTS = 3 * sr.BLOCK, 128, 8
+
+
+def _routing(name, k, tokens=TOKENS, seed=0):
+    """(tokens, k) int32: each token's experts (the kernel does not ask that they differ)."""
+    rng = np.random.default_rng(seed)
+    if name == "even":
+        experts = rng.integers(0, EXPERTS, (tokens, k))
+    elif name == "skewed":  # half of all pairs on expert 2
+        experts = np.where(rng.random((tokens, k)) < 0.5, 2, rng.integers(0, EXPERTS, (tokens, k)))
+    elif name == "an_expert_with_no_pair":  # 3 and the last get none; 0 gets one pair
+        experts = rng.choice([1, 2, 4, 5, 6], (tokens, k))
+        experts[tokens // 2, 0] = 0
+    elif name == "every_pair_on_one_expert":
+        experts = np.full((tokens, k), 5)
+    return experts.astype(np.int32)
+
+
+def _operands(routing, k, dtype, tokens=TOKENS, width=WIDTH):
+    experts = jnp.asarray(_routing(routing, k, tokens))
+    _, inverse = moe.expert_order(experts)
+    rows = jax.random.normal(jax.random.PRNGKey(k), (tokens * k, width), jnp.float32).astype(dtype)
+    return rows, inverse, experts
+
+
+ROUTINGS = ("even", "skewed", "an_expert_with_no_pair", "every_pair_on_one_expert")
+
+
+@pytest.mark.parametrize("dtype", (jnp.bfloat16, jnp.float32), ids=("bf16", "f32"))
+@pytest.mark.parametrize("routing", ROUTINGS)
+@pytest.mark.parametrize("k", (1, 2, 8))
+def test_the_kernel_sums_what_the_xla_form_sums(k, routing, dtype):
+    rows, inverse, experts = _operands(routing, k, dtype)
+    got = sr.sum_rows(rows, inverse, sr.sorted_runs(experts, EXPERTS), k, backend="pallas", interpret=True)
+    want = sr.xla_sum_rows(rows, inverse, k)
+    assert got.dtype == dtype and got.shape == (TOKENS, WIDTH)
+    # The same k float32 terms, added in another order: the last bit of the rounded sum may differ.
+    np.testing.assert_allclose(np.asarray(got, np.float32), np.asarray(want, np.float32),
+                               rtol=2 ** -7 if dtype == jnp.bfloat16 else 1e-6, atol=1e-6)
+    if k == 1:  # a permutation: nothing is added
+        np.testing.assert_array_equal(np.asarray(got, np.float32), np.asarray(want, np.float32))
+
+
+@pytest.mark.parametrize("routing", ROUTINGS)
+@pytest.mark.parametrize("k", (1, 2, 8))
+def test_the_pieces_cover_each_blocks_rows_once_and_rows_read_counts_them(k, routing):
+    experts = _routing(routing, k)
+    runs = sr.sorted_runs(jnp.asarray(experts), EXPERTS)
+    count, tile, lo, hi = (np.asarray(a) for a in runs)
+    _, inverse = moe.expert_order(jnp.asarray(experts))
+    inverse = np.asarray(inverse).reshape(TOKENS // sr.BLOCK, sr.BLOCK * k)
+    per_block = tile.shape[0] // count.shape[0]
+    brute_force = 0
+    for b, n in enumerate(count):
+        at = slice(b * per_block, (b + 1) * per_block)
+        assert not tile[at][n:].any() and not lo[at][n:].any() and not hi[at][n:].any()
+        owned = np.concatenate([t * sr.PIECE + np.arange(l, h)
+                                for t, l, h in zip(tile[at][:n], lo[at][:n], hi[at][:n])])
+        assert sorted(owned) == sorted(inverse[b])  # every row of the block, none twice
+        by_expert = experts[b * sr.BLOCK:(b + 1) * sr.BLOCK].reshape(-1)
+        brute_force += sum(len(set(inverse[b][by_expert == e] // sr.PIECE)) for e in range(EXPERTS))
+    assert sr.rows_read(experts, sr.PIECE) == brute_force * sr.PIECE == count.sum() * sr.PIECE
+    assert TOKENS * k <= brute_force * sr.PIECE <= TOKENS * k + 2 * sr.PIECE * count.shape[0] * EXPERTS
+    # The kernel reads whole chunks: a block's last one is filled up from tile 0.
+    chunk = sr.chunk_rows(WIDTH, 2)
+    assert sr.rows_read(experts, chunk) == sum(-(-n * sr.PIECE // chunk) * chunk for n in count)
+
+
+@pytest.mark.parametrize("poisoned", ("rows_of_other_blocks", "the_stage_before_the_call"))
+@pytest.mark.parametrize("k", (2, 8))
+def test_what_is_not_a_tokens_own_adds_nothing(k, poisoned):
+    """A copy moves whole `PIECE`-row tiles, so a block's stage holds rows of
+    other blocks; and what a stage held before must not matter either. Neither
+    may reach a sum, not even as `0 x NaN`."""
+    rows, inverse, experts = _operands("even", k, jnp.bfloat16)
+    want = np.asarray(sr.xla_sum_rows(rows, inverse, k), np.float32)
+    runs = sr.sorted_runs(experts, EXPERTS)
+    if poisoned == "rows_of_other_blocks":
+        own = np.zeros(TOKENS * k, bool)
+        own[np.asarray(inverse)[sr.BLOCK * k:2 * sr.BLOCK * k]] = True  # the second block's rows
+        rows = jnp.where(own[:, None], rows, jnp.nan)
+        got = sr.sum_rows(rows, inverse, runs, k, backend="pallas", interpret=True)
+        got, want = (np.asarray(a, np.float32)[sr.BLOCK:2 * sr.BLOCK] for a in (got, want))
+    else:  # TPU interpret mode: scratch memory starts as NaN
+        got = sr.sum_rows(rows, inverse, runs, k, backend="pallas",
+                          interpret=pltpu.InterpretParams(uninitialized_memory="nan"))
+        got = np.asarray(got, np.float32)
+    assert np.isfinite(got).all()
+    np.testing.assert_allclose(got, want, rtol=2 ** -7)
+
+
+@pytest.mark.parametrize("tokens, width", [(sr.BLOCK + 8, 128), (sr.BLOCK, 64), (sr.BLOCK, 192)], ids=(
+    "tokens_not_in_whole_blocks", "a_width_under_128", "a_width_of_no_whole_lane_tiles"))
+def test_shapes_that_do_not_tile_take_the_xla_form(tokens, width):
+    rows, inverse, experts = _operands("even", 2, jnp.bfloat16, tokens, width)
+    runs = sr.sorted_runs(experts, EXPERTS)
+    assert (runs is None) == (tokens % sr.BLOCK != 0)
+    got = sr.sum_rows(rows, inverse, runs, 2)
+    np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                  np.asarray(sr.xla_sum_rows(rows, inverse, 2), np.float32))
+    with pytest.raises(ValueError, match="does not tile"):
+        sr.sum_rows(rows, inverse, runs, 2, backend="pallas")
+
+
+@functools.lru_cache(maxsize=None)
+def _moe_gradients(k, through_the_kernel):
+    """`moe_mlp`'s output and gradients (input and every weight) with `combine`
+    and `dispatch`'s gradient through the kernel, or through the XLA form."""
+    d, f = 128, 64
+    keys = jax.random.split(jax.random.PRNGKey(k), 6)
+    x = jax.random.normal(keys[0], (2, sr.BLOCK, d))
+    cotangent = jax.random.normal(keys[1], x.shape)
+    weights = (jax.random.normal(keys[2], (d, EXPERTS)), jax.random.normal(keys[3], (EXPERTS, d, f)) / d ** 0.5,
+               jax.random.normal(keys[4], (EXPERTS, d, f)) / d ** 0.5,
+               jax.random.normal(keys[5], (EXPERTS, f, d)) / f ** 0.5)
+    backend = dict(backend="pallas", interpret=True) if through_the_kernel else dict(backend="xla")
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(moe, "sum_rows", functools.partial(sr.sum_rows, **backend))
+        out, vjp = jax.vjp(lambda x, *w: moe.moe_mlp(x, *w, k=k)[0], x, *weights)
+        return [np.asarray(a) for a in (out, *vjp(cotangent))]
+
+
+@pytest.mark.parametrize("what", range(6), ids=("out", "x", "router_w", "w_gate", "w_up", "w_down"))
+@pytest.mark.parametrize("k", (1, 8))
+def test_moe_mlp_and_its_gradients_are_the_same_through_the_kernel(k, what):
+    got, want = _moe_gradients(k, True)[what], _moe_gradients(k, False)[what]
+    assert np.abs(want).max() > 1e-3
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
