@@ -1,0 +1,7 @@
+"""`kernels.gmm_ms` in `lfm2-24b-a2b-ep8-l5.fed4k`: that entry lists its cells and a later cell cannot
+append itself, so the cell brings the same reading under a name of its own."""
+
+from benchmark.layer_metrics import kernels_gmm_ms as listed
+
+META = {**listed.META, "name": "kernels.gmm_ms.lfm2-24b-a2b-ep8-l5"}
+read = listed.read

@@ -7,6 +7,7 @@ from ray_tpu.models.gpt import (
     param_logical_axes,
     train_flops_per_token,
 )
+from ray_tpu.models.lfm2 import LFM2Config
 from ray_tpu.models.llama import LlamaConfig
 from ray_tpu.models.olmoe import OLMoEConfig
 from ray_tpu.models.resnet import ResNetConfig
@@ -21,6 +22,7 @@ from ray_tpu.models.training import (
 
 __all__ = [
     "GPTConfig",
+    "LFM2Config",
     "LlamaConfig",
     "OLMoEConfig",
     "ResNetConfig",

@@ -11,18 +11,49 @@ dispatch between the halves, the scopes `blocks`, `qkv`, `attention` and
 when the mesh has pipeline > 1, the GPipe microbatch schedule with optional
 in-region ring attention (parallel/pipeline.py); then the head's product and
 the causal LM loss.
+
+A model whose layers are not all of one kind hands `apply_stack` a `Pattern`:
+the kinds by name, each with its parts (`qkv_part` None where the kind has no
+attention in its middle and `out_part(x, None, layer, rng)` mixes the
+positions itself), the kinds of the leading layers, of one period and of the
+trailing layers, in the published order. Leading and trailing layers are
+applied once each; the periods are one `lax.scan` whose body is a period's
+layers unrolled, each place in the period with a stack of its own over the
+periods. A stack of identical blocks is the pattern of one kind.
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Any, Callable, Optional, Tuple
+from typing import Any, Callable, Dict, List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
 
-def block(x, layer, config, qkv_part: Callable, out_part: Callable,
+class Pattern(NamedTuple):
+    """A stack whose layers differ in kind. Its parameters are `{"leading":
+    [one tree a layer, ...], "period": [for each place in the period, the tree
+    of the layers at that place, stacked over the periods on a leading axis],
+    "trailing": [one tree a layer, ...]}`: the scan slices every place's
+    stack by the period and nothing is indexed inside its body."""
+    kinds: Dict[str, Tuple[Optional[Callable], Callable]]  # name -> (qkv_part | None, out_part)
+    period: Tuple[str, ...]  # the kinds of one period's layers
+    n_periods: int
+    leading: Tuple[str, ...] = ()  # the kinds of the layers before the first period
+    trailing: Tuple[str, ...] = ()  # ... and after the last
+
+    def layers(self, blocks) -> List[Tuple[str, Any]]:
+        """(kind, the layer's own parameters) of every layer, in the published
+        order: the stack as a plain list, for whoever walks it layer by layer."""
+        out = list(zip(self.leading, blocks["leading"]))
+        for p in range(self.n_periods):
+            out += [(kind, jax.tree.map(lambda a: a[p], place))
+                    for kind, place in zip(self.period, blocks["period"])]
+        return out + list(zip(self.trailing, blocks["trailing"]))
+
+
+def block(x, layer, config, qkv_part: Optional[Callable], out_part: Callable,
           attention_fn: Optional[Callable] = None, mesh=None, streams: tuple = (), rng=None):
     """One block on x (B, S, D): `out_part`'s (x, aux), under the remat the
     config asks for. `remat_policy` None recomputes everything in the block;
@@ -32,14 +63,21 @@ def block(x, layer, config, qkv_part: Callable, out_part: Callable,
     the kernel's lse) are saved, so the backward pass never re-runs the
     attention kernel, the most expensive op per byte saved.
 
+    A block with no attention in its middle (`qkv_part` None) is `out_part`
+    alone, with `o` None: under any remat all of it is recomputed from its
+    input ("save_attn" has nothing of it to save), "dots" keeping its matmul
+    outputs.
+
     Scope names are read from the compiled program's `op_name`s by whoever
     splits a device trace by part of the step (PERF.md, "names")."""
-    save_attn = config.remat and config.remat_policy == "save_attn"
+    save_attn = config.remat and config.remat_policy == "save_attn" and qkv_part is not None
     if save_attn:
         qkv_part = jax.checkpoint(qkv_part, prevent_cse=False)
         out_part = jax.checkpoint(out_part, prevent_cse=False)
 
     def parts(x, layer, streams, rng):
+        if qkv_part is None:
+            return out_part(x, None, layer, rng)
         with jax.named_scope("qkv"):
             q, k, v = qkv_part(x, layer, *streams)
         with jax.named_scope("attention"):
@@ -54,12 +92,13 @@ def block(x, layer, config, qkv_part: Callable, out_part: Callable,
 
 
 def apply_stack(
-    blocks,  # stacked per-layer params, leading dim config.n_layer
+    blocks,  # stacked per-layer params, leading dim config.n_layer; a `Pattern`'s as it says
     x,  # (B, S, D)
     config,  # of any model: n_layer, remat, remat_policy, attention
-    qkv_part: Callable,
-    out_part: Callable,
+    qkv_part: Optional[Callable] = None,
+    out_part: Optional[Callable] = None,
     *,
+    pattern: Optional[Pattern] = None,  # in place of the two parts, where layers differ in kind
     attention_fn: Optional[Callable],
     mesh=None,
     num_microbatches: Optional[int] = None,
@@ -73,19 +112,39 @@ def apply_stack(
     each rank receives its own slice, so global positions stay correct. With
     `layers_rng` (a model with dropout, training), `out_part` gets a key of
     its own for every layer, and under the pipeline for every microbatch."""
-    def block_fn(first_layer, attn, mb_idx, streams, x, xs):
-        """The scan's body over (layer_params, idx), once the first four are bound."""
-        layer, idx = xs
+    uniform = pattern is None
+    if uniform:
+        pattern = Pattern({"layer": (qkv_part, out_part)}, ("layer",), config.n_layer)
+        blocks = {"leading": [], "period": [blocks], "trailing": []}
+    per_period = len(pattern.period)
+
+    def one(kind, layer, index, attn, mb_idx, streams, x):
         rng = None
         if layers_rng is not None:
-            rng = jax.random.fold_in(layers_rng, first_layer + idx)
+            rng = jax.random.fold_in(layers_rng, index)
             if mb_idx is not None:
                 # Independent dropout mask per microbatch under PP.
                 rng = jax.random.fold_in(rng, mb_idx)
-        return block(x, layer, config, qkv_part, out_part, attn, mesh, streams, rng)
+        return block(x, layer, config, *pattern.kinds[kind], attn, mesh, streams, rng)
+
+    def period_fn(first_layer, attn, mb_idx, streams, x, xs):
+        """The scan's body over (a period's layers, idx), once the first four
+        are bound: the period's layers in their order."""
+        layers, idx = xs
+        auxs = []
+        for j, (kind, layer) in enumerate(zip(pattern.period, layers)):
+            index = first_layer + (idx if per_period == 1 else idx * per_period + j)
+            x, aux = one(kind, layer, index, attn, mb_idx, streams, x)
+            auxs.append(aux)
+        return x, functools.reduce(jnp.add, auxs)
 
     n_pipeline = int(mesh.shape.get("pipeline", 1)) if mesh is not None else 1
     if n_pipeline > 1:
+        if not uniform:
+            raise NotImplementedError(
+                "a stack whose layers differ in kind (stack.Pattern) cannot be cut into pipeline "
+                f"stages yet (the mesh has pipeline={n_pipeline}): parallel/pipeline.py takes one "
+                "stacked tree of identical layers")
         from ray_tpu.parallel.pipeline import pipeline_apply, to_stages
 
         # Combining PP with CP: the pipeline region is manual over `pipeline`,
@@ -102,9 +161,9 @@ def apply_stack(
         def stack_fn(stage_local, xm, first_layer, mb_idx, streams):
             n_local = config.n_layer // n_pipeline
             xm, auxs = jax.lax.scan(
-                functools.partial(block_fn, first_layer, inner_attn, mb_idx, streams),
+                functools.partial(period_fn, first_layer, inner_attn, mb_idx, streams),
                 xm,
-                (stage_local, jnp.arange(n_local)),
+                ([stage_local], jnp.arange(n_local)),
             )
             return xm, jnp.sum(auxs)
 
@@ -112,17 +171,26 @@ def apply_stack(
         M = num_microbatches or (2 * n_pipeline if B % (2 * n_pipeline) == 0 else n_pipeline)
         with jax.named_scope("blocks"):
             return pipeline_apply(
-                mesh, to_stages(blocks, n_pipeline), x, stack_fn, M,
+                mesh, to_stages(blocks["period"][0], n_pipeline), x, stack_fn, M,
                 context_manual=context_manual,
                 seq_streams=seq_streams,
             )
     with jax.named_scope("blocks"):
-        x, auxs = jax.lax.scan(
-            functools.partial(block_fn, 0, attention_fn, None, seq_streams),
+        n_leading, auxs = len(pattern.leading), []
+        for i, (kind, layer) in enumerate(zip(pattern.leading, blocks["leading"])):
+            x, aux = one(kind, layer, i, attention_fn, None, seq_streams, x)
+            auxs.append(aux)
+        x, of_periods = jax.lax.scan(
+            functools.partial(period_fn, n_leading, attention_fn, None, seq_streams),
             x,
-            (blocks, jnp.arange(config.n_layer)),
+            (blocks["period"], jnp.arange(pattern.n_periods)),
         )
-        return x, jnp.sum(auxs)
+        auxs.append(jnp.sum(of_periods))
+        first_trailing = n_leading + pattern.n_periods * per_period
+        for i, (kind, layer) in enumerate(zip(pattern.trailing, blocks["trailing"])):
+            x, aux = one(kind, layer, first_trailing + i, attention_fn, None, seq_streams, x)
+            auxs.append(aux)
+        return x, functools.reduce(jnp.add, auxs)
 
 
 def resolve_attention(q, k, v, attention_mode: str, attention_fn: Optional[Callable],

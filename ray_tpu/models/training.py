@@ -26,7 +26,10 @@ from ray_tpu.util.tracing import annotate
 def model_for(config):
     """A configuration's model: the module that defines its class (gpt, llama,
     olmoe, resnet, ... or a user's own) and, beside it, what one
-    TrainState/step factory needs of any model."""
+    TrainState/step factory needs of any model. A model that keeps buffers
+    among its parameters (a router's selection bias, ...) also defines
+    `frozen_params(config)`, a tree of bools like the parameters', True where
+    no optimizer step may change the leaf (`make_train_step`)."""
     module = sys.modules[type(config).__module__]
     missing = [name for name in ("init_params", "param_logical_axes", "loss_fn")
                if not callable(getattr(module, name, None))]
@@ -99,6 +102,7 @@ def make_train_step(
     """One fused SPMD update: loss -> grads -> optimizer -> new state."""
 
     base_rng = jax.random.PRNGKey(0x5eed)
+    frozen = getattr(model_for(config), "frozen_params", None)
 
     def step_fn(state: TrainState, batch):
         dropout_rng = (
@@ -117,6 +121,9 @@ def make_train_step(
         loss, grads = jax.value_and_grad(loss_of)(state.params)
         with jax.named_scope("optimizer"):
             updates, new_opt = optimizer.update(grads, state.opt_state, state.params)
+            if frozen is not None:  # a buffer takes no update, a decoupled weight decay's neither
+                updates = jax.tree.map(lambda u, is_buffer: jnp.zeros_like(u) if is_buffer else u,
+                                       updates, frozen(config))
             new_params = optax.apply_updates(state.params, updates)
         new_state = TrainState(
             params=new_params, opt_state=new_opt, step=state.step + 1

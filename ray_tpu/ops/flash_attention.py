@@ -67,6 +67,10 @@ MAX_UNROLLED_HEAD_BYTES = 2048 * 128 * 2
 # scratch beside its tile: from heads of 4096 x 128 in bf16 on, 1024-tiles no
 # longer fit under the 16 MiB (`(2, 16, 4096, 128)` misses by 308 KB in an
 # ahead-of-time v5e compile, PR 28; `(1, 8, 4096, 128)` fits), 512-tiles do.
+# What counts is the VMEM a head takes, and there its last dimension is padded
+# to the 128 lanes: a 4096 x 64 head takes what a 4096 x 128 one does, and
+# `(8, 32, 4096, 64)` compiles in this form alone (PR 35).
+LANES = 128
 LONG_HEAD_SEQ = 4096
 LONG_HEAD_BYTES = 4096 * 128 * 2
 LONG_HEAD_TILE = 512
@@ -74,7 +78,7 @@ NEG_INF = -1e30
 
 
 def _long_head(seq: int, head_dim: int, itemsize: int) -> bool:
-    return seq >= LONG_HEAD_SEQ and seq * head_dim * itemsize >= LONG_HEAD_BYTES
+    return seq >= LONG_HEAD_SEQ and seq * max(head_dim, LANES) * itemsize >= LONG_HEAD_BYTES
 
 
 # --------------------------------------------------------------------------- XLA form

@@ -1,7 +1,8 @@
 """Grouped matrix multiplication for mixture-of-experts layers.
 
 `grouped_matmul(lhs, rhs, group_sizes)`: the rows of `lhs` (m, k) come in
-`g` consecutive groups of `group_sizes` rows (summing to m), and row i is
+`g` consecutive groups of `group_sizes` rows (summing to m, or with
+`short=True` to fewer: the rows past the last group are nobody's), and row i is
 multiplied by the matrix of its group, `rhs[group(i)]` (k, n). What
 `jax.lax.ragged_dot` computes, and off the TPU that is what runs; on the TPU
 three Pallas kernels of the repo's own do, because XLA's lowering of
@@ -41,6 +42,15 @@ least one more than there are), and the ones past the last real visit repeat
 its block indices and multiply nothing. Tile sizes follow from the shapes
 alone (`_tiles`). `issued_rows` counts the rows of products a call issues:
 at most `m + g * sub`, where multiplying whole tiles issued `m + g * tile`.
+
+Rows past the last group (`short=True`: an expert layer that holds some of the
+experts sorts the pairs of the others there, `models/moe.py`) get no visit in
+any of the three kernels: nothing is multiplied for them, `issued_rows` counts
+nothing for them, and they add nothing to a group's `drhs`. Their rows of the
+result (and of `dlhs`) are therefore never written by the kernels and hold
+whatever the buffer held, where `ragged_dot` writes zeros: a caller must not
+read them (`moe_mlp` masks them where it reads a whole array, and `sum_rows`
+is never pointed at them).
 """
 
 from __future__ import annotations
@@ -100,13 +110,15 @@ def _tiles(m: int, k: int, n: int, itemsize: int) -> Optional[Tiles]:
 
 # `inline=True`: traced once for its arguments' shapes and then replayed into
 # the caller's trace, with no call of its own in the program.
-@functools.partial(jax.jit, static_argnums=(1, 2, 3), inline=True)
-def _visits(group_sizes, m: int, tile: int, visit_empty: bool):
+@functools.partial(jax.jit, static_argnums=(1, 2, 3, 4), inline=True)
+def _visits(group_sizes, m: int, tile: int, visit_empty: bool, short: bool = False):
     """The (group, row tile) pairs a kernel walks, in order: `group_ids` and
     `tile_ids` (each `m / tile + g` long; entries past `num` repeat the last
     real visit), the groups' first and one-past-last rows, and `num`, the
     number of real visits. With `visit_empty`, a group of no rows is visited
-    once (at a tile it touches no row of)."""
+    once (at a tile it touches no row of). With `short` the groups may hold
+    fewer than m rows, even none: then there may be no visit at all, and the
+    entries name the last group and a tile inside the array all the same."""
     g = group_sizes.shape[0]
     n_tiles = m // tile
     sizes = group_sizes.astype(jnp.int32)
@@ -119,6 +131,8 @@ def _visits(group_sizes, m: int, tile: int, visit_empty: bool):
     num = visit_ends[-1]
     v = jnp.minimum(jnp.arange(n_tiles + g, dtype=jnp.int32), num - 1)
     group_ids = jnp.searchsorted(visit_ends, v, side="right").astype(jnp.int32)
+    if short:
+        v, group_ids = jnp.maximum(v, 0), jnp.minimum(group_ids, g - 1)
     tile_ids = first[group_ids] + v - (visit_ends - count)[group_ids]
     return group_ids, tile_ids.astype(jnp.int32), starts, ends, num.reshape(1)
 
@@ -245,10 +259,12 @@ def _gmm_call(m: int, contraction: int, g: int, out_dim: int, dtype, rows: int, 
     )
 
 
-def _gmm(lhs, rhs, group_sizes, rows: int, out_tile: int, transpose_rhs: bool, interpret: bool):
+def _gmm(lhs, rhs, group_sizes, rows: int, out_tile: int, transpose_rhs: bool, interpret: bool,
+         short: bool = False):
     call = _gmm_call(*lhs.shape, rhs.shape[0], rhs.shape[1 if transpose_rhs else 2], lhs.dtype,
                      rows, out_tile, transpose_rhs, interpret)
-    return call(*_visits(group_sizes, lhs.shape[0], rows, False), *_matrix_slots(group_sizes), lhs, rhs)
+    visits = _visits(group_sizes, lhs.shape[0], rows, False, short)
+    return call(*visits, *_matrix_slots(group_sizes), lhs, rhs)
 
 
 # --------------------------------------------------------------------------- drhs
@@ -311,21 +327,21 @@ def _drhs(lhs, dout, group_sizes, g: int, rows: int, tile_k: int, tile_n: int, i
 
 
 # --------------------------------------------------------------------- the product
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _pallas_grouped_matmul(lhs, rhs, group_sizes, interpret: bool):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _pallas_grouped_matmul(lhs, rhs, group_sizes, interpret: bool, short: bool = False):
     tiles = _tiles(lhs.shape[0], rhs.shape[1], rhs.shape[2], lhs.dtype.itemsize)
-    return _gmm(lhs, rhs, group_sizes, tiles.rows, tiles.out_fwd, False, interpret)
+    return _gmm(lhs, rhs, group_sizes, tiles.rows, tiles.out_fwd, False, interpret, short)
 
 
-def _fwd_rule(lhs, rhs, group_sizes, interpret):
-    return _pallas_grouped_matmul(lhs, rhs, group_sizes, interpret), (lhs, rhs, group_sizes)
+def _fwd_rule(lhs, rhs, group_sizes, interpret, short):
+    return _pallas_grouped_matmul(lhs, rhs, group_sizes, interpret, short), (lhs, rhs, group_sizes)
 
 
-def _bwd_rule(interpret, res, dout):
+def _bwd_rule(interpret, short, res, dout):
     lhs, rhs, group_sizes = res
     tiles = _tiles(lhs.shape[0], rhs.shape[1], rhs.shape[2], lhs.dtype.itemsize)
     dout = dout.astype(lhs.dtype)
-    dlhs = _gmm(dout, rhs, group_sizes, tiles.rows, tiles.out_dlhs, True, interpret)
+    dlhs = _gmm(dout, rhs, group_sizes, tiles.rows, tiles.out_dlhs, True, interpret, short)
     drhs = _drhs(lhs, dout, group_sizes, rhs.shape[0], tiles.drhs_rows, tiles.drhs_k,
                  tiles.drhs_n, interpret)
     return dlhs, drhs.astype(rhs.dtype), None
@@ -339,10 +355,15 @@ def xla_grouped_matmul(lhs, rhs, group_sizes):
     return jax.lax.ragged_dot(lhs, rhs, group_sizes.astype(jnp.int32))
 
 
-def grouped_matmul(lhs, rhs, group_sizes, backend: Optional[str] = None, interpret: bool = False):
+def grouped_matmul(lhs, rhs, group_sizes, backend: Optional[str] = None, interpret: bool = False,
+                   short: bool = False):
     """`out[i] = lhs[i] @ rhs[group of row i]` for `lhs` (m, k) whose rows lie
     in `g` consecutive groups of `group_sizes` rows, `rhs` (g, k, n).
-    `group_sizes` must sum to m. Differentiable in `lhs` and `rhs`.
+    `group_sizes` must sum to m, or with `short` to m at most: the rows past
+    the last group are multiplied by nothing and add nothing to the gradient
+    of `rhs`; their rows of the result and of the gradient of `lhs` are zeros
+    in the XLA form and unwritten by the kernels: not to be read.
+    Differentiable in `lhs` and `rhs`.
 
     backend: "pallas" | "xla" | None: the kernels where the computation is
     lowered for a TPU and the shapes tile (rows, k and n multiples of 128),
@@ -356,7 +377,7 @@ def grouped_matmul(lhs, rhs, group_sizes, backend: Optional[str] = None, interpr
             "rows, k and n must be multiples of 128")
     if backend == "xla" or not tiled:
         return xla_grouped_matmul(lhs, rhs, group_sizes)
-    pallas = functools.partial(_pallas_grouped_matmul, interpret=interpret)
+    pallas = functools.partial(_pallas_grouped_matmul, interpret=interpret, short=short)
     if backend == "pallas":
         return pallas(lhs, rhs, group_sizes)
     return jax.lax.platform_dependent(lhs, rhs, group_sizes, tpu=pallas, default=xla_grouped_matmul)

@@ -70,11 +70,17 @@ def _max_pieces(k: int, n_experts: int) -> int:
 
 
 # `inline=True`: as `grouped_matmul._visits`.
-@functools.partial(jax.jit, static_argnums=(1,), inline=True)
-def sorted_runs(experts, n_experts: int) -> Optional[Runs]:
+@functools.partial(jax.jit, static_argnums=(1, 2), inline=True)
+def sorted_runs(experts, n_experts: int, some_unowned: bool = False) -> Optional[Runs]:
     """The pieces of the sorted rows that each block of `BLOCK` tokens owns,
     for `experts` (tokens, k), each token's choices: nothing where the tokens
-    do not come in whole blocks (`sum_rows` takes the XLA form then)."""
+    do not come in whole blocks (`sum_rows` takes the XLA form then).
+
+    With `some_unowned`, a choice of `n_experts` or more is nobody's (an
+    expert held on another chip, `models/moe.py`): it has no run, the kernel
+    never reads its row, and it adds nothing to its token's sum. A block may
+    then own no piece at all; it is given one that holds none of its rows, so
+    that every block has a chunk for the block before it to start."""
     tokens, k = experts.shape
     if tokens % BLOCK:
         return None
@@ -99,7 +105,8 @@ def sorted_runs(experts, n_experts: int) -> Optional[Runs]:
     tile = of_run(first - (upto - n)) + jnp.where(run < n_experts, q, 0)
     lo = jnp.clip(of_run(starts) - tile * PIECE, 0, PIECE)
     hi = jnp.clip(of_run(ends) - tile * PIECE, 0, PIECE)
-    return Runs(upto[:, -1], tile.reshape(-1), lo.reshape(-1), hi.reshape(-1))
+    count = jnp.maximum(upto[:, -1], 1) if some_unowned else upto[:, -1]
+    return Runs(count, tile.reshape(-1), lo.reshape(-1), hi.reshape(-1))
 
 
 def rows_read(experts, chunk_rows: int = CHUNK_ROWS[0]) -> int:

@@ -24,22 +24,24 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 # memory_stats()["bytes_limit"] of one v5e chip (chip run, PR 21).
 V5E_HBM_BYTES = 16_909_336_064
 B, S = 16, 1024  # the flagship cell: gpt2_small, batch 16 x seq 1024
+LONG_HEAD_64 = "kernel:8x32x4096x64"  # the lfm2 cell's attention layer: 8 rows, 32 heads of 4096 x 64
 
 
-def _kernel_case(topo):
-    """Forward + fused backward kernel at GPT-2 shapes, one device."""
+def _kernel_case(topo, shape=(B, 12, S, 64)):
+    """Forward + fused backward kernel at GPT-2 shapes (or `shape`), one device."""
     import jax
     import jax.numpy as jnp
 
-    from ray_tpu.ops.flash_attention import flash_attention
+    from ray_tpu.ops.flash_attention import flash_attention, kernel_plan
 
     x = jax.ShapeDtypeStruct(
-        (B, 12, S, 64), jnp.bfloat16,
+        shape, jnp.bfloat16,
         sharding=jax.sharding.SingleDeviceSharding(topo.devices[0]),
     )
     loss = lambda q, k, v: flash_attention(q, k, v, backend="pallas").astype(jnp.float32).sum()
     compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(x, x, x).compile()
-    return {"mosaic_calls": compiled.as_text().count("tpu_custom_call")}
+    return {"mosaic_calls": compiled.as_text().count("tpu_custom_call"),
+            "plan": list(kernel_plan(shape))}
 
 
 def _step_case(topo, axes, compile_it):
@@ -101,6 +103,8 @@ def _main(cases):
     for case in cases:
         if case == "kernel":
             results[case] = _kernel_case(topo)
+        elif case.startswith("kernel:"):
+            results[case] = _kernel_case(topo, tuple(int(n) for n in case[len("kernel:"):].split("x")))
         else:
             verb, mesh = case.split(":")
             results[case] = _step_case(topo, _MESHES[mesh], verb == "compile")
@@ -120,7 +124,7 @@ def _run(cases):
 
 @pytest.fixture(scope="module")
 def aot():
-    return _run(["kernel", "lower:d4", "lower:d2t2"])
+    return _run(["kernel", LONG_HEAD_64, "lower:d4", "lower:d2t2"])
 
 
 def test_topology_is_the_v5e(aot):
@@ -129,6 +133,25 @@ def test_topology_is_the_v5e(aot):
 
 def test_flash_kernels_compile_for_v5e_at_gpt2_shapes(aot):
     assert aot["kernel"]["mosaic_calls"] == 2  # forward, fused backward
+
+
+def test_flash_kernels_compile_for_v5e_at_a_head_of_4096_by_64(aot):
+    """`(8, 32, 4096, 64)` bf16: 64 lanes pad to 128 in VMEM, so the head takes
+    what a 4096 x 128 one does and must run the same form, 512-tiles with the
+    backward pass's whole-head operands single-buffered. Counted by
+    `seq * head_dim` it kept the 1024-tile double-buffered form, and `flash_bwd`
+    failed here with RESOURCE_EXHAUSTED in VMEM at every tile size (PR 35)."""
+    assert aot[LONG_HEAD_64]["mosaic_calls"] == 2  # forward, fused backward
+    assert aot[LONG_HEAD_64]["plan"] == [512, 512, 36, 8, 64, False]
+
+
+def test_the_plans_of_the_other_cells_shapes_are_what_they_were():
+    from ray_tpu.ops.flash_attention import kernel_plan
+
+    assert kernel_plan((8, 16, 1024, 64)) == (512, 512, 3, 2, 4, True)  # gpt2-medium
+    assert kernel_plan((4, 25, 1024, 64)) == (512, 512, 3, 2, 4, True)  # gpt2-xl-fsdp4, a chip
+    assert kernel_plan((2, 16, 4096, 128)) == (512, 512, 36, 8, 64, False)  # olmoe-1b-7b-l1
+    assert kernel_plan((16, 32, 2048, 64)) == (512, 512, 10, 4, 16, True)  # shorter heads of 64: untouched
 
 
 @pytest.mark.parametrize("mesh", ["d4", "d2t2"])

@@ -161,3 +161,62 @@ def test_what_does_not_tile_runs_the_xla_form_or_says_so():
         gm.grouped_matmul(lhs, rhs, sizes, backend="pallas")
     with pytest.raises(ValueError, match="lhs is"):
         gm.grouped_matmul(lhs.astype(jnp.bfloat16), rhs, sizes)
+
+
+# ------------------------------------------------ groups that end before the rows do
+# 1,024 rows of which the groups hold fewer (`short=True`): an expert layer that holds some of
+# the experts sorts the other experts' pairs behind its own groups (`models/moe.py`).
+SHORT = {
+    "an_eighth_held": [60, 40, 0, 28, 0],
+    "held_rows_end_inside_a_tile": [300, 20, 50, 0, 0],
+    "held_rows_end_on_a_tile_edge": [256, 200, 56, 0, 0],
+    "all_but_one_row": [1000, 23, 0, 0, 0],
+    "one_row": [0, 0, 1, 0, 0],
+    "none": [0, 0, 0, 0, 0],
+}
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("grouping", sorted(SHORT))
+def test_rows_past_the_last_group_are_multiplied_by_nothing(grouping, dtype):
+    """Forward and both gradients against `ragged_dot` on the rows the groups
+    hold; the gradient of the matrices gets nothing from the rows past them,
+    although `lhs` and `dout` are not zero there."""
+    sizes = jnp.asarray(SHORT[grouping], jnp.int32)
+    held = sum(SHORT[grouping])
+    lhs, rhs, dout = _operands(jnp.dtype(dtype), 1024)
+
+    def run(backend):
+        out, vjp = jax.vjp(lambda a, b: gm.grouped_matmul(
+            a, b, sizes, backend=backend, interpret=True, short=True), lhs, rhs)
+        return (out, *vjp(dout))
+
+    with jax.default_matmul_precision("highest"):
+        got, want = run("pallas"), run("xla")
+        # What the held rows alone give: the XLA form itself adds nothing for the others.
+        alone = _forward_and_gradients("xla", lhs[:held], rhs, sizes, dout[:held])
+    tol = dict(rtol=1e-4, atol=1e-3) if dtype == "float32" else dict(rtol=2e-2, atol=0.5)
+    f32 = lambda a: np.asarray(a, np.float32)
+    for name, a, b, c in zip(("out", "dlhs"), got, want, alone):
+        np.testing.assert_allclose(f32(a)[:held], f32(b)[:held], err_msg=name, **tol)
+        np.testing.assert_allclose(f32(b)[:held], f32(c), err_msg=name, **tol)
+        assert not f32(b)[held:].any()  # the XLA form writes zeros there; the kernels nothing
+    np.testing.assert_allclose(f32(got[2]), f32(want[2]), err_msg="drhs", **tol)
+    np.testing.assert_allclose(f32(want[2]), f32(alone[2]), err_msg="drhs", **tol)
+
+
+@pytest.mark.parametrize("grouping", sorted(SHORT))
+def test_no_visit_and_no_issued_row_for_the_rows_past_the_last_group(grouping):
+    sizes = np.asarray(SHORT[grouping])
+    held = int(sizes.sum())
+    for tile, sub, visit_empty in ((256, gm.SUB_ROWS, False), (512, gm.DRHS_SUB_ROWS, True)):
+        group_ids, tile_ids, starts, ends, num = (
+            np.asarray(x) for x in gm._visits(jnp.asarray(sizes, jnp.int32), 1024, tile, visit_empty, True))
+        real = slice(0, int(num[0]))
+        # No visit of a tile that lies wholly past the held rows (an empty group's one visit in
+        # `gmm_drhs`, which multiplies nothing, is at the tile its start falls in).
+        assert (tile_ids[real] <= held // tile).all()
+        assert ((0 <= tile_ids) & (tile_ids < 1024 // tile)).all()
+        assert ((0 <= group_ids) & (group_ids < len(sizes))).all()
+        *_, count = gm._live_window(tile_ids[real], starts[group_ids[real]], ends[group_ids[real]], tile, sub)
+        assert int(np.sum(count)) * sub == gm.issued_rows(sizes, sub) <= held + len(sizes) * sub

@@ -32,9 +32,13 @@ from benchmark.harness.program_trace import PHASES, phase, scope_map  # noqa: E4
 # its own parts. HALVES are the parts of a block on either side of `attention`, with what they hold.
 SHARED = ("embed", "blocks", "qkv", "attention", "head", "loss", "optimizer")
 OWN = {"gpt": ("out_mlp",), "llama": ("out_mlp",),
-       "olmoe": ("attn_out", "moe", "router", "dispatch", "experts", "combine")}
+       "olmoe": ("attn_out", "moe", "router", "dispatch", "experts", "combine"),
+       "lfm2": ("attn_out", "moe", "router", "dispatch", "experts", "combine",
+                "short_conv", "conv_mix", "dense_mlp")}
+# For lfm2 also what a block with no attention in its middle holds: all of it is recomputed.
 HALVES = {"gpt": {"qkv", "out_mlp"}, "llama": {"qkv", "out_mlp"},
-          "olmoe": {"qkv", "attn_out", "moe", "router", "experts"}}
+          "olmoe": {"qkv", "attn_out", "moe", "router", "experts"},
+          "lfm2": {"qkv", "attn_out", "moe", "router", "experts", "short_conv", "conv_mix", "dense_mlp"}}
 SCOPES = SHARED + OWN["gpt"] + ("grad_norm",)
 # This tree's programs (ahead-of-time compile for v5e:2x2 on this installation,
 # pinned at PR 30, which stored the attention weights as matrices): instructions
@@ -137,7 +141,8 @@ def _nano_step(model, remat_policy):
 
     from ray_tpu import models
 
-    config = {"gpt": models.GPTConfig, "llama": models.LlamaConfig, "olmoe": models.OLMoEConfig}
+    config = {"gpt": models.GPTConfig, "llama": models.LlamaConfig, "olmoe": models.OLMoEConfig,
+              "lfm2": models.LFM2Config}
     cfg = config[model].nano(remat=remat_policy != "off",
                              remat_policy=None if remat_policy == "off" else remat_policy)
     opt = models.default_optimizer()
@@ -149,7 +154,9 @@ def _nano_step(model, remat_policy):
 @pytest.mark.parametrize("model, remat_policy", [
     ("gpt", "save_attn"), ("gpt", "dots"), ("gpt", "off"),
     # `llama.py` named nothing before PR 31: its step fell into no phase.
-    ("llama", "save_attn"), ("olmoe", "save_attn")])
+    ("llama", "save_attn"), ("olmoe", "save_attn"),
+    # A patterned stack (PR 35): leading layers, a scan over periods, layers with no attention.
+    ("lfm2", "save_attn"), ("lfm2", "off")])
 def test_what_the_nano_step_names_falls_into_the_phases(model, remat_policy):
     """Of the compiled instructions that carry an `op_name` (on the CPU four
     in ten carry none: converts, constants and fusions the compiler made),

@@ -124,7 +124,9 @@ def test_kernel_plan_counts_the_triangle():
     # every non-causal call, walk the largest tile in the loop form as before.
     assert kernel_plan((1, 32, 2048, 128), True) == kernel_plan((1, 8, 2048, 64), True)
     assert kernel_plan((1, 8, 2048, 64), True) == (512, 512, 10, 4, 16, True)
-    assert kernel_plan((1, 8, 4096, 64), True) == (1024, 1024, 10, 4, 16, False)
+    # 64 lanes pad to 128 in VMEM: a 4096 x 64 head is as long as a 4096 x 128 one (PR 35).
+    assert kernel_plan((1, 8, 4096, 64), True) == kernel_plan((1, 8, 4096, 128), True)
+    assert kernel_plan((1, 8, 4096, 64), True) == (512, 512, 36, 8, 64, False)
     assert kernel_plan((1, 8, 2048, 128), True, dtype=jnp.float32) == (1024, 1024, 3, 2, 4, False)
     assert kernel_plan((1, 8, 2048, 128), True, 1024, 1024) == (1024, 1024, 3, 2, 4, False)
     assert kernel_plan((8, 16, 1024, 64), False) == (1024, 1024, 1, 0, 1, False)

@@ -143,3 +143,35 @@ def test_moe_mlp_and_its_gradients_are_the_same_through_the_kernel(k, what):
     got, want = _moe_gradients(k, True)[what], _moe_gradients(k, False)[what]
     assert np.abs(want).max() > 1e-3
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+
+
+# ------------------------------------------- choices that are nobody's (experts held elsewhere)
+@pytest.mark.parametrize("dtype", (jnp.bfloat16, jnp.float32), ids=("bf16", "f32"))
+@pytest.mark.parametrize("k", (2, 4))
+def test_rows_of_experts_held_elsewhere_are_never_read_and_a_block_may_own_none(k, dtype):
+    """An expert layer that holds 2 of 8 experts gives every other choice the
+    id 2 (`models/moe.py`): those pairs sort behind the held groups and have no
+    run. Their rows hold NaN here, and no sum sees one. The second block of
+    tokens owns no row at all: it still gets a chunk (of nobody's rows), so that
+    the block before it has something to start and it has something to wait for."""
+    held = 2
+    rng = np.random.default_rng(k)
+    experts = rng.integers(0, 8, (TOKENS, k))
+    experts[sr.BLOCK:2 * sr.BLOCK] = rng.integers(held, 8, (sr.BLOCK, k))  # none of the held ones
+    local = jnp.asarray(np.where(experts < held, experts, held).astype(np.int32))
+    order, inverse = moe.expert_order(local)
+    n_held = int((np.asarray(local) < held).sum())
+    rows = jax.random.normal(jax.random.PRNGKey(0), (TOKENS * k, WIDTH), jnp.float32).astype(dtype)
+    rows = jnp.where((jnp.arange(TOKENS * k) < n_held)[:, None], rows, jnp.nan)
+    runs = sr.sorted_runs(local, held, True)
+    count = np.asarray(runs.count)
+    assert count[1] == 1 and not np.asarray(runs.hi).reshape(3, -1)[1].any()  # a piece that owns no row
+    assert count.min() >= 1 and sr.sorted_runs(local, held).count[1] == 0
+    got = sr.sum_rows(rows, inverse, runs, k, backend="pallas", interpret=True)
+    mine = (np.asarray(local) < held)[..., None]
+    by_token = np.asarray(rows, np.float32)[np.asarray(inverse)].reshape(TOKENS, k, WIDTH)
+    want = np.where(mine, by_token, 0.0).sum(axis=1)
+    assert np.isfinite(np.asarray(got, np.float32)).all()
+    assert not np.asarray(got, np.float32)[sr.BLOCK:2 * sr.BLOCK].any()
+    np.testing.assert_allclose(np.asarray(got, np.float32), want,
+                               rtol=2 ** -7 if dtype == jnp.bfloat16 else 1e-6, atol=1e-6)
