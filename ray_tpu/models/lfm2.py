@@ -390,8 +390,10 @@ def routing_stats(params: Dict[str, Any], tokens, config: LFM2Config) -> Dict[st
     `load_max_over_mean` (L,); `held_pairs` (L,), the (token, expert) pairs
     whose expert this share holds, and `elsewhere_pairs`, the others;
     `dropped` (L,): the held pairs less the rows their experts processed
-    (`moe_mlp`'s count). The layer is dropless, so `dropped` is 0; it is
-    counted, not assumed."""
+    (`moe_mlp`'s count, made in the form of the layer that ran). The layer is
+    dropless, so `dropped` is 0; it is counted, not assumed. `compact` (L,)
+    bool: the layer ran over the prefix of the sort that the held pairs fill,
+    not over every pair (`moe.held_row_bound`)."""
     x = params["embed"].astype(config.dtype)[tokens]
     streams = rope_tables(tokens.shape[1], config.head_dim, config.rope_theta)
     pairs = tokens.size * config.experts_per_token
@@ -408,5 +410,6 @@ def routing_stats(params: Dict[str, Any], tokens, config: LFM2Config) -> Dict[st
                 "held_pairs": aux["held_pairs"],
                 "elsewhere_pairs": pairs - aux["held_pairs"],
                 "dropped": aux["held_pairs"] - aux["rows_processed"],
+                "compact": aux["compact"],
             })
     return jax.tree.map(lambda *leaves: jnp.stack(leaves), *per_layer)

@@ -23,12 +23,20 @@ annotations alone (correct on any mesh).
 A layer may hold a range of the experts and not all (`moe_mlp(held_from=)`,
 the expert weights leading with the held count): one chip's share of an
 expert-parallel deployment. The router still scores every expert. The pairs
-whose expert lies elsewhere sort behind the held groups, no grouped matmul
-visits them, `sum_rows` is never pointed at them, and what those experts
-would have added to a token is left out: the layer returns the partial sum
-that this share computes. The exchange that would send those pairs to their
-chips and bring the other chips' partial sums back does not exist yet
-(ROADMAP: "an expert exchange across chips").
+whose expert lies elsewhere sort behind the held groups, so the held pairs
+are a prefix of the sort, and the sorted form is that prefix alone: the first
+`held_row_bound` rows (twice what an even router gives the held experts, a
+static count) are gathered, multiplied, gated and summed, by the kernels and
+by XLA's operations between them alike, and no array is `tokens * k` rows
+long but the sort's own index vectors. A routing that gives this share more
+pairs than the bound takes the whole-length form, the same function at
+`tokens * k` rows, in which no grouped matmul visits the rows behind the held
+groups and `sum_rows` is never pointed at them: `lax.cond` on `held_pairs`
+picks, so no pair is ever dropped, and `aux["compact"]` says which ran. What
+the other experts would have added to a token is left out either way: the
+layer returns the partial sum that this share computes. The exchange that
+would send those pairs to their chips and bring the other chips' partial sums
+back does not exist yet (ROADMAP: "an expert exchange across chips").
 """
 
 from __future__ import annotations
@@ -43,12 +51,20 @@ import jax.numpy as jnp
 from ray_tpu.ops.grouped_matmul import grouped_matmul
 from ray_tpu.ops.sum_rows import sorted_runs, sum_rows
 
+# Where a layer holds some of the experts, its sorted form is as long as this many times the
+# pairs an even router gives them, and the whole-length form takes a routing that gives it
+# more. Twice the mean: over the benchmark's seeds a layer of the LFM2 cell read 0.10-0.15 of
+# the pairs where the even share is 0.125 (PERF.md section 6, PR 35 and PR 36).
+HELD_ROWS_OVER_EVEN = 2
+ROW_TILE = 512  # the longest row tile a kernel takes (`grouped_matmul.DRHS_ROW_TILES`)
+
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
 def _gather_rows(x, order, inverse, runs, k: int):
     """Row `order[i] // k` of `x` for every i: the tokens in expert order.
-    `order` is a permutation of the `tokens * k` pairs and `inverse` undoes
-    it, so the gradient sums each token's `k` rows where they lie
+    `order` is the sort of the `tokens * k` pairs, or its first rows where
+    they hold every pair of a held expert, and `inverse` undoes the whole
+    sort, so the gradient sums each token's `k` rows where they lie
     (`_sum_rows`; `runs` says where, `ops/sum_rows.py sorted_runs`), where the
     transpose jax would derive is a scatter-add of `tokens * k` rows."""
     return x[order // k]
@@ -79,9 +95,10 @@ def _sum_rows_bwd(k, res, g):
 
 @jax.custom_vjp
 def _sort_weights(weights, order, inverse):
-    """The `tokens * k` weights, one per pair, in the order of the sorted
-    rows. The gradient is a gather by `inverse`, where the transpose jax
-    would derive is a scatter-add of `tokens * k` updates."""
+    """The weights, one per (token, expert) pair, in the order of the sorted
+    rows (`order` may be a prefix of the sort: a pair sorted behind it gets a
+    zero for its gradient). The gradient is a gather by `inverse`, where the
+    transpose jax would derive is a scatter-add of `tokens * k` updates."""
     return weights[order]
 
 
@@ -90,7 +107,9 @@ def _sort_weights_fwd(weights, order, inverse):
 
 
 def _sort_weights_bwd(inverse, g):
-    return g[inverse], None, None
+    if g.shape == inverse.shape:
+        return g[inverse], None, None
+    return g.at[inverse].get(mode="fill", fill_value=0), None, None
 
 
 _gather_rows.defvjp(_gather_rows_fwd, _gather_rows_bwd)
@@ -147,6 +166,21 @@ def expert_order(experts):
     return order, inverse
 
 
+def _under_the_current_abstract_mesh(f):
+    """`f` traced with the abstract mesh that is current set as such. jax traces
+    a `custom_vjp`'s function with none set and its forward rule, under
+    `linearize`, with the empty one (or the caller's), and every jit on the way
+    down to a kernel keeps a trace for each context: so the forward rule finds
+    the traces the function left, and no kernel's body is traced twice (a
+    `sum_rows` costs 0.25-0.4 s each time, every run: PERF.md section 6, PR 36)."""
+    @functools.wraps(f)
+    def traced(*args, **kwargs):
+        with jax.sharding.use_abstract_mesh(jax.sharding.get_abstract_mesh()):
+            return f(*args, **kwargs)
+    return traced
+
+
+@_under_the_current_abstract_mesh
 def moe_mlp(
     x,  # (B, S, D) activations, config.dtype
     router_w,  # (D, E)
@@ -163,8 +197,9 @@ def moe_mlp(
     """Returns (out (B, S, D), aux): `out = sum over the token's k experts of
     p_e * W_down,e (silu(W_gate,e h) * W_up,e h)`, `aux` as `route` gives it
     plus `experts` (tokens, k), each token's choices, `rows_processed`,
-    the rows of the sorted form that their own expert takes, and `held_pairs`,
-    the (token, expert) pairs whose expert this layer holds.
+    the rows of the sorted form that their own expert takes, `held_pairs`,
+    the (token, expert) pairs whose expert this layer holds, and `compact`,
+    whether the sorted form was the held prefix of the sort and not all of it.
     The scopes are read from a device trace by the benchmark's `moe.*_ms`.
 
     Where the expert weights hold fewer experts than the router scores, they
@@ -172,7 +207,6 @@ def moe_mlp(
     the token's k that are among them (the module's docstring): `out` is this
     share's partial sum."""
     B, S, D = x.shape
-    cdt = x.dtype
     n_experts, n_held = router_w.shape[-1], w_gate.shape[0]
     partial = n_held < n_experts
     tokens = x.reshape(B * S, D)
@@ -190,19 +224,48 @@ def moe_mlp(
             sizes = sizes[held_from:held_from + n_held]
         order, inverse = expert_order(experts)
         runs = sorted_runs(experts, n_held, partial)  # where each block of tokens' rows lie
-        rows = _gather_rows(tokens, order, inverse, runs, k)  # (T * k, D), expert order
-        row_weights = _sort_weights(weights.reshape(-1), order, inverse)  # (T * k,) f32
+        aux["held_pairs"] = jnp.sum(sizes)
+    pairs = order.shape[0]
+    bound = held_row_bound(pairs, n_held, n_experts)
+    operands = (tokens, weights, w_gate, w_up, w_down)
+    routing = (sizes, experts, order, inverse, runs)
+    if bound == pairs:
+        out, aux["rows_processed"] = _sorted_form(pairs, k, partial, *operands, *routing)
+        aux["compact"] = jnp.zeros((), bool)
+    else:
+        out, aux["rows_processed"], aux["compact"] = _prefix_or_whole_jit(k, bound, operands, routing)
+    return out.reshape(B, S, D), aux
+
+
+def held_row_bound(pairs: int, n_held: int, n_experts: int) -> int:
+    """The rows of the sorted form where a layer holds `n_held` of `n_experts`
+    experts: `HELD_ROWS_OVER_EVEN` times the held experts' even share of the
+    `pairs`, in whole `ROW_TILE`s, and no more than `pairs`."""
+    even = pairs * n_held / n_experts
+    return min(pairs, math.ceil(HELD_ROWS_OVER_EVEN * even / ROW_TILE) * ROW_TILE)
+
+
+def _sorted_form(n: int, k: int, partial: bool, tokens, weights, w_gate, w_up, w_down,
+                 sizes, experts, order, inverse, runs):
+    """Everything of the layer that is as long as the sorted form, over its first
+    `n` rows: (the tokens' sums (T, D), the rows that their own expert took).
+    `n` is every pair, or with `partial` at least the held pairs, which the sort
+    put first."""
+    cdt = tokens.dtype
+    with jax.named_scope("dispatch"):
+        order = order[:n]
+        rows = _gather_rows(tokens, order, inverse, runs, k)  # (n, D), expert order
+        row_weights = _sort_weights(weights.reshape(-1), order, inverse)  # (n,) f32
         # A grouped matmul gives row i to the group the running sum of `sizes`
         # puts it in: a row is processed where that is its own expert.
-        group = jnp.searchsorted(jnp.cumsum(sizes), jnp.arange(order.shape[0]), side="right")
+        group = jnp.searchsorted(jnp.cumsum(sizes), jnp.arange(n), side="right")
         mine = group == experts.reshape(-1)[order]
-        aux["held_pairs"] = jnp.sum(sizes)
         if partial:
-            held = jnp.arange(order.shape[0]) < aux["held_pairs"]  # by sorted row
+            held = jnp.arange(n) < jnp.sum(sizes)  # by sorted row
             mine = mine & held
             if runs is None:  # the XLA sums read every row, the gradient's too
                 rows = _held_rows(rows, held)
-        aux["rows_processed"] = jnp.sum(mine)
+        processed = jnp.sum(mine)
     with jax.named_scope("experts"):
         gate = grouped_matmul(rows, w_gate.astype(cdt), sizes, short=partial)
         up = grouped_matmul(rows, w_up.astype(cdt), sizes, short=partial)
@@ -219,7 +282,60 @@ def moe_mlp(
             rows = _held_rows(rows, held)  # the XLA sum reads every row; the kernel only held ones
     with jax.named_scope("combine"):
         out = _sum_rows(rows, order, inverse, runs, k)
-    return out.reshape(B, S, D), aux
+    return out, processed
+
+
+def _either_form(bound: int, routing, branch, *operands):
+    """(whether the first `bound` sorted rows hold every held pair, `branch(n)`
+    of the operands for n those rows if so, else for every pair)."""
+    sizes, _, order, _, _ = routing
+    compact = jnp.sum(sizes) <= bound
+    return compact, jax.lax.cond(compact, branch(bound), branch(order.shape[0]), *operands)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _prefix_or_whole(k: int, bound: int, operands, routing):
+    """`_sorted_form` over the first `bound` rows where they hold every held
+    pair, else over every pair: both dropless, the same function of a row
+    count; with the count of processed rows, whether it was the prefix.
+    Differentiated as a whole, so that what a branch keeps for its backward
+    pass lives inside the branch: through a plain `lax.cond` each branch also
+    returns the other's residuals, as zeros, and the forward pass keeps both
+    sets (the LFM2 step then wants 12.6 GB of temporaries for 10.3, and does
+    not compile for a v5e: PERF.md section 6, PR 36). The backward pass makes
+    the branch's forward pass again instead."""
+    return _prefix_or_whole_fwd(k, bound, operands, routing)[0]
+
+
+def _prefix_or_whole_fwd(k, bound, operands, routing):
+    def forward(n):
+        return lambda operands, routing: _sorted_form(n, k, True, *operands, *routing)
+
+    compact, (out, processed) = _either_form(bound, routing, forward, operands, routing)
+    return (out, processed, compact), (operands, routing)
+
+
+def _prefix_or_whole_bwd(k, bound, res, g):
+    operands, routing = res
+
+    def backward(n):
+        def branch(operands, routing, g):
+            def forward(*operands):
+                # `jax.vjp` wraps the first scope opened under it (`jvp(sorted_form)`,
+                # `transpose(jvp(sorted_form))`): the layer's own stay whole path components.
+                with jax.named_scope("sorted_form"):
+                    return _sorted_form(n, k, True, *operands, *routing)[0]
+
+            return jax.vjp(forward, *operands)[1](g)
+        return branch
+
+    return _either_form(bound, routing, backward, operands, routing, g[0])[1], None
+
+
+_prefix_or_whole.defvjp(_prefix_or_whole_fwd, _prefix_or_whole_bwd)
+# A function of its own in the program, as `sum_rows._pallas_sum_rows`: a model's layers of one
+# shape trace both forms and their backward passes once, and lower their kernels once.
+_prefix_or_whole_jit = jax.jit(_prefix_or_whole, static_argnums=(0, 1))
 
 
 def _held_rows(rows, held):

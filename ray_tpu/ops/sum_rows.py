@@ -1,10 +1,12 @@
 """The sum of each token's rows, out of rows that lie sorted by expert.
 
-`sum_rows(rows, inverse, runs, k)`: `rows` (tokens * k, width) are the
-results of a mixture-of-experts layer in expert order, `inverse[t * k + j]` is
+`sum_rows(rows, inverse, runs, k)`: `rows` (width wide) are the results of a
+mixture-of-experts layer in expert order, the sorted rows or the prefix of
+them that holds every row `inverse` is asked for; `inverse[t * k + j]` is
 where the j-th pair of token t went (`models/moe.py expert_order`), and
 `out[t] = sum over j of rows[inverse[t * k + j]]`, added up in float32 and
-rounded once. Off the TPU that is a gather and a reduction (`xla_sum_rows`);
+rounded once (a pair sorted behind a prefix adds nothing: an expert held on
+another chip). Off the TPU that is a gather and a reduction (`xla_sum_rows`);
 XLA's gather on the TPU reads every 4 KB row by itself and writes them all in
 token order only for the reduction to read them again (2.6 ms for 268 MB at
 OLMoE's shapes: PERF.md section 6, PR 34). On the TPU one Pallas kernel,
@@ -220,10 +222,11 @@ def _sum_rows_kernel(count, tile, lo, hi, inverse_ref, rows_hbm, out_ref, stage,
 
 
 @functools.lru_cache(maxsize=None)
-def _sum_rows_call(n_rows: int, width: int, dtype, k: int, max_pieces: int, interpret):
-    """The `pallas_call` for these shapes, made once a process (as `_gmm_call`)."""
+def _sum_rows_call(tokens: int, width: int, dtype, k: int, max_pieces: int, interpret):
+    """The `pallas_call` for these shapes, made once a process (as `_gmm_call`):
+    how many sorted rows there are to read from is not among them."""
     chunk = chunk_rows(width, jnp.dtype(dtype).itemsize) // PIECE  # in pieces
-    blocks = n_rows // k // BLOCK
+    blocks = tokens // BLOCK
     return pl.pallas_call(
         functools.partial(_sum_rows_kernel, chunk=chunk, max_pieces=max_pieces, k=k),
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -237,7 +240,7 @@ def _sum_rows_call(n_rows: int, width: int, dtype, k: int, max_pieces: int, inte
                             pltpu.SemaphoreType.DMA((2,)),
                             pltpu.SMEM((1,), jnp.int32)],
         ),
-        out_shape=jax.ShapeDtypeStruct((n_rows // k, width), dtype),
+        out_shape=jax.ShapeDtypeStruct((tokens, width), dtype),
         interpret=interpret,
         name="sum_rows",
         compiler_params=None if interpret else pltpu.CompilerParams(
@@ -250,23 +253,29 @@ def _sum_rows_call(n_rows: int, width: int, dtype, k: int, max_pieces: int, inte
 @functools.partial(jax.jit, static_argnames=("k", "interpret"))
 def _pallas_sum_rows(rows, inverse, runs: Runs, k: int, interpret=False):
     max_pieces = runs.tile.shape[0] // runs.count.shape[0]
-    call = _sum_rows_call(*rows.shape, rows.dtype, k, max_pieces, interpret)
+    call = _sum_rows_call(inverse.shape[0] // k, rows.shape[1], rows.dtype, k, max_pieces, interpret)
     # (k, tokens): dense in HBM, where (tokens, k) would be padded to 128 lanes.
     return call(*runs, inverse.reshape(-1, k).T.astype(jnp.int32), rows)
 
 
 def xla_sum_rows(rows, inverse, k: int):
     """The XLA form: what runs off the TPU, and the kernel's test reference."""
-    by_token = rows[inverse].reshape(-1, k, rows.shape[-1])
+    if rows.shape[0] == inverse.shape[0]:
+        by_token = rows[inverse]
+    else:  # a prefix of the sorted rows: a position past it adds nothing
+        by_token = rows.at[inverse].get(mode="fill", fill_value=0)
+    by_token = by_token.reshape(-1, k, rows.shape[-1])
     return by_token.astype(jnp.float32).sum(axis=1).astype(rows.dtype)
 
 
 def sum_rows(rows, inverse, runs: Optional[Runs], k: int, backend: Optional[str] = None,
              interpret=False):
     """`out[t] = sum over j < k of rows[inverse[t * k + j]]` in float32, rounded
-    to `rows.dtype`: `rows` (tokens * k, width) sorted by expert, `inverse` the
-    sorted position of every (token, choice) pair, `runs` as `sorted_runs` gives
-    them for the same routing.
+    to `rows.dtype`: `rows` (width wide) the sorted rows, or the prefix of them
+    that holds every row `inverse` is asked for (a position past it adds
+    nothing: the kernel has no run there, the XLA form fills in zeros);
+    `inverse` (tokens * k,) the sorted position of every (token, choice) pair,
+    `runs` as `sorted_runs` gives them for the same routing.
 
     backend: "pallas" | "xla" | None: the kernel where the computation is
     lowered for a TPU and the shapes tile (`BLOCK` tokens, a width of 128s),

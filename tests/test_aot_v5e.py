@@ -15,6 +15,7 @@ start-up is kept out of the pytest process and its 8-device CPU backend.
 
 import json
 import os
+import re
 import subprocess
 import sys
 
@@ -44,22 +45,21 @@ def _kernel_case(topo, shape=(B, 12, S, 64)):
             "plan": list(kernel_plan(shape))}
 
 
-def _step_case(topo, axes, compile_it):
-    """`make_train_step` on gpt2_small over `axes`, from abstract inputs laid
+def _lowered_step(topo, axes, cfg, rows, seq):
+    """`make_train_step` for `cfg` over `axes`, lowered from abstract inputs laid
     out as `create_train_state` / `shard_batch` lay out real ones."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from ray_tpu.models import GPTConfig, default_optimizer, gpt, make_train_step
-    from ray_tpu.models.training import TrainState, param_shardings
+    from ray_tpu.models import default_optimizer, make_train_step
+    from ray_tpu.models.training import TrainState, model_for, param_shardings
     from ray_tpu.parallel import MeshSpec, ShardingRules, batch_spec
 
     spec = MeshSpec(**axes)
     mesh = spec.build(topo.devices[: spec.num_devices])
-    cfg = GPTConfig.gpt2_small()
     opt = default_optimizer(learning_rate=3e-4)
-    shapes = jax.eval_shape(lambda: gpt.init_params(cfg, jax.random.PRNGKey(0)))
+    shapes = jax.eval_shape(lambda: model_for(cfg).init_params(cfg, jax.random.PRNGKey(0)))
     shardings = param_shardings(cfg, mesh, ShardingRules())
     replicated = NamedSharding(mesh, P())
     by_shape = dict(zip(
@@ -77,8 +77,15 @@ def _step_case(topo, axes, compile_it):
         step=jax.ShapeDtypeStruct((), jnp.int32, sharding=replicated),
     )
     batch = {"tokens": jax.ShapeDtypeStruct(
-        (B, S + 1), jnp.int32, sharding=NamedSharding(mesh, batch_spec()))}
-    lowered = make_train_step(cfg, opt, mesh=mesh).lower(state, batch)
+        (rows, seq + 1), jnp.int32, sharding=NamedSharding(mesh, batch_spec()))}
+    return make_train_step(cfg, opt, mesh=mesh).lower(state, batch)
+
+
+def _step_case(topo, axes, compile_it):
+    """gpt2_small's step at the flagship cell's batch."""
+    from ray_tpu.models import GPTConfig
+
+    lowered = _lowered_step(topo, axes, GPTConfig.gpt2_small(), B, S)
     out = {"mosaic_calls": lowered.as_text().count("tpu_custom_call")}
     if compile_it:
         compiled = lowered.compile()
@@ -89,6 +96,62 @@ def _step_case(topo, axes, compile_it):
             - mem.alias_size_in_bytes + mem.temp_size_in_bytes
         )
     return out
+
+
+CALLED = re.compile(r"(?:calls|to_apply|body|condition|true_computation|false_computation)=%?([\w.\-]+)"
+                    r"|branch_computations=\{([^}]*)\}")
+
+
+def wide_results_by_branch(text, wide):
+    """Of a compiled program's text: for every `conditional`, how many results
+    that match `wide` (a shape, `[4096,256]`) each of its branches holds, in
+    the branch's computation and whatever that calls; and how many the program
+    holds outside every branch."""
+    computations, name = {}, None
+    for line in text.splitlines():
+        if line.endswith("{") and " = " not in line:
+            name = line.split()[1 if line.startswith("ENTRY") else 0].lstrip("%")
+            computations[name] = []
+        elif name is not None and " = " in line:
+            computations[name].append(line)
+
+    def called(lines):
+        for line in lines:
+            for one, many in CALLED.findall(line):
+                yield from [one] if one else (n.strip().lstrip("%") for n in many.split(","))
+
+    def closure(root):
+        seen, todo = set(), [root]
+        while todo:
+            n = todo.pop()
+            if n not in seen and n in computations:
+                seen.add(n)
+                todo += called(computations[n])
+        return seen
+
+    def count(names):
+        return sum(bool(re.search(wide, line.split(" = ")[1].split("(")[0]))
+                   for n in names for line in computations[n])
+
+    branches = [closure(b) for lines in computations.values() for line in lines
+                if " conditional(" in line for b in called([line])]
+    return [count(b) for b in branches], count(set(computations) - set().union(*branches))
+
+
+def _held_experts_case(topo):
+    """An LFM2 step whose one expert layer holds 2 of 16 experts at shapes that
+    tile: 2 x 1,024 tokens of 256, two experts a token (4,096 pairs, a bound
+    of 1,024 rows), experts of 128. Where are the arrays as long as all pairs?"""
+    from ray_tpu.models import LFM2Config
+    from ray_tpu.models.lfm2 import CONV
+
+    cfg = LFM2Config(vocab_size=512, layer_types=(CONV, CONV), n_dense_layers=1, n_head=4, n_kv_head=2,
+                     d_model=256, d_ff=512, d_expert=128, n_experts=16, experts_per_token=2,
+                     n_experts_held=2, first_expert_held=4, max_seq_len=1024)
+    text = _lowered_step(topo, {"data": 1}, cfg, 2, 1024).compile().as_text()
+    by_branch, outside = wide_results_by_branch(text, r"\[4096,(256|128)\]")
+    kernels = sorted(set(re.findall(r"(gmm_\w+?|sum_rows)[.\d]* = ", text)))
+    return {"wide_by_branch": by_branch, "wide_outside": outside, "kernels": kernels}
 
 
 _MESHES = {"d1": {"data": 1}, "d4": {"data": 4}, "d2t2": {"data": 2, "tensor": 2}}
@@ -103,6 +166,8 @@ def _main(cases):
     for case in cases:
         if case == "kernel":
             results[case] = _kernel_case(topo)
+        elif case == "held_experts":
+            results[case] = _held_experts_case(topo)
         elif case.startswith("kernel:"):
             results[case] = _kernel_case(topo, tuple(int(n) for n in case[len("kernel:"):].split("x")))
         else:
@@ -124,7 +189,7 @@ def _run(cases):
 
 @pytest.fixture(scope="module")
 def aot():
-    return _run(["kernel", LONG_HEAD_64, "lower:d4", "lower:d2t2"])
+    return _run(["kernel", LONG_HEAD_64, "held_experts", "lower:d4", "lower:d2t2"])
 
 
 def test_topology_is_the_v5e(aot):
@@ -152,6 +217,22 @@ def test_the_plans_of_the_other_cells_shapes_are_what_they_were():
     assert kernel_plan((4, 25, 1024, 64)) == (512, 512, 3, 2, 4, True)  # gpt2-xl-fsdp4, a chip
     assert kernel_plan((2, 16, 4096, 128)) == (512, 512, 36, 8, 64, False)  # olmoe-1b-7b-l1
     assert kernel_plan((16, 32, 2048, 64)) == (512, 512, 10, 4, 16, True)  # shorter heads of 64: untouched
+
+
+def test_a_share_of_the_experts_holds_no_array_as_long_as_every_pair_where_the_prefix_runs(aot):
+    """`moe_mlp` with fewer experts than the router scores runs its sorted form
+    over the prefix of the sort that the held pairs fill, or over every pair
+    where they do not fit it: a `conditional` in the forward pass and one in
+    the backward pass (the recomputation's has no reader and is gone). The
+    `[pairs, D]` and `[pairs, F]` arrays are all inside the whole-length
+    branches: the branch that runs when the router is anywhere near even
+    holds none, forward or backward, and neither does the program around
+    them. Both branches call the kernels."""
+    got = aot["held_experts"]
+    assert len(got["wide_by_branch"]) == 4 and got["wide_outside"] == 0, got
+    prefix, whole = sorted(got["wide_by_branch"])[:2], sorted(got["wide_by_branch"])[2:]
+    assert prefix == [0, 0] and min(whole) > 0, got
+    assert got["kernels"] == ["gmm_dlhs", "gmm_drhs", "gmm_fwd", "sum_rows"]
 
 
 @pytest.mark.parametrize("mesh", ["d4", "d2t2"])

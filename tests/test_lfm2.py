@@ -249,16 +249,22 @@ def _share(p, first, count, k=2):
     return out, vjp(p["dout"]), aux
 
 
+@pytest.mark.parametrize("tokens, favoured", [(128, None), (1024, 0)], ids=("every_share_whole_length", "a_share_on_each_side_of_the_bound"))
 @pytest.mark.parametrize("held", [1, 2, 4])
-def test_the_shares_add_up_to_the_whole_layer_and_their_gradients_are_its_slices(held):
+def test_the_shares_add_up_to_the_whole_layer_and_their_gradients_are_its_slices(held, tokens, favoured):
     """Eight shares of one expert each (four of two, two of four): what every
     share computes alike (the router) counted once, their outputs add up to
     the uncut layer's, the matrices' gradients are the uncut gradient's
     slices, and the gradients for the tokens add up: their experts' part
-    each, and the router's part, which every share computes whole, once."""
+    each, and the router's part, which every share computes whole, once.
+    At 128 tokens the row bound of a share is every pair; at 1,024, with every
+    token's first choice on expert 0, the share that holds it runs whole-length
+    and the others over the prefix (`moe.held_row_bound`)."""
     import jax
 
-    p = _expert_layer()
+    p = _expert_layer(tokens=tokens)
+    if favoured is not None:
+        p["bias"] = p["bias"].at[favoured].add(10.0)
     with jax.default_matmul_precision("highest"):
         whole_out, whole_grads, whole_aux = _share(p, 0, 8)
         shares = [_share(p, first, held) for first in range(0, 8, held)]
@@ -275,7 +281,9 @@ def test_the_shares_add_up_to_the_whole_layer_and_their_gradients_are_its_slices
             np.testing.assert_allclose(mine, whole[i * held:(i + 1) * held], rtol=1e-4, atol=1e-5)
         assert int(aux["held_pairs"]) == int(aux["rows_processed"])  # nothing dropped
         assert (aux["experts"] == whole_aux["experts"]).all()  # every share routes alike
-    assert sum(int(aux["held_pairs"]) for _, _, aux in shares) == 128 * 2
+    assert sum(int(aux["held_pairs"]) for _, _, aux in shares) == tokens * 2
+    compact = [bool(aux["compact"]) for _, _, aux in shares]
+    assert compact == [favoured is not None and held < 4 and i > 0 for i in range(8 // held)]
     np.testing.assert_allclose(sum(grads[0] for _, grads, _ in shares), whole_grads[0],
                                rtol=1e-4, atol=1e-5)
 

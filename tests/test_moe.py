@@ -4,7 +4,9 @@ projection, where the definition puts it. `moe_mlp` applies it one product
 earlier, to the rows SwiGLU writes, and sends it through the layer's own
 permutation; output and every gradient must not know the difference, for one
 expert a token and for eight, with and without renormalised weights, and
-through `GPTConfig.moe_experts`."""
+through `GPTConfig.moe_experts`. Then a layer that holds some of the experts:
+its sorted form is a prefix of the sort while the held pairs fit a bound, and
+that form must be the whole-length one to rounding, on both sides of the bound."""
 
 import functools
 import os
@@ -131,3 +133,108 @@ def test_through_gpt_config_moe_experts_loss_and_gradients_agree(monkeypatch):
     assert float(jnp.abs(want_grads["blocks"]["moe"]["router_w"]).max()) > 1e-4
     jax.tree.map(lambda a, b: np.testing.assert_allclose(a, b, rtol=2e-4, atol=1e-6),
                  grads, want_grads)
+
+
+# ------------------------------------------- a share of the experts: the prefix form and the whole one
+SHARE_TOKENS, SHARE_E, SHARE_HELD, SHARE_FROM = 1024, 16, 2, 2  # 2,048 pairs: the bound is 512 rows
+BOUND = 512
+HELD_PAIRS = {"below_the_bound": 300, "at_the_bound": BOUND, "one_over_the_bound": BOUND + 1,
+              "every_pair_held": 2 * SHARE_TOKENS}
+
+
+@functools.lru_cache(maxsize=None)
+def _share(routing, whole_length):
+    """Output, gradients and `aux` of a layer that holds experts 2 and 3 of 16,
+    two a token, where `HELD_PAIRS[routing]` of the pairs are its own: as
+    `moe_mlp` runs it, or with the bound lifted to every pair (the whole-length
+    form, and no `cond`)."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models import moe
+
+    held_pairs = HELD_PAIRS[routing]
+    both, one = held_pairs // 2, held_pairs % 2  # tokens with both choices held here, with one
+    keys = jax.random.split(jax.random.PRNGKey(7), 7)
+    # The first three features say where a token goes (both held / one held / none); the rest is noise.
+    kind = jnp.where(jnp.arange(SHARE_TOKENS) < both, 0, jnp.where(jnp.arange(SHARE_TOKENS) < both + one, 1, 2))
+    x = jnp.concatenate([6.0 * jax.nn.one_hot(kind, 3), jax.random.normal(keys[0], (SHARE_TOKENS, D - 3))], axis=1)
+    x = x.reshape(2, SHARE_TOKENS // 2, D)
+    pull = np.zeros((D, SHARE_E), np.float32)
+    pull[0, [2, 3]] = pull[1, [3, 9]] = pull[2, [11, 12]] = 1.0
+    params = {"router_w": jnp.asarray(pull) + 0.1 * jax.random.normal(keys[1], (D, SHARE_E)),
+              "w_gate": jax.random.normal(keys[2], (SHARE_HELD, D, F)) / np.sqrt(D),
+              "w_up": jax.random.normal(keys[3], (SHARE_HELD, D, F)) / np.sqrt(D),
+              "w_down": jax.random.normal(keys[4], (SHARE_HELD, F, D)) / np.sqrt(F)}
+    cotangent = jax.random.normal(keys[5], x.shape)
+
+    def scalar(x, p):
+        out, aux = moe.moe_mlp(x, *(p[name] for name in WEIGHTS), k=2, norm_topk_prob=True,
+                               held_from=SHARE_FROM)
+        return jnp.sum(out * cotangent), (out, aux)
+
+    with pytest.MonkeyPatch.context() as patch:
+        if whole_length:
+            patch.setattr(moe, "held_row_bound", lambda pairs, n_held, n_experts: pairs)
+        assert moe.held_row_bound(2 * SHARE_TOKENS, SHARE_HELD, SHARE_E) == (
+            2 * SHARE_TOKENS if whole_length else BOUND)
+        (_, (out, aux)), (dx, grads) = jax.jit(jax.value_and_grad(scalar, argnums=(0, 1), has_aux=True))(x, params)
+    return {"out": out, "x": dx, **grads}, aux
+
+
+@pytest.mark.parametrize("what", ("out", "x") + WEIGHTS)
+@pytest.mark.parametrize("routing", HELD_PAIRS)
+def test_the_prefix_form_is_the_whole_length_form_on_both_sides_of_the_bound(routing, what):
+    (got, aux), (want, whole_aux) = _share(routing, False), _share(routing, True)
+    assert int(aux["held_pairs"]) == int(whole_aux["held_pairs"]) == HELD_PAIRS[routing]
+    assert int(aux["rows_processed"]) == HELD_PAIRS[routing]  # dropless in the form that ran
+    assert bool(aux["compact"]) == (HELD_PAIRS[routing] <= BOUND) and not bool(whole_aux["compact"])
+    assert np.abs(np.asarray(want[what])).max() > 1e-3
+    np.testing.assert_allclose(np.asarray(got[what]), np.asarray(want[what]), rtol=1e-4, atol=1e-5)
+
+
+@pytest.mark.parametrize("pairs, n_held, n_experts, rows", [
+    (131072, 8, 64, 32768),  # the LFM2 cell: a quarter
+    (2048, 2, 16, 512), (2048, 1, 16, 512),  # whole row tiles
+    (65536, 64, 64, 65536), (256, 2, 8, 256), (2048, 8, 16, 2048)])  # no more than every pair
+def test_the_bound_is_twice_the_even_share_in_whole_row_tiles(pairs, n_held, n_experts, rows):
+    from ray_tpu.models.moe import held_row_bound
+
+    assert held_row_bound(pairs, n_held, n_experts) == rows
+
+
+
+@functools.lru_cache(maxsize=None)
+def _share_at_kernel_shapes(through_the_kernels):
+    """Output, gradients and `aux` of a layer that holds expert 5 of 8 at
+    shapes the Pallas kernels take (512 tokens of 128, two a token: 1,024
+    pairs, a bound of 512 rows), through the kernels in interpret mode or
+    through the XLA forms."""
+    import jax
+
+    from ray_tpu.models import moe
+    from ray_tpu.ops.grouped_matmul import grouped_matmul
+    from ray_tpu.ops.sum_rows import sum_rows
+
+    tokens, d, f = 512, 128, 128
+    keys = jax.random.split(jax.random.PRNGKey(3), 6)
+    x = jax.random.normal(keys[0], (1, tokens, d))
+    cotangent = jax.random.normal(keys[1], x.shape)
+    weights = (jax.random.normal(keys[2], (d, 8)), jax.random.normal(keys[3], (1, d, f)) / d ** 0.5,
+               jax.random.normal(keys[4], (1, d, f)) / d ** 0.5, jax.random.normal(keys[5], (1, f, d)) / f ** 0.5)
+    backend = dict(backend="pallas", interpret=True) if through_the_kernels else dict(backend="xla")
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(moe, "sum_rows", functools.partial(sum_rows, **backend))
+        patch.setattr(moe, "grouped_matmul", functools.partial(grouped_matmul, **backend))
+        out, vjp, aux = jax.vjp(lambda x, *w: moe.moe_mlp(x, *w, k=2, held_from=5), x, *weights, has_aux=True)
+        return [np.asarray(a) for a in (out, *vjp(cotangent))], aux
+
+
+@pytest.mark.parametrize("what", range(6), ids=("out", "x") + WEIGHTS)
+def test_the_prefix_form_is_the_same_through_the_kernels(what):
+    """`grouped_matmul(short=True)` and `sum_rows` take the 512 rows of the
+    prefix (the kernels' visits and runs stop at the held pairs either way)."""
+    (got, aux), (want, _) = _share_at_kernel_shapes(True), _share_at_kernel_shapes(False)
+    assert bool(aux["compact"]) and 0 < int(aux["held_pairs"]) == int(aux["rows_processed"]) <= 512
+    assert np.abs(want[what]).max() > 1e-3
+    np.testing.assert_allclose(got[what], want[what], rtol=1e-4, atol=1e-4)

@@ -175,3 +175,32 @@ def test_rows_of_experts_held_elsewhere_are_never_read_and_a_block_may_own_none(
     assert not np.asarray(got, np.float32)[sr.BLOCK:2 * sr.BLOCK].any()
     np.testing.assert_allclose(np.asarray(got, np.float32), want,
                                rtol=2 ** -7 if dtype == jnp.bfloat16 else 1e-6, atol=1e-6)
+
+
+@pytest.mark.parametrize("form", ("kernel", "xla"))
+@pytest.mark.parametrize("k", (2, 4))
+def test_a_prefix_that_holds_every_held_row_sums_to_what_all_the_rows_do(k, form):
+    """The held pairs sort first, so a layer may hand over the first rows of
+    the sorted form alone, with `inverse` whole (`models/moe.py`: the prefix
+    form). The kernel is pointed only at held rows and reads what the runs
+    say; the XLA form gathers by every position, and one past the array's end
+    adds nothing."""
+    held = 2
+    experts = np.random.default_rng(k).integers(0, 8, (TOKENS, k))
+    local = jnp.asarray(np.where(experts < held, experts, held).astype(np.int32))
+    _, inverse = moe.expert_order(local)
+    n_held = int((np.asarray(local) < held).sum())
+    prefix = -(-n_held // sr.BLOCK) * sr.BLOCK
+    assert n_held < prefix < TOKENS * k
+    rows = jax.random.normal(jax.random.PRNGKey(1), (TOKENS * k, WIDTH), jnp.float32)
+    rows = jnp.where((jnp.arange(TOKENS * k) < n_held)[:, None], rows, 0.0)  # as `moe_mlp` masks them
+    runs = sr.sorted_runs(local, held, True)
+    backend = dict(backend="pallas", interpret=True) if form == "kernel" else dict(backend="xla")
+    got = sr.sum_rows(rows[:prefix], inverse, runs, k, **backend)
+    want = sr.sum_rows(rows, inverse, runs, k, **backend)
+    assert got.shape == (TOKENS, WIDTH) and np.abs(np.asarray(want)).max() > 1
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    # The gradient of the XLA form gives a position past the prefix nothing to scatter.
+    if form == "xla":
+        g = jax.grad(lambda r: sr.xla_sum_rows(r, inverse, k).sum())(rows[:prefix])
+        np.testing.assert_array_equal(np.asarray(g), np.ones((prefix, WIDTH), np.float32))
