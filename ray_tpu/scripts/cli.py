@@ -392,6 +392,10 @@ def cmd_train(ns):
         for bucket, secs in (g.get("buckets") or {}).items():
             share = secs / wall * 100 if wall > 0 else 0.0
             print(f"    {bucket:<16} {secs:>10.3f}s {share:>6.1f}%")
+        from ray_tpu.train._internal.ledger import bringup_lines
+
+        for line in bringup_lines(g):
+            print("  " + line)
         straggler = g.get("straggler")
         if straggler:
             print(f"  straggler: rank {straggler['rank']} "

@@ -20,6 +20,7 @@ fail.
 
 from __future__ import annotations
 
+import contextlib
 import time
 from typing import Any, Callable, Dict, List, Optional
 
@@ -69,14 +70,6 @@ def unregister_round_hook(fn: Callable[[Any, int], None]) -> None:
         pass
 
 
-def _rendezvous_wait_total() -> float:
-    """Runs on a worker: process-lifetime seconds blocked in collective
-    rendezvous (includes jax.distributed.initialize gang-join)."""
-    from ray_tpu.util.collective import rendezvous
-
-    return float(rendezvous._WAIT_STATS["wait_s"])
-
-
 def _tpu_shortfall(bundles: List[Dict[str, float]]) -> str:
     """Why a gang that wants chips could not be placed, when the reason is
     that the cluster does not have them: names what chip detection saw."""
@@ -108,6 +101,8 @@ class BackendExecutor:
         self._trial_info = trial_info or {}
         self._gang_id = gang_id or self._trial_info.get("trial_id") or "default"
         self._ledger = ledger  # GoodputLedger (driver-owned) or None
+        # The driver's bring-up span log: the ledger's, so none without one.
+        self._spans = ledger.spans if ledger is not None else None
         self._pg = None
         self.worker_group: Optional[WorkerGroup] = None
         self._ranks: List[int] = []
@@ -129,23 +124,65 @@ class BackendExecutor:
         # recovery assembly at resize time.
         self._spare_payloads: List[Dict[str, Any]] = []
 
+    # ---------------------------------------------------------------- bring-up
+    def span(self, name: str, **attributes: Any):
+        """One seam of the gang's bring-up as a span (`telemetry.SpanLog`),
+        nested in whichever is open; nothing where no ledger keeps them."""
+        if self._spans is None:
+            return contextlib.nullcontext()
+        return self._spans.span("ray_tpu.train.bringup." + name, **attributes)
+
+    def span_attributes(self, **attributes: Any) -> None:
+        """More attributes for the innermost open span: what a backend knows
+        of the seam the executor opened round it."""
+        if self._spans is not None and self._spans.innermost is not None:
+            self._spans.innermost["attributes"].update(attributes)
+
+    def worker_trace(self) -> Optional[Dict[str, Any]]:
+        """What a worker call made now takes along to hang its spans here."""
+        return self._spans.wire() if self._spans is not None else None
+
+    def note_worker_spans(self, spans: List[Dict[str, Any]]) -> None:
+        if self._ledger is not None:
+            self._ledger.note_spans(spans)
+
+    def _spawn(self, placement_group=None) -> List[WorkerMetadata]:
+        """The gang's actors, up: asked for -> every `metadata()` back, and
+        ranks dealt. Each worker's reply splits its share in two: scheduler ->
+        exec, and python + `import ray_tpu` -> the actor constructed."""
+        with self.span("spawn") as span:
+            self.worker_group = WorkerGroup(
+                self._scaling.num_workers,
+                resources_per_worker=self._scaling._resources,
+                placement_group=placement_group,
+            )
+            meta = self.worker_group.fetch_metadata()
+        self._assign_ranks(meta)
+        for rank, m in zip(self._ranks, meta) if span is not None else ():
+            asked = span["start"]
+            exec_at = max(asked, m.process_start_wall)
+            self._spans.record(
+                "ray_tpu.train.bringup.spawn.worker", asked,
+                max(exec_at, m.ready_wall), span, rank=rank, pid=m.pid,
+                exec_s=round(exec_at - asked, 6),
+                import_s=round(max(0.0, m.ready_wall - exec_at), 6))
+        return meta
+
     # ------------------------------------------------------------------ start
     def start(self):
         if self._elastic:
             # No placement group: atomic all-or-nothing placement is the
             # opposite contract from resize-in-place membership.
             try:
-                self.worker_group = WorkerGroup(
-                    self._scaling.num_workers,
-                    resources_per_worker=self._scaling._resources,
-                )
-                meta = self.worker_group.fetch_metadata()
+                meta = self._spawn()
             except Exception as e:
                 raise TrainingWorkerError(f"gang startup failed: {e}") from e
         else:
             bundles = self._scaling.as_placement_group_bundles()
-            self._pg = placement_group(bundles, strategy=self._scaling.placement_strategy)
-            if not self._pg.ready(timeout=60.0):
+            with self.span("placement", bundles=bundles):
+                self._pg = placement_group(bundles, strategy=self._scaling.placement_strategy)
+                placed = self._pg.ready(timeout=60.0)
+            if not placed:
                 remove_placement_group(self._pg)
                 self._pg = None
                 raise TrainingWorkerError(
@@ -153,22 +190,17 @@ class BackendExecutor:
                     + _tpu_shortfall(bundles)
                 )
             try:
-                self.worker_group = WorkerGroup(
-                    self._scaling.num_workers,
-                    resources_per_worker=self._scaling._resources,
-                    placement_group=self._pg,
-                )
-                meta = self.worker_group.fetch_metadata()
+                meta = self._spawn(self._pg)
             except Exception as e:
                 # Worker/actor death during gang bring-up must consume the
                 # FailureConfig budget (gang restart), not surface as a
                 # driver-side bug (reference retries startup failures too).
                 raise TrainingWorkerError(f"gang startup failed: {e}") from e
-        self._assign_ranks(meta)
         if self._elastic:
             self._assign_peers(meta)
         try:
-            self._backend.on_start(self, self._backend_config)
+            with self.span("backend"):
+                self._backend.on_start(self, self._backend_config)
         except RayTpuError as e:
             raise TrainingWorkerError(f"gang startup failed: {e}") from e
         self._last_resize_at = time.monotonic()
@@ -239,7 +271,12 @@ class BackendExecutor:
             self._backend.on_training_start(self, self._backend_config)
         except RayTpuError as e:
             raise TrainingWorkerError(f"gang startup failed: {e}") from e
+        with self.span("session"):
+            self._init_sessions(train_fn, config, checkpoint, dataset_shards, mesh_builder)
+
+    def _init_sessions(self, train_fn, config, checkpoint, dataset_shards, mesh_builder):
         refs = []
+        trace = self.worker_trace()
         for i, w in enumerate(self.worker_group.workers):
             info = self.world_info(i)
             args = SessionArgs(
@@ -256,6 +293,7 @@ class BackendExecutor:
                 ],
                 mesh_builder=mesh_builder,
                 gang_id=self._gang_id,
+                trace=trace,
                 **self._trial_info,
             )
             refs.append(w.init_session.remote(args))
@@ -263,20 +301,6 @@ class BackendExecutor:
             ray_tpu.get(refs)
         except Exception as e:
             raise TrainingWorkerError(f"gang startup failed: {e}") from e
-
-    def gang_rendezvous_seconds(self) -> float:
-        """Gang-mean seconds the workers spent blocked in rendezvous so far
-        (the ledger's rendezvous_wait share of bring-up). Best-effort: 0.0
-        when observability is off or the gang is unreachable."""
-        from ray_tpu._private.telemetry import metrics_enabled
-
-        if not metrics_enabled() or self.worker_group is None:
-            return 0.0
-        try:
-            totals = self.worker_group.execute(_rendezvous_wait_total)
-        except Exception:  # noqa: BLE001 — dying gang; caller handles failure
-            return 0.0
-        return sum(totals) / len(totals) if totals else 0.0
 
     def get_next_results(self) -> Optional[List[TrainingResult]]:
         """One result per worker (ordered by world rank), or None when all DONE.
@@ -314,6 +338,10 @@ class BackendExecutor:
                 "training worker(s) failed:\n" + "\n".join(r.error for r in errors)
             )
         if all(r.type == DONE for r in by_rank):
+            if self._ledger is not None:
+                for r in by_rank:
+                    if r.telemetry:
+                        self._ledger.note_totals(r.world_rank, r.telemetry)
             return None
         if any(r.type != REPORT for r in by_rank):
             if self._elastic and any(r.type == DRAINED for r in by_rank):

@@ -11,6 +11,7 @@ loop continues — the property PBT-style mutation relies on).
 
 from __future__ import annotations
 
+import contextlib
 import queue
 import threading
 import time
@@ -67,6 +68,9 @@ class SessionArgs:
     # Stable id shared by every rank (and every restart) of one fit() — the
     # `gang` tag on train metrics and the training_report KV key.
     gang_id: str = ""
+    # Where this rank's bring-up spans hang (`SpanLog.wire()` of the driver's
+    # session span); None when no ledger keeps them, and none are made.
+    trace: Optional[Dict[str, Any]] = None
 
 
 class _TrainSession:
@@ -86,6 +90,8 @@ class _TrainSession:
         self.gang_id = args.gang_id or args.trial_id or "default"
         self.mesh = None
         self._clock = None  # StepClock, built in-thread by _run
+        self._bringup = None  # SpanLog of this rank, beside the clock
+        self._first_report = None  # the open `worker.first_report` span, the counter then
         self._q: "queue.Queue[TrainingResult]" = queue.Queue(maxsize=1)
         self._thread = threading.Thread(target=self._run, daemon=True)
         self._finished = threading.Event()
@@ -97,25 +103,37 @@ class _TrainSession:
 
     # ----------------------------------------------------------- thread side
     def _run(self):
-        from ray_tpu.train._internal.telemetry import make_clock
+        from ray_tpu.train._internal.telemetry import COMPILE_TOTALS, SpanLog, make_clock
 
         air_session._set_session(self)
         try:
             # Built here, not in __init__: the train_step span must live in
             # this thread so collective spans auto-parent under it.
             self._clock = make_clock(self.gang_id, self.world_rank)
+            if self._clock is not None and self.args.trace:
+                self._bringup = SpanLog.from_wire(self.args.trace, self.world_rank)
             if self.args.mesh_builder is not None:
                 if self._clock is not None:
                     self._clock.mark("compile")
-                self.mesh = self.args.mesh_builder()
+                with self._seam("ray_tpu.train.worker.mesh_build") as span:
+                    self.mesh = self.args.mesh_builder()
+                    if span is not None:
+                        span["attributes"]["mesh"] = dict(getattr(self.mesh, "shape", {}))
                 if self._clock is not None:
                     self._clock.mark("step_exec")
+            if self._bringup is not None:
+                # The user's set-up as the framework sees it: train_fn entered
+                # -> its first report() (which carries the span home).
+                self._first_report = (
+                    self._bringup.open("ray_tpu.train.worker.first_report"),
+                    dict(COMPILE_TOTALS))
             self.args.train_fn(self.args.config)
             done = TrainingResult(DONE, world_rank=self.world_rank)
             if self._clock is not None:
                 totals = self._clock.finalize()
                 if self._clock.metrics_on:
                     done.telemetry = totals
+                    self._attach_bringup(totals)  # a loop that never reported
                     # The driver kills gang workers right after DONE — don't
                     # let a short run's step samples die in the 1 Hz flusher.
                     try:
@@ -124,6 +142,10 @@ class _TrainSession:
                         flush_metrics()
                     except Exception:  # noqa: BLE001
                         pass
+                # Nor its last spans (bring-up, train_step), for the timeline.
+                from ray_tpu.util import tracing
+
+                tracing.flush_spans()
             self._q.put(done)
         except SessionDrained:
             # Elastic stop at a step boundary: clean, no result to forward
@@ -145,6 +167,27 @@ class _TrainSession:
         finally:
             self._finished.set()
             air_session._set_session(None)
+
+    def _seam(self, name: str):
+        """A bring-up span of this rank, where a ledger keeps them."""
+        if self._bringup is None:
+            return contextlib.nullcontext()
+        return self._bringup.span(name)
+
+    def _attach_bringup(self, telem: Dict[str, Any]) -> None:
+        """Close `worker.first_report` if it is open and send this rank's
+        finished bring-up spans home on `telem`."""
+        from ray_tpu.train._internal.telemetry import compile_delta
+
+        if self._bringup is None:
+            return
+        if self._first_report is not None:
+            span, before = self._first_report
+            # With what jax traced, lowered and compiled inside it.
+            self._bringup.close(span, **compile_delta(before))
+            self._first_report = None
+        if self._bringup.spans:
+            telem["spans"] = self._bringup.take()
 
     def mark_phase(self, phase: str) -> None:
         """Explicit phase seam from the user loop (air.session.mark_phase).
@@ -181,6 +224,8 @@ class _TrainSession:
         telem = clock.close_step(checkpoint=checkpoint is not None)
         if clock.metrics_on:
             result.telemetry = telem
+            if self._first_report is not None:
+                self._attach_bringup(telem)
         # The bounded-queue put is driver backpressure: accrue it as the
         # report (or checkpoint) phase of the step now opening.
         clock.mark("checkpoint" if checkpoint is not None else "report")

@@ -17,6 +17,7 @@ from __future__ import annotations
 
 import os
 import socket
+import time
 from dataclasses import dataclass
 from typing import Any, Callable, Dict, List, Optional
 
@@ -30,17 +31,39 @@ from ray_tpu.util.scheduling_strategies import PlacementGroupSchedulingStrategy
 _WORKER_CONCURRENCY = 4
 
 
+def _process_start_wall() -> float:
+    """When this process was exec'ed, on the wall clock (`/proc/self/stat`
+    field 22 is in clock ticks since boot)."""
+    with open("/proc/self/stat") as fh:
+        ticks = int(fh.read().rsplit(")", 1)[1].split()[19])
+    with open("/proc/uptime") as fh:
+        uptime = float(fh.read().split()[0])
+    return time.time() - (uptime - ticks / os.sysconf("SC_CLK_TCK"))
+
+
 class RayTrainWorker:
     """Actor hosting one training process (one TPU host's worth of chips)."""
+
+    def __init__(self):
+        self._ready_wall = time.time()
 
     def execute(self, fn: Callable, *args, **kwargs):
         return fn(*args, **kwargs)
 
     def metadata(self) -> Dict[str, Any]:
+        """Where this worker runs, and the two marks that split its spawn:
+        the process exec'ed, and the actor constructed (python started,
+        `ray_tpu` imported, the class unpickled)."""
+        try:
+            started = _process_start_wall()
+        except (OSError, ValueError, IndexError):
+            started = self._ready_wall
         return {
             "node_ip": socket.gethostbyname(socket.gethostname()),
             "hostname": socket.gethostname(),
             "pid": os.getpid(),
+            "process_start_wall": started,
+            "ready_wall": self._ready_wall,
         }
 
     def ping(self) -> bool:
@@ -126,6 +149,8 @@ class WorkerMetadata:
     node_ip: str
     hostname: str
     pid: int
+    process_start_wall: float = 0.0
+    ready_wall: float = 0.0
 
 
 class WorkerGroup:

@@ -191,6 +191,9 @@ class DataParallelTrainer(BaseTrainer):
                 self.backend_config, self.scaling_config, trial_info,
                 gang_id=gang_id, ledger=ledger,
             )
+            # One root span an attempt: every seam of this bring-up, in every
+            # process, is its descendant.
+            root = ledger.open_root(failures) if ledger is not None else None
             try:
                 recovering = failures > 0
                 executor.start()
@@ -218,7 +221,7 @@ class DataParallelTrainer(BaseTrainer):
                             recover_s=round(recover_s, 6),
                         )
                     else:
-                        ledger.account_init(executor.gang_rendezvous_seconds())
+                        ledger.account_init()
                 while True:
                     try:
                         results = executor.get_next_results()
@@ -259,6 +262,7 @@ class DataParallelTrainer(BaseTrainer):
                         )
                 executor.shutdown()
                 if ledger is not None:
+                    ledger.close_root(root)
                     ledger.finalize("done")
                 return Result(
                     metrics=last_metrics,
@@ -271,6 +275,7 @@ class DataParallelTrainer(BaseTrainer):
                 executor.shutdown()
                 failures += 1
                 if ledger is not None:
+                    ledger.close_root(root, "ERROR")
                     ledger.failures = failures
                 if max_failures >= 0 and failures > max_failures:
                     if ledger is not None:
@@ -286,6 +291,7 @@ class DataParallelTrainer(BaseTrainer):
             except BaseException as e:  # driver-side bug: no retry
                 executor.shutdown()
                 if ledger is not None:
+                    ledger.close_root(root, "ERROR")
                     ledger.finalize("failed")
                 if not isinstance(e, Exception):
                     raise  # KeyboardInterrupt/SystemExit must propagate

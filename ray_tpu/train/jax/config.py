@@ -93,40 +93,49 @@ def _tpu_process_envs(grants: List[Dict[str, Any]]) -> List[Dict[str, str]]:
 
 
 def _start_jax(coordinator: Optional[str], num_processes: int, process_id: int,
-               granted_chips: int, tpu_env: Dict[str, str]) -> Dict[str, Any]:
+               granted_chips: int, tpu_env: Dict[str, str],
+               trace: Optional[Dict[str, Any]] = None) -> Dict[str, Any]:
     """Runs on every worker before any user code: compile cache, gang join,
-    and — for a worker granted chips — proof that jax came up on them."""
+    and — for a worker granted chips — proof that jax came up on them. Each
+    of the three is a span (`trace` says where they hang), returned under
+    `spans` beside the device's report."""
     import os
 
     from ray_tpu._private.accelerators import jax_process
+    from ray_tpu.train._internal.telemetry import (
+        DISTRIBUTED_INIT_SPAN, SpanLog, span_seconds)
 
+    log = SpanLog.from_wire(trace, process_id)
     os.environ.update(tpu_env)
-    jax_process.configure_compile_cache()
-    if coordinator is not None:
-        import time
-
+    with log.span("ray_tpu.train.worker.import_jax"):
+        jax_process.configure_compile_cache()
         import jax
-
+    if coordinator is not None:
         from ray_tpu.util.collective import rendezvous
 
         # initialize() blocks until every process joins — a gang rendezvous.
-        # Account the blocked time so the goodput ledger's rendezvous_wait
-        # bucket covers jax bring-up, not just the collective KV waits.
-        t0 = time.perf_counter()
+        # The span's seconds are the goodput ledger's rendezvous_wait share
+        # of bring-up, and this process's accumulator takes the same number.
         try:
-            jax.distributed.initialize(
-                coordinator_address=coordinator,
-                num_processes=num_processes,
-                process_id=process_id,
-            )
+            with log.span(DISTRIBUTED_INIT_SPAN) as span:
+                jax.distributed.initialize(
+                    coordinator_address=coordinator,
+                    num_processes=num_processes,
+                    process_id=process_id,
+                )
         finally:
-            rendezvous.note_wait(time.perf_counter() - t0)
-    if granted_chips:
-        return jax_process.require_granted_chips(granted_chips)
-    if coordinator is not None:
-        return jax_process.device_report()
-    # A lone CPU worker: leave the backend to the user loop.
-    return {}
+            rendezvous.note_wait(span_seconds(span))
+    report: Dict[str, Any] = {}
+    if granted_chips or coordinator is not None:
+        # The first jax.local_devices(): libtpu opens the chip.
+        with log.span("ray_tpu.train.worker.device_touch",
+                      TPU_VISIBLE_CHIPS=os.environ.get("TPU_VISIBLE_CHIPS", "")) as span:
+            report = (jax_process.require_granted_chips(granted_chips) if granted_chips
+                      else jax_process.device_report())
+            span["attributes"].update(platform=report["platform"],
+                                      local_devices=report["local_devices"])
+    # A lone CPU worker leaves the backend to the user loop.
+    return {**report, "spans": log.take()}
 
 
 def _shutdown_jax_distributed():
@@ -192,23 +201,30 @@ class _JaxBackend(Backend):
         )
         granted = int(executor._scaling._resources.get("TPU", 0))
         tpu_envs: List[Dict[str, str]] = [{} for _ in range(n)]
-        if granted and distributed:
-            tpu_envs = _tpu_process_envs(wg.execute(_chip_grant))
         coordinator = None
         rank_of = executor.ranks
-        if distributed:
-            # Rank 0's node hosts the jax coordination service.
-            rank0_index = rank_of.index(0)
-            meta = wg._metadata or wg.fetch_metadata()
-            port = wg.execute_single(rank0_index, _free_port_fn)
-            coordinator = f"{meta[rank0_index].node_ip}:{port}"
+        seam = {"distributed": distributed, "granted": granted}
+        executor.span_attributes(**seam)  # on the `backend` span round all of this
+        with executor.span("backend.chip_grant", **seam):
+            if granted and distributed:
+                tpu_envs = _tpu_process_envs(wg.execute(_chip_grant))
+            if distributed:
+                # Rank 0's node hosts the jax coordination service.
+                rank0_index = rank_of.index(0)
+                meta = wg._metadata or wg.fetch_metadata()
+                port = wg.execute_single(rank0_index, _free_port_fn)
+                coordinator = f"{meta[rank0_index].node_ip}:{port}"
         # All workers must enter initialize() together: fire async, then gather.
-        reports = ray_tpu.get([
-            w.execute.remote(
-                _start_jax, coordinator, n, rank_of[i], granted, tpu_envs[i]
-            )
-            for i, w in enumerate(wg.workers)
-        ])
+        with executor.span("backend.start_jax", **seam):
+            trace = executor.worker_trace()
+            reports = ray_tpu.get([
+                w.execute.remote(
+                    _start_jax, coordinator, n, rank_of[i], granted, tpu_envs[i], trace
+                )
+                for i, w in enumerate(wg.workers)
+            ])
+        for r in reports:
+            executor.note_worker_spans(r.pop("spans"))
         if distributed:
             counts = [r["global_devices"] for r in reports]
             want = n * granted if granted else counts[0]

@@ -177,8 +177,13 @@ def training_report(gang: Optional[str] = None) -> Dict[str, Any]:
     ~1.0), goodput_frac, steps, failures, elastic membership history
     (resizes, last_resize {old_world, new_world, direction, reason,
     resize_s, ckpt_source}, proactive_checkpoints), the current skew and
-    the named straggler ({rank, phase, skew_s}), and the last round's
-    per-rank phase split.
+    the named straggler ({rank, phase, skew_s}), the last round's per-rank
+    phase split, `bringup` (the seams of fit() -> train_fn entered as spans:
+    placement, spawn, backend with the chip grant and every rank's import of
+    jax, gang join and first device touch, session, mesh build, train_fn ->
+    first report; at most 64) and `compile` (what jax traced, lowered and
+    compiled: `rank0` with its functions by seconds, `gang_max` of each
+    total). The `compile` bucket needs no `mark_phase("compile")`.
 
     Returns ``{"gangs": {gang_id: report}}`` (one entry when `gang` given;
     empty when `enable_metrics` is off — nothing is published then)."""

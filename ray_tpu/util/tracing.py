@@ -296,10 +296,12 @@ def start_span(name: str, kind: str, trace_context: Optional[Dict[str, str]] = N
     return span
 
 
-def end_span(span: Optional[dict], status: str = "OK") -> None:
+def end_span(span: Optional[dict], status: str = "OK",
+             end: Optional[float] = None) -> None:
+    """Close and buffer a span; `end` where its end was read elsewhere."""
     if span is None:
         return
-    span["end"] = time.time()
+    span["end"] = time.time() if end is None else end
     span["status"] = status
     if not span.pop("_detached", False):
         _state.span = span.pop("_prev", None)
