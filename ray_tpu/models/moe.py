@@ -16,6 +16,27 @@ expert-slot axis: work and memory are linear in tokens (the Switch layer this
 replaces went through dense `(B, S, E, C)` one-hots, three times the experts'
 own FLOPs at 64 experts).
 
+What moves how. Rows (a token's `D` values) move through `_gather_rows` and
+`sum_rows`, and through nothing else. The `tokens * k` scalars of the pairs
+(the weights, the sorted expert ids, `order`, `inverse`, the router's picked
+scores, the experts' counts) move through sorts and through compares against
+an iota of `E`, and nothing gathers or scatters them one element at a time:
+XLA's gather and scatter of single 4-byte elements cost the v5e 5-10 ns an
+element whatever the array (a `f32[131072]` gather 0.93-1.34 ms, the scatter
+that built `inverse` 0.61, the scatter-add of `counts` into 64 bins 1.15),
+a sort that carries the same elements along a ninth of that (0.08-0.13 ms),
+and a compare of `tokens x k x E` inside a fusion nothing that can be seen
+(PERF.md section 6, PR 38: 15.3 of 20.8 ms under `router` and 8.8 of 31.2
+under `dispatch` in the LFM2 step were such gathers and scatters). So: the weights and the pairs'
+positions ride the one sort by expert as further operands, `inverse` is a
+second sort (of `order`, a permutation, with an iota), the weights' gradient
+rides a sort keyed by `order` home, and a pick among the `E` columns of a
+token, like a count of them, is a sum over a compare. Every one of these moves
+values or adds exact zeros: the numbers are those of the gathers, to the bit.
+The keys of every sort are all different (`order` is a permutation; the ids
+sort with the pair's position as second key), so none is asked to be stable:
+XLA takes three times as long to compile a stable sort of this length.
+
 Expert weights carry the `expert` logical axis, so a mesh with an `expert`
 axis shards them; the sorted form is partitioned by XLA from the sharding
 annotations alone (correct on any mesh).
@@ -32,7 +53,8 @@ long but the sort's own index vectors. A routing that gives this share more
 pairs than the bound takes the whole-length form, the same function at
 `tokens * k` rows, in which no grouped matmul visits the rows behind the held
 groups and `sum_rows` is never pointed at them: `lax.cond` on `held_pairs`
-picks, so no pair is ever dropped, and `aux["compact"]` says which ran. What
+picks, so no pair is ever dropped, and `aux["compact"]` says which ran (neither
+branch gathers a scalar: `tests/test_model_scopes.py` counts both). What
 the other experts would have added to a token is left out either way: the
 layer returns the partial sum that this share computes. The exchange that
 would send those pairs to their chips and bring the other chips' partial sums
@@ -93,23 +115,46 @@ def _sum_rows_bwd(k, res, g):
     return _gather_rows(g, *res, k), None, None, None
 
 
+def _iota(like):
+    return jax.lax.iota(jnp.int32, like.shape[0])
+
+
+def _carried(permutation, values):
+    """`values` carried to where a sort of `permutation` puts them: `out[permutation[i]] =
+    values[i]`. The keys are all different, so the sort need not be stable (a stable sort of
+    131,072 pairs takes XLA three times as long to compile for the v5e: PERF.md, PR 38)."""
+    return jax.lax.sort((permutation, values), num_keys=1, is_stable=False)[1]
+
+
+def _by_expert(ids, weights):
+    # A pair's position is the second key: the order of a stable sort by id, from keys that are
+    # all different (see `_carried`).
+    return tuple(jax.lax.sort((ids, _iota(ids), weights), num_keys=2, is_stable=False))
+
+
 @jax.custom_vjp
-def _sort_weights(weights, order, inverse):
-    """The weights, one per (token, expert) pair, in the order of the sorted
-    rows (`order` may be a prefix of the sort: a pair sorted behind it gets a
-    zero for its gradient). The gradient is a gather by `inverse`, where the
-    transpose jax would derive is a scatter-add of `tokens * k` updates."""
-    return weights[order]
+def _sort_weights(ids, weights):
+    """The one sort of the `tokens * k` (token, slot) pairs by expert id (and
+    by token inside an expert, as a stable sort would leave them): (the ids in
+    sorted order; `order`, the pair that each sorted row is; the weights in
+    sorted order). The pairs'
+    positions and weights ride the sort as further operands, so nothing is
+    gathered by `order` afterwards; the weights' gradient rides a sort keyed
+    by `order` home (`order` is a permutation: sorting it carries each position
+    back to its pair), where jax's own derivative of a many-operand sort
+    gathers the tangents by the sorted iota. A caller that takes a prefix of
+    the sorted weights hands back zeros behind it: a pair sorted behind the
+    prefix gets a zero for its gradient."""
+    return _by_expert(ids, weights)
 
 
-def _sort_weights_fwd(weights, order, inverse):
-    return weights[order], inverse
+def _sort_weights_fwd(ids, weights):
+    out = _by_expert(ids, weights)
+    return out, out[1]
 
 
-def _sort_weights_bwd(inverse, g):
-    if g.shape == inverse.shape:
-        return g[inverse], None, None
-    return g.at[inverse].get(mode="fill", fill_value=0), None, None
+def _sort_weights_bwd(order, g):
+    return None, _carried(order, g[2])
 
 
 _gather_rows.defvjp(_gather_rows_fwd, _gather_rows_bwd)
@@ -127,7 +172,10 @@ def route(x, router_w, k: int, norm_topk_prob: bool = False, *, bias=None, scale
     `score + bias`, the bias entering the choice only (no gradient reaches
     it: it is a buffer that a balancing rule outside the loss would move);
     the weights are the scores themselves at the chosen, renormalised with
-    `norm_topk_prob`, times `scale`.
+    `norm_topk_prob`, times `scale`. Either way `top_k` gives the choice alone
+    (its own instructions are a sort along `E`, 0.16 ms for 32,768 x 64 on the
+    v5e); the chosen scores are picked, and the experts' loads counted, by a
+    compare of the choice against an iota of `E`, summed.
     `aux` holds the two auxiliary terms of Muennighoff et al. 2024 and the
     load they are computed from (what a caller does not use of it, the
     compiler drops): `load_balance` = E * sum_e f_e * P_e with
@@ -137,33 +185,41 @@ def route(x, router_w, k: int, norm_topk_prob: bool = False, *, bias=None, scale
     n_experts = router_w.shape[-1]
     logits = jnp.einsum("td,de->te", x.astype(jnp.float32), router_w.astype(jnp.float32))
     if bias is None:
-        probs = jax.nn.softmax(logits, axis=-1)
-        weights, experts = jax.lax.top_k(probs, k)
+        scores = choice = jax.nn.softmax(logits, axis=-1)
     else:
-        probs = jax.nn.sigmoid(logits)
-        _, experts = jax.lax.top_k(probs + jax.lax.stop_gradient(bias.astype(jnp.float32)), k)
-        weights = jnp.take_along_axis(probs, experts, axis=-1)
+        scores = jax.nn.sigmoid(logits)
+        choice = scores + bias.astype(jnp.float32)
+    _, experts = jax.lax.top_k(jax.lax.stop_gradient(choice), k)
+    # A pick among E columns is a compare against an iota inside one fusion, and its gradient the
+    # same compare: the token's own score and exact zeros, summed (the module's docstring).
+    chosen = experts[..., None] == jnp.arange(n_experts, dtype=experts.dtype)  # (T, k, E)
+    weights = jnp.sum(jnp.where(chosen, scores[:, None, :], 0), axis=-1)
     if norm_topk_prob:
+        # Without the barrier XLA folds the sum of a token's k weights into the sum over E above,
+        # one reduction of k x E picks and zeros that adds the k in another order: a last bit of
+        # the weights (with it the LFM2 check's loss is the gathers' to the bit: PERF.md, PR 38).
+        weights = jax.lax.optimization_barrier(weights)
         weights = weights / weights.sum(axis=-1, keepdims=True)
     if scale != 1.0:
         weights = weights * scale
-    counts = jnp.zeros((n_experts,), jnp.int32).at[experts.reshape(-1)].add(1)
+    counts = jnp.sum(chosen, axis=(0, 1), dtype=jnp.int32)
     aux = {
         "load_balance": n_experts * jnp.sum(
-            counts.astype(jnp.float32) / x.shape[0] * probs.mean(axis=0)),
+            counts.astype(jnp.float32) / x.shape[0] * scores.mean(axis=0)),
         "z": jnp.mean(jax.scipy.special.logsumexp(logits, axis=-1) ** 2),
         "tokens_per_expert": counts,
     }
     return weights, experts, aux
 
 
-def expert_order(experts):
-    """(order, inverse) for `experts` (T, k): `order` lists the `T * k`
-    (token, slot) pairs by expert (stable, so by token inside an expert),
-    `inverse` is where each pair went."""
-    order = jnp.argsort(experts.reshape(-1), stable=True)
-    inverse = jnp.zeros_like(order).at[order].set(jnp.arange(order.shape[0], dtype=order.dtype))
-    return order, inverse
+def expert_order(experts, weights):
+    """(ids, order, inverse, sorted weights) for `experts` and `weights`
+    (T, k): `order` lists the `T * k` (token, slot) pairs by expert (stable, so
+    by token inside an expert), `ids` and the sorted weights are the experts
+    and the weights in that order, and `inverse` is where each pair went: a
+    second sort, of `order` with an iota (`_sort_weights`)."""
+    ids, order, weights = _sort_weights(experts.reshape(-1), weights.reshape(-1))
+    return ids, order, _carried(order, _iota(order)), weights
 
 
 def _under_the_current_abstract_mesh(f):
@@ -222,13 +278,13 @@ def moe_mlp(
             local = experts - held_from
             experts = jnp.where((local >= 0) & (local < n_held), local, n_held)
             sizes = sizes[held_from:held_from + n_held]
-        order, inverse = expert_order(experts)
+        ids, order, inverse, weights = expert_order(experts, weights)
         runs = sorted_runs(experts, n_held, partial)  # where each block of tokens' rows lie
         aux["held_pairs"] = jnp.sum(sizes)
     pairs = order.shape[0]
     bound = held_row_bound(pairs, n_held, n_experts)
     operands = (tokens, weights, w_gate, w_up, w_down)
-    routing = (sizes, experts, order, inverse, runs)
+    routing = (sizes, ids, order, inverse, runs)
     if bound == pairs:
         out, aux["rows_processed"] = _sorted_form(pairs, k, partial, *operands, *routing)
         aux["compact"] = jnp.zeros((), bool)
@@ -246,20 +302,20 @@ def held_row_bound(pairs: int, n_held: int, n_experts: int) -> int:
 
 
 def _sorted_form(n: int, k: int, partial: bool, tokens, weights, w_gate, w_up, w_down,
-                 sizes, experts, order, inverse, runs):
+                 sizes, ids, order, inverse, runs):
     """Everything of the layer that is as long as the sorted form, over its first
     `n` rows: (the tokens' sums (T, D), the rows that their own expert took).
     `n` is every pair, or with `partial` at least the held pairs, which the sort
-    put first."""
+    put first. `weights` and `ids` are in sorted order (`expert_order`)."""
     cdt = tokens.dtype
     with jax.named_scope("dispatch"):
         order = order[:n]
         rows = _gather_rows(tokens, order, inverse, runs, k)  # (n, D), expert order
-        row_weights = _sort_weights(weights.reshape(-1), order, inverse)  # (n,) f32
+        row_weights = weights[:n]  # (n,) f32; its gradient is zeros behind the prefix
         # A grouped matmul gives row i to the group the running sum of `sizes`
         # puts it in: a row is processed where that is its own expert.
-        group = jnp.searchsorted(jnp.cumsum(sizes), jnp.arange(n), side="right")
-        mine = group == experts.reshape(-1)[order]
+        group = jnp.searchsorted(jnp.cumsum(sizes), jnp.arange(n), side="right", method="compare_all")
+        mine = group == ids[:n]
         if partial:
             held = jnp.arange(n) < jnp.sum(sizes)  # by sorted row
             mine = mine & held

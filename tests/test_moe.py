@@ -238,3 +238,133 @@ def test_the_prefix_form_is_the_same_through_the_kernels(what):
     assert bool(aux["compact"]) and 0 < int(aux["held_pairs"]) == int(aux["rows_processed"]) <= 512
     assert np.abs(want[what]).max() > 1e-3
     np.testing.assert_allclose(got[what], want[what], rtol=1e-4, atol=1e-4)
+
+
+# ------------------------------ scalars of the pairs move through sorts and compares: the same numbers, exactly
+def gathered_route(x, router_w, k, norm_topk_prob=False, *, bias=None):
+    """`models/moe.py route` as it stood until PR 38, the reference: the picked
+    scores are `top_k`'s own values or a `take_along_axis` (a gather of
+    `tokens * k` scalars, a scatter-add backward), `counts` a scatter-add."""
+    import jax
+    import jax.numpy as jnp
+
+    n_experts = router_w.shape[-1]
+    logits = jnp.einsum("td,de->te", x.astype(jnp.float32), router_w.astype(jnp.float32))
+    if bias is None:
+        probs = jax.nn.softmax(logits, axis=-1)
+        weights, experts = jax.lax.top_k(probs, k)
+    else:
+        probs = jax.nn.sigmoid(logits)
+        _, experts = jax.lax.top_k(probs + jax.lax.stop_gradient(bias.astype(jnp.float32)), k)
+        weights = jnp.take_along_axis(probs, experts, axis=-1)
+    if norm_topk_prob:
+        weights = weights / weights.sum(axis=-1, keepdims=True)
+    counts = jnp.zeros((n_experts,), jnp.int32).at[experts.reshape(-1)].add(1)
+    aux = {"load_balance": n_experts * jnp.sum(counts.astype(jnp.float32) / x.shape[0] * probs.mean(axis=0)),
+           "z": jnp.mean(jax.scipy.special.logsumexp(logits, axis=-1) ** 2),
+           "tokens_per_expert": counts}
+    return weights, experts, aux
+
+
+def gathered_order(experts, weights, n):
+    """`expert_order` and `_sort_weights` as they stood until PR 38: (ids,
+    order, inverse, the first `n` weights in sorted order) by `argsort`, a
+    scatter of an iota and gathers; the weights' gradient is a gather by
+    `inverse` that fills with zeros behind the prefix."""
+    import jax
+    import jax.numpy as jnp
+
+    @jax.custom_vjp
+    def sort_weights(weights, order, inverse):
+        return weights[order]
+
+    def bwd(inverse, g):
+        return g.at[inverse].get(mode="fill", fill_value=0), None, None
+
+    sort_weights.defvjp(lambda weights, order, inverse: (weights[order], inverse), bwd)
+    flat = experts.reshape(-1)
+    order = jnp.argsort(flat, stable=True)
+    inverse = jnp.zeros_like(order).at[order].set(jnp.arange(order.shape[0], dtype=order.dtype))
+    return flat[order], order, inverse, sort_weights(weights.reshape(-1), order[:n], inverse)
+
+
+SCALAR_CASES = {  # tokens, experts, k, input dtype, with a selection bias, renormalised
+    "f32_k4_of_16": (96, 16, 4, "float32", False, False),
+    "bf16_k2_of_8_bias": (64, 8, 2, "bfloat16", True, True),
+    "f32_k1_of_4": (40, 4, 1, "float32", False, False),
+    "f32_k1_of_4_bias": (40, 4, 1, "float32", True, False),
+    "bf16_k8_of_64_renormalised": (128, 64, 8, "bfloat16", False, True),
+    "f32_k4_of_64_bias": (128, 64, 4, "float32", True, True),
+}
+SCALAR_WHATS = ("experts", "weights", "counts", "load_balance", "z", "d_x", "d_router_w",
+                "ids", "order", "inverse", "sorted_weights", "d_weights", "sorted_prefix", "d_weights_behind_a_prefix")
+
+
+@functools.lru_cache(maxsize=None)
+def _scalar_forms(case):
+    """{what: (as `models/moe.py` moves it, as the gather / scatter forms did)}
+    for one routing: `route`'s outputs and its gradients by the tokens and the
+    router's matrix (through the weights and both auxiliary terms), then the
+    sort of its pairs, the weights in sorted order and their gradient at whole
+    length and for a prefix that leaves a third of the pairs behind it. Expert
+    1 gets no token (its column of the router pulls away from every token);
+    every other expert gets many, so the ids are full of ties."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models import moe
+
+    tokens, n_experts, k, dtype, with_bias, renormalised = SCALAR_CASES[case]
+    keys = jax.random.split(jax.random.PRNGKey(len(case)), 6)
+    x = jax.random.normal(keys[0], (tokens, D)).astype(dtype)
+    router_w = jax.random.normal(keys[1], (D, n_experts)).at[:, 1].set(0.0).astype(dtype)
+    bias = 0.3 * jax.random.normal(keys[2], (n_experts,)).at[1].set(-50.0) if with_bias else None
+    if not with_bias:  # softmax scores: the second feature is large and positive, its column of the router negative
+        x = x.at[:, 1].set(8.0)
+        router_w = router_w.at[1, 1].set(-8.0)
+    cot = jax.random.normal(keys[3], (tokens, k))
+    pairs = tokens * k
+    prefix = pairs * 2 // 3
+    sorted_cot = jax.random.normal(keys[4], (pairs,))
+
+    def both(route, order_of):
+        def scalar(x, router_w):
+            weights, experts, aux = route(x, router_w, k, renormalised, bias=bias)
+            return jnp.sum(weights * cot) + 0.3 * aux["load_balance"] + 0.1 * aux["z"], (weights, experts, aux)
+
+        (_, (weights, experts, aux)), (d_x, d_router_w) = jax.value_and_grad(
+            scalar, argnums=(0, 1), has_aux=True)(x, router_w)
+        out = {"experts": experts, "weights": weights, "counts": aux["tokens_per_expert"],
+               "load_balance": aux["load_balance"], "z": aux["z"], "d_x": d_x, "d_router_w": d_router_w}
+        for n, value, gradient in ((pairs, "sorted_weights", "d_weights"),
+                                   (prefix, "sorted_prefix", "d_weights_behind_a_prefix")):
+            (ids, order, inverse, out[value]), vjp = jax.vjp(lambda w: order_of(experts, w, n), weights)
+            out[gradient] = vjp((np.zeros(pairs, jax.dtypes.float0),) * 3 + (sorted_cot[:n],))[0]
+        return {**out, "ids": ids, "order": order, "inverse": inverse}
+
+    def sorted_order(experts, weights, n):
+        ids, order, inverse, weights = moe.expert_order(experts, weights)
+        return ids, order, inverse, weights[:n]
+
+    got, want = both(moe.route, sorted_order), both(gathered_route, gathered_order)
+    assert int(want["counts"][1]) == 0 and int(want["counts"].max()) > 1  # an empty expert, and ties
+    return {what: (np.asarray(got[what]), np.asarray(want[what])) for what in SCALAR_WHATS}
+
+
+@pytest.mark.parametrize("what", SCALAR_WHATS)
+@pytest.mark.parametrize("case", SCALAR_CASES)
+def test_scalars_move_through_sorts_and_compares_and_not_a_bit_changes(case, what):
+    """Until PR 38 the layer gathered and scattered its `tokens * k` scalars one
+    4-byte element at a time (7 ns each on the v5e); now every such movement is
+    an operand of a sort or a compare against an iota of E. Values only move
+    and only exact zeros are added, so every output and gradient is the same
+    array (`array_equal`, not `allclose`): for float32 and bfloat16 inputs, one
+    expert a token and eight, softmax scores and sigmoids with a bias, an
+    expert that no token chose, and pairs sorted behind a prefix."""
+    got, want = _scalar_forms(case)[what]
+    assert got.dtype == want.dtype and got.shape == want.shape
+    if what.startswith("d_") or what in ("weights", "sorted_weights", "sorted_prefix"):
+        assert np.abs(want).max() > 1e-3
+    if what == "d_weights_behind_a_prefix":  # a third of the pairs lie behind the prefix: zeros
+        assert (want == 0).sum() >= want.size // 3
+    np.testing.assert_array_equal(got, want)

@@ -33,9 +33,14 @@ def _routing(name, k, tokens=TOKENS, seed=0):
     return experts.astype(np.int32)
 
 
+def _order(experts):
+    """(order, inverse) of `models/moe.py expert_order`, which sorts the pairs' weights along."""
+    return moe.expert_order(experts, jnp.zeros(experts.shape, jnp.float32))[1:3]
+
+
 def _operands(routing, k, dtype, tokens=TOKENS, width=WIDTH):
     experts = jnp.asarray(_routing(routing, k, tokens))
-    _, inverse = moe.expert_order(experts)
+    _, inverse = _order(experts)
     rows = jax.random.normal(jax.random.PRNGKey(k), (tokens * k, width), jnp.float32).astype(dtype)
     return rows, inverse, experts
 
@@ -64,7 +69,7 @@ def test_the_pieces_cover_each_blocks_rows_once_and_rows_read_counts_them(k, rou
     experts = _routing(routing, k)
     runs = sr.sorted_runs(jnp.asarray(experts), EXPERTS)
     count, tile, lo, hi = (np.asarray(a) for a in runs)
-    _, inverse = moe.expert_order(jnp.asarray(experts))
+    _, inverse = _order(jnp.asarray(experts))
     inverse = np.asarray(inverse).reshape(TOKENS // sr.BLOCK, sr.BLOCK * k)
     per_block = tile.shape[0] // count.shape[0]
     brute_force = 0
@@ -159,7 +164,7 @@ def test_rows_of_experts_held_elsewhere_are_never_read_and_a_block_may_own_none(
     experts = rng.integers(0, 8, (TOKENS, k))
     experts[sr.BLOCK:2 * sr.BLOCK] = rng.integers(held, 8, (sr.BLOCK, k))  # none of the held ones
     local = jnp.asarray(np.where(experts < held, experts, held).astype(np.int32))
-    order, inverse = moe.expert_order(local)
+    order, inverse = _order(local)
     n_held = int((np.asarray(local) < held).sum())
     rows = jax.random.normal(jax.random.PRNGKey(0), (TOKENS * k, WIDTH), jnp.float32).astype(dtype)
     rows = jnp.where((jnp.arange(TOKENS * k) < n_held)[:, None], rows, jnp.nan)
@@ -188,7 +193,7 @@ def test_a_prefix_that_holds_every_held_row_sums_to_what_all_the_rows_do(k, form
     held = 2
     experts = np.random.default_rng(k).integers(0, 8, (TOKENS, k))
     local = jnp.asarray(np.where(experts < held, experts, held).astype(np.int32))
-    _, inverse = moe.expert_order(local)
+    _, inverse = _order(local)
     n_held = int((np.asarray(local) < held).sum())
     prefix = -(-n_held // sr.BLOCK) * sr.BLOCK
     assert n_held < prefix < TOKENS * k

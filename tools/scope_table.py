@@ -1,0 +1,91 @@
+"""A traced benchmark run's device time by instruction, under the scopes the program names.
+
+    python3 benchmark/run.py --workload lfm2-24b-a2b-ep8-l5.fed4k --seed 7 --seconds 20 --trace 1
+    python3 tools/scope_table.py benchmark/out/lfm2-24b-a2b-ep8-l5.fed4k.7 router dispatch combine
+
+The first argument is a traced run's stem under a checkout's `benchmark/out/`
+(`<cell>.<seed>`, what `--trace 1` leaves there: `<stem>.trace.json` and rank
+0's raw `.xplane.pb` under `trace/<stem>.rank0/`), the others the scopes
+(`jax.named_scope` components of an `op_name`). For each scope: the busy union
+a step of everything under it (what `benchmark/harness/scope_trace.scope_ms`
+reads: `moe.router_ms`, `moe.dispatch_ms`, ...), then every device operation of
+the traced steps under it, grouped by opcode, result type, the step's phase and
+the last two components of its `op_name`: calls a step, ms a step (the sum of the calls'
+own time over the traced steps, divided by their number), most first. With
+`--json PATH` the rows are written there too, each with its instructions' names.
+
+It reads with the benchmark's own readers (`harness/xplane.py`,
+`harness/program_trace.read_xplane`) of the checkout it lies in, so a copy of
+the file dropped into an older checkout reads that checkout's run. This is how
+the by-instruction tables of PERF.md section 5 are made (PR 36, PR 38). No
+benchmark cell and no test runs it.
+"""
+
+from __future__ import annotations
+
+import argparse
+import json
+import os
+import sys
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def table(stem: str, scopes):
+    """({scope: busy-union ms a step}, rows) of the traced run at `stem`; a
+    row is `{scope, opcode, type, tail, calls, ms, names}`."""
+    from statistics import median
+
+    from benchmark.harness import program_trace, xplane
+
+    path = program_trace.raw_trace_path({"summary": {"trace_table": stem + ".trace.json"}})
+    if path is None:
+        raise SystemExit(f"no raw trace of rank 0 beside {stem}.trace.json")
+    trace = xplane.Trace(xplane.extract(path))
+    op_names = program_trace.read_xplane(path)["scopes"]
+    dev = trace.devices[0]
+    runs = trace.step_runs(dev)
+    unions, rows = {}, {}
+    for scope in scopes:
+        mine = [op for op in trace._leaf_ops(dev) if scope in op_names.get(op[0], "").split("/")]
+        spans = [(op[4], op[4] + op[5]) for op in mine]
+        unions[scope] = median(
+            xplane.measure(xplane.union(xplane.clip(spans, start, start + dur)))
+            for _, _, start, dur in runs) / 1e6 if mine and runs else None
+        for op_name, opcode, target, rtype, start, dur in mine:
+            if not any(s <= start and start + dur <= s + d for _, _, s, d in runs):
+                continue
+            tail = (program_trace.phase(op_names[op_name]) + " "
+                    + "/".join(op_names[op_name].split("/")[-2:]))
+            row = rows.setdefault((scope, target or opcode, rtype, tail), {
+                "scope": scope, "opcode": target or opcode, "type": rtype, "tail": tail,
+                "calls": 0, "ms": 0.0, "names": set()})
+            row["calls"] += 1 / len(runs)
+            row["ms"] += dur / 1e6 / len(runs)
+            row["names"].add(op_name)
+    rows = sorted(rows.values(), key=lambda r: (scopes.index(r["scope"]), -r["ms"]))
+    return unions, [{**r, "names": sorted(r["names"])} for r in rows]
+
+
+def main(argv=None):
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("stem", help="benchmark/out/<cell>.<seed> of a --trace 1 run")
+    ap.add_argument("scopes", nargs="+")
+    ap.add_argument("--json", help="also write {unions, rows} here")
+    args = ap.parse_args(argv)
+    sys.path.insert(0, REPO)
+    unions, rows = table(args.stem, args.scopes)
+    for scope in args.scopes:
+        mine = [r for r in rows if r["scope"] == scope]
+        print(f"== {scope}: busy union {unions[scope]} ms a step, "
+              f"sum of calls {sum(r['ms'] for r in mine):.3f}")
+        for r in mine:
+            print(f"{r['ms']:9.3f} ms {r['calls']:6.1f} x {r['opcode']:<16} {r['type']:<22} "
+                  f"{r['tail']}  [{', '.join(r['names'][:3])}{', ...' if len(r['names']) > 3 else ''}]")
+    if args.json:
+        with open(args.json, "w") as fh:
+            json.dump({"unions": unions, "rows": rows}, fh)
+
+
+if __name__ == "__main__":
+    main()

@@ -88,8 +88,6 @@ def main(argv=None):
             readings.append((time.perf_counter() - t0) / args.calls * 1e6)
         return median(readings)
 
-    from ray_tpu.models.moe import expert_order
-
     def xla_form(rows, inverse, experts, k, n_experts):
         by_token = rows[inverse].reshape(-1, k, rows.shape[-1])
         return by_token.astype(jnp.float32).sum(axis=1).astype(rows.dtype)
@@ -111,7 +109,9 @@ def main(argv=None):
             drawn = draw_experts(tokens, k, n_experts, routing == "skewed")
             load = np.bincount(drawn.reshape(-1), minlength=n_experts)
             experts = jnp.asarray(drawn)
-            _, inverse = jax.jit(expert_order)(experts)
+            # Where each (token, slot) pair goes in the stable sort by expert: `models/moe.py`'s
+            # `inverse`, made here so that the file runs in any checkout.
+            inverse = jnp.argsort(jnp.argsort(experts.reshape(-1), stable=True))
             want = None
             for name, fn in implementations.items():
                 line = {"shape": [tokens, k, width, n_experts], "dtype": "bfloat16", "implementation": name,
