@@ -1,0 +1,7 @@
+"""`moe.experts_ms` in `glm-4.7-flash-ep8-l5.fed4k`: that entry lists its cells and a later cell cannot
+append itself, so the cell brings the same reading under a name of its own."""
+
+from benchmark.layer_metrics import moe_experts_ms as listed
+
+META = {**listed.META, "name": "moe.experts_ms.glm-4.7-flash-ep8-l5"}
+read = listed.read

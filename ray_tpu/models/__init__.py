@@ -7,6 +7,7 @@ from ray_tpu.models.gpt import (
     param_logical_axes,
     train_flops_per_token,
 )
+from ray_tpu.models.glm4_moe_lite import GLM4MoELiteConfig
 from ray_tpu.models.lfm2 import LFM2Config
 from ray_tpu.models.llama import LlamaConfig
 from ray_tpu.models.olmoe import OLMoEConfig
@@ -21,6 +22,7 @@ from ray_tpu.models.training import (
 )
 
 __all__ = [
+    "GLM4MoELiteConfig",
     "GPTConfig",
     "LFM2Config",
     "LlamaConfig",

@@ -37,7 +37,7 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.models.llama import apply_rope, rms_norm, rope_tables
-from ray_tpu.models.moe import moe_mlp
+from ray_tpu.models.moe import moe_mlp, swiglu
 from ray_tpu.models.stack import Pattern, apply_stack, block, lm_head, lm_loss
 
 CONV, ATTENTION = "conv", "full_attention"
@@ -317,10 +317,7 @@ def _kinds(config: LFM2Config, stats: bool = False):
     def dense_ffn(x, layer):
         with jax.named_scope("dense_mlp"):
             h = rms_norm(x, layer["ffn_norm"], eps).astype(cdt)
-            gate = jnp.einsum("bsd,df->bsf", h, layer["w_gate"].astype(cdt))
-            up = jnp.einsum("bsd,df->bsf", h, layer["w_up"].astype(cdt))
-            act = (jax.nn.silu(gate.astype(jnp.float32)) * up.astype(jnp.float32)).astype(cdt)
-            return x + jnp.einsum("bsf,fd->bsd", act, layer["w_down"].astype(cdt)), None
+            return x + swiglu(h, layer["w_gate"], layer["w_up"], layer["w_down"]), None
 
     def moe_ffn(x, layer):
         with jax.named_scope("moe"):

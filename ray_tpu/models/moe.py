@@ -293,6 +293,26 @@ def moe_mlp(
     return out.reshape(B, S, D), aux
 
 
+def swiglu(x, w_gate, w_up, w_down):
+    """`W_down (silu(W_gate x) * W_up x)` of x (B, S, D) with (D, F), (D, F),
+    (F, D): operands in x's dtype, the gate in float32, rounded once."""
+    cdt = x.dtype
+    gate = jnp.einsum("bsd,df->bsf", x, w_gate.astype(cdt))
+    up = jnp.einsum("bsd,df->bsf", x, w_up.astype(cdt))
+    act = (jax.nn.silu(gate.astype(jnp.float32)) * up.astype(jnp.float32)).astype(cdt)
+    return jnp.einsum("bsf,fd->bsd", act, w_down.astype(cdt))
+
+
+def shared_expert(x, w_gate, w_up, w_down):
+    """The expert every token meets, beside the routed ones: one `swiglu`
+    outside the sort, with no router and no weight. A chip that holds a share
+    of the routed experts computes it whole, for its own tokens: across the
+    shares of a layer it is counted once, not once a share. The scope is read
+    from a device trace by the benchmark's `moe.shared_ms`."""
+    with jax.named_scope("shared_expert"):
+        return swiglu(x, w_gate, w_up, w_down)
+
+
 def held_row_bound(pairs: int, n_held: int, n_experts: int) -> int:
     """The rows of the sorted form where a layer holds `n_held` of `n_experts`
     experts: `HELD_ROWS_OVER_EVEN` times the held experts' even share of the

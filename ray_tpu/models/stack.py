@@ -228,14 +228,18 @@ def lm_head(x, norm: Callable, table, dtype):
         )
 
 
-def causal_lm_loss(logits, targets):
+def causal_lm_loss(logits, targets, mask=None):
     """Fused cross entropy: logsumexp - logit[target], one reduction over V
     instead of materializing the (B, S, V) log-softmax (saves ~2x V-sized HBM
-    traffic)."""
+    traffic). The mean is over every position, or with `mask` (broadcast
+    against (B, S)) over the positions it keeps."""
     with jax.named_scope("loss"):
         lse = jax.scipy.special.logsumexp(logits, axis=-1)
         at_target = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
-        return (lse - at_target).mean()
+        if mask is None:
+            return (lse - at_target).mean()
+        mask = jnp.broadcast_to(mask, targets.shape)
+        return jnp.where(mask, lse - at_target, 0.0).sum() / mask.sum()
 
 
 def lm_loss(forward: Callable, params, batch, config, attention_fn=None, dropout_rng=None,
@@ -243,15 +247,19 @@ def lm_loss(forward: Callable, params, batch, config, attention_fn=None, dropout
     """Causal LM cross entropy (mean over tokens) of a model's `forward` on
     `batch`, {"tokens": (B, S+1)} or {"inputs", "targets"}, plus the auxiliary
     loss `forward(..., return_aux=True)` returns beside the logits: a scalar
-    the model has already weighted, or None where it has none."""
+    the model has already weighted, or None where it has none. A model with
+    further prediction depths (`config.n_predict_layers`: each position also
+    predicts tokens beyond its next) computes their loss as that scalar, and
+    its `forward` is handed the `targets` for it."""
     if "inputs" in batch:
         inputs, targets = batch["inputs"], batch["targets"]
     else:
         tokens = batch["tokens"]
         inputs, targets = tokens[:, :-1], tokens[:, 1:]
+    deeper = {"targets": targets} if getattr(config, "n_predict_layers", 0) else {}
     logits, aux = forward(
         params, inputs, config, attention_fn, dropout_rng, mesh, num_microbatches,
-        return_aux=True,
+        return_aux=True, **deeper,
     )
     loss = causal_lm_loss(logits, targets)
     return loss if aux is None else loss + aux

@@ -16,7 +16,10 @@ Design (pallas_guide.md playbook):
  - backward: ONE fused kernel computing dk/dv per K tile and accumulating dq
    in a VMEM scratch: s/p are recomputed once per tile pair instead of twice
    (the classic two-kernel split recomputes them in both the dq and dkv
-   kernels). O(seq) memory, the point of flash attention.
+   kernels). O(seq) memory, the point of flash attention. Where a head is too
+   large for a program to hold q, do and the row statistics whole (from 2 MiB
+   a head in VMEM on: 4096 x 256 in bf16), the same fused kernel runs with a
+   program a (Q tile, K tile) pair and every operand streamed (`_bwd_pairs`).
  - matmuls run on the MXU with preferred_element_type=float32; inputs can be
    bfloat16.
 
@@ -33,6 +36,7 @@ from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -74,11 +78,43 @@ LANES = 128
 LONG_HEAD_SEQ = 4096
 LONG_HEAD_BYTES = 4096 * 128 * 2
 LONG_HEAD_TILE = 512
+# Above that size, and for any head wider than the lanes that is not unrolled,
+# the backward program no longer holds a head: it is one (Q tile, K tile) pair
+# of 512-tiles (`_bwd_pairs`), every operand the pair's own tile, streamed, and
+# a head keeps its f32 dq scratch alone. 4096 x 128 is the one size that runs
+# whole on the chip today, and nothing above it is held whole: alone, by the
+# smallest `vmem_limit_bytes` that compiles for the v5e (PR 39), the whole-head
+# form needs 8.0 MiB at 4096 x 128, 10.0 at 6144 x 128, 12.5 at 8192 x 128,
+# 14.5 at 3584 x 256, 15.5 at 4096 x 256 (the pair form 5.0, 6.0, 7.5, 8.5,
+# 9.0), and inside a step what XLA fuses into the call's operands comes on
+# top: 19.03 MiB at 4096 x 256. What a tile takes grows with the width: 2048 x
+# 256 at 1024-tiles asks for 16.75 MiB forward and 21.56 backward, 1024 x 512
+# whole at 512-tiles for 17.25.
+# The sweep on the v5e (tools/flash_bench.py, PR 39; one call at (40, 4096,
+# 256) causal, forward / backward, us; the forward is the loop form at every
+# size): 512-tiles 3070 / 5724, 256-tiles 3601 / 8674, 512 x 256 (Q x K) 3582 /
+# 7351, 256 x 512 3064 / 6412; 57 % and 61 % of the MXU's peak on the causal
+# half (the backward's five products counted as four), where jax's own flash
+# and splash kernels take 13,103 / 39,061 and 15,520 / 33,431. The same head
+# at 128 wide, (32, 4096, 128) in the loop form: 1482 / 2974.
+# The largest head the forward program holds (K and V whole, twice over: 14.0
+# MiB alone at 4096 x 256 with 512-tiles):
+MAX_HEAD_BYTES = 4096 * 256 * 2
 NEG_INF = -1e30
 
 
+def _head_bytes(seq: int, head_dim: int, itemsize: int) -> int:
+    """The VMEM one (batch, head) of q, k, v or o takes: lanes padded."""
+    return seq * max(head_dim, LANES) * itemsize
+
+
 def _long_head(seq: int, head_dim: int, itemsize: int) -> bool:
-    return seq >= LONG_HEAD_SEQ and seq * max(head_dim, LANES) * itemsize >= LONG_HEAD_BYTES
+    return seq >= LONG_HEAD_SEQ and _head_bytes(seq, head_dim, itemsize) >= LONG_HEAD_BYTES
+
+
+def _streamed_head(seq: int, head_dim: int, itemsize: int) -> bool:
+    """The backward pass holds nothing of such a head whole but its dq scratch."""
+    return _head_bytes(seq, head_dim, itemsize) > LONG_HEAD_BYTES or head_dim > LANES
 
 
 # --------------------------------------------------------------------------- XLA form
@@ -259,6 +295,38 @@ def _fwd(q, k, v, causal, sm_scale, plan, interpret):
 
 
 # --------------------------------------------------------------------------- backward kernel
+def _pair_grads(q_ref, do_ref, lse_ref, delta_ref, dq_acc, rows, acc_rows, k, v, keep, sm_scale,
+                dk, dv):
+    """One (Q tile, K tile) pair of the backward pass: s and p recomputed once,
+    `dq_acc[acc_rows]` gains the pair's dq, and (dk, dv) come back with the
+    pair's share added. `rows` are the Q tile's rows in the four refs (all of
+    a ref that is the tile itself); `keep` masks a pair the diagonal crosses."""
+    q = q_ref[0, rows, :]
+    do = do_ref[0, rows, :]
+    qs = (q.astype(jnp.float32) * sm_scale).astype(q.dtype)
+    s = jax.lax.dot_general(
+        qs, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+    )  # (tile_q, tile_k)
+    if keep is not None:
+        s = jnp.where(keep, s, NEG_INF)
+    p = jnp.exp(s - lse_ref[0, rows, :])
+    dv = dv + jax.lax.dot_general(
+        p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+    dp = jax.lax.dot_general(
+        do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+    )
+    ds = (p * (dp - delta_ref[0, rows, :]) * sm_scale).astype(q.dtype)
+    dk = dk + jax.lax.dot_general(
+        ds, q, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
+    )
+    dq_acc[acc_rows, :] += jax.lax.dot_general(
+        ds, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+    )
+    return dk, dv
+
+
 def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 dq_ref, dk_ref, dv_ref, dq_acc, *,
                 sm_scale, causal, tile_q, tile_k, static):
@@ -278,32 +346,9 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
         def q_tile(masked):
             def body(i, carry):
-                dk, dv = carry
                 rows = _tile(i, tile_q, static)
-                q = q_ref[0, rows, :]
-                do = do_ref[0, rows, :]
-                qs = (q.astype(jnp.float32) * sm_scale).astype(q.dtype)
-                s = jax.lax.dot_general(
-                    qs, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-                )  # (tile_q, tile_k)
-                if masked:
-                    s = jnp.where(keep(i, j), s, NEG_INF)
-                p = jnp.exp(s - lse_ref[0, rows, :])
-                dv = dv + jax.lax.dot_general(
-                    p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32,
-                )
-                dp = jax.lax.dot_general(
-                    do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-                )
-                ds = (p * (dp - delta_ref[0, rows, :]) * sm_scale).astype(q.dtype)
-                dk = dk + jax.lax.dot_general(
-                    ds, q, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-                )
-                dq_acc[rows, :] += jax.lax.dot_general(
-                    ds, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-                )
-                return dk, dv
+                return _pair_grads(q_ref, do_ref, lse_ref, delta_ref, dq_acc, rows, rows, k, v,
+                                   keep(i, j) if masked else None, sm_scale, *carry)
 
             return body
 
@@ -336,11 +381,103 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         pl.when(j == n_k - 1)(flush)
 
 
+def _pair_schedule(seq: int, plan: "KernelPlan", causal: bool):
+    """The pair-streamed backward pass's steps, int32 (6, steps): for each the
+    Q tile, the K tile, the dq tile its output block is, and whether it is the
+    K tile's first pair, a pair the diagonal crosses, the pair after which its
+    Q tile's dq is whole. K tiles in turn, under each its Q tiles on or under
+    the diagonal: a Q tile's last pair comes later the later the tile, so the
+    dq block of a step is the next tile to become whole, and it is written in
+    that step alone."""
+    n_q, n_k = seq // plan.tile_q, seq // plan.tile_k
+    pairs = []
+    for j in range(n_k):
+        diag, end = _diag_and_end(j, plan.tile_k, plan.tile_q, n_q, True) if causal else (0, 0)
+        pairs += [(i, j, i == diag, diag <= i < end) for i in range(diag, n_q)]
+    whole_at = {i: t for t, (i, *_) in enumerate(pairs)}
+    assert sorted(whole_at.values()) == [whole_at[i] for i in range(n_q)]
+    steps, due = [], 0
+    for t, (i, j, first, masked) in enumerate(pairs):
+        steps.append((i, j, due, first, masked, whole_at[i] == t))
+        due = min(due + (whole_at[due] == t), n_q - 1)
+    return np.asarray(steps, np.int32).T
+
+
+def _bwd_pairs_kernel(steps_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                      dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc, *,
+                      sm_scale, tile_q, tile_k):
+    """The fused backward pass with a program a (Q tile, K tile) pair
+    (`_pair_schedule`, grid axis 1, sequential): every operand is the pair's
+    own tile, streamed by the pipeline, and all a head keeps in VMEM is its
+    f32 dq (seq, d) beside the K tile's f32 dk and dv."""
+    t = pl.program_id(1)
+    i, j = steps_ref[0, t], steps_ref[1, t]
+    first, masked, whole = (steps_ref[r, t] == 1 for r in (3, 4, 5))
+    acc_rows = _tile(i, tile_q, False)
+
+    @pl.when(t == 0)
+    def _():
+        dq_acc[...] = jnp.zeros_like(dq_acc)
+
+    @pl.when(first)
+    def _():
+        dk_acc[...] = jnp.zeros_like(dk_acc)
+        dv_acc[...] = jnp.zeros_like(dv_acc)
+
+    def pair(crossed):
+        def run():
+            keep = _causal_mask(tile_q, tile_k)(i, j) if crossed else None
+            dk_acc[...], dv_acc[...] = _pair_grads(
+                q_ref, do_ref, lse_ref, delta_ref, dq_acc, slice(None), acc_rows, k_ref[0], v_ref[0],
+                keep, sm_scale, dk_acc[...], dv_acc[...])
+        return run
+
+    pl.when(masked)(pair(True))
+    pl.when(jnp.logical_not(masked))(pair(False))
+
+    @pl.when(whole)
+    def _():
+        dq_ref[0] = dq_acc[acc_rows, :].astype(dq_ref.dtype)
+
+    @pl.when(i == dq_acc.shape[0] // tile_q - 1)  # the K tile's last pair
+    def _():
+        dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
+        dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
+
+
+def _bwd_pairs(q, k, v, do, lse, delta, causal, sm_scale, plan, interpret):
+    bh, seq, d = q.shape
+    steps = _pair_schedule(seq, plan, causal)
+    tile = lambda size, width, row: pl.BlockSpec(
+        (1, size, width), lambda b, t, steps: (b, steps[row, t], 0))
+    q_tile, k_tile, stat = tile(plan.tile_q, d, 0), tile(plan.tile_k, d, 1), tile(plan.tile_q, 1, 0)
+    with jax.named_scope(plan.scope):
+        return pl.pallas_call(
+            functools.partial(_bwd_pairs_kernel, sm_scale=sm_scale,
+                              tile_q=plan.tile_q, tile_k=plan.tile_k),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1,
+                grid=(bh, steps.shape[1]),
+                in_specs=[q_tile, k_tile, k_tile, q_tile, stat, stat],
+                out_specs=[tile(plan.tile_q, d, 2), k_tile, k_tile],
+                scratch_shapes=[pltpu.VMEM((seq, d), jnp.float32),
+                                pltpu.VMEM((plan.tile_k, d), jnp.float32),
+                                pltpu.VMEM((plan.tile_k, d), jnp.float32)],
+            ),
+            out_shape=[jax.ShapeDtypeStruct((bh, seq, d), q.dtype)] * 3,
+            interpret=interpret,
+            name="flash_bwd",
+            compiler_params=_compiler_params(interpret, "parallel", "arbitrary"),
+        )(jnp.asarray(steps), q, k, v, do, lse, delta)
+
+
 def _bwd(causal, sm_scale, plan, interpret, res, g):
     q, k, v, o, lse = res
     do = g
     bh, seq, d = q.shape
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)[..., None]  # (bh, seq, 1)
+    if not plan.unrolled and _streamed_head(seq, d, q.dtype.itemsize):
+        return _bwd_pairs(q, k, v, do, lse, delta, causal, sm_scale, plan, interpret)
     tiles, head, mine = _specs(seq, plan, plan.tile_k)
     whole, stats = head(d), head(1)
     if not plan.unrolled and _long_head(seq, d, q.dtype.itemsize):
@@ -376,8 +513,8 @@ def _bwd(causal, sm_scale, plan, interpret, res, g):
 def blockwise_attention(q, k, v, causal: bool = True, sm_scale: Optional[float] = None,
                         block_k: int = 1024):
     """O(S * block_k)-memory attention as a remat'ed scan over K blocks — the
-    long-sequence path while the pallas kernels keep full-seq K/V in VMEM
-    (which caps them around S~8k at d=64). Exact, differentiable, pure XLA."""
+    long-sequence path while the forward kernel keeps full-seq K/V in VMEM
+    (which caps it at `MAX_HEAD_BYTES` a head). Exact, differentiable, pure XLA."""
     if sm_scale is None:
         sm_scale = q.shape[-1] ** -0.5
     B, H, S, D = q.shape
@@ -482,7 +619,8 @@ def _kernel_blocks(seq: int, head_dim: int, causal: bool,
         small = plan(CAUSAL_TILE)
         if small.unrolled:
             return small
-    return plan(LONG_HEAD_TILE if _long_head(seq, head_dim, itemsize) else FULL_TILE)
+    small = _long_head(seq, head_dim, itemsize) or _streamed_head(seq, head_dim, itemsize)
+    return plan(LONG_HEAD_TILE if small else FULL_TILE)
 
 
 def kernel_plan(shape, causal: bool = True,
@@ -502,14 +640,18 @@ def select_backend(shape, platform: Optional[str] = None,
     The choice is by platform and shape only, and this function is the one
     place it is made, so a caller can say which path its step compiled
     without reading the HLO: off-TPU the XLA form; on TPU the kernel while
-    K/V for one (batch, head) fit in VMEM (~2*S*D bytes in bf16: up to ~8k
-    tokens at d=64, 4k at d=128), the blockwise scan beyond that, and the XLA form for a
-    sequence with no block of at least 128 dividing it.
+    the forward program holds K and V of one (batch, head) in VMEM twice over
+    beside its tiles: a head of up to `MAX_HEAD_BYTES` there, its last
+    dimension padded to the 128 lanes (in bf16 8,192 positions at a head_dim
+    of 64 or 128, 4,096 at 256); the blockwise scan beyond that, and the XLA
+    form for a sequence with no block of at least 128 dividing it. The
+    backward program's form follows from the same count (`_bwd`): whole heads
+    up to `LONG_HEAD_BYTES`, (Q tile, K tile) pairs above.
     """
     if (platform or jax.default_backend()) != "tpu":
         return "xla"
     _, _, s, d = shape
-    if s * d > 8192 * 64:
+    if _head_bytes(s, d, 2) > MAX_HEAD_BYTES:
         return "blockwise"
     plan = kernel_plan(shape, True, block_q, block_k)
     if min(plan.tile_q, plan.tile_k) < 128:

@@ -26,6 +26,12 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 V5E_HBM_BYTES = 16_909_336_064
 B, S = 16, 1024  # the flagship cell: gpt2_small, batch 16 x seq 1024
 LONG_HEAD_64 = "kernel:8x32x4096x64"  # the lfm2 cell's attention layer: 8 rows, 32 heads of 4096 x 64
+WIDE_HEAD_256 = "kernel:2x20x4096x256"  # the glm-4.7-flash cell's: 2 rows, 20 heads of 4096 x 256
+LONGER_HEADS = ("kernel:2x32x8192x64", "kernel:1x16x8192x128")  # what failed in `flash_bwd` until PR 39
+# Between the largest head the backward program holds whole (4096 x 128) and the largest the forward
+# program holds (4096 x 256), and 2048 x 256, whose default tile (1024) failed in both kernels.
+BETWEEN_HEADS = ("kernel:2x4x3072x256", "kernel:2x4x3584x256", "kernel:2x4x6144x128",
+                 "kernel:2x4x7168x128", "kernel:2x4x2048x256")
 
 
 def _kernel_case(topo, shape=(B, 12, S, 64)):
@@ -189,7 +195,8 @@ def _run(cases):
 
 @pytest.fixture(scope="module")
 def aot():
-    return _run(["kernel", LONG_HEAD_64, "held_experts", "lower:d4", "lower:d2t2"])
+    return _run(["kernel", LONG_HEAD_64, WIDE_HEAD_256, *LONGER_HEADS, *BETWEEN_HEADS, "held_experts",
+                 "lower:d4", "lower:d2t2"])
 
 
 def test_topology_is_the_v5e(aot):
@@ -210,6 +217,24 @@ def test_flash_kernels_compile_for_v5e_at_a_head_of_4096_by_64(aot):
     assert aot[LONG_HEAD_64]["plan"] == [512, 512, 36, 8, 64, False]
 
 
+@pytest.mark.parametrize("case", [WIDE_HEAD_256, *LONGER_HEADS, *BETWEEN_HEADS])
+def test_flash_kernels_compile_for_v5e_at_heads_above_that_vmem(aot, case):
+    """`(2, 20, 4096, 256)` bf16, GLM-4.7-Flash's latent attention: a head takes
+    2 MiB of VMEM, and the loop form's backward program (q, do and two (seq, 1)
+    statistics whole, dq's output block twice, an f32 dq scratch: 18 MiB before
+    a tile) failed with RESOURCE_EXHAUSTED. For every head above 1 MiB the
+    backward program is one (Q tile, K tile) pair with every operand streamed,
+    still one fused Mosaic call; `(2, 32, 8192, 64)` and `(1, 16, 8192, 128)`,
+    PERF.md's long-context failures, take the same VMEM and compile the same
+    way, as does every head between (the same model at 3,072 or 3,584
+    positions; 128-wide heads of 6,144 and 7,168), and all of them walk
+    512-tiles: 1024-tiles at 256 wide failed in both kernels."""
+    assert aot[case]["mosaic_calls"] == 2  # forward, fused backward
+    seq = int(case.split("x")[2])
+    n = seq // 512
+    assert aot[case]["plan"] == [512, 512, n * (n + 1) // 2, n, n * n, False]
+
+
 def test_the_plans_of_the_other_cells_shapes_are_what_they_were():
     from ray_tpu.ops.flash_attention import kernel_plan
 
@@ -217,6 +242,17 @@ def test_the_plans_of_the_other_cells_shapes_are_what_they_were():
     assert kernel_plan((4, 25, 1024, 64)) == (512, 512, 3, 2, 4, True)  # gpt2-xl-fsdp4, a chip
     assert kernel_plan((2, 16, 4096, 128)) == (512, 512, 36, 8, 64, False)  # olmoe-1b-7b-l1
     assert kernel_plan((16, 32, 2048, 64)) == (512, 512, 10, 4, 16, True)  # shorter heads of 64: untouched
+    assert kernel_plan((8, 32, 4096, 64)) == (512, 512, 36, 8, 64, False)  # lfm2-24b-a2b-ep8-l5
+    # ... and the form of the backward program behind the plan: pairs streamed above 1 MiB a head.
+    import importlib
+
+    fa = importlib.import_module("ray_tpu.ops.flash_attention")
+    streamed = lambda b, h, s, d: fa._streamed_head(s, d, 2)  # noqa: E731
+    assert not any(streamed(*shape) for shape in (
+        (8, 16, 1024, 64), (4, 25, 1024, 64), (2, 16, 4096, 128), (8, 32, 4096, 64), (16, 32, 2048, 64)))
+    assert all(streamed(*shape) for shape in (
+        (2, 20, 4096, 256), (2, 32, 8192, 64), (1, 16, 8192, 128), (2, 20, 2560, 256), (2, 20, 3072, 256),
+        (2, 16, 4608, 128), (2, 16, 7680, 128), (2, 4, 2048, 256)))
 
 
 def test_a_share_of_the_experts_holds_no_array_as_long_as_every_pair_where_the_prefix_runs(aot):

@@ -37,11 +37,16 @@ SHARED = ("embed", "blocks", "qkv", "attention", "head", "loss", "optimizer")
 OWN = {"gpt": ("out_mlp",), "llama": ("out_mlp",),
        "olmoe": ("attn_out", "moe", "router", "dispatch", "experts", "combine"),
        "lfm2": ("attn_out", "moe", "router", "dispatch", "experts", "combine",
-                "short_conv", "conv_mix", "dense_mlp")}
+                "short_conv", "conv_mix", "dense_mlp"),
+       # PR 39: latent attention, a shared expert, and the prediction module's scope round a block,
+       # a head and a loss of its own (`jvp(mtp)` in a compiled step: the parts are split on brackets).
+       "glm4": ("attn_out", "moe", "router", "dispatch", "experts", "combine",
+                "mla_latent", "shared_expert", "dense_mlp", "mtp")}
 # For lfm2 also what a block with no attention in its middle holds: all of it is recomputed.
 HALVES = {"gpt": {"qkv", "out_mlp"}, "llama": {"qkv", "out_mlp"},
           "olmoe": {"qkv", "attn_out", "moe", "router", "experts"},
-          "lfm2": {"qkv", "attn_out", "moe", "router", "experts", "short_conv", "conv_mix", "dense_mlp"}}
+          "lfm2": {"qkv", "attn_out", "moe", "router", "experts", "short_conv", "conv_mix", "dense_mlp"},
+          "glm4": {"qkv", "attn_out", "moe", "router", "experts", "mla_latent", "shared_expert", "dense_mlp"}}
 SCOPES = SHARED + OWN["gpt"] + ("grad_norm",)
 # This tree's programs (ahead-of-time compile for v5e:2x2 on this installation,
 # pinned at PR 30, which stored the attention weights as matrices): instructions
@@ -199,7 +204,7 @@ def _nano_step(model, remat_policy):
     from ray_tpu import models
 
     config = {"gpt": models.GPTConfig, "llama": models.LlamaConfig, "olmoe": models.OLMoEConfig,
-              "lfm2": models.LFM2Config}
+              "lfm2": models.LFM2Config, "glm4": models.GLM4MoELiteConfig}
     cfg = config[model].nano(remat=remat_policy != "off",
                              remat_policy=None if remat_policy == "off" else remat_policy)
     opt = models.default_optimizer()
@@ -213,7 +218,9 @@ def _nano_step(model, remat_policy):
     # `llama.py` named nothing before PR 31: its step fell into no phase.
     ("llama", "save_attn"), ("olmoe", "save_attn"),
     # A patterned stack (PR 35): leading layers, a scan over periods, layers with no attention.
-    ("lfm2", "save_attn"), ("lfm2", "off")])
+    ("lfm2", "save_attn"), ("lfm2", "off"),
+    # Latent attention, a shared expert and a second prediction depth (PR 39).
+    ("glm4", "save_attn"), ("glm4", "off")])
 def test_what_the_nano_step_names_falls_into_the_phases(model, remat_policy):
     """Of the compiled instructions that carry an `op_name` (on the CPU four
     in ten carry none: converts, constants and fusions the compiler made),

@@ -102,6 +102,60 @@ def test_kernel_matches_xla_in_both_forms_of_the_schedule(seq, causal, block_q, 
     assert max(errs.values()) <= TOLERANCE[jnp.float32], (plan, errs)
 
 
+@pytest.fixture
+def every_head_is_streamed(monkeypatch):
+    """The backward pass of the loop form as it runs for heads above 4096 x 128
+    (bf16): a program a (Q tile, K tile) pair, every operand streamed."""
+    import importlib
+
+    fa = importlib.import_module("ray_tpu.ops.flash_attention")
+    monkeypatch.setattr(fa, "LONG_HEAD_BYTES", -1)
+    monkeypatch.setattr(fa, "MAX_UNROLLED_HEAD_BYTES", 0)
+    return fa
+
+
+@pytest.mark.parametrize("seq,head_dim,causal,block_q,block_k,dtype", [
+    (1024, 256, True, 256, 256, jnp.float32),  # the GLM head's width: 10 of 16 pairs, 4 masked
+    (1024, 256, True, 256, 256, jnp.bfloat16),
+    (1024, 256, False, 256, 256, jnp.float32),  # nothing skipped: dq whole at the last K tile only
+    (1024, 64, True, 128, 256, jnp.float32),  # tiles that are not square, both ways
+    (1024, 64, True, 256, 128, jnp.float32),
+    (1024, 128, False, 256, 128, jnp.float32),
+    (512, 256, True, 512, 512, jnp.float32),  # one pair a head
+])
+def test_the_pair_streamed_backward_matches_xla(every_head_is_streamed, seq, head_dim, causal, block_q,
+                                                block_k, dtype):
+    plan = kernel_plan((1, 2, seq, head_dim), causal, block_q, block_k, dtype=dtype)
+    assert (plan.tile_q, plan.tile_k, plan.unrolled) == (block_q, block_k, False)
+    errs = _kernel_against_xla(seq, head_dim, causal, dtype, block_q=block_q, block_k=block_k)
+    assert max(errs.values()) <= TOLERANCE[dtype], (plan, errs)
+
+
+def test_the_pair_schedule_visits_each_pair_once_and_writes_each_dq_tile_when_it_is_whole():
+    import importlib
+
+    fa = importlib.import_module("ray_tpu.ops.flash_attention")
+    for seq, tq, tk, causal in ((4096, 512, 512, True), (4096, 512, 512, False), (2048, 256, 512, True),
+                                (2048, 512, 256, True), (1024, 128, 256, False)):
+        plan = kernel_plan((1, 1, seq, 256), causal, tq, tk)
+        i, j, due, first, masked, whole = fa._pair_schedule(seq, plan, causal)
+        n_q, n_k = seq // tq, seq // tk
+        pairs = list(zip(i.tolist(), j.tolist()))
+        assert len(pairs) == len(set(pairs)) == plan.tiles_visited and int(masked.sum()) == plan.tiles_masked
+        want = {(a, b) for a in range(n_q) for b in range(n_k) if not causal or b * tk < (a + 1) * tq}
+        assert set(pairs) == want
+        assert j.tolist() == sorted(j.tolist()) and int(first.sum()) == n_k  # K tiles in turn
+        # Every K tile's pairs end at the last Q tile, where dk and dv are written.
+        assert all(i[t] == n_q - 1 for t in range(len(pairs)) if t + 1 == len(pairs) or j[t + 1] != j[t])
+        # A dq tile is written once, in its last pair, into the block the step's output is;
+        # that block never changes before it has been written, and never comes back after.
+        assert int(whole.sum()) == n_q and all(due[t] == i[t] for t in range(len(pairs)) if whole[t])
+        assert all(t == max(u for u, a in enumerate(i) if a == i[t]) for t in range(len(pairs)) if whole[t])
+        assert due.tolist() == sorted(due.tolist())
+        assert all(whole[t] for t in range(len(pairs) - 1) if due[t + 1] != due[t])
+    assert kernel_plan((2, 20, 4096, 256), True) == (512, 512, 36, 8, 64, False)  # glm-4.7-flash: 36 pairs a head
+
+
 def test_kernel_plan_counts_the_triangle():
     # A square causal schedule of n x n tiles visits n(n+1)/2 and masks n.
     for seq, tile in ((1024, 256), (1024, 128), (2048, 256), (512, 512), (4096, 256)):
@@ -147,7 +201,14 @@ def test_misaligned_seq_selection_is_visible_not_silent():
         flash_attention(q, q, q, backend="pallas", interpret=True, block_q=64, block_k=64)
     assert select_backend(q.shape, platform="tpu") == "xla"
     assert select_backend((16, 12, 1024, 64), platform="tpu") == "pallas"
-    assert select_backend((1, 8, 8192, 128), platform="tpu") == "blockwise"
+    # By the VMEM a head takes, lanes padded (PR 39): up to 4096 x 256 in bf16, which is
+    # 8192 x 128 and 8192 x 64 too; the scan over K blocks beyond that.
+    assert select_backend((1, 8, 8192, 128), platform="tpu") == "pallas"
+    assert select_backend((2, 20, 4096, 256), platform="tpu") == "pallas"
+    assert select_backend((2, 32, 8192, 64), platform="tpu") == "pallas"
+    assert select_backend((1, 8, 8192 + 512, 128), platform="tpu") == "blockwise"
+    assert select_backend((1, 8, 8192 + 512, 64), platform="tpu") == "blockwise"
+    assert select_backend((1, 8, 4096 + 512, 256), platform="tpu") == "blockwise"
     assert select_backend((16, 12, 1024, 64), platform="cpu") == "xla"
     out = flash_attention(q, q, q)  # this process is on CPU: the XLA form
     ref = xla_attention(q, q, q, causal=True)

@@ -111,7 +111,7 @@ def test_multiplexed_deployment_handle_and_context(ray_start_regular):
     that holds it)."""
     from ray_tpu import serve
 
-    serve.start(http_options={"location": "NoServer"})
+    serve.start(http_options={"location": "NoServer", "port": 0})
 
     @serve.deployment(max_concurrent_queries=4)
     class Multi:
@@ -152,7 +152,7 @@ def test_multiplexed_over_http_header(ray_start_regular):
     from ray_tpu import serve
     from ray_tpu.serve.multiplex import MODEL_ID_HEADER
 
-    serve.start()
+    serve.start(http_options={"port": 0})  # a free port: other serve test files hold 8000 on other xdist workers
 
     @serve.deployment
     class Multi:
@@ -182,7 +182,7 @@ def test_multiplexed_streaming_generator(ray_start_regular):
     context fix): each streamed chunk can consult the request's model."""
     from ray_tpu import serve
 
-    serve.start(http_options={"location": "NoServer"})
+    serve.start(http_options={"location": "NoServer", "port": 0})
 
     @serve.deployment(max_concurrent_queries=2)
     class Streamer:
@@ -213,7 +213,7 @@ def test_model_affinity_routing(ray_start_regular):
 
     from ray_tpu import serve
 
-    serve.start(http_options={"location": "NoServer"})
+    serve.start(http_options={"location": "NoServer", "port": 0})
 
     @serve.deployment(num_replicas=2, max_concurrent_queries=2)
     class Multi:
@@ -246,7 +246,7 @@ def test_model_affinity_load_escape(ray_start_regular):
 
     from ray_tpu import serve
 
-    serve.start(http_options={"location": "NoServer"})
+    serve.start(http_options={"location": "NoServer", "port": 0})
 
     @serve.deployment(num_replicas=2, max_concurrent_queries=1)
     class Slow:
