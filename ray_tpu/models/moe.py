@@ -17,7 +17,14 @@ replaces went through dense `(B, S, E, C)` one-hots, three times the experts'
 own FLOPs at 64 experts).
 
 What moves how. Rows (a token's `D` values) move through `_gather_rows` and
-`sum_rows`, and through nothing else. The `tokens * k` scalars of the pairs
+`sum_rows`, and through nothing else: where the sorted form is every pair,
+XLA's gather by `order // k` (at its floor out of 8,192 tokens) and the
+`sum_rows` kernel at 512-row chunks; where it is a prefix of the sort (below),
+`sum_rows` at the chunk the held share gives (`ops/sum_rows.py chunk_rows`)
+and, out of a source too large for XLA's gather to stay fast (`_rows_by`), the
+`gather_rows` kernel, both a block of 128 tokens at a time over the runs the
+sort left that block's rows in, so that nothing is copied or written for a
+pair held elsewhere beyond the 8-row tiles a run touches. The `tokens * k` scalars of the pairs
 (the weights, the sorted expert ids, `order`, `inverse`, the router's picked
 scores, the experts' counts) move through sorts and through compares against
 an iota of `E`, and nothing gathers or scatters them one element at a time:
@@ -49,7 +56,10 @@ are a prefix of the sort, and the sorted form is that prefix alone: the first
 `held_row_bound` rows (twice what an even router gives the held experts, a
 static count) are gathered, multiplied, gated and summed, by the kernels and
 by XLA's operations between them alike, and no array is `tokens * k` rows
-long but the sort's own index vectors. A routing that gives this share more
+long but the sort's own index vectors. Of those rows only the owned ones are
+written by the gather (and zeros to the next row tile): the grouped matmuls
+visit no other, `sum_rows` is pointed at no other, and the selects between
+them (`_held_rows`) let nothing else through. A routing that gives this share more
 pairs than the bound takes the whole-length form, the same function at
 `tokens * k` rows, in which no grouped matmul visits the rows behind the held
 groups and `sum_rows` is never pointed at them: `lax.cond` on `held_pairs`
@@ -71,7 +81,7 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.ops.grouped_matmul import grouped_matmul
-from ray_tpu.ops.sum_rows import sorted_runs, sum_rows
+from ray_tpu.ops.sum_rows import gather_rows, sorted_runs, sum_rows
 
 # Where a layer holds some of the experts, its sorted form is as long as this many times the
 # pairs an even router gives them, and the whole-length form takes a routing that gives it
@@ -79,6 +89,7 @@ from ray_tpu.ops.sum_rows import sorted_runs, sum_rows
 # the pairs where the even share is 0.125 (PERF.md section 6, PR 35 and PR 36).
 HELD_ROWS_OVER_EVEN = 2
 ROW_TILE = 512  # the longest row tile a kernel takes (`grouped_matmul.DRHS_ROW_TILES`)
+GATHER_SOURCE_BYTES = 128 * 2 ** 20  # from here on a prefix is gathered by the kernel (`_rows_by`)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
@@ -89,11 +100,26 @@ def _gather_rows(x, order, inverse, runs, k: int):
     sort, so the gradient sums each token's `k` rows where they lie
     (`_sum_rows`; `runs` says where, `ops/sum_rows.py sorted_runs`), where the
     transpose jax would derive is a scatter-add of `tokens * k` rows."""
+    return _rows_by(x, order, inverse, runs, k)
+
+
+def _rows_by(x, order, inverse, runs, k):
+    """Which gather runs, read off the shapes. XLA's gather writes 4 KB rows
+    at 14-24 ns a row out of a source of 33-100 MB (OLMoE's 65,536 rows out of
+    8,192 tokens at 650-730 GB/s, at its floor) and at 36 ns a row, a copy
+    descriptor's price, out of one of 128 MiB, which is also the v5e's VMEM:
+    so a prefix of the sort (`order` shorter than `inverse`: half of its rows
+    are nobody's) out of a source that large goes through `ops/sum_rows.py
+    gather_rows`, the kernel that writes the owned rows alone a block of tokens
+    at a time (0.63 ms for 1.19 at LFM2's 32,768 x 2,048; 0.23 for 0.16 at
+    GLM's 8,192, 0.37 for 0.38 at 16,384: PERF.md section 6, PR 40)."""
+    if order.shape[0] < inverse.shape[0] and x.size * x.dtype.itemsize >= GATHER_SOURCE_BYTES:
+        return gather_rows(x, order, inverse, runs, k)
     return x[order // k]
 
 
 def _gather_rows_fwd(x, order, inverse, runs, k):
-    return x[order // k], (order, inverse, runs)
+    return _rows_by(x, order, inverse, runs, k), (order, inverse, runs)
 
 
 def _gather_rows_bwd(k, res, g):

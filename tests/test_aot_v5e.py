@@ -30,6 +30,9 @@ WIDE_HEAD_256 = "kernel:2x20x4096x256"  # the glm-4.7-flash cell's: 2 rows, 20 h
 LONGER_HEADS = ("kernel:2x32x8192x64", "kernel:1x16x8192x128")  # what failed in `flash_bwd` until PR 39
 # Between the largest head the backward program holds whole (4096 x 128) and the largest the forward
 # program holds (4096 x 256), and 2048 x 256, whose default tile (1024) failed in both kernels.
+# The expert layers of the lfm2 and glm-4.7-flash cells: 8 x 4,096 and 2 x 4,096 tokens, 4 of 64 experts a
+# token, 8 held: a prefix of 32,768 and of 8,192 sorted rows of 2,048.
+ROW_MOVERS = ("row_movers:32768", "row_movers:8192")
 BETWEEN_HEADS = ("kernel:2x4x3072x256", "kernel:2x4x3584x256", "kernel:2x4x6144x128",
                  "kernel:2x4x7168x128", "kernel:2x4x2048x256")
 
@@ -49,6 +52,31 @@ def _kernel_case(topo, shape=(B, 12, S, 64)):
     compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(x, x, x).compile()
     return {"mosaic_calls": compiled.as_text().count("tpu_custom_call"),
             "plan": list(kernel_plan(shape))}
+
+
+def _row_movers_case(topo, tokens, k=4, width=2048, n_experts=64, held=8):
+    """`gather_rows` and `sum_rows` over the prefix of a layer that holds `held`
+    of `n_experts` experts, at a cell's shapes, one device."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models import moe
+    from ray_tpu.ops import sum_rows as sr
+
+    one = jax.sharding.SingleDeviceSharding(topo.devices[0])
+    n = moe.held_row_bound(tokens * k, held, n_experts)
+
+    def both(x, experts, rows):
+        _, order, inverse, _ = moe.expert_order(experts, jnp.zeros(experts.shape, jnp.float32))
+        runs = sr.sorted_runs(experts, held, True)
+        return (sr.gather_rows(x, order[:n], inverse, runs, k, backend="pallas"),
+                sr.sum_rows(rows, inverse, runs, k, backend="pallas"))
+
+    shapes = [jax.ShapeDtypeStruct(shape, dtype, sharding=one) for shape, dtype in (
+        ((tokens, width), jnp.bfloat16), ((tokens, k), jnp.int32), ((n, width), jnp.bfloat16))]
+    text = jax.jit(both).lower(*shapes).compile().as_text()
+    return {"rows": n, "chunk_rows": [sr.chunk_rows(width, 2, k, n, tokens * k, times) for times in (2, 1)],
+            "kernels": sorted(re.findall(r"(gather_rows|sum_rows)[.\d]* = ", text))}
 
 
 def _lowered_step(topo, axes, cfg, rows, seq):
@@ -156,7 +184,7 @@ def _held_experts_case(topo):
                      n_experts_held=2, first_expert_held=4, max_seq_len=1024)
     text = _lowered_step(topo, {"data": 1}, cfg, 2, 1024).compile().as_text()
     by_branch, outside = wide_results_by_branch(text, r"\[4096,(256|128)\]")
-    kernels = sorted(set(re.findall(r"(gmm_\w+?|sum_rows)[.\d]* = ", text)))
+    kernels = sorted(set(re.findall(r"(gmm_\w+?|sum_rows|gather_rows)[.\d]* = ", text)))
     return {"wide_by_branch": by_branch, "wide_outside": outside, "kernels": kernels}
 
 
@@ -174,6 +202,8 @@ def _main(cases):
             results[case] = _kernel_case(topo)
         elif case == "held_experts":
             results[case] = _held_experts_case(topo)
+        elif case.startswith("row_movers:"):
+            results[case] = _row_movers_case(topo, int(case[len("row_movers:"):]))
         elif case.startswith("kernel:"):
             results[case] = _kernel_case(topo, tuple(int(n) for n in case[len("kernel:"):].split("x")))
         else:
@@ -196,7 +226,7 @@ def _run(cases):
 @pytest.fixture(scope="module")
 def aot():
     return _run(["kernel", LONG_HEAD_64, WIDE_HEAD_256, *LONGER_HEADS, *BETWEEN_HEADS, "held_experts",
-                 "lower:d4", "lower:d2t2"])
+                 *ROW_MOVERS, "lower:d4", "lower:d2t2"])
 
 
 def test_topology_is_the_v5e(aot):
@@ -268,7 +298,19 @@ def test_a_share_of_the_experts_holds_no_array_as_long_as_every_pair_where_the_p
     assert len(got["wide_by_branch"]) == 4 and got["wide_outside"] == 0, got
     prefix, whole = sorted(got["wide_by_branch"])[:2], sorted(got["wide_by_branch"])[2:]
     assert prefix == [0, 0] and min(whole) > 0, got
+    # 2,048 tokens of 256: a source that XLA's gather serves (`moe._rows_by`), so no `gather_rows`.
     assert got["kernels"] == ["gmm_dlhs", "gmm_drhs", "gmm_fwd", "sum_rows"]
+
+
+@pytest.mark.parametrize("case, rows", zip(ROW_MOVERS, (32768, 8192)))
+def test_the_row_movers_of_the_prefix_form_compile_for_v5e_at_the_cells_shapes(aot, case, rows):
+    """`gather_rows` (tokens into expert order, written a block of tokens at a
+    time, at 128-row chunks) and `sum_rows` at the 256-row chunk that a layer
+    holding an eighth of the experts gets (`chunk_rows`; 512 where every pair
+    is held): one Mosaic call each, at the prefix lengths the LFM2 and
+    GLM-4.7-Flash steps run (the second's gather stays XLA's in the step,
+    `moe._rows_by`: the kernel compiles there all the same)."""
+    assert aot[case] == {"rows": rows, "chunk_rows": [256, 128], "kernels": ["gather_rows", "sum_rows"]}
 
 
 @pytest.mark.parametrize("mesh", ["d4", "d2t2"])

@@ -88,9 +88,18 @@ KERNELS = {
 # `dispatch` / `combine` (tokens into expert order, the results' gradient likewise). OLMoE: one layer,
 # one form. LFM2: four layers, each with the whole-length form (131,072 rows) and the prefix form
 # (32,768) behind `lax.cond`; in a form, the forward pass's gather and, in the backward `cond`, the
-# forward pass again and the gradient's: (1 + 2) x 2 forms x 4 layers. Its program is pinned by
-# nothing else here (57 s of compile: `PARENT`'s three take 120).
-ROW_GATHERS = {"olmoe-1b-7b-l1": 2, "lfm2-24b-a2b-ep8-l5": 24}
+# forward pass again and the gradient's: (1 + 2) x 2 forms x 4 layers, until PR 40. Since then the
+# prefix form's three a layer are calls of the `gather_rows` kernel (`PREFIX_KERNELS`) and the
+# whole-length form's stay XLA's: 3 x 4. Its program is pinned by nothing else here (57 s of compile:
+# `PARENT`'s three take 120).
+ROW_GATHERS = {"olmoe-1b-7b-l1": 2, "lfm2-24b-a2b-ep8-l5": 12}
+# The row movers of the LFM2 step's prefix form, `jit(_prefix_or_whole)/cond/branch_1_fun`, by (phase,
+# the scope of `moe_mlp` they stand in, under `jvp(sorted_form)`: the forward pass made again inside
+# the backward `cond`): four layers of each. `moe.dispatch_ms` reads both kernels through these scopes.
+PREFIX_KERNELS = {
+    "gather_rows": [("backward", "combine", False), ("backward", "dispatch", True), ("forward", "dispatch", False)],
+    "sum_rows": [("backward", "dispatch", False), ("forward", "combine", False)],
+}
 # Temporaries of the steps before PR 30. gpt2-xl-fsdp4 must stay under its own
 # (a cold run peaks 219 MiB from the chip's limit: PERF.md section 7); the
 # one-chip step came out 999,936 bytes (0.011 %) over, in XLA's packing of the
@@ -321,6 +330,7 @@ def _aot_main(cells):
             "output": mem.output_size_in_bytes, "alias": mem.alias_size_in_bytes,
             "mosaic_scopes": [scopes.get(INSTRUCTION.match(line).group(1), "") for line in mosaic],
             "phases": sorted({phase(n) for n in scopes.values()}),
+            "recomputed": sum("rematted_computation" in n.split("/") for n in scopes.values()),
         }
         out[cell]["gather_minor_dims"], out[cell]["gathered_weight_copies"] = (
             block_weight_gathers(text, scopes))
@@ -412,6 +422,33 @@ def test_the_sorted_rows_are_only_permuted_and_summed(aot, what, count):
                              if kind not in ("gather", "reduce_sum", "pallas_call") for n in names],
            "backward scatter-add": scatter_adds}.get(what, moved.get(what, []))
     assert len(got) == count, (what, moved, scatter_adds)
+
+
+def test_the_lfm2_step_makes_nothing_again_that_it_kept_before(aot):
+    """XLA keeps most of what `save_attn` says to make again in this step (PR
+    36), and what tips it back is not the step's memory: three small index
+    tables among what the expert layer keeps for its backward pass made it
+    recompute three layers' routers, sorts and short convolutions, 16 ms of
+    440 on the chip (1,427 instructions under `rematted_computation` for the
+    142 that the dense layer's and the attention layer's recomputation hold;
+    PERF.md section 6, PR 40). A PR that changes what a layer keeps sees it here."""
+    assert aot["lfm2-24b-a2b-ep8-l5"]["recomputed"] <= 142
+
+
+@pytest.mark.parametrize("kernel", sorted(PREFIX_KERNELS))
+def test_the_prefix_form_moves_its_rows_through_the_two_kernels(aot, kernel):
+    """Where a layer holds some of the experts, the branch that runs over the
+    held prefix gathers its tokens by `gather_rows` (forward, forward again in
+    the backward `cond`, and as `combine`'s transpose) and sums by `sum_rows`
+    (`combine`, and `dispatch`'s transpose): no XLA gather of rows is left in
+    it (`ROW_GATHERS` counts the whole-length branch's three a layer), and the
+    whole-length branch calls no `gather_rows`."""
+    scopes = [n for n in aot["lfm2-24b-a2b-ep8-l5"]["mosaic_scopes"] if n.split("/")[-2] == kernel]
+    prefix = [n for n in scopes if "branch_1_fun" in n.split("jit(_prefix_or_whole)/cond/")[1].split("/")[0]]
+    where = sorted((phase(n), *({"dispatch", "combine"} & set(n.split("/"))), "jvp(sorted_form)" in n.split("/"))
+                   for n in prefix)
+    assert where == sorted(PREFIX_KERNELS[kernel] * 4), where
+    assert len(scopes) - len(prefix) == {"gather_rows": 0, "sum_rows": 8}[kernel]
 
 
 @pytest.mark.parametrize("cell", sorted(ROW_GATHERS))

@@ -68,7 +68,7 @@ def test_the_kernel_sums_what_the_xla_form_sums(k, routing, dtype):
 def test_the_pieces_cover_each_blocks_rows_once_and_rows_read_counts_them(k, routing):
     experts = _routing(routing, k)
     runs = sr.sorted_runs(jnp.asarray(experts), EXPERTS)
-    count, tile, lo, hi = (np.asarray(a) for a in runs)
+    count, tile, lo, hi = (np.asarray(a) for a in runs[:4])
     _, inverse = _order(jnp.asarray(experts))
     inverse = np.asarray(inverse).reshape(TOKENS // sr.BLOCK, sr.BLOCK * k)
     per_block = tile.shape[0] // count.shape[0]
@@ -83,9 +83,11 @@ def test_the_pieces_cover_each_blocks_rows_once_and_rows_read_counts_them(k, rou
         brute_force += sum(len(set(inverse[b][by_expert == e] // sr.PIECE)) for e in range(EXPERTS))
     assert sr.rows_read(experts, sr.PIECE) == brute_force * sr.PIECE == count.sum() * sr.PIECE
     assert TOKENS * k <= brute_force * sr.PIECE <= TOKENS * k + 2 * sr.PIECE * count.shape[0] * EXPERTS
-    # The kernel reads whole chunks: a block's last one is filled up from tile 0.
-    chunk = sr.chunk_rows(WIDTH, 2)
-    assert sr.rows_read(experts, chunk) == sum(-(-n * sr.PIECE // chunk) * chunk for n in count)
+    # At the largest chunk the kernel reads whole chunks: a block's last one is filled up from tile 0.
+    # At a smaller one (k = 1: a block's 128 rows) it copies the block's own pieces and no other.
+    chunk = sr.chunk_rows(WIDTH, 2, k, TOKENS * k, TOKENS * k)
+    assert chunk == (256 if k == 1 else 512)
+    assert sr.rows_read(experts, chunk) == sum(-(-n * sr.PIECE // chunk) * chunk if k > 1 else n * sr.PIECE for n in count)
 
 
 @pytest.mark.parametrize("poisoned", ("rows_of_other_blocks", "the_stage_before_the_call"))
@@ -209,3 +211,144 @@ def test_a_prefix_that_holds_every_held_row_sums_to_what_all_the_rows_do(k, form
     if form == "xla":
         g = jax.grad(lambda r: sr.xla_sum_rows(r, inverse, k).sum())(rows[:prefix])
         np.testing.assert_array_equal(np.asarray(g), np.ones((prefix, WIDTH), np.float32))
+
+
+# ------------------------------------------- gather_rows: the prefix form's tokens in expert order
+G_EXPERTS, G_HELD, G_TOKENS = 24, 3, 4 * sr.BLOCK  # an eighth of the experts held, as the cells hold
+
+
+def _held_routing(name, k, seed=0):
+    """(tokens, k) int32 over `G_EXPERTS` experts, of which the first `G_HELD` are held."""
+    rng = np.random.default_rng(seed)
+    experts = np.argsort(rng.random((G_TOKENS, G_EXPERTS)), axis=1)[:, :k]  # k distinct a token
+    if name == "one_held_expert_takes_twice_its_share":
+        more = (rng.random(G_TOKENS) < 0.5 * k / G_EXPERTS / (1 - k / G_EXPERTS)) & ~(experts == 0).any(axis=1)
+        experts[more, 0] = 0
+    elif name == "a_block_of_tokens_with_no_held_pair":
+        experts[sr.BLOCK:2 * sr.BLOCK] = rng.integers(G_HELD, G_EXPERTS, (sr.BLOCK, k))
+    elif name == "runs_that_end_on_a_tile_edge":  # a block's runs are 8 and 16 rows: every tile is one run's
+        experts = rng.integers(G_HELD, G_EXPERTS, (G_TOKENS, k))
+        for b in range(G_TOKENS // sr.BLOCK):
+            experts[b * sr.BLOCK:b * sr.BLOCK + 8, 0] = 0
+            experts[b * sr.BLOCK + 8:b * sr.BLOCK + 24, 0] = 2
+    elif name == "a_held_expert_with_no_row":  # the middle one: its neighbours' groups touch
+        experts = np.where(experts == 1, G_EXPERTS - 1, experts)
+    elif name == "groups_shorter_than_a_tile":  # three groups inside the first tile
+        experts = rng.integers(G_HELD, G_EXPERTS, (G_TOKENS, k))
+        experts[[3, 200, 300], 0] = 0
+        experts[[5, 450], 1] = 1
+        experts[[7, 130, 131, 480], 0] = 2
+    return experts.astype(np.int32)
+
+
+HELD_ROUTINGS = ("even", "one_held_expert_takes_twice_its_share", "a_block_of_tokens_with_no_held_pair",
+                 "runs_that_end_on_a_tile_edge", "a_held_expert_with_no_row", "groups_shorter_than_a_tile")
+
+
+def _prefix(routing, k, seed=0):
+    """(local ids, order of the prefix, inverse, runs, owned rows) as `moe_mlp` makes them."""
+    experts = _held_routing(routing, k, seed)
+    local = jnp.asarray(np.where(experts < G_HELD, experts, G_HELD).astype(np.int32))
+    order, inverse = _order(local)
+    n = moe.held_row_bound(G_TOKENS * k, G_HELD, G_EXPERTS)
+    owned = int((np.asarray(local) < G_HELD).sum())
+    assert owned <= n < G_TOKENS * k
+    return local, order[:n], inverse, sr.sorted_runs(local, G_HELD, True), owned
+
+
+@pytest.mark.parametrize("dtype", (jnp.bfloat16, jnp.float32), ids=("bf16", "f32"))
+@pytest.mark.parametrize("routing", HELD_ROUTINGS)
+@pytest.mark.parametrize("k", (4, 8))
+def test_gather_rows_writes_every_owned_row_and_zeros_to_the_next_row_tile(k, routing, dtype):
+    """Every owned row is its token's row, bit for bit (one row times 1.0, the
+    rest times exact zeros); behind them zeros up to a multiple of `ZEROED`,
+    so that a kernel that loads a row tile across the last group's end
+    multiplies nothing that is not a number. The stage starts as NaN."""
+    _, order, inverse, runs, owned = _prefix(routing, k)
+    x = jax.random.normal(jax.random.PRNGKey(k), (G_TOKENS, WIDTH), jnp.float32).astype(dtype)
+    got = sr.gather_rows(x, order, inverse, runs, k, backend="pallas",
+                         interpret=pltpu.InterpretParams(uninitialized_memory="nan"))
+    assert got.dtype == dtype and got.shape == (order.shape[0], WIDTH)
+    got, want = np.asarray(got, np.float32), np.asarray(x[order // k], np.float32)
+    np.testing.assert_array_equal(got[:owned], want[:owned])
+    assert not got[owned:min(-(-owned // sr.ZEROED) * sr.ZEROED, order.shape[0])].any()
+
+
+def test_gather_rows_off_the_tpu_and_untiled_is_the_xla_gather():
+    _, order, inverse, runs, _ = _prefix("even", 4)
+    x = jax.random.normal(jax.random.PRNGKey(0), (G_TOKENS, WIDTH), jnp.float32)
+    np.testing.assert_array_equal(np.asarray(sr.gather_rows(x, order, inverse, runs, 4)), np.asarray(x[order // 4]))
+    np.testing.assert_array_equal(np.asarray(sr.gather_rows(x[:, :64], order, inverse, runs, 4)),
+                                  np.asarray(x[:, :64][order // 4]))
+    with pytest.raises(ValueError, match="does not tile"):
+        sr.gather_rows(x[:, :64], order, inverse, runs, 4, backend="pallas")
+
+
+@pytest.mark.parametrize("which", ("gather", "sum"))
+@pytest.mark.parametrize("k", (4, 8))
+def test_the_pair_of_kernels_are_each_others_transposes_as_jax_derives_them(k, which):
+    """`_gather_rows`' gradient is `_sum_rows` and the other way round
+    (`models/moe.py`), both through the kernels here: against the transposes
+    jax derives for the plain gather and the plain gather-and-sum, over the
+    owned rows (a cotangent is zeros behind them, as `moe_mlp`'s masks make it)."""
+    _, order, inverse, runs, owned = _prefix("even", k)
+    n = order.shape[0]
+    is_owned = (jnp.arange(n) < owned)[:, None]
+    keys = jax.random.split(jax.random.PRNGKey(k), 3)
+    x = jax.random.normal(keys[0], (G_TOKENS, WIDTH), jnp.float32)
+    rows = jnp.where(is_owned, jax.random.normal(keys[1], (n, WIDTH), jnp.float32), 0)
+    kernels = dict(backend="pallas", interpret=True)
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(moe, "sum_rows", functools.partial(sr.sum_rows, **kernels))
+        patch.setattr(moe, "gather_rows", functools.partial(sr.gather_rows, **kernels))
+        if which == "gather":
+            got = jax.vjp(lambda x: moe._gather_rows(x, order, inverse, runs, k), x)[1](rows)[0]
+            want = jax.vjp(lambda x: x[order // k], x)[1](rows)[0]
+        else:
+            g = jax.random.normal(keys[2], (G_TOKENS, WIDTH), jnp.float32)
+            got = jax.vjp(lambda r: moe._sum_rows(r, order, inverse, runs, k), rows)[1](g)[0]
+            want = jax.vjp(lambda r: sr.xla_sum_rows(r, inverse, k), rows)[1](g)[0]
+            got, want = got[:owned], want[:owned]
+    assert np.abs(np.asarray(want)).max() > 1
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-6, atol=1e-6)
+
+
+@pytest.mark.parametrize("cell, k, rows, pairs, chunk", [
+    ("olmoe-1b-7b-l1", 8, 65536, 65536, 512), ("lfm2-24b-a2b-ep8-l5", 4, 32768, 131072, 256),
+    ("glm-4.7-flash-ep8-l5", 4, 8192, 32768, 256), ("lfm2-24b-a2b-ep8-l5, every pair", 4, 131072, 131072, 512)])
+def test_a_chunk_is_sized_by_the_share_of_the_pairs_the_rows_hold(cell, k, rows, pairs, chunk):
+    assert sr.chunk_rows(2048, 2, k, rows, pairs) == chunk
+    assert sr.chunk_rows(2048, 4, k, rows, pairs) == min(chunk, 256)  # float32: 512 rows do not fit a slot
+    assert sr.chunk_rows(1 << 20, 2, k, rows, pairs) is None
+
+
+def test_the_rows_moved_for_a_layer_that_holds_an_eighth_of_the_experts():
+    """At the cells' geometry (4 of 64 experts a token, 8 held) a block of
+    tokens owns 64 rows in 8 runs: `sum_rows` at 256-row chunks reads the
+    tiles of its runs, under half of the 512 rows a block it read before, and
+    `gather_rows` writes every tile once."""
+    experts = np.argsort(np.random.default_rng(0).random((16 * sr.BLOCK, 64)), axis=1)[:, :4]
+    owned = int((experts < 8).sum())
+    at_512, at_256, tiles = (sr.rows_read(experts, c, 8) for c in (512, 256, sr.PIECE))
+    assert owned < at_256 == tiles < 2.2 * owned and at_512 == 16 * 512 > 3.5 * owned
+    runs = sum(len(np.unique(b[b < 8])) for b in experts.reshape(16, -1))
+    assert owned <= sr.rows_written(experts, 8) <= owned + sr.PIECE * runs + sr.ZEROED
+    assert sr.rows_written(experts, 8) - (-(-owned // sr.ZEROED) * sr.ZEROED - -(-owned // sr.PIECE) * sr.PIECE) <= tiles
+    # Every pair owned: what `rows_read` counted before it learnt of a prefix.
+    assert sr.rows_read(experts % 8) == sr.rows_read(experts % 8, 512, 8)
+
+
+@pytest.mark.parametrize("tokens, rows, kernel", [
+    (32768, 32768, True), (8192, 8192, False), (16384, 16384, False), (32768, 131072, False), (8192, 65536, False)],
+    ids=("lfm2", "glm-4.7-flash", "half of lfm2", "lfm2, every pair", "olmoe"))
+def test_which_gather_runs_is_read_off_the_shapes(tokens, rows, kernel):
+    """`moe._rows_by`: the kernel for a prefix of the sort out of a source of
+    128 MiB or more, XLA's gather for everything else."""
+    called = []
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(moe, "gather_rows", lambda x, order, *_: called.append(order.shape) or x[order // 4])
+        k = 8 if rows == 65536 else 4
+        out = jax.eval_shape(lambda x, order, inverse: moe._rows_by(x, order, inverse, None, k),
+                             *(jax.ShapeDtypeStruct(*s) for s in (((tokens, 2048), jnp.bfloat16), ((rows,), jnp.int32),
+                                                                 ((tokens * k,), jnp.int32))))
+    assert out.shape == (rows, 2048) and bool(called) == kernel
