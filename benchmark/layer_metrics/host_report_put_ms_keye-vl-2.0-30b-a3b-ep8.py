@@ -1,0 +1,7 @@
+"""`host.report_put_ms` in `keye-vl-2.0-30b-a3b-ep8.fed16k`: that entry lists its cells and a later cell cannot
+append itself, so the cell brings the same reading under a name of its own."""
+
+from benchmark.layer_metrics import host_report_put_ms as listed
+
+META = {**listed.META, "name": "host.report_put_ms.keye-vl-2.0-30b-a3b-ep8"}
+read = listed.read

@@ -1,0 +1,7 @@
+"""`moe.held_pairs_share` in `keye-vl-2.0-30b-a3b-ep8.fed16k`: that entry lists its cells and a later cell cannot
+append itself, so the cell brings the same reading under a name of its own."""
+
+from benchmark.layer_metrics import moe_held_pairs_share as listed
+
+META = {**listed.META, "name": "moe.held_pairs_share.keye-vl-2.0-30b-a3b-ep8"}
+read = listed.read

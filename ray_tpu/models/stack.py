@@ -54,7 +54,8 @@ class Pattern(NamedTuple):
 
 
 def block(x, layer, config, qkv_part: Optional[Callable], out_part: Callable,
-          attention_fn: Optional[Callable] = None, mesh=None, streams: tuple = (), rng=None):
+          attention_fn: Optional[Callable] = None, mesh=None, streams: tuple = (), rng=None,
+          attend: Optional[Callable] = None):
     """One block on x (B, S, D): `out_part`'s (x, aux), under the remat the
     config asks for. `remat_policy` None recomputes everything in the block;
     "dots" saves matmul outputs across the remat boundary (less recompute,
@@ -68,6 +69,14 @@ def block(x, layer, config, qkv_part: Optional[Callable], out_part: Callable,
     input ("save_attn" has nothing of it to save), "dots" keeping its matmul
     outputs.
 
+    A layer whose attention is more than a function of q, k and v (a learned
+    selection of keys, ...) brings `attend`: its `qkv_part` yields `(q, k, v,
+    more)`, `attend(q, k, v, more, attention_fn, mesh)` stands where the
+    dispatch stands and yields `(o, further)`, and `out_part(x, o, layer, rng,
+    further)` takes what it yields beside `o` (a loss of its own to add to the
+    aux sum, ...). Under "save_attn" it is not recomputed, like the call it
+    replaces: what it keeps for its backward pass is saved.
+
     Scope names are read from the compiled program's `op_name`s by whoever
     splits a device trace by part of the step (PERF.md, "names")."""
     save_attn = config.remat and config.remat_policy == "save_attn" and qkv_part is not None
@@ -79,10 +88,13 @@ def block(x, layer, config, qkv_part: Optional[Callable], out_part: Callable,
         if qkv_part is None:
             return out_part(x, None, layer, rng)
         with jax.named_scope("qkv"):
-            q, k, v = qkv_part(x, layer, *streams)
+            q, k, v, *more = qkv_part(x, layer, *streams)
         with jax.named_scope("attention"):
-            o = resolve_attention(q, k, v, config.attention, attention_fn, mesh)  # (B, nh, S, hd)
-        return out_part(x, o, layer, rng)
+            if attend is None:
+                o, further = resolve_attention(q, k, v, config.attention, attention_fn, mesh), ()  # (B, nh, S, hd)
+            else:
+                o, *further = attend(q, k, v, *more, attention_fn, mesh)
+        return out_part(x, o, layer, rng, *further)
 
     if config.remat and not save_attn:
         dots = jax.checkpoint_policies.dots_with_no_batch_dims_saveable
@@ -104,6 +116,7 @@ def apply_stack(
     num_microbatches: Optional[int] = None,
     seq_streams: tuple = (),
     layers_rng=None,
+    attend: Optional[Callable] = None,  # `block`'s: in place of the attention dispatch, in every layer
 ) -> Tuple[Any, Any]:
     """Returns (activations, aux_sum): `block` over every layer, `out_part`'s
     scalar aux summed. `seq_streams` are per-position arrays (leading dim S,
@@ -125,7 +138,7 @@ def apply_stack(
             if mb_idx is not None:
                 # Independent dropout mask per microbatch under PP.
                 rng = jax.random.fold_in(rng, mb_idx)
-        return block(x, layer, config, *pattern.kinds[kind], attn, mesh, streams, rng)
+        return block(x, layer, config, *pattern.kinds[kind], attn, mesh, streams, rng, attend)
 
     def period_fn(first_layer, attn, mb_idx, streams, x, xs):
         """The scan's body over (a period's layers, idx), once the first four

@@ -20,6 +20,24 @@ Design (pallas_guide.md playbook):
    large for a program to hold q, do and the row statistics whole (from 2 MiB
    a head in VMEM on: 4096 x 256 in bf16), the same fused kernel runs with a
    program a (Q tile, K tile) pair and every operand streamed (`_bwd_pairs`).
+ - forward, where a head is too large for a program to hold K and V (above
+   2 MiB a head in VMEM: from 8,192 x 128 in bf16 on), where key/value heads
+   are fewer than query heads, or where the call brings a selection: the
+   backward's pair form (`_fwd_pairs`): a program a (Q tile, K tile) pair
+   under a scalar-prefetched schedule, Q tiles in turn and under each its K
+   tiles up to the diagonal, the running maximum, sum and f32 output of the Q
+   tile in VMEM scratch, every operand the pair's own tile. A query head
+   reads key/value head `head // group` by index map: k and v are never
+   repeated, and dk, dv leave the backward kernel a query head each for the
+   caller to sum.
+ - a selection (`keep`): which keys each query may attend to, shared by the
+   heads of a row, a bit a pair (`pack_keep`: 33.5 MB a row of 16,384). Both
+   pair kernels take the pair's (tile_q, 128) block of words as one more
+   streamed operand and mask every pair by it (`_keep_tile`: a shift and a
+   mask a lane tile), the diagonal's mask beside it where it crosses. Every
+   tile pair on or under the diagonal is still visited: a token-level choice
+   of 2,048 keys in 16,384 leaves none empty (`dsa.live_tiles_share` 1.0,
+   PERF.md section 6, PR 42).
  - matmuls run on the MXU with preferred_element_type=float32; inputs can be
    bfloat16.
 
@@ -100,7 +118,33 @@ LONG_HEAD_TILE = 512
 # The largest head the forward program holds (K and V whole, twice over: 14.0
 # MiB alone at 4096 x 256 with 512-tiles):
 MAX_HEAD_BYTES = 4096 * 256 * 2
+# Above it the forward program holds no head either: a program is one (Q tile, K
+# tile) pair with every operand streamed (`_fwd_pairs`, the forward in the
+# backward's `_bwd_pairs` form), up to the head whose f32 dq scratch (seq x 128
+# lanes x 4 B, 8 MiB at 16,384) the backward program still holds beside its
+# tiles. The same two programs take a call with a selection (`keep`) or with
+# fewer key/value heads than query heads, whatever its size: the selection's
+# tile and the shared key/value head are one more index map there.
+MAX_STREAMED_HEAD_BYTES = 16384 * 128 * 2
+# The pair forms' K tile at heads no wider than the lanes. The sweep on the v5e
+# (tools/flash_bench.py, PR 42; one call at (32, 16384, 128) causal, both
+# passes a pair a program, forward / backward, us): 512 x 1024 (Q x K) 17,879 /
+# 34,335; 512-tiles 30,825 / 41,677; 256 x 512 37,740 / 50,000; 512 x 256
+# 57,147 / 57,165; 256-tiles 69,526 / 94,960. A program is short here (a pair
+# of 512-tiles is 0.13 GFLOP forward) and pays its start-up and the (tile_q, 1)
+# statistics' lane-padded blocks every time: the longer K tile halves the
+# programs. What compiles ahead of time for the v5e under the 16 MiB: these
+# five and 256 x 1024; a Q tile of 1,024 fails in `flash_bwd` (its f32 dq
+# scratch alone is 8 MiB), a K tile of 2,048 in `flash_bwd` at any Q tile.
+PAIRS_TILE_K = 1024
 NEG_INF = -1e30
+# A selection (`keep`) is packed a bit a (query, key) pair, shared by the heads of
+# a row: word [q, span * 128 + lane] of int32 holds in bit b the key
+# `span * 4096 + b * 128 + lane`, so a K tile of 128 * n keys is n bits of one
+# (tile_q, 128) block, each a shift and a mask of whole lane tiles away
+# (`_keep_tile`): 33.5 MB a row of 16,384 where a byte a pair is 268.
+KEEP_BITS = 32
+KEEP_SPAN = KEEP_BITS * LANES
 
 
 def _head_bytes(seq: int, head_dim: int, itemsize: int) -> int:
@@ -118,10 +162,43 @@ def _streamed_head(seq: int, head_dim: int, itemsize: int) -> bool:
 
 
 # --------------------------------------------------------------------------- XLA form
-def xla_attention(q, k, v, causal: bool = True, sm_scale: Optional[float] = None):
-    """Plain-XLA attention (fused well by the compiler; O(S^2) memory)."""
+def pack_keep(mask):
+    """(..., queries, keys) bool -> (..., queries, spans * 128) int32, the packed
+    form every `keep` argument of this file takes (`KEEP_SPAN`)."""
+    *lead, keys = mask.shape
+    spans = -(-keys // KEEP_SPAN)
+    bits = jnp.pad(mask, [(0, 0)] * len(lead) + [(0, spans * KEEP_SPAN - keys)])
+    bits = bits.reshape(*lead, spans, KEEP_BITS, LANES).astype(jnp.uint32)
+    words = jnp.sum(bits << jnp.arange(KEEP_BITS, dtype=jnp.uint32)[:, None], axis=-2, dtype=jnp.uint32)
+    return jax.lax.bitcast_convert_type(words, jnp.int32).reshape(*lead, spans * LANES)
+
+
+def unpack_keep(keep, keys: int):
+    """`pack_keep`'s inverse: (..., queries, spans * 128) int32 -> (..., queries, keys) bool."""
+    *lead, width = keep.shape
+    words = keep.reshape(*lead, width // LANES, 1, LANES)
+    bits = (words >> jnp.arange(KEEP_BITS, dtype=jnp.int32)[:, None]) & 1
+    return bits.reshape(*lead, width * KEEP_BITS)[..., :keys] != 0
+
+
+def _repeat_kv(q, k, v):
+    """k and v at the query heads' count: query head a reads key/value head
+    `a // group` (what the pair-streamed kernels do by index map)."""
+    group = q.shape[1] // k.shape[1]
+    if group == 1:
+        return k, v
+    return jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1)
+
+
+def xla_attention(q, k, v, causal: bool = True, sm_scale: Optional[float] = None, keep=None,
+                  return_lse: bool = False):
+    """Plain-XLA attention (fused well by the compiler; O(S^2) memory). `keep`
+    (batch, queries, spans * 128), packed (`pack_keep`): the keys each query may
+    attend to, the same for every head. With `return_lse` also each row's
+    log-sum-exp of the scaled scores it attends to, (batch, heads, queries) f32."""
     if sm_scale is None:
         sm_scale = q.shape[-1] ** -0.5
+    k, v = _repeat_kv(q, k, v)
     s = jnp.einsum(
         "bhqd,bhkd->bhqk", q, k, preferred_element_type=jnp.float32
     ) * sm_scale
@@ -129,8 +206,13 @@ def xla_attention(q, k, v, causal: bool = True, sm_scale: Optional[float] = None
         qlen, klen = s.shape[-2], s.shape[-1]
         mask = jnp.tril(jnp.ones((qlen, klen), dtype=bool), k=klen - qlen)
         s = jnp.where(mask, s, NEG_INF)
+    if keep is not None:
+        s = jnp.where(unpack_keep(keep, s.shape[-1])[:, None], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
-    return jnp.einsum("bhqk,bhkd->bhqd", p, v, preferred_element_type=jnp.float32).astype(q.dtype)
+    o = jnp.einsum("bhqk,bhkd->bhqd", p, v, preferred_element_type=jnp.float32).astype(q.dtype)
+    if return_lse:
+        return o, jax.lax.stop_gradient(jax.scipy.special.logsumexp(s, axis=-1))
+    return o
 
 
 # --------------------------------------------------------------------------- tile schedule
@@ -179,6 +261,26 @@ def _causal_mask(tile_q, tile_k):
         return cache[offset]
 
     return keep
+
+
+def _keep_tile(keep_ref, j, tile_k):
+    """The selection's (tile_q, tile_k) bool for K tile `j`, out of the packed
+    (1, tile_q, 128) block that holds it: tile_k / 128 bits of every word, a
+    lane tile each."""
+    bits = tile_k // LANES
+    words = keep_ref[0]
+    first = (j % (KEEP_SPAN // tile_k)) * bits
+    return jnp.concatenate([(words >> (first + b)) & 1 for b in range(bits)], axis=1) != 0
+
+
+def _keep_spec(heads: int, tile_q: int, tile_k: int, q_row: int, k_row: int):
+    """The block of a pair's selection under a scalar-prefetched schedule whose
+    rows `q_row` and `k_row` are the pair's tiles: the row's own (grid axis 0
+    counts batch * heads), the Q tile's, the span the K tile lies in."""
+    assert KEEP_SPAN % tile_k == 0 and tile_k % LANES == 0, f"a K tile of {tile_k} splits no span of {KEEP_SPAN}"
+    per_span = KEEP_SPAN // tile_k
+    return pl.BlockSpec((1, tile_q, LANES),
+                        lambda b, t, steps: (b // heads, steps[q_row, t], steps[k_row, t] // per_span))
 
 
 # --------------------------------------------------------------------------- forward kernel
@@ -294,6 +396,116 @@ def _fwd(q, k, v, causal, sm_scale, plan, interpret):
     return o, lse
 
 
+def _fwd_schedule(seq: int, plan: "KernelPlan", causal: bool):
+    """The pair-streamed forward pass's steps, int32 (5, steps): for each the Q
+    tile, the K tile, and whether it is the Q tile's first pair, a pair the
+    diagonal crosses, the Q tile's last pair. Q tiles in turn, under each its K
+    tiles on or under the diagonal: a Q tile's output block is the same over its
+    run of steps and is written in the last."""
+    n_q, n_k = seq // plan.tile_q, seq // plan.tile_k
+    steps = []
+    for i in range(n_q):
+        diag, end = _diag_and_end(i, plan.tile_q, plan.tile_k, n_k, True) if causal else (n_k, n_k)
+        steps += [(i, j, j == 0, diag <= j < end, j == end - 1) for j in range(end)]
+    return np.asarray(steps, np.int32).T
+
+
+def _fwd_pairs_kernel(steps_ref, q_ref, k_ref, v_ref, *refs, sm_scale, tile_q, tile_k, has_keep):
+    """The forward pass with a program a (Q tile, K tile) pair (`_fwd_schedule`,
+    grid axis 1, sequential): every operand is the pair's own tile, streamed,
+    and all a Q tile keeps in VMEM over its pairs is its running maximum, sum
+    and f32 output. `keep_ref` (with `has_keep`) is the pair's tile of the
+    packed selection."""
+    keep_ref = refs[0] if has_keep else None
+    o_ref, lse_ref, m_acc, l_acc, o_acc = refs[has_keep:]
+    t = pl.program_id(1)
+    i, j = steps_ref[0, t], steps_ref[1, t]
+    first, masked, last = (steps_ref[r, t] == 1 for r in (2, 3, 4))
+
+    @pl.when(first)
+    def _():
+        m_acc[...] = jnp.full_like(m_acc, NEG_INF)
+        l_acc[...] = jnp.zeros_like(l_acc)
+        o_acc[...] = jnp.zeros_like(o_acc)
+
+    def pair(crossed):
+        def run():
+            q = (q_ref[0].astype(jnp.float32) * sm_scale).astype(q_ref.dtype)
+            v = v_ref[0]
+            s = jax.lax.dot_general(
+                q, k_ref[0], (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+            mask = _causal_mask(tile_q, tile_k)(i, j) if crossed else None
+            if has_keep:
+                kept = _keep_tile(keep_ref, j, tile_k)
+                mask = kept if mask is None else mask & kept
+            if mask is not None:
+                s = jnp.where(mask, s, NEG_INF)
+            m_prev = m_acc[...]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            if has_keep:
+                # A row may keep no key of this tile, and none of any tile before it:
+                # its maximum is still NEG_INF, and exp(0) must not count.
+                p = jnp.where(mask, p, 0.0)
+            alpha = jnp.exp(m_prev - m_new)
+            l_acc[...] = l_acc[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+            o_acc[...] = o_acc[...] * alpha + jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+            m_acc[...] = m_new
+        return run
+
+    pl.when(masked)(pair(True))
+    pl.when(jnp.logical_not(masked))(pair(False))
+
+    @pl.when(last)
+    def _():
+        l = jnp.maximum(l_acc[...], 1e-30)
+        o_ref[0] = (o_acc[...] / l).astype(o_ref.dtype)
+        lse_ref[0] = m_acc[...] + jnp.log(l)
+
+
+def _pair_specs(plan, d: int, group: int, q_row: int, k_row: int):
+    """(spec of a Q tile `width` wide, of a key/value tile) under a
+    scalar-prefetched schedule: a query head reads key/value head `head // group`."""
+    q_tile = lambda width, row=q_row: pl.BlockSpec(
+        (1, plan.tile_q, width), lambda b, t, steps: (b, steps[row, t], 0))
+    kv = (lambda b: b) if group == 1 else (lambda b: b // group)
+    k_tile = pl.BlockSpec((1, plan.tile_k, d), lambda b, t, steps: (kv(b), steps[k_row, t], 0))
+    return q_tile, k_tile
+
+
+def _fwd_pairs(q, k, v, keep, causal, sm_scale, plan, interpret):
+    """q (batch * heads, seq, d); k, v (batch * kv heads, seq, d); keep
+    (batch, seq, spans * 128) or None."""
+    bh, seq, d = q.shape
+    group = bh // k.shape[0]
+    steps = _fwd_schedule(seq, plan, causal)
+    q_tile, k_tile = _pair_specs(plan, d, group, 0, 1)
+    in_specs, operands = [q_tile(d), k_tile, k_tile], [q, k, v]
+    if keep is not None:
+        in_specs.append(_keep_spec(bh // keep.shape[0], plan.tile_q, plan.tile_k, 0, 1))
+        operands.append(keep)
+    with jax.named_scope(plan.scope):
+        return pl.pallas_call(
+            functools.partial(_fwd_pairs_kernel, sm_scale=sm_scale, tile_q=plan.tile_q,
+                              tile_k=plan.tile_k, has_keep=keep is not None),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1,
+                grid=(bh, steps.shape[1]),
+                in_specs=in_specs,
+                out_specs=[q_tile(d), q_tile(1)],
+                scratch_shapes=[pltpu.VMEM((plan.tile_q, 1), jnp.float32),
+                                pltpu.VMEM((plan.tile_q, 1), jnp.float32),
+                                pltpu.VMEM((plan.tile_q, d), jnp.float32)],
+            ),
+            out_shape=[jax.ShapeDtypeStruct((bh, seq, d), q.dtype),
+                       jax.ShapeDtypeStruct((bh, seq, 1), jnp.float32)],
+            interpret=interpret,
+            name="flash_fwd",
+            compiler_params=_compiler_params(interpret, "parallel", "arbitrary"),
+        )(jnp.asarray(steps), *operands)
+
+
 # --------------------------------------------------------------------------- backward kernel
 def _pair_grads(q_ref, do_ref, lse_ref, delta_ref, dq_acc, rows, acc_rows, k, v, keep, sm_scale,
                 dk, dv):
@@ -403,13 +615,16 @@ def _pair_schedule(seq: int, plan: "KernelPlan", causal: bool):
     return np.asarray(steps, np.int32).T
 
 
-def _bwd_pairs_kernel(steps_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                      dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc, *,
-                      sm_scale, tile_q, tile_k):
+def _bwd_pairs_kernel(steps_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *refs,
+                      sm_scale, tile_q, tile_k, has_keep=False):
     """The fused backward pass with a program a (Q tile, K tile) pair
     (`_pair_schedule`, grid axis 1, sequential): every operand is the pair's
     own tile, streamed by the pipeline, and all a head keeps in VMEM is its
-    f32 dq (seq, d) beside the K tile's f32 dk and dv."""
+    f32 dq (seq, d) beside the K tile's f32 dk and dv. `keep_ref` (with
+    `has_keep`) is the pair's tile of the packed selection, which then masks
+    every pair, beside the diagonal where it crosses."""
+    keep_ref = refs[0] if has_keep else None
+    dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc = refs[has_keep:]
     t = pl.program_id(1)
     i, j = steps_ref[0, t], steps_ref[1, t]
     first, masked, whole = (steps_ref[r, t] == 1 for r in (3, 4, 5))
@@ -427,6 +642,9 @@ def _bwd_pairs_kernel(steps_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref
     def pair(crossed):
         def run():
             keep = _causal_mask(tile_q, tile_k)(i, j) if crossed else None
+            if has_keep:
+                kept = _keep_tile(keep_ref, j, tile_k)
+                keep = kept if keep is None else keep & kept
             dk_acc[...], dv_acc[...] = _pair_grads(
                 q_ref, do_ref, lse_ref, delta_ref, dq_acc, slice(None), acc_rows, k_ref[0], v_ref[0],
                 keep, sm_scale, dk_acc[...], dv_acc[...])
@@ -445,20 +663,25 @@ def _bwd_pairs_kernel(steps_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref
         dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
 
 
-def _bwd_pairs(q, k, v, do, lse, delta, causal, sm_scale, plan, interpret):
+def _bwd_pairs(q, k, v, do, lse, delta, causal, sm_scale, plan, interpret, keep=None):
+    """k and v may hold fewer heads than q (batch * kv heads, seq, d): dk and dv
+    still come back a query head each, for the caller to sum over the group."""
     bh, seq, d = q.shape
     steps = _pair_schedule(seq, plan, causal)
     tile = lambda size, width, row: pl.BlockSpec(
         (1, size, width), lambda b, t, steps: (b, steps[row, t], 0))
     q_tile, k_tile, stat = tile(plan.tile_q, d, 0), tile(plan.tile_k, d, 1), tile(plan.tile_q, 1, 0)
+    kv_tile = k_tile if k.shape[0] == bh else _pair_specs(plan, d, bh // k.shape[0], 0, 1)[1]
+    extra = [] if keep is None else [keep]
+    keep_specs = [_keep_spec(bh // keep.shape[0], plan.tile_q, plan.tile_k, 0, 1)] if extra else []
     with jax.named_scope(plan.scope):
         return pl.pallas_call(
             functools.partial(_bwd_pairs_kernel, sm_scale=sm_scale,
-                              tile_q=plan.tile_q, tile_k=plan.tile_k),
+                              tile_q=plan.tile_q, tile_k=plan.tile_k, has_keep=keep is not None),
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=1,
                 grid=(bh, steps.shape[1]),
-                in_specs=[q_tile, k_tile, k_tile, q_tile, stat, stat],
+                in_specs=[q_tile, kv_tile, kv_tile, q_tile, stat, stat] + keep_specs,
                 out_specs=[tile(plan.tile_q, d, 2), k_tile, k_tile],
                 scratch_shapes=[pltpu.VMEM((seq, d), jnp.float32),
                                 pltpu.VMEM((plan.tile_k, d), jnp.float32),
@@ -468,7 +691,7 @@ def _bwd_pairs(q, k, v, do, lse, delta, causal, sm_scale, plan, interpret):
             interpret=interpret,
             name="flash_bwd",
             compiler_params=_compiler_params(interpret, "parallel", "arbitrary"),
-        )(jnp.asarray(steps), q, k, v, do, lse, delta)
+        )(jnp.asarray(steps), q, k, v, do, lse, delta, *extra)
 
 
 def _bwd(causal, sm_scale, plan, interpret, res, g):
@@ -574,6 +797,35 @@ def _flash_bwd_rule(causal, sm_scale, plan, interpret, res, g):
 _flash_bhsd.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
+def _flash_pairs(q, k, v, keep, causal, sm_scale, plan, interpret):
+    """Both passes a (Q tile, K tile) pair a program: (o, lse) of q (batch *
+    heads, seq, d) on k, v (batch * kv heads, seq, d) under `keep` (batch, seq,
+    spans * 128; None: every key). `lse` (batch * heads, seq, 1) carries no
+    gradient: it is for whoever needs the probabilities again."""
+    return _fwd_pairs(q, k, v, keep, causal, sm_scale, plan, interpret)
+
+
+def _flash_pairs_fwd(q, k, v, keep, causal, sm_scale, plan, interpret):
+    o, lse = _fwd_pairs(q, k, v, keep, causal, sm_scale, plan, interpret)
+    return (o, lse), (q, k, v, keep, o, lse)
+
+
+def _flash_pairs_bwd(causal, sm_scale, plan, interpret, res, g):
+    q, k, v, keep, o, lse = res
+    do = g[0]
+    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)[..., None]
+    dq, dk, dv = _bwd_pairs(q, k, v, do, lse, delta, causal, sm_scale, plan, interpret, keep)
+    group = q.shape[0] // k.shape[0]
+    if group > 1:  # a key/value head's gradient is its query heads' sum
+        dk, dv = (x.reshape(k.shape[0], group, *x.shape[1:]).sum(axis=1, dtype=jnp.float32).astype(x.dtype)
+                  for x in (dk, dv))
+    return dq, dk, dv, None
+
+
+_flash_pairs.defvjp(_flash_pairs_fwd, _flash_pairs_bwd)
+
+
 class KernelPlan(NamedTuple):
     """The tile schedule one (batch, head) program of both kernels walks."""
 
@@ -593,23 +845,28 @@ class KernelPlan(NamedTuple):
 
 def _kernel_blocks(seq: int, head_dim: int, causal: bool,
                    block_q: Optional[int] = None, block_k: Optional[int] = None,
-                   itemsize: int = 2) -> KernelPlan:
+                   itemsize: int = 2, pairs: bool = False) -> KernelPlan:
     """The one place tile sizes and the form of the schedule are chosen, from
     what the call observes: `seq`, `head_dim`, `causal`, and `block_q` /
     `block_k` where the caller passes them. Tiles are capped to seq and shrunk
     to a divisor (gcd keeps the largest power-of-two factor), so a default
-    works for any seq that has one: S=1536 walks 3 x 3 tiles of 512."""
+    works for any seq that has one: S=1536 walks 3 x 3 tiles of 512. `pairs`:
+    the call runs both passes a pair a program (`_streams_pairs`), which is
+    never unrolled and walks `LONG_HEAD_TILE` x `PAIRS_TILE_K` pairs."""
 
     def plan(default):
         tile_q = math.gcd(min(block_q or default, seq), seq)
-        tile_k = math.gcd(min(block_k or default, seq), seq)
+        wide_k = PAIRS_TILE_K if pairs and head_dim <= LANES else default
+        tile_k = math.gcd(min(block_k or wide_k, seq), seq)
+        if pairs:  # a K tile is whole bits of one span of a packed selection (`_keep_tile`)
+            tile_k = math.gcd(tile_k, KEEP_SPAN)
         n_q, n_k = seq // tile_q, seq // tile_k
         visited = masked = 0
         for i in range(n_q):
             diag, end = _diag_and_end(i, tile_q, tile_k, n_k, True) if causal else (n_k, n_k)
             visited += end
             masked += end - diag
-        unrolled = (causal and max(tile_q, tile_k) <= CAUSAL_TILE
+        unrolled = (causal and not pairs and max(tile_q, tile_k) <= CAUSAL_TILE
                     and visited <= MAX_UNROLLED_TILES
                     and visited * tile_q * tile_k <= MAX_UNROLLED_SCORES
                     and seq * head_dim * itemsize <= MAX_UNROLLED_HEAD_BYTES)
@@ -619,17 +876,27 @@ def _kernel_blocks(seq: int, head_dim: int, causal: bool,
         small = plan(CAUSAL_TILE)
         if small.unrolled:
             return small
-    small = _long_head(seq, head_dim, itemsize) or _streamed_head(seq, head_dim, itemsize)
+    small = pairs or _long_head(seq, head_dim, itemsize) or _streamed_head(seq, head_dim, itemsize)
     return plan(LONG_HEAD_TILE if small else FULL_TILE)
+
+
+def _streams_pairs(seq: int, head_dim: int, itemsize: int, kv_heads_fewer: bool, keep: bool) -> bool:
+    """Whether a call runs both passes a (Q tile, K tile) pair a program
+    (`_flash_pairs`): a head the forward program cannot hold, a selection, or
+    key/value heads shared by a group of query heads."""
+    return keep or kv_heads_fewer or _head_bytes(seq, head_dim, itemsize) > MAX_HEAD_BYTES
 
 
 def kernel_plan(shape, causal: bool = True,
                 block_q: Optional[int] = None, block_k: Optional[int] = None,
-                dtype=jnp.bfloat16) -> KernelPlan:
+                dtype=jnp.bfloat16, kv_heads: Optional[int] = None, keep: bool = False) -> KernelPlan:
     """The schedule the kernels run for q/k/v of `shape` (batch, heads, seq,
-    head_dim): static, so asking costs nothing per step."""
-    _, _, s, d = shape
-    return _kernel_blocks(s, d, causal, block_q, block_k, jnp.dtype(dtype).itemsize)
+    head_dim), `kv_heads` key/value heads where they are fewer, with a
+    selection where `keep`: static, so asking costs nothing per step."""
+    _, h, s, d = shape
+    itemsize = jnp.dtype(dtype).itemsize
+    pairs = _streams_pairs(s, d, itemsize, kv_heads not in (None, h), keep)
+    return _kernel_blocks(s, d, causal, block_q, block_k, itemsize, pairs)
 
 
 def select_backend(shape, platform: Optional[str] = None,
@@ -643,15 +910,17 @@ def select_backend(shape, platform: Optional[str] = None,
     the forward program holds K and V of one (batch, head) in VMEM twice over
     beside its tiles: a head of up to `MAX_HEAD_BYTES` there, its last
     dimension padded to the 128 lanes (in bf16 8,192 positions at a head_dim
-    of 64 or 128, 4,096 at 256); the blockwise scan beyond that, and the XLA
-    form for a sequence with no block of at least 128 dividing it. The
+    of 64 or 128, 4,096 at 256); beyond that the kernel with K and V streamed
+    (`_fwd_pairs`) up to `MAX_STREAMED_HEAD_BYTES` (16,384 positions at 64 or
+    128, 8,192 at 256), the blockwise scan for what no form holds, and the
+    XLA form for a sequence with no block of at least 128 dividing it. The
     backward program's form follows from the same count (`_bwd`): whole heads
     up to `LONG_HEAD_BYTES`, (Q tile, K tile) pairs above.
     """
     if (platform or jax.default_backend()) != "tpu":
         return "xla"
     _, _, s, d = shape
-    if _head_bytes(s, d, 2) > MAX_HEAD_BYTES:
+    if _head_bytes(s, d, 2) > MAX_STREAMED_HEAD_BYTES:
         return "blockwise"
     plan = kernel_plan(shape, True, block_q, block_k)
     if min(plan.tile_q, plan.tile_k) < 128:
@@ -670,9 +939,18 @@ def flash_attention(
     backend: Optional[str] = None,
     interpret: bool = False,
     mesh=None,
+    keep=None,
+    return_lse: bool = False,
 ):
-    """Multi-head attention, (batch, heads, seq, head_dim) layout.
+    """Multi-head attention, (batch, heads, seq, head_dim) layout; k and v may
+    hold fewer heads, each shared by a group of consecutive query heads.
 
+    keep: (batch, seq, spans * 128) int32, a selection of keys for every query,
+      shared by the heads and packed a bit a pair (`pack_keep`); with `causal`
+      a key is attended to where both allow it. None (every key) runs the
+      programs this function ran before it took the argument.
+    return_lse: also each row's log-sum-exp over the keys it attends to,
+      (batch, heads, seq) f32, with no gradient.
     backend: "pallas" | "xla" | "blockwise" | None (`select_backend`).
     block_q, block_k: the kernel's tile sizes; None lets `kernel_plan` pick
       them from the shape.
@@ -691,15 +969,23 @@ def flash_attention(
         platform = mesh.devices.flat[0].platform if mesh is not None else None
         backend = select_backend(q.shape, platform, block_q, block_k)
     if backend == "xla":
-        return xla_attention(q, k, v, causal=causal, sm_scale=sm_scale)
+        return xla_attention(q, k, v, causal=causal, sm_scale=sm_scale, keep=keep, return_lse=return_lse)
     if backend == "blockwise":
-        return blockwise_attention(q, k, v, causal=causal, sm_scale=sm_scale)
-    plan = kernel_plan(q.shape, causal, block_q, block_k, q.dtype)
+        if keep is not None or return_lse:
+            raise NotImplementedError(
+                f"flash_attention: a head of {q.shape[2]} x {q.shape[3]} runs the blockwise scan, "
+                "which takes no selection and returns no row statistics")
+        return blockwise_attention(q, *_repeat_kv(q, k, v), causal=causal, sm_scale=sm_scale)
+    pairs = return_lse or _streams_pairs(
+        q.shape[2], q.shape[3], q.dtype.itemsize, k.shape[1] != q.shape[1], keep is not None)
+    plan = _kernel_blocks(q.shape[2], q.shape[3], causal, block_q, block_k, q.dtype.itemsize, pairs)
     if min(plan.tile_q, plan.tile_k) < 128:
         raise ValueError(
             f"flash_attention(backend='pallas'): seq_len {q.shape[2]} has no "
             "block of at least 128 dividing it; the kernel cannot tile it"
         )
+    if pairs:
+        return _pairs_call(q, k, v, keep, causal, sm_scale, plan, interpret, mesh, return_lse)
 
     def kernel(q, k, v):
         b, h, s, d = q.shape
@@ -720,3 +1006,31 @@ def flash_attention(
             check_vma=False,
         )
     return kernel(q, k, v)
+
+
+def _pairs_call(q, k, v, keep, causal, sm_scale, plan, interpret, mesh, return_lse):
+    """`_flash_pairs` on (batch, heads, seq, d) operands, over `mesh` as
+    `flash_attention` partitions its other kernels (a selection goes with its
+    row's batch)."""
+    def kernel(q, k, v, *keep):
+        b, h, s, d = q.shape
+        flat = lambda x: x.reshape(-1, s, d)
+        o, lse = _flash_pairs(flat(q), flat(k), flat(v), keep[0] if keep else None,
+                              causal, sm_scale, plan, interpret)
+        return o.reshape(b, h, s, d), jax.lax.stop_gradient(lse).reshape(b, h, s)
+
+    operands = (q, k, v) + (() if keep is None else (keep,))
+    if mesh is not None and mesh.size > 1:
+        from ray_tpu.parallel import ShardingRules
+
+        rules = ShardingRules()
+        spec = rules.mesh_axes(("batch", "heads", None, None), mesh=mesh, shape=q.shape)
+        kv_spec = rules.mesh_axes(("batch", "heads", None, None), mesh=mesh, shape=k.shape)
+        if kv_spec != spec:
+            raise NotImplementedError("key/value heads that the mesh cannot split as it splits the query heads")
+        in_specs = (spec, spec, spec) + (() if keep is None else (
+            rules.mesh_axes(("batch", None, None), mesh=mesh, shape=keep.shape),))
+        kernel = jax.shard_map(kernel, mesh=mesh, in_specs=in_specs,
+                               out_specs=(spec, jax.sharding.PartitionSpec(*spec[:3])), check_vma=False)
+    o, lse = kernel(*operands)
+    return (o, lse) if return_lse else o

@@ -33,6 +33,10 @@ LONGER_HEADS = ("kernel:2x32x8192x64", "kernel:1x16x8192x128")  # what failed in
 # The expert layers of the lfm2 and glm-4.7-flash cells: 8 x 4,096 and 2 x 4,096 tokens, 4 of 64 experts a
 # token, 8 held: a prefix of 32,768 and of 8,192 sorted rows of 2,048.
 ROW_MOVERS = ("row_movers:32768", "row_movers:8192")
+# The Keye cell's attention (PR 42): one row, 32 query heads on 4 key/value heads of 16,384 x 128, a
+# packed selection; and the two kernels of `ops/lightning_indexer.py` at its indexer's 16 heads of 64.
+SELECTED_16K = "selected:1x32x4x16384x128"
+INDEXER_16K = "indexer:1x32x4x16384x128x16x64x2048"
 BETWEEN_HEADS = ("kernel:2x4x3072x256", "kernel:2x4x3584x256", "kernel:2x4x6144x128",
                  "kernel:2x4x7168x128", "kernel:2x4x2048x256")
 
@@ -52,6 +56,47 @@ def _kernel_case(topo, shape=(B, 12, S, 64)):
     compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(x, x, x).compile()
     return {"mosaic_calls": compiled.as_text().count("tpu_custom_call"),
             "plan": list(kernel_plan(shape))}
+
+
+def _selected_case(topo, batch, heads, kv_heads, seq, d):
+    """Both flash kernels a (Q tile, K tile) pair a program: grouped heads, K and V streamed, a `keep`."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.ops.flash_attention import KEEP_SPAN, flash_attention, kernel_plan
+
+    one = jax.sharding.SingleDeviceSharding(topo.devices[0])
+    q, k = (jax.ShapeDtypeStruct((batch, h, seq, d), jnp.bfloat16, sharding=one) for h in (heads, kv_heads))
+    keep = jax.ShapeDtypeStruct((batch, seq, -(-seq // KEEP_SPAN) * 128), jnp.int32, sharding=one)
+    loss = lambda q, k, v, keep: flash_attention(q, k, v, backend="pallas", keep=keep).astype(jnp.float32).sum()
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(q, k, k, keep).compile().as_text()
+    dense = jax.jit(jax.grad(lambda q, k, v: loss(q, k, v, None), argnums=(0, 1, 2))).lower(q, k, k).compile()
+    return {"mosaic_calls": text.count("tpu_custom_call"), "mosaic_calls_without_keep": dense.as_text().count(
+                "tpu_custom_call"),
+            "plan": list(kernel_plan(q.shape, kv_heads=kv_heads, keep=True)),
+            "kernels": sorted(set(re.findall(r"(flash_fwd|flash_bwd)[.\d]* = ", text)))}
+
+
+def _indexer_case(topo, batch, heads, kv_heads, seq, d, index_heads, index_d, topk):
+    """`select` and `index_loss` (with the gradient it keeps) at a cell's shapes."""
+    import importlib
+
+    import jax
+    import jax.numpy as jnp
+
+    li = importlib.import_module("ray_tpu.ops.lightning_indexer")
+    one = jax.sharding.SingleDeviceSharding(topo.devices[0])
+    sd = lambda shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+    q_i, k_i, w = sd((batch, index_heads, seq, index_d)), sd((batch, seq, index_d)), sd((batch, seq, index_heads), jnp.float32)
+    select = jax.jit(lambda q_i, k_i, w: li.select(q_i, k_i, w, topk, backend="pallas")).lower(q_i, k_i, w).compile()
+    keep, lse_i = (sd(x.shape, x.dtype) for x in jax.eval_shape(lambda: li.select(q_i, k_i, w, topk, backend="xla")))
+    loss = lambda q_i, k_i, w, q, k, lse, keep, lse_i: li.index_loss(q, k, lse, keep, q_i, k_i, w, lse_i, backend="pallas")
+    index_loss = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        q_i, k_i, w, sd((batch, heads, seq, d)), sd((batch, kv_heads, seq, d)), sd((batch, heads, seq), jnp.float32),
+        keep, lse_i).compile()
+    named = lambda compiled: sorted(set(re.findall(r"(select|index_loss)[.\d]* = ", compiled.as_text())))
+    return {"select": named(select), "index_loss": named(index_loss), "keep": list(keep.shape),
+            "index_loss_mosaic_calls": index_loss.as_text().count("tpu_custom_call")}
 
 
 def _row_movers_case(topo, tokens, k=4, width=2048, n_experts=64, held=8):
@@ -204,6 +249,10 @@ def _main(cases):
             results[case] = _held_experts_case(topo)
         elif case.startswith("row_movers:"):
             results[case] = _row_movers_case(topo, int(case[len("row_movers:"):]))
+        elif case.startswith("selected:"):
+            results[case] = _selected_case(topo, *(int(n) for n in case[len("selected:"):].split("x")))
+        elif case.startswith("indexer:"):
+            results[case] = _indexer_case(topo, *(int(n) for n in case[len("indexer:"):].split("x")))
         elif case.startswith("kernel:"):
             results[case] = _kernel_case(topo, tuple(int(n) for n in case[len("kernel:"):].split("x")))
         else:
@@ -226,7 +275,7 @@ def _run(cases):
 @pytest.fixture(scope="module")
 def aot():
     return _run(["kernel", LONG_HEAD_64, WIDE_HEAD_256, *LONGER_HEADS, *BETWEEN_HEADS, "held_experts",
-                 *ROW_MOVERS, "lower:d4", "lower:d2t2"])
+                 *ROW_MOVERS, SELECTED_16K, INDEXER_16K, "lower:d4", "lower:d2t2"])
 
 
 def test_topology_is_the_v5e(aot):
@@ -263,6 +312,25 @@ def test_flash_kernels_compile_for_v5e_at_heads_above_that_vmem(aot, case):
     seq = int(case.split("x")[2])
     n = seq // 512
     assert aot[case]["plan"] == [512, 512, n * (n + 1) // 2, n, n * n, False]
+
+
+def test_both_flash_kernels_stream_pairs_under_a_selection_at_16384_by_128(aot):
+    """`(1, 32 on 4, 16384, 128)` bf16 with a packed `keep`: a head is 4 MiB, which no forward program
+    holds, so `flash_fwd` runs in `flash_bwd`'s pair-streamed form, each one Mosaic call under the 16
+    MiB default, at pairs of 512 x 1024 (1024-row Q tiles and 2048-key K tiles fail in `flash_bwd`: its
+    f32 dq scratch alone is 8 MiB here); key/value heads unrepeated; and the same two programs without a selection."""
+    got = aot[SELECTED_16K]
+    assert got["mosaic_calls"] == got["mosaic_calls_without_keep"] == 2
+    assert got["plan"] == [512, 1024, 272, 32, 512, False] and got["kernels"] == ["flash_bwd", "flash_fwd"]
+
+
+def test_the_selection_and_the_indexer_loss_compile_for_v5e_at_the_cells_shapes(aot):
+    """`select` (64 query rows' scores against 16,384 keys in VMEM, the threshold, the packed words)
+    and `index_loss` (grid (1, 1056 pairs of 256 x 512, 32 heads); the gradients leave the same call,
+    so its backward pass is no kernel) at the Keye cell's indexer of 16 heads of 64, top-2,048."""
+    got = aot[INDEXER_16K]
+    assert got["select"] == ["select"] and got["index_loss"] == ["index_loss"]
+    assert got["keep"] == [1, 16384, 512] and got["index_loss_mosaic_calls"] == 1
 
 
 def test_the_plans_of_the_other_cells_shapes_are_what_they_were():

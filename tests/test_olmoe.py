@@ -253,7 +253,9 @@ def test_the_kernels_boundary_this_configuration_stands_on():
     # Until PR 39 the kernels ended here (`s * d <= 8192 * 64`); since then the backward pass
     # streams tile pairs from heads of twice this VMEM on, and the limit is four times it.
     assert select_backend((2, 16, 8192, 128), "tpu") == "pallas"
-    assert select_backend((2, 16, 8192 + 128, 128), "tpu") == "blockwise"
+    # ... and since PR 42 the forward pass streams them too, up to 16,384 x 128; the scan beyond.
+    assert select_backend((2, 16, 8192 + 128, 128), "tpu") == "pallas"
+    assert select_backend((2, 16, 16384 + 128, 128), "tpu") == "blockwise"
     # Heads of 4096 x 128 walk 512-tiles in the loop form (1024-tiles miss the
     # 16 MiB of VMEM in the backward pass); shorter heads are as they were. A
     # 4096 x 64 head takes the same VMEM (64 lanes pad to 128) and the same form

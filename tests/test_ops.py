@@ -202,13 +202,19 @@ def test_misaligned_seq_selection_is_visible_not_silent():
     assert select_backend(q.shape, platform="tpu") == "xla"
     assert select_backend((16, 12, 1024, 64), platform="tpu") == "pallas"
     # By the VMEM a head takes, lanes padded (PR 39): up to 4096 x 256 in bf16, which is
-    # 8192 x 128 and 8192 x 64 too; the scan over K blocks beyond that.
+    # 8192 x 128 and 8192 x 64 too, the forward program holds K and V; beyond that it streams
+    # them a (Q tile, K tile) pair a program (PR 42) up to the head whose f32 dq the backward
+    # program holds (16,384 x 128), and the scan over K blocks is for what no form holds.
     assert select_backend((1, 8, 8192, 128), platform="tpu") == "pallas"
     assert select_backend((2, 20, 4096, 256), platform="tpu") == "pallas"
     assert select_backend((2, 32, 8192, 64), platform="tpu") == "pallas"
-    assert select_backend((1, 8, 8192 + 512, 128), platform="tpu") == "blockwise"
-    assert select_backend((1, 8, 8192 + 512, 64), platform="tpu") == "blockwise"
-    assert select_backend((1, 8, 4096 + 512, 256), platform="tpu") == "blockwise"
+    assert select_backend((1, 8, 8192 + 512, 128), platform="tpu") == "pallas"
+    assert select_backend((1, 8, 8192 + 512, 64), platform="tpu") == "pallas"
+    assert select_backend((1, 8, 4096 + 512, 256), platform="tpu") == "pallas"
+    assert select_backend((1, 32, 16384, 128), platform="tpu") == "pallas"
+    assert select_backend((1, 8, 16384 + 512, 128), platform="tpu") == "blockwise"
+    assert select_backend((1, 8, 16384 + 512, 64), platform="tpu") == "blockwise"
+    assert select_backend((1, 8, 8192 + 512, 256), platform="tpu") == "blockwise"
     assert select_backend((16, 12, 1024, 64), platform="cpu") == "xla"
     out = flash_attention(q, q, q)  # this process is on CPU: the XLA form
     ref = xla_attention(q, q, q, causal=True)
@@ -259,3 +265,221 @@ def test_dropout_applied_and_deterministic_eval():
     tr2 = forward(params, toks, cfg, dropout_rng=jax.random.PRNGKey(2))
     assert np.abs(np.asarray(tr1) - np.asarray(tr2)).max() > 1e-6  # stochastic
     assert np.abs(np.asarray(tr1) - np.asarray(eval1)).max() > 1e-6
+
+
+# ----------------------------------------------------------------------------- a selection of keys (PR 42)
+def _dense_masked(q, k, v, mask):
+    """Softmax attention over the keys `mask` (batch, queries, keys) keeps, key/value heads repeated:
+    the yardstick, written with no function of `ops/flash_attention.py`."""
+    group = q.shape[1] // k.shape[1]
+    k, v = jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1)
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k) * q.shape[-1] ** -0.5
+    s = jnp.where(mask[:, None], s, -jnp.inf)
+    return jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, axis=-1), v), jax.scipy.special.logsumexp(s, axis=-1)
+
+
+@pytest.fixture(scope="module")
+def selected():
+    """q on fewer key/value heads, and a random selection under the diagonal in which some
+    query keeps no key of its first tile and every query keeps itself."""
+    keys = jax.random.split(jax.random.PRNGKey(11), 4)
+    b, h, hk, s, d = 2, 4, 2, 384, 64
+    q = jax.random.normal(keys[0], (b, h, s, d), jnp.float32)
+    k, v = (jax.random.normal(kk, (b, hk, s, d), jnp.float32) for kk in keys[1:3])
+    mask = jax.random.bernoulli(keys[3], 0.2, (b, s, s)) | jnp.eye(s, dtype=bool)
+    mask = mask.at[:, 200:, :128].set(False) & jnp.tril(jnp.ones((s, s), bool))
+    return q, k, v, mask
+
+
+@pytest.mark.parametrize("keys", [64, 128, 4096, 4096 + 128, 3 * 4096])
+def test_a_selection_packs_to_a_bit_a_pair_and_back(keys):
+    from ray_tpu.ops.flash_attention import KEEP_SPAN, pack_keep, unpack_keep
+
+    mask = jax.random.bernoulli(jax.random.PRNGKey(keys), 0.5, (2, 3, keys))
+    packed = pack_keep(mask)
+    assert packed.dtype == jnp.int32 and packed.shape == (2, 3, -(-keys // KEEP_SPAN) * 128)
+    assert bool((unpack_keep(packed, keys) == mask).all())
+    # Bit b of word [span * 128 + lane] is key span * 4096 + b * 128 + lane: the sign bit too.
+    one = jnp.zeros((1, 2 * KEEP_SPAN), bool).at[0, KEEP_SPAN + 31 * 128 + 5].set(True)
+    assert int(pack_keep(one)[0, 128 + 5]) == -(2 ** 31) and int((pack_keep(one) != 0).sum()) == 1
+
+
+@pytest.mark.parametrize("backend,blocks", [("xla", {}), ("pallas", {"block_q": 128, "block_k": 128}),
+                                            ("pallas", {"block_q": 384, "block_k": 384})],
+                         ids=["xla", "pallas-128", "pallas-384x128"])
+def test_keep_forward_and_backward_against_a_dense_masked_softmax(selected, backend, blocks):
+    from ray_tpu.ops.flash_attention import pack_keep
+
+    q, k, v, mask = selected
+    keep = pack_keep(mask)
+    attn = lambda q, k, v: flash_attention(q, k, v, keep=keep, return_lse=True, backend=backend,
+                                           interpret=True, **blocks)
+    o, lse = attn(q, k, v)
+    want_o, want_lse = _dense_masked(q, k, v, mask)
+    np.testing.assert_allclose(np.asarray(o), np.asarray(want_o), atol=2e-5)
+    np.testing.assert_allclose(np.asarray(lse), np.asarray(want_lse), atol=2e-5)
+    loss = lambda f: lambda q, k, v: (f(q, k, v)[0] ** 2).sum()
+    got = jax.grad(loss(attn), argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(loss(lambda q, k, v: _dense_masked(q, k, v, mask)), argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(got, want):
+        assert a.shape == b.shape
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-4)
+
+
+def test_without_a_selection_grouped_heads_stream_pairs_and_equal_heads_run_what_they_ran(selected):
+    from ray_tpu.ops.flash_attention import _streams_pairs
+
+    q, k, v, _ = selected
+    causal = jnp.tril(jnp.ones((q.shape[2],) * 2, bool))[None]
+    o = flash_attention(q, k, v, backend="pallas", interpret=True, block_q=128, block_k=128)
+    np.testing.assert_allclose(np.asarray(o), np.asarray(_dense_masked(q, k, v, causal)[0]), atol=2e-5)
+    np.testing.assert_allclose(np.asarray(xla_attention(q, k, v)), np.asarray(o), atol=2e-5)
+    assert _streams_pairs(384, 64, 4, kv_heads_fewer=True, keep=False)
+    assert not _streams_pairs(4096, 256, 2, False, False) and _streams_pairs(4096 + 512, 256, 2, False, False)
+    assert not _streams_pairs(8192, 128, 2, False, False) and _streams_pairs(16384, 128, 2, False, False)
+    # The plan of a call that streams pairs: never unrolled, 512 x 1024 tiles; the others' are what they were.
+    assert kernel_plan((1, 32, 16384, 128)) == (512, 1024, 272, 32, 512, False)
+    assert kernel_plan((1, 4, 1024, 64), keep=True) == (512, 1024, 2, 2, 2, False)
+    assert kernel_plan((1, 4, 1024, 64), kv_heads=2) == (512, 1024, 2, 2, 2, False)
+    assert kernel_plan((1, 8, 8192, 256), keep=True) == (512, 512, 136, 16, 256, False)  # wider than the lanes: 512-tiles
+    assert kernel_plan((1, 4, 1024, 64), kv_heads=4) == kernel_plan((1, 4, 1024, 64)) == (512, 512, 3, 2, 4, True)
+
+
+def test_the_forward_schedule_visits_each_pair_once_a_q_tile_at_a_time():
+    from ray_tpu.ops.flash_attention import KernelPlan, _fwd_schedule
+
+    steps = _fwd_schedule(2048, KernelPlan(256, 512, 0, 0, 0, False), True)
+    i, j, first, masked, last = steps
+    assert steps.shape == (5, sum(-(-(n + 1) * 256 // 512) for n in range(8)))
+    assert len({(a, b) for a, b in zip(i, j)}) == steps.shape[1] and (np.diff(i) >= 0).all()
+    for tile in range(8):
+        mine = i == tile
+        assert list(j[mine]) == list(range(mine.sum())) and first[mine][0] == 1 and last[mine][-1] == 1
+        assert first[mine].sum() == last[mine].sum() == 1 and masked[mine][-1] == 1
+    full = _fwd_schedule(1024, KernelPlan(512, 512, 0, 0, 0, False), False)
+    assert full.shape == (5, 4) and not full[3].any()
+
+
+def _scores(q_i, k_i, w):
+    s = jnp.einsum("bjqd,bkd->bjqk", q_i, k_i)
+    return jnp.einsum("bjqk,bqj->bqk", jax.nn.relu(s), w)
+
+
+def _top_k_set(scores, k):
+    """The selection by `lax.top_k`: the keys of the past at or above the k-th largest."""
+    seq = scores.shape[-1]
+    causal = jnp.tril(jnp.ones((seq, seq), bool))
+    masked = jnp.where(causal, scores, -jnp.inf)
+    tau = jax.lax.top_k(masked, min(k, seq))[0][..., -1:]
+    return (masked >= tau) & causal
+
+
+@pytest.fixture(scope="module")
+def indexed():
+    keys = jax.random.split(jax.random.PRNGKey(3), 3)
+    b, heads, seq, d = 2, 4, 512, 32
+    q_i = jax.random.normal(keys[0], (b, heads, seq, d), jnp.float32)
+    k_i = jax.random.normal(keys[1], (b, seq, d), jnp.float32)
+    # Keys 100-139 alike: their scores tie for every query, in and out of the top k.
+    k_i = k_i.at[:, 100:140].set(k_i[:, 100:101])
+    w = jax.random.normal(keys[2], (b, seq, heads), jnp.float32) * 0.3
+    return q_i, k_i, w
+
+
+@pytest.mark.parametrize("backend", ["xla", "pallas"])
+@pytest.mark.parametrize("topk", [1, 96, 130, 511, 512, 2048])
+def test_the_selection_is_lax_top_ks_set_ties_and_short_rows_included(indexed, backend, topk):
+    from ray_tpu.ops import lightning_indexer  # noqa: F401  (the module, not the package's function)
+    from ray_tpu.ops.flash_attention import unpack_keep
+    from ray_tpu.ops.lightning_indexer import select
+
+    q_i, k_i, w = indexed
+    keep, lse = select(q_i, k_i, w, topk, backend=backend, interpret=True)
+    got = np.asarray(unpack_keep(keep, 512))
+    scores = _scores(q_i, k_i, w)
+    want = np.asarray(_top_k_set(scores, topk))
+    assert (got == want).all()
+    per_query = got.sum(-1)
+    assert (per_query[:, :topk] == np.arange(1, min(topk, 512) + 1)).all()  # rows shorter than k keep their past
+    assert (per_query >= np.minimum(np.arange(1, 513), topk)).all()
+    if topk in (96, 130):
+        assert (per_query[:, topk:] > topk).any()  # the planted ties at the threshold all stay
+    want_lse = jax.scipy.special.logsumexp(jnp.where(want, scores, -jnp.inf), axis=-1)
+    np.testing.assert_allclose(np.asarray(lse), np.asarray(want_lse), atol=2e-5)
+
+
+@pytest.mark.parametrize("values", ["mixed", "negative", "zeros"])
+def test_the_threshold_is_the_kth_largest_on_the_bit_pattern(values):
+    from ray_tpu.ops.lightning_indexer import INT_MIN, _threshold, sortable, unsortable
+
+    x = jax.random.normal(jax.random.PRNGKey(9), (7, 300)) * 1e3
+    x = {"mixed": x.at[:, ::7].set(0.0).at[:, 1::11].set(-0.0), "negative": -jnp.abs(x) - 1e-30,
+         "zeros": jnp.zeros_like(x)}[values]
+    keys = sortable(x)
+    assert bool((unsortable(keys) == x).all())
+    assert bool((unsortable(jnp.sort(keys, axis=-1)) == jnp.sort(x, axis=-1)).all())  # the floats' order
+    assert bool((sortable(jnp.asarray([-0.0, 0.0])) == 0).all())  # one zero
+    for k in (1, 2, 150, 300):
+        count = lambda t: jnp.sum(keys >= t, axis=-1, keepdims=True, dtype=jnp.int32)
+        tau = unsortable(_threshold(count, k, keys[:, :1]))
+        np.testing.assert_array_equal(np.asarray(tau[:, 0]), np.asarray(jnp.sort(x, axis=-1)[:, -k]))
+    none = _threshold(lambda t: jnp.sum(keys >= t, axis=-1, keepdims=True, dtype=jnp.int32), 301, keys[:, :1])
+    assert bool((none == INT_MIN).all())  # fewer keys than k: everything is at or above it
+
+
+@pytest.mark.parametrize("backend", ["xla", "pallas"])
+def test_the_index_loss_and_its_gradient_against_the_dense_form(indexed, backend):
+    from ray_tpu.ops.flash_attention import pack_keep
+    from ray_tpu.ops.lightning_indexer import index_loss, select, selection_counts
+
+    q_i, k_i, w = indexed
+    keys = jax.random.split(jax.random.PRNGKey(4), 2)
+    q = jax.random.normal(keys[0], (2, 4, 512, 64), jnp.float32)
+    k = jax.random.normal(keys[1], (2, 2, 512, 64), jnp.float32)
+    kept = _top_k_set(_scores(q_i, k_i, w), 96)
+    keep, lse_i = select(q_i, k_i, w, 96, backend="xla")
+    assert bool((pack_keep(kept) == keep).all())
+    _, lse = _dense_masked(q, k, k, kept)
+
+    def dense(q_i, k_i, w):
+        s = jnp.einsum("bhqd,bhkd->bhqk", q, jnp.repeat(k, 2, axis=1)) * 64 ** -0.5
+        p = jax.nn.softmax(jnp.where(kept[:, None], s, -jnp.inf), axis=-1).mean(axis=1)
+        log_q = jax.nn.log_softmax(jnp.where(kept, _scores(q_i, k_i, w), -jnp.inf), axis=-1)
+        live = kept & (p > 0)
+        return jnp.sum(jnp.where(live, p * (jnp.log(jnp.where(live, p, 1.0)) - jnp.where(kept, log_q, 0.0)), 0.0)) / 1024
+
+    mine = lambda q_i, k_i, w: index_loss(q, k, lse, keep, q_i, k_i, w, lse_i, backend=backend, interpret=True)
+    want, want_g = jax.value_and_grad(dense, argnums=(0, 1, 2))(q_i, k_i, w)
+    got, got_g = jax.value_and_grad(mine, argnums=(0, 1, 2))(q_i, k_i, w)
+    assert float(got) == pytest.approx(float(want), rel=2e-5) and float(want) > 0.05
+    for a, b in zip(got_g, want_g):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-5 * float(jnp.abs(b).max()) + 1e-9)
+    # No gradient reaches the attention's own operands, and a cotangent scales the three.
+    dq, dk = jax.grad(lambda q, k: index_loss(q, k, lse, keep, q_i, k_i, w, lse_i, backend=backend,
+                                              interpret=True), argnums=(0, 1))(q, k)
+    assert float(jnp.abs(dq).max()) == float(jnp.abs(dk).max()) == 0.0
+    twice = jax.grad(lambda w: 2.0 * mine(q_i, k_i, w))(w)
+    np.testing.assert_allclose(np.asarray(twice), 2 * np.asarray(got_g[2]), rtol=1e-6)
+    counts = selection_counts(keep, tile=128)
+    assert float(counts["selected_pairs"]) == float(kept.sum()) and int(counts["tiles"]) == 2 * 10
+    assert float(counts["causal_pairs"]) == 2 * 512 * 513 / 2 and 1 <= int(counts["live_tiles"]) <= 20
+    assert int(counts["keys_per_query_min"]) == 1 and int(counts["keys_per_query_max"]) >= 96
+
+
+def test_keep_at_the_pair_forms_own_tiles_of_512_by_1024():
+    """No tile sizes asked for: a Q tile of 512 on a K tile of 1,024, eight bits of a word a pair, the
+    diagonal crossing each K tile's two Q tiles at another offset."""
+    from ray_tpu.ops.flash_attention import pack_keep
+
+    keys = jax.random.split(jax.random.PRNGKey(17), 4)
+    q = jax.random.normal(keys[0], (1, 2, 2048, 64), jnp.float32)
+    k, v = (jax.random.normal(kk, (1, 1, 2048, 64), jnp.float32) for kk in keys[1:3])
+    mask = (jax.random.bernoulli(keys[3], 0.1, (1, 2048, 2048)) | jnp.eye(2048, dtype=bool)) & jnp.tril(
+        jnp.ones((2048, 2048), bool))
+    assert kernel_plan(q.shape, kv_heads=1, keep=True, dtype=jnp.float32)[:2] == (512, 1024)
+    attn = lambda q, k, v: flash_attention(q, k, v, keep=pack_keep(mask), backend="pallas", interpret=True)
+    np.testing.assert_allclose(np.asarray(attn(q, k, v)), np.asarray(_dense_masked(q, k, v, mask)[0]), atol=2e-5)
+    got = jax.grad(lambda *a: (attn(*a) ** 2).sum(), argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(lambda *a: (_dense_masked(*a, mask)[0] ** 2).sum(), argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-4)
