@@ -15,7 +15,14 @@ An S x S matrix of f32 scores is 1.07 GB a row of 16,384 and is never made:
 alone, a bit a (query, key) pair in the form `ops/flash_attention.py` reads
 (`pack_keep`), with each row's log-sum-exp of its selected scores; `index_loss`
 makes the scores again a (Q tile, K tile) pair at a time beside the
-probabilities.
+probabilities: a program of its kernel is a whole pair, every attention head
+(a key/value head read once for its group) and every indexer head inside it,
+the tile laid keys down and queries across, so that what belongs to a query (a
+head's log-sum-exp, an indexer head's weight, the loss's term) is a row and no
+column is ever spread over the lanes. The indexer's 64-wide heads lie two to a
+row of 128 lanes; the pair's index scores are made once and kept for the
+gradient where `_loss_plan` counts room for them, and the tiles are what that
+plan derives from the shapes and the bytes a program holds.
 
 The k-th largest is found on the bit pattern: an f32 maps to an int32 whose
 order is the float's (`sortable`), and 32 rounds of compare-and-count, one a
@@ -39,7 +46,7 @@ runs off the TPU, and the tests' yardstick for the kernels.
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -47,13 +54,21 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ray_tpu.ops.flash_attention import (KEEP_BITS, KEEP_SPAN, LANES, KernelPlan, _causal_mask, _fwd_schedule,
-                                         _keep_tile, pack_keep, unpack_keep)
+from ray_tpu.ops.flash_attention import (KEEP_BITS, KEEP_SPAN, LANES, KernelPlan, _fwd_schedule,
+                                         pack_keep, unpack_keep)
 
 INT_MIN = -(2 ** 31)
 SELECT_ROWS = 64  # query rows a `select` program holds the scores of: (64, 16384) f32 is 4 MiB of VMEM
 SELECT_CHUNK = 2048  # keys a product of the `select` kernel makes at a time
-LOSS_TILE_Q, LOSS_TILE_K = 256, 512  # `index_loss`'s pair: what its (heads, tile_q, 64) blocks leave room for
+# `index_loss`'s (Q tile, K tile) pairs, for `_loss_plan` to choose among by the VMEM a program holds, and what it
+# may hold: Mosaic's default 16 MiB less what XLA fuses into the call's operands inside a step (a kernel that
+# needs 15.4 MiB alone, 512 x 512 with the scores made twice, compiles alone and not in the Keye step), so that
+# no `vmem_limit_bytes` is asked for (`flash_attention._compiler_params`). One call at (1, 32 on 4, 16384, 128),
+# 16 x 64, kernel ms on the v5e (tools/index_loss_bench.py, PR 43; PERF.md section 6): scores kept 256 x 256
+# 16.81, 256 x 512 17.19, 512 x 512 17.52 (the last two under a limit of 40 MiB); made twice 256 x 256 23.37,
+# 256 x 512 19.97, 256 x 1024 18.94, 512 x 512 18.33, 512 x 1024 21.37; what this replaced 33.73.
+LOSS_TILES = tuple((q, k) for q in (512, 256) for k in (1024, 512, 256))
+LOSS_VMEM_BYTES = 14 * 2 ** 20
 XLA_CHUNK = 256  # query rows a step of the XLA forms holds the scores of
 
 
@@ -248,122 +263,233 @@ def select(q_i, k_i, w, topk: int, backend: Optional[str] = None, interpret: boo
 
 
 # --------------------------------------------------------------------------- the loss kernel
-def _index_loss_kernel(steps_ref, q_ref, k_ref, lse_ref, keep_ref, qi_ref, ki_ref, w_ref, lsei_ref,
-                       loss_ref, dqi_ref, dw_ref, dki_ref, p_acc, dqi_acc, dw_acc, loss_acc, *,
-                       sm_scale, tile_q, tile_k, heads, index_heads):
-    """Grid (batch, pairs, heads), the last two sequential. A head a step adds
-    its probabilities of the pair to `p_acc`; the last head's step makes the
-    pair's index scores, the loss's terms, the gradient `softmax(I) - P` and
-    its way through the scores: `dqi_acc`, `dw_acc` and `loss_acc` gather over
-    the Q tile's pairs, the pair's share of dkI goes out as a block of its own."""
-    t, h = pl.program_id(1), pl.program_id(2)
-    i, j = steps_ref[0, t], steps_ref[1, t]
-    first, last = steps_ref[2, t] == 1, steps_ref[4, t] == 1
+def _kept_pairs(keep_ref, i, j, tile_q, tile_k):
+    """The pair's selected and causal (key, query) bool, keys down and queries
+    across: `flash_attention._keep_tile` and `_causal_mask` turned over. The
+    packed block is (tile_q, 128) words, bit b of word [q, lane] the key
+    `b * 128 + lane` of its span: turned, a bit is a (128, tile_q) slab of keys."""
+    bits = tile_k // LANES
+    words = keep_ref[0].T  # (128, tile_q)
+    first = (j % (KEEP_SPAN // tile_k)) * bits
+    kept = jnp.concatenate([(words >> (first + b)) & 1 for b in range(bits)], axis=0) != 0
+    diff = (jax.lax.broadcasted_iota(jnp.int32, (tile_k, tile_q), 1)
+            - jax.lax.broadcasted_iota(jnp.int32, (tile_k, tile_q), 0))
+    return kept & (diff >= j * tile_k - i * tile_q)
 
-    @pl.when(first & (h == 0))
+
+def _pair_probabilities(qs, k_ref, lse_ref, *, heads, kv_heads):
+    """Sum over the heads of exp(q_h . k - lse_h), (tile_k, tile_q) f32: a key/value
+    head is read once for its group of query heads, a head's log-sum-exp is a row
+    that goes down the sublanes. What is not selected may overflow here: the
+    caller's select lets none of it through."""
+    group = heads // kv_heads
+    p = None
+    for g in range(kv_heads):
+        k = k_ref[0, g]
+        for h in range(g * group, (g + 1) * group):
+            s = jax.lax.dot_general(k, qs[h], (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+            e = jnp.exp(s - lse_ref[0, h:h + 1, :])
+            p = e if p is None else p + e
+    return p
+
+
+def _lane_rows(index_heads: int, d_i: int):
+    """(the indexer's heads that lie side by side in a row of 128 lanes, the heads
+    counted up to whole rows): 2 and 16 at 16 heads of 64; 4 and 4 at 3 heads of 32."""
+    pack = max(LANES // d_i, 1)
+    return pack, -(-index_heads // pack) * pack
+
+
+def _pair_gradients(p, mask, qi_ref, ki_ref, w_ref, lsei_ref, dqi_acc, dw_acc, loss_acc, dki_ref, *,
+                    index_heads, d_i, keep_scores):
+    """The pair's index scores, its terms of the loss, `softmax(I) - P` and that
+    gradient's way through the scores, keys down and queries across. The indexer's
+    heads lie `pack` to a row of 128 lanes (as `_pallas_index_loss` lays them): head
+    n's product is the lane row's against the keys at that head's lanes, zeros at
+    the others', as deep and as dear on the MXU as its own 64 lanes alone. With
+    `keep_scores` a head's f32 scores stay from the sum to the gradient; else their
+    product is made again there: three products a head or four, all 64 deep."""
+    tile_k, tile_q = p.shape
+    pack, _ = _lane_rows(index_heads, d_i)
+    width = pack * d_i
+    k_all = ki_ref[0]  # (tile_k, width): the key's d_i numbers, `pack` times over
+    slot = jax.lax.broadcasted_iota(jnp.int32, k_all.shape, 1) // d_i
+    k_at = [k_all if pack == 1 else jnp.where(slot == r, k_all, jnp.zeros_like(k_all)) for r in range(pack)]
+    lanes = lambda n: slice((n // pack) * width, (n // pack + 1) * width)  # head n's row of lanes
+    rows = lambda n: qi_ref[0, :, lanes(n)]  # (tile_q, width)
+    score = lambda n: jax.lax.dot_general(k_at[n % pack], rows(n), (((1,), (1,)), ((), ())),
+                                          preferred_element_type=jnp.float32)  # (tile_k, tile_q)
+    weight = lambda n: w_ref[0, n:n + 1, :]  # (1, tile_q)
+
+    made = [score(n) for n in range(index_heads)] if keep_scores else None
+    again = (lambda n: made[n]) if keep_scores else score
+    scores = sum(weight(n) * jnp.maximum(again(n), 0.0) for n in range(index_heads))
+    log_q = scores - lsei_ref[0]
+    positive = p > 0
+    loss_acc[...] += jnp.sum(
+        jnp.where(positive, p * (jnp.log(jnp.where(positive, p, 1.0)) - log_q), 0.0), axis=0, keepdims=True)
+    d_scores = jnp.where(mask, jnp.exp(log_q), 0.0) - p
+
+    dk = [jnp.zeros((tile_k, width), jnp.float32) for _ in range(pack)]
+    for n in range(index_heads):
+        s_n = again(n)
+        through = jnp.where(s_n > 0, d_scores, 0.0)  # d loss / d relu's input, the weight aside
+        dw_acc[n:n + 1, :] += jnp.sum(through * s_n, axis=0, keepdims=True)
+        g = (through * weight(n)).astype(k_all.dtype)
+        dqi_acc[:, lanes(n)] += jax.lax.dot_general(g, k_at[n % pack], (((0,), (0,)), ((), ())),
+                                                 preferred_element_type=jnp.float32)
+        dk[n % pack] = dk[n % pack] + jax.lax.dot_general(g, rows(n), (((1,), (0,)), ((), ())),
+                                                          preferred_element_type=jnp.float32)
+    # Slot r's product holds head r's dkI at its own lanes and another head's pairing at the rest.
+    dki_ref[0, 0] = sum(dk[r][:, r * d_i:(r + 1) * d_i] for r in range(pack))
+
+
+class LossPlan(NamedTuple):
+    """What one program of `index_loss` is: its (Q tile, K tile) pair, whether it
+    keeps the pair's index scores between their two uses, and the VMEM it holds."""
+
+    tile_q: int
+    tile_k: int
+    keep_scores: bool
+    vmem_bytes: int
+
+
+def _loss_bytes(tile_q, tile_k, keep_scores, heads, kv_heads, d, index_heads, d_i, itemsize) -> int:
+    """The VMEM a program holds, counted from above: every block at the lanes and
+    sublanes it pads to, twice where the pipeline fetches it ahead of a pair (what
+    changes with the Q tile alone is held once), the scratch, and the (tile_k,
+    tile_q) f32 values alive at once: the probabilities, the mask, the scores'
+    sum, a product coming out and one going in, and with `keep_scores` one an
+    indexer head. The smallest `vmem_limit_bytes` that compiles for the v5e at
+    (32 on 4, 128), 16 x 64 in bf16 (ahead-of-time compiles, PR 43): 256 x 256
+    kept 10.75 MiB for the 12.8 counted here, 256 x 512 kept 16.5 for 18.6, not
+    kept 256 x 512 9.5 for 10.6, 256 x 1024 13.6 for 14.4, 512 x 1024 20.3 for 25.2."""
+    lanes = lambda n: -(-n // LANES) * LANES
+    rows = lambda n: -(-n // 8) * 8
+    pack, padded = _lane_rows(index_heads, d_i)
+    lane_rows = lanes(padded * d_i)
+    of_q_tile = (2 * heads * tile_q * lanes(d) * itemsize  # q and its scaled copy
+                 + (rows(heads) + 4 * rows(index_heads) + 4 * 8) * tile_q * 4  # lse; w, dw twice, its sum; lse_i, the loss
+                 + tile_q * lane_rows * (3 * itemsize + 4))  # q_i, dq_i twice, its f32 sum
+    of_pair = 2 * (kv_heads * tile_k * lanes(d) * itemsize + tile_q * LANES * 4
+                   + tile_k * lanes(pack * d_i) * itemsize + tile_k * lanes(d_i) * 4)  # k, keep, k_i, dk_i's share
+    alive = 4 + (index_heads if keep_scores else 0)
+    return of_q_tile + of_pair + alive * tile_k * tile_q * 4
+
+
+def _loss_plan(heads, kv_heads, seq, d, index_heads, d_i, itemsize) -> LossPlan:
+    """The pair a program of `index_loss` takes, from the shapes and `_loss_bytes`:
+    of `LOSS_TILES` cut to the row (gcd) the largest pair that holds `LOSS_VMEM_BYTES`
+    or less, one that keeps the scores before one that makes them twice, and of two
+    as large the one with more query rows (each Q tile writes a dkI share of its
+    own). Where none fits, the smallest, scores made twice."""
+    plans = []
+    for tile_q, tile_k in LOSS_TILES:
+        tile_q, tile_k = int(np.gcd(seq, tile_q)), int(np.gcd(seq, tile_k))
+        for keep_scores in (True, False):
+            size = _loss_bytes(tile_q, tile_k, keep_scores, heads, kv_heads, d, index_heads, d_i, itemsize)
+            plans.append(LossPlan(tile_q, tile_k, keep_scores, size))
+    fitting = [p for p in plans if p.vmem_bytes <= LOSS_VMEM_BYTES]
+    if not fitting:
+        return min(plans, key=lambda p: p.vmem_bytes)
+    return max(fitting, key=lambda p: (p.keep_scores, p.tile_q * p.tile_k, p.tile_q))
+
+
+def _index_loss_kernel(steps_ref, q_ref, k_ref, lse_ref, keep_ref, qi_ref, ki_ref, w_ref, lsei_ref,
+                       loss_ref, dqi_ref, dw_ref, dki_ref, qs, dqi_acc, dw_acc, loss_acc, *,
+                       sm_scale, tile_q, tile_k, heads, kv_heads, index_heads, d_i, keep_scores):
+    """Grid (batch, pairs), the pairs in turn: a program is a whole (Q tile, K
+    tile) pair, every head of it. Tiles inside are (keys, queries): what belongs
+    to a query (a head's log-sum-exp, an indexer head's weight, the loss's term)
+    is a row, read down the sublanes and summed down them. The Q tile's first
+    pair scales its queries into `qs`; `dqi_acc`, `dw_acc` and `loss_acc` gather
+    over the Q tile's pairs and leave in its last; the pair's share of dkI goes
+    out as a block of its own."""
+    t = pl.program_id(1)
+    i, j = steps_ref[0, t], steps_ref[1, t]
+
+    @pl.when(steps_ref[2, t] == 1)
     def _():
+        def scale(h, _):
+            qs[h] = (q_ref[0, h].astype(jnp.float32) * sm_scale).astype(qs.dtype)
+            return 0
+
+        jax.lax.fori_loop(0, heads, scale, 0)
         dqi_acc[...] = jnp.zeros_like(dqi_acc)
         dw_acc[...] = jnp.zeros_like(dw_acc)
         loss_acc[...] = jnp.zeros_like(loss_acc)
 
-    @pl.when(h == 0)
+    mask = _kept_pairs(keep_ref, i, j, tile_q, tile_k)
+    p = _pair_probabilities(qs, k_ref, lse_ref, heads=heads, kv_heads=kv_heads)
+    p = jnp.where(mask, p * (1.0 / heads), 0.0)
+    _pair_gradients(p, mask, qi_ref, ki_ref, w_ref, lsei_ref, dqi_acc, dw_acc, loss_acc, dki_ref,
+                    index_heads=index_heads, d_i=d_i, keep_scores=keep_scores)
+
+    @pl.when(steps_ref[4, t] == 1)
     def _():
-        p_acc[...] = jnp.zeros_like(p_acc)
-
-    q = (q_ref[0].astype(jnp.float32) * sm_scale).astype(q_ref.dtype)
-    s = jax.lax.dot_general(q, k_ref[0], (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
-    # What is not selected may overflow here: the select below lets none of it through.
-    p_acc[...] += jnp.exp(s - lse_ref[0])
-
-    @pl.when(h == heads - 1)
-    def _():
-        mask = _keep_tile(keep_ref, j, tile_k) & _causal_mask(tile_q, tile_k)(i, j)
-        p = jnp.where(mask, p_acc[...] * (1.0 / heads), 0.0)
-        k_i, w = ki_ref[0], w_ref[0]
-        lane = jax.lax.broadcasted_iota(jnp.int32, w.shape, 1)
-        column = lambda n: jnp.sum(jnp.where(lane == n, w, 0.0), axis=1, keepdims=True)  # (tile_q, 1)
-        score = lambda n: jax.lax.dot_general(qi_ref[0, n], k_i, (((1,), (1,)), ((), ())),
-                                              preferred_element_type=jnp.float32)
-        weights = [column(n) for n in range(index_heads)]
-        scores = sum(weights[n] * jnp.maximum(score(n), 0.0) for n in range(index_heads))
-        log_q = scores - lsei_ref[0]
-        positive = p > 0
-        loss_acc[...] += jnp.sum(
-            jnp.where(positive, p * (jnp.log(jnp.where(positive, p, 1.0)) - log_q), 0.0), axis=1, keepdims=True)
-        d_scores = jnp.where(mask, jnp.exp(log_q), 0.0) - p
-        dk = jnp.zeros(k_i.shape, jnp.float32)
-        dw = jnp.zeros(w.shape, jnp.float32)
-        for n in range(index_heads):
-            s_n = score(n)
-            g = jnp.where(s_n > 0, d_scores * weights[n], 0.0).astype(k_i.dtype)
-            dqi_acc[n] += jax.lax.dot_general(g, k_i, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-            dk = dk + jax.lax.dot_general(g, qi_ref[0, n], (((0,), (0,)), ((), ())),
-                                          preferred_element_type=jnp.float32)
-            dw = dw + jnp.where(lane == n, jnp.sum(d_scores * jnp.maximum(s_n, 0.0), axis=1, keepdims=True), 0.0)
-        dw_acc[...] += dw
-        dki_ref[0, 0] = dk
-
-        @pl.when(last)
-        def _():
-            loss_ref[0] = loss_acc[...]
-            dqi_ref[0] = dqi_acc[...].astype(dqi_ref.dtype)
-            dw_ref[0] = dw_acc[...]
+        loss_ref[0] = loss_acc[...]
+        dqi_ref[0] = dqi_acc[...].astype(dqi_ref.dtype)
+        dw_ref[0] = dw_acc[...]
 
 
 def _pallas_index_loss(q, k, lse, keep, q_i, k_i, w, lse_i, sm_scale, interpret):
     """(the loss, d loss / d q_i, d k_i, d w), the mean's 1 / (batch * seq) in all four."""
     batch, heads, seq, d = q.shape
     kv_heads, index_heads, d_i = k.shape[1], q_i.shape[1], q_i.shape[3]
-    tile_q, tile_k = int(np.gcd(seq, LOSS_TILE_Q)), int(np.gcd(seq, LOSS_TILE_K))
+    plan = _loss_plan(heads, kv_heads, seq, d, index_heads, d_i, q.dtype.itemsize)
+    tile_q, tile_k = plan.tile_q, plan.tile_k
     n_q = seq // tile_q
-    plan = KernelPlan(tile_q, tile_k, 0, 0, 0, False)
-    steps = _fwd_schedule(seq, plan, True)
-    group = heads // kv_heads
+    steps = _fwd_schedule(seq, KernelPlan(tile_q, tile_k, 0, 0, 0, False), True)
     per_span = KEEP_SPAN // tile_k
-    w_lanes = jnp.pad(w.astype(jnp.float32), ((0, 0), (0, 0), (0, LANES - index_heads)))
-    row = lambda width: pl.BlockSpec((1, tile_q, width), lambda b, t, h, steps: (b, steps[0, t], 0))
+    # The indexer's heads side by side along the lanes, `pack` to a row of 128, zero heads to fill the last.
+    pack, padded = _lane_rows(index_heads, d_i)
+    q_lanes = jnp.pad(q_i, ((0, 0), (0, padded - index_heads), (0, 0), (0, 0)))
+    q_lanes = q_lanes.transpose(0, 2, 1, 3).reshape(batch, seq, padded * d_i)
+    k_lanes = jnp.tile(k_i, (1, 1, pack))
+    once = pl.Buffered(1)  # a block that changes with the Q tile alone: nothing to fetch ahead of a pair
+    of_q = lambda *block, **kw: pl.BlockSpec((1, *block), lambda b, t, steps: (b, 0, steps[0, t]), **kw)
+    q_rows = lambda width, **kw: pl.BlockSpec((1, tile_q, width), lambda b, t, steps: (b, steps[0, t], 0), **kw)
     with jax.named_scope("index_loss"):
-        loss, dq_i, dw, dk_parts = pl.pallas_call(
-            functools.partial(_index_loss_kernel, sm_scale=sm_scale, tile_q=tile_q, tile_k=tile_k,
-                              heads=heads, index_heads=index_heads),
+        loss, dq_lanes, dw, dk_parts = pl.pallas_call(
+            functools.partial(_index_loss_kernel, sm_scale=sm_scale, tile_q=tile_q, tile_k=tile_k, heads=heads,
+                              kv_heads=kv_heads, index_heads=index_heads, d_i=d_i, keep_scores=plan.keep_scores),
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=1,
-                grid=(batch, steps.shape[1], heads),
+                grid=(batch, steps.shape[1]),
                 in_specs=[
-                    pl.BlockSpec((1, tile_q, d), lambda b, t, h, steps: (b * heads + h, steps[0, t], 0)),
-                    pl.BlockSpec((1, tile_k, d),
-                                 lambda b, t, h, steps: (b * kv_heads + h // group, steps[1, t], 0)),
-                    pl.BlockSpec((1, tile_q, 1), lambda b, t, h, steps: (b * heads + h, steps[0, t], 0)),
-                    pl.BlockSpec((1, tile_q, LANES),
-                                 lambda b, t, h, steps: (b, steps[0, t], steps[1, t] // per_span)),
-                    pl.BlockSpec((1, index_heads, tile_q, d_i), lambda b, t, h, steps: (b, 0, steps[0, t], 0)),
-                    pl.BlockSpec((1, tile_k, d_i), lambda b, t, h, steps: (b, steps[1, t], 0)),
-                    row(LANES), row(1)],
+                    pl.BlockSpec((1, heads, tile_q, d), lambda b, t, steps: (b, 0, steps[0, t], 0),
+                                 pipeline_mode=once),
+                    pl.BlockSpec((1, kv_heads, tile_k, d), lambda b, t, steps: (b, 0, steps[1, t], 0)),
+                    of_q(heads, tile_q, pipeline_mode=once),
+                    pl.BlockSpec((1, tile_q, LANES), lambda b, t, steps: (b, steps[0, t], steps[1, t] // per_span)),
+                    q_rows(padded * d_i, pipeline_mode=once),
+                    pl.BlockSpec((1, tile_k, pack * d_i), lambda b, t, steps: (b, steps[1, t], 0)),
+                    of_q(index_heads, tile_q, pipeline_mode=once),
+                    of_q(1, tile_q, pipeline_mode=once)],
                 out_specs=[
-                    row(1),
-                    pl.BlockSpec((1, index_heads, tile_q, d_i), lambda b, t, h, steps: (b, 0, steps[0, t], 0)),
-                    row(LANES),
-                    pl.BlockSpec((1, 1, tile_k, d_i), lambda b, t, h, steps: (b, steps[0, t], steps[1, t], 0))],
-                scratch_shapes=[pltpu.VMEM((tile_q, tile_k), jnp.float32),
-                                pltpu.VMEM((index_heads, tile_q, d_i), jnp.float32),
-                                pltpu.VMEM((tile_q, LANES), jnp.float32),
-                                pltpu.VMEM((tile_q, 1), jnp.float32)]),
-            out_shape=[jax.ShapeDtypeStruct((batch, seq, 1), jnp.float32),
-                       jax.ShapeDtypeStruct(q_i.shape, q_i.dtype),
-                       jax.ShapeDtypeStruct((batch, seq, LANES), jnp.float32),
+                    of_q(1, tile_q), q_rows(padded * d_i), of_q(index_heads, tile_q),
+                    pl.BlockSpec((1, 1, tile_k, d_i), lambda b, t, steps: (b, steps[0, t], steps[1, t], 0))],
+                scratch_shapes=[pltpu.VMEM((heads, tile_q, d), q.dtype),
+                                pltpu.VMEM((tile_q, padded * d_i), jnp.float32),
+                                pltpu.VMEM((index_heads, tile_q), jnp.float32),
+                                pltpu.VMEM((1, tile_q), jnp.float32)]),
+            out_shape=[jax.ShapeDtypeStruct((batch, 1, seq), jnp.float32),
+                       jax.ShapeDtypeStruct(q_lanes.shape, q_i.dtype),
+                       jax.ShapeDtypeStruct((batch, index_heads, seq), jnp.float32),
                        jax.ShapeDtypeStruct((batch, n_q, seq, d_i), jnp.float32)],
             interpret=interpret,
             name="index_loss",
             compiler_params=None if interpret else pltpu.CompilerParams(
-                dimension_semantics=("parallel", "arbitrary", "arbitrary")),
-        )(jnp.asarray(steps), q.reshape(-1, seq, d), k.reshape(-1, seq, d), lse.reshape(-1, seq, 1), keep,
-          q_i, k_i, w_lanes, lse_i[..., None])
+                dimension_semantics=("parallel", "arbitrary")),
+        )(jnp.asarray(steps), q, k, lse, keep, q_lanes, k_lanes, w.astype(jnp.float32).transpose(0, 2, 1),
+          lse_i[:, None, :])
         # A Q tile wrote the K tiles of its past and no other block of its part.
         visited = (np.arange(seq)[None, :] // tile_k) * tile_k < (np.arange(n_q)[:, None] + 1) * tile_q
         dk_i = jnp.sum(jnp.where(visited[None, :, :, None], dk_parts, 0.0), axis=1)
         scale = 1.0 / (batch * seq)
+        dq_i = dq_lanes.reshape(batch, seq, padded, d_i)[:, :, :index_heads].transpose(0, 2, 1, 3)
         return (jnp.sum(loss) * scale, (dq_i.astype(jnp.float32) * scale).astype(q_i.dtype),
-                (dk_i * scale).astype(k_i.dtype), dw[..., :index_heads] * scale)
+                (dk_i * scale).astype(k_i.dtype), dw.transpose(0, 2, 1) * scale)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(8, 9))

@@ -326,8 +326,10 @@ def test_both_flash_kernels_stream_pairs_under_a_selection_at_16384_by_128(aot):
 
 def test_the_selection_and_the_indexer_loss_compile_for_v5e_at_the_cells_shapes(aot):
     """`select` (64 query rows' scores against 16,384 keys in VMEM, the threshold, the packed words)
-    and `index_loss` (grid (1, 1056 pairs of 256 x 512, 32 heads); the gradients leave the same call,
-    so its backward pass is no kernel) at the Keye cell's indexer of 16 heads of 64, top-2,048."""
+    and `index_loss` (grid (1, 2080 pairs of 256 x 256), a program a whole pair: the 32 heads' products on
+    their 4 key/value heads, the 16 index scores made once and kept, 10.75 MiB of VMEM by the smallest
+    limit that compiles, under the default with none asked for; the gradients leave the same call, so its
+    backward pass is no kernel) at the Keye cell's indexer of 16 heads of 64, top-2,048."""
     got = aot[INDEXER_16K]
     assert got["select"] == ["select"] and got["index_loss"] == ["index_loss"]
     assert got["keep"] == [1, 16384, 512] and got["index_loss_mosaic_calls"] == 1

@@ -374,16 +374,19 @@ def _top_k_set(scores, k):
     return (masked >= tau) & causal
 
 
-@pytest.fixture(scope="module")
-def indexed():
-    keys = jax.random.split(jax.random.PRNGKey(3), 3)
-    b, heads, seq, d = 2, 4, 512, 32
+def _indexer_inputs(b, heads, seq, d, seed=3):
+    keys = jax.random.split(jax.random.PRNGKey(seed), 3)
     q_i = jax.random.normal(keys[0], (b, heads, seq, d), jnp.float32)
     k_i = jax.random.normal(keys[1], (b, seq, d), jnp.float32)
     # Keys 100-139 alike: their scores tie for every query, in and out of the top k.
     k_i = k_i.at[:, 100:140].set(k_i[:, 100:101])
     w = jax.random.normal(keys[2], (b, seq, heads), jnp.float32) * 0.3
     return q_i, k_i, w
+
+
+@pytest.fixture(scope="module")
+def indexed():
+    return _indexer_inputs(2, 4, 512, 32)
 
 
 @pytest.mark.parametrize("backend", ["xla", "pallas"])
@@ -427,33 +430,60 @@ def test_the_threshold_is_the_kth_largest_on_the_bit_pattern(values):
     assert bool((none == INT_MIN).all())  # fewer keys than k: everything is at or above it
 
 
+# batch, heads on key/value heads, row, head_dim, the indexer's heads x width, top k, operands, (Q tile, K tile)
+# where the case asks for tiles of its own: else what `_loss_plan` gives the shape
+INDEX_LOSS_CASES = {
+    "one_pair": (2, 4, 2, 512, 64, 4, 32, 96, jnp.float32, None),
+    # Q tiles of 256 on K tiles of 512: the third Q tile has two K tiles, the last crossed by the
+    # diagonal at its upper half, the fourth's at its lower; a group is 4 heads on a key/value head.
+    "several_k_tiles_8_on_2": (1, 8, 2, 1536, 64, 4, 32, 200, jnp.float32, (256, 512)),
+    "the_plans_own_tiles_of_1536": (1, 8, 2, 1536, 64, 4, 32, 200, jnp.float32, None),  # 512 x 256: two K tiles a diagonal
+    "three_indexer_heads": (1, 4, 2, 512, 64, 3, 32, 96, jnp.float32, (256, 256)),  # they fill no row of 128 lanes
+    "a_row_no_tile_divides": (1, 4, 2, 640, 64, 4, 32, 96, jnp.float32, None),  # 5 x 5 tiles of gcd(640, .) = 128
+    "two_heads_a_lane_row": (1, 4, 1, 512, 64, 6, 64, 96, jnp.float32, (128, 256)),  # the Keye cell's 64-wide heads
+    "scores_made_twice": (1, 4, 2, 512, 64, 4, 32, 96, jnp.float32, (256, 128, False)),
+    "bf16": (1, 8, 2, 1024, 64, 4, 32, 128, jnp.bfloat16, (256, 256)),
+}
+
+
 @pytest.mark.parametrize("backend", ["xla", "pallas"])
-def test_the_index_loss_and_its_gradient_against_the_dense_form(indexed, backend):
+@pytest.mark.parametrize("case", INDEX_LOSS_CASES)
+def test_the_index_loss_and_its_gradient_against_the_dense_form(case, backend, monkeypatch):
+    import importlib
+
     from ray_tpu.ops.flash_attention import pack_keep
     from ray_tpu.ops.lightning_indexer import index_loss, select, selection_counts
 
-    q_i, k_i, w = indexed
+    b, heads, kv_heads, seq, d, index_heads, index_d, topk, dtype, tiles = INDEX_LOSS_CASES[case]
+    if tiles is not None:
+        li = importlib.import_module("ray_tpu.ops.lightning_indexer")
+        monkeypatch.setattr(li, "_loss_plan", lambda *shape: li.LossPlan(*tiles[:2], (*tiles, True)[2], 0))
+    rounded = lambda x: x.astype(dtype).astype(jnp.float32)  # the yardstick sees what the operands hold
+    q_i, k_i, w = _indexer_inputs(b, index_heads, seq, index_d)
+    q_i, k_i = rounded(q_i), rounded(k_i)
     keys = jax.random.split(jax.random.PRNGKey(4), 2)
-    q = jax.random.normal(keys[0], (2, 4, 512, 64), jnp.float32)
-    k = jax.random.normal(keys[1], (2, 2, 512, 64), jnp.float32)
-    kept = _top_k_set(_scores(q_i, k_i, w), 96)
-    keep, lse_i = select(q_i, k_i, w, 96, backend="xla")
+    q = rounded(jax.random.normal(keys[0], (b, heads, seq, d), jnp.float32))
+    k = rounded(jax.random.normal(keys[1], (b, kv_heads, seq, d), jnp.float32))
+    kept = _top_k_set(_scores(q_i, k_i, w), topk)
+    keep, lse_i = select(q_i, k_i, w, topk, backend="xla")
     assert bool((pack_keep(kept) == keep).all())
     _, lse = _dense_masked(q, k, k, kept)
 
     def dense(q_i, k_i, w):
-        s = jnp.einsum("bhqd,bhkd->bhqk", q, jnp.repeat(k, 2, axis=1)) * 64 ** -0.5
+        s = jnp.einsum("bhqd,bhkd->bhqk", q, jnp.repeat(k, heads // kv_heads, axis=1)) * d ** -0.5
         p = jax.nn.softmax(jnp.where(kept[:, None], s, -jnp.inf), axis=-1).mean(axis=1)
         log_q = jax.nn.log_softmax(jnp.where(kept, _scores(q_i, k_i, w), -jnp.inf), axis=-1)
         live = kept & (p > 0)
-        return jnp.sum(jnp.where(live, p * (jnp.log(jnp.where(live, p, 1.0)) - jnp.where(kept, log_q, 0.0)), 0.0)) / 1024
+        return jnp.sum(jnp.where(live, p * (jnp.log(jnp.where(live, p, 1.0)) - jnp.where(kept, log_q, 0.0)), 0.0)) / (b * seq)
 
-    mine = lambda q_i, k_i, w: index_loss(q, k, lse, keep, q_i, k_i, w, lse_i, backend=backend, interpret=True)
+    mine = lambda q_i, k_i, w: index_loss(q.astype(dtype), k.astype(dtype), lse, keep, q_i.astype(dtype), k_i.astype(dtype),
+                                          w, lse_i, backend=backend, interpret=True)
+    tol = 2e-5 if dtype == jnp.float32 else TOLERANCE[dtype]
     want, want_g = jax.value_and_grad(dense, argnums=(0, 1, 2))(q_i, k_i, w)
     got, got_g = jax.value_and_grad(mine, argnums=(0, 1, 2))(q_i, k_i, w)
-    assert float(got) == pytest.approx(float(want), rel=2e-5) and float(want) > 0.05
-    for a, b in zip(got_g, want_g):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-5 * float(jnp.abs(b).max()) + 1e-9)
+    assert float(got) == pytest.approx(float(want), rel=tol) and float(want) > 0.05
+    for a, b_ in zip(got_g, want_g):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b_), atol=tol * float(jnp.abs(b_).max()) + 1e-9)
     # No gradient reaches the attention's own operands, and a cotangent scales the three.
     dq, dk = jax.grad(lambda q, k: index_loss(q, k, lse, keep, q_i, k_i, w, lse_i, backend=backend,
                                               interpret=True), argnums=(0, 1))(q, k)
@@ -461,9 +491,34 @@ def test_the_index_loss_and_its_gradient_against_the_dense_form(indexed, backend
     twice = jax.grad(lambda w: 2.0 * mine(q_i, k_i, w))(w)
     np.testing.assert_allclose(np.asarray(twice), 2 * np.asarray(got_g[2]), rtol=1e-6)
     counts = selection_counts(keep, tile=128)
-    assert float(counts["selected_pairs"]) == float(kept.sum()) and int(counts["tiles"]) == 2 * 10
-    assert float(counts["causal_pairs"]) == 2 * 512 * 513 / 2 and 1 <= int(counts["live_tiles"]) <= 20
-    assert int(counts["keys_per_query_min"]) == 1 and int(counts["keys_per_query_max"]) >= 96
+    n = seq // 128
+    assert float(counts["selected_pairs"]) == float(kept.sum()) and int(counts["tiles"]) == b * n * (n + 1) // 2
+    assert float(counts["causal_pairs"]) == b * seq * (seq + 1) / 2 and 1 <= int(counts["live_tiles"]) <= int(counts["tiles"])
+    assert int(counts["keys_per_query_min"]) == 1 and int(counts["keys_per_query_max"]) >= topk
+
+
+def test_the_index_loss_plan_counts_what_a_program_holds_and_cuts_its_tiles_to_the_row():
+    """At the Keye cell's shapes the plan keeps the pair's 16 index scores, at the largest pair whose count
+    stays under what it may hold with no `vmem_limit_bytes` asked for; the count is from above (the v5e's
+    compiler takes 10.75 MiB for that program: `tests/test_aot_v5e.py` compiles it); tiles divide every row."""
+    from ray_tpu.ops.flash_attention import KEEP_SPAN, LANES
+    from ray_tpu.ops.lightning_indexer import LOSS_VMEM_BYTES, _loss_bytes, _loss_plan
+
+    cell = (32, 4, 16384, 128, 16, 64, 2)
+    plan = _loss_plan(*cell)
+    assert plan == (256, 256, True, _loss_bytes(256, 256, True, 32, 4, 128, 16, 64, 2))
+    assert 10.75 * 2 ** 20 < plan.vmem_bytes <= LOSS_VMEM_BYTES < 16 * 2 ** 20
+    assert _loss_bytes(256, 512, True, 32, 4, 128, 16, 64, 2) > LOSS_VMEM_BYTES  # the next pair up holds too much
+    assert _loss_bytes(512, 512, False, 32, 4, 128, 16, 64, 2) > 16 * 2 ** 20  # 15.4 MiB alone, not inside a step
+    # More indexer heads than any pair keeps the scores of: made twice, at the largest pair that fits.
+    wide = _loss_plan(32, 4, 16384, 128, 32, 64, 2)
+    assert not wide.keep_scores and wide.vmem_bytes <= LOSS_VMEM_BYTES and wide[:2] == (256, 512)
+    for seq, shape in ((512, (4, 2, 512, 64, 4, 64, 4)), (1536, (8, 2, 1536, 64, 4, 32, 4)), (640, (4, 2, 640, 64, 4, 32, 4)),
+                       (4096, (32, 8, 4096, 64, 16, 64, 2))):
+        plan = _loss_plan(*shape)
+        assert seq % plan.tile_q == seq % plan.tile_k == 0 and KEEP_SPAN % plan.tile_k == plan.tile_k % LANES == 0
+        assert plan.vmem_bytes <= LOSS_VMEM_BYTES
+    assert _loss_plan(4, 2, 640, 64, 4, 32, 4)[:2] == (128, 128)
 
 
 def test_keep_at_the_pair_forms_own_tiles_of_512_by_1024():
