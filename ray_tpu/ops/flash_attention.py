@@ -22,14 +22,19 @@ Design (pallas_guide.md playbook):
    program a (Q tile, K tile) pair and every operand streamed (`_bwd_pairs`).
  - forward, where a head is too large for a program to hold K and V (above
    2 MiB a head in VMEM: from 8,192 x 128 in bf16 on), where key/value heads
-   are fewer than query heads, or where the call brings a selection: the
-   backward's pair form (`_fwd_pairs`): a program a (Q tile, K tile) pair
-   under a scalar-prefetched schedule, Q tiles in turn and under each its K
-   tiles up to the diagonal, the running maximum, sum and f32 output of the Q
-   tile in VMEM scratch, every operand the pair's own tile. A query head
-   reads key/value head `head // group` by index map: k and v are never
-   repeated, and dk, dv leave the backward kernel a query head each for the
-   caller to sum.
+   are fewer than query heads, or where the call brings a selection: a
+   program a (Q tile, K tile) pair (`_fwd_pairs`) under a scalar-prefetched
+   schedule, Q tiles in turn and under each its K tiles up to the diagonal,
+   of several query heads at once: a key/value head's whole group, part of
+   it, or several groups with their key/value heads (`_fwd_pairs_plan`, by
+   the VMEM a program holds). k, v and the selection's block are fetched and
+   the mask made once for all of them. Inside, a tile lies keys down and
+   queries across, so what belongs to a query (maximum, sum, alpha) is a
+   lane-dense row and no reduction crosses the lanes; the pair is walked a
+   128 x 128 block of scores at a time, which stay in registers from the
+   product that makes them to the product that uses them. k and v are never
+   repeated, and dk, dv leave the backward kernel (a pair of one query head a
+   program, `_bwd_pairs`) a query head each for the caller to sum.
  - a selection (`keep`): which keys each query may attend to, shared by the
    heads of a row, a bit a pair (`pack_keep`: 33.5 MB a row of 16,384). Both
    pair kernels take the pair's (tile_q, 128) block of words as one more
@@ -128,16 +133,50 @@ MAX_HEAD_BYTES = 4096 * 256 * 2
 MAX_STREAMED_HEAD_BYTES = 16384 * 128 * 2
 # The pair forms' K tile at heads no wider than the lanes. The sweep on the v5e
 # (tools/flash_bench.py, PR 42; one call at (32, 16384, 128) causal, both
-# passes a pair a program, forward / backward, us): 512 x 1024 (Q x K) 17,879 /
-# 34,335; 512-tiles 30,825 / 41,677; 256 x 512 37,740 / 50,000; 512 x 256
-# 57,147 / 57,165; 256-tiles 69,526 / 94,960. A program is short here (a pair
-# of 512-tiles is 0.13 GFLOP forward) and pays its start-up and the (tile_q, 1)
-# statistics' lane-padded blocks every time: the longer K tile halves the
-# programs. What compiles ahead of time for the v5e under the 16 MiB: these
-# five and 256 x 1024; a Q tile of 1,024 fails in `flash_bwd` (its f32 dq
-# scratch alone is 8 MiB), a K tile of 2,048 in `flash_bwd` at any Q tile.
+# passes a pair of one head a program, forward / backward, us): 512 x 1024 (Q x
+# K) 17,879 / 34,335; 512-tiles 30,825 / 41,677; 256 x 512 37,740 / 50,000; 512
+# x 256 57,147 / 57,165; 256-tiles 69,526 / 94,960. A program is short there (a
+# pair of 512-tiles is 0.13 GFLOP forward) and pays its start-up every time:
+# the longer K tile halves the programs. What compiles ahead of time for the
+# v5e under the 16 MiB: these five and 256 x 1024; a Q tile of 1,024 fails in
+# `flash_bwd` (its f32 dq scratch alone is 8 MiB), a K tile of 2,048 in
+# `flash_bwd` at any Q tile.
+# The forward since PR 44 (`_fwd_pairs_kernel`; tools/flash_bench.py --tiles
+# plan, one call at 512 x 1024, forward us, the form it replaced / this one;
+# the backward is untouched, 34,854 with a selection): 32 heads on 4 with a
+# selection of 2,048 keys a query (--kv-heads 4 --keep-topk 2048), 8 heads x
+# 512 queries a program: 23,761 / 15,804, where the MXU needs 11,860 for the
+# 272 walked pairs; the same without a selection 17,960 / 15,181; 32 equal
+# heads, four and their key/value heads a program, 17,879 / 15,796 (one a
+# program, every block unrolled: 20,011); (8, 8192, 256) at 512-tiles 2,228 /
+# 1,869. By --fwd: 8 x 256 15,068 where 8 x 512 was 14,010. By --part at 8 x
+# 512: the two products alone 15,519 (the first 8,005, the second 8,269), the
+# softmax alone 9,938, neither 4,661: the MXU binds, the vector work hides
+# behind it, and the loop over key blocks is the rest (unrolled: 14,026 whole,
+# 13,933 the products, 2,375 neither).
 PAIRS_TILE_K = 1024
 NEG_INF = -1e30
+# Where the pair-streamed forward's running maximum starts (`_fwd_pairs_kernel`):
+# far above `NEG_INF`, far below any score, so that exp(NEG_INF - m) is 0 by itself.
+FLOOR = -1e20
+# What a program of that forward may hold of Mosaic's default 16 MiB (`_fwd_pairs_plan`; as
+# `lightning_indexer.LOSS_VMEM_BYTES`: inside a step XLA's fused operands come on top).
+FWD_PAIRS_VMEM_BYTES = 14 * 2 ** 20
+# The block of scores its online softmax steps by, (keys, queries): 32 registers of f32, which stay
+# in the register file from the product that makes them to the product that uses them; and the
+# blocks a trip of its loop over key blocks may hold (every chain of the program, a head's block of
+# queries, over as many key blocks as that allows: 2 for 8 heads x 512 queries, 4 for 4 heads). By
+# the compiler's own schedule (cycles a program of 8 heads x 512 x 1,024, ahead of time for the v5e,
+# PR 44; the MXU's own 16.4 k): a whole (1,024, 512) tile a step 24.9 k; blocks of (256, 128) 22.0 k,
+# (256, 256) 21.5 k, (128, 256) 18.6 k, (128, 128) 18.3 k, (64, 256) 50.4 k; (128, 256) with a
+# head's statistics read and written a block 17.3 k, as (128, 128). On the chip, us a call: the
+# whole tile 18,846, (128, 128) 14,010, (128, 256) 14,242, every block unrolled. Unrolled, a program
+# is 128 blocks that every trace of a step traces and lowers again: + 15 s of a 72 s set-up, and
+# what holds the interpreter that long brought the runtime's watchdog down on the worker (PERF.md
+# sections 6 and 7, PR 44). In a loop, a trip's first and last blocks have nothing to overlap
+# with: 64 blocks a trip 18.1 k cycles and 14,624 us, 32 a trip 19.8 k and 15,804, 16 a trip 23.2 k
+# (4 heads: 18,224 us at 16 a trip, 15,796 at 32, 14,563 unrolled); set-up + 3.5 s at 32 a trip.
+FWD_STEP_KEYS, FWD_STEP_QUERIES, FWD_BLOCKS_A_TRIP = 128, 256, 32
 # A selection (`keep`) is packed a bit a (query, key) pair, shared by the heads of
 # a row: word [q, span * 128 + lane] of int32 holds in bit b the key
 # `span * 4096 + b * 128 + lane`, so a K tile of 128 * n keys is n bits of one
@@ -410,58 +449,140 @@ def _fwd_schedule(seq: int, plan: "KernelPlan", causal: bool):
     return np.asarray(steps, np.int32).T
 
 
+def _keep_tile_t(keep_ref, j, tile_k):
+    """`_keep_tile` turned over, (tile_k, tile_q) bool, keys down and queries
+    across: bit b of the turned words is a (128, tile_q) slab of keys."""
+    bits = tile_k // LANES
+    words = keep_ref[0].T  # (128, tile_q)
+    first = (j % (KEEP_SPAN // tile_k)) * bits
+    return jnp.concatenate([(words >> (first + b)) & 1 for b in range(bits)], axis=0) != 0
+
+
+def _scores_t(k, qs):
+    """s^T (keys, queries) f32 of one head: keys down the sublanes, queries across the lanes."""
+    return jax.lax.dot_general(k, qs, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+
+
+def _softmax_step(s, m_prev, l_prev):
+    """One step of the online softmax, keys down: the maximum and the sum run
+    down the sublanes, and what belongs to a query (m, l, alpha) is a (1,
+    queries) row. A masked score is `NEG_INF` and `m_prev` at least `FLOOR`, so
+    its exp is 0 by itself. -> (p, m_new, l_new, alpha)."""
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=0, keepdims=True))
+    p = jnp.exp(s - m_new)
+    alpha = jnp.exp(m_prev - m_new)
+    return p, m_new, l_prev * alpha + jnp.sum(p, axis=0, keepdims=True), alpha
+
+
+def _values_t(v_t, p):
+    """o^T's share of a step, (d, queries) f32, from `v_t` (d, keys) and `p` (keys, queries)."""
+    return jax.lax.dot_general(v_t, p, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+
+
+@jax.jit
+def _block_step(k, v_t, qs, bias, m, l, acc):
+    """One block of keys against one block of a head's queries: the head's (m, l, o^T) of those
+    queries after it. Jitted: the kernel holds up to `FWD_BLOCKS_A_TRIP` of these a trip and a step traces
+    the kernel four or five times; so they are traced once a process and not one by one."""
+    s = _scores_t(k, qs)
+    if bias is not None:
+        s = s + bias
+    p, m, l, alpha = _softmax_step(s, m, l)
+    return m, l, acc * alpha + _values_t(v_t, p.astype(v_t.dtype))
+
+
 def _fwd_pairs_kernel(steps_ref, q_ref, k_ref, v_ref, *refs, sm_scale, tile_q, tile_k, has_keep):
     """The forward pass with a program a (Q tile, K tile) pair (`_fwd_schedule`,
-    grid axis 1, sequential): every operand is the pair's own tile, streamed,
-    and all a Q tile keeps in VMEM over its pairs is its running maximum, sum
-    and f32 output. `keep_ref` (with `has_keep`) is the pair's tile of the
-    packed selection."""
+    grid axis 1, sequential) of the `heads` consecutive query heads that the
+    program takes (`q_ref` (1, heads, tile_q, d), held once a Q tile and scaled
+    into `qs` in its first pair) and of the key/value heads they read (`k_ref`,
+    `v_ref` (1, kv heads, tile_k, d): one that the heads share, or one a group
+    of them): k, v and the packed selection are the pair's own tiles, fetched
+    once for all the heads. Inside, a tile is (keys, queries): the pair's mask
+    (the selection's, the diagonal's where it crosses) becomes one additive
+    `bias` that every head reads, v is turned once, and a head's running
+    maximum, sum and f32 output `o^T` (d, tile_q) stay in VMEM over the Q
+    tile's pairs; the output is turned back and written in the last.
+
+    The pair itself is walked a (`FWD_STEP_KEYS`, `FWD_STEP_QUERIES`) block of
+    scores at a time, the online softmax's step (`_block_step`): 32 registers
+    of scores that never leave the register file, where a whole (tile_k,
+    tile_q) tile is stored between its maximum and its exponentials and loaded
+    again. A head's blocks of the same queries are a chain; the chains of a
+    program are independent and lie side by side in a trip of the loop over
+    key blocks, where the compiler interleaves them so that the MXU does not
+    wait for the vector unit (PERF.md section 6, PR 44)."""
     keep_ref = refs[0] if has_keep else None
-    o_ref, lse_ref, m_acc, l_acc, o_acc = refs[has_keep:]
+    o_ref, lse_ref, qs, m_acc, l_acc, o_acc, bias = refs[has_keep:]
+    heads, kv_heads = q_ref.shape[1], k_ref.shape[1]
     t = pl.program_id(1)
     i, j = steps_ref[0, t], steps_ref[1, t]
-    first, masked, last = (steps_ref[r, t] == 1 for r in (2, 3, 4))
+    first, crossed, last = (steps_ref[r, t] == 1 for r in (2, 3, 4))
 
     @pl.when(first)
     def _():
-        m_acc[...] = jnp.full_like(m_acc, NEG_INF)
+        for h in range(heads):
+            qs[h] = (q_ref[0, h].astype(jnp.float32) * sm_scale).astype(qs.dtype)
+        # Far above `NEG_INF`, far below any score: a row that has kept no key yet sums zeros.
+        m_acc[...] = jnp.full_like(m_acc, FLOOR)
         l_acc[...] = jnp.zeros_like(l_acc)
         o_acc[...] = jnp.zeros_like(o_acc)
 
-    def pair(crossed):
+    def mask_to_bias(diagonal):
         def run():
-            q = (q_ref[0].astype(jnp.float32) * sm_scale).astype(q_ref.dtype)
-            v = v_ref[0]
-            s = jax.lax.dot_general(
-                q, k_ref[0], (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
-            mask = _causal_mask(tile_q, tile_k)(i, j) if crossed else None
-            if has_keep:
-                kept = _keep_tile(keep_ref, j, tile_k)
-                mask = kept if mask is None else mask & kept
-            if mask is not None:
-                s = jnp.where(mask, s, NEG_INF)
-            m_prev = m_acc[...]
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-            p = jnp.exp(s - m_new)
-            if has_keep:
-                # A row may keep no key of this tile, and none of any tile before it:
-                # its maximum is still NEG_INF, and exp(0) must not count.
-                p = jnp.where(mask, p, 0.0)
-            alpha = jnp.exp(m_prev - m_new)
-            l_acc[...] = l_acc[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-            o_acc[...] = o_acc[...] * alpha + jax.lax.dot_general(
-                p.astype(v.dtype), v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-            m_acc[...] = m_new
+            mask = _keep_tile_t(keep_ref, j, tile_k) if has_keep else None
+            if diagonal:
+                diff = (jax.lax.broadcasted_iota(jnp.int32, (tile_k, tile_q), 1)
+                        - jax.lax.broadcasted_iota(jnp.int32, (tile_k, tile_q), 0))
+                under = diff >= j * tile_k - i * tile_q
+                mask = under if mask is None else mask & under
+            bias[...] = jnp.where(mask, 0.0, NEG_INF)
         return run
 
-    pl.when(masked)(pair(True))
-    pl.when(jnp.logical_not(masked))(pair(False))
+    def heads_of_pair(masked):
+        def run():
+            width, depth = math.gcd(tile_q, FWD_STEP_QUERIES), math.gcd(tile_k, FWD_STEP_KEYS)
+            steps, chains = tile_k // depth, heads * (tile_q // width)
+            # Key blocks a trip: as many as keep a trip's blocks at `FWD_BLOCKS_A_TRIP` or fewer, and one at least.
+            a_trip = max(n for n in range(1, steps + 1)
+                         if steps % n == 0 and n * chains <= max(FWD_BLOCKS_A_TRIP, chains))
+
+            def trip(n, carry):
+                for u in range(a_trip):
+                    keys = pl.ds(pl.multiple_of((n * a_trip + u) * depth, depth), depth)
+                    k = [k_ref[0, g, keys] for g in range(kv_heads)]
+                    v_t = [v_ref[0, g, keys].T for g in range(kv_heads)]
+                    for h in range(heads):
+                        g = h * kv_heads // heads  # the head's key/value head among the program's
+                        for c in range(tile_q // width):
+                            row, cols = slice(h, h + 1), slice(c * width, (c + 1) * width)
+                            m_acc[row, cols], l_acc[row, cols], o_acc[h, :, cols] = _block_step(
+                                k[g], v_t[g], qs[h, cols], bias[keys, cols] if masked else None,
+                                m_acc[row, cols], l_acc[row, cols], o_acc[h, :, cols])
+                return carry
+
+            jax.lax.fori_loop(0, steps // a_trip, trip, 0)
+        return run
+
+    if has_keep:  # every pair is masked: one body, behind whichever mask the pair has
+        pl.when(crossed)(mask_to_bias(True))
+        pl.when(jnp.logical_not(crossed))(mask_to_bias(False))
+        heads_of_pair(True)()
+    else:
+        @pl.when(crossed)
+        def _():
+            mask_to_bias(True)()
+            heads_of_pair(True)()
+
+        pl.when(jnp.logical_not(crossed))(heads_of_pair(False))
 
     @pl.when(last)
     def _():
-        l = jnp.maximum(l_acc[...], 1e-30)
-        o_ref[0] = (o_acc[...] / l).astype(o_ref.dtype)
-        lse_ref[0] = m_acc[...] + jnp.log(l)
+        for h in range(heads):
+            l = l_acc[h:h + 1]
+            o_ref[0, h] = (o_acc[h] / jnp.maximum(l, 1e-30)).T.astype(o_ref.dtype)
+            # A row that kept no key at all: o = 0, and the XLA form's log-sum-exp of nothing but `NEG_INF`.
+            lse_ref[0, h:h + 1] = jnp.where(l > 0, m_acc[h:h + 1] + jnp.log(l), NEG_INF)
 
 
 def _pair_specs(plan, d: int, group: int, q_row: int, k_row: int):
@@ -474,36 +595,91 @@ def _pair_specs(plan, d: int, group: int, q_row: int, k_row: int):
     return q_tile, k_tile
 
 
+def _fwd_pairs_bytes(heads: int, kv_heads: int, tile_q: int, tile_k: int, d: int, itemsize: int) -> int:
+    """The VMEM a program of `_fwd_pairs_kernel` holds, counted from above: of its
+    `heads` query heads q once and its scaled copy, o's block twice, the f32 `o^T`,
+    the statistics' rows; of its `kv_heads` key/value heads k and v twice (the
+    pipeline fetches them ahead of a pair) and v turned; the selection twice; the
+    (tile_k, tile_q) f32 bias and as much again for what the mask is made from.
+    The smallest `vmem_limit_bytes` that compiles ahead of time for the v5e (bf16,
+    PR 44): 8 heads on one x 512 on 1,024 keys at d = 128 with a selection 10.3 MiB
+    for the 11.8 counted here, 8 x 256 5.7 for 6.5, 32 x 256 14.8 for 15.6, 32 x 128
+    (no selection) 7.9 for 8.4, one head x 512 on 512 keys at d = 256 4.5 for 5.3."""
+    wide = max(d, LANES)
+    rows = -(-heads // 8) * 8
+    of_q_tile = heads * tile_q * (4 * wide * itemsize + d * 4) + 4 * rows * tile_q * 4
+    of_pair = kv_heads * 5 * tile_k * wide * itemsize + 2 * tile_q * LANES * 4
+    return of_q_tile + of_pair + 2 * tile_k * tile_q * 4
+
+
+def _fwd_pairs_plan(group: int, row_heads: int, d: int, itemsize: int, plan: "KernelPlan"):
+    """(query heads a program takes, its Q tile) of the pair-streamed forward
+    pass, from the shapes and `_fwd_pairs_bytes`. The heads are consecutive ones
+    of the `row_heads` that share a selection: part of a key/value head's
+    `group`, the group, or several groups with their key/value heads. The Q
+    tile is `plan`'s or a half, a quarter of it (the backward pass keeps the
+    plan's). Of those that hold `FWD_PAIRS_VMEM_BYTES` or less, the most heads x
+    queries: a program's chains are what fills the MXU's pipeline (PERF.md
+    section 6, PR 44: one head a program is 12 % slower than the form this
+    replaced); more heads before longer tiles (k, v and the mask are made once a
+    program); where nothing fits, the least."""
+    tiles = [plan.tile_q] + [plan.tile_q // n for n in (2, 4) if plan.tile_q % (n * LANES) == 0]
+    shapes = [(heads, tile_q) for heads in range(row_heads, 0, -1)
+              if row_heads % heads == 0 and (group % heads == 0 or heads % group == 0) for tile_q in tiles]
+    kv_heads = lambda shape: max(shape[0] // group, 1)
+    size = lambda shape: _fwd_pairs_bytes(shape[0], kv_heads(shape), shape[1], plan.tile_k, d, itemsize)
+    fitting = [shape for shape in shapes if size(shape) <= FWD_PAIRS_VMEM_BYTES]
+    if not fitting:
+        return min(shapes, key=size)
+    return max(fitting, key=lambda shape: (shape[0] * shape[1], -kv_heads(shape), shape[0]))
+
+
 def _fwd_pairs(q, k, v, keep, causal, sm_scale, plan, interpret):
     """q (batch * heads, seq, d); k, v (batch * kv heads, seq, d); keep
-    (batch, seq, spans * 128) or None."""
+    (batch, seq, spans * 128) or None. -> o as q, lse (batch * heads, seq, 1):
+    it leaves the kernel lane-dense, a row a head, and is reshaped to what
+    `_bwd_pairs` reads."""
     bh, seq, d = q.shape
     group = bh // k.shape[0]
-    steps = _fwd_schedule(seq, plan, causal)
-    q_tile, k_tile = _pair_specs(plan, d, group, 0, 1)
-    in_specs, operands = [q_tile(d), k_tile, k_tile], [q, k, v]
+    # Without a selection nothing ties a program's heads to one row of the batch.
+    row_heads = bh if keep is None else bh // keep.shape[0]
+    heads, tile_q = _fwd_pairs_plan(group, row_heads, d, q.dtype.itemsize, plan)
+    tile_k = plan.tile_k
+    steps = _fwd_schedule(seq, plan._replace(tile_q=tile_q), causal)
+    # Grid axis 0: `heads` query heads a program, on `kv_heads` key/value heads of their own or on one
+    # that `parts` programs share.
+    programs, kv_heads, parts = bh // heads, max(heads // group, 1), max(group // heads, 1)
+    once = pl.Buffered(1)  # a block that changes with the Q tile alone: nothing to fetch ahead of a pair
+    q_tile = lambda **kw: pl.BlockSpec((1, heads, tile_q, d), lambda b, t, steps: (b, 0, steps[0, t], 0), **kw)
+    k_tile = pl.BlockSpec((1, kv_heads, tile_k, d), lambda b, t, steps: (b // parts, 0, steps[1, t], 0))
+    in_specs = [q_tile(pipeline_mode=once), k_tile, k_tile]
+    operands = [q.reshape(programs, heads, seq, d), *(x.reshape(-1, kv_heads, seq, d) for x in (k, v))]
     if keep is not None:
-        in_specs.append(_keep_spec(bh // keep.shape[0], plan.tile_q, plan.tile_k, 0, 1))
+        in_specs.append(_keep_spec(programs // keep.shape[0], tile_q, tile_k, 0, 1))
         operands.append(keep)
-    with jax.named_scope(plan.scope):
-        return pl.pallas_call(
-            functools.partial(_fwd_pairs_kernel, sm_scale=sm_scale, tile_q=plan.tile_q,
-                              tile_k=plan.tile_k, has_keep=keep is not None),
+    # `group_<n>`: which form a trace's `flash_fwd` ran (the heads a program takes).
+    with jax.named_scope(plan.scope), jax.named_scope(f"group_{heads}"):
+        o, lse = pl.pallas_call(
+            functools.partial(_fwd_pairs_kernel, sm_scale=sm_scale, tile_q=tile_q, tile_k=tile_k,
+                              has_keep=keep is not None),
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=1,
-                grid=(bh, steps.shape[1]),
+                grid=(programs, steps.shape[1]),
                 in_specs=in_specs,
-                out_specs=[q_tile(d), q_tile(1)],
-                scratch_shapes=[pltpu.VMEM((plan.tile_q, 1), jnp.float32),
-                                pltpu.VMEM((plan.tile_q, 1), jnp.float32),
-                                pltpu.VMEM((plan.tile_q, d), jnp.float32)],
+                out_specs=[q_tile(), pl.BlockSpec((1, heads, tile_q), lambda b, t, steps: (b, 0, steps[0, t]))],
+                scratch_shapes=[pltpu.VMEM((heads, tile_q, d), q.dtype),
+                                pltpu.VMEM((heads, tile_q), jnp.float32),
+                                pltpu.VMEM((heads, tile_q), jnp.float32),
+                                pltpu.VMEM((heads, d, tile_q), jnp.float32),
+                                pltpu.VMEM((tile_k, tile_q), jnp.float32)],
             ),
-            out_shape=[jax.ShapeDtypeStruct((bh, seq, d), q.dtype),
-                       jax.ShapeDtypeStruct((bh, seq, 1), jnp.float32)],
+            out_shape=[jax.ShapeDtypeStruct((programs, heads, seq, d), q.dtype),
+                       jax.ShapeDtypeStruct((programs, heads, seq), jnp.float32)],
             interpret=interpret,
             name="flash_fwd",
             compiler_params=_compiler_params(interpret, "parallel", "arbitrary"),
         )(jnp.asarray(steps), *operands)
+    return o.reshape(bh, seq, d), lse.reshape(bh, seq, 1)
 
 
 # --------------------------------------------------------------------------- backward kernel

@@ -36,6 +36,10 @@ ROW_MOVERS = ("row_movers:32768", "row_movers:8192")
 # The Keye cell's attention (PR 42): one row, 32 query heads on 4 key/value heads of 16,384 x 128, a
 # packed selection; and the two kernels of `ops/lightning_indexer.py` at its indexer's 16 heads of 64.
 SELECTED_16K = "selected:1x32x4x16384x128"
+# Heads above 2 MiB with as many key/value heads: the pair-streamed forward takes four of them a program (PR 44);
+# and a llama-like 8 on 2 heads of 2,048 x 64, which streams pairs for its shared key/value heads alone.
+STREAMED_HEADS = ("kernel:1x8x16384x128", "kernel:1x8x8192x256")
+GROUPED_2K = "selected:2x8x2x2048x64"
 INDEXER_16K = "indexer:1x32x4x16384x128x16x64x2048"
 BETWEEN_HEADS = ("kernel:2x4x3072x256", "kernel:2x4x3584x256", "kernel:2x4x6144x128",
                  "kernel:2x4x7168x128", "kernel:2x4x2048x256")
@@ -63,7 +67,7 @@ def _selected_case(topo, batch, heads, kv_heads, seq, d):
     import jax
     import jax.numpy as jnp
 
-    from ray_tpu.ops.flash_attention import KEEP_SPAN, flash_attention, kernel_plan
+    from ray_tpu.ops.flash_attention import KEEP_SPAN, _fwd_pairs_plan, flash_attention, kernel_plan
 
     one = jax.sharding.SingleDeviceSharding(topo.devices[0])
     q, k = (jax.ShapeDtypeStruct((batch, h, seq, d), jnp.bfloat16, sharding=one) for h in (heads, kv_heads))
@@ -74,6 +78,8 @@ def _selected_case(topo, batch, heads, kv_heads, seq, d):
     return {"mosaic_calls": text.count("tpu_custom_call"), "mosaic_calls_without_keep": dense.as_text().count(
                 "tpu_custom_call"),
             "plan": list(kernel_plan(q.shape, kv_heads=kv_heads, keep=True)),
+            "forward": list(_fwd_pairs_plan(heads // kv_heads, heads, d, 2, kernel_plan(q.shape, kv_heads=kv_heads, keep=True))),
+            "forward_scopes": sorted(set(re.findall(r"/(group_\d+)/flash_fwd/", text))),
             "kernels": sorted(set(re.findall(r"(flash_fwd|flash_bwd)[.\d]* = ", text)))}
 
 
@@ -275,7 +281,7 @@ def _run(cases):
 @pytest.fixture(scope="module")
 def aot():
     return _run(["kernel", LONG_HEAD_64, WIDE_HEAD_256, *LONGER_HEADS, *BETWEEN_HEADS, "held_experts",
-                 *ROW_MOVERS, SELECTED_16K, INDEXER_16K, "lower:d4", "lower:d2t2"])
+                 *ROW_MOVERS, SELECTED_16K, INDEXER_16K, *STREAMED_HEADS, GROUPED_2K, "lower:d4", "lower:d2t2"])
 
 
 def test_topology_is_the_v5e(aot):
@@ -322,6 +328,33 @@ def test_both_flash_kernels_stream_pairs_under_a_selection_at_16384_by_128(aot):
     got = aot[SELECTED_16K]
     assert got["mosaic_calls"] == got["mosaic_calls_without_keep"] == 2
     assert got["plan"] == [512, 1024, 272, 32, 512, False] and got["kernels"] == ["flash_bwd", "flash_fwd"]
+    # The forward's program is a pair of a key/value head's whole group, at the plan's own Q tile (PR 44: 10.3 MiB by
+    # the smallest limit that compiles, under the default 16 with none asked for), and its scope says so.
+    assert got["forward"] == [8, 512] and got["forward_scopes"] == ["group_8"]
+
+
+@pytest.mark.parametrize("case, plan, forward", [
+    (STREAMED_HEADS[0], [512, 1024, 272, 32, 512, False], [4, 512]),
+    (STREAMED_HEADS[1], [512, 512, 136, 16, 256, False], [4, 512])])
+def test_the_streamed_forward_takes_four_equal_heads_a_program(aot, case, plan, forward):
+    """`(1, 8, 16384, 128)` and `(1, 8, 8192, 256)` bf16, as many key/value heads as heads and no selection: the
+    same forward program with a group of one, four heads and their four key/value heads a program (one head a
+    program was 12 % slower on the chip than the form it replaced, four are 17 % faster: PERF.md section 6, PR 44)."""
+    import importlib
+
+    fa = importlib.import_module("ray_tpu.ops.flash_attention")
+    assert aot[case]["mosaic_calls"] == 2 and aot[case]["plan"] == plan
+    _, heads, seq, d = (int(n) for n in case[len("kernel:"):].split("x"))
+    assert list(fa._fwd_pairs_plan(1, heads, d, 2, fa.kernel_plan((1, heads, seq, d)))) == forward
+
+
+def test_grouped_heads_of_2048_by_64_stream_pairs_with_and_without_a_selection(aot):
+    """`(2, 8 on 2, 2048, 64)` bf16, what `models/llama.py` calls with `n_kv_head < n_head`: two groups of four and
+    their two key/value heads a program at a head of 64 lanes (`o^T` is (64, tile_q) there)."""
+    got = aot[GROUPED_2K]
+    assert got["mosaic_calls"] == got["mosaic_calls_without_keep"] == 2
+    assert got["plan"] == [512, 1024, 6, 4, 8, False] and got["forward"] == [8, 512]
+    assert got["forward_scopes"] == ["group_8"]
 
 
 def test_the_selection_and_the_indexer_loss_compile_for_v5e_at_the_cells_shapes(aot):

@@ -465,6 +465,8 @@ def test_the_keye_step_hands_mosaic_the_selection_the_streamed_kernels_and_the_l
     assert "attention" in scope.split("/") and "rematted_computation" not in scope.split("/")
     if kernel.startswith("flash_"):
         assert "tiles_272of512" in scope.split("/")
+        # The forward's program is a pair of a key/value head's whole group of 8 (PR 44); the backward's a head's.
+        assert ("group_8" in scope.split("/")) == (kernel == "flash_fwd")
     else:
         assert scope.split("/").count(kernel) == 2  # the scope the `dsa.*_ms` readers pick, and the kernel's name
     with open(os.path.join(REPO, "benchmark", "configs", KEYE + ".json")) as fh:

@@ -278,17 +278,22 @@ def _dense_masked(q, k, v, mask):
     return jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, axis=-1), v), jax.scipy.special.logsumexp(s, axis=-1)
 
 
-@pytest.fixture(scope="module")
-def selected():
-    """q on fewer key/value heads, and a random selection under the diagonal in which some
-    query keeps no key of its first tile and every query keeps itself."""
+def _selection(b, h, hk, d, s=384, none_at=()):
+    """q on `hk` key/value heads, and a random selection under the diagonal in which some query keeps
+    no key of its first tile, every query keeps itself, and the queries `none_at` keep no key at all."""
     keys = jax.random.split(jax.random.PRNGKey(11), 4)
-    b, h, hk, s, d = 2, 4, 2, 384, 64
     q = jax.random.normal(keys[0], (b, h, s, d), jnp.float32)
     k, v = (jax.random.normal(kk, (b, hk, s, d), jnp.float32) for kk in keys[1:3])
     mask = jax.random.bernoulli(keys[3], 0.2, (b, s, s)) | jnp.eye(s, dtype=bool)
     mask = mask.at[:, 200:, :128].set(False) & jnp.tril(jnp.ones((s, s), bool))
+    for row in none_at:
+        mask = mask.at[:, row].set(False)
     return q, k, v, mask
+
+
+@pytest.fixture(scope="module")
+def selected():
+    return _selection(2, 4, 2, 64)
 
 
 @pytest.mark.parametrize("keys", [64, 128, 4096, 4096 + 128, 3 * 4096])
@@ -304,20 +309,41 @@ def test_a_selection_packs_to_a_bit_a_pair_and_back(keys):
     assert int(pack_keep(one)[0, 128 + 5]) == -(2 ** 31) and int((pack_keep(one) != 0).sum()) == 1
 
 
-@pytest.mark.parametrize("backend,blocks", [("xla", {}), ("pallas", {"block_q": 128, "block_k": 128}),
-                                            ("pallas", {"block_q": 384, "block_k": 384})],
-                         ids=["xla", "pallas-128", "pallas-384x128"])
-def test_keep_forward_and_backward_against_a_dense_masked_softmax(selected, backend, blocks):
-    from ray_tpu.ops.flash_attention import pack_keep
+# (batch, heads, key/value heads, head_dim, queries that keep no key at all) of `_selection`; None: the fixture's.
+# The pair-streamed forward takes a key/value head's whole group a program (PR 44): groups of 1, 2 and 8,
+# heads of 64, 128 and 256, one and three K tiles a Q tile.
+@pytest.mark.parametrize("backend,blocks,shape", [
+    ("xla", {}, None), ("pallas", {"block_q": 128, "block_k": 128}, None), ("pallas", {"block_q": 384, "block_k": 384}, None),
+    ("pallas", {"block_q": 384, "block_k": 384}, (1, 8, 1, 128, ())),
+    ("pallas", {"block_q": 128, "block_k": 128}, (1, 2, 2, 256, ())),
+    ("pallas", {"block_q": 128, "block_k": 128}, (1, 8, 1, 64, ())),
+    ("pallas", {"block_q": 384, "block_k": 384}, (1, 4, 2, 64, (5, 300))),
+    ("pallas", {"block_q": 128, "block_k": 128}, (1, 2, 2, 128, (0, 383))),
+    ("xla", {}, (1, 4, 2, 64, (5, 300)))],
+    ids=["xla", "pallas-128", "pallas-384x128", "group8-d128-384x128", "group1-d256-128", "group8-d64-128",
+         "group2-no-key-384x128", "group1-no-key-128", "xla-no-key"])
+def test_keep_forward_and_backward_against_a_dense_masked_softmax(selected, backend, blocks, shape):
+    from ray_tpu.ops.flash_attention import NEG_INF, pack_keep
 
-    q, k, v, mask = selected
+    q, k, v, mask = selected if shape is None else _selection(*shape[:4], none_at=shape[4])
     keep = pack_keep(mask)
     attn = lambda q, k, v: flash_attention(q, k, v, keep=keep, return_lse=True, backend=backend,
                                            interpret=True, **blocks)
     o, lse = attn(q, k, v)
     want_o, want_lse = _dense_masked(q, k, v, mask)
-    np.testing.assert_allclose(np.asarray(o), np.asarray(want_o), atol=2e-5)
-    np.testing.assert_allclose(np.asarray(lse), np.asarray(want_lse), atol=2e-5)
+    some = np.asarray(mask.any(axis=-1))[:, None]  # (batch, 1, queries): the rows that keep a key
+    np.testing.assert_allclose(np.where(some[..., None], o, 0), np.where(some[..., None], want_o, 0), atol=2e-5)
+    np.testing.assert_allclose(np.where(some, lse, 0), np.where(some, want_lse, 0), atol=2e-5)
+    if not some.all():
+        # A row that keeps no key at all: the kernel's o is 0 (the XLA form's is the mean of every value), and its
+        # log-sum-exp the XLA form's, of nothing but `NEG_INF`.
+        none = np.broadcast_to(~some, lse.shape)
+        xla_lse = xla_attention(q, k, v, keep=keep, return_lse=True)[1]
+        np.testing.assert_array_equal(np.asarray(lse)[none], np.asarray(xla_lse)[none])
+        assert (np.asarray(lse)[none] == np.float32(NEG_INF)).all()
+        if backend == "pallas":
+            assert (np.asarray(o)[np.broadcast_to(~some[..., None], o.shape)] == 0).all()
+        return  # the gradient of such a row is not defined: the softmax of nothing
     loss = lambda f: lambda q, k, v: (f(q, k, v)[0] ** 2).sum()
     got = jax.grad(loss(attn), argnums=(0, 1, 2))(q, k, v)
     want = jax.grad(loss(lambda q, k, v: _dense_masked(q, k, v, mask)), argnums=(0, 1, 2))(q, k, v)
@@ -326,14 +352,30 @@ def test_keep_forward_and_backward_against_a_dense_masked_softmax(selected, back
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-4)
 
 
-def test_without_a_selection_grouped_heads_stream_pairs_and_equal_heads_run_what_they_ran(selected):
+@pytest.mark.parametrize("shape,blocks", [
+    (None, {"block_q": 128, "block_k": 128}), ((1, 8, 1, 128, ()), {"block_q": 384, "block_k": 384}),
+    ((1, 2, 2, 256, ()), {"block_q": 128, "block_k": 128}), ((2, 2, 2, 64, ()), {"block_q": 384, "block_k": 128}),
+    ((1, 8, 4, 64, ()), {})],
+    ids=["group2-d64-128", "group8-d128-384", "group1-d256-128", "group1-d64-384x128", "two-groups-of-2-a-program"])
+def test_without_a_selection_grouped_heads_stream_pairs_and_equal_heads_run_what_they_ran(selected, shape, blocks):
     from ray_tpu.ops.flash_attention import _streams_pairs
 
-    q, k, v, _ = selected
+    q, k, v, _ = selected if shape is None else _selection(*shape[:4])
     causal = jnp.tril(jnp.ones((q.shape[2],) * 2, bool))[None]
-    o = flash_attention(q, k, v, backend="pallas", interpret=True, block_q=128, block_k=128)
-    np.testing.assert_allclose(np.asarray(o), np.asarray(_dense_masked(q, k, v, causal)[0]), atol=2e-5)
-    np.testing.assert_allclose(np.asarray(xla_attention(q, k, v)), np.asarray(o), atol=2e-5)
+    # `return_lse` sends equal heads to the pair-streamed kernels too, as a head above 2 MiB goes by itself.
+    attn = lambda q, k, v: flash_attention(q, k, v, backend="pallas", interpret=True, return_lse=True, **blocks)
+    o, lse = attn(q, k, v)
+    want_o, want_lse = _dense_masked(q, k, v, causal)
+    np.testing.assert_allclose(np.asarray(o), np.asarray(want_o), atol=2e-5)
+    np.testing.assert_allclose(np.asarray(lse), np.asarray(want_lse), atol=2e-5)
+    xla_o, xla_lse = xla_attention(q, k, v, return_lse=True)
+    np.testing.assert_allclose(np.asarray(xla_o), np.asarray(o), atol=2e-5)
+    np.testing.assert_allclose(np.asarray(xla_lse), np.asarray(lse), atol=2e-5)
+    loss = lambda f: lambda q, k, v: (f(q, k, v)[0] ** 2).sum()
+    got = jax.grad(loss(attn), argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(loss(lambda q, k, v: _dense_masked(q, k, v, causal)), argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-4)
     assert _streams_pairs(384, 64, 4, kv_heads_fewer=True, keep=False)
     assert not _streams_pairs(4096, 256, 2, False, False) and _streams_pairs(4096 + 512, 256, 2, False, False)
     assert not _streams_pairs(8192, 128, 2, False, False) and _streams_pairs(16384, 128, 2, False, False)
@@ -343,6 +385,30 @@ def test_without_a_selection_grouped_heads_stream_pairs_and_equal_heads_run_what
     assert kernel_plan((1, 4, 1024, 64), kv_heads=2) == (512, 1024, 2, 2, 2, False)
     assert kernel_plan((1, 8, 8192, 256), keep=True) == (512, 512, 136, 16, 256, False)  # wider than the lanes: 512-tiles
     assert kernel_plan((1, 4, 1024, 64), kv_heads=4) == kernel_plan((1, 4, 1024, 64)) == (512, 512, 3, 2, 4, True)
+
+
+def test_the_forward_takes_a_group_or_several_a_program_by_what_it_holds():
+    """`_fwd_pairs_plan`: the query heads a program of the pair-streamed forward takes and its Q tile, from the
+    shapes alone; the backward's plan is not touched (`kernel_plan` above)."""
+    from ray_tpu.ops.flash_attention import FWD_PAIRS_VMEM_BYTES, _fwd_pairs_bytes, _fwd_pairs_plan
+
+    def taken(heads, kv_heads, seq, d, keep, itemsize=2):
+        plan = kernel_plan((1, heads, seq, d), kv_heads=kv_heads, keep=keep)
+        return _fwd_pairs_plan(heads // kv_heads, heads, d, itemsize, plan)
+
+    assert taken(32, 4, 16384, 128, True) == (8, 512)  # the Keye cell: a key/value head's whole group, 11.8 MiB
+    assert _fwd_pairs_bytes(8, 1, 512, 1024, 128, 2) == 11.8125 * 2 ** 20 <= FWD_PAIRS_VMEM_BYTES
+    assert taken(32, 32, 16384, 128, False) == (4, 512)  # equal heads: four of them, each with its own k and v
+    assert taken(8, 8, 8192, 256, False) == (4, 512)
+    assert taken(32, 1, 2048, 128, False) == (32, 128)  # one key/value head under 32: all of them, a quarter of the Q tile
+    assert taken(32, 8, 2048, 128, False) == (8, 512)  # llama's 32 on 8: two groups of four with their two key/value heads
+    assert taken(12, 4, 1536, 64, True) == (12, 512)  # 512-tiles cut to the row; three groups of three
+    assert taken(2, 1, 384, 64, True, itemsize=4) == (2, 384)
+    for heads, kv_heads, seq, d in ((32, 4, 16384, 128), (32, 32, 16384, 128), (8, 8, 8192, 256), (32, 1, 2048, 128)):
+        took, tile_q = taken(heads, kv_heads, seq, d, True)
+        plan = kernel_plan((1, heads, seq, d), kv_heads=kv_heads, keep=True)
+        assert heads % took == 0 and plan.tile_q % tile_q == 0 and tile_q % 128 == 0
+        assert _fwd_pairs_bytes(took, max(took * kv_heads // heads, 1), tile_q, plan.tile_k, d, 2) <= FWD_PAIRS_VMEM_BYTES
 
 
 def test_the_forward_schedule_visits_each_pair_once_a_q_tile_at_a_time():
