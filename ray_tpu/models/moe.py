@@ -64,7 +64,7 @@ pairs than the bound takes the whole-length form, the same function at
 `tokens * k` rows, in which no grouped matmul visits the rows behind the held
 groups and `sum_rows` is never pointed at them: `lax.cond` on `held_pairs`
 picks, so no pair is ever dropped, and `aux["compact"]` says which ran (neither
-branch gathers a scalar: `tests/test_model_scopes.py` counts both). What
+branch gathers a scalar: `tests/test_aot_expert_steps.py` counts both). What
 the other experts would have added to a token is left out either way: the
 layer returns the partial sum that this share computes. The exchange that
 would send those pairs to their chips and bring the other chips' partial sums

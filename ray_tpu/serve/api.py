@@ -122,9 +122,25 @@ def ingress(asgi_app):
 _client: Dict[str, Any] = {}
 
 
+def _drop_a_gone_runtimes_handles() -> None:
+    """`_client`'s handles and the routers' long polls are those of the
+    runtime that made them. After a `ray_tpu.shutdown()` without
+    `serve.shutdown()` they name dead actors, and the next runtime's every
+    `serve.run` in this process would ask them ("Actor is dead: actor not
+    found"): forget them, asking nothing of the actors."""
+    from ray_tpu.serve.handle import close_all_routers
+
+    runtime = ray_tpu._private.worker.global_worker._session_gen
+    if _client.setdefault("runtime", runtime) != runtime:
+        close_all_routers()
+        _client.clear()
+        _client["runtime"] = runtime
+
+
 def _get_controller(create: bool = True):
     from ray_tpu.serve._private.controller import ServeController
 
+    _drop_a_gone_runtimes_handles()
     if "controller" in _client:
         return _client["controller"]
     try:
@@ -159,6 +175,7 @@ def _get_controller(create: bool = True):
 def _get_proxy(create: bool = True, port: int = DEFAULT_HTTP_PORT):
     from ray_tpu.serve._private.http_proxy import HTTPProxy
 
+    _drop_a_gone_runtimes_handles()
     if "proxy" in _client:
         return _client["proxy"]
     controller = _get_controller()
@@ -246,6 +263,7 @@ def proxy_ports() -> Dict[str, int]:
 
 
 def http_port() -> Optional[int]:
+    _drop_a_gone_runtimes_handles()
     if "http_port" in _client:
         return _client["http_port"]
     proxy = _get_proxy(create=False)
@@ -410,6 +428,7 @@ def delete(name: str) -> None:
 def shutdown() -> None:
     from ray_tpu.serve.handle import close_all_routers
 
+    _drop_a_gone_runtimes_handles()
     close_all_routers()
     if "controller" in _client:
         try:

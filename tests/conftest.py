@@ -43,8 +43,69 @@ import ray_tpu  # noqa: E402
 
 
 import contextlib  # noqa: E402
-import json  # noqa: E402
+import faulthandler  # noqa: E402
+import signal  # noqa: E402
 import subprocess  # noqa: E402
+
+# What a test's set-up, its call and its teardown may each take. The driver's command gives the whole
+# run one clock; without a limit of its own a test that hangs takes the run, and every test behind it,
+# with it. (`tests/aot_v5e.py CASE_LIMIT_S`, what a test may wait for an ahead-of-time compile, stands
+# under this so that a slow compile is named first; `tests/benchmark/`'s one test of 185-220 s under the
+# suite's own load, PR 46, carries a `timeout=300` of its own.)
+TEST_LIMIT_S = 300.0
+
+
+def pytest_configure(config):
+    config.addinivalue_line("markers", "slow: left out of tier-1 (`-m 'not slow'`): minutes of compiles")
+
+
+def _limited(item, phase):
+    """Fail `item` alone, by its name and with every thread's stack, when
+    `phase` of it passes `TEST_LIMIT_S`. SIGALRM reaches Python between two
+    bytecodes of the main thread: it bounds waits, sleeps, joins and
+    subprocesses, which is what hangs here, not a native call that never
+    returns (an XLA compile runs to its end first). Worker threads and
+    platforms without `setitimer` run unlimited."""
+    def passed(signum, frame):
+        faulthandler.dump_traceback(file=sys.stderr, all_threads=True)
+        pytest.fail(f"{item.nodeid}: {phase} passed its limit of {TEST_LIMIT_S:g} s (stacks above)", pytrace=False)
+
+    before = signal.signal(signal.SIGALRM, passed)
+    signal.setitimer(signal.ITIMER_REAL, TEST_LIMIT_S)
+    try:
+        return (yield)
+    finally:
+        signal.setitimer(signal.ITIMER_REAL, 0)
+        signal.signal(signal.SIGALRM, before)
+
+
+@pytest.hookimpl(wrapper=True)
+def pytest_runtest_setup(item):
+    return (yield from _limited(item, "set-up"))
+
+
+@pytest.hookimpl(wrapper=True)
+def pytest_runtest_call(item):
+    return (yield from _limited(item, "call"))
+
+
+@pytest.hookimpl(wrapper=True)
+def pytest_runtest_teardown(item):
+    return (yield from _limited(item, "teardown"))
+
+
+def kill_and_sweep(proc, timeout=15):
+    """SIGKILL `proc` (a head, a driver) and remove what it then cannot: its
+    `/dev/shm/ray_tpu_{head,session}_<pid>_*` directory. A test that kills a
+    process on purpose owns what the process leaves (92 such directories stood
+    on one machine at PR 40's anchor)."""
+    import glob
+    import shutil
+
+    proc.kill()
+    proc.wait(timeout=timeout)
+    for left in glob.glob(f"/dev/shm/ray_tpu_*_{proc.pid}_*"):
+        shutil.rmtree(left, ignore_errors=True)
 
 
 @contextlib.contextmanager
@@ -69,7 +130,7 @@ def head_process_runtime(num_cpus=4):
         try:
             proc.wait(timeout=10)
         except subprocess.TimeoutExpired:
-            proc.kill()
+            kill_and_sweep(proc)
 
 
 @pytest.fixture

@@ -1,0 +1,45 @@
+"""The Keye cell's whole train step, compiled ahead of time for a `v5e:2x2`
+(`tests/aot_v5e.py`, a process of its own; until PR 46 in `tests/test_model_scopes.py`)."""
+
+import json
+import os
+
+import pytest
+
+import aot_v5e
+from aot_v5e import REPO
+from benchmark.harness.program_trace import PHASES, phase
+
+# The Keye cell's step (PR 42): what its five layers, one scan, hand to Mosaic. The two flash kernels
+# a (Q tile, K tile) pair a program over 272 of 512 pairs of 512 x 1,024, the selection kernel and the
+# indexer loss's (forward only: its gradients are made there), and the held-prefix expert layer's.
+KEYE = "keye-vl-2.0-30b-a3b-ep8"
+KEYE_KERNELS = {"flash_fwd": 1, "flash_bwd": 1, "select": 1, "index_loss": 1}
+
+
+@pytest.fixture(scope="module")
+def aot():
+    return aot_v5e.steps(KEYE)
+
+
+@pytest.mark.parametrize("kernel", sorted(KEYE_KERNELS))
+def test_the_keye_step_hands_mosaic_the_selection_the_streamed_kernels_and_the_loss(aot, kernel):
+    """Every kernel of `ops/lightning_indexer.py` and both flash kernels once in the scanned layer,
+    under the scope their reader looks for, in the phase they belong to; the step's temporaries beside
+    6.75 GB of arguments fit the chip (the recorded `memory_analysis_v5e_bytes` are this compile's)."""
+    got = aot(KEYE)
+    scopes = [n for n in got["mosaic_scopes"] if n.split("/")[-2] == kernel]
+    assert len(scopes) == KEYE_KERNELS[kernel], got["mosaic_scopes"]
+    (scope,) = scopes
+    assert phase(scope) == ("backward" if kernel == "flash_bwd" else "forward")
+    assert "attention" in scope.split("/") and "rematted_computation" not in scope.split("/")
+    if kernel.startswith("flash_"):
+        assert "tiles_272of512" in scope.split("/")
+        # The forward's program is a pair of a key/value head's whole group of 8 (PR 44); the backward's a head's.
+        assert ("group_8" in scope.split("/")) == (kernel == "flash_fwd")
+    else:
+        assert scope.split("/").count(kernel) == 2  # the scope the `dsa.*_ms` readers pick, and the kernel's name
+    with open(os.path.join(REPO, "benchmark", "configs", KEYE + ".json")) as fh:
+        recorded = json.load(fh)["memory_analysis_v5e_bytes"]
+    assert got["argument"] == recorded["arguments"] and got["temp"] <= recorded["temporaries"]
+    assert got["phases"] == sorted(PHASES)

@@ -22,6 +22,7 @@ import pytest
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
+import shared_checks  # noqa: E402
 from benchmark.harness.manifest import Manifest  # noqa: E402
 from benchmark.models import glm4_moe_lite as bench  # noqa: E402
 
@@ -61,18 +62,30 @@ def trained_f32(nano, tokens):
     return _trained(dict(nano, dtype="float32"), tokens)
 
 
-def _both_heads(system, tokens):
+# A bf16 system built once; `check` keeps the system's side of the comparison for it and for
+# `trained_f32`, which the four negative cases read (`tests/shared_checks.py`).
+@pytest.fixture(scope="module")
+def bf16(nano):
+    return bench.build(nano, None, 0)
+
+
+@pytest.fixture(scope="module")
+def check():
+    return shared_checks.Checked(bench)
+
+
+def _both_heads(system, tokens, params):
     """(main logits, module logits) of the program, as `loss_fn` computes them."""
     from ray_tpu.models import glm4_moe_lite as program
 
-    params, cfg = system.state.params, system.cfg
+    cfg = system.cfg
     inputs, targets = tokens[:, :-1], tokens[:, 1:]
     x = program.hidden(params, inputs, cfg)
     return program.forward(params, inputs, cfg), program.mtp_logits(params, x, targets, cfg)
 
 
-def test_the_bf16_system_is_within_the_written_tolerance_of_the_reference(nano, tokens):
-    got = bench.check(bench.build(nano, None, 0), tokens)
+def test_the_bf16_system_is_within_the_written_tolerance_of_the_reference(bf16, check, tokens):
+    got = check(bf16, tokens)
     assert got["ok"], got
     assert got["routing"]["dropped"] == 0 and len(got["routing"]["held_pairs_per_layer"]) == 3
     assert got["loss_abs_err"] < bench.LOSS_ABS_TOL and got["grad_norm_rel_err"] < bench.GRAD_NORM_REL_TOL
@@ -87,9 +100,9 @@ def test_in_float32_they_agree_to_rounding_by_leaf_logit_and_choice(trained_f32,
 
     system, c = trained_f32, trained_f32.c
     params = system.state.params
-    loss, grads = jax.value_and_grad(lambda p: program.loss_fn(p, {"tokens": tokens}, system.cfg))(params)
-    (ref_loss, chosen), ref_grads = jax.value_and_grad(
-        lambda p: bench.reference_loss(p, tokens, c), has_aux=True)(params)
+    loss, grads = jax.jit(jax.value_and_grad(lambda p: program.loss_fn(p, {"tokens": tokens}, system.cfg)))(params)
+    (ref_loss, chosen), ref_grads = jax.jit(jax.value_and_grad(
+        lambda p: bench.reference_loss(p, tokens, c), has_aux=True))(params)
     assert float(loss) == pytest.approx(float(ref_loss), abs=2e-5)
     for (path, g), r in zip(jax.tree_util.tree_leaves_with_path(grads), jax.tree.leaves(ref_grads)):
         scale = float(np.abs(r).max()) + 1e-12
@@ -98,8 +111,8 @@ def test_in_float32_they_agree_to_rounding_by_leaf_logit_and_choice(trained_f32,
     for tree in (grads, ref_grads):
         bias = tree["blocks"]["period"][0]["moe"]["expert_bias"]
         assert float(np.abs(bias).max()) == 0.0 and float(np.abs(tree["mtp"]["block"]["moe"]["expert_bias"]).max()) == 0.0
-    main, module = _both_heads(system, tokens)
-    _, _, ref_main, ref_module, _ = bench.reference_logits(params, tokens, c)
+    main, module = jax.jit(lambda p: _both_heads(system, tokens, p))(params)
+    _, _, ref_main, ref_module, _ = jax.jit(lambda p: bench.reference_logits(p, tokens, c))(params)
     assert float(np.abs(main - ref_main).max()) < 2e-4 * float(np.abs(ref_main).max())
     assert float(np.abs(module - ref_module).max()) < 2e-4 * float(np.abs(ref_module).max())
     stats = program.routing_stats(params, tokens, system.cfg)
@@ -107,8 +120,8 @@ def test_in_float32_they_agree_to_rounding_by_leaf_logit_and_choice(trained_f32,
     assert same.all() and stats["experts"].shape == (3, 128, 2) and int(stats["dropped"].sum()) == 0
 
 
-def test_they_agree_at_trained_weights_too(trained_f32, tokens):
-    got = bench.check(trained_f32, tokens, loss_tol=2e-5, grad_tol=2e-4, flipped_tol=0.0)
+def test_they_agree_at_trained_weights_too(trained_f32, check, tokens):
+    got = check(trained_f32, tokens, loss_tol=2e-5, grad_tol=2e-4, flipped_tol=0.0)
     assert got["ok"], got
 
 
@@ -125,7 +138,7 @@ def test_latent_attention_alone_is_the_references(nano):
 
     c = dict(nano, dtype="float32")
     cfg = bench.model_config(c)
-    layer = program.init_params(cfg, jax.random.PRNGKey(3))["blocks"]["leading"][0]
+    layer = jax.jit(lambda key: program.init_params(cfg, key))(jax.random.PRNGKey(3))["blocks"]["leading"][0]
     keys = jax.random.split(jax.random.PRNGKey(4), 4)
     layer = {**layer, **{name: 1.0 + 0.3 * jax.random.normal(k, layer[name].shape)
                          for name, k in zip(("attn_norm", "q_a_norm", "kv_a_norm"), keys)}}
@@ -178,8 +191,8 @@ def test_without_the_module_it_is_the_model_with_no_module_to_the_bit(nano, toke
 
     with_module = bench.model_config(dict(nano, dtype="float32"))
     without = bench.model_config(dict(nano, dtype="float32", num_nextn_predict_layers=0))
-    params = program.init_params(with_module, jax.random.PRNGKey(0))
-    bare = program.init_params(without, jax.random.PRNGKey(0))
+    params = jax.jit(lambda key: program.init_params(with_module, key))(jax.random.PRNGKey(0))
+    bare = jax.jit(lambda key: program.init_params(without, key))(jax.random.PRNGKey(0))
     assert "mtp" not in bare and set(params) - set(bare) == {"mtp"}
     assert all(bool((a == b).all()) for a, b in zip(
         jax.tree.leaves(bare), jax.tree.leaves({k: v for k, v in params.items() if k != "mtp"})))
@@ -271,9 +284,9 @@ def _module_unweighted(real):
     ("weights_not_scaled_by_1.8", _unscaled, "reference_logits"),
     ("the_second_loss_at_weight_1", _module_unweighted, "reference_loss"),
 ])
-def test_a_reference_of_another_function_fails_the_comparison(trained_f32, tokens, monkeypatch, name, wrong, of):
+def test_a_reference_of_another_function_fails_the_comparison(trained_f32, check, tokens, monkeypatch, name, wrong, of):
     monkeypatch.setattr(bench, of, wrong(getattr(bench, of)))
-    got = bench.check(trained_f32, tokens)
+    got = check(trained_f32, tokens)
     assert not got["ok"], (name, got)
     assert got["loss_abs_err"] > bench.LOSS_ABS_TOL or got["grad_norm_rel_err"] > bench.GRAD_NORM_REL_TOL
 
@@ -290,20 +303,18 @@ def test_the_reference_in_bf16_is_outside_a_tolerance(nano, tokens):
     import jax.numpy as jnp
 
     params = bench.build(nano, None, 2).state.params
-    exact, chosen = bench.reference_loss(params, tokens, nano)
-    rounded, low_chosen = bench.reference_loss(params, tokens, nano, dtype=jnp.bfloat16)
+    exact, chosen = jax.jit(lambda p: bench.reference_loss(p, tokens, nano))(params)
+    rounded, low_chosen = jax.jit(lambda p: bench.reference_loss(p, tokens, nano, dtype=jnp.bfloat16))(params)
     low_experts = jax.lax.top_k(low_chosen.astype(jnp.float32), nano["num_experts_per_tok"])[1]
     flipped = 1.0 - float(jnp.take_along_axis(chosen, low_experts, axis=-1).mean())
     assert flipped > bench.FLIPPED_SHARE_TOL
     assert 0 < abs(float(exact) - float(rounded)) < bench.LOSS_ABS_TOL
 
 
-def test_parameters_kept_in_bf16_fail_the_check(nano, tokens):
-    import jax
+def test_parameters_kept_in_bf16_fail_the_check(bf16, tokens):
+    import jax.numpy as jnp
 
-    system = bench.build(nano, None, 0)
-    system.state.params = jax.tree.map(lambda p: p.astype(jax.numpy.bfloat16), system.state.params)
-    got = bench.check(system, tokens)
+    got = bench.check(shared_checks.in_dtype(bf16, jnp.bfloat16), tokens)
     assert not got["ok"] and got["state_dtypes_other_than_stated"] == ["bfloat16"]
 
 
@@ -317,14 +328,15 @@ def test_a_dropped_shared_expert_fails_the_check(nano, tokens, monkeypatch):
     real = bench.reference_logits
     system.state.params = jax.tree_util.tree_map_with_path(
         lambda path, p: p * 30.0 if "shared_" in jax.tree_util.keystr(path) else p, system.state.params)
-    assert bench.check(system, tokens)["ok"]
+    check = shared_checks.Checked(bench)  # the system's side once for the two comparisons: its weights stay
+    assert check(system, tokens)["ok"]
 
     def no_shared(params, tokens, c, dtype=None):
         return real(jax.tree_util.tree_map_with_path(
             lambda path, p: p * 0 if "shared_down" in jax.tree_util.keystr(path) else p, params), tokens, c, dtype)
 
     monkeypatch.setattr(bench, "reference_logits", no_shared)
-    assert not bench.check(system, tokens)["ok"]
+    assert not check(system, tokens)["ok"]
 
 
 # ------------------------------------------------------------------ the share

@@ -10,6 +10,7 @@ import time
 
 import pytest
 
+from conftest import kill_and_sweep
 from ray_tpu._private.launch import spawn_head
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -149,8 +150,7 @@ time.sleep(600)  # hold the actor's ownership until the parent kills us
         # actor + running job.
         _wait_for_journal(persist, "counter", job_id=job_id)
     finally:
-        proc.kill()  # hard kill mid-job (chaos, not graceful shutdown)
-        proc.wait(timeout=10)
+        kill_and_sweep(proc)  # hard kill mid-job (chaos, not graceful shutdown)
         if client_proc is not None:
             client_proc.kill()
             client_proc.wait(timeout=10)
@@ -220,8 +220,7 @@ time.sleep(600)
         # Wait for a persist tick to journal the record.
         _wait_for_journal(persist, "mortal")
     finally:
-        proc.kill()
-        proc.wait(timeout=10)
+        kill_and_sweep(proc)
         if client_proc is not None:
             client_proc.kill()
             client_proc.wait(timeout=10)
@@ -310,8 +309,7 @@ def test_daemon_rejoins_restarted_head(tmp_path):
         assert "PID" in out
 
         # Chaos: SIGKILL the head; the daemon must survive and retry.
-        head.kill()
-        head.wait(timeout=15)
+        kill_and_sweep(head)
         time.sleep(1.0)
         assert daemon.poll() is None, "daemon died with the head"
 
@@ -349,6 +347,6 @@ def test_daemon_rejoins_restarted_head(tmp_path):
         for proc in (daemon, head):
             if proc is not None:
                 try:
-                    proc.kill()
+                    kill_and_sweep(proc)
                 except Exception:
                     pass

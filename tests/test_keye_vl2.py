@@ -23,6 +23,7 @@ import pytest
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
+import shared_checks  # noqa: E402
 from benchmark.harness.manifest import Manifest  # noqa: E402
 from benchmark.models import keye_vl2 as bench  # noqa: E402
 
@@ -50,7 +51,20 @@ def f32(nano):
 
     c = {**nano, "dtype": "float32"}
     cfg = bench.model_config(c)
-    return c, cfg, model.init_params(cfg, jax.random.PRNGKey(7))
+    return c, cfg, jax.jit(lambda key: model.init_params(cfg, key))(jax.random.PRNGKey(7))
+
+
+# The bf16 system built once; `check` keeps the system's side of the comparison for it: the program reads
+# `system.cfg` and the reference `system.c`, so the three negative cases, copies with another `c`, read the
+# same outputs (`tests/shared_checks.py`).
+@pytest.fixture(scope="module")
+def bf16(nano):
+    return bench.build(nano, None, 3)
+
+
+@pytest.fixture(scope="module")
+def check():
+    return shared_checks.Checked(bench)
 
 
 def _system_loss(params, tokens, cfg, positions=None):
@@ -62,8 +76,8 @@ def _system_loss(params, tokens, cfg, positions=None):
 
 
 # ------------------------------------------------------------------ system against reference
-def test_the_bf16_system_is_within_the_written_tolerance_of_the_reference(nano, tokens):
-    out = bench.check(bench.build(nano, None, 3), tokens)
+def test_the_bf16_system_is_within_the_written_tolerance_of_the_reference(bf16, check, nano, tokens):
+    out = check(bf16, tokens)
     assert out["ok"], out
     assert out["routing"]["dropped"] == 0 and out["state_dtypes_other_than_stated"] == []
     limits = nano["check_tolerances"]  # the toy's own: the model file's are the published widths'
@@ -78,9 +92,9 @@ def test_in_float32_they_agree_to_rounding_by_loss_and_leaf(f32, tokens):
     from ray_tpu.models import keye_vl2 as model
 
     c, cfg, params = f32
-    mine, mine_g = jax.value_and_grad(lambda p: model.loss_fn(p, {"tokens": tokens}, cfg))(params)
-    (theirs, aux), theirs_g = jax.value_and_grad(
-        lambda p: bench.reference_loss(p, tokens, c), has_aux=True)(params)
+    mine, mine_g = jax.jit(jax.value_and_grad(lambda p: model.loss_fn(p, {"tokens": tokens}, cfg)))(params)
+    (theirs, aux), theirs_g = jax.jit(jax.value_and_grad(
+        lambda p: bench.reference_loss(p, tokens, c), has_aux=True))(params)
     assert abs(float(mine) - float(theirs)) < 2e-5
     flat = lambda g: {jax.tree_util.keystr(k): v for k, v in jax.tree_util.tree_leaves_with_path(g)}
     for name, g in flat(theirs_g).items():
@@ -181,21 +195,19 @@ def test_the_selection_is_a_choice_of_topk_keys_of_the_past(f32, tokens):
     ("topk", lambda c: {**c, "sa_config": {**c["sa_config"], "topk": 23}}),
     ("aux_loss_weight", lambda c: {**c, "aux_loss_weight": 0.1}),
 ])
-def test_a_reference_of_another_function_fails_the_comparison(nano, tokens, name, wrong):
-    system = bench.build(nano, None, 3)
-    system.c = wrong(nano)  # the reference reads `system.c`, the program `system.cfg`
-    out = bench.check(system, tokens)
+def test_a_reference_of_another_function_fails_the_comparison(bf16, check, nano, tokens, monkeypatch, name, wrong):
+    monkeypatch.setattr(bf16, "c", wrong(nano))  # the reference reads `system.c`, the program `system.cfg`
+    out = check(bf16, tokens)
     assert not out["ok"], (name, out)
 
 
-def test_parameters_kept_in_bf16_fail_the_check(nano, tokens):
+def test_parameters_kept_in_bf16_fail_the_check(bf16, check, nano, tokens, monkeypatch):
     out = bench.check(bench.build({**nano, "param_dtype": "bfloat16"}, None, 3),
                       tokens, loss_tol=1.0, grad_tol=1.0, index_loss_tol=1.0, flipped_tol=1.0, selection_tol=1.0,
                       selected_tol=1.0)
     assert out["state_dtypes_other_than_stated"] == [] and out["ok"]  # stated bf16, kept bf16: consistent
-    system = bench.build(nano, None, 3)
-    system.c = {**nano, "param_dtype": "bfloat16"}
-    out = bench.check(system, tokens)
+    monkeypatch.setattr(bf16, "c", {**nano, "param_dtype": "bfloat16"})
+    out = check(bf16, tokens)
     assert out["state_dtypes_other_than_stated"] == ["float32"] and not out["ok"]
 
 

@@ -22,6 +22,7 @@ import pytest
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
+import shared_checks  # noqa: E402
 from benchmark.harness.manifest import Manifest  # noqa: E402
 from benchmark.models import lfm2 as bench_lfm2  # noqa: E402
 
@@ -60,9 +61,26 @@ def trained_f32(nano, tokens):
     return _trained(dict(nano, dtype="float32"), tokens)
 
 
+# A system a precision, built once; `check` keeps the system's side of the comparison for each of them
+# and for `trained_f32`, which the five negative cases read (`tests/shared_checks.py`).
+@pytest.fixture(scope="module")
+def bf16(nano):
+    return bench_lfm2.build(nano, None, 7)
+
+
+@pytest.fixture(scope="module")
+def f32(nano):
+    return bench_lfm2.build(dict(nano, dtype="float32"), None, 7)
+
+
+@pytest.fixture(scope="module")
+def check():
+    return shared_checks.Checked(bench_lfm2)
+
+
 # ------------------------------------------------------------ they agree
-def test_the_bf16_system_is_within_the_written_tolerance_of_the_reference(nano, tokens):
-    got = bench_lfm2.check(bench_lfm2.build(nano, None, 7), tokens)
+def test_the_bf16_system_is_within_the_written_tolerance_of_the_reference(bf16, check, tokens):
+    got = check(bf16, tokens)
     assert got["ok"], got
     assert abs(got["loss_reference"] - np.log(256)) < 0.1  # no auxiliary term
     routing = got["routing"]
@@ -71,16 +89,15 @@ def test_the_bf16_system_is_within_the_written_tolerance_of_the_reference(nano, 
     assert 0 < routing["held_pairs_share"] < 0.6  # 2 of 8 experts: 0.25 were the router even
 
 
-def test_in_float32_they_agree_to_rounding_by_leaf_and_pick_the_same_experts(nano, tokens):
+def test_in_float32_they_agree_to_rounding_by_leaf_and_pick_the_same_experts(f32, check, tokens):
     """The reference computes the system's function, not one near it: loss,
     every leaf of the gradient, and every choice."""
     import jax
 
     from ray_tpu.models import lfm2
 
-    c = dict(nano, dtype="float32")
-    system = bench_lfm2.build(c, None, 7)
-    got = bench_lfm2.check(system, tokens)
+    system, c = f32, f32.c
+    got = check(system, tokens)
     assert got["loss_abs_err"] < 2e-6 and got["grad_norm_rel_err"] < 2e-5, got
     assert got["expert_choices_flipped_share"] == 0.0
     params = system.state.params
@@ -92,8 +109,8 @@ def test_in_float32_they_agree_to_rounding_by_leaf_and_pick_the_same_experts(nan
     assert not np.asarray(bias).any()  # it enters the choice only
 
 
-def test_they_agree_at_trained_weights_too(trained_f32, tokens):
-    exact = bench_lfm2.check(trained_f32, tokens)
+def test_they_agree_at_trained_weights_too(trained_f32, check, tokens):
+    exact = check(trained_f32, tokens)
     assert exact["loss_abs_err"] < 2e-6 and exact["expert_choices_flipped_share"] == 0.0, exact
 
 
@@ -162,23 +179,23 @@ def _one_position_late(real):
     ("short_conv_mix", _shifted_by_one), ("short_conv_mix", _one_position_late)],
     ids=["renormalised_over_all_scores", "selection_bias_in_the_weights", "qk_norm_over_the_projection",
          "convolution_taps_reversed", "convolution_one_position_late"])
-def test_a_reference_of_another_function_fails_the_comparison(trained_f32, tokens, monkeypatch,
+def test_a_reference_of_another_function_fails_the_comparison(trained_f32, check, tokens, monkeypatch,
                                                               name, wrong):
     monkeypatch.setattr(bench_lfm2, name, wrong(getattr(bench_lfm2, name)))
-    got = bench_lfm2.check(trained_f32, tokens)
+    got = check(trained_f32, tokens)
     assert not got["ok"], got
     assert (got["grad_norm_rel_err"] > 2 * bench_lfm2.GRAD_NORM_REL_TOL
             or got["loss_abs_err"] > 2 * bench_lfm2.LOSS_ABS_TOL), got
 
 
-def test_the_reference_in_bf16_is_outside_a_tolerance(nano, tokens):
+def test_the_reference_in_bf16_is_outside_a_tolerance(bf16, nano, tokens):
     """What the nearest precision below the configuration's would give: the
     reference with parameters, router, norms and logits in bf16 (PERF.md
     section 6, PR 35, has the chip's reading at the published widths)."""
     import jax
     import jax.numpy as jnp
 
-    params = bench_lfm2.build(nano, None, 7).state.params
+    params = bf16.state.params
     exact, chosen = jax.jit(lambda p: bench_lfm2.reference_loss(p, tokens, nano))(params)
     low, low_chosen = jax.jit(
         lambda p: bench_lfm2.reference_loss(p, tokens, nano, dtype=jnp.bfloat16))(params)
@@ -186,13 +203,10 @@ def test_the_reference_in_bf16_is_outside_a_tolerance(nano, tokens):
     assert 0 < float((chosen != low_chosen).mean())  # and its bf16 router picks other experts
 
 
-def test_parameters_kept_in_bf16_fail_the_check(nano, tokens):
-    import jax
+def test_parameters_kept_in_bf16_fail_the_check(bf16, tokens):
     import jax.numpy as jnp
 
-    system = bench_lfm2.build(nano, None, 7)
-    system.state.params = jax.tree.map(lambda p: p.astype(jnp.bfloat16), system.state.params)
-    got = bench_lfm2.check(system, tokens)
+    got = bench_lfm2.check(shared_checks.in_dtype(bf16, jnp.bfloat16), tokens)
     assert not got["ok"] and got["state_dtypes_other_than_stated"] == ["bfloat16"]
 
 

@@ -23,6 +23,25 @@ def nano():
     return GPTConfig.nano(dtype=jnp.float32)
 
 
+@pytest.fixture(scope="module")
+def on_one_device(nano):
+    """The losses of nano's step over some batches on a one-device mesh, from `PRNGKey(1)`'s state at learning
+    rate 1e-3: the yardstick of the two parity tests, its step compiled once for both."""
+    opt = default_optimizer(learning_rate=1e-3)
+    mesh1 = MeshSpec(data=1).build(jax.devices()[:1])
+    first = create_train_state(nano, jax.random.PRNGKey(1), opt, mesh=mesh1)
+    step1 = make_train_step(nano, opt, mesh=mesh1)
+
+    def losses(batches):
+        state, out = jax.tree.map(jnp.copy, first), []  # the step donates its state
+        for b in batches:
+            state, m = step1(state, shard_batch(b, mesh1))
+            out.append(float(m["loss"]))
+        return out
+
+    return losses
+
+
 def _batch(rng, batch=8, seq=64, vocab=256):
     start = rng.integers(0, vocab - 56, size=(batch, 1))
     toks = (start + np.arange(seq + 1)) % vocab
@@ -90,7 +109,7 @@ def test_fsdp_mesh_shards_the_optimizer_moments_like_the_params(nano):
     assert "fsdp" in str(mu["blocks"]["qkv_w"].sharding.spec)
 
 
-def test_dp_equals_single_device_loss(nano):
+def test_dp_equals_single_device_loss(nano, on_one_device):
     """DP loss-curve parity: same data, same init -> same loss whether the mesh
     is 1 device or 8 (the reference's torch-parity property, SURVEY.md §6)."""
     opt = default_optimizer(learning_rate=1e-3)
@@ -105,13 +124,7 @@ def test_dp_equals_single_device_loss(nano):
         s8, m = step8(s8, shard_batch(b, mesh8))
         losses8.append(float(m["loss"]))
 
-    mesh1 = MeshSpec(data=1).build(jax.devices()[:1])
-    s1 = create_train_state(nano, jax.random.PRNGKey(1), opt, mesh=mesh1)
-    step1 = make_train_step(nano, opt, mesh=mesh1)
-    losses1 = []
-    for b in batches:
-        s1, m = step1(s1, shard_batch(b, mesh1))
-        losses1.append(float(m["loss"]))
+    losses1 = on_one_device(batches)
 
     np.testing.assert_allclose(losses8, losses1, rtol=1e-4)
 
@@ -174,7 +187,7 @@ def test_context_parallel_training(nano):
     assert float(metrics["loss"]) < first
 
 
-def test_pipeline_parallel_equals_single_device_loss(nano):
+def test_pipeline_parallel_equals_single_device_loss(nano, on_one_device):
     """PP loss parity: a data=2 x pipeline=2 x tensor=2 mesh (GPipe microbatch
     schedule, parallel/pipeline.py) trains identically to a 1-device mesh."""
     opt = default_optimizer(learning_rate=1e-3)
@@ -191,13 +204,7 @@ def test_pipeline_parallel_equals_single_device_loss(nano):
         sp, m = stepp(sp, shard_batch(b, meshp))
         lossesp.append(float(m["loss"]))
 
-    mesh1 = MeshSpec(data=1).build(jax.devices()[:1])
-    s1 = create_train_state(nano, jax.random.PRNGKey(1), opt, mesh=mesh1)
-    step1 = make_train_step(nano, opt, mesh=mesh1)
-    losses1 = []
-    for b in batches:
-        s1, m = step1(s1, shard_batch(b, mesh1))
-        losses1.append(float(m["loss"]))
+    losses1 = on_one_device(batches)
 
     np.testing.assert_allclose(lossesp, losses1, rtol=1e-4)
 

@@ -135,7 +135,13 @@ def test_put_loop_stays_under_capacity(small_store):
         ref = ray_tpu.put(np.zeros(1_000_000))  # 8MB each
         del ref
         gc.collect()
-    usage = sum(os.path.getsize(os.path.join(shm, f)) for f in os.listdir(shm))
+    def size(name):
+        try:
+            return os.path.getsize(os.path.join(shm, name))
+        except FileNotFoundError:  # reclaimed between the listing and here: the runtime unlinks on its own thread
+            return 0
+
+    usage = sum(size(f) for f in os.listdir(shm))
     assert usage <= 40 * 1024 * 1024
 
 

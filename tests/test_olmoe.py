@@ -18,6 +18,7 @@ import pytest
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
+import shared_checks  # noqa: E402
 from benchmark.harness.manifest import Manifest  # noqa: E402
 from benchmark.models import olmoe as bench_olmoe  # noqa: E402
 
@@ -54,9 +55,21 @@ def trained_f32(nano, tokens):
     return _trained(dict(nano, dtype="float32"), tokens)
 
 
+# A bf16 system built once; `check` keeps the system's side of the comparison for it and for
+# `trained_f32`, which the three negative cases read (`tests/shared_checks.py`).
+@pytest.fixture(scope="module")
+def bf16(nano):
+    return bench_olmoe.build(nano, None, 7)
+
+
+@pytest.fixture(scope="module")
+def check():
+    return shared_checks.Checked(bench_olmoe)
+
+
 # ------------------------------------------------------------ they agree
-def test_the_bf16_system_is_within_the_written_tolerance_of_the_reference(nano, tokens):
-    got = bench_olmoe.check(bench_olmoe.build(nano, None, 7), tokens)
+def test_the_bf16_system_is_within_the_written_tolerance_of_the_reference(bf16, check, tokens):
+    got = check(bf16, tokens)
     assert got["ok"], got
     assert got["loss_abs_err"] < bench_olmoe.LOSS_ABS_TOL
     assert got["grad_norm_rel_err"] < bench_olmoe.GRAD_NORM_REL_TOL
@@ -72,10 +85,10 @@ def test_in_float32_they_agree_to_rounding_and_pick_the_same_experts(nano, token
     assert got["expert_choices_flipped_share"] == 0.0
 
 
-def test_they_agree_at_trained_weights_too(trained, trained_f32, tokens):
-    got = bench_olmoe.check(trained, tokens)
+def test_they_agree_at_trained_weights_too(trained, trained_f32, check, tokens):
+    got = check(trained, tokens)
     assert got["ok"], got
-    exact = bench_olmoe.check(trained_f32, tokens)
+    exact = check(trained_f32, tokens)
     assert exact["loss_abs_err"] < 2e-6 and exact["expert_choices_flipped_share"] == 0.0, exact
 
 
@@ -131,23 +144,23 @@ def _dropping(real):
     ("routing_matrix", _renormalising), ("routing_matrix", _dropping),
     ("qk_norm", lambda real: _per_head_qk_norm)],
     ids=["renormalised_top_k", "tokens_dropped_over_capacity", "qk_norm_per_head"])
-def test_a_reference_of_another_function_fails_the_comparison(trained_f32, tokens, monkeypatch,
+def test_a_reference_of_another_function_fails_the_comparison(trained_f32, check, tokens, monkeypatch,
                                                               name, wrong):
     monkeypatch.setattr(bench_olmoe, name, wrong(getattr(bench_olmoe, name)))
-    got = bench_olmoe.check(trained_f32, tokens)
+    got = check(trained_f32, tokens)
     assert not got["ok"], got
     assert (got["grad_norm_rel_err"] > 2 * bench_olmoe.GRAD_NORM_REL_TOL
             or got["loss_abs_err"] > 2 * bench_olmoe.LOSS_ABS_TOL), got
 
 
-def test_the_reference_in_bf16_is_outside_the_loss_tolerance(nano, tokens):
+def test_the_reference_in_bf16_is_outside_the_loss_tolerance(bf16, nano, tokens):
     """What the nearest precision below the configuration's would give: the
     reference with parameters, router, norms and logits in bf16 (PERF.md
     section 6, PR 28, has the chip's reading at the published widths)."""
     import jax
     import jax.numpy as jnp
 
-    params = bench_olmoe.build(nano, None, 7).state.params
+    params = bf16.state.params
     exact, chosen = jax.jit(lambda p: bench_olmoe.reference_loss(p, tokens, nano))(params)
     low, low_chosen = jax.jit(
         lambda p: bench_olmoe.reference_loss(p, tokens, nano, dtype=jnp.bfloat16))(params)
@@ -155,13 +168,10 @@ def test_the_reference_in_bf16_is_outside_the_loss_tolerance(nano, tokens):
     assert 0 < float((chosen != low_chosen).mean())  # and its bf16 router picks other experts
 
 
-def test_parameters_kept_in_bf16_fail_the_check(nano, tokens):
-    import jax
+def test_parameters_kept_in_bf16_fail_the_check(bf16, tokens):
     import jax.numpy as jnp
 
-    system = bench_olmoe.build(nano, None, 7)
-    system.state.params = jax.tree.map(lambda p: p.astype(jnp.bfloat16), system.state.params)
-    got = bench_olmoe.check(system, tokens)
+    got = bench_olmoe.check(shared_checks.in_dtype(bf16, jnp.bfloat16), tokens)
     assert not got["ok"] and got["state_dtypes_other_than_stated"] == ["bfloat16"]
 
 

@@ -79,7 +79,7 @@ def test_asgi_ingress(serve_ctx):
     class Api:
         pass
 
-    serve.run(Api.bind(), route_prefix="/api")
+    serve.run(Api.bind(), route_prefix="/api", port=0)
     port = serve.http_port()
     status, body = _get(f"http://127.0.0.1:{port}/api/hello")
     assert status == 200 and body == b"hello asgi"
@@ -103,7 +103,7 @@ def test_streaming_http_response(serve_ctx):
             for i in range(n):
                 yield f"tok{i} "
 
-    serve.run(Streamer.bind(), route_prefix="/gen")
+    serve.run(Streamer.bind(), route_prefix="/gen", port=0)
     port = serve.http_port()
     status, body = _get(f"http://127.0.0.1:{port}/gen?n=5")
     assert status == 200
@@ -151,7 +151,7 @@ def test_two_deployment_graph_with_streamed_response(serve_ctx):
             for s in scores:
                 yield f"{s},"
 
-    serve.run(StreamingRanker.bind(Embedder.bind()), route_prefix="/rank")
+    serve.run(StreamingRanker.bind(Embedder.bind()), route_prefix="/rank", port=0)
     port = serve.http_port()
     status, body = _get(f"http://127.0.0.1:{port}/rank?text=hello")
     assert status == 200
@@ -174,7 +174,7 @@ def test_dag_driver(serve_ctx):
     dag = add_one.bind(double.bind(inp))
 
     handle = serve.run(
-        serve.deployment(DAGDriver).bind(dag), route_prefix="/calc"
+        serve.deployment(DAGDriver).bind(dag), route_prefix="/calc", port=0
     )
     # Python handle path.
     assert handle.predict.remote(5).result() == 11
@@ -203,7 +203,7 @@ def test_dag_driver_multi_route(serve_ctx):
     dag_neg = negate.bind(InputNode())
     handle = serve.run(
         serve.deployment(DAGDriver).bind({"/sq": dag_sq, "/neg": dag_neg}),
-        route_prefix="/m",
+        route_prefix="/m", port=0,
     )
     assert handle.predict_with_route.remote("/sq", 6).result() == 36
     port = serve.http_port()
@@ -228,7 +228,7 @@ def test_streaming_http_incremental_arrival(serve_ctx):
                 time.sleep(0.4)
                 yield f"chunk{i};"
 
-    serve.run(SlowStreamer.bind(), route_prefix="/slowgen")
+    serve.run(SlowStreamer.bind(), route_prefix="/slowgen", port=0)
     port = serve.http_port()
     conn = http.client.HTTPConnection("127.0.0.1", port, timeout=30)
     t0 = time.time()
@@ -262,7 +262,7 @@ def test_route_live_immediately_after_run(serve_ctx):
 
     for i in range(5):
         name = f"Hi{i}"
-        serve.run(Hi.options(name=name).bind(), route_prefix=f"/hi{i}")
+        serve.run(Hi.options(name=name).bind(), route_prefix=f"/hi{i}", port=0)
         port = serve.http_port()
         status, _body = _get(f"http://127.0.0.1:{port}/hi{i}")
         assert status == 200

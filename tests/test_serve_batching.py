@@ -192,7 +192,7 @@ def test_serve_batch_over_http(ray_start_regular):
 
     from ray_tpu import serve
 
-    serve.start()
+    serve.start(http_options={"port": 0})
 
     @serve.deployment(max_concurrent_queries=8)
     class Squarer:
@@ -203,7 +203,7 @@ def test_serve_batch_over_http(ray_start_regular):
         async def __call__(self, request):
             return await self.compute(request.json())
 
-    serve.run(Squarer.bind(), route_prefix="/sq")
+    serve.run(Squarer.bind(), route_prefix="/sq", port=0)
     port = serve.http_port()
 
     def hit(i):
@@ -231,7 +231,7 @@ def test_sync_deployment_parallel_under_concurrency(ray_start_regular):
 
     from ray_tpu import serve
 
-    serve.start()
+    serve.start(http_options={"port": 0})
 
     @serve.deployment(max_concurrent_queries=4)
     class Slow:
@@ -239,7 +239,7 @@ def test_sync_deployment_parallel_under_concurrency(ray_start_regular):
             _t.sleep(0.4)
             return "done"
 
-    serve.run(Slow.bind(), route_prefix="/slow")
+    serve.run(Slow.bind(), route_prefix="/slow", port=0)
     port = serve.http_port()
 
     def hit(_):
@@ -265,7 +265,7 @@ def test_serve_batch_in_replica(ray_start_regular):
     coalesce into vectorized batches inside the replica."""
     from ray_tpu import serve
 
-    serve.start(http_options={"location": "NoServer"})
+    serve.start(http_options={"location": "NoServer", "port": 0})
 
     @serve.deployment(max_concurrent_queries=8)
     class Doubler:

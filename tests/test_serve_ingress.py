@@ -151,7 +151,7 @@ def test_batch_shed_reason_survives_the_wire(serve_session):
         async def __call__(self, request):
             return await self.run(1)
 
-    serve.run(Batched.bind(), route_prefix="/batched")
+    serve.run(Batched.bind(), route_prefix="/batched", port=0)
     port = serve.http_port()
     url = f"http://127.0.0.1:{port}/batched"
     results = []
@@ -223,7 +223,7 @@ def test_proxy_sheds_over_app_cap_and_recovers(serve_session):
             time.sleep(0.4)
             return "done"
 
-    serve.run(Slow.bind(), route_prefix="/slow")
+    serve.run(Slow.bind(), route_prefix="/slow", port=0)
     port = serve.http_port()
     url = f"http://127.0.0.1:{port}/slow"
 
@@ -386,7 +386,7 @@ def test_dashboard_api_serve(serve_session):
     def ping(request):
         return "pong"
 
-    serve.run(ping.bind(), route_prefix="/ping")
+    serve.run(ping.bind(), route_prefix="/ping", port=0)
     port = serve.http_port()
     status, body, _ = _get(f"http://127.0.0.1:{port}/ping")
     assert status == 200
@@ -496,12 +496,11 @@ def test_proxy_failover_under_load():
                 if nid != "head" and p
             }
             if len(ports) == 2:
-                ok = True
-                for p in ports.values():
-                    status, _b, _h = _get(
-                        f"http://127.0.0.1:{p}/hello", timeout=5
-                    )
-                    ok = ok and status == 200
+                try:
+                    ok = all(_get(f"http://127.0.0.1:{p}/hello", timeout=5)[0] == 200
+                             for p in ports.values())
+                except OSError:  # registered, not listening yet: the loop is what waits for it
+                    ok = False
                 if ok:
                     break
             time.sleep(0.5)

@@ -44,6 +44,7 @@ def test_proxy_crash_recovers():
     weak #9 — per-node proxies had only a 2-node ping)."""
     import os
     import signal
+    import socket
     import time
 
     import ray_tpu
@@ -56,8 +57,14 @@ def test_proxy_crash_recovers():
         def hello(request):
             return "alive"
 
-        serve.run(hello.bind(), route_prefix="/hello")
+        # A fixed port, as a restarted proxy binds the one it had (`port=0` proxies are not restarted), but
+        # not the default 8000: other Serve test files run beside this one (`-n 6 --dist loadfile`).
+        with socket.socket() as probe:
+            probe.bind(("127.0.0.1", 0))
+            free = probe.getsockname()[1]
+        serve.run(hello.bind(), route_prefix="/hello", port=free)
         port = serve.http_port()
+        assert port == free
         status, body = _get(f"http://127.0.0.1:{port}/hello")
         assert body == b"alive"
 
@@ -82,3 +89,22 @@ def test_proxy_crash_recovers():
         serve.shutdown()
     finally:
         cluster.shutdown()
+
+
+def test_a_runtime_that_ended_without_serve_shutdown_leaves_the_next_one_a_working_serve():
+    """What a test that fails half way leaves behind (and a user who calls
+    `ray_tpu.shutdown()` alone): Serve's cached handles are the dead
+    runtime's, and until PR 46 the next runtime's every `serve.run` in that
+    process asked them ("Actor is dead: actor not found": two failures in
+    one Serve file took the nine tests of the next file with them)."""
+    for n in range(2):
+        ray_tpu.init(num_cpus=2)
+        try:
+            @serve.deployment
+            def hi(request):
+                return f"hi {request}"
+
+            handle = serve.run(hi.bind(), _blocking_http=False)
+            assert handle.remote(n).result() == f"hi {n}"
+        finally:
+            ray_tpu.shutdown()  # and no `serve.shutdown()`

@@ -66,7 +66,7 @@ def test_serve_request_one_connected_trace_and_latency_report():
             def __call__(self, req):
                 return {"out": ray_tpu.get(nested.remote(3))}
 
-        serve.run(App.bind(), route_prefix="/app")
+        serve.run(App.bind(), route_prefix="/app", port=0)
         from ray_tpu._private.worker import global_worker
 
         port = global_worker.context.serve_directory()[0]["port"]
