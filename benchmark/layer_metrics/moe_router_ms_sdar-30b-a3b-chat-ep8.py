@@ -1,0 +1,7 @@
+"""`moe.router_ms` in `sdar-30b-a3b-chat-ep8.fed8k`: that entry lists its cells and a later cell cannot
+append itself, so the cell brings the same reading under a name of its own."""
+
+from benchmark.layer_metrics import moe_router_ms as listed
+
+META = {**listed.META, "name": "moe.router_ms.sdar-30b-a3b-chat-ep8"}
+read = listed.read

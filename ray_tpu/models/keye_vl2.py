@@ -28,8 +28,10 @@ Built from what the zoo has: RMSNorm and the rotation are `llama.py`'s, the
 block's skeleton is `stack.py`'s with the layer's own `attend` where the
 attention dispatch stands (`stack.block`), the operators round the attention
 call are `ops/lightning_indexer.py`'s, the attention itself
-`flash_attention(keep=)` with its key/value heads unrepeated, and the expert
-layer is `moe.moe_mlp`, told which experts this chip holds (`n_experts_held`).
+`flash_attention(keep=)` with its key/value heads unrepeated, and the layer
+round them (projections with per-head QK-norm, output projection, the expert
+layer `moe.moe_mlp` told which experts this chip holds, the parameter tree) is
+`gqa_experts.py`'s, which `sdar.py` shares.
 `index_topk` None is the model without `sa_config`: dense grouped-query
 attention, no indexer.
 """
@@ -38,15 +40,15 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import math
 from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ray_tpu.models import gqa_experts
+from ray_tpu.models.gqa_experts import by_batch
 from ray_tpu.models.llama import apply_rope, rms_norm
-from ray_tpu.models.moe import moe_mlp
 from ray_tpu.models.stack import apply_stack, block, lm_head, lm_loss
 
 
@@ -110,50 +112,34 @@ class KeyeVL2Config:
 
 # --------------------------------------------------------------------------- sizes
 def _layer_shapes(config: KeyeVL2Config):
-    """{name: (shape, init: a normal's std, or "ones" / "zeros", logical axes)} of one layer."""
-    d, nh, nkv, hd, f = config.d_model, config.n_head, config.n_kv_head, config.head_dim, config.d_expert
-    std, out_std = 0.02, 0.02 / math.sqrt(2 * config.n_layer)
-    shapes: Dict[str, Any] = {
-        "attn_norm": ((d,), "ones", (None,)), "mlp_norm": ((d,), "ones", (None,)),
-        "wq": ((d, nh, hd), std, ("embed", "heads", None)),
-        "wk": ((d, nkv, hd), std, ("embed", "kv_heads", None)),
-        "wv": ((d, nkv, hd), std, ("embed", "kv_heads", None)),
-        "q_norm": ((hd,), "ones", (None,)), "k_norm": ((hd,), "ones", (None,)),
-        "wo": ((nh, hd, d), out_std, ("heads", None, "embed")),
-        "moe": {
-            "router_w": ((d, config.n_experts), std, ("embed", None)),
-            "w_gate": ((config.held, d, f), std, ("expert", "embed", "mlp")),
-            "w_up": ((config.held, d, f), std, ("expert", "embed", "mlp")),
-            "w_down": ((config.held, f, d), out_std, ("expert", "mlp", "embed")),
-        },
-    }
+    """`gqa_experts.layer_shapes`, and the indexer's leaves where the configuration has one."""
+    shapes = gqa_experts.layer_shapes(config)
     if config.sparse:
-        hi, di = config.index_n_heads, config.index_head_dim
+        d, hi, di = config.d_model, config.index_n_heads, config.index_head_dim
         shapes["indexer"] = {
-            "wq": ((d, hi, di), std, ("embed", None, None)),
-            "wk": ((d, di), std, ("embed", None)),
+            "wq": ((d, hi, di), 0.02, ("embed", None, None)),
+            "wk": ((d, di), 0.02, ("embed", None)),
             "k_norm": ((di,), "ones", (None,)), "k_norm_bias": ((di,), "zeros", (None,)),
-            "ww": ((d, hi), std, ("embed", None)),
+            "ww": ((d, hi), 0.02, ("embed", None)),
         }
     return shapes
 
 
-_is_shape = lambda x: isinstance(x, tuple) and len(x) == 3 and isinstance(x[0], tuple)  # noqa: E731
+def _indexer_matmul_params(config: KeyeVL2Config) -> int:
+    if not config.sparse:
+        return 0
+    return config.d_model * (config.index_n_heads * (config.index_head_dim + 1) + config.index_head_dim)
 
 
 def _matmul_params(config: KeyeVL2Config) -> int:
     """One layer's parameters that every token meets as an operand of a product."""
-    d, hd = config.d_model, config.head_dim
-    n = 2 * d * config.n_head * hd + 2 * d * config.n_kv_head * hd + d * config.n_experts
-    if config.sparse:
-        n += d * config.index_n_heads * (config.index_head_dim + 1) + d * config.index_head_dim
-    return n
+    return gqa_experts.matmul_params(config) + _indexer_matmul_params(config)
 
 
 def num_params(config: KeyeVL2Config) -> int:
     """Of this share: the experts held, not all the router names; embedding and head untied."""
     d = config.d_model
-    per_layer = (_matmul_params(config) + 3 * config.held * d * config.d_expert + 2 * d + 2 * config.head_dim
+    per_layer = (gqa_experts.layer_params(config) + _indexer_matmul_params(config)
                  + (2 * config.index_head_dim if config.sparse else 0))
     return 2 * config.vocab_size * d + d + config.n_layer * per_layer
 
@@ -186,41 +172,13 @@ def train_flops_per_token(config: KeyeVL2Config, seq_len: int) -> float:
 
 
 # --------------------------------------------------------------------------- init
-def _tree(config: KeyeVL2Config, layer_leaf: Callable, leaf: Callable):
-    """A tree like the parameters': `layer_leaf(shape, init, axes)` for a layer's
-    leaves (stacked over the layers), `leaf(shape, init, axes)` for the others.
-
-    The embedding's rows are N(0, 1), `torch.nn.Embedding`'s own: at 0.02 a
-    token's row (norm 0.9) is outweighed after one layer by the running mean of
-    the values, which a group of 8 query heads on one key/value head adds up
-    coherently (2.2) and which is the same vector for every query; the routers
-    of the later layers then see one input, a row's 16,384 tokens all choose
-    the same 8 experts, and a layer's held load is 0, 1, 2 or 3 times the
-    even share by the draw (PERF.md section 6, PR 42)."""
-    d = config.d_model
-    return {
-        "embed": leaf((config.vocab_size, d), 1.0, ("vocab", "embed")),
-        "blocks": jax.tree.map(lambda spec: layer_leaf(*spec), _layer_shapes(config), is_leaf=_is_shape),
-        "final_norm": leaf((d,), "ones", (None,)),
-        "lm_head": leaf((config.vocab_size, d), 0.02, ("vocab", "embed")),
-    }
-
-
 def init_params(config: KeyeVL2Config, key) -> Dict[str, Any]:
-    pd, counter = config.param_dtype, iter(range(1 << 30))
-
-    def array(stack):
-        def make(shape, init, axes):
-            if isinstance(init, str):
-                return jnp.full(stack + shape, {"ones": 1.0, "zeros": 0.0}[init], pd)
-            return (jax.random.normal(jax.random.fold_in(key, next(counter)), stack + shape) * init).astype(pd)
-        return make
-
-    return _tree(config, array((config.n_layer,)), array(()))
+    """`gqa_experts.init_params` (which says why the embedding's rows are N(0, 1)) over this model's leaves."""
+    return gqa_experts.init_params(config, key, _layer_shapes(config))
 
 
 def param_logical_axes(config: KeyeVL2Config) -> Dict[str, Any]:
-    return _tree(config, lambda shape, init, axes: ("layers",) + axes, lambda shape, init, axes: axes)
+    return gqa_experts.param_logical_axes(config, _layer_shapes(config))
 
 
 # --------------------------------------------------------------------------- forward
@@ -263,16 +221,10 @@ def _parts(config: KeyeVL2Config, stats: bool = False):
     configuration. The scope names are read from the compiled program's
     `op_name`s (PERF.md, "names")."""
     cdt, eps = config.dtype, config.norm_eps
-    by_batch = lambda table: table.transpose(1, 0, 2)[:, None]  # (S, B, pairs) -> (B, 1, S, pairs)
 
     def qkv_part(x, layer, cos, sin, *index_tables):
         h = rms_norm(x, layer["attn_norm"], eps).astype(cdt)
-        cos, sin = by_batch(cos), by_batch(sin)
-        q = jnp.einsum("bsd,dnh->bnsh", h, layer["wq"].astype(cdt))
-        k = jnp.einsum("bsd,dnh->bnsh", h, layer["wk"].astype(cdt))
-        v = jnp.einsum("bsd,dnh->bnsh", h, layer["wv"].astype(cdt))
-        q = apply_rope(rms_norm(q, layer["q_norm"], eps).astype(cdt), cos, sin)
-        k = apply_rope(rms_norm(k, layer["k_norm"], eps).astype(cdt), cos, sin)
+        q, k, v = gqa_experts.qkv_heads(h, layer, by_batch(cos), by_batch(sin), config)
         if not config.sparse:
             return q, k, v
         with jax.named_scope("indexer"):
@@ -304,19 +256,11 @@ def _parts(config: KeyeVL2Config, stats: bool = False):
 
     def out_part(x, o, layer, rng, further=None):
         del rng  # no dropout
-        with jax.named_scope("attn_out"):
-            x = x + jnp.einsum("bnsh,nhd->bsd", o.astype(cdt), layer["wo"].astype(cdt))
-        with jax.named_scope("moe"):
-            h = rms_norm(x, layer["mlp_norm"], eps).astype(cdt)
-            moe = layer["moe"]
-            h, aux = moe_mlp(
-                h, moe["router_w"], moe["w_gate"], moe["w_up"], moe["w_down"],
-                k=config.experts_per_token, norm_topk_prob=config.norm_topk_prob,
-                held_from=config.first_expert_held)
+        x, aux = gqa_experts.out_and_experts(x, o, layer, config)
         if stats:
-            return x + h, {"moe": aux, "selection": further}
+            return x, {"moe": aux, "selection": further}
         aux = config.aux_loss_weight * aux["load_balance"]
-        return x + h, aux if further is None else aux + further
+        return x, aux if further is None else aux + further
 
     return qkv_part, out_part, attend if config.sparse else None
 
@@ -326,7 +270,7 @@ def forward(
     tokens,  # (B, S) int32
     config: KeyeVL2Config,
     attention_fn: Optional[Callable] = None,
-    dropout_rng=None,  # accepted for API parity; no dropout
+    step_rng=None,  # the step's key (`make_train_step`): nothing here draws
     mesh=None,
     num_microbatches: Optional[int] = None,
     return_aux: bool = False,
@@ -335,7 +279,7 @@ def forward(
     """Logits (B, S, vocab) f32 against the head (untied); with `return_aux`,
     also the weighted sum over the layers of the indexer's loss and the
     load-balancing term."""
-    del dropout_rng
+    del step_rng
     with jax.named_scope("embed"):
         x = params["embed"].astype(config.dtype)[tokens]
     qkv_part, out_part, attend = _parts(config)
@@ -373,18 +317,7 @@ def routing_stats(params: Dict[str, Any], tokens, config: KeyeVL2Config, walked=
     as `glm4_moe_lite.routing_stats` reports it, the load-balancing term beside.
     `walked`: `layer_stats` of the same arguments, where the caller has it."""
     aux = (walked or layer_stats(params, tokens, config))["moe"]
-    pairs = tokens[:, :-1].size * config.experts_per_token
-    counts = aux["tokens_per_expert"]
-    return {
-        "experts": aux["experts"],
-        "tokens_per_expert": counts,
-        "load_max_over_mean": counts.max(axis=-1) / counts.mean(axis=-1),
-        "load_balance": aux["load_balance"],
-        "held_pairs": aux["held_pairs"],
-        "elsewhere_pairs": pairs - aux["held_pairs"],
-        "dropped": aux["held_pairs"] - aux["rows_processed"],
-        "compact": aux["compact"],
-    }
+    return gqa_experts.routing_stats(aux, tokens[:, :-1].size * config.experts_per_token)
 
 
 def selection_stats(params: Dict[str, Any], tokens, config: KeyeVL2Config, walked=None) -> Dict[str, Any]:

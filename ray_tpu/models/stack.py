@@ -241,21 +241,32 @@ def lm_head(x, norm: Callable, table, dtype):
         )
 
 
-def causal_lm_loss(logits, targets, mask=None):
-    """Fused cross entropy: logsumexp - logit[target], one reduction over V
-    instead of materializing the (B, S, V) log-softmax (saves ~2x V-sized HBM
-    traffic). The mean is over every position, or with `mask` (broadcast
-    against (B, S)) over the positions it keeps."""
+def cross_entropy(logits, targets):
+    """Each position's cross entropy (B, S) f32, fused: logsumexp - logit[target], one reduction over V
+    instead of materializing the (B, S, V) log-softmax (saves ~2x V-sized HBM traffic)."""
+    lse = jax.scipy.special.logsumexp(logits, axis=-1)
+    return lse - jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
+
+
+def causal_lm_loss(logits, targets, mask=None, weights=None):
+    """The mean of `cross_entropy` over every position, or with `mask` (broadcast
+    against (B, S)) over the positions it keeps: weights of 0 and 1,
+    normalised by their sum. `weights` (float, broadcast against (B, S)) are
+    an objective's own, and their sum is no normaliser: the weighted sum over
+    the number of positions (block diffusion's 1 / t at the masked positions
+    of a row of S, `sdar.py`)."""
     with jax.named_scope("loss"):
-        lse = jax.scipy.special.logsumexp(logits, axis=-1)
-        at_target = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
+        ce = cross_entropy(logits, targets)
+        if weights is not None:
+            assert mask is None, "weights of their own, or a mask's 0 and 1"
+            return (jnp.broadcast_to(weights, targets.shape) * ce).sum() / targets.size
         if mask is None:
-            return (lse - at_target).mean()
+            return ce.mean()
         mask = jnp.broadcast_to(mask, targets.shape)
-        return jnp.where(mask, lse - at_target, 0.0).sum() / mask.sum()
+        return jnp.where(mask, ce, 0.0).sum() / mask.sum()
 
 
-def lm_loss(forward: Callable, params, batch, config, attention_fn=None, dropout_rng=None,
+def lm_loss(forward: Callable, params, batch, config, attention_fn=None, step_rng=None,
             mesh=None, num_microbatches=None):
     """Causal LM cross entropy (mean over tokens) of a model's `forward` on
     `batch`, {"tokens": (B, S+1)} or {"inputs", "targets"}, plus the auxiliary
@@ -263,7 +274,9 @@ def lm_loss(forward: Callable, params, batch, config, attention_fn=None, dropout
     the model has already weighted, or None where it has none. A model with
     further prediction depths (`config.n_predict_layers`: each position also
     predicts tokens beyond its next) computes their loss as that scalar, and
-    its `forward` is handed the `targets` for it."""
+    its `forward` is handed the `targets` for it. `step_rng` is the step's key
+    (`make_train_step` folds one from `state.step` for every model): dropout's
+    in a model that has some, unused in the others."""
     if "inputs" in batch:
         inputs, targets = batch["inputs"], batch["targets"]
     else:
@@ -271,7 +284,7 @@ def lm_loss(forward: Callable, params, batch, config, attention_fn=None, dropout
         inputs, targets = tokens[:, :-1], tokens[:, 1:]
     deeper = {"targets": targets} if getattr(config, "n_predict_layers", 0) else {}
     logits, aux = forward(
-        params, inputs, config, attention_fn, dropout_rng, mesh, num_microbatches,
+        params, inputs, config, attention_fn, step_rng, mesh, num_microbatches,
         return_aux=True, **deeper,
     )
     loss = causal_lm_loss(logits, targets)

@@ -105,15 +105,14 @@ def make_train_step(
     frozen = getattr(model_for(config), "frozen_params", None)
 
     def step_fn(state: TrainState, batch):
-        dropout_rng = (
-            jax.random.fold_in(base_rng, state.step)
-            if getattr(config, "dropout", 0) > 0
-            else None
-        )
+        # The step's key, for whatever the model draws anew each step (dropout's
+        # masks, a diffusion objective's noise); a model that draws nothing
+        # ignores it, and the fold is dead code in its program.
+        step_rng = jax.random.fold_in(base_rng, state.step)
 
         def loss_of(p):
             return model_for(config).loss_fn(
-                p, batch, config, attention_fn, dropout_rng, mesh=mesh
+                p, batch, config, attention_fn, step_rng, mesh=mesh
             )
 
         import optax

@@ -43,6 +43,15 @@ Design (pallas_guide.md playbook):
    tile pair on or under the diagonal is still visited: a token-level choice
    of 2,048 keys in 16,384 leaves none empty (`dsa.live_tiles_share` 1.0,
    PERF.md section 6, PR 42).
+ - a mask by structure (`causal=` a hashable description in place of True:
+   `BlockDiffusion`): a static function of two positions, which costs no
+   operand. The pair kernels' schedules ask it of every tile pair whether it
+   is empty, whole or crossed (`_tiles_under`) and walk the empty ones not at
+   all; a crossed pair's mask is made from iotas inside the kernel
+   (`_kept_in_pair`). The whole-head forms know the causal diagonal alone, so
+   any other mask runs both passes a pair a program. Block diffusion's row of
+   16,384 (two copies of 8,192, blocks of 4) walks 160 of 512 pairs of 512 x
+   1,024 where the causal diagonal walks 272 (PERF.md section 6, PR 47).
  - matmuls run on the MXU with preferred_element_type=float32; inputs can be
    bfloat16.
 
@@ -154,6 +163,14 @@ MAX_STREAMED_HEAD_BYTES = 16384 * 128 * 2
 # softmax alone 9,938, neither 4,661: the MXU binds, the vector work hides
 # behind it, and the loop over key blocks is the rest (unrolled: 14,026 whole,
 # 13,933 the products, 2,375 neither).
+# Under a mask by structure, block diffusion's (tools/flash_bench.py --block-diffusion 4, PR 47; one call at (32 on 4,
+# 16384, 128), two copies of 8,192 in blocks of 4, forward / backward, us): 512 x 1024 9,562 / 21,368 over the 160 live
+# pairs of 512, 48 of them crossed: 58.4 % and 52.3 % of the MXU's peak on the 67.1 M kept pairs a head, the floor
+# `kernels.flash_roofline` reads by. The causal walk of the same row in the same call takes 15,176 / 33,874 over 272
+# pairs, 32 crossed: a pair costs 55.8 / 124.5 there and 59.8 / 133.5 here, the crossed pairs' dearer mask (two
+# compares of shifted iotas where the diagonal's is one, in 30 % of the pairs where 12 % were) and a Q tile's shorter
+# runs. 512-tiles 8,613 / 22,972 over 288 pairs (the forward 10 % faster, the backward 7.5 % slower: the plan's tile
+# stays); 256 x 1024 9,493 / 23,548 over 320.
 PAIRS_TILE_K = 1024
 NEG_INF = -1e30
 # Where the pair-streamed forward's running maximum starts (`_fwd_pairs_kernel`):
@@ -200,6 +217,72 @@ def _streamed_head(seq: int, head_dim: int, itemsize: int) -> bool:
     return _head_bytes(seq, head_dim, itemsize) > LONG_HEAD_BYTES or head_dim > LANES
 
 
+# --------------------------------------------------------------------------- masks by structure
+EMPTY, WHOLE, CROSSED = 0, 1, 2  # what a mask keeps of a rectangle of scores: nothing, everything, some
+
+
+class BlockDiffusion(NamedTuple):
+    """Block-diffusion training's mask (BD3-LMs, SDAR) over a row of `2 * seq`
+    positions: a clean copy of `seq` tokens first, then its noised copy, both
+    cut into blocks of `block`. With `blk(p) = (p mod seq) // block`,
+
+        query \\ key     clean copy               noised copy
+        clean, block i   blocks <= i              none
+        noised, block i  blocks < i (strict)      block i alone, both directions
+
+    so every query keeps a key (its own block's), and the mask lies on or under
+    the block-causal diagonal of the doubled row. Hashable and static: what
+    `flash_attention(causal=)`, `xla_attention` and `blockwise_attention` take
+    in place of True."""
+
+    seq: int
+    block: int
+
+    def kept(self, rows, cols):
+        """bool: whether query `rows` attends to key `cols` (int arrays of one
+        shape, numpy's or jax's, positions in the doubled row)."""
+        shift = self.block.bit_length() - 1
+        blk = (lambda p: p >> shift) if 1 << shift == self.block else (lambda p: p // self.block)
+        q_noised, k_noised = rows >= self.seq, cols >= self.seq
+        strict = q_noised.astype(rows.dtype)
+        q_blk, k_blk = blk(rows - self.seq * strict), blk(cols - self.seq * k_noised.astype(cols.dtype))
+        return (k_noised & q_noised & (k_blk == q_blk)) | (~k_noised & (k_blk <= q_blk - strict))
+
+    def tile_class(self, r0: int, r1: int, c0: int, c1: int) -> int:
+        """EMPTY, WHOLE or CROSSED: what `kept` keeps of queries [r0, r1) x keys
+        [c0, c1), from the corners' blocks alone (a rectangle that spans both
+        copies is classed a copy at a time)."""
+        L, parts = self.seq, set()
+        for q_noised, (qa, qb) in enumerate(((r0, min(r1, L)), (max(r0, L), r1))):
+            for k_noised, (ka, kb) in enumerate(((c0, min(c1, L)), (max(c0, L), c1))):
+                if qa >= qb or ka >= kb:
+                    continue
+                q0, q1, k0, k1 = ((p - L * noised) // self.block for p, noised in (
+                    (qa, q_noised), (qb - 1, q_noised), (ka, k_noised), (kb - 1, k_noised)))
+                if k_noised:  # its own block alone, and a clean query sees none of the noised copy
+                    whole = q_noised and q0 == q1 == k0 == k1
+                    empty = not q_noised or k0 > q1 or k1 < q0
+                else:  # blocks <= the query's, < where the query is noised
+                    whole, empty = k1 <= q0 - q_noised, k0 > q1 - q_noised
+                parts.add(WHOLE if whole else EMPTY if empty else CROSSED)
+        return parts.pop() if len(parts) == 1 else CROSSED
+
+    def dense(self, queries: int, keys: int):
+        """The (queries, keys) bool of the whole doubled row, for the XLA forms."""
+        assert queries == keys == 2 * self.seq, f"a row of {queries} x {keys} under a mask of 2 x {self.seq}"
+        return self.kept(jax.lax.broadcasted_iota(jnp.int32, (queries, keys), 0),
+                         jax.lax.broadcasted_iota(jnp.int32, (queries, keys), 1))
+
+
+def _dense_mask(causal, queries: int, keys: int):
+    """(queries, keys) bool of `causal` (True: the diagonal, the last query on the last key), None for False."""
+    if causal is False:
+        return None
+    if causal is True:
+        return jnp.tril(jnp.ones((queries, keys), dtype=bool), k=keys - queries)
+    return causal.dense(queries, keys)
+
+
 # --------------------------------------------------------------------------- XLA form
 def pack_keep(mask):
     """(..., queries, keys) bool -> (..., queries, spans * 128) int32, the packed
@@ -229,9 +312,10 @@ def _repeat_kv(q, k, v):
     return jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1)
 
 
-def xla_attention(q, k, v, causal: bool = True, sm_scale: Optional[float] = None, keep=None,
+def xla_attention(q, k, v, causal=True, sm_scale: Optional[float] = None, keep=None,
                   return_lse: bool = False):
-    """Plain-XLA attention (fused well by the compiler; O(S^2) memory). `keep`
+    """Plain-XLA attention (fused well by the compiler; O(S^2) memory). `causal`:
+    True, False or a mask by structure (`BlockDiffusion`). `keep`
     (batch, queries, spans * 128), packed (`pack_keep`): the keys each query may
     attend to, the same for every head. With `return_lse` also each row's
     log-sum-exp of the scaled scores it attends to, (batch, heads, queries) f32."""
@@ -241,9 +325,8 @@ def xla_attention(q, k, v, causal: bool = True, sm_scale: Optional[float] = None
     s = jnp.einsum(
         "bhqd,bhkd->bhqk", q, k, preferred_element_type=jnp.float32
     ) * sm_scale
-    if causal:
-        qlen, klen = s.shape[-2], s.shape[-1]
-        mask = jnp.tril(jnp.ones((qlen, klen), dtype=bool), k=klen - qlen)
+    mask = _dense_mask(causal, s.shape[-2], s.shape[-1])
+    if mask is not None:
         s = jnp.where(mask, s, NEG_INF)
     if keep is not None:
         s = jnp.where(unpack_keep(keep, s.shape[-1])[:, None], s, NEG_INF)
@@ -266,6 +349,40 @@ def _diag_and_end(t, size, other, n_other, static):
     diag = (t * size) // other
     end = ((t + 1) * size + other - 1) // other
     return diag, (min(end, n_other) if static else jnp.minimum(end, n_other))
+
+
+def _tiles_under(mask, t: int, size: int, other: int, n_other: int, t_is_q: bool):
+    """[(tile of the other side, whether the mask crosses the pair)] that tile
+    `t` (of `size`; a Q tile where `t_is_q`, else a K tile) is walked with, in
+    the order of the walk: what the pair-streamed schedules and the plan's
+    counts are made from. `mask` False: every tile, none crossed; True: the
+    causal diagonal's (`_diag_and_end`); else the pairs the mask does not class
+    EMPTY, be they contiguous or not."""
+    if mask is False:
+        return [(o, False) for o in range(n_other)]
+    if mask is True:
+        diag, end = _diag_and_end(t, size, other, n_other, True)
+        return [(j, j >= diag) for j in range(end)] if t_is_q else [(i, i < end) for i in range(diag, n_other)]
+    mine, out = (t * size, (t + 1) * size), []
+    for o in range(n_other):
+        theirs = (o * other, (o + 1) * other)
+        kind = mask.tile_class(*mine, *theirs) if t_is_q else mask.tile_class(*theirs, *mine)
+        if kind != EMPTY:
+            out.append((o, kind == CROSSED))
+    return out
+
+
+def _kept_in_pair(mask, i, j, tile_q: int, tile_k: int, shape, q_axis: int):
+    """bool `shape`: the scores of the crossed pair (Q tile i, K tile j) that
+    `mask` keeps, queries along `q_axis` of the tile and keys along the other,
+    from iotas and the pair's offsets (scalars of the kernel)."""
+    rows = jax.lax.broadcasted_iota(jnp.int32, shape, q_axis)
+    cols = jax.lax.broadcasted_iota(jnp.int32, shape, 1 - q_axis)
+    if isinstance(mask, bool):
+        # The diagonal: `row - col` inside a tile is one constant a program, a pair adds its offset. (A call
+        # that is not causal traces a crossed pair's branch too, and never takes it.)
+        return rows - cols >= j * tile_k - i * tile_q
+    return mask.kept(rows + i * tile_q, cols + j * tile_k)
 
 
 def _for(lo, hi, body, carry, static):
@@ -435,18 +552,26 @@ def _fwd(q, k, v, causal, sm_scale, plan, interpret):
     return o, lse
 
 
-def _fwd_schedule(seq: int, plan: "KernelPlan", causal: bool):
+def _fwd_schedule(seq: int, plan: "KernelPlan", causal):
     """The pair-streamed forward pass's steps, int32 (5, steps): for each the Q
     tile, the K tile, and whether it is the Q tile's first pair, a pair the
-    diagonal crosses, the Q tile's last pair. Q tiles in turn, under each its K
-    tiles on or under the diagonal: a Q tile's output block is the same over its
-    run of steps and is written in the last."""
+    mask crosses, the Q tile's last pair. Q tiles in turn, under each the K
+    tiles the mask leaves it (`_tiles_under`: on or under the diagonal when
+    causal): a Q tile's output block is the same over its run of steps and is
+    written in the last."""
     n_q, n_k = seq // plan.tile_q, seq // plan.tile_k
     steps = []
     for i in range(n_q):
-        diag, end = _diag_and_end(i, plan.tile_q, plan.tile_k, n_k, True) if causal else (n_k, n_k)
-        steps += [(i, j, j == 0, diag <= j < end, j == end - 1) for j in range(end)]
+        tiles = _tiles_under(causal, i, plan.tile_q, plan.tile_k, n_k, True)
+        assert tiles, f"Q tile {i} keeps no key under {causal}: its output would never be written"
+        steps += [(i, j, n == 0, crossed, n == len(tiles) - 1) for n, (j, crossed) in enumerate(tiles)]
     return np.asarray(steps, np.int32).T
+
+
+def _walk_scope(steps, seq: int, tile_q: int, tile_k: int) -> str:
+    """`KernelPlan.scope` from a pair-streamed schedule itself: the steps a program of the call walks (its
+    grid's second axis) of the tile pairs of the square, at the tiles the call runs."""
+    return f"tiles_{steps.shape[1]}of{(seq // tile_q) * (seq // tile_k)}"
 
 
 def _keep_tile_t(keep_ref, j, tile_k):
@@ -491,7 +616,7 @@ def _block_step(k, v_t, qs, bias, m, l, acc):
     return m, l, acc * alpha + _values_t(v_t, p.astype(v_t.dtype))
 
 
-def _fwd_pairs_kernel(steps_ref, q_ref, k_ref, v_ref, *refs, sm_scale, tile_q, tile_k, has_keep):
+def _fwd_pairs_kernel(steps_ref, q_ref, k_ref, v_ref, *refs, sm_scale, tile_q, tile_k, has_keep, mask=True):
     """The forward pass with a program a (Q tile, K tile) pair (`_fwd_schedule`,
     grid axis 1, sequential) of the `heads` consecutive query heads that the
     program takes (`q_ref` (1, heads, tile_q, d), held once a Q tile and scaled
@@ -500,7 +625,8 @@ def _fwd_pairs_kernel(steps_ref, q_ref, k_ref, v_ref, *refs, sm_scale, tile_q, t
     of them): k, v and the packed selection are the pair's own tiles, fetched
     once for all the heads. Inside, a tile is (keys, queries): the pair's mask
     (the selection's, the diagonal's where it crosses) becomes one additive
-    `bias` that every head reads, v is turned once, and a head's running
+    `bias` that every head reads (`mask`: what a crossed pair's is made from,
+    `_kept_in_pair`), v is turned once, and a head's running
     maximum, sum and f32 output `o^T` (d, tile_q) stay in VMEM over the Q
     tile's pairs; the output is turned back and written in the last.
 
@@ -530,13 +656,11 @@ def _fwd_pairs_kernel(steps_ref, q_ref, k_ref, v_ref, *refs, sm_scale, tile_q, t
 
     def mask_to_bias(diagonal):
         def run():
-            mask = _keep_tile_t(keep_ref, j, tile_k) if has_keep else None
+            kept = _keep_tile_t(keep_ref, j, tile_k) if has_keep else None
             if diagonal:
-                diff = (jax.lax.broadcasted_iota(jnp.int32, (tile_k, tile_q), 1)
-                        - jax.lax.broadcasted_iota(jnp.int32, (tile_k, tile_q), 0))
-                under = diff >= j * tile_k - i * tile_q
-                mask = under if mask is None else mask & under
-            bias[...] = jnp.where(mask, 0.0, NEG_INF)
+                under = _kept_in_pair(mask, i, j, tile_q, tile_k, (tile_k, tile_q), 1)
+                kept = under if kept is None else kept & under
+            bias[...] = jnp.where(kept, 0.0, NEG_INF)
         return run
 
     def heads_of_pair(masked):
@@ -658,10 +782,10 @@ def _fwd_pairs(q, k, v, keep, causal, sm_scale, plan, interpret):
         in_specs.append(_keep_spec(programs // keep.shape[0], tile_q, tile_k, 0, 1))
         operands.append(keep)
     # `group_<n>`: which form a trace's `flash_fwd` ran (the heads a program takes).
-    with jax.named_scope(plan.scope), jax.named_scope(f"group_{heads}"):
+    with jax.named_scope(_walk_scope(steps, seq, tile_q, tile_k)), jax.named_scope(f"group_{heads}"):
         o, lse = pl.pallas_call(
             functools.partial(_fwd_pairs_kernel, sm_scale=sm_scale, tile_q=tile_q, tile_k=tile_k,
-                              has_keep=keep is not None),
+                              has_keep=keep is not None, mask=causal),
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=1,
                 grid=(programs, steps.shape[1]),
@@ -769,21 +893,23 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         pl.when(j == n_k - 1)(flush)
 
 
-def _pair_schedule(seq: int, plan: "KernelPlan", causal: bool):
+def _pair_schedule(seq: int, plan: "KernelPlan", causal):
     """The pair-streamed backward pass's steps, int32 (6, steps): for each the
     Q tile, the K tile, the dq tile its output block is, and whether it is the
-    K tile's first pair, a pair the diagonal crosses, the pair after which its
-    Q tile's dq is whole. K tiles in turn, under each its Q tiles on or under
-    the diagonal: a Q tile's last pair comes later the later the tile, so the
-    dq block of a step is the next tile to become whole, and it is written in
-    that step alone."""
+    K tile's first pair, a pair the mask crosses, the pair after which its
+    Q tile's dq is whole. K tiles in turn, under each the Q tiles the mask
+    leaves it (`_tiles_under`: on or under the diagonal when causal): a Q
+    tile's last pair comes later the later the tile (asserted: a mask on or
+    under a block-causal diagonal has it so), so the dq block of a step is the
+    next tile to become whole, and it is written in that step alone."""
     n_q, n_k = seq // plan.tile_q, seq // plan.tile_k
     pairs = []
     for j in range(n_k):
-        diag, end = _diag_and_end(j, plan.tile_k, plan.tile_q, n_q, True) if causal else (0, 0)
-        pairs += [(i, j, i == diag, diag <= i < end) for i in range(diag, n_q)]
+        tiles = _tiles_under(causal, j, plan.tile_k, plan.tile_q, n_q, False)
+        assert tiles, f"K tile {j} is kept by no query under {causal}: its dk and dv would never be written"
+        pairs += [(i, j, n == 0, crossed) for n, (i, crossed) in enumerate(tiles)]
     whole_at = {i: t for t, (i, *_) in enumerate(pairs)}
-    assert sorted(whole_at.values()) == [whole_at[i] for i in range(n_q)]
+    assert sorted(whole_at.values()) == [whole_at[i] for i in range(n_q)], f"no schedule under {causal}"
     steps, due = [], 0
     for t, (i, j, first, masked) in enumerate(pairs):
         steps.append((i, j, due, first, masked, whole_at[i] == t))
@@ -792,13 +918,14 @@ def _pair_schedule(seq: int, plan: "KernelPlan", causal: bool):
 
 
 def _bwd_pairs_kernel(steps_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *refs,
-                      sm_scale, tile_q, tile_k, has_keep=False):
+                      sm_scale, tile_q, tile_k, has_keep=False, mask=True):
     """The fused backward pass with a program a (Q tile, K tile) pair
     (`_pair_schedule`, grid axis 1, sequential): every operand is the pair's
     own tile, streamed by the pipeline, and all a head keeps in VMEM is its
     f32 dq (seq, d) beside the K tile's f32 dk and dv. `keep_ref` (with
     `has_keep`) is the pair's tile of the packed selection, which then masks
-    every pair, beside the diagonal where it crosses."""
+    every pair, beside the diagonal where it crosses; `mask` is what a crossed
+    pair's own mask is made from (`_kept_in_pair`)."""
     keep_ref = refs[0] if has_keep else None
     dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc = refs[has_keep:]
     t = pl.program_id(1)
@@ -817,7 +944,7 @@ def _bwd_pairs_kernel(steps_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref
 
     def pair(crossed):
         def run():
-            keep = _causal_mask(tile_q, tile_k)(i, j) if crossed else None
+            keep = _kept_in_pair(mask, i, j, tile_q, tile_k, (tile_q, tile_k), 0) if crossed else None
             if has_keep:
                 kept = _keep_tile(keep_ref, j, tile_k)
                 keep = kept if keep is None else keep & kept
@@ -833,7 +960,13 @@ def _bwd_pairs_kernel(steps_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref
     def _():
         dq_ref[0] = dq_acc[acc_rows, :].astype(dq_ref.dtype)
 
-    @pl.when(i == dq_acc.shape[0] // tile_q - 1)  # the K tile's last pair
+    if isinstance(mask, bool):  # every K tile's walk ends at the last Q tile
+        last = i == dq_acc.shape[0] // tile_q - 1
+    else:  # the next step is another K tile's, or there is none
+        end = pl.num_programs(1) - 1
+        last = jnp.logical_or(t == end, steps_ref[1, jnp.minimum(t + 1, end)] != j)
+
+    @pl.when(last)  # the K tile's last pair
     def _():
         dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
@@ -850,10 +983,10 @@ def _bwd_pairs(q, k, v, do, lse, delta, causal, sm_scale, plan, interpret, keep=
     kv_tile = k_tile if k.shape[0] == bh else _pair_specs(plan, d, bh // k.shape[0], 0, 1)[1]
     extra = [] if keep is None else [keep]
     keep_specs = [_keep_spec(bh // keep.shape[0], plan.tile_q, plan.tile_k, 0, 1)] if extra else []
-    with jax.named_scope(plan.scope):
+    with jax.named_scope(_walk_scope(steps, seq, plan.tile_q, plan.tile_k)):
         return pl.pallas_call(
-            functools.partial(_bwd_pairs_kernel, sm_scale=sm_scale,
-                              tile_q=plan.tile_q, tile_k=plan.tile_k, has_keep=keep is not None),
+            functools.partial(_bwd_pairs_kernel, sm_scale=sm_scale, tile_q=plan.tile_q, tile_k=plan.tile_k,
+                              has_keep=keep is not None, mask=causal),
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=1,
                 grid=(bh, steps.shape[1]),
@@ -909,11 +1042,12 @@ def _bwd(causal, sm_scale, plan, interpret, res, g):
 
 
 # --------------------------------------------------------------------------- blockwise (long-seq XLA)
-def blockwise_attention(q, k, v, causal: bool = True, sm_scale: Optional[float] = None,
+def blockwise_attention(q, k, v, causal=True, sm_scale: Optional[float] = None,
                         block_k: int = 1024):
     """O(S * block_k)-memory attention as a remat'ed scan over K blocks — the
     long-sequence path while the forward kernel keeps full-seq K/V in VMEM
-    (which caps it at `MAX_HEAD_BYTES` a head). Exact, differentiable, pure XLA."""
+    (which caps it at `MAX_HEAD_BYTES` a head). Exact, differentiable, pure XLA.
+    `causal`: True, False or a mask by structure, as `xla_attention` takes it."""
     if sm_scale is None:
         sm_scale = q.shape[-1] ** -0.5
     B, H, S, D = q.shape
@@ -933,9 +1067,9 @@ def blockwise_attention(q, k, v, causal: bool = True, sm_scale: Optional[float] 
             "bhqd,bhkd->bhqk", qf, kblk.astype(jnp.float32),
             preferred_element_type=jnp.float32,
         ) * sm_scale
-        if causal:
+        if causal is not False:
             col = j * block_k + jax.lax.broadcasted_iota(jnp.int32, (S, block_k), 1)
-            s = jnp.where((row >= col)[None, None], s, NEG_INF)
+            s = jnp.where((row >= col if causal is True else causal.kept(row, col))[None, None], s, NEG_INF)
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
         p = jnp.exp(s - m_new[..., None])
         alpha = jnp.exp(m_prev - m_new)
@@ -1019,11 +1153,12 @@ class KernelPlan(NamedTuple):
         return f"tiles_{self.tiles_visited}of{self.tiles_total}"
 
 
-def _kernel_blocks(seq: int, head_dim: int, causal: bool,
+def _kernel_blocks(seq: int, head_dim: int, causal,
                    block_q: Optional[int] = None, block_k: Optional[int] = None,
                    itemsize: int = 2, pairs: bool = False) -> KernelPlan:
     """The one place tile sizes and the form of the schedule are chosen, from
-    what the call observes: `seq`, `head_dim`, `causal`, and `block_q` /
+    what the call observes: `seq`, `head_dim`, `causal` (True, False or a mask
+    by structure, which always runs a pair a program), and `block_q` /
     `block_k` where the caller passes them. Tiles are capped to seq and shrunk
     to a divisor (gcd keeps the largest power-of-two factor), so a default
     works for any seq that has one: S=1536 walks 3 x 3 tiles of 512. `pairs`:
@@ -1039,16 +1174,17 @@ def _kernel_blocks(seq: int, head_dim: int, causal: bool,
         n_q, n_k = seq // tile_q, seq // tile_k
         visited = masked = 0
         for i in range(n_q):
-            diag, end = _diag_and_end(i, tile_q, tile_k, n_k, True) if causal else (n_k, n_k)
-            visited += end
-            masked += end - diag
-        unrolled = (causal and not pairs and max(tile_q, tile_k) <= CAUSAL_TILE
+            tiles = _tiles_under(causal, i, tile_q, tile_k, n_k, True)
+            visited += len(tiles)
+            masked += sum(crossed for _, crossed in tiles)
+        unrolled = (causal is True and not pairs and max(tile_q, tile_k) <= CAUSAL_TILE
                     and visited <= MAX_UNROLLED_TILES
                     and visited * tile_q * tile_k <= MAX_UNROLLED_SCORES
                     and seq * head_dim * itemsize <= MAX_UNROLLED_HEAD_BYTES)
         return KernelPlan(tile_q, tile_k, visited, masked, n_q * n_k, unrolled)
 
-    if causal:
+    assert pairs or isinstance(causal, bool), f"{causal} runs a pair a program alone (`_streams_pairs`)"
+    if causal is True:
         small = plan(CAUSAL_TILE)
         if small.unrolled:
             return small
@@ -1056,14 +1192,16 @@ def _kernel_blocks(seq: int, head_dim: int, causal: bool,
     return plan(LONG_HEAD_TILE if small else FULL_TILE)
 
 
-def _streams_pairs(seq: int, head_dim: int, itemsize: int, kv_heads_fewer: bool, keep: bool) -> bool:
+def _streams_pairs(seq: int, head_dim: int, itemsize: int, kv_heads_fewer: bool, keep: bool, causal=True) -> bool:
     """Whether a call runs both passes a (Q tile, K tile) pair a program
-    (`_flash_pairs`): a head the forward program cannot hold, a selection, or
-    key/value heads shared by a group of query heads."""
-    return keep or kv_heads_fewer or _head_bytes(seq, head_dim, itemsize) > MAX_HEAD_BYTES
+    (`_flash_pairs`): a head the forward program cannot hold, a selection,
+    key/value heads shared by a group of query heads, or a mask other than the
+    causal diagonal (the whole-head forms know that one alone)."""
+    return (keep or kv_heads_fewer or not isinstance(causal, bool)
+            or _head_bytes(seq, head_dim, itemsize) > MAX_HEAD_BYTES)
 
 
-def kernel_plan(shape, causal: bool = True,
+def kernel_plan(shape, causal=True,
                 block_q: Optional[int] = None, block_k: Optional[int] = None,
                 dtype=jnp.bfloat16, kv_heads: Optional[int] = None, keep: bool = False) -> KernelPlan:
     """The schedule the kernels run for q/k/v of `shape` (batch, heads, seq,
@@ -1071,7 +1209,7 @@ def kernel_plan(shape, causal: bool = True,
     selection where `keep`: static, so asking costs nothing per step."""
     _, h, s, d = shape
     itemsize = jnp.dtype(dtype).itemsize
-    pairs = _streams_pairs(s, d, itemsize, kv_heads not in (None, h), keep)
+    pairs = _streams_pairs(s, d, itemsize, kv_heads not in (None, h), keep, causal)
     return _kernel_blocks(s, d, causal, block_q, block_k, itemsize, pairs)
 
 
@@ -1108,7 +1246,7 @@ def flash_attention(
     q,
     k,
     v,
-    causal: bool = True,
+    causal=True,
     sm_scale: Optional[float] = None,
     block_q: Optional[int] = None,
     block_k: Optional[int] = None,
@@ -1121,6 +1259,10 @@ def flash_attention(
     """Multi-head attention, (batch, heads, seq, head_dim) layout; k and v may
     hold fewer heads, each shared by a group of consecutive query heads.
 
+    causal: True (the diagonal), False (every key), or a mask by structure, a
+      static hashable description (`BlockDiffusion`): the kernels' schedules
+      skip the tile pairs it leaves empty and make a crossed pair's mask from
+      iotas, so it costs no operand.
     keep: (batch, seq, spans * 128) int32, a selection of keys for every query,
       shared by the heads and packed a bit a pair (`pack_keep`); with `causal`
       a key is attended to where both allow it. None (every key) runs the
@@ -1153,7 +1295,7 @@ def flash_attention(
                 "which takes no selection and returns no row statistics")
         return blockwise_attention(q, *_repeat_kv(q, k, v), causal=causal, sm_scale=sm_scale)
     pairs = return_lse or _streams_pairs(
-        q.shape[2], q.shape[3], q.dtype.itemsize, k.shape[1] != q.shape[1], keep is not None)
+        q.shape[2], q.shape[3], q.dtype.itemsize, k.shape[1] != q.shape[1], keep is not None, causal)
     plan = _kernel_blocks(q.shape[2], q.shape[3], causal, block_q, block_k, q.dtype.itemsize, pairs)
     if min(plan.tile_q, plan.tile_k) < 128:
         raise ValueError(

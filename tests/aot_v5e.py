@@ -25,6 +25,7 @@ Case names:
     topology                    the described devices' kind
     kernel[:BxHxSxD]            `flash_attention` forward + fused backward (gpt2_small's shapes with no shape)
     selected:BxHxKVxSxD         both flash kernels under a packed `keep`, grouped heads; and with no `keep`
+    masked:BxHxKVxSxDxBLOCK     both flash kernels under `BlockDiffusion(S / 2, BLOCK)`, a mask by structure
     indexer:BxHxKVxSxDxIHxIDxK  `select` and `index_loss` of `ops/lightning_indexer.py`
     row_movers:TOKENS           `gather_rows` and `sum_rows` over a held prefix
     held_experts                an LFM2 step whose expert layer holds 2 of 16 experts
@@ -239,6 +240,26 @@ def _selected_case(topo, batch, heads, kv_heads, seq, d):
             "kernels": sorted(set(re.findall(r"(flash_fwd|flash_bwd)[.\d]* = ", text)))}
 
 
+def _masked_case(topo, batch, heads, kv_heads, seq, d, block):
+    """Both flash kernels under the block-diffusion mask of a doubled row of `seq`: a pair a program, no `keep`."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.ops.flash_attention import BlockDiffusion, _fwd_pairs_plan, flash_attention, kernel_plan
+
+    one = jax.sharding.SingleDeviceSharding(topo.devices[0])
+    q, k = (jax.ShapeDtypeStruct((batch, h, seq, d), jnp.bfloat16, sharding=one) for h in (heads, kv_heads))
+    mask = BlockDiffusion(seq // 2, block)
+    loss = lambda q, k, v: flash_attention(q, k, v, causal=mask, backend="pallas").astype(jnp.float32).sum()
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(q, k, k).compile().as_text()
+    plan = kernel_plan(q.shape, mask, kv_heads=kv_heads)
+    return {"mosaic_calls": text.count("tpu_custom_call"), "plan": list(plan),
+            "forward": list(_fwd_pairs_plan(heads // kv_heads, batch * heads, d, 2, plan)),
+            "scopes": sorted(set(re.findall(r"\b(tiles_\d+of\d+)\b", text))),
+            "kernels": sorted(set(re.findall(r"(flash_fwd|flash_bwd)[.\d]* = ", text))),
+            "words_of_a_selection": len(re.findall(r"s32\[%d,%d,\d+\]" % (batch, seq), text))}
+
+
 def _indexer_case(topo, batch, heads, kv_heads, seq, d, index_heads, index_d, topk):
     """`select` and `index_loss` (with the gradient it keeps) at a cell's shapes."""
     import importlib
@@ -377,6 +398,7 @@ def _step_case(topo, cell):
         "instructions": len(INSTRUCTION.findall(text)),
         "argument": mem.argument_size_in_bytes, "temp": mem.temp_size_in_bytes,
         "output": mem.output_size_in_bytes, "alias": mem.alias_size_in_bytes,
+        "peak": getattr(mem, "peak_memory_in_bytes", None),
         "mosaic_scopes": [scopes.get(INSTRUCTION.match(line).group(1), "") for line in mosaic],
         "phases": sorted({phase(n) for n in scopes.values()}),
         "recomputed": sum("rematted_computation" in n.split("/") for n in scopes.values()),
@@ -398,6 +420,8 @@ def _case(topo, case):
         return _kernel_case(topo, *([numbers()] if rest else []))
     if name == "selected":
         return _selected_case(topo, *numbers())
+    if name == "masked":
+        return _masked_case(topo, *numbers())
     if name == "indexer":
         return _indexer_case(topo, *numbers())
     if name == "row_movers":

@@ -34,6 +34,8 @@ SELECTED_16K = "selected:1x32x4x16384x128"
 # and a llama-like 8 on 2 heads of 2,048 x 64, which streams pairs for its shared key/value heads alone.
 STREAMED_HEADS = ("kernel:1x8x16384x128", "kernel:1x8x8192x256")
 GROUPED_2K = "selected:2x8x2x2048x64"
+# The SDAR cell's attention (PR 47): a clean and a noised copy of 8,192 under the block-diffusion mask, blocks of 4.
+MASKED_16K = "masked:1x32x4x16384x128x4"
 INDEXER_16K = "indexer:1x32x4x16384x128x16x64x2048"
 BETWEEN_HEADS = ("kernel:2x4x3072x256", "kernel:2x4x3584x256", "kernel:2x4x6144x128",
                  "kernel:2x4x7168x128", "kernel:2x4x2048x256")
@@ -50,7 +52,7 @@ def aot():
     return aot_v5e.Cases(
         ["kernel", LONG_HEAD_64, WIDE_HEAD_256, *LONGER_HEADS, *BETWEEN_HEADS, *STREAMED_HEADS, "lower:d4", "lower:d2t2"],
         ["held_experts", *ROW_MOVERS],
-        [SELECTED_16K, INDEXER_16K, GROUPED_2K])
+        [SELECTED_16K, INDEXER_16K, GROUPED_2K, MASKED_16K])
 
 
 def test_topology_is_the_v5e(aot):
@@ -115,6 +117,16 @@ def test_the_streamed_forward_takes_four_equal_heads_a_program(aot, case, plan, 
     assert aot[case]["mosaic_calls"] == 2 and aot[case]["plan"] == plan
     _, heads, seq, d = (int(n) for n in case[len("kernel:"):].split("x"))
     assert list(fa._fwd_pairs_plan(1, heads, d, 2, fa.kernel_plan((1, heads, seq, d)))) == forward
+
+
+def test_both_flash_kernels_walk_the_block_diffusion_masks_live_pairs_at_16384_by_128(aot):
+    """`(1, 32 on 4, 16384, 128)` bf16 under `BlockDiffusion(8192, 4)`: the two pair-streamed programs of the Keye
+    cell's call with no selection operand (no int32 words a (row, query) in the program), a crossed pair's mask
+    made from iotas, shifts and compares in the kernel, over 160 of the 512 pairs of 512 x 1,024."""
+    got = aot[MASKED_16K]
+    assert got["mosaic_calls"] == 2 and got["kernels"] == ["flash_bwd", "flash_fwd"]
+    assert got["plan"] == [512, 1024, 160, 48, 512, False] and got["scopes"] == ["tiles_160of512"]
+    assert got["forward"] == [8, 512] and got["words_of_a_selection"] == 0
 
 
 def test_grouped_heads_of_2048_by_64_stream_pairs_with_and_without_a_selection(aot):

@@ -4,6 +4,8 @@
     chiprun -- python3 tools/flash_bench.py --shapes 32x2048x128 --tiles 512,256,128x256
     chiprun -- python3 tools/flash_bench.py --shapes 32x16384x128 --kv-heads 4 --keep-topk 2048 \
         --tiles plan --part whole,products,softmax,empty    # the Keye cell's call (PERF.md section 6, PR 44)
+    chiprun -- python3 tools/flash_bench.py --shapes 32x16384x128 --kv-heads 4 --block-diffusion 4 \
+        --tiles plan    # the SDAR cell's call: two copies of 8,192 under the block-diffusion mask (PR 47)
 
 For each shape (batch*heads x seq x head_dim, bf16) and each tile size
 (`block_q = block_k`, or `QxK`), the forward kernel and forward + backward
@@ -18,7 +20,10 @@ dispatches closed by `block_until_ready`; it carries each program's launch
 `--kv-heads N` gives k and v N heads for the shape's BH query heads, and
 `--keep-topk K` a selection from `--seed` (`pack_keep`): for every query K keys
 of its past, or all of it where it is shorter, the cell's kind of `keep`; both
-send the call to the pair-streamed kernels. `--part` times the forward kernel's
+send the call to the pair-streamed kernels. `--block-diffusion B` reads the shape's SEQ as a clean and a noised
+copy of SEQ / 2 in blocks of B and runs the kernels under `BlockDiffusion` (a mask by structure, `causal=`), and
+adds to the line the kept pairs a head and each pass's share of the MXU's peak on them (`fwd_mxu_pct`, two products
+forward; `bwd_mxu_pct`, four backward: the compute floor of `kernels.flash_roofline`). `--part` times the forward kernel's
 halves alone, by standing a stub where the other is (`_softmax_step`: the scores
 cast and no statistic; `_scores_t` / `_values_t`: a constant and a sum of eight
 rows): `products` its two products (`scores`, `values`: the first, the second
@@ -94,9 +99,11 @@ def _check(jax, jnp, fa, bh, kv_heads, seq, d, args):
     topk = min(args.keep_topk, seq // 4)
     extra = {"keep": _selection(jax, jnp, fa, seq, topk, args.seed)} if topk else {}
 
+    causal = fa.BlockDiffusion(seq // 2, args.block_diffusion) if args.block_diffusion else not args.non_causal
+
     def run(attn, **kw):
         def f(q, k, v):
-            o, lse = attn(q, k, v, causal=not args.non_causal, return_lse=True, **extra, **kw)
+            o, lse = attn(q, k, v, causal=causal, return_lse=True, **extra, **kw)
             return (o.astype(jnp.float32) * do.astype(jnp.float32)).sum(), (o, lse)
         grads, (o, lse) = jax.jit(jax.grad(f, argnums=(0, 1, 2), has_aux=True))(q, k, v)
         return {"o": o, "lse": lse, **dict(zip(("dq", "dk", "dv"), grads))}
@@ -221,6 +228,8 @@ def main(argv=None):
     ap.add_argument("--non-causal", action="store_true")
     ap.add_argument("--kv-heads", type=int, default=0, help="key/value heads under the shape's BH query heads (0: as many)")
     ap.add_argument("--keep-topk", type=int, default=0, help="a selection of this many keys a query (0: none)")
+    ap.add_argument("--block-diffusion", type=int, default=0,
+                    help="blocks of this many tokens: SEQ is two copies of SEQ / 2 under the block-diffusion mask (0: none)")
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--part", default="whole", help=",".join(PARTS) + ", comma separated")
     ap.add_argument("--fwd", default="plan",
@@ -266,6 +275,7 @@ def main(argv=None):
     for shape in args.shapes.split(","):
         bh, seq, d = (int(n) for n in shape.split("x"))
         kv_heads = args.kv_heads or bh
+        mask = fa.BlockDiffusion(seq // 2, args.block_diffusion) if args.block_diffusion else causal
         if args.check_seq and hasattr(fa, "pack_keep"):
             emit(_check(jax, jnp, fa, bh, kv_heads, min(args.check_seq, seq), d, args))
         keys = jax.random.split(jax.random.PRNGKey(args.seed), 4)
@@ -277,7 +287,7 @@ def main(argv=None):
         the_plan = getattr(fa, "_fwd_pairs_plan", None)
         for tile_q, tile_k, forward, part in ((*_parse_tile(t), f, part) for t in args.tiles.split(",")
                                               for f in args.fwd.split(",") for part in args.part.split(",")):
-            about = {"shape": [bh, seq, d], **what, "causal": causal, "block_q": tile_q, "block_k": tile_k, "part": part}
+            about = {"shape": [bh, seq, d], **what, "causal": str(mask) if args.block_diffusion else causal, "block_q": tile_q, "block_k": tile_k, "part": part}
             if part not in PARTS or (part != "whole" and not halves):
                 print(json.dumps({**about, "error": "this checkout's forward has no such half to time alone"}), flush=True)
                 continue
@@ -286,7 +296,7 @@ def main(argv=None):
                 fa._fwd_pairs_plan = lambda *_, asked=asked: asked
                 about["fwd_plan"] = list(asked)
             attn = lambda q, k, v, tq=tile_q, tk=tile_k: fa.flash_attention(
-                q, k, v, causal=causal, backend="pallas", block_q=tq, block_k=tk, **extra)
+                q, k, v, causal=mask, backend="pallas", block_q=tq, block_k=tk, **extra)
             fwd = jax.jit(attn)
             # A half alone leaves nothing a backward pass could use.
             both = (jax.jit(lambda q, k, v, do, attn=attn: jax.vjp(attn, q, k, v)[1](do))
@@ -353,9 +363,17 @@ def main(argv=None):
             tiles = (c["about"]["block_q"], c["about"]["block_k"])
             if hasattr(fa, "kernel_plan"):
                 selected = {"kv_heads": kv_heads, "keep": bool(args.keep_topk)} if extra or kv_heads != bh else {}
-                plan = fa.kernel_plan((1, bh, seq, d), causal, *tiles, **selected)
+                plan = fa.kernel_plan((1, bh, seq, d), mask, *tiles, **selected)
                 line["plan"] = plan._asdict()
-                if hasattr(fa, "_fwd_pairs_plan") and fa._streams_pairs(seq, d, 2, kv_heads != bh, bool(extra)):
+                if args.block_diffusion:
+                    n = seq // 2 // mask.block
+                    line["kept_pairs"] = (n * n + n) * mask.block ** 2
+                    for name, products in (("fwd", 2), ("bwd", 4)):
+                        if name + "_us" in line:
+                            floor_us = products * 2 * d * line["kept_pairs"] * bh / 197e12 * 1e6  # the v5e's bf16 peak
+                            line[name + "_mxu_pct"] = round(100 * floor_us / line[name + "_us"], 2)
+                if hasattr(fa, "_fwd_pairs_plan") and (args.block_diffusion or fa._streams_pairs(
+                        seq, d, 2, kv_heads != bh, bool(extra))):
                     # What the pair-streamed forward takes for itself: (query heads a program, its Q tile).
                     line.setdefault("fwd_plan", list(fa._fwd_pairs_plan(bh // kv_heads, bh, d, 2, plan)))
             emit(line)
