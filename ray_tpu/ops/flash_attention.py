@@ -52,6 +52,18 @@ Design (pallas_guide.md playbook):
    any other mask runs both passes a pair a program. Block diffusion's row of
    16,384 (two copies of 8,192, blocks of 4) walks 160 of 512 pairs of 512 x
    1,024 where the causal diagonal walks 272 (PERF.md section 6, PR 47).
+ - a crossed pair's live span: both pair-streamed schedules name, for every
+   pair the mask crosses, the run of 128-key blocks of its K tile outside which
+   the mask keeps no score of any query of its Q tile (`_live_span`, asked of
+   the mask itself, whichever it is), and the kernels' products, mask and
+   exponentials run over that run alone: the forward's loop over key blocks
+   takes its bounds from the schedule, the backward slices k, v, dk and dv.
+   What is skipped is what the mask masks, so no output changes. At 512 x
+   1,024 half of the causal diagonal's crossed pairs and two thirds of block
+   diffusion's hold their live scores in half their keys (`keys_<scored>of
+   <walked>` beside `tiles_...` in the call's scope: 2,112 of 2,176 blocks and
+   1,152 of 1,280); at square tiles every span is the whole tile and the
+   kernels are the programs they were (PERF.md section 6, PR 48).
  - matmuls run on the MXU with preferred_element_type=float32; inputs can be
    bfloat16.
 
@@ -151,11 +163,10 @@ MAX_STREAMED_HEAD_BYTES = 16384 * 128 * 2
 # `flash_bwd` (its f32 dq scratch alone is 8 MiB), a K tile of 2,048 in
 # `flash_bwd` at any Q tile.
 # The forward since PR 44 (`_fwd_pairs_kernel`; tools/flash_bench.py --tiles
-# plan, one call at 512 x 1024, forward us, the form it replaced / this one;
-# the backward is untouched, 34,854 with a selection): 32 heads on 4 with a
-# selection of 2,048 keys a query (--kv-heads 4 --keep-topk 2048), 8 heads x
-# 512 queries a program: 23,761 / 15,804, where the MXU needs 11,860 for the
-# 272 walked pairs; the same without a selection 17,960 / 15,181; 32 equal
+# plan, one call at 512 x 1024, forward us, the form it replaced / this one): 32
+# heads on 4 with a selection of 2,048 keys a query (--kv-heads 4 --keep-topk
+# 2048), 8 heads x 512 queries a program: 23,761 / 15,804, where the MXU needs
+# 11,860 for the 272 walked pairs; the same without a selection 17,960 / 15,181; 32 equal
 # heads, four and their key/value heads a program, 17,879 / 15,796 (one a
 # program, every block unrolled: 20,011); (8, 8192, 256) at 512-tiles 2,228 /
 # 1,869. By --fwd: 8 x 256 15,068 where 8 x 512 was 14,010. By --part at 8 x
@@ -163,14 +174,18 @@ MAX_STREAMED_HEAD_BYTES = 16384 * 128 * 2
 # softmax alone 9,938, neither 4,661: the MXU binds, the vector work hides
 # behind it, and the loop over key blocks is the rest (unrolled: 14,026 whole,
 # 13,933 the products, 2,375 neither).
-# Under a mask by structure, block diffusion's (tools/flash_bench.py --block-diffusion 4, PR 47; one call at (32 on 4,
-# 16384, 128), two copies of 8,192 in blocks of 4, forward / backward, us): 512 x 1024 9,562 / 21,368 over the 160 live
-# pairs of 512, 48 of them crossed: 58.4 % and 52.3 % of the MXU's peak on the 67.1 M kept pairs a head, the floor
-# `kernels.flash_roofline` reads by. The causal walk of the same row in the same call takes 15,176 / 33,874 over 272
-# pairs, 32 crossed: a pair costs 55.8 / 124.5 there and 59.8 / 133.5 here, the crossed pairs' dearer mask (two
-# compares of shifted iotas where the diagonal's is one, in 30 % of the pairs where 12 % were) and a Q tile's shorter
-# runs. 512-tiles 8,613 / 22,972 over 288 pairs (the forward 10 % faster, the backward 7.5 % slower: the plan's tile
-# stays); 256 x 1024 9,493 / 23,548 over 320.
+# Since PR 48 both kernels score a crossed pair over its live span of keys alone (`_live_span`; one call at (32 on 4,
+# 16384, 128) and 512 x 1024, forward / backward, us, every key of every pair / the spans): with that selection
+# (--kv-heads 4 --keep-topk 2048) 15,805 / 34,854 -> 15,415 / 34,218, without one (--kv-heads 4) 15,180 / 33,874 ->
+# 14,757 / 33,237, over 272 pairs, 32 crossed, 2,112 of their 2,176 blocks of 128 keys scored. Under a mask by
+# structure, block diffusion's (--kv-heads 4 --block-diffusion 4: two copies of 8,192 in blocks of 4): 9,562 / 21,368 ->
+# 8,560 / 19,455 over the 160 live pairs of 512, 48 of them crossed, 1,152 of 1,280 blocks scored: 65.2 % and 57.4 % of
+# the MXU's peak on the 67.1 M kept pairs a head, the floor `kernels.flash_roofline` reads by. A scored block costs 6.99
+# / 15.74 under the diagonal and 7.43 / 16.89 here: the crossed pairs' dearer mask (two compares of shifted iotas where
+# the diagonal's is one, in 30 % of the pairs where 12 % were) and a Q tile's shorter runs. The backward's noised
+# diagonal by its four live 128 x 128 squares of sixteen (a third form of the pair): 19,253, 1.0 ms of a step of 394: left out.
+# Before the spans (PR 47): 512-tiles 8,613 / 22,972 over 288 pairs (the forward 10 % faster, the backward 7.5 % slower:
+# the plan's tile stays; every span is whole there); 256 x 1024 9,493 / 23,548 over 320.
 PAIRS_TILE_K = 1024
 NEG_INF = -1e30
 # Where the pair-streamed forward's running maximum starts (`_fwd_pairs_kernel`):
@@ -372,17 +387,41 @@ def _tiles_under(mask, t: int, size: int, other: int, n_other: int, t_is_q: bool
     return out
 
 
-def _kept_in_pair(mask, i, j, tile_q: int, tile_k: int, shape, q_axis: int):
+def _keeps_some(mask, r0: int, r1: int, c0: int, c1: int) -> bool:
+    """Whether `mask` (True: the diagonal; else a mask by structure) keeps a score of queries [r0, r1) x keys [c0, c1)."""
+    return c0 < r1 if mask is True else mask.tile_class(r0, r1, c0, c1) != EMPTY
+
+
+def _span_keys(tile_k: int) -> int:
+    """The block of keys a pair's live span is counted in: the forward's step down a K tile."""
+    return math.gcd(tile_k, FWD_STEP_KEYS)
+
+
+def _live_span(mask, i: int, j: int, tile_q: int, tile_k: int, crossed: bool):
+    """(first block, blocks) of `_span_keys`: the run of K tile j outside which `mask` keeps no score of any
+    query of Q tile i, asked of the mask itself a block of keys at a time. The whole tile for a pair that is
+    not crossed (and for most that are: every square pair of the causal diagonal)."""
+    unit = _span_keys(tile_k)
+    if not crossed:
+        return 0, tile_k // unit
+    live = [b for b in range(tile_k // unit) if _keeps_some(
+        mask, i * tile_q, (i + 1) * tile_q, j * tile_k + b * unit, j * tile_k + (b + 1) * unit)]
+    return live[0], live[-1] + 1 - live[0]
+
+
+def _kept_in_pair(mask, i, j, tile_q: int, tile_k: int, shape, q_axis: int, start=None):
     """bool `shape`: the scores of the crossed pair (Q tile i, K tile j) that
     `mask` keeps, queries along `q_axis` of the tile and keys along the other,
-    from iotas and the pair's offsets (scalars of the kernel)."""
+    from iotas and the pair's offsets (scalars of the kernel); with `start`, of
+    the pair's keys from that one of the K tile on (its live span's first)."""
     rows = jax.lax.broadcasted_iota(jnp.int32, shape, q_axis)
     cols = jax.lax.broadcasted_iota(jnp.int32, shape, 1 - q_axis)
+    k0 = lambda: j * tile_k if start is None else j * tile_k + start
     if isinstance(mask, bool):
         # The diagonal: `row - col` inside a tile is one constant a program, a pair adds its offset. (A call
         # that is not causal traces a crossed pair's branch too, and never takes it.)
-        return rows - cols >= j * tile_k - i * tile_q
-    return mask.kept(rows + i * tile_q, cols + j * tile_k)
+        return rows - cols >= k0() - i * tile_q
+    return mask.kept(rows + i * tile_q, cols + k0())
 
 
 def _for(lo, hi, body, carry, static):
@@ -419,13 +458,21 @@ def _causal_mask(tile_q, tile_k):
     return keep
 
 
-def _keep_tile(keep_ref, j, tile_k):
+def _first_bit(j, tile_k):
+    """The bit of a packed selection's words that holds the first 128 keys of K tile `j` (`KEEP_SPAN`)."""
+    return (j % (KEEP_SPAN // tile_k)) * (tile_k // LANES)
+
+
+def _keep_tile(keep_ref, j, tile_k, start=None, keys=None):
     """The selection's (tile_q, tile_k) bool for K tile `j`, out of the packed
     (1, tile_q, 128) block that holds it: tile_k / 128 bits of every word, a
-    lane tile each."""
+    lane tile each. With `start` (a scalar of the kernel, whole lane tiles) and
+    `keys`: of the tile's `keys` keys from that one on, (tile_q, keys)."""
     bits = tile_k // LANES
     words = keep_ref[0]
-    first = (j % (KEEP_SPAN // tile_k)) * bits
+    first = _first_bit(j, tile_k)
+    if start is not None:
+        first, bits = first + start // LANES, keys // LANES
     return jnp.concatenate([(words >> (first + b)) & 1 for b in range(bits)], axis=1) != 0
 
 
@@ -553,9 +600,10 @@ def _fwd(q, k, v, causal, sm_scale, plan, interpret):
 
 
 def _fwd_schedule(seq: int, plan: "KernelPlan", causal):
-    """The pair-streamed forward pass's steps, int32 (5, steps): for each the Q
-    tile, the K tile, and whether it is the Q tile's first pair, a pair the
-    mask crosses, the Q tile's last pair. Q tiles in turn, under each the K
+    """The pair-streamed forward pass's steps, int32 (7, steps): for each the Q
+    tile, the K tile, whether it is the Q tile's first pair, a pair the
+    mask crosses, the Q tile's last pair, and the pair's live span of keys
+    (`_live_span`: first block, blocks). Q tiles in turn, under each the K
     tiles the mask leaves it (`_tiles_under`: on or under the diagonal when
     causal): a Q tile's output block is the same over its run of steps and is
     written in the last."""
@@ -564,14 +612,24 @@ def _fwd_schedule(seq: int, plan: "KernelPlan", causal):
     for i in range(n_q):
         tiles = _tiles_under(causal, i, plan.tile_q, plan.tile_k, n_k, True)
         assert tiles, f"Q tile {i} keeps no key under {causal}: its output would never be written"
-        steps += [(i, j, n == 0, crossed, n == len(tiles) - 1) for n, (j, crossed) in enumerate(tiles)]
+        steps += [(i, j, n == 0, crossed, n == len(tiles) - 1, *_live_span(causal, i, j, plan.tile_q, plan.tile_k, crossed))
+                  for n, (j, crossed) in enumerate(tiles)]
     return np.asarray(steps, np.int32).T
 
 
-def _walk_scope(steps, seq: int, tile_q: int, tile_k: int) -> str:
-    """`KernelPlan.scope` from a pair-streamed schedule itself: the steps a program of the call walks (its
-    grid's second axis) of the tile pairs of the square, at the tiles the call runs."""
-    return f"tiles_{steps.shape[1]}of{(seq // tile_q) * (seq // tile_k)}"
+def _walk_scope(steps, seq: int, tile_q: int, tile_k: int):
+    """(`KernelPlan.scope` from a pair-streamed schedule itself, what the walk scores), the two `jax.named_scope`s
+    round such a call: the steps a program of the call walks (its grid's second axis) of the tile pairs of the
+    square, at the tiles the call runs, and the blocks of keys (`_span_keys`) inside the steps' live spans of those
+    the steps hold."""
+    walked = steps.shape[1] * (tile_k // _span_keys(tile_k))
+    return f"tiles_{steps.shape[1]}of{(seq // tile_q) * (seq // tile_k)}", f"keys_{steps[-1].sum()}of{walked}"
+
+
+def _short_spans(steps, tile_k: int):
+    """The lengths, in keys, of a schedule's live spans that are shorter than the K tile: () where every pair
+    scores its whole tile, and the kernels are then the programs they were before a schedule named a span."""
+    return tuple(sorted({int(n) * _span_keys(tile_k) for n in steps[-1]} - {tile_k}))
 
 
 def _keep_tile_t(keep_ref, j, tile_k):
@@ -579,7 +637,7 @@ def _keep_tile_t(keep_ref, j, tile_k):
     across: bit b of the turned words is a (128, tile_q) slab of keys."""
     bits = tile_k // LANES
     words = keep_ref[0].T  # (128, tile_q)
-    first = (j % (KEEP_SPAN // tile_k)) * bits
+    first = _first_bit(j, tile_k)
     return jnp.concatenate([(words >> (first + b)) & 1 for b in range(bits)], axis=0) != 0
 
 
@@ -616,7 +674,8 @@ def _block_step(k, v_t, qs, bias, m, l, acc):
     return m, l, acc * alpha + _values_t(v_t, p.astype(v_t.dtype))
 
 
-def _fwd_pairs_kernel(steps_ref, q_ref, k_ref, v_ref, *refs, sm_scale, tile_q, tile_k, has_keep, mask=True):
+def _fwd_pairs_kernel(steps_ref, q_ref, k_ref, v_ref, *refs, sm_scale, tile_q, tile_k, has_keep, mask=True,
+                      a_trip, spans=False):
     """The forward pass with a program a (Q tile, K tile) pair (`_fwd_schedule`,
     grid axis 1, sequential) of the `heads` consecutive query heads that the
     program takes (`q_ref` (1, heads, tile_q, d), held once a Q tile and scaled
@@ -636,14 +695,22 @@ def _fwd_pairs_kernel(steps_ref, q_ref, k_ref, v_ref, *refs, sm_scale, tile_q, t
     tile_q) tile is stored between its maximum and its exponentials and loaded
     again. A head's blocks of the same queries are a chain; the chains of a
     program are independent and lie side by side in a trip of the loop over
-    key blocks, where the compiler interleaves them so that the MXU does not
-    wait for the vector unit (PERF.md section 6, PR 44)."""
+    key blocks (`a_trip` of them a trip), where the compiler interleaves them
+    so that the MXU does not wait for the vector unit (PERF.md section 6, PR 44).
+
+    `spans`: the schedule names a live span of keys shorter than the K tile
+    for some pair (`_short_spans`). A masked pair's mask is then made and its
+    key blocks walked over its span alone, by loops whose bounds are the
+    schedule's values: outside it every score is masked, and its exponential
+    is 0.0 against any maximum from `FLOOR` up, so nothing of o or lse changes."""
     keep_ref = refs[0] if has_keep else None
     o_ref, lse_ref, qs, m_acc, l_acc, o_acc, bias = refs[has_keep:]
     heads, kv_heads = q_ref.shape[1], k_ref.shape[1]
     t = pl.program_id(1)
     i, j = steps_ref[0, t], steps_ref[1, t]
     first, crossed, last = (steps_ref[r, t] == 1 for r in (2, 3, 4))
+    width, depth = math.gcd(tile_q, FWD_STEP_QUERIES), _span_keys(tile_k)
+    span = (steps_ref[5, t], steps_ref[5, t] + steps_ref[6, t]) if spans else (0, tile_k // depth)  # key blocks
 
     @pl.when(first)
     def _():
@@ -656,6 +723,18 @@ def _fwd_pairs_kernel(steps_ref, q_ref, k_ref, v_ref, *refs, sm_scale, tile_q, t
 
     def mask_to_bias(diagonal):
         def run():
+            if diagonal and spans:  # the span's rows alone, a block of keys at a time: the loop reads no others
+                words = keep_ref[0].T if has_keep else None  # `_keep_tile_t`, a bit at a time
+
+                def block(b, carry):
+                    kept = _kept_in_pair(mask, i, j, tile_q, tile_k, (depth, tile_q), 1, b * depth)
+                    if has_keep:
+                        kept &= ((words >> (_first_bit(j, tile_k) + b)) & 1) != 0
+                    bias[pl.ds(pl.multiple_of(b * depth, depth), depth)] = jnp.where(kept, 0.0, NEG_INF)
+                    return carry
+
+                jax.lax.fori_loop(*span, block, 0)
+                return
             kept = _keep_tile_t(keep_ref, j, tile_k) if has_keep else None
             if diagonal:
                 under = _kept_in_pair(mask, i, j, tile_q, tile_k, (tile_k, tile_q), 1)
@@ -665,11 +744,7 @@ def _fwd_pairs_kernel(steps_ref, q_ref, k_ref, v_ref, *refs, sm_scale, tile_q, t
 
     def heads_of_pair(masked):
         def run():
-            width, depth = math.gcd(tile_q, FWD_STEP_QUERIES), math.gcd(tile_k, FWD_STEP_KEYS)
-            steps, chains = tile_k // depth, heads * (tile_q // width)
-            # Key blocks a trip: as many as keep a trip's blocks at `FWD_BLOCKS_A_TRIP` or fewer, and one at least.
-            a_trip = max(n for n in range(1, steps + 1)
-                         if steps % n == 0 and n * chains <= max(FWD_BLOCKS_A_TRIP, chains))
+            lo, hi = span if masked else (0, tile_k // depth)  # every key of a pair with no mask is live
 
             def trip(n, carry):
                 for u in range(a_trip):
@@ -685,7 +760,7 @@ def _fwd_pairs_kernel(steps_ref, q_ref, k_ref, v_ref, *refs, sm_scale, tile_q, t
                                 m_acc[row, cols], l_acc[row, cols], o_acc[h, :, cols])
                 return carry
 
-            jax.lax.fori_loop(0, steps // a_trip, trip, 0)
+            jax.lax.fori_loop(lo // a_trip, hi // a_trip, trip, 0)
         return run
 
     if has_keep:  # every pair is masked: one body, behind whichever mask the pair has
@@ -770,6 +845,14 @@ def _fwd_pairs(q, k, v, keep, causal, sm_scale, plan, interpret):
     heads, tile_q = _fwd_pairs_plan(group, row_heads, d, q.dtype.itemsize, plan)
     tile_k = plan.tile_k
     steps = _fwd_schedule(seq, plan._replace(tile_q=tile_q), causal)
+    scopes, spans = _walk_scope(steps, seq, tile_q, tile_k), bool(_short_spans(steps, tile_k))
+    # Key blocks a trip of the kernel's loop: as many as keep a trip's blocks at `FWD_BLOCKS_A_TRIP` or fewer, one at
+    # least, and a number that divides every live span of the schedule, so that a trip lies inside one.
+    blocks, chains = tile_k // _span_keys(tile_k), heads * (tile_q // math.gcd(tile_q, FWD_STEP_QUERIES))
+    a_trip = max(n for n in range(1, blocks + 1) if blocks % n == 0 and not (steps[5:] % n).any()
+                 and n * chains <= max(FWD_BLOCKS_A_TRIP, chains))
+    if not spans:  # the kernel reads the rows it read before a schedule named a span
+        steps = steps[:5]
     # Grid axis 0: `heads` query heads a program, on `kv_heads` key/value heads of their own or on one
     # that `parts` programs share.
     programs, kv_heads, parts = bh // heads, max(heads // group, 1), max(group // heads, 1)
@@ -782,10 +865,10 @@ def _fwd_pairs(q, k, v, keep, causal, sm_scale, plan, interpret):
         in_specs.append(_keep_spec(programs // keep.shape[0], tile_q, tile_k, 0, 1))
         operands.append(keep)
     # `group_<n>`: which form a trace's `flash_fwd` ran (the heads a program takes).
-    with jax.named_scope(_walk_scope(steps, seq, tile_q, tile_k)), jax.named_scope(f"group_{heads}"):
+    with jax.named_scope(scopes[0]), jax.named_scope(scopes[1]), jax.named_scope(f"group_{heads}"):
         o, lse = pl.pallas_call(
             functools.partial(_fwd_pairs_kernel, sm_scale=sm_scale, tile_q=tile_q, tile_k=tile_k,
-                              has_keep=keep is not None, mask=causal),
+                              has_keep=keep is not None, mask=causal, a_trip=a_trip, spans=spans),
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=1,
                 grid=(programs, steps.shape[1]),
@@ -894,10 +977,11 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 
 def _pair_schedule(seq: int, plan: "KernelPlan", causal):
-    """The pair-streamed backward pass's steps, int32 (6, steps): for each the
-    Q tile, the K tile, the dq tile its output block is, and whether it is the
+    """The pair-streamed backward pass's steps, int32 (8, steps): for each the
+    Q tile, the K tile, the dq tile its output block is, whether it is the
     K tile's first pair, a pair the mask crosses, the pair after which its
-    Q tile's dq is whole. K tiles in turn, under each the Q tiles the mask
+    Q tile's dq is whole, and the pair's live span of keys (`_live_span`:
+    first block, blocks). K tiles in turn, under each the Q tiles the mask
     leaves it (`_tiles_under`: on or under the diagonal when causal): a Q
     tile's last pair comes later the later the tile (asserted: a mask on or
     under a block-causal diagonal has it so), so the dq block of a step is the
@@ -912,20 +996,27 @@ def _pair_schedule(seq: int, plan: "KernelPlan", causal):
     assert sorted(whole_at.values()) == [whole_at[i] for i in range(n_q)], f"no schedule under {causal}"
     steps, due = [], 0
     for t, (i, j, first, masked) in enumerate(pairs):
-        steps.append((i, j, due, first, masked, whole_at[i] == t))
+        steps.append((i, j, due, first, masked, whole_at[i] == t,
+                      *_live_span(causal, i, j, plan.tile_q, plan.tile_k, masked)))
         due = min(due + (whole_at[due] == t), n_q - 1)
     return np.asarray(steps, np.int32).T
 
 
 def _bwd_pairs_kernel(steps_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *refs,
-                      sm_scale, tile_q, tile_k, has_keep=False, mask=True):
+                      sm_scale, tile_q, tile_k, has_keep=False, mask=True, short_spans=()):
     """The fused backward pass with a program a (Q tile, K tile) pair
     (`_pair_schedule`, grid axis 1, sequential): every operand is the pair's
     own tile, streamed by the pipeline, and all a head keeps in VMEM is its
     f32 dq (seq, d) beside the K tile's f32 dk and dv. `keep_ref` (with
     `has_keep`) is the pair's tile of the packed selection, which then masks
     every pair, beside the diagonal where it crosses; `mask` is what a crossed
-    pair's own mask is made from (`_kept_in_pair`)."""
+    pair's own mask is made from (`_kept_in_pair`). `short_spans`: the lengths
+    of the schedule's live spans shorter than the K tile (`_short_spans`). A
+    crossed pair of such a span runs its products, its mask and its
+    exponentials over those keys alone, a slice of k, v, dk and dv that starts
+    where the schedule says: every score outside it is masked and its p is
+    exp(`NEG_INF` - lse) = 0.0, so nothing of dq, dk or dv changes. One more
+    static form of the pair a length; none where every span is the whole tile."""
     keep_ref = refs[0] if has_keep else None
     dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc = refs[has_keep:]
     t = pl.program_id(1)
@@ -942,18 +1033,27 @@ def _bwd_pairs_kernel(steps_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    def pair(crossed):
+    def pair(crossed, keys=tile_k):
         def run():
-            keep = _kept_in_pair(mask, i, j, tile_q, tile_k, (tile_q, tile_k), 0) if crossed else None
+            start, cols = None, ...
+            if keys != tile_k:  # the live span: `keys` keys of the tile from `start` on
+                start = pl.multiple_of(steps_ref[6, t] * _span_keys(tile_k), _span_keys(tile_k))
+                cols = pl.ds(start, keys)
+            keep = _kept_in_pair(mask, i, j, tile_q, tile_k, (tile_q, keys), 0, start) if crossed else None
             if has_keep:
-                kept = _keep_tile(keep_ref, j, tile_k)
+                kept = _keep_tile(keep_ref, j, tile_k, start, keys)
                 keep = kept if keep is None else keep & kept
-            dk_acc[...], dv_acc[...] = _pair_grads(
-                q_ref, do_ref, lse_ref, delta_ref, dq_acc, slice(None), acc_rows, k_ref[0], v_ref[0],
-                keep, sm_scale, dk_acc[...], dv_acc[...])
+            dk_acc[cols], dv_acc[cols] = _pair_grads(
+                q_ref, do_ref, lse_ref, delta_ref, dq_acc, slice(None), acc_rows, k_ref[0, cols], v_ref[0, cols],
+                keep, sm_scale, dk_acc[cols], dv_acc[cols])
         return run
 
-    pl.when(masked)(pair(True))
+    if short_spans:
+        scored = steps_ref[7, t] * _span_keys(tile_k)  # the keys of the pair's live span
+        for keys in (*short_spans, tile_k):
+            pl.when(jnp.logical_and(masked, scored == keys))(pair(True, keys))
+    else:
+        pl.when(masked)(pair(True))
     pl.when(jnp.logical_not(masked))(pair(False))
 
     @pl.when(whole)
@@ -977,16 +1077,19 @@ def _bwd_pairs(q, k, v, do, lse, delta, causal, sm_scale, plan, interpret, keep=
     still come back a query head each, for the caller to sum over the group."""
     bh, seq, d = q.shape
     steps = _pair_schedule(seq, plan, causal)
+    scopes, short_spans = _walk_scope(steps, seq, plan.tile_q, plan.tile_k), _short_spans(steps, plan.tile_k)
+    if not short_spans:  # the kernel reads the rows it read before a schedule named a span
+        steps = steps[:6]
     tile = lambda size, width, row: pl.BlockSpec(
         (1, size, width), lambda b, t, steps: (b, steps[row, t], 0))
     q_tile, k_tile, stat = tile(plan.tile_q, d, 0), tile(plan.tile_k, d, 1), tile(plan.tile_q, 1, 0)
     kv_tile = k_tile if k.shape[0] == bh else _pair_specs(plan, d, bh // k.shape[0], 0, 1)[1]
     extra = [] if keep is None else [keep]
     keep_specs = [_keep_spec(bh // keep.shape[0], plan.tile_q, plan.tile_k, 0, 1)] if extra else []
-    with jax.named_scope(_walk_scope(steps, seq, plan.tile_q, plan.tile_k)):
+    with jax.named_scope(scopes[0]), jax.named_scope(scopes[1]):
         return pl.pallas_call(
             functools.partial(_bwd_pairs_kernel, sm_scale=sm_scale, tile_q=plan.tile_q, tile_k=plan.tile_k,
-                              has_keep=keep is not None, mask=causal),
+                              has_keep=keep is not None, mask=causal, short_spans=short_spans),
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=1,
                 grid=(bh, steps.shape[1]),

@@ -237,6 +237,7 @@ def _selected_case(topo, batch, heads, kv_heads, seq, d):
             "plan": list(kernel_plan(q.shape, kv_heads=kv_heads, keep=True)),
             "forward": list(_fwd_pairs_plan(heads // kv_heads, heads, d, 2, kernel_plan(q.shape, kv_heads=kv_heads, keep=True))),
             "forward_scopes": sorted(set(re.findall(r"/(group_\d+)/flash_fwd/", text))),
+            "scored": sorted(set(re.findall(r"\b(keys_\d+of\d+)\b", text))),
             "kernels": sorted(set(re.findall(r"(flash_fwd|flash_bwd)[.\d]* = ", text)))}
 
 
@@ -256,6 +257,7 @@ def _masked_case(topo, batch, heads, kv_heads, seq, d, block):
     return {"mosaic_calls": text.count("tpu_custom_call"), "plan": list(plan),
             "forward": list(_fwd_pairs_plan(heads // kv_heads, batch * heads, d, 2, plan)),
             "scopes": sorted(set(re.findall(r"\b(tiles_\d+of\d+)\b", text))),
+            "scored": sorted(set(re.findall(r"\b(keys_\d+of\d+)\b", text))),
             "kernels": sorted(set(re.findall(r"(flash_fwd|flash_bwd)[.\d]* = ", text))),
             "words_of_a_selection": len(re.findall(r"s32\[%d,%d,\d+\]" % (batch, seq), text))}
 

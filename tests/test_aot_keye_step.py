@@ -34,7 +34,7 @@ def test_the_keye_step_hands_mosaic_the_selection_the_streamed_kernels_and_the_l
     assert phase(scope) == ("backward" if kernel == "flash_bwd" else "forward")
     assert "attention" in scope.split("/") and "rematted_computation" not in scope.split("/")
     if kernel.startswith("flash_"):
-        assert "tiles_272of512" in scope.split("/")
+        assert "tiles_272of512" in scope.split("/") and "keys_2112of2176" in scope.split("/")
         # The forward's program is a pair of a key/value head's whole group of 8 (PR 44); the backward's a head's.
         assert ("group_8" in scope.split("/")) == (kernel == "flash_fwd")
     else:

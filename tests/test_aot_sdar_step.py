@@ -26,6 +26,7 @@ def test_the_sdar_step_hands_mosaic_the_streamed_kernels_under_the_masks_schedul
     parts = scope.split("/")
     assert "attention" in parts and "rematted_computation" not in parts  # `save_attn`: the kernels run once a layer
     assert "tiles_160of512" in parts and ("group_8" in parts) == (kernel == "flash_fwd")
+    assert "keys_1152of1280" in parts  # both score a crossed pair over its live span of keys (PR 48)
 
 
 def test_the_sdar_step_fits_the_chip_and_names_its_phases_and_the_draw(aot):

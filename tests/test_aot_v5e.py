@@ -102,6 +102,8 @@ def test_both_flash_kernels_stream_pairs_under_a_selection_at_16384_by_128(aot):
     # The forward's program is a pair of a key/value head's whole group, at the plan's own Q tile (PR 44: 10.3 MiB by
     # the smallest limit that compiles, under the default 16 with none asked for), and its scope says so.
     assert got["forward"] == [8, 512] and got["forward_scopes"] == ["group_8"]
+    # Both programs score a crossed pair over its live span (PR 48): 16 of the 32 crossed pairs hold it in half their keys.
+    assert got["scored"] == ["keys_2112of2176"]
 
 
 @pytest.mark.parametrize("case, plan, forward", [
@@ -126,6 +128,7 @@ def test_both_flash_kernels_walk_the_block_diffusion_masks_live_pairs_at_16384_b
     got = aot[MASKED_16K]
     assert got["mosaic_calls"] == 2 and got["kernels"] == ["flash_bwd", "flash_fwd"]
     assert got["plan"] == [512, 1024, 160, 48, 512, False] and got["scopes"] == ["tiles_160of512"]
+    assert got["scored"] == ["keys_1152of1280"]  # 32 of the 48 crossed pairs hold their live span in half their keys (PR 48)
     assert got["forward"] == [8, 512] and got["words_of_a_selection"] == 0
 
 
@@ -135,7 +138,7 @@ def test_grouped_heads_of_2048_by_64_stream_pairs_with_and_without_a_selection(a
     got = aot[GROUPED_2K]
     assert got["mosaic_calls"] == got["mosaic_calls_without_keep"] == 2
     assert got["plan"] == [512, 1024, 6, 4, 8, False] and got["forward"] == [8, 512]
-    assert got["forward_scopes"] == ["group_8"]
+    assert got["forward_scopes"] == ["group_8"] and got["scored"] == ["keys_40of48"]
 
 
 def test_the_selection_and_the_indexer_loss_compile_for_v5e_at_the_cells_shapes(aot):

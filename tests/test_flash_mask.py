@@ -1,7 +1,8 @@
 """A mask by structure in `ops/flash_attention.py` (PR 47): `BlockDiffusion` as the schedules class it and as the
 kernels apply it, against the same mask handed over as a packed selection (`keep=pack_keep(dense mask)`), through
-`xla_attention`, `blockwise_attention` and, in interpret mode, both pair-streamed kernels; and the causal schedules,
-which must come out as the parent built them (the seven cells run them)."""
+`xla_attention`, `blockwise_attention` and, in interpret mode, both pair-streamed kernels; the causal schedules,
+which must come out as the parent built them (the seven cells run them); and the live span of keys that both
+schedules name for every pair (PR 48), under this mask and under the causal diagonal."""
 
 import importlib
 
@@ -70,18 +71,66 @@ def test_both_schedules_visit_each_live_pair_once_and_write_each_block_when_it_i
     live = {(i, j): mask.tile_class(i * tile_q, (i + 1) * tile_q, j * tile_k, (j + 1) * tile_k)
             for i in range(n // tile_q) for j in range(n // tile_k)}
     live = {pair: kind == fa.CROSSED for pair, kind in live.items() if kind != fa.EMPTY}
-    i, j, first, crossed, last = fa._fwd_schedule(n, plan, mask)
+    i, j, first, crossed, last = fa._fwd_schedule(n, plan, mask)[:5]
     assert sorted(zip(i, j)) == sorted(live) and all(live[pair] == c for pair, c in zip(zip(i, j), crossed))
     assert list(i) == sorted(i) and first.sum() == last.sum() == n // tile_q  # a Q tile's pairs in one run
     for t in range(len(i)):
         assert first[t] == (t == 0 or i[t - 1] != i[t]) and last[t] == (t == len(i) - 1 or i[t + 1] != i[t])
-    i, j, due, first, crossed, whole = fa._pair_schedule(n, plan, mask)
+    i, j, due, first, crossed, whole = fa._pair_schedule(n, plan, mask)[:6]
     assert sorted(zip(i, j)) == sorted(live) and all(live[pair] == c for pair, c in zip(zip(i, j), crossed))
     assert list(j) == sorted(j) and whole.sum() == n // tile_q
     for t in range(len(i)):
         assert first[t] == (t == 0 or j[t - 1] != j[t])
         assert whole[t] == (i[t] not in i[t + 1:]) and (not whole[t] or due[t] == i[t])
     assert list(due) == sorted(due)  # the dq block moves on only once it was written
+
+
+# ------------------------------------------------------------------ a pair's live span of keys (PR 48)
+def _kept_of_pair(mask, i, j, tile_q, tile_k):
+    rows, cols = np.meshgrid(np.arange(i * tile_q, (i + 1) * tile_q), np.arange(j * tile_k, (j + 1) * tile_k), indexing="ij")
+    return rows >= cols if mask is True else np.asarray(mask.kept(rows, cols))
+
+
+@pytest.mark.parametrize("mask,seq,tile_q,tile_k", [
+    (BlockDiffusion(8192, 4), 16384, 512, 1024), (BlockDiffusion(8192, 4), 16384, 256, 1024),
+    (BlockDiffusion(2048, 4), 4096, 512, 512), (BlockDiffusion(1024, 16), 2048, 128, 256),
+    (BlockDiffusion(512, 4), 1024, 256, 128), (True, 16384, 512, 1024), (True, 2048, 128, 256), (True, 2048, 256, 1024),
+    (True, 2048, 512, 256), (True, 4096, 512, 512)])
+def test_every_live_span_holds_every_kept_score_of_its_pair_and_no_block_more(mask, seq, tile_q, tile_k):
+    """Rows 5-6 of `_fwd_schedule` and 6-7 of `_pair_schedule`: outside its span a pair keeps no score (what the
+    kernels skip is what the mask masks), the span's first and last blocks each keep one (it is the shortest such
+    run), a pair the mask does not cross carries its whole tile, and both schedules say the same of a pair."""
+    plan, blocks = KernelPlan(tile_q, tile_k, 0, 0, 0, False), tile_k // 128
+    spans = {}
+    for i, j, crossed, first, count in fa._fwd_schedule(seq, plan, mask)[[0, 1, 3, 5, 6]].T:
+        spans[i, j] = (first, count)
+        if not crossed:
+            assert (first, count) == (0, blocks)
+            continue
+        kept = _kept_of_pair(mask, i, j, tile_q, tile_k).reshape(tile_q, blocks, 128).any(axis=(0, 2))
+        assert 0 <= first and 0 < count and first + count <= blocks
+        assert not kept[:first].any() and not kept[first + count:].any() and kept[first] and kept[first + count - 1]
+    backward = fa._pair_schedule(seq, plan, mask)
+    assert {(i, j): (first, count) for i, j, first, count in backward[[0, 1, 6, 7]].T} == spans
+    if tile_q == tile_k and mask is True:  # the diagonal's last query sees every key of its own tile
+        assert fa._short_spans(backward, tile_k) == ()
+
+
+def test_the_walks_score_1152_of_1280_key_blocks_in_the_sdar_row_2112_of_2176_in_keyes_and_all_of_them_at_square_tiles():
+    def scopes(shape, mask, **kw):
+        plan = kernel_plan(shape, mask, **kw)
+        return tuple("/".join(fa._walk_scope(schedule(shape[2], plan, mask), shape[2], plan.tile_q, plan.tile_k))
+                     for schedule in (fa._fwd_schedule, fa._pair_schedule))
+
+    assert scopes((1, 32, 16384, 128), BlockDiffusion(8192, 4), kv_heads=4) == ("tiles_160of512/keys_1152of1280",) * 2
+    assert scopes((1, 32, 16384, 128), True, kv_heads=4, keep=True) == ("tiles_272of512/keys_2112of2176",) * 2
+    assert scopes((2, 20, 4096, 256), True)[1] == "tiles_36of64/keys_144of144"  # GLM's backward: nothing to drop
+    assert scopes((1, 8, 8192, 256), True, keep=True) == ("tiles_136of256/keys_544of544",) * 2
+    # The lengths the backward pass has a form for: a half at these tiles, none where the tiles are square.
+    plan = kernel_plan((1, 32, 16384, 128), BlockDiffusion(8192, 4), kv_heads=4)
+    assert fa._short_spans(fa._pair_schedule(16384, plan, BlockDiffusion(8192, 4)), 1024) == (512,)
+    assert fa._short_spans(fa._pair_schedule(16384, plan, True), 1024) == (512,)
+    assert fa._short_spans(fa._pair_schedule(4096, kernel_plan((2, 20, 4096, 256)), True), 512) == ()
 
 
 # ------------------------------------------------------------------ the causal schedules are the parent's
@@ -139,9 +188,9 @@ def test_the_causal_schedules_and_counts_of_the_seven_cells_are_the_parents(cell
         for tile_q in (plan.tile_q, plan.tile_q // 2):  # the forward may halve its Q tile (`_fwd_pairs_plan`)
             mine = fa._fwd_schedule(seq, plan._replace(tile_q=tile_q), causal)
             theirs = _parents_fwd_schedule(seq, tile_q, plan.tile_k, causal)
-            assert mine.dtype == theirs.dtype and mine.tobytes() == theirs.tobytes()
+            assert mine.dtype == theirs.dtype and mine[:5].tobytes() == theirs.tobytes()  # the rows the parent had
         mine, theirs = fa._pair_schedule(seq, plan, causal), _parents_pair_schedule(seq, plan.tile_q, plan.tile_k, causal)
-        assert mine.dtype == theirs.dtype and mine.tobytes() == theirs.tobytes()
+        assert mine.dtype == theirs.dtype and mine[:6].tobytes() == theirs.tobytes()
 
 
 def test_the_cells_plans_are_those_of_pr_46():
@@ -165,13 +214,15 @@ def _value_and_grads(f, q, k, v, w):
 
 
 # `block` 1: a noised query of the first block keeps one key, its own (a row of a crossed tile with one kept score).
-@pytest.mark.parametrize("form,block,tiles", [
-    ("xla", 4, None), ("xla", 1, None), ("blockwise", 4, None),
-    ("pallas", 4, (128, 128)), ("pallas", 4, (128, 256)), ("pallas", 4, (256, 128)), ("pallas", 1, (128, 256))])
-def test_the_mask_by_structure_is_the_packed_selection_of_its_dense_form(form, block, tiles):
-    seq = 256
+# (128, 256) and (256, 1024): a crossed pair's live span is a half of its K tile, the first or the second (PR 48); with
+# equal heads too (the forward then takes them all a program, each with its own key/value head).
+@pytest.mark.parametrize("form,block,tiles,seq,kv_heads", [
+    ("xla", 4, None, 256, 2), ("xla", 1, None, 256, 2), ("blockwise", 4, None, 256, 2),
+    ("pallas", 4, (128, 128), 256, 2), ("pallas", 4, (128, 256), 256, 2), ("pallas", 4, (256, 128), 256, 2),
+    ("pallas", 1, (128, 256), 256, 2), ("pallas", 4, (256, 1024), 1024, 2), ("pallas", 4, (128, 256), 256, 4)])
+def test_the_mask_by_structure_is_the_packed_selection_of_its_dense_form(form, block, tiles, seq, kv_heads):
     mask = BlockDiffusion(seq, block)
-    q, k, v, w = _operands(seq, 4, 2, 32)
+    q, k, v, w = _operands(seq, 4, kv_heads, 32)
     keep = pack_keep(jnp.asarray(_dense(mask)))[None]
     if block == 1:
         assert _dense(mask)[seq].sum() == 1
@@ -190,10 +241,11 @@ def test_the_mask_by_structure_is_the_packed_selection_of_its_dense_form(form, b
         np.testing.assert_allclose(np.asarray(mine), np.asarray(theirs), atol=5e-5)
 
 
-def test_the_kernels_row_statistics_under_the_mask_are_the_xla_forms():
-    mask = BlockDiffusion(256, 4)
-    q, k, v, _ = _operands(256, 4, 2, 32, seed=3)
+@pytest.mark.parametrize("seq,tiles", [(256, (128, 256)), (1024, (256, 1024))])
+def test_the_kernels_row_statistics_under_the_mask_are_the_xla_forms(seq, tiles):
+    mask = BlockDiffusion(seq, 4)
+    q, k, v, _ = _operands(seq, 4, 2, 32, seed=3)
     _, want = xla_attention(q, k, v, causal=mask, return_lse=True)
-    _, got = flash_attention(q, k, v, causal=mask, backend="pallas", interpret=True, block_q=128, block_k=256,
+    _, got = flash_attention(q, k, v, causal=mask, backend="pallas", interpret=True, block_q=tiles[0], block_k=tiles[1],
                              return_lse=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)

@@ -47,7 +47,7 @@ def test_the_pair_schedule_visits_each_pair_once_and_writes_each_dq_tile_when_it
     for seq, tq, tk, causal in ((4096, 512, 512, True), (4096, 512, 512, False), (2048, 256, 512, True),
                                 (2048, 512, 256, True), (1024, 128, 256, False)):
         plan = kernel_plan((1, 1, seq, 256), causal, tq, tk)
-        i, j, due, first, masked, whole = fa._pair_schedule(seq, plan, causal)
+        i, j, due, first, masked, whole = fa._pair_schedule(seq, plan, causal)[:6]
         n_q, n_k = seq // tq, seq // tk
         pairs = list(zip(i.tolist(), j.tolist()))
         assert len(pairs) == len(set(pairs)) == plan.tiles_visited and int(masked.sum()) == plan.tiles_masked
@@ -198,15 +198,15 @@ def test_the_forward_schedule_visits_each_pair_once_a_q_tile_at_a_time():
     from ray_tpu.ops.flash_attention import KernelPlan, _fwd_schedule
 
     steps = _fwd_schedule(2048, KernelPlan(256, 512, 0, 0, 0, False), True)
-    i, j, first, masked, last = steps
-    assert steps.shape == (5, sum(-(-(n + 1) * 256 // 512) for n in range(8)))
+    i, j, first, masked, last = steps[:5]  # rows 5-6: the pair's live span of keys (`tests/test_flash_mask.py`)
+    assert steps.shape == (7, sum(-(-(n + 1) * 256 // 512) for n in range(8)))
     assert len({(a, b) for a, b in zip(i, j)}) == steps.shape[1] and (np.diff(i) >= 0).all()
     for tile in range(8):
         mine = i == tile
         assert list(j[mine]) == list(range(mine.sum())) and first[mine][0] == 1 and last[mine][-1] == 1
         assert first[mine].sum() == last[mine].sum() == 1 and masked[mine][-1] == 1
     full = _fwd_schedule(1024, KernelPlan(512, 512, 0, 0, 0, False), False)
-    assert full.shape == (5, 4) and not full[3].any()
+    assert full.shape == (7, 4) and not full[3].any() and (full[5:] == [[0], [4]]).all()
 
 
 
@@ -227,5 +227,40 @@ def test_keep_at_the_pair_forms_own_tiles_of_512_by_1024():
     (_, o), got = both(attn)(q, k, v)
     (_, want_o), want = both(lambda *a: _dense_masked(*a, mask)[0])(q, k, v)
     np.testing.assert_allclose(np.asarray(o), np.asarray(want_o), atol=2e-5)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-4)
+
+
+# (Q tile, K tile) at which the causal diagonal leaves a crossed pair a half, or one to three quarters, of its K tile
+# to score (PR 48): both kernels walk that span alone. With and without a selection, 4 heads on 2 and on 4, and a head
+# of 128 at the plan's K tile.
+@pytest.mark.parametrize("seq,d,tiles,heads,kv_heads,selected", [
+    (512, 32, (128, 256), 4, 2, False), (512, 32, (128, 256), 4, 2, True), (512, 32, (128, 256), 4, 4, False),
+    (512, 32, (128, 256), 4, 4, True), (2048, 64, (256, 1024), 2, 1, True), (2048, 128, (256, 1024), 2, 2, False)],
+    ids=["group2", "group2-keep", "equal", "equal-keep", "256x1024-group2-keep", "256x1024-equal-d128"])
+def test_a_pair_scored_over_its_live_span_alone_gives_what_xla_gives(seq, d, tiles, heads, kv_heads, selected):
+    import importlib
+
+    fa = importlib.import_module("ray_tpu.ops.flash_attention")
+    plan = fa._kernel_blocks(seq, d, True, *tiles, 4, True)
+    assert fa._short_spans(fa._pair_schedule(seq, plan, True), tiles[1]) == tuple(range(tiles[0], tiles[1], tiles[0]))
+    keys = jax.random.split(jax.random.PRNGKey(seq + d), 5)
+    q, w = (jax.random.normal(kk, (1, heads, seq, d), jnp.float32) for kk in keys[:2])
+    k, v = (jax.random.normal(kk, (1, kv_heads, seq, d), jnp.float32) for kk in keys[2:4])
+    keep = fa.pack_keep((jax.random.bernoulli(keys[4], 0.2, (1, seq, seq)) | jnp.eye(seq, dtype=bool))
+                        & jnp.tril(jnp.ones((seq, seq), bool))) if selected else None
+
+    def both(attn):
+        def loss(q, k, v):
+            o, lse = attn(q, k, v, keep=keep, return_lse=True)
+            return (o * w).sum(), (o, lse)
+        (_, (o, lse)), grads = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2), has_aux=True))(q, k, v)
+        return o, lse, grads
+
+    o, lse, got = both(lambda *a, **kw: flash_attention(*a, backend="pallas", interpret=True, block_q=tiles[0],
+                                                        block_k=tiles[1], **kw))
+    want_o, want_lse, want = both(xla_attention)
+    np.testing.assert_allclose(np.asarray(o), np.asarray(want_o), atol=2e-5)
+    np.testing.assert_allclose(np.asarray(lse), np.asarray(want_lse), atol=2e-5)
     for a, b in zip(got, want):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-4)

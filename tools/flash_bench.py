@@ -365,6 +365,11 @@ def main(argv=None):
                 selected = {"kv_heads": kv_heads, "keep": bool(args.keep_topk)} if extra or kv_heads != bh else {}
                 plan = fa.kernel_plan((1, bh, seq, d), mask, *tiles, **selected)
                 line["plan"] = plan._asdict()
+                if hasattr(fa, "_walk_scope") and (args.block_diffusion or fa._streams_pairs(seq, d, 2, kv_heads != bh, bool(extra))):
+                    # What a program of the pair-streamed backward walks and scores: `tiles_<walked>of<all>`, and since
+                    # PR 48 `keys_<scored>of<walked>`, blocks of 128 keys inside the pairs' live spans.
+                    walk = fa._walk_scope(fa._pair_schedule(seq, plan, mask), seq, plan.tile_q, plan.tile_k)
+                    line["walk"] = walk if isinstance(walk, str) else "/".join(walk)
                 if args.block_diffusion:
                     n = seq // 2 // mask.block
                     line["kept_pairs"] = (n * n + n) * mask.block ** 2

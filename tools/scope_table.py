@@ -10,7 +10,8 @@ The first argument is a traced run's stem under a checkout's `benchmark/out/`
 a step of everything under it (what `benchmark/harness/scope_trace.scope_ms`
 reads: `moe.router_ms`, `moe.dispatch_ms`, ...), then every device operation of
 the traced steps under it, grouped by opcode, result type, the step's phase and
-the last two components of its `op_name`: calls a step, ms a step (the sum of the calls'
+the last two components of its `op_name` (before them, for a pair-streamed flash call, what it
+walked and scored: `tiles_<walked>of<all>/keys_<scored>of<walked>`): calls a step, ms a step (the sum of the calls'
 own time over the traced steps, divided by their number), most first. With
 `--json PATH` the rows are written there too, each with its instructions' names.
 
@@ -26,6 +27,7 @@ from __future__ import annotations
 import argparse
 import json
 import os
+import re
 import sys
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -55,8 +57,10 @@ def table(stem: str, scopes):
         for op_name, opcode, target, rtype, start, dur in mine:
             if not any(s <= start and start + dur <= s + d for _, _, s, d in runs):
                 continue
-            tail = (program_trace.phase(op_names[op_name]) + " "
-                    + "/".join(op_names[op_name].split("/")[-2:]))
+            parts = op_names[op_name].split("/")
+            # A pair-streamed flash call says what it walked and scored (`tiles_<walked>of<all>`, `keys_<scored>of<walked>`).
+            walk = [part for part in parts[:-2] if re.fullmatch(r"(tiles|keys)_\d+of\d+", part)]
+            tail = program_trace.phase(op_names[op_name]) + " " + "/".join(walk + parts[-2:])
             row = rows.setdefault((scope, target or opcode, rtype, tail), {
                 "scope": scope, "opcode": target or opcode, "type": rtype, "tail": tail,
                 "calls": 0, "ms": 0.0, "names": set()})
