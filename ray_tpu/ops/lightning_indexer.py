@@ -11,18 +11,24 @@ distribution.
                L = mean_t sum_{s in S_t} P_ts (log P_ts - log softmax_{S_t}(I_t.)_s)
 
 An S x S matrix of f32 scores is 1.07 GB a row of 16,384 and is never made:
-`select` makes a tile of query rows' scores at a time and keeps the selection
+`select` makes the scores of 128 queries at a time and keeps the selection
 alone, a bit a (query, key) pair in the form `ops/flash_attention.py` reads
 (`pack_keep`), with each row's log-sum-exp of its selected scores; `index_loss`
 makes the scores again a (Q tile, K tile) pair at a time beside the
 probabilities: a program of its kernel is a whole pair, every attention head
-(a key/value head read once for its group) and every indexer head inside it,
-the tile laid keys down and queries across, so that what belongs to a query (a
-head's log-sum-exp, an indexer head's weight, the loss's term) is a row and no
-column is ever spread over the lanes. The indexer's 64-wide heads lie two to a
-row of 128 lanes; the pair's index scores are made once and kept for the
-gradient where `_loss_plan` counts room for them, and the tiles are what that
-plan derives from the shapes and the bytes a program holds.
+(a key/value head read once for its group) and every indexer head inside it.
+Both kernels lay their tiles keys down and queries across, so that what belongs
+to a query (a head's log-sum-exp, an indexer head's weight, the threshold and
+the count of `select`'s search, the loss's term) is a row and no column is ever
+spread over the lanes (`index_loss` since PR 43, `select` since PR 49: a count
+is then whole vregs added down the keys, and the keys stream through the MXU
+against a head's stationary queries). In `index_loss` the indexer's 64-wide
+heads lie two to a row of 128 lanes; the pair's index scores are made once and
+kept for the gradient where `_loss_plan` counts room for them, and the tiles
+are what that plan derives from the shapes and the bytes a program holds.
+`select`'s program holds the sortable scores of its 128 queries against every
+key of their past, (16384, 128) int32 = 8 MiB at the Keye cell's row, and `kI`
+comes in by copies of a chunk (`_select_bytes`, `_select_plan`).
 
 The k-th largest is found on the bit pattern: an f32 maps to an int32 whose
 order is the float's (`sortable`), and 32 rounds of compare-and-count, one a
@@ -58,8 +64,17 @@ from ray_tpu.ops.flash_attention import (KEEP_BITS, KEEP_SPAN, LANES, KernelPlan
                                          pack_keep, unpack_keep)
 
 INT_MIN = -(2 ** 31)
-SELECT_ROWS = 64  # query rows a `select` program holds the scores of: (64, 16384) f32 is 4 MiB of VMEM
-SELECT_CHUNK = 2048  # keys a product of the `select` kernel makes at a time
+SUBLANES = 8
+# A `select` program: its queries across the lanes (a full lane row; fewer fill part of one and hold as much),
+# and the keys a pass takes at a time, both cut to the row by gcd. At (1, 16, 16384, 64), top 2,048, a program
+# holds 10.27 MiB by `_select_bytes` (8 MiB the sortable scores; the v5e's compiler takes 9.25, the smallest
+# `vmem_limit_bytes` that compiles, under the default with none asked for, alone and inside the Keye step).
+# Kernel ms a call on the v5e by chunk (tools/select_bench.py, PR 49; PERF.md section 6): 256 7.28, 512 6.52,
+# 1024 9.69, 2048 10.21: the search's pass, a chunk's 64 vregs of keys against one of candidates, is 2.73 ms
+# at 512 and 5.94 at 1,024, where a chunk no longer fits the vregs; what this replaced (64 queries down the
+# sublanes, keys across) 10.77.
+SELECT_QUERIES = 128
+SELECT_CHUNK = 512
 # `index_loss`'s (Q tile, K tile) pairs, for `_loss_plan` to choose among by the VMEM a program holds, and what it
 # may hold: Mosaic's default 16 MiB less what XLA fuses into the call's operands inside a step (a kernel that
 # needs 15.4 MiB alone, 512 x 512 with the scores made twice, compiles alone and not in the Keye step), so that
@@ -70,6 +85,16 @@ SELECT_CHUNK = 2048  # keys a product of the `select` kernel makes at a time
 LOSS_TILES = tuple((q, k) for q in (512, 256) for k in (1024, 512, 256))
 LOSS_VMEM_BYTES = 14 * 2 ** 20
 XLA_CHUNK = 256  # query rows a step of the XLA forms holds the scores of
+
+
+def _lane_rows_of(n: int) -> int:
+    """n counted up to whole rows of 128 lanes: what a minor dimension takes in VMEM."""
+    return -(-n // LANES) * LANES
+
+
+def _sublane_rows_of(n: int) -> int:
+    """n counted up to whole tiles of 8 sublanes."""
+    return -(-n // SUBLANES) * SUBLANES
 
 
 # --------------------------------------------------------------------------- the order of floats
@@ -172,82 +197,176 @@ def _xla_index_loss(q, k, lse, keep, q_i, k_i, w, sm_scale):
 
 
 # --------------------------------------------------------------------------- the selection kernel
-def _select_kernel(q_ref, k_ref, w_ref, keep_ref, lse_ref, keys, *, topk, rows, chunk, seq, heads):
-    """One tile of `rows` queries: their scores against every key up to the
-    tile's last, a chunk of keys a product, kept as sortable int32 in `keys`
-    (chunks, rows, chunk); the threshold by `_threshold`; the row's log-sum-exp
-    over the selected scores; the selection packed (`flash_attention.pack_keep`'s form)."""
-    i = pl.program_id(1)
-    row = i * rows + jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
-    live = ((i + 1) * rows + chunk - 1) // chunk  # the chunks that hold a key of this tile's past
-    col0 = jax.lax.broadcasted_iota(jnp.int32, (1, chunk), 1)
+class SelectPlan(NamedTuple):
+    """What one program of `select` is: the queries it holds across the lanes, the
+    keys a product makes the scores of at a time, and the VMEM it holds."""
 
-    def fill(c, _):
-        k_t = k_ref[0, c]  # (d, chunk)
-        acc = jnp.zeros((rows, chunk), jnp.float32)
+    queries: int
+    chunk: int
+    vmem_bytes: int
+
+
+def _select_bytes(queries, chunk, seq, heads, d, itemsize) -> int:
+    """The VMEM a program holds, counted from above as `_loss_bytes` counts: the
+    sortable keys of every (key, query) of the row, a lane row of 128 whatever the
+    queries are (64 queries pad to it and save nothing), the two chunks of `kI` its
+    copies fill in turn (64 lanes pad to 128 as well), the blocks the pipeline
+    fetches ahead (the queries' indexer heads, their weights, the packed words, the
+    row statistic), and the (chunk, queries) f32 values alive at once in a chunk's
+    scores: the sum, a product coming out, its relu going in, the sortable form."""
+    lanes, rows = _lane_rows_of, _sublane_rows_of
+    spans = -(-seq // KEEP_SPAN)
+    held = seq * lanes(queries) * 4 + 2 * chunk * lanes(d) * itemsize
+    blocks = 2 * (heads * rows(d) * lanes(queries) * itemsize + (rows(heads) + SUBLANES) * lanes(queries) * 4
+                  + rows(queries) * spans * LANES * 4)
+    return held + blocks + 4 * chunk * lanes(queries) * 4
+
+
+def _select_plan(seq, heads, d, itemsize) -> SelectPlan:
+    """The program `select` takes: `SELECT_QUERIES` and `SELECT_CHUNK` cut to the
+    row (gcd), and what `_select_bytes` counts for them."""
+    queries, chunk = int(np.gcd(seq, SELECT_QUERIES)), int(np.gcd(seq, SELECT_CHUNK))
+    return SelectPlan(queries, chunk, _select_bytes(queries, chunk, seq, heads, d, itemsize))
+
+
+def _select_scores(q_ref, k_hbm, w_ref, keys, k_buf, sem, row, live, *, chunk, heads):
+    """The scores of the program's queries against the `live` chunks of keys that
+    hold their past, a chunk a pass, into `keys` as sortable int32; the largest
+    key a query has, (1, queries). `kI` stays in HBM and a chunk of it is copied
+    in while the one before is multiplied: the keys stream through the MXU,
+    `chunk` rows against one indexer head's (d, queries) at a time, and that
+    head's weight is a row that multiplies down the sublanes."""
+    b, queries, d = pl.program_id(0), row.shape[1], q_ref.shape[3]
+    copy = lambda c, slot: pltpu.make_async_copy(k_hbm.at[b, c], k_buf.at[slot], sem.at[slot])
+    copy(0, 0).start()
+    key = jax.lax.broadcasted_iota(jnp.int32, (chunk, 1), 0)
+
+    def fill(c, top):
+        slot = c % 2
+        copy(c, slot).wait()
+
+        @pl.when(c + 1 < live)
+        def _():
+            copy(c + 1, 1 - slot).start()
+
+        k = k_buf[slot, :, :d]
+        acc = jnp.zeros((chunk, queries), jnp.float32)
         for j in range(heads):
-            s = jax.lax.dot_general(q_ref[0, j], k_t, (((1,), (0,)), ((), ())),
-                                    preferred_element_type=jnp.float32)
-            acc = acc + w_ref[0, j] * jnp.maximum(s, 0.0)
-        keys[c] = sortable(jnp.where(c * chunk + col0 <= row, acc, -jnp.inf))
-        return 0
+            s = jax.lax.dot_general(k, q_ref[0, 0, j], (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+            acc = acc + w_ref[0, 0, j:j + 1, :] * jnp.maximum(s, 0.0)
+        ks = sortable(jnp.where(c * chunk + key <= row, acc, -jnp.inf)).reshape(chunk // SUBLANES, SUBLANES, queries)
+        keys[c] = ks
+        return jnp.maximum(top, jnp.max(ks, axis=0))
 
-    jax.lax.fori_loop(0, live, fill, 0)
+    top = jax.lax.fori_loop(0, live, fill, jnp.full((SUBLANES, queries), INT_MIN, jnp.int32))
+    return jnp.max(top, axis=0, keepdims=True)
 
-    def over_chunks(f, init):
-        return jax.lax.fori_loop(0, live, lambda c, carry: f(carry, keys[c], c * chunk + col0 <= row), init)
 
+def _select_search(keys, live, topk, queries):
+    """`_threshold` over the live chunks, (1, queries) int32. A round compares every
+    key with its query's candidate, a row spread down the sublanes once a round,
+    and adds whole vregs down the keys into one (8, queries) count, folded once a
+    round. A key of the last live chunk that lies in a query's future is -inf's
+    and counts for a candidate no finite score reaches: for a row shorter than
+    `topk` alone, which keeps its whole past whatever the threshold."""
     def count_at_least(t):
-        return over_chunks(lambda n, ks, _: n + jnp.sum((ks >= t).astype(jnp.int32), axis=1, keepdims=True),
-                           jnp.zeros((rows, 1), jnp.int32))
+        spread = jnp.broadcast_to(t, (SUBLANES, queries))
+        count = jax.lax.fori_loop(0, live, lambda c, n: n + jnp.sum((keys[c] >= spread).astype(jnp.int32), axis=0),
+                                  jnp.zeros((SUBLANES, queries), jnp.int32))
+        return jnp.sum(count, axis=0, keepdims=True)
 
-    tau = _threshold(count_at_least, topk, jnp.zeros((rows, 1), jnp.int32))
-    kept_scores = lambda ks, causal: jnp.where((ks >= tau) & causal, unsortable(ks), -jnp.inf)
-    top = over_chunks(lambda m, ks, causal: jnp.maximum(m, jnp.max(kept_scores(ks, causal), axis=1, keepdims=True)),
-                      jnp.full((rows, 1), -jnp.inf, jnp.float32))
-    total = over_chunks(
-        lambda z, ks, causal: z + jnp.sum(jnp.exp(kept_scores(ks, causal) - top), axis=1, keepdims=True),
-        jnp.zeros((rows, 1), jnp.float32))
-    lse_ref[0] = top + jnp.log(total)
+    return _threshold(count_at_least, topk, jnp.zeros((1, queries), jnp.int32))
 
-    lane = jax.lax.broadcasted_iota(jnp.int32, (1, LANES), 1)
+
+def _select_lse(keys, live, tau, top):
+    """The log-sum-exp of a query's selected scores, (1, queries) f32. The largest
+    score is always selected, so the row's largest key is the maximum; a key of
+    the future is -inf's and adds exp(-inf) = 0 where the threshold lets it through."""
+    queries = tau.shape[1]
+    top = unsortable(top)
+    tau, most = jnp.broadcast_to(tau, (SUBLANES, queries)), jnp.broadcast_to(top, (SUBLANES, queries))
+
+    def add(c, total):
+        ks = keys[c]
+        return total + jnp.sum(jnp.where(ks >= tau, jnp.exp(unsortable(ks) - most), 0.0), axis=0)
+
+    total = jax.lax.fori_loop(0, live, add, jnp.zeros((SUBLANES, queries), jnp.float32))
+    return top + jnp.log(jnp.sum(total, axis=0, keepdims=True))
+
+
+def _select_pack(keep_ref, keys, tau, row, *, chunk, seq):
+    """The selection in `flash_attention.pack_keep`'s form, queries down: a span's
+    32 blocks of 128 keys go into a (128 keys, queries) word a bit each, and the
+    span's words are turned over once. The blocks under the program's first query
+    are every query's past; those from its last on are never read (a chunk past
+    `live` was never filled); the causal test is made for the blocks between."""
+    queries = row.shape[1]
+    first_row = pl.program_id(1) * queries
+    key = jax.lax.broadcasted_iota(jnp.int32, (LANES, 1), 0)
+    blocks = LANES // SUBLANES
     for span in range(keep_ref.shape[2] // LANES):
-        word = jnp.zeros((rows, LANES), jnp.int32)
-        for b in range(KEEP_BITS):
+        def add(b, word, crossed, span=span):
             first = span * KEEP_SPAN + b * LANES
-            if first >= seq:
-                break
-            c, at = divmod(first, chunk)
-            # A chunk past `live` was never filled: every key of it lies in the tile's future.
-            bit = (keys[c, :, at:at + LANES] >= tau) & (first + lane <= row)
-            word = word | (bit.astype(jnp.int32) << b)
-        keep_ref[0, :, span * LANES:(span + 1) * LANES] = word
+            c, at = first // chunk, pl.multiple_of((first % chunk) // SUBLANES, blocks)
+            kept = keys[c, pl.ds(at, blocks)].reshape(LANES, queries) >= tau
+            if crossed:
+                kept = kept & (first + key <= row)
+            return word | (kept.astype(jnp.int32) << b)
+
+        bits = min(KEEP_BITS, (seq - span * KEEP_SPAN) // LANES)
+        past = jnp.clip((first_row - span * KEEP_SPAN) // LANES, 0, bits)
+        live = jnp.clip((first_row + queries - span * KEEP_SPAN + LANES - 1) // LANES, 0, bits)
+        word = jax.lax.fori_loop(0, past, functools.partial(add, crossed=False), jnp.zeros((LANES, queries), jnp.int32))
+        word = jax.lax.fori_loop(past, live, functools.partial(add, crossed=True), word)
+        keep_ref[0, :, span * LANES:(span + 1) * LANES] = word.T
+
+
+def _select_kernel(q_ref, k_hbm, w_ref, keep_ref, lse_ref, keys, k_buf, sem, *, topk, queries, chunk, seq, heads):
+    """One program: `queries` queries across the lanes, the keys down. Their scores
+    against every key up to the program's last, kept as sortable int32 in `keys`
+    (chunks, chunk / 8, 8, queries); the threshold by `_threshold`; the row's
+    log-sum-exp over the selected scores; the selection packed. What belongs to a
+    query (an indexer head's weight, the threshold, a count, the row statistic) is
+    a (1, queries) row; the causal test is key index (down) <= query index (across)."""
+    i = pl.program_id(1)
+    row = i * queries + jax.lax.broadcasted_iota(jnp.int32, (1, queries), 1)
+    live = ((i + 1) * queries + chunk - 1) // chunk  # the chunks that hold a key of this program's past
+    top = _select_scores(q_ref, k_hbm, w_ref, keys, k_buf, sem, row, live, chunk=chunk, heads=heads)
+    tau = _select_search(keys, live, topk, queries)
+    lse_ref[0, 0] = _select_lse(keys, live, tau, top)
+    _select_pack(keep_ref, keys, tau, row, chunk=chunk, seq=seq)
 
 
 def _pallas_select(q_i, k_i, w, topk: int, interpret: bool):
     batch, heads, seq, d = q_i.shape
-    rows, chunk = int(np.gcd(seq, SELECT_ROWS)), int(np.gcd(seq, SELECT_CHUNK))
-    spans = -(-seq // KEEP_SPAN)
-    k_t = k_i.reshape(batch, seq // chunk, chunk, d).transpose(0, 1, 3, 2)  # (batch, chunks, d, chunk)
-    weights = w.astype(jnp.float32).transpose(0, 2, 1)[..., None]  # (batch, heads, seq, 1)
+    assert seq % LANES == 0, f"the selection packs blocks of {LANES} keys: a row of {seq} has a part of one"
+    plan = _select_plan(seq, heads, d, q_i.dtype.itemsize)
+    queries, chunk = plan.queries, plan.chunk
+    n, spans = seq // queries, -(-seq // KEEP_SPAN)
+    # A program's queries across the lanes: (batch, programs, heads, d, queries) and the weights beside them.
+    q_t = q_i.reshape(batch, heads, n, queries, d).transpose(0, 2, 1, 4, 3)
+    weights = w.astype(jnp.float32).reshape(batch, n, queries, heads).transpose(0, 1, 3, 2)
+    # A copy moves whole lane rows: the keys' d numbers at the head of a row of 128, as VMEM would hold them anyway.
+    k_rows = jnp.pad(k_i, ((0, 0), (0, 0), (0, -d % LANES))).reshape(batch, seq // chunk, chunk, -1)
     with jax.named_scope("select"):
         keep, lse = pl.pallas_call(
-            functools.partial(_select_kernel, topk=topk, rows=rows, chunk=chunk, seq=seq, heads=heads),
-            grid=(batch, seq // rows),
-            in_specs=[pl.BlockSpec((1, heads, rows, d), lambda b, i: (b, 0, i, 0)),
-                      pl.BlockSpec((1, seq // chunk, d, chunk), lambda b, i: (b, 0, 0, 0)),
-                      pl.BlockSpec((1, heads, rows, 1), lambda b, i: (b, 0, i, 0))],
-            out_specs=[pl.BlockSpec((1, rows, spans * LANES), lambda b, i: (b, i, 0)),
-                       pl.BlockSpec((1, rows, 1), lambda b, i: (b, i, 0))],
+            functools.partial(_select_kernel, topk=topk, queries=queries, chunk=chunk, seq=seq, heads=heads),
+            grid=(batch, n),
+            in_specs=[pl.BlockSpec((1, 1, heads, d, queries), lambda b, i: (b, i, 0, 0, 0)),
+                      pl.BlockSpec(memory_space=pl.ANY),
+                      pl.BlockSpec((1, 1, heads, queries), lambda b, i: (b, i, 0, 0))],
+            out_specs=[pl.BlockSpec((1, queries, spans * LANES), lambda b, i: (b, i, 0)),
+                       pl.BlockSpec((1, 1, 1, queries), lambda b, i: (b, i, 0, 0))],
             out_shape=[jax.ShapeDtypeStruct((batch, seq, spans * LANES), jnp.int32),
-                       jax.ShapeDtypeStruct((batch, seq, 1), jnp.float32)],
-            scratch_shapes=[pltpu.VMEM((seq // chunk, rows, chunk), jnp.int32)],
+                       jax.ShapeDtypeStruct((batch, n, 1, queries), jnp.float32)],
+            scratch_shapes=[pltpu.VMEM((seq // chunk, chunk // SUBLANES, SUBLANES, queries), jnp.int32),
+                            pltpu.VMEM((2, *k_rows.shape[2:]), k_i.dtype), pltpu.SemaphoreType.DMA((2,))],
             interpret=interpret,
             name="select",
             compiler_params=None if interpret else pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel")),
-        )(q_i, k_t, weights)
-    return keep, lse[..., 0]
+        )(q_t, k_rows, weights)
+    return keep, lse.reshape(batch, seq)
 
 
 def select(q_i, k_i, w, topk: int, backend: Optional[str] = None, interpret: bool = False, mesh=None):
@@ -364,8 +483,7 @@ def _loss_bytes(tile_q, tile_k, keep_scores, heads, kv_heads, d, index_heads, d_
     (32 on 4, 128), 16 x 64 in bf16 (ahead-of-time compiles, PR 43): 256 x 256
     kept 10.75 MiB for the 12.8 counted here, 256 x 512 kept 16.5 for 18.6, not
     kept 256 x 512 9.5 for 10.6, 256 x 1024 13.6 for 14.4, 512 x 1024 20.3 for 25.2."""
-    lanes = lambda n: -(-n // LANES) * LANES
-    rows = lambda n: -(-n // 8) * 8
+    lanes, rows = _lane_rows_of, _sublane_rows_of
     pack, padded = _lane_rows(index_heads, d_i)
     lane_rows = lanes(padded * d_i)
     of_q_tile = (2 * heads * tile_q * lanes(d) * itemsize  # q and its scaled copy

@@ -142,8 +142,9 @@ def test_grouped_heads_of_2048_by_64_stream_pairs_with_and_without_a_selection(a
 
 
 def test_the_selection_and_the_indexer_loss_compile_for_v5e_at_the_cells_shapes(aot):
-    """`select` (64 query rows' scores against 16,384 keys in VMEM, the threshold, the packed words)
-    and `index_loss` (grid (1, 2080 pairs of 256 x 256), a program a whole pair: the 32 heads' products on
+    """`select` (128 queries across the lanes, their sortable scores against 16,384 keys down in 8 MiB of VMEM,
+    `kI` copied in a chunk at a time, the threshold, the packed words turned over a span; under the default
+    limit with none asked for) and `index_loss` (grid (1, 2080 pairs of 256 x 256), a program a whole pair: the 32 heads' products on
     their 4 key/value heads, the 16 index scores made once and kept, 10.75 MiB of VMEM by the smallest
     limit that compiles, under the default with none asked for; the gradients leave the same call, so its
     backward pass is no kernel) at the Keye cell's indexer of 16 heads of 64, top-2,048."""

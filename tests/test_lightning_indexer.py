@@ -1,6 +1,6 @@
 """`ops/lightning_indexer.py`: the selection (`select`, the threshold on the bit pattern) and the indexer's loss
 with the gradients it keeps (`index_loss`, its plan), XLA form and Pallas kernel (interpret mode on CPU) against
-dense forms written here (PR 42, 43)."""
+dense forms written here (PR 42, 43); the selection's kernel against its XLA form word for word (PR 49)."""
 
 import functools
 
@@ -37,6 +37,68 @@ def test_the_selection_is_lax_top_ks_set_ties_and_short_rows_included(indexed, b
         assert (per_query[:, topk:] > topk).any()  # the planted ties at the threshold all stay
     want_lse = jax.scipy.special.logsumexp(jnp.where(want, scores, -jnp.inf), axis=-1)
     np.testing.assert_allclose(np.asarray(lse), np.asarray(want_lse), atol=2e-5)
+
+
+# the row, top k, and what stands in the module for the case: (`SELECT_QUERIES`, `SELECT_CHUNK`), None what it has
+SELECT_CASES = {
+    "a_row_of_384_three_programs": (384, 96, None, None),  # chunks of gcd(384, 512) = 128: a program has 1, 2, 3 live
+    "ties_at_zero_past_topk": (512, 96, None, None),
+    "topk_at_least_the_row": (512, 512, None, None),
+    "topk_past_the_row": (384, 2048, None, None),
+    "128_queries_chunks_of_128": (512, 130, 128, 128),  # four chunks in the last program: both slots of the copy
+    "64_queries": (512, 130, 64, None),
+    "64_queries_chunks_of_256": (512, 96, 64, 256),
+    "bf16": (512, 96, None, None),
+}
+
+
+@pytest.mark.parametrize("case", SELECT_CASES)
+def test_the_selection_kernel_keeps_the_xla_forms_keys_bit_for_bit(case, monkeypatch):
+    """Queries across the lanes and keys down (PR 49) against `_xla_select`, the words of `keep` themselves."""
+    import importlib
+
+    from ray_tpu.ops.flash_attention import unpack_keep
+
+    li = importlib.import_module("ray_tpu.ops.lightning_indexer")
+    seq, topk, queries, chunk = SELECT_CASES[case]
+    if queries is not None:
+        monkeypatch.setattr(li, "SELECT_QUERIES", queries)
+    if chunk is not None:
+        monkeypatch.setattr(li, "SELECT_CHUNK", chunk)
+    q_i, k_i, w = _indexer_inputs(1, 4, seq, 32)
+    if case == "ties_at_zero_past_topk":
+        # Query 300's weights are zeros of both signs: its 301 scores tie at zero, three times `topk`.
+        w = w.at[:, 300].set(jnp.asarray([0.0, -0.0, -0.0, 0.0])).at[:, 301].set(-0.0)
+    if case == "bf16":
+        q_i, k_i = q_i.astype(jnp.bfloat16), k_i.astype(jnp.bfloat16)
+    plan = li._select_plan(seq, 4, 32, q_i.dtype.itemsize)
+    assert plan.queries == (queries or 128) and seq % plan.chunk == 0 and plan.chunk % 128 == 0
+    want_keep, want_lse = li.select(q_i, k_i, w, topk, backend="xla")
+    keep, lse = li.select(q_i, k_i, w, topk, backend="pallas", interpret=True)
+    assert keep.shape == want_keep.shape and keep.dtype == jnp.int32 and bool((keep == want_keep).all())
+    np.testing.assert_allclose(np.asarray(lse), np.asarray(want_lse), atol=2e-5)
+    per_query = np.asarray(unpack_keep(keep, seq)).sum(-1)
+    assert (per_query >= np.minimum(np.arange(1, seq + 1), topk)).all()
+    if case == "ties_at_zero_past_topk":
+        assert (per_query[0, 300:302] == (301, 302)).all()
+    if topk >= seq:
+        assert (per_query == np.arange(1, seq + 1)).all()
+
+
+def test_the_selection_plan_counts_what_a_program_holds():
+    """At the Keye cell's shapes a program is 128 queries across the lanes; the count is from above (the v5e's
+    compiler takes the program inside its default 16 MiB: `tests/test_aot_v5e.py` compiles it, the Keye step
+    too); 64 queries hold the lane rows 128 do, so a row's gcd chooses them and the bytes do not."""
+    from ray_tpu.ops.lightning_indexer import LOSS_VMEM_BYTES, SELECT_CHUNK, _select_bytes, _select_plan
+
+    plan = _select_plan(16384, 16, 64, 2)
+    assert plan[:2] == (128, SELECT_CHUNK) and plan.vmem_bytes == _select_bytes(128, SELECT_CHUNK, 16384, 16, 64, 2)
+    assert 8 * 2 ** 20 < plan.vmem_bytes <= LOSS_VMEM_BYTES < 16 * 2 ** 20  # what a kernel may hold inside a step
+    assert _select_bytes(64, SELECT_CHUNK, 16384, 16, 64, 2) > 8 * 2 ** 20  # half the lanes, every lane row
+    assert _select_bytes(128, 2048, 16384, 16, 64, 2) > LOSS_VMEM_BYTES
+    for seq in (384, 512, 640, 1536, 4096):
+        plan = _select_plan(seq, 4, 32, 4)
+        assert seq % plan.queries == seq % plan.chunk == 0 and plan.chunk % 128 == 0
 
 
 @pytest.mark.parametrize("values", ["mixed", "negative", "zeros"])
