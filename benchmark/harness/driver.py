@@ -219,6 +219,9 @@ def main(argv: List[str], t_start: float) -> int:
         record = run(argv, t_start, manifest)
         describe(record)
         line = result_line(record, manifest)
+        if record["rehearse"]:
+            from benchmark.harness import rehearsal_names  # goes with three tests outside `paths`
+            rehearsal_names.add_names_before_the_fold(line, manifest, record["cell"])
     except BaseException as e:  # noqa: BLE001 - the one exit: say why, then fail with no result
         if isinstance(e, SystemExit) and not e.code:
             raise

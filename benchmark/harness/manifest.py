@@ -1,7 +1,15 @@
 """`BENCHMARK.json` and the files it names. Everything that belongs to one
 configuration, one mix or one per-layer metric is a file of its own, found
 here by name, so that a later PR adds files and appends entries and edits
-nothing that is there."""
+nothing that is there.
+
+A per-layer entry with a `workloads` list takes no later cell, and only a
+`benchmark` PR may edit it. So a later cell brings such a reading as a copy,
+an appended entry `<metric>.<configuration>` and a three-line reader file
+(`tests/benchmark/widened_manifest.py` rehearses it), and the copy lives
+until the next `benchmark` PR puts the cell on the listed entry's own list and
+deletes the file: one entry and one reader file a reading (PR 50 folded 53
+copies of 16 readings; `tests/benchmark/listed_readings.py` holds the lists)."""
 
 from __future__ import annotations
 

@@ -187,7 +187,13 @@ class WorkerRun:
     def inspect_step(self, batch) -> None:
         """The compiled step: Mosaic calls in its text, its memory by
         `memory_analysis()` (the runtime's `peak_bytes_in_use` leaves the
-        program's temporaries out: PERF.md)."""
+        program's temporaries out: PERF.md). `step_bytes` is XLA's own
+        `peak_memory_in_bytes`, the arguments and the most of the temporaries
+        that live at one time; arguments + `temp_size_in_bytes` count every
+        temporary as if all lived at once and read more than a chip holds
+        (PR 50). On the chip that peak is the one meaning of `step_bytes`: a
+        compile that gives none fails the run. A CPU rehearsal, whose backend
+        gives 0, keeps the sum, and its line says `rehearsal.`."""
         with self.setup("inspect_step"):
             compiled = self.system.step.lower(self.system.state, batch).compile()
             mem = compiled.memory_analysis()
@@ -197,8 +203,12 @@ class WorkerRun:
                 "temp_bytes": int(mem.temp_size_in_bytes),
                 "output_bytes": int(mem.output_size_in_bytes),
                 "alias_bytes": int(mem.alias_size_in_bytes),
+                "peak_bytes": int(getattr(mem, "peak_memory_in_bytes", 0) or 0),
             }
-            self.inspected["step_bytes"] = (
+            if not self.inspected["peak_bytes"] and not self.rehearse:
+                raise RuntimeError("the compiled step's memory_analysis() gives no peak_memory_in_bytes: "
+                                   "device.step_hbm_gib and memory_peak_bytes have no other meaning on the chip")
+            self.inspected["step_bytes"] = self.inspected["peak_bytes"] or (
                 self.inspected["argument_bytes"] + self.inspected["temp_bytes"]
                 + self.inspected["output_bytes"] - self.inspected["alias_bytes"])
         self.log(f"compiled step {json.dumps(self.inspected)}")

@@ -1,6 +1,7 @@
 """The least time the chip could take for one step's `conv_mix`: the bytes it must move (the
-model file's `conv_mix_bytes_per_step`, from shapes: forward, recomputation and backward, in
-bf16) over peak HBM bandwidth (it multiplies no matrix, so bandwidth is its only bound), over
+model file's `conv_mix_bytes_per_step`, from shapes: forward and backward, in bf16; no
+recomputation, which the compiled step does not run) over peak HBM bandwidth (it multiplies no
+matrix, so bandwidth is its only bound), over
 `conv.mix_ms`, the time under the scope. Nothing where the model file counts no such bytes or
 the program has no such scope."""
 

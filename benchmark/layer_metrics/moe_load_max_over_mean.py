@@ -1,6 +1,6 @@
 """The largest expert's load over the mean load, the worst layer's, in the
-routing the reference check saw (`routing_stats` of `models/olmoe.py` on the
-first rows of the run's first batch): 1 is an even spread, `num_experts` is
+routing the reference check saw (the `routing_stats` of the cell's model, by
+its model file's `check`, on the first rows of the run's first batch): 1 is an even spread, `num_experts` is
 everything on one expert."""
 
 META = {

@@ -1,4 +1,4 @@
-"""Device time per step under the scope `dense_mlp` of `models/lfm2.py` (the leading layers'
+"""Device time per step under the scope `dense_mlp` (`models/lfm2.py`, `models/glm4_moe_lite.py`: the leading layers'
 dense SwiGLU: three matmuls at `intermediate_size` and the gate between them), forward,
 recomputation and backward together: `scope_trace.scope_ms`."""
 

@@ -113,10 +113,14 @@ def conv_mix_bytes_per_step(c: Dict[str, Any], rows: int, seq: int) -> float:
     """Bytes the scope `conv_mix` of one train step must move in bf16 (gate
     `B * u`, three taps, gate `C * c`: no matmul, so bandwidth is its only
     bound): forward it reads the in-projection's three (tokens, hidden) parts
-    and writes one; the recomputation does the same again; backward it reads
-    the three and the result's gradient and writes the three's gradients: 15
-    such arrays a conv layer. The taps' own (3, hidden) are not counted."""
-    return 15.0 * rows * seq * c["hidden_size"] * 2 * _layers(c)["conv"]
+    and writes one; backward it reads the three and the result's gradient and
+    writes the three's gradients: 11 such arrays a conv layer. The four of a
+    recomputation are not counted: the compiled step has run none under this
+    scope since PR 36 (no operation there carries `rematted_computation`;
+    PERF.md section 5, PR 50), and a floor that held them would let a kernel
+    at HBM's rate on the passes that exist read over 100 %. The taps' own
+    (3, hidden) are not counted."""
+    return 11.0 * rows * seq * c["hidden_size"] * 2 * _layers(c)["conv"]
 
 
 # ---------------------------------------------------------------------- system

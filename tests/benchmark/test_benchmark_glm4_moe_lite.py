@@ -18,6 +18,7 @@ sys.path.insert(0, REPO)
 
 from benchmark.harness.manifest import Manifest, problems, reduced_problems  # noqa: E402
 from benchmark.models import glm4_moe_lite as glm  # noqa: E402
+import listed_readings  # noqa: E402
 from widened_manifest import named_run  # noqa: E402,F401  (fixture)
 
 CONFIG, CELL = "glm-4.7-flash-ep8-l5", "glm-4.7-flash-ep8-l5.fed4k"
@@ -168,43 +169,30 @@ LISTED = ("data.wait_ms", "host.h2d_ms", "host.report_ms", "host.report_put_ms",
           "moe.held_pairs_share", "moe.issued_over_held")
 
 
-def test_the_listed_readings_come_under_the_configurations_name_and_the_new_ones_list_the_cell():
-    m = Manifest()
-    readers = m.layer_readers()
-    mine = {e["name"] for e in m.metrics_for(CELL, "per_layer")}
-    by_name = {e["name"]: e for e in m.data["per_layer"]}
-    for listed in LISTED:
-        name = f"{listed}.{CONFIG}"
-        assert name in mine and listed not in mine and by_name[name]["workloads"] == [CELL]
-        assert readers[name].read.__code__ == readers[listed].read.__code__
-        assert {**readers[listed].META, "name": name} == readers[name].META
-    for name in NEW:
-        assert by_name[name]["workloads"] == [CELL] and name in mine
+def test_the_cell_is_on_the_list_of_each_listed_reading_it_reports_and_the_new_ones_list_the_cell():
+    by_name, unlisted = listed_readings.holds_for(CELL, LISTED, NEW)
     assert {by_name[name]["layer"] for name in NEW} == {"latent attention", "expert layer", "prediction module"}
     # Every unlisted reading of the accepted benchmark is the cell's too: the four `kernels.flash_*` among them.
-    unlisted = {e["name"] for e in m.data["per_layer"] if "workloads" not in e}
-    assert unlisted <= mine and {"kernels.flash_ms", "kernels.flash_fwd_ms", "kernels.flash_bwd_ms",
-                                 "kernels.flash_roofline"} <= unlisted
-    assert len(mine) == len(LISTED) + len(NEW) + len(unlisted)
-    # No stall reading and no block-pull reading: an entry is listed only where every traced line
+    assert {"kernels.flash_ms", "kernels.flash_fwd_ms", "kernels.flash_bwd_ms", "kernels.flash_roofline"} <= unlisted
+    # No stall reading and no block-pull reading: an entry lists a cell only where every traced line
     # carries it, and of two traced runs of this cell one held no pull inside its 8 steps (a packed
-    # block is about 16 rows, so a pull comes about every eighth step: PERF.md section 3, PR 39).
-    assert not {f"host.stall_pct.{CONFIG}", f"data.fetch_block_ms.{CONFIG}"} & set(by_name)
+    # block is 16 or 17 rows, so a pull comes every eighth or ninth step: PERF.md section 3, PR 39).
+    assert not {n for n in ("host.stall_pct", "data.fetch_block_ms") if CELL in listed_readings.TABLE[n]}
 
 
 def test_the_new_readers_return_nothing_on_a_program_without_the_scopes(named_run):
     readers = Manifest().layer_readers()
     run = dict(named_run, summary={**named_run["summary"], "check": {}}, peaks={
         "bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9})
-    names = NEW + tuple(f"{n}.{CONFIG}" for n in ("step.dense_mlp_ms", "moe.held_pairs_share", "moe.issued_over_held"))
+    names = NEW + ("step.dense_mlp_ms", "moe.held_pairs_share", "moe.issued_over_held")
     assert [readers[name].read(run) for name in names] == [None] * len(names)  # gpt2: no such scope
 
 
 def test_the_counters_read_the_checks_routing():
     readers = Manifest().layer_readers()
     run = {"summary": {"check": {"routing": {"held_pairs_share": 0.126, "issued_over_held": 1.3}}}}
-    assert readers[f"moe.held_pairs_share.{CONFIG}"].read(run) == 0.126
-    assert readers[f"moe.issued_over_held.{CONFIG}"].read(run) == 1.3
+    assert readers["moe.held_pairs_share"].read(run) == 0.126
+    assert readers["moe.issued_over_held"].read(run) == 1.3
     # 8 groups of 512 rows on block edges issue nothing extra; ragged ones a block each at most.
     assert glm._issued_rows([[512] * 8]) == 9 * 4096
     ragged = [[500, 530, 490, 515, 520, 505, 525, 511]]

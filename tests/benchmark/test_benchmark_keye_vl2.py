@@ -20,6 +20,7 @@ sys.path.insert(0, REPO)
 
 from benchmark.harness.manifest import Manifest, problems, reduced_problems  # noqa: E402
 from benchmark.models import keye_vl2 as keye  # noqa: E402
+import listed_readings  # noqa: E402
 from widened_manifest import named_run  # noqa: E402,F401  (fixture)
 
 CONFIG, CELL = "keye-vl-2.0-30b-a3b-ep8", "keye-vl-2.0-30b-a3b-ep8.fed16k"
@@ -187,25 +188,13 @@ LISTED = ("data.wait_ms", "host.h2d_ms", "host.report_ms", "host.report_put_ms",
           "moe.held_pairs_share", "moe.issued_over_held")
 
 
-def test_the_listed_readings_come_under_the_configurations_name_and_the_new_ones_list_the_cell():
-    m = Manifest()
-    readers = m.layer_readers()
-    mine = {e["name"] for e in m.metrics_for(CELL, "per_layer")}
-    by_name = {e["name"]: e for e in m.data["per_layer"]}
-    for listed in LISTED:
-        name = f"{listed}.{CONFIG}"
-        assert name in mine and listed not in mine and by_name[name]["workloads"] == [CELL]
-        assert readers[name].read.__code__ == readers[listed].read.__code__
-        assert {**readers[listed].META, "name": name} == readers[name].META
-    for name in NEW:
-        assert by_name[name]["workloads"] == [CELL] and name in mine
+def test_the_cell_is_on_the_list_of_each_listed_reading_it_reports_and_the_new_ones_list_the_cell():
+    by_name, unlisted = listed_readings.holds_for(CELL, LISTED, NEW)
     assert {by_name[name]["layer"] for name in NEW} == {"sparse attention", "kernels"}
-    unlisted = {e["name"] for e in m.data["per_layer"] if "workloads" not in e}
-    assert unlisted <= mine and {"kernels.flash_ms", "kernels.flash_fwd_ms", "kernels.flash_bwd_ms",
-                                 "kernels.flash_roofline", "step.mfu_pct"} <= unlisted
-    assert len(mine) == len(LISTED) + len(NEW) + len(unlisted)
+    assert {"kernels.flash_ms", "kernels.flash_fwd_ms", "kernels.flash_bwd_ms", "kernels.flash_roofline",
+            "step.mfu_pct"} <= unlisted
     # No stall reading (about 20 steps a window) and no block-pull reading (PERF.md section 3).
-    assert not {f"host.stall_pct.{CONFIG}", f"data.fetch_block_ms.{CONFIG}"} & set(by_name)
+    assert not {n for n in ("host.stall_pct", "data.fetch_block_ms") if CELL in listed_readings.TABLE[n]}
 
 
 def test_the_new_readers_return_nothing_on_a_program_without_the_scopes(named_run):
@@ -246,5 +235,5 @@ def test_the_new_readers_read_their_scope_kernel_and_counter(named_run, config):
                                  "routing": {"held_pairs_share": 0.126, "issued_over_held": 1.3}}}}
     assert readers["dsa.selected_share"].read(run) == 0.2345
     assert readers["dsa.live_tiles_share"].read(run) == 0.99
-    assert readers[f"moe.held_pairs_share.{CONFIG}"].read(run) == 0.126
-    assert readers[f"moe.issued_over_held.{CONFIG}"].read(run) == 1.3
+    assert readers["moe.held_pairs_share"].read(run) == 0.126
+    assert readers["moe.issued_over_held"].read(run) == 1.3

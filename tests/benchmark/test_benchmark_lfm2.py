@@ -15,6 +15,7 @@ sys.path.insert(0, REPO)
 
 from benchmark.harness.manifest import Manifest, problems  # noqa: E402
 from benchmark.models import lfm2  # noqa: E402
+import listed_readings  # noqa: E402
 from widened_manifest import named_run  # noqa: E402,F401  (fixture)
 
 CONFIG, CELL = "lfm2-24b-a2b-ep8-l5", "lfm2-24b-a2b-ep8-l5.fed4k"
@@ -68,8 +69,8 @@ def test_flops_and_bytes_by_hand(config):
     + 3 x 4 x 2048^2 = 60,817,408; four routers of 2048 x 64; of its 4 experts a
     layer the 8/64 held here, 0.5 x 3 x 2048 x 1536 a layer; the head's 8,192
     rows. Attention: one layer of 32 heads, 6 products x 2 x 4096^2 x 64 / 2 a
-    head. Experts: 16,384 expected pairs a layer. `conv_mix`: 15 passes of
-    32,768 x 2048 bf16 a conv layer, four of them."""
+    head. Experts: 16,384 expected pairs a layer. `conv_mix`: 11 passes of
+    32,768 x 2048 bf16 a conv layer (forward 4, backward 7; the recomputation's 4 have not run since PR 36), four of them."""
     rows, seq = 8, 4096
     active = 89_128_960 + 60_817_408 + 4 * 131_072 + 4 * 0.5 * 9_437_184 + 8_192 * 2_048
     assert lfm2.active_matmul_params(config) == active == 186_122_240
@@ -80,7 +81,7 @@ def test_flops_and_bytes_by_hand(config):
     assert lfm2.moe_expert_flops_per_step(config, rows, seq) == 4 * 16_384 * 18.0 * 2048 * 1536
     assert lfm2.moe_expert_bytes_per_step(config, rows, seq) == 4 * 18.0 * (
         16_384 * 2048 + 8 * 2048 * 1536 + 16_384 * 1536)
-    assert lfm2.conv_mix_bytes_per_step(config, rows, seq) == 4 * 15 * 32_768 * 2048 * 2
+    assert lfm2.conv_mix_bytes_per_step(config, rows, seq) == 4 * 11 * 32_768 * 2048 * 2
     shares = {"dense": 89_128_960, "convs": 4 * 4 * 2048 ** 2, "experts": 4 * 0.5 * 9_437_184}
     assert {k: round(100 * v / active) for k, v in shares.items()} == {"dense": 48, "convs": 36, "experts": 10}
     assert round(100 * 3 * 2048 * 11776 / active) == 39  # the dense SwiGLU alone
@@ -116,28 +117,18 @@ def test_the_reference_walks_the_tree_in_the_published_order():
 
 
 # ------------------------------------------------------------------ readers
-NEW = ("conv.short_conv_ms", "conv.mix_ms", "conv.mix_roofline", "step.dense_mlp_ms",
-       "moe.held_pairs_share", "moe.issued_over_held")
+NEW = ("conv.short_conv_ms", "conv.mix_ms", "conv.mix_roofline")
+BROUGHT = ("step.dense_mlp_ms", "moe.held_pairs_share", "moe.issued_over_held")  # PR 35's; later cells are on their lists
 LISTED = ("data.wait_ms", "host.h2d_ms", "host.report_ms", "host.report_put_ms", "data.fetch_block_ms",
           "moe.router_ms", "moe.dispatch_ms", "moe.experts_ms", "moe.experts_roofline",
-          "moe.load_max_over_mean", "kernels.gmm_ms", "kernels.gmm_roofline")
+          "moe.load_max_over_mean", "kernels.gmm_ms", "kernels.gmm_roofline") + BROUGHT
 
 
-def test_the_listed_readings_come_under_the_configurations_name_and_the_new_ones_list_the_cell():
-    m = Manifest()
-    readers = m.layer_readers()
-    mine = {e["name"] for e in m.metrics_for(CELL, "per_layer")}
-    for listed in LISTED:
-        name = f"{listed}.{CONFIG}"
-        assert name in mine and listed not in mine
-        assert readers[name].read.__code__ == readers[listed].read.__code__
-        assert {**readers[listed].META, "name": name} == readers[name].META
-    by_name = {e["name"]: e for e in m.data["per_layer"]}
-    for name in NEW:
-        assert by_name[name]["workloads"] == [CELL] and name in mine
-    # Every unlisted reading of the accepted benchmark is the cell's too.
-    assert {e["name"] for e in m.data["per_layer"] if "workloads" not in e} <= mine
-    assert len(mine) == len(LISTED) + len(NEW) + sum("workloads" not in e for e in m.data["per_layer"])
+def test_the_cell_is_on_the_list_of_each_listed_reading_it_reports_and_the_new_ones_list_the_cell():
+    """The one cell on `data.fetch_block_ms`'s list: 8 rows a step out of packed blocks of 16 or 17 rows
+    is a pull every second step, at most every third: four in any window of 8 traced steps."""
+    listed_readings.holds_for(CELL, LISTED, NEW)
+    assert listed_readings.TABLE["data.fetch_block_ms"] == [CELL]
 
 
 def test_the_cell_brings_no_stall_reading_because_a_traced_window_of_its_steps_has_none():
@@ -147,7 +138,7 @@ def test_the_cell_brings_no_stall_reading_because_a_traced_window_of_its_steps_h
     (as the four-chip cell does not)."""
     from benchmark.harness.clock import TRACE_STEPS, WindowClock
 
-    assert f"host.stall_pct.{CONFIG}" not in {e["name"] for e in Manifest().data["per_layer"]}
+    assert CELL not in next(e for e in Manifest().data["per_layer"] if e["name"] == "host.stall_pct")["workloads"]
     clock = WindowClock(20.0, 8 * 4096, (0, 20))
     clock.completed_at = [0.6 * (i + 1) for i in range(32)]
     assert clock.stall_share() is not None  # untraced: four readings a position
@@ -159,7 +150,8 @@ def test_the_new_readers_return_nothing_on_a_program_without_the_scopes(named_ru
     readers = Manifest().layer_readers()
     run = dict(named_run, summary={**named_run["summary"], "check": {}}, peaks={
         "bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9})
-    assert [readers[name].read(run) for name in NEW] == [None] * len(NEW)  # gpt2: no such scope
+    names = NEW + BROUGHT
+    assert [readers[name].read(run) for name in names] == [None] * len(names)  # gpt2: no such scope
 
 
 def test_the_counters_read_the_checks_routing():
@@ -186,7 +178,7 @@ def test_the_cells_cpu_rehearsal_prints_the_contracts_line():
     assert line["correct"] is True and line["failed"] == 0 and line["attempted"] > 3
     assert line["device"]["platform"] == "cpu" and "platform=cpu" in proc.stdout
     assert all(name.startswith("rehearsal.") for name in line["metrics"])
-    assert f"rehearsal.data.wait_ms.{CONFIG}" in line["metrics"]
+    assert "rehearsal.data.wait_ms" in line["metrics"]
     assert 0.0 < line["metrics"]["rehearsal.moe.held_pairs_share"]["value"] < 0.6
     assert line["metrics"]["rehearsal.moe.issued_over_held"]["value"] >= 1.0
     assert '"dropped": 0' in proc.stdout and "expert_choices_flipped_share" in proc.stdout

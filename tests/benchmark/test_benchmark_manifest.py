@@ -4,6 +4,7 @@ state the contract and not the contents: each runs on the live manifest and
 on a copy widened by new files and appended entries (`widened_manifest.py`), so what
 a later PR appends passes them without an edit here."""
 
+import glob
 import json
 import os
 import shutil
@@ -15,7 +16,7 @@ import pytest
 REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 sys.path[:0] = [REPO, os.path.dirname(os.path.abspath(__file__))]
 
-from benchmark.harness import peaks  # noqa: E402
+from benchmark.harness import driver, peaks, rehearsal_names  # noqa: E402
 from benchmark.harness.manifest import Manifest, problems, reduced_problems  # noqa: E402
 from widened_manifest import manifest_root, widened  # noqa: E402,F401  (fixtures)
 
@@ -34,6 +35,51 @@ def run_benchmark(root, *args, env=None, timeout=300):
 
 def test_manifest_meets_the_contract(manifest_root):
     assert problems(Manifest(manifest_root)) == []
+
+
+def test_every_entry_has_one_reader_file_and_every_reader_file_one_entry(manifest_root):
+    """`layer_readers()` finds a reader by its `META["name"]`, so a second file of one name would
+    stand in the first one's place unseen, and a file no entry names is read by no run."""
+    m = Manifest(manifest_root)
+    files = glob.glob(os.path.join(m.dir, "layer_metrics", "*.py"))
+    readers = m.layer_readers()
+    entries = [e["name"] for e in m.data["per_layer"]]
+    assert len(files) == len(readers) == len(entries), (len(files), len(readers), len(entries))
+    assert set(readers) == set(entries), set(readers) ^ set(entries)
+
+
+def test_per_layer_keeps_the_widening_rehearsal_under_the_contracts_cap(widened):
+    """The contract caps `per_layer` at 128 and `test_benchmark_program_trace.py` holds a copy widened
+    twice to it, so what a later PR may append is 128 less the live entries less two widenings. Only
+    that cap is held here: a `model_config` PR may edit nothing in this directory, so a margin asserted
+    on the live manifest would refuse the very PR the room is for. A configuration with a new kernel
+    and new layers brings about twenty entries (its own and a copy of each listed reading its cell
+    reports: thirteen for a fed expert cell); how many such the room takes is PERF.md's to say."""
+    live, appended = len(Manifest().data["per_layer"]), len(widened.metrics)
+    room = 128 - live - 2 * appended
+    assert room >= 0, (
+        f"`per_layer` holds {live} entries and the widening rehearsal appends 2 x {appended}: {-room} over the cap "
+        f"of 128, so the next configuration may append none. No `model_config` PR can make room: a `benchmark` PR "
+        f"does, by folding the `<metric>.<configuration>` copies accepted since the last one into the listed "
+        f"entries' `workloads` and deleting their reader files (PERF.md section 4, "
+        f"`tests/benchmark/listed_readings.py`)")
+
+
+def test_the_names_before_the_fold_go_on_a_rehearsals_line_and_on_no_other():
+    """Three CPU rehearsal tests outside this directory still look a listed reading up under
+    `<metric>.<configuration>` (`harness/rehearsal_names.py`, to delete with them): `driver.main` adds
+    those names to a rehearsal's line, and the function that writes the chip's line has no part in it."""
+    m = Manifest()
+    cell, reading = m.cell("glm-4.7-flash-ep8-l5.fed4k"), {"value": 1.25, "unit": "ms/step"}
+    line = {"metrics": {"rehearsal.data.wait_ms": reading, "rehearsal.step.device_ms": reading}}
+    rehearsal_names.add_names_before_the_fold(line, m, cell)
+    assert line["metrics"] == {"rehearsal.data.wait_ms": reading, "rehearsal.step.device_ms": reading,
+                               "rehearsal.data.wait_ms.glm-4.7-flash-ep8-l5": reading}
+    chips = {"metrics": {"data.wait_ms": reading, "step.device_ms": reading}}
+    rehearsal_names.add_names_before_the_fold(chips, m, cell)
+    assert set(chips["metrics"]) == {"data.wait_ms", "step.device_ms"}
+    assert "rehearsal_names" not in driver.result_line.__code__.co_names
+    assert "rehearsal_names" in driver.main.__code__.co_names
 
 
 def test_the_seeds_cells_are_there_and_four_chip_cells_keep_to_their_share(manifest_root):

@@ -16,6 +16,7 @@ sys.path.insert(0, REPO)
 from benchmark.harness import scope_trace  # noqa: E402
 from benchmark.harness.manifest import Manifest  # noqa: E402
 from benchmark.models import olmoe  # noqa: E402
+import listed_readings  # noqa: E402
 from widened_manifest import named_run  # noqa: E402,F401  (fixture)
 
 CONFIG, CELL = "olmoe-1b-7b-l1", "olmoe-1b-7b-l1.fed4k"
@@ -103,16 +104,16 @@ def test_the_moe_readers_return_nothing_on_a_program_without_the_scopes(named_ru
     assert [readers[name].read(run) for name in mine] == [None] * len(mine)
 
 
-def test_the_six_listed_readings_come_under_the_configurations_name():
-    m = Manifest()
-    readers = m.layer_readers()
-    mine = {e["name"] for e in m.metrics_for(CELL, "per_layer")}
-    for listed in ("data.wait_ms", "host.h2d_ms", "host.report_ms", "host.report_put_ms",
-                   "data.fetch_block_ms", "host.stall_pct"):
-        name = f"{listed}.{CONFIG}"
-        assert name in mine and listed not in mine
-        assert readers[name].read.__code__ == readers[listed].read.__code__
-        assert {**readers[listed].META, "name": name} == readers[name].META
+def test_the_cell_is_on_the_list_of_each_listed_reading_it_reports():
+    """The four fed readings, the stall reading (175 steps a window) and the seven of the expert layer
+    that this cell brought (PR 28). Not `data.fetch_block_ms`: 2 rows a step out of packed blocks of 16
+    or 17 rows, so a pull comes every eighth or ninth step and a traced window of 8 can hold none
+    (PR 50; the cell read it by luck until then)."""
+    listed_readings.holds_for(CELL, (
+        "data.wait_ms", "host.h2d_ms", "host.report_ms", "host.report_put_ms", "host.stall_pct",
+        "moe.router_ms", "moe.dispatch_ms", "moe.experts_ms", "moe.experts_roofline",
+        "moe.load_max_over_mean", "kernels.gmm_ms", "kernels.gmm_roofline"), new=())
+    assert CELL not in listed_readings.TABLE["data.fetch_block_ms"]
 
 
 def test_load_max_over_mean_reads_the_checks_routing():
@@ -133,7 +134,7 @@ def test_the_cells_cpu_rehearsal_prints_the_contracts_line():
     assert line["correct"] is True and line["failed"] == 0 and line["attempted"] > 3
     assert line["device"]["platform"] == "cpu" and "platform=cpu" in proc.stdout
     assert all(name.startswith("rehearsal.") for name in line["metrics"])
-    assert f"rehearsal.data.wait_ms.{CONFIG}" in line["metrics"]
+    assert "rehearsal.data.wait_ms" in line["metrics"]
     assert 1.0 <= line["metrics"]["rehearsal.moe.load_max_over_mean"]["value"] <= 8.0
     assert '"dropped": 0' in proc.stdout and "expert_choices_flipped_share" in proc.stdout
 

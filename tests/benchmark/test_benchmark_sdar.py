@@ -19,6 +19,7 @@ sys.path.insert(0, REPO)
 
 from benchmark.harness.manifest import Manifest, problems, reduced_problems  # noqa: E402
 from benchmark.models import sdar  # noqa: E402
+import listed_readings  # noqa: E402
 from widened_manifest import named_run  # noqa: E402,F401  (fixture)
 
 CONFIG, CELL = "sdar-30b-a3b-chat-ep8", "sdar-30b-a3b-chat-ep8.fed8k"
@@ -191,33 +192,23 @@ def test_the_attention_path_of_the_cell_is_the_kernels_on_the_doubled_row(config
 
 # ------------------------------------------------------------------ readers
 NEW = ("bd.walked_over_live_tiles",)
-# Eight of the thirteen listed readings the Keye cell brought: `per_layer` may hold 128 entries, the rehearsal of the next
-# two configurations (`test_benchmark_widening.py`) appends 16, and 103 were there, which leaves nine (PERF.md section 7).
-# Readings that cannot move (the share the check's one draw masked, the held share under tiled routers, the draw's two
-# adds) take none of them: they are in the check's summary, and `tests/test_sdar.py` holds them.
-LISTED = ("data.wait_ms", "moe.router_ms", "moe.dispatch_ms", "moe.experts_ms", "moe.experts_roofline",
-          "moe.load_max_over_mean", "kernels.gmm_ms", "kernels.gmm_roofline")
+# Twelve of the thirteen listed readings the Keye cell reports (PR 47 had room for eight; PR 50 folded the copies and the
+# cell is on the other four's lists). Readings that cannot move (the share the check's one draw masked, the held share
+# under tiled routers: `moe.held_pairs_share` would read 0.125 in every run; the draw's two adds) take no entry: they
+# are in the check's summary, and `tests/test_sdar.py` holds them.
+LISTED = ("data.wait_ms", "host.h2d_ms", "host.report_ms", "host.report_put_ms",
+          "moe.router_ms", "moe.dispatch_ms", "moe.experts_ms", "moe.experts_roofline",
+          "moe.load_max_over_mean", "kernels.gmm_ms", "kernels.gmm_roofline", "moe.issued_over_held")
 
 
-def test_the_listed_readings_come_under_the_configurations_name_and_the_new_ones_list_the_cell():
-    m = Manifest()
-    readers = m.layer_readers()
-    mine = {e["name"] for e in m.metrics_for(CELL, "per_layer")}
-    by_name = {e["name"]: e for e in m.data["per_layer"]}
-    for listed in LISTED:
-        name = f"{listed}.{CONFIG}"
-        assert name in mine and listed not in mine and by_name[name]["workloads"] == [CELL]
-        assert readers[name].read.__code__ == readers[listed].read.__code__
-        assert {**readers[listed].META, "name": name} == readers[name].META
-    for name in NEW:
-        assert by_name[name]["workloads"] == [CELL] and name in mine
+def test_the_cell_is_on_the_list_of_each_listed_reading_it_reports_and_the_new_ones_list_the_cell():
+    by_name, unlisted = listed_readings.holds_for(CELL, LISTED, NEW)
     assert {by_name[name]["layer"] for name in NEW} == {"block diffusion"}
-    unlisted = {e["name"] for e in m.data["per_layer"] if "workloads" not in e}
-    assert unlisted <= mine and {"kernels.flash_ms", "kernels.flash_fwd_ms", "kernels.flash_bwd_ms",
-                                 "kernels.flash_roofline", "step.mfu_pct"} <= unlisted
-    assert len(mine) == len(LISTED) + len(NEW) + len(unlisted)
-    # No stall reading (some 45 steps a window) and no block-pull reading (PERF.md section 3).
-    assert not {f"host.stall_pct.{CONFIG}", f"data.fetch_block_ms.{CONFIG}"} & set(by_name)
+    assert {"kernels.flash_ms", "kernels.flash_fwd_ms", "kernels.flash_bwd_ms", "kernels.flash_roofline",
+            "step.mfu_pct"} <= unlisted
+    # No stall reading (some 45 steps a window), no block-pull reading (PERF.md section 3), no held share (0.125 always).
+    assert not {n for n in ("host.stall_pct", "data.fetch_block_ms", "moe.held_pairs_share")
+                if CELL in listed_readings.TABLE[n]}
 
 
 def test_the_new_readers_return_nothing_on_a_program_without_the_scope_or_the_counters(named_run):
@@ -257,5 +248,5 @@ def test_the_walk_is_read_off_the_traced_kernels_and_the_shares_divide_by_this_m
     trace = run["device_trace"]
     assert readers["step.mfu_pct"].read(run) == pytest.approx(
         100 * sdar.train_flops_per_token(config, SEQ) * trace.host_steps * SEQ / trace.window_s / 197e12)
-    assert readers[f"moe.experts_roofline.{CONFIG}"].read(run) is None  # the recorded step has no expert layer
-    assert readers[f"kernels.gmm_roofline.{CONFIG}"].read(run) is None
+    assert readers["moe.experts_roofline"].read(run) is None  # the recorded step has no expert layer
+    assert readers["kernels.gmm_roofline"].read(run) is None

@@ -23,7 +23,7 @@ TESTDATA = os.path.join(REPO, "benchmark", "testdata")
 NAMED = os.path.join(TESTDATA, "tiny_gpt_named_v5e.xplane.pb.gz")    # PR 24's: the kernels carry names
 UNNAMED = os.path.join(TESTDATA, "tiny_gpt_3_steps_v5e.xplane.pb.gz")  # PR 22's: none does
 OTHER_KERNEL = "grouped_matmul"  # a Mosaic kernel's `pl.pallas_call(name=...)` that is no flash kernel
-FED_CELL = "gpt2-medium.fed"  # the one-chip fed cell that the entries listing their cells list
+FED_CELL = "gpt2-medium.fed"  # the one-chip fed cell whose listed readings the rehearsal's cell copies
 
 MODEL_MODULE = '''"""A model module of another name: what `worker.build_system` and the readers
 ask of one. This one trains GPT-2's block; a real one brings its own `System`,
@@ -56,9 +56,11 @@ def read(run):
     return program.kernel("{kernel}") if program else None
 '''
 # An entry that lists its cells takes no later cell, and no PR but a `benchmark` one may
-# edit it: a later fed cell brings the same reading under a name of its own.
+# edit it: a later fed cell brings the same reading under a name of its own, and the next
+# `benchmark` PR puts the cell on the listed entry's list and deletes the copy (PR 50 folded 53).
 SAME_READING = '''"""`{listed}` in `{cell}`: that entry lists its cells and a later cell cannot
-append itself, so the cell brings the same reading under a name of its own."""
+append itself, so the cell brings the same reading under a name of its own, until a
+`benchmark` PR puts the cell on that entry's list and deletes this file."""
 
 from benchmark.layer_metrics import {stem} as listed
 
