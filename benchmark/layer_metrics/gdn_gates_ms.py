@@ -1,0 +1,18 @@
+"""Device time per step under the scope `gdn_gates` of `models/olmo_hybrid.py` (the L2 norms of q and k, `beta` and
+the log decay `g` with their two projections of 30 columns), forward, recomputation and backward together:
+`scope_trace.scope_ms`."""
+
+from benchmark.harness import scope_trace
+
+META = {
+    "name": "gdn.gates_ms",
+    "unit": "ms/step",
+    "better": "lower",
+    "source": "device_trace",
+    "layer": "linear attention",
+    "moves": "tokens_per_s_per_chip"
+}
+
+
+def read(run):
+    return scope_trace.scope_ms(run, ('gdn_gates',))

@@ -15,7 +15,9 @@ the causal LM loss.
 A model whose layers are not all of one kind hands `apply_stack` a `Pattern`:
 the kinds by name, each with its parts (`qkv_part` None where the kind has no
 attention in its middle and `out_part(x, None, layer, rng)` mixes the
-positions itself), the kinds of the leading layers, of one period and of the
+positions itself; a third, the kind's own `attend`, where something other than
+the attention dispatch stands between its two parts: a recurrent scan beside
+kinds that attend), the kinds of the leading layers, of one period and of the
 trailing layers, in the published order. Leading and trailing layers are
 applied once each; the periods are one `lax.scan` whose body is a period's
 layers unrolled, each place in the period with a stack of its own over the
@@ -37,7 +39,7 @@ class Pattern(NamedTuple):
     of the layers at that place, stacked over the periods on a leading axis],
     "trailing": [one tree a layer, ...]}`: the scan slices every place's
     stack by the period and nothing is indexed inside its body."""
-    kinds: Dict[str, Tuple[Optional[Callable], Callable]]  # name -> (qkv_part | None, out_part)
+    kinds: Dict[str, tuple]  # name -> (qkv_part | None, out_part[, the kind's own `block(attend=)`])
     period: Tuple[str, ...]  # the kinds of one period's layers
     n_periods: int
     leading: Tuple[str, ...] = ()  # the kinds of the layers before the first period
@@ -116,7 +118,7 @@ def apply_stack(
     num_microbatches: Optional[int] = None,
     seq_streams: tuple = (),
     layers_rng=None,
-    attend: Optional[Callable] = None,  # `block`'s: in place of the attention dispatch, in every layer
+    attend: Optional[Callable] = None,  # `block`'s: in place of the attention dispatch, in every layer whose kind brings none
 ) -> Tuple[Any, Any]:
     """Returns (activations, aux_sum): `block` over every layer, `out_part`'s
     scalar aux summed. `seq_streams` are per-position arrays (leading dim S,
@@ -138,7 +140,8 @@ def apply_stack(
             if mb_idx is not None:
                 # Independent dropout mask per microbatch under PP.
                 rng = jax.random.fold_in(rng, mb_idx)
-        return block(x, layer, config, *pattern.kinds[kind], attn, mesh, streams, rng, attend)
+        qkv, out, *own = pattern.kinds[kind]
+        return block(x, layer, config, qkv, out, attn, mesh, streams, rng, own[0] if own else attend)
 
     def period_fn(first_layer, attn, mb_idx, streams, x, xs):
         """The scan's body over (a period's layers, idx), once the first four

@@ -27,6 +27,7 @@ Case names:
     selected:BxHxKVxSxD         both flash kernels under a packed `keep`, grouped heads; and with no `keep`
     masked:BxHxKVxSxDxBLOCK     both flash kernels under `BlockDiffusion(S / 2, BLOCK)`, a mask by structure
     indexer:BxHxKVxSxDxIHxIDxK  `select` and `index_loss` of `ops/lightning_indexer.py`
+    gdn:BxHxSxDKxDV             `gdn_fwd` and `gdn_bwd` of `ops/gated_delta_rule.py`, the call and its gradient
     row_movers:TOKENS           `gather_rows` and `sum_rows` over a held prefix
     held_experts                an LFM2 step whose expert layer holds 2 of 16 experts
     lower:MESH, compile:MESH    gpt2_small's step over d1, d4 or d2t2, lowered or compiled
@@ -284,6 +285,25 @@ def _indexer_case(topo, batch, heads, kv_heads, seq, d, index_heads, index_d, to
             "index_loss_mosaic_calls": index_loss.as_text().count("tpu_custom_call")}
 
 
+def _gdn_case(topo, batch, heads, seq, dk, dv):
+    """The gated delta rule's two kernels at a linear layer's shapes, one device."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.ops import gated_delta_rule as gdn
+
+    one = jax.sharding.SingleDeviceSharding(topo.devices[0])
+    sd = lambda shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(shape, dtype, sharding=one)  # noqa: E731
+    wide, gates = (batch, heads, seq), sd((batch, heads, seq), jnp.float32)
+    loss = lambda *a: gdn.gated_delta_rule(*a, backend="pallas").astype(jnp.float32).sum()  # noqa: E731
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        sd((*wide, dk)), sd((*wide, dk)), sd((*wide, dv)), gates, gates).compile().as_text()
+    return {"mosaic_calls": text.count("tpu_custom_call"),
+            "kernels": sorted(set(re.findall(r"(gdn_fwd|gdn_bwd)[.\d]* = ", text))),
+            "chunks": sorted(set(re.findall(r"\b(chunk_\d+)\b", text))),
+            "states": sorted(set(re.findall(r"f32\[%d,\d+,%d,%d\]" % (batch * heads, dk, dv), text)))}
+
+
 def _row_movers_case(topo, tokens, k=4, width=2048, n_experts=64, held=8):
     """`gather_rows` and `sum_rows` over the prefix of a layer that holds `held`
     of `n_experts` experts, at a cell's shapes, one device."""
@@ -426,6 +446,8 @@ def _case(topo, case):
         return _masked_case(topo, *numbers())
     if name == "indexer":
         return _indexer_case(topo, *numbers())
+    if name == "gdn":
+        return _gdn_case(topo, *numbers())
     if name == "row_movers":
         return _row_movers_case(topo, int(rest))
     if case == "held_experts":

@@ -2,6 +2,7 @@
 
     python3 benchmark/run.py --workload lfm2-24b-a2b-ep8-l5.fed4k --seed 7 --seconds 20 --trace 1
     python3 tools/scope_table.py benchmark/out/lfm2-24b-a2b-ep8-l5.fed4k.7 router dispatch combine
+    python3 tools/scope_table.py benchmark/out/olmo-hybrid-7b-fsdp4.fed4k.7 gdn gdn_conv gdn_gates gdn_out dense_mlp attn_out
 
 The first argument is a traced run's stem under a checkout's `benchmark/out/`
 (`<cell>.<seed>`, what `--trace 1` leaves there: `<stem>.trace.json` and rank
@@ -11,7 +12,7 @@ a step of everything under it (what `benchmark/harness/scope_trace.scope_ms`
 reads: `moe.router_ms`, `moe.dispatch_ms`, ...), then every device operation of
 the traced steps under it, grouped by opcode, result type, the step's phase and
 the last two components of its `op_name` (before them, for a pair-streamed flash call, what it
-walked and scored: `tiles_<walked>of<all>/keys_<scored>of<walked>`): calls a step, ms a step (the sum of the calls'
+walked and scored: `tiles_<walked>of<all>/keys_<scored>of<walked>`; for a gated delta-rule kernel its `chunk_<C>`): calls a step, ms a step (the sum of the calls'
 own time over the traced steps, divided by their number), most first. With
 `--json PATH` the rows are written there too, each with its instructions' names.
 
@@ -58,8 +59,9 @@ def table(stem: str, scopes):
             if not any(s <= start and start + dur <= s + d for _, _, s, d in runs):
                 continue
             parts = op_names[op_name].split("/")
-            # A pair-streamed flash call says what it walked and scored (`tiles_<walked>of<all>`, `keys_<scored>of<walked>`).
-            walk = [part for part in parts[:-2] if re.fullmatch(r"(tiles|keys)_\d+of\d+", part)]
+            # A pair-streamed flash call says what it walked and scored (`tiles_<walked>of<all>`, `keys_<scored>of<walked>`),
+            # a gated delta-rule kernel the positions of its chunk (`chunk_<C>`).
+            walk = [part for part in parts[:-2] if re.fullmatch(r"(tiles|keys)_\d+of\d+|chunk_\d+", part)]
             tail = program_trace.phase(op_names[op_name]) + " " + "/".join(walk + parts[-2:])
             row = rows.setdefault((scope, target or opcode, rtype, tail), {
                 "scope": scope, "opcode": target or opcode, "type": rtype, "tail": tail,
