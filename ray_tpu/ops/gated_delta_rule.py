@@ -29,13 +29,31 @@ and no row-by-row substitution, the same in the XLA form and in the kernel.
 plain two-dimensional arrays, gates as rows `(1, C)`: the XLA form maps
 `_chunk_fwd` over batch and heads inside a scan over the chunks (what runs off
 the TPU, differentiated by jax, and what the kernels are held to); the Mosaic
-kernels `gdn_fwd` and `gdn_bwd` call the same two functions on their blocks, a
-head a program, the chunks along a sequential grid axis with the state (`dS`
-in the reverse walk) in VMEM scratch. The forward kernel writes out the state
-every chunk starts from, (B, H, S / C, d_k, d_v) f32, for the backward pass,
-which makes `T` and `N` again. Every product that touches the state, the decay
-or `T` is f32 x f32 at full precision; only `K K^T` and `Q K^T` multiply the
-operands as they come (bf16 in a bf16 model, f32 accumulation).
+kernels `gdn_fwd` and `gdn_bwd` call the same two functions on their blocks,
+the chunks along a sequential grid axis with the state (`dS` in the reverse
+walk) in VMEM scratch. The forward kernel writes out the state every chunk
+starts from, (B, H, S / C, d_k, d_v) f32, for the backward pass, which makes
+`T` and `N` again.
+
+A program walks G heads, unrolled in one body (`heads_per_program`: a divisor
+of the heads the call holds, by the VMEM they need; the kernels' scope says
+which, `chunk_128/heads_3of30`). The doubling is twelve products of C^3 each
+waiting for the one before it, and a head alone has nothing to issue while
+one drains; heads share nothing, so G of them are G independent chains, made
+level by level side by side (`_unit_lower_inverses`): Mosaic overlaps products
+that stand next to each other in the program, and does not lift a later head's
+over an earlier head's chain (heads unrolled one after the other gained nothing).
+
+The MXU passes of a product follow its operands' types as they arrive (`_mm`).
+Every product of two f32 arrays (the state, the decay, `T`, `N`, a cotangent
+on both sides) is at full f32 precision, six passes. A product of a bf16 array
+(q, k, do in a bf16 model) with an f32 one is three passes, the bf16 array
+against the f32 one's three bf16 parts: the six-pass product of its cast to
+f32 without the three passes that multiply the cast's zero parts; so that the
+bf16 array itself is the operand, a row scaling stands on the product's other
+side (`(q eg) S = eg (q S)`, `(k to_end)^T N = k^T (to_end N)`). `K K^T` and
+`Q K^T` multiply the operands as they come (bf16 in a bf16 model, f32
+accumulation). With f32 operands every product is six passes.
 """
 
 from __future__ import annotations
@@ -52,17 +70,37 @@ from jax.experimental.pallas import tpu as pltpu
 # layer-row of the Olmo-Hybrid cell takes 12.0 ms forward and backward at 128, 13.7 at 64, 18.2 at 32
 # (`tools/gdn_bench.py`, PR 51), and the states kept for the backward pass halve with each doubling.
 CHUNK = 128
-F32 = jnp.float32
+F32, BF16 = jnp.float32, jnp.bfloat16
 NT = (((1,), (1,)), ((), ()))  # a @ b.T
 NN = (((1,), (0,)), ((), ()))  # a @ b
 TN = (((0,), (0,)), ((), ()))  # a.T @ b
 
 
+def _bf16_parts(x):
+    """An f32 array as three bf16 arrays whose sum (in f32) is the array to 2^-24 of it: its high, middle
+    and low parts, what the MXU's six-pass product makes of an f32 operand."""
+    high = x.astype(BF16)
+    rest = x - high.astype(F32)
+    middle = rest.astype(BF16)
+    return high, middle, (rest - middle.astype(F32)).astype(BF16)
+
+
 def _mm(a, b, dims=NN):
-    """a . b with f32 accumulation; at full f32 precision where both are f32."""
-    exact = a.dtype == F32 and b.dtype == F32
-    return jax.lax.dot_general(a, b, dims, preferred_element_type=F32,
-                               precision=jax.lax.Precision.HIGHEST if exact else None)
+    """a . b with f32 accumulation, by the operands' types as they arrive. Both f32: at full f32 precision,
+    the MXU's six passes (an operand is three bf16 parts; the six products of parts that matter). One
+    bf16, the other f32: three passes, the bf16 operand against the three parts of the f32 one, which
+    is the six-pass product of its cast to f32 with the passes that multiply zeros left out. Both
+    bf16 (or any other pair): one product of the operands as they come."""
+    one = functools.partial(jax.lax.dot_general, dimension_numbers=dims, preferred_element_type=F32)
+    if a.dtype == F32 and b.dtype == F32:
+        return one(a, b, precision=jax.lax.Precision.HIGHEST)
+    if a.dtype == BF16 and b.dtype == F32:
+        high, middle, low = (one(a, part) for part in _bf16_parts(b))
+        return high + (middle + low)
+    if a.dtype == F32 and b.dtype == BF16:
+        high, middle, low = (one(part, b) for part in _bf16_parts(a))
+        return high + (middle + low)
+    return one(a, b)
 
 
 def _iotas(n: int):
@@ -80,67 +118,78 @@ def _row(col):
     return jnp.sum(jnp.where(r == c, col, 0.0), axis=0, keepdims=True)
 
 
-def _unit_lower_inverse(a):
-    """(I + a)^-1 for a strictly lower triangular (C, C), C a power of two, by
-    doubling the block size of the block diagonal's inverse."""
-    n = a.shape[0]
+def _unit_lower_inverses(mats):
+    """(I + a)^-1 for each strictly lower triangular (C, C) `a` of `mats`, C a power of two, by doubling
+    the block size of the block diagonal's inverse; a level's two products for every matrix before the
+    next level's, so that each product stands beside the other matrices' and not behind its own last one."""
+    n = mats[0].shape[0]
     r, c = _iotas(n)
-    joins = lambda level: jnp.where(((r ^ c) >> level) == 1, a, 0.0)  # noqa: E731  (same block of 2b, other half of it)
-    x = (r == c).astype(F32) - joins(0)  # blocks of 2: X - X E X with X the identity
+    joins = lambda a, level: jnp.where(((r ^ c) >> level) == 1, a, 0.0)  # noqa: E731  (same block of 2b, other half of it)
+    xs = [(r == c).astype(F32) - joins(a, 0) for a in mats]  # blocks of 2: X - X E X with X the identity
     level = 1
     while (1 << level) < n:
-        x = x - _mm(x, _mm(joins(level), x))
+        ys = [_mm(joins(a, level), x) for a, x in zip(mats, xs)]
+        xs = [x - _mm(x, y) for x, y in zip(xs, ys)]
         level += 1
-    return x
+    return xs
 
 
-def _chunk_parts(q, k, v, gam, beta, s):
-    """What the forward and the backward pass of a chunk both need."""
+def _unit_lower_inverse(a):
+    return _unit_lower_inverses([a])[0]
+
+
+def _chunk_gates(k, gam, beta):
+    """What a chunk's gates make, and `A` of them and the keys: all that stands before the inverse."""
     n = k.shape[0]
     r, c = _iotas(n)
     gam_c, beta_c = _col(gam), _col(beta)
     decay = jnp.exp(jnp.where(r >= c, gam_c - gam, -jnp.inf))  # D: 0 above the diagonal, 1 on it
     a = jnp.where(r > c, beta_c * _mm(k, k, NT) * decay, 0.0)
-    t = _unit_lower_inverse(a)
-    p = _mm(q, k, NT) * decay
-    qf, kf, vf = q.astype(F32), k.astype(F32), v.astype(F32)
-    eg = jnp.exp(gam_c)
-    ks = _mm(kf, s)
-    z = vf - eg * ks
-    new = _mm(t, beta_c * z)  # N
     last = jax.lax.broadcasted_iota(jnp.int32, (1, n), 1) == n - 1
     gam_end = jnp.sum(jnp.where(last, gam, 0.0), axis=1, keepdims=True)  # (1, 1)
-    to_end = jnp.exp(gam_end - gam_c)
-    return dict(r=r, c=c, gam_c=gam_c, beta_c=beta_c, decay=decay, a=a, t=t, p=p, qf=qf, kf=kf, eg=eg,
-                ks=ks, z=z, new=new, gam_end=gam_end, to_end=to_end, last=last)
+    return dict(r=r, c=c, gam_c=gam_c, beta_c=beta_c, decay=decay, a=a, eg=jnp.exp(gam_c), last=last,
+                gam_end=gam_end, to_end=jnp.exp(gam_end - gam_c))
 
 
-def _chunk_fwd(q, k, v, gam, beta, s):
+def _chunk_parts(q, k, v, gam, beta, s, first=None):
+    """What the forward and the backward pass of a chunk both need. `first`: `_chunk_gates`' parts with the
+    inverse `t` among them, where the caller has made them (a kernel, for its heads together)."""
+    if first is None:
+        first = _chunk_gates(k, gam, beta)
+        first["t"] = _unit_lower_inverse(first["a"])
+    p = _mm(q, k, NT) * first["decay"]
+    ks = _mm(k, s)
+    z = v.astype(F32) - first["eg"] * ks
+    new = _mm(first["t"], first["beta_c"] * z)  # N
+    return dict(first, p=p, ks=ks, z=z, new=new)
+
+
+def _chunk_fwd(q, k, v, gam, beta, s, first=None):
     """One chunk of one head: q, k (C, d_k), v (C, d_v), `gam` the running sum
     of g inside the chunk and `beta` as rows (1, C) f32, `s` (d_k, d_v) f32 the
     state before it. Returns (o (C, d_v) f32, the state after it)."""
-    m = _chunk_parts(q, k, v, gam, beta, s)
-    o = _mm(m["qf"] * m["eg"], s) + _mm(m["p"], m["new"])
-    s_new = jnp.exp(m["gam_end"]) * s + _mm(m["kf"] * m["to_end"], m["new"], TN)
+    m = _chunk_parts(q, k, v, gam, beta, s, first)
+    o = m["eg"] * _mm(q, s) + _mm(m["p"], m["new"])
+    s_new = jnp.exp(m["gam_end"]) * s + _mm(k, m["to_end"] * m["new"], TN)
     return o, s_new
 
 
-def _chunk_bwd(q, k, v, gam, beta, s, do, ds_new):
+def _chunk_bwd(q, k, v, gam, beta, s, do, ds_new, first=None):
     """The chunk's vector-Jacobian product: from `do` (C, d_v) and the
     cotangent `ds_new` of the state after the chunk to (dq, dk, dv, dgam (1, C),
     dbeta (1, C), ds), all f32. The lines follow `_chunk_fwd`'s backwards."""
-    m = _chunk_parts(q, k, v, gam, beta, s)
+    m = _chunk_parts(q, k, v, gam, beta, s, first)
     r, c, decay, a, t, p = m["r"], m["c"], m["decay"], m["a"], m["t"], m["p"]
-    qf, kf, eg, beta_c, ks, new = m["qf"], m["kf"], m["eg"], m["beta_c"], m["ks"], m["new"]
-    do = do.astype(F32)
+    eg, beta_c, ks, new, to_end = m["eg"], m["beta_c"], m["ks"], m["new"], m["to_end"]
+    qf, kf = q.astype(F32), k.astype(F32)  # for the sums over a row; the products take q, k, do as they come
     rows = lambda x: jnp.sum(x, axis=1, keepdims=True)  # noqa: E731
     cols = lambda x: jnp.sum(x, axis=0, keepdims=True)  # noqa: E731
     # s_new = exp(gam_end) s + (k to_end)^T new
-    e_end, kd = jnp.exp(m["gam_end"]), kf * m["to_end"]
+    e_end, kd = jnp.exp(m["gam_end"]), kf * to_end
     ds = e_end * ds_new
-    d_new = _mm(kd, ds_new)
+    d_new = to_end * _mm(k, ds_new)
     d_kd = _mm(new, ds_new, NT)
-    dk = m["to_end"] * d_kd
+    dk = to_end * d_kd
     through_kd = rows(d_kd * kd)
     dgam_c = -through_kd
     dgam_end = e_end * jnp.sum(rows(ds_new * s), axis=0, keepdims=True) + cols(through_kd)
@@ -154,8 +203,8 @@ def _chunk_bwd(q, k, v, gam, beta, s, do, ds_new):
     d_new += _mm(p, do, TN)
     # p = (q k^T) decay, on and under the diagonal
     dp_decayed = dp * decay
-    dq += _mm(dp_decayed, kf)
-    dk += _mm(dp_decayed, qf, TN)
+    dq += _mm(dp_decayed, k)
+    dk += _mm(dp_decayed, q, TN)
     through_p = dp * p
     dgam_c += rows(through_p)
     dgam_r = -cols(through_p)
@@ -164,8 +213,8 @@ def _chunk_bwd(q, k, v, gam, beta, s, do, ds_new):
     da = -_mm(dr, new, NT)
     # a = beta_i (k_i . k_j) decay, under the diagonal
     da_decayed = jnp.where(r > c, da * decay, 0.0)
-    d_kb = _mm(da_decayed, kf)
-    dk += _mm(da_decayed, beta_c * kf, TN) + beta_c * d_kb
+    d_kb = _mm(da_decayed, k)
+    dk += _mm(beta_c * da_decayed, k, TN) + beta_c * d_kb
     dbeta_c = rows(d_kb * kf)
     through_a = da * a
     dgam_c += rows(through_a)
@@ -176,7 +225,7 @@ def _chunk_bwd(q, k, v, gam, beta, s, do, ds_new):
     d_ks = -(beta_c * eg) * dr
     dgam_c += rows(d_ks * ks)
     dk += _mm(d_ks, s, NT)
-    ds += _mm(kf, d_ks, TN)
+    ds += _mm(k, d_ks, TN)
     dgam = _row(dgam_c) + dgam_r + jnp.where(m["last"], dgam_end, 0.0)
     return dq, dk, dv, dgam, _row(dbeta_c), ds
 
@@ -206,6 +255,23 @@ def _xla_gated_delta_rule(q, k, v, gam, beta, chunk: int):
 
 
 # --------------------------------------------------------------------------- the kernels
+# `f` as the kernels call it. A kernel's body is traced for every call in a step and every time the step is traced (36
+# times in the Olmo-Hybrid cell's set-up), the program's heads unrolled in it; under `jax.jit` the chunk's mathematics
+# is a jaxpr kept by function and operand types, which Python walks once a process (`compile.trace_s` 48.3 -> 13.8 s
+# there, PR 52), and the lowering writes it in line where it is called.
+_once = jax.jit
+
+
+def _heads_of_a_program(k_ref, gam_ref, beta_ref, at):
+    """[(k, gam, beta, `_chunk_gates`' parts and the inverse `t`)] of chunk `at`, one a head of the program. The
+    heads share nothing, so their doublings are independent chains: made together, level by level."""
+    heads = [(k_ref[h], gam_ref[h, pl.ds(at, 1), :], beta_ref[h, pl.ds(at, 1), :]) for h in range(k_ref.shape[0])]
+    firsts = [_once(_chunk_gates)(*head) for head in heads]
+    for first, t in zip(firsts, _once(_unit_lower_inverses)([first["a"] for first in firsts])):
+        first["t"] = t
+    return [(*head, first) for head, first in zip(heads, firsts)]
+
+
 def _fwd_kernel(q_ref, k_ref, v_ref, gam_ref, beta_ref, o_ref, states_ref, s_ref):
     i = pl.program_id(1)
 
@@ -213,12 +279,13 @@ def _fwd_kernel(q_ref, k_ref, v_ref, gam_ref, beta_ref, o_ref, states_ref, s_ref
     def _():
         s_ref[...] = jnp.zeros_like(s_ref)
 
-    s = s_ref[...]
-    states_ref[0, 0] = s
-    o, s_new = _chunk_fwd(q_ref[0], k_ref[0], v_ref[0], gam_ref[0, pl.ds(i, 1), :],
-                          beta_ref[0, pl.ds(i, 1), :], s)
-    o_ref[0] = o.astype(o_ref.dtype)
-    s_ref[...] = s_new
+    heads = _heads_of_a_program(k_ref, gam_ref, beta_ref, i)
+    for h, (k, gam, beta, first) in enumerate(heads):
+        s = s_ref[h]
+        states_ref[h, 0] = s
+        o, s_new = _once(_chunk_fwd)(q_ref[h], k, v_ref[h], gam, beta, s, first)
+        o_ref[h] = o.astype(o_ref.dtype)
+        s_ref[h] = s_new
 
 
 def _bwd_kernel(q_ref, k_ref, v_ref, gam_ref, beta_ref, states_ref, do_ref,
@@ -230,25 +297,65 @@ def _bwd_kernel(q_ref, k_ref, v_ref, gam_ref, beta_ref, states_ref, do_ref,
     def _():
         ds_ref[...] = jnp.zeros_like(ds_ref)
 
-    dq, dk, dv, dgam, dbeta, ds = _chunk_bwd(
-        q_ref[0], k_ref[0], v_ref[0], gam_ref[0, pl.ds(at, 1), :], beta_ref[0, pl.ds(at, 1), :],
-        states_ref[0, 0], do_ref[0], ds_ref[...])
-    dq_ref[0] = dq.astype(dq_ref.dtype)
-    dk_ref[0] = dk.astype(dk_ref.dtype)
-    dv_ref[0] = dv.astype(dv_ref.dtype)
-    dgam_ref[0, pl.ds(at, 1), :] = dgam
-    dbeta_ref[0, pl.ds(at, 1), :] = dbeta
-    ds_ref[...] = ds
+    heads = _heads_of_a_program(k_ref, gam_ref, beta_ref, at)
+    for h, (k, gam, beta, first) in enumerate(heads):
+        dq, dk, dv, dgam, dbeta, ds = _once(_chunk_bwd)(
+            q_ref[h], k, v_ref[h], gam, beta, states_ref[h, 0], do_ref[h], ds_ref[h], first)
+        dq_ref[h] = dq.astype(dq_ref.dtype)
+        dk_ref[h] = dk.astype(dk_ref.dtype)
+        dv_ref[h] = dv.astype(dv_ref.dtype)
+        dgam_ref[h, pl.ds(at, 1), :] = dgam
+        dbeta_ref[h, pl.ds(at, 1), :] = dbeta
+        ds_ref[h] = ds
 
 
-def chunk_flops(chunk: int, dk: int, dv: int, backward: bool = False) -> int:
-    """Products the kernels issue for one chunk of one head (2 a multiply-add),
-    the doubling's `2 (log2 C - 1)` products of C^3 included: what XLA is told."""
-    levels = chunk.bit_length() - 2
-    common = 2 * chunk * chunk * dk * 2 + 2 * levels * 2 * chunk ** 3 + 2 * chunk * dk * dv + 2 * chunk * chunk * dv
+def mxu_passes(chunk: int, dk: int, dv: int, dtype, backward: bool = False) -> float:
+    """MXU passes of 128^3 multiply-adds the kernels issue for one chunk of one head with q, k, v (and do)
+    of `dtype`: six for a product of two f32 arrays, three where one operand is bf16, one where both are."""
+    bf16 = jnp.dtype(dtype) == BF16
+    operands, one_cast = (1, 3) if bf16 else (6, 6)
+    unit = 128 ** 3
+    ccd, cdd, ccv = chunk * chunk * dk / unit, chunk * dk * dv / unit, chunk * chunk * dv / unit
+    doubling = 6 * 2 * (chunk.bit_length() - 2) * chunk ** 3 / unit
+    passes = doubling + 2 * operands * ccd + one_cast * cdd + 6 * ccv  # K K^T, Q K^T; K S; T R
     if not backward:
-        return common + 2 * chunk * dk * dv * 2 + 2 * chunk * chunk * dv
-    return common + 2 * chunk * dk * dv * 6 + 2 * chunk * chunk * dv * 4 + 2 * chunk * chunk * dk * 4
+        return passes + 2 * one_cast * cdd + 6 * ccv  # Q S, K^T N; P N
+    # k dS', dO S^T, qg^T dO, K^T dKS; dO N^T, P^T dO; dP K, dP^T Q, dA K, (beta dA)^T K
+    passes += one_cast * (4 * cdd + 2 * ccv + 4 * ccd)
+    return passes + 6 * (2 * cdd + 2 * ccv)  # N dS'^T, dKS S^T; T^T dN, dR N^T
+
+
+def chunk_flops(chunk: int, dk: int, dv: int, dtype, backward: bool = False) -> int:
+    """Multiply-adds (2 FLOP each) the kernels issue for one chunk of one head, the doubling's
+    `2 (log2 C - 1)` products of C^3 included and a product counted once for every MXU pass it takes
+    (`mxu_passes`): what XLA is told."""
+    return int(2 * 128 ** 3 * mxu_passes(chunk, dk, dv, dtype, backward))
+
+
+def _lanes(d: int) -> int:
+    return -(-d // 128) * 128
+
+
+# What a program's heads may hold of VMEM between them: three quarters of the 16 MiB Mosaic gives a kernel on the v5e.
+VMEM_BUDGET = 12 << 20
+# Heads a program at most. On the v5e a layer-row of the Olmo-Hybrid cell (30 heads x 4,096) takes, forward + backward,
+# 10.67 ms at one head a program, 7.65 at two, 7.28 at three, 7.20 at five, 7.05 at six, and the two kernels compile in
+# 0.8, 1.6, 3.5, 6.5, 7.9 s (`tools/gdn_bench.py --heads`, PR 52): past three the doubling is within 16 % of its six-pass
+# floor and a further head buys 1 % for twice the compile.
+MAX_HEADS = 3
+
+
+def heads_per_program(heads: int, seq: int, chunk: int, dk: int, dv: int, itemsize: int) -> int:
+    """G, the heads one program walks side by side: the largest divisor of the `heads` the call holds (batch x
+    heads on this device), at most `MAX_HEADS`, whose blocks, scratch and working set in the backward kernel (the
+    larger one) fit `VMEM_BUDGET`; 1 where nothing divides them."""
+    n = seq // chunk
+    # q, k, dq, dk; v, do, dv; the chunk's state; the two gates and their gradients, a whole row of them a head
+    blocks = (4 * chunk * _lanes(dk) + 3 * chunk * _lanes(dv)) * itemsize + dk * _lanes(dv) * 4 + 4 * n * chunk * 4
+    working = (8 * chunk * _lanes(chunk) + 4 * chunk * (_lanes(dk) + _lanes(dv))) * 4  # f32 values live at once
+    a_head = 2 * blocks + dk * _lanes(dv) * 4 + working  # every block has two buffers; dS in scratch
+    fit = max(1, min(MAX_HEADS, VMEM_BUDGET // a_head))
+    return max(g for g in range(1, fit + 1) if heads % g == 0)
 
 
 def _compiler_params(interpret):
@@ -260,26 +367,34 @@ def _gates_by_chunk(x, chunk: int):
     return x.reshape(bh, s // chunk, chunk)
 
 
+def _plan(k, v, chunk):
+    """(G, the two scopes that name the plan: `chunk_128`, `heads_3of30`)."""
+    bh, seq, dk = k.shape
+    g = heads_per_program(bh, seq, chunk, dk, v.shape[-1], k.dtype.itemsize)
+    return g, f"chunk_{chunk}", f"heads_{g}of{bh}"
+
+
 def _fwd(q, k, v, gam, beta, chunk, interpret):
     """Flat heads: q, k (BH, S, d_k), v (BH, S, d_v), gam, beta (BH, S) f32."""
     bh, seq, dk = k.shape
     dv, n = v.shape[-1], seq // chunk
-    per_chunk = lambda d: pl.BlockSpec((1, chunk, d), lambda h, i: (h, i, 0))  # noqa: E731
-    per_head = pl.BlockSpec((1, n, chunk), lambda h, i: (h, 0, 0))
-    with jax.named_scope(f"chunk_{chunk}"):
+    g, chunk_scope, heads_scope = _plan(k, v, chunk)
+    per_chunk = lambda d: pl.BlockSpec((g, chunk, d), lambda h, i: (h, i, 0))  # noqa: E731
+    per_head = pl.BlockSpec((g, n, chunk), lambda h, i: (h, 0, 0))
+    with jax.named_scope(chunk_scope), jax.named_scope(heads_scope):
         return pl.pallas_call(
             _fwd_kernel,
-            grid=(bh, n),
+            grid=(bh // g, n),
             in_specs=[per_chunk(dk), per_chunk(dk), per_chunk(dv), per_head, per_head],
-            out_specs=[per_chunk(dv), pl.BlockSpec((1, 1, dk, dv), lambda h, i: (h, i, 0, 0))],
+            out_specs=[per_chunk(dv), pl.BlockSpec((g, 1, dk, dv), lambda h, i: (h, i, 0, 0))],
             out_shape=[jax.ShapeDtypeStruct(v.shape, v.dtype),
                        jax.ShapeDtypeStruct((bh, n, dk, dv), F32)],
-            scratch_shapes=[pltpu.VMEM((dk, dv), F32)],
+            scratch_shapes=[pltpu.VMEM((g, dk, dv), F32)],
             interpret=interpret,
             name="gdn_fwd",
             compiler_params=_compiler_params(interpret),
             cost_estimate=pl.CostEstimate(
-                flops=bh * n * chunk_flops(chunk, dk, dv),
+                flops=bh * n * chunk_flops(chunk, dk, dv, k.dtype),
                 bytes_accessed=bh * (seq * (2 * dk + 2 * dv) * q.dtype.itemsize + n * dk * dv * 4 + 2 * seq * 4),
                 transcendentals=bh * n * chunk * chunk),
         )(q, k, v, _gates_by_chunk(gam, chunk), _gates_by_chunk(beta, chunk))
@@ -288,24 +403,25 @@ def _fwd(q, k, v, gam, beta, chunk, interpret):
 def _bwd(q, k, v, gam, beta, states, do, chunk, interpret):
     bh, seq, dk = k.shape
     dv, n = v.shape[-1], seq // chunk
-    per_chunk = lambda d: pl.BlockSpec((1, chunk, d), lambda h, i: (h, n - 1 - i, 0))  # noqa: E731
-    per_head = pl.BlockSpec((1, n, chunk), lambda h, i: (h, 0, 0))
+    g, chunk_scope, heads_scope = _plan(k, v, chunk)
+    per_chunk = lambda d: pl.BlockSpec((g, chunk, d), lambda h, i: (h, n - 1 - i, 0))  # noqa: E731
+    per_head = pl.BlockSpec((g, n, chunk), lambda h, i: (h, 0, 0))
     gates = jax.ShapeDtypeStruct((bh, n, chunk), F32)
-    with jax.named_scope(f"chunk_{chunk}"):
+    with jax.named_scope(chunk_scope), jax.named_scope(heads_scope):
         dq, dk_, dv_, dgam, dbeta = pl.pallas_call(
             _bwd_kernel,
-            grid=(bh, n),
+            grid=(bh // g, n),
             in_specs=[per_chunk(dk), per_chunk(dk), per_chunk(dv), per_head, per_head,
-                      pl.BlockSpec((1, 1, dk, dv), lambda h, i: (h, n - 1 - i, 0, 0)), per_chunk(dv)],
+                      pl.BlockSpec((g, 1, dk, dv), lambda h, i: (h, n - 1 - i, 0, 0)), per_chunk(dv)],
             out_specs=[per_chunk(dk), per_chunk(dk), per_chunk(dv), per_head, per_head],
             out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype), jax.ShapeDtypeStruct(k.shape, k.dtype),
                        jax.ShapeDtypeStruct(v.shape, v.dtype), gates, gates],
-            scratch_shapes=[pltpu.VMEM((dk, dv), F32)],
+            scratch_shapes=[pltpu.VMEM((g, dk, dv), F32)],
             interpret=interpret,
             name="gdn_bwd",
             compiler_params=_compiler_params(interpret),
             cost_estimate=pl.CostEstimate(
-                flops=bh * n * chunk_flops(chunk, dk, dv, backward=True),
+                flops=bh * n * chunk_flops(chunk, dk, dv, k.dtype, backward=True),
                 bytes_accessed=bh * (seq * (4 * dk + 4 * dv) * q.dtype.itemsize + n * dk * dv * 4 + 4 * seq * 4),
                 transcendentals=bh * n * chunk * chunk),
         )(q, k, v, _gates_by_chunk(gam, chunk), _gates_by_chunk(beta, chunk), states, do)

@@ -301,6 +301,7 @@ def _gdn_case(topo, batch, heads, seq, dk, dv):
     return {"mosaic_calls": text.count("tpu_custom_call"),
             "kernels": sorted(set(re.findall(r"(gdn_fwd|gdn_bwd)[.\d]* = ", text))),
             "chunks": sorted(set(re.findall(r"\b(chunk_\d+)\b", text))),
+            "plans": sorted(set(re.findall(r"\bchunk_\d+\)*/(heads_\d+of\d+)\b", text))),
             "states": sorted(set(re.findall(r"f32\[%d,\d+,%d,%d\]" % (batch * heads, dk, dv), text)))}
 
 

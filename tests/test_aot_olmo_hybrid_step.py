@@ -3,6 +3,7 @@
 
 import json
 import os
+import re
 
 import pytest
 
@@ -31,6 +32,10 @@ def test_the_scans_kernels_compile_for_the_v5e_at_the_cells_widths(aot):
     got = aot[GDN_4K]
     assert got["mosaic_calls"] == 2 and got["kernels"] == ["gdn_bwd", "gdn_fwd"]
     assert got["chunks"] == [f"chunk_{gdn.CHUNK}"]
+    # The plan beside it: the heads a program walks, what the rule picks for the 30 heads a device of the cell
+    # holds. That many heads unrolled in one body lower for the v5e and fit its VMEM: this compile is the proof.
+    heads = gdn.heads_per_program(30, 4096, gdn.CHUNK, 96, 192, 2)
+    assert heads > 1 and got["plans"] == [f"heads_{heads}of30"]
     # The states the forward kernel keeps for the backward one: one a chunk a head, f32.
     assert got["states"] == [f"f32[30,{4096 // gdn.CHUNK},96,192]"]
 
@@ -43,6 +48,8 @@ def test_the_step_runs_each_kernel_once_a_layer_and_never_again_in_the_backward_
     assert (count("gdn_fwd"), count("gdn_bwd"), count("flash_fwd"), count("flash_bwd")) == (3, 3, 1, 1)
     for name, scope in kernels:
         parts = scope.split("/")
+        if name.startswith("gdn_"):  # the kernels' scope names the plan, inside the step's shard_map too
+            assert re.fullmatch(r"heads_\d+of30", parts[-3]) and parts[-4] == "chunk_128", scope
         assert phase(scope) == ("backward" if name.endswith("_bwd") else "forward")
         assert "rematted_computation" not in parts and "attention" in parts and "shard_map" in parts  # `save_attn`
         assert ("gdn" in parts) == name.startswith("gdn_")

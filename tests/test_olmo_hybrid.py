@@ -111,15 +111,14 @@ def test_a_state_or_a_decay_in_bf16_or_a_decay_left_out_passes_the_limits_of_the
     against the same program sound (which the test above holds to the reference): each fault moves a named
     leaf, whose gradient only the scan's backward pass makes, past the limit that test passes under. So a
     scan that kept its state or its decay in bf16 fails that test."""
-    forward, parts = gdn._chunk_fwd, gdn._chunk_parts
+    forward, gates = gdn._chunk_fwd, gdn._chunk_gates
     bf16 = lambda x: x.astype(jnp.bfloat16).astype(jnp.float32)  # noqa: E731
     if fault == "state_in_bf16":
         monkeypatch.setattr(gdn, "_chunk_fwd", lambda q, k, v, gam, beta, s: forward(q, k, v, gam, beta, bf16(s)))
     elif fault == "decay_in_bf16":
-        monkeypatch.setattr(gdn, "_chunk_parts", lambda q, k, v, gam, beta, s: parts(q, k, v, bf16(gam), beta, s))
+        monkeypatch.setattr(gdn, "_chunk_gates", lambda k, gam, beta: gates(k, bf16(gam), beta))
     else:
-        monkeypatch.setattr(gdn, "_chunk_parts",
-                            lambda q, k, v, gam, beta, s: parts(q, k, v, jnp.zeros_like(gam), beta, s))
+        monkeypatch.setattr(gdn, "_chunk_gates", lambda k, gam, beta: gates(k, jnp.zeros_like(gam), beta))
     _, _, leaves = _system_side(f32, monkeypatch)
     moved = np.abs(leaves - sound[2]) / sound[2]
     assert moved.max() > F32_LIMITS["leaf_tol"], dict(zip(bench.CHECKED_LEAVES, moved))

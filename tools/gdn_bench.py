@@ -1,19 +1,32 @@
-"""The gated delta-rule kernels alone, on the chip: time a call by chunk and by part, beside their floors.
+"""The gated delta-rule kernels alone, on the chip: time a call by chunk, by heads a program and by part, beside their floors.
 
     chiprun -- python3 tools/gdn_bench.py
     chiprun -- python3 tools/gdn_bench.py --chunk 64,128 --part whole,no_inverse,empty --check-seq 512
+    chiprun -- python3 tools/gdn_bench.py --chunk 128 --heads 1,2,3,5,plan --part whole,six_pass,no_inverse
 
 One call of `ops/gated_delta_rule.py gated_delta_rule` and of its gradient (the Mosaic calls named
 `gdn_fwd` and `gdn_bwd` and the XLA running sums round them; nothing else runs) at `--shape
 BATCHxHEADSxSEQxDKxDV`, bf16 q, k (L2-normalised), v and f32 gates made from `--seed`, one linear layer
 of the Olmo-Hybrid cell by default: a row of 4,096, 30 heads, key width 96, value width 192.
 
+How a bf16 operand's products are issued (PR 52): a product of a bf16 array (q, k, do as the layer hands them)
+with an f32 one (the state, `N`, a cotangent) is three MXU passes, the bf16 array against the three bf16 parts
+of the f32 one (`_mm`, `_bf16_parts`): the six-pass product of its cast to f32 less the three passes that would
+multiply the cast's zero middle and low parts. A product of two f32 arrays is six passes, one of two bf16
+arrays one. `passes` in a line is what a chunk of a head issues, in units of 128^3, forward and backward, counted in
+the chunk's jaxpr as the module stands (`whole`: `mxu_passes`, 101.6 + 148.9 at the cell's widths).
+
+`--heads` times the kernels at so many heads a program (`heads_per_program` stood in: a divisor of the heads;
+a count that does not compile, for VMEM, gives a line with `error`), `plan` at what the module's rule picks.
+
 `--part` times the kernels with a stub where a stage is: `no_inverse` stands `I - A` where `(I + A)^-1`
 is made (the doubling's `2 (log2 C - 1)` products of C^3 gone, everything else as it is), `empty` stands zeros
-where a chunk's mathematics is (the grid's steps, the blocks' copies and the state's stores), `whole` the
-kernels. A stubbed kernel's results mean nothing; its time does not depend on the values.
+where a chunk's mathematics is (the grid's steps, the blocks' copies and the state's stores), `six_pass` casts
+a bf16 operand up where it meets an f32 array, so that product is six passes again (PR 51's form: read beside
+`whole`, the three-pass products apart from the heads a program), `whole` the kernels. A stubbed kernel's
+results mean nothing (`six_pass`'s do); its time does not depend on the values.
 
-For each chunk and part a JSON line, on stdout and in `chiprun_out/gdn_bench.jsonl`: `fwd_us` and
+For each chunk, heads a program and part a JSON line, on stdout and in `chiprun_out/gdn_bench.jsonl`: `fwd_us` and
 `bwd_us`, the device time of the Mosaic calls in a trace of `--rounds` calls of the gradient, median
 (what `kernels.gdn_fwd_ms` and `kernels.gdn_bwd_ms` sum a step); `call_us`, the host's clock over one
 call of the gradient closed by `block_until_ready`, median of `--rounds`; `compile_s`; and for `whole`
@@ -38,7 +51,7 @@ import time
 from statistics import median
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-PARTS = ("whole", "no_inverse", "empty")
+PARTS = ("whole", "no_inverse", "empty", "six_pass")
 
 
 def _kernel_us(trace_dir, name):
@@ -93,15 +106,42 @@ def check(jax, jnp, gdn, shape, seed, chunk, interpret):
     return out
 
 
-def stub(gdn, jnp, part):
-    """Stand the part's stub in the module; returns what puts the module back."""
-    kept = {name: getattr(gdn, name) for name in ("_unit_lower_inverse", "_chunk_fwd", "_chunk_bwd")}
-    if part == "no_inverse":
-        gdn._unit_lower_inverse = lambda a: (gdn._iotas(a.shape[0])[0] == gdn._iotas(a.shape[0])[1]).astype(a.dtype) - a
+def passes_issued(jax, jnp, gdn, chunk, dk, dv):
+    """[forward, backward]: the MXU passes, in units of 128^3 multiply-adds, of the products in the jaxpr of one
+    chunk of one head with bf16 operands as the module stands (a stub in place counts): six for a product at
+    full f32 precision, one for any other (a three-pass product is three of those)."""
+    sd = lambda shape, dtype=jnp.float32: jax.ShapeDtypeStruct(shape, dtype)  # noqa: E731
+    wide = lambda d: sd((chunk, d), jnp.bfloat16)  # noqa: E731
+    chunk_args = (wide(dk), wide(dk), wide(dv), sd((1, chunk)), sd((1, chunk)), sd((dk, dv)))
+    out = []
+    for f, args in ((gdn._chunk_fwd, chunk_args), (gdn._chunk_bwd, (*chunk_args, wide(dv), sd((dk, dv))))):
+        passes = 0.0
+        for eqn in jax.make_jaxpr(f)(*args).eqns:
+            if eqn.primitive.name == "dot_general":
+                ((ca,), (cb,)), _ = eqn.params["dimension_numbers"]
+                a, b = (v.aval.shape for v in eqn.invars)
+                full = eqn.params["precision"] is not None  # `_mm` asks for HIGHEST or for nothing
+                passes += (6 if full else 1) * a[1 - ca] * a[ca] * b[1 - cb] / 128 ** 3
+        out.append(passes)
+    return out
+
+
+def stub(gdn, jnp, part, heads):
+    """Stand the part's stub and the heads a program in the module; returns what puts the module back."""
+    kept = {name: getattr(gdn, name)
+            for name in ("_unit_lower_inverses", "_chunk_fwd", "_chunk_bwd", "_mm", "heads_per_program")}
+    if heads != "plan":
+        gdn.heads_per_program = lambda *_: int(heads)
+    if part == "six_pass":
+        f32 = lambda x, other: x.astype(jnp.float32) if other.dtype == jnp.float32 else x  # noqa: E731
+        gdn._mm = lambda a, b, dims=gdn.NN: kept["_mm"](f32(a, b), f32(b, a), dims)
+    elif part == "no_inverse":
+        eye = lambda a: (gdn._iotas(a.shape[0])[0] == gdn._iotas(a.shape[0])[1]).astype(a.dtype)  # noqa: E731
+        gdn._unit_lower_inverses = lambda mats: [eye(a) - a for a in mats]
     elif part == "empty":
         f32 = lambda x: x.astype(jnp.float32)  # noqa: E731
-        gdn._chunk_fwd = lambda q, k, v, gam, beta, s: (f32(v), s)
-        gdn._chunk_bwd = lambda q, k, v, gam, beta, s, do, ds: (f32(q), f32(k), f32(do), gam, beta, ds + s)
+        gdn._chunk_fwd = lambda q, k, v, gam, beta, s, first=None: (f32(v), s)
+        gdn._chunk_bwd = lambda q, k, v, gam, beta, s, do, ds, first=None: (f32(q), f32(k), f32(do), gam, beta, ds + s)
     return lambda: [setattr(gdn, name, f) for name, f in kept.items()]
 
 
@@ -110,6 +150,7 @@ def main():
     p.add_argument("--shape", default="1x30x4096x96x192")
     p.add_argument("--chunk", default="64")
     p.add_argument("--part", default="whole")
+    p.add_argument("--heads", default="plan")
     p.add_argument("--seed", type=int, default=0)
     p.add_argument("--rounds", type=int, default=5)
     p.add_argument("--check-seq", type=int, default=512)
@@ -141,20 +182,27 @@ def main():
     for chunk in (int(c) for c in args.chunk.split(",")):
         say(chunk=chunk, check=check(jax, jnp, gdn, (batch, min(heads, 4), args.check_seq, dk, dv),
                                       args.seed, chunk, args.rehearse))
-        for part in args.part.split(","):
+        for held, part in ((h, p) for h in args.heads.split(",") for p in args.part.split(",")):
             assert part in PARTS, part
-            restore = stub(gdn, jnp, part)
+            restore = stub(gdn, jnp, part, held)
+            jax.clear_caches()  # the kernels keep the chunk's jaxprs by function (`_once`): a stub under one is not seen
+            per_program = gdn.heads_per_program(batch * heads, seq + -seq % chunk, chunk, dk, dv, 2)
+            at = {"chunk": chunk, "heads_per_program": per_program, "part": part}
             try:
                 loss = lambda *a: gdn.gated_delta_rule(  # noqa: E731
                     *a, backend="pallas", chunk=chunk, interpret=args.rehearse).astype(jnp.float32).sum()
                 t = time.perf_counter()
                 call = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(*operands).compile()
                 compile_s = time.perf_counter() - t
+                passes = passes_issued(jax, jnp, gdn, chunk, dk, dv)
+            except Exception as e:  # so many heads do not fit the kernel's VMEM: the line says so
+                say(**at, error=f"{type(e).__name__}: {str(e)[-300:]}")
+                continue
             finally:
                 restore()
             jax.block_until_ready(call(*operands))
             if args.rehearse:
-                say(chunk=chunk, part=part, rehearsal=True, compile_s=round(compile_s, 2))
+                say(**at, passes=passes, rehearsal=True, compile_s=round(compile_s, 2))
                 continue
             clock = []
             for _ in range(args.rounds):
@@ -167,7 +215,7 @@ def main():
                     jax.block_until_ready(call(*operands))
                 jax.profiler.stop_trace()
                 fwd, bwd = (median(_kernel_us(trace_dir, name)) for name in ("gdn_fwd", "gdn_bwd"))
-            line = {"chunk": chunk, "part": part, "programs": batch * heads * (-(-seq // chunk)),
+            line = {**at, "programs": batch * heads // per_program * (-(-seq // chunk)), "passes": passes,
                     "fwd_us": fwd, "bwd_us": bwd, "call_us": median(clock), "compile_s": round(compile_s, 2)}
             if part == "whole":
                 one_layer = {"linear_key_head_dim": dk, "linear_value_head_dim": dv, "linear_num_value_heads": heads,

@@ -45,19 +45,20 @@ def light_system(bench, c, mesh, seed):
 
 @contextlib.contextmanager
 def planted(fault):
-    """The chunk's mathematics with `fault` while the block runs: the XLA form and both kernels call these two."""
+    """The chunk's mathematics with `fault` while the block runs: the XLA form and both kernels call these."""
     import jax.numpy as jnp
 
     from ray_tpu.ops import gated_delta_rule as gdn
 
-    kept = {name: getattr(gdn, name) for name in ("_chunk_fwd", "_chunk_bwd", "_chunk_parts")}
+    kept = {name: getattr(gdn, name) for name in ("_chunk_fwd", "_chunk_bwd", "_chunk_gates")}
     bf16 = lambda x: x.astype(jnp.bfloat16).astype(jnp.float32)  # noqa: E731
     if fault == "state_bf16":
-        gdn._chunk_fwd = lambda q, k, v, gam, beta, s: kept["_chunk_fwd"](q, k, v, gam, beta, bf16(s))
-        gdn._chunk_bwd = lambda q, k, v, gam, beta, s, do, ds: kept["_chunk_bwd"](q, k, v, gam, beta, bf16(s), do, bf16(ds))
+        gdn._chunk_fwd = lambda q, k, v, gam, beta, s, *made: kept["_chunk_fwd"](q, k, v, gam, beta, bf16(s), *made)
+        gdn._chunk_bwd = lambda q, k, v, gam, beta, s, do, ds, *made: kept["_chunk_bwd"](
+            q, k, v, gam, beta, bf16(s), do, bf16(ds), *made)
     else:
         gam_of = bf16 if fault == "decay_bf16" else jnp.zeros_like
-        gdn._chunk_parts = lambda q, k, v, gam, beta, s: kept["_chunk_parts"](q, k, v, gam_of(gam), beta, s)
+        gdn._chunk_gates = lambda k, gam, beta: kept["_chunk_gates"](k, gam_of(gam), beta)
     try:
         yield
     finally:

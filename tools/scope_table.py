@@ -12,7 +12,7 @@ a step of everything under it (what `benchmark/harness/scope_trace.scope_ms`
 reads: `moe.router_ms`, `moe.dispatch_ms`, ...), then every device operation of
 the traced steps under it, grouped by opcode, result type, the step's phase and
 the last two components of its `op_name` (before them, for a pair-streamed flash call, what it
-walked and scored: `tiles_<walked>of<all>/keys_<scored>of<walked>`; for a gated delta-rule kernel its `chunk_<C>`): calls a step, ms a step (the sum of the calls'
+walked and scored: `tiles_<walked>of<all>/keys_<scored>of<walked>`; for a gated delta-rule kernel its `chunk_<C>/heads_<G>of<held>`): calls a step, ms a step (the sum of the calls'
 own time over the traced steps, divided by their number), most first. With
 `--json PATH` the rows are written there too, each with its instructions' names.
 
@@ -60,8 +60,8 @@ def table(stem: str, scopes):
                 continue
             parts = op_names[op_name].split("/")
             # A pair-streamed flash call says what it walked and scored (`tiles_<walked>of<all>`, `keys_<scored>of<walked>`),
-            # a gated delta-rule kernel the positions of its chunk (`chunk_<C>`).
-            walk = [part for part in parts[:-2] if re.fullmatch(r"(tiles|keys)_\d+of\d+|chunk_\d+", part)]
+            # a gated delta-rule kernel the positions of its chunk and the heads a program walks (`chunk_<C>`, `heads_<G>of<held>`).
+            walk = [part for part in parts[:-2] if re.fullmatch(r"(tiles|keys|heads)_\d+of\d+|chunk_\d+", part)]
             tail = program_trace.phase(op_names[op_name]) + " " + "/".join(walk + parts[-2:])
             row = rows.setdefault((scope, target or opcode, rtype, tail), {
                 "scope": scope, "opcode": target or opcode, "type": rtype, "tail": tail,
