@@ -130,7 +130,7 @@ def out_and_experts(x, o, layer, config):
             h, moe["router_w"], moe["w_gate"], moe["w_up"], moe["w_down"],
             k=config.experts_per_token, norm_topk_prob=config.norm_topk_prob,
             held_from=config.first_expert_held)
-    return x + h, aux
+        return x + h, aux
 
 
 def routing_stats(aux: Dict[str, Any], pairs: int) -> Dict[str, Any]:

@@ -312,7 +312,9 @@ def _kinds(config: LFM2Config, stats: bool = False):
 
     def conv_op(x, o, layer):
         del o  # no attention in this kind's middle
-        return x + short_conv(rms_norm(x, layer["op_norm"], eps).astype(cdt), layer, config)
+        with jax.named_scope("conv_norm"):  # the operator's norm: `short_conv` opens its scope after it
+            h = rms_norm(x, layer["op_norm"], eps).astype(cdt)
+        return x + short_conv(h, layer, config)
 
     def dense_ffn(x, layer):
         with jax.named_scope("dense_mlp"):

@@ -44,7 +44,6 @@ from ray_tpu.models.llama import rms_norm
 from ray_tpu.models.moe import swiglu
 from ray_tpu.models.stack import Pattern, apply_stack, lm_head, lm_loss
 from ray_tpu.ops import gated_delta_rule as gdn
-from ray_tpu.util.tracing import annotate
 
 LINEAR, FULL = "linear_attention", "full_attention"
 PERIOD = (LINEAR, LINEAR, LINEAR, FULL)
@@ -286,9 +285,7 @@ def _kinds(config: OlmoHybridConfig):
         del attention_fn  # the full layers'
         if mesh is not None and int(mesh.shape.get("pipeline", 1)) > 1:
             mesh = None  # as `resolve_attention`: no second shard_map inside the pipeline's region
-        with jax.named_scope("gdn"), annotate(
-                "ray_tpu.models.gdn", chunk=gdn.CHUNK, heads=config.linear_heads,
-                state_bytes=4 * config.linear_key_dim * config.linear_value_dim):
+        with jax.named_scope("gdn"):
             return (gdn.gated_delta_rule(q, k, v, g, beta, mesh=mesh),)
 
     def linear_out_part(x, o, layer, rng):

@@ -6,11 +6,11 @@ A model supplies its two halves of a block as pure functions,
 `qkv_part(x, layer, *streams) -> (q, k, v)` in (B, nh, S, hd) and
 `out_part(x, o, layer, rng) -> (x, aux)`, and this module does the rest:
 the remat decision (`config.remat`, `config.remat_policy`), the attention
-dispatch between the halves, the scopes `blocks`, `qkv`, `attention` and
-`head`, the per-layer dropout key, lax.scan over stacked layer params and,
-when the mesh has pipeline > 1, the GPipe microbatch schedule with optional
-in-region ring attention (parallel/pipeline.py); then the head's product and
-the causal LM loss.
+dispatch between the halves, the scopes `blocks`, `layer_scan`, `qkv`,
+`attention` and `head`, the per-layer dropout key, lax.scan over stacked
+layer params and, when the mesh has pipeline > 1, the GPipe microbatch
+schedule with optional in-region ring attention (parallel/pipeline.py); then
+the head's product and the causal LM loss.
 
 A model whose layers are not all of one kind hands `apply_stack` a `Pattern`:
 the kinds by name, each with its parts (`qkv_part` None where the kind has no
@@ -196,11 +196,14 @@ def apply_stack(
         for i, (kind, layer) in enumerate(zip(pattern.leading, blocks["leading"])):
             x, aux = one(kind, layer, i, attention_fn, None, seq_streams, x)
             auxs.append(aux)
-        x, of_periods = jax.lax.scan(
-            functools.partial(period_fn, n_leading, attention_fn, None, seq_streams),
-            x,
-            (blocks["period"], jnp.arange(pattern.n_periods)),
-        )
+        # What the scan itself does round its body (the stacked residuals' buffers, the layers' slices)
+        # gets a name of its own: a trace's account files it there, not under `blocks` bare.
+        with jax.named_scope("layer_scan"):
+            x, of_periods = jax.lax.scan(
+                functools.partial(period_fn, n_leading, attention_fn, None, seq_streams),
+                x,
+                (blocks["period"], jnp.arange(pattern.n_periods)),
+            )
         auxs.append(jnp.sum(of_periods))
         first_trailing = n_leading + pattern.n_periods * per_period
         for i, (kind, layer) in enumerate(zip(pattern.trailing, blocks["trailing"])):

@@ -3,6 +3,7 @@
     python3 benchmark/run.py --workload lfm2-24b-a2b-ep8-l5.fed4k --seed 7 --seconds 20 --trace 1
     python3 tools/scope_table.py benchmark/out/lfm2-24b-a2b-ep8-l5.fed4k.7 router dispatch combine
     python3 tools/scope_table.py benchmark/out/olmo-hybrid-7b-fsdp4.fed4k.7 gdn gdn_conv gdn_gates gdn_out dense_mlp attn_out
+    python3 tools/scope_table.py benchmark/out/gpt2-medium.fed.7 [--by source]
 
 The first argument is a traced run's stem under a checkout's `benchmark/out/`
 (`<cell>.<seed>`, what `--trace 1` leaves there: `<stem>.trace.json` and rank
@@ -15,6 +16,10 @@ the last two components of its `op_name` (before them, for a pair-streamed flash
 walked and scored: `tiles_<walked>of<all>/keys_<scored>of<walked>`; for a gated delta-rule kernel its `chunk_<C>/heads_<G>of<held>`): calls a step, ms a step (the sum of the calls'
 own time over the traced steps, divided by their number), most first. With
 `--json PATH` the rows are written there too, each with its instructions' names.
+
+With no scope it prints the step's whole account (`benchmark/harness/step_account.py`, PR 53): every
+operation of the traced steps by owner, class and phase (`--by source`: by the Python `file:line` the
+trace carries for it), with the flops and bytes XLA counts for it and what part is misfiled.
 
 It reads with the benchmark's own readers (`harness/xplane.py`,
 `harness/program_trace.read_xplane`) of the checkout it lies in, so a copy of
@@ -73,13 +78,41 @@ def table(stem: str, scopes):
     return unions, [{**r, "names": sorted(r["names"])} for r in rows]
 
 
+def account(stem: str, by: str):
+    """The rows of `step_account.Account.rows` for the traced run at `stem`, all of them."""
+    from benchmark.harness import program_trace, step_account, xplane
+    from benchmark.harness.peaks import peaks_for
+
+    run = {"summary": {"trace_table": stem + ".trace.json"}}
+    path = program_trace.raw_trace_path(run)
+    if path is None:
+        raise SystemExit(f"no raw trace of rank 0 beside {stem}.trace.json")
+    with open(stem + ".trace.json") as fh:
+        trace = xplane.Trace(json.load(fh))
+    with open(stem + ".json") as fh:  # the run's record: which device's peaks the floor and XLA's byte count divide by
+        peaks = peaks_for(json.load(fh)["summary"]["device"]["kind"])
+    mine = step_account.Account(trace, step_account.read(path), peaks)
+    rows = mine.rows(by)
+    step_account.print_rows(rows, by)
+    print(f"classes ms/step {json.dumps(mine.classes)} of step.device_ms {trace.step_device_ms()}; "
+          f"unowned {mine.unowned_ms()} misfiled {mine.misfiled_ms()} product floor {mine.product_floor_ms()}")
+    return {"classes": mine.classes, "rows": rows}
+
+
 def main(argv=None):
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("stem", help="benchmark/out/<cell>.<seed> of a --trace 1 run")
-    ap.add_argument("scopes", nargs="+")
-    ap.add_argument("--json", help="also write {unions, rows} here")
+    ap.add_argument("scopes", nargs="*", help="none: the step's whole account")
+    ap.add_argument("--by", choices=("owner", "source"), default="owner", help="the account's rows, with no scope")
+    ap.add_argument("--json", help="also write {unions, rows} (the account's {classes, rows}) here")
     args = ap.parse_args(argv)
     sys.path.insert(0, REPO)
+    if not args.scopes:
+        out = account(args.stem, args.by)
+        if args.json:
+            with open(args.json, "w") as fh:
+                json.dump(out, fh)
+        return
     unions, rows = table(args.stem, args.scopes)
     for scope in args.scopes:
         mine = [r for r in rows if r["scope"] == scope]
