@@ -9,7 +9,7 @@ whole projection. Both kinds norm after the sub-layer, not before it.
     linear:  q = silu(conv4(W_q x)), k = silu(conv4(W_k x))   H heads of d_k
              v = silu(conv4(W_v x))                           H heads of d_v
              conv4: causal, depthwise, `conv_kernel` taps, no bias
-             q, k L2-normalised over a head, q scaled by d_k^-1/2
+             q, k L2-normalised over a head, q scaled by d_k^-1/2      `ops/short_conv.py`, all of it
              beta = 2 sigmoid(w_b . x)          (the 2: `allow_neg_eigval`)
              g = -exp(A_log) softplus(w_a . x + dt_bias)       f32
              o = gated_delta_rule(q, k, v, g, beta)            `ops/gated_delta_rule.py`
@@ -20,13 +20,25 @@ whole projection. Both kinds norm after the sub-layer, not before it.
              through the recurrent ones)
 
 Built from what the zoo has: RMSNorm is `llama.py`'s, the SwiGLU `moe.py`'s,
-the shifted copies of the convolution `lfm2.py`'s, the patterned stack, head
-and loss `stack.py`'s. The linear kind brings its own `attend` (the scan) to
+the patterned stack, head and loss `stack.py`'s. The linear kind brings its own `attend` (the scan) to
 `stack.Pattern`, the full kind keeps the attention dispatch: under
 "save_attn" the scan's residuals (q, k, v, the gates and the chunks' states)
 are saved as the flash call's are, and neither kernel runs again in the
 backward pass. Every matrix is stored as a matrix, its `embed` axis first or
 last, and shards over it (FSDP) as GPT-2's do.
+
+From a projection's bf16 output to the scan's bf16 operand q, k and v go through
+`ops/short_conv.py short_conv` (PR 54): the convolution, SiLU, the move to
+heads-first, the L2 norm and q's scale in float32, rounded once at the end, which
+are the roundings this file made before it. Forward it is the same chain of
+XLA operations on every platform. Its gradient is one Mosaic kernel
+(`short_conv_bwd`, under the scope `gdn_conv`) where the step is compiled for a
+TPU, chosen by the mesh's platform as the scan's kernels are; it reads z and
+the cotangent and keeps nothing else of the forward pass, so the remat of the
+layer's first part does not run the chain a second time. Off the TPU (and at the
+nano size, whose widths fill no lane row) jax differentiates the chain as before.
+`linear_out`'s gated norm was measured beside it and left as it is (PERF.md
+section 6, PR 54).
 """
 
 from __future__ import annotations
@@ -39,11 +51,11 @@ from typing import Any, Callable, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models.lfm2 import _shifted
 from ray_tpu.models.llama import rms_norm
 from ray_tpu.models.moe import swiglu
 from ray_tpu.models.stack import Pattern, apply_stack, lm_head, lm_loss
 from ray_tpu.ops import gated_delta_rule as gdn
+from ray_tpu.ops.short_conv import short_conv
 
 LINEAR, FULL = "linear_attention", "full_attention"
 PERIOD = (LINEAR, LINEAR, LINEAR, FULL)
@@ -206,39 +218,33 @@ def param_logical_axes(config: OlmoHybridConfig) -> Dict[str, Any]:
 
 
 # --------------------------------------------------------------------------- forward
-def _conv_silu(z, taps):
-    """silu of the causal depthwise convolution of z (B, S, C) with `taps` (kernel, C), in float32."""
-    n = taps.shape[0]
-    z, w = z.astype(jnp.float32), taps.astype(jnp.float32)
-    return jax.nn.silu(sum(w[j] * _shifted(z, n - 1 - j) for j in range(n)))
-
-
 def _heads_first(x, heads: int):
     """(B, S, heads * d) -> (B, heads, S, d)."""
     b, s, _ = x.shape
     return x.reshape(b, s, heads, -1).transpose(0, 2, 1, 3)
 
 
-def linear_qkv(x, layer, config: OlmoHybridConfig):
+def linear_qkv(x, layer, config: OlmoHybridConfig, mesh=None):
     """What the scan reads, of the layer's input x (B, S, D): q, k (B, H, S,
-    d_k) and v (B, H, S, d_v) in the compute dtype, g and beta (B, H, S) f32."""
-    cdt, h = config.dtype, config.linear_heads
+    d_k) and v (B, H, S, d_v) in the compute dtype, g and beta (B, H, S) f32.
+    `mesh`: what the step shards over, for the Mosaic call in `short_conv`'s gradient."""
+    cdt = config.dtype
     x = x.astype(cdt)
     with jax.named_scope("gdn"):
         q, k, v = (jnp.einsum("bsd,de->bse", x, layer[w].astype(cdt)) for w in ("wq", "wk", "wv"))
         with jax.named_scope("gdn_conv"):
-            q, k, v = (_heads_first(_conv_silu(z, layer[w]), h)
-                       for z, w in ((q, "conv_q"), (k, "conv_k"), (v, "conv_v")))
+            conv = functools.partial(short_conv, heads=config.linear_heads, mesh=mesh)
+            q = conv(q, layer["conv_q"], normalize=True, scale=config.linear_key_dim ** -0.5)
+            k = conv(k, layer["conv_k"], normalize=True)
+            v = conv(v, layer["conv_v"])
         with jax.named_scope("gdn_gates"):
-            unit = lambda z: z * jax.lax.rsqrt(jnp.sum(z * z, axis=-1, keepdims=True) + 1e-6)  # noqa: E731
-            q, k = unit(q) * config.linear_key_dim ** -0.5, unit(k)
             xf = x.astype(jnp.float32)
             gate = lambda w: jnp.einsum("bsd,dh->bhs", xf, layer[w].astype(jnp.float32),  # noqa: E731
                                         precision=jax.lax.Precision.HIGHEST)
             beta = jax.nn.sigmoid(gate("w_b")) * (2.0 if config.allow_neg_eigval else 1.0)
             a, dt_bias = (layer[w].astype(jnp.float32)[None, :, None] for w in ("A_log", "dt_bias"))
             g = -jnp.exp(a) * jax.nn.softplus(gate("w_a") + dt_bias)
-        return q.astype(cdt), k.astype(cdt), v.astype(cdt), g, beta
+        return q, k, v, g, beta
 
 
 def linear_out(x, o, layer, config: OlmoHybridConfig):
@@ -252,10 +258,11 @@ def linear_out(x, o, layer, config: OlmoHybridConfig):
         return jnp.einsum("bse,ed->bsd", gated, layer["wo"].astype(cdt))
 
 
-def _kinds(config: OlmoHybridConfig):
+def _kinds(config: OlmoHybridConfig, mesh=None):
     """`stack.Pattern.kinds`: (qkv_part, out_part) of the full kind, (qkv_part,
     out_part, attend) of the linear one. The scope names are read from the
-    compiled program's `op_name`s (PERF.md, "names")."""
+    compiled program's `op_name`s (PERF.md, "names"). `mesh`: `forward`'s, for
+    the one part that holds a Mosaic call and is handed no mesh by the stack."""
     cdt, eps = config.dtype, config.norm_eps
 
     def finish(x, mixed, layer):
@@ -292,13 +299,15 @@ def _kinds(config: OlmoHybridConfig):
         del rng
         return finish(x, linear_out(x, o, layer, config), layer)
 
-    return {LINEAR: (lambda x, layer: linear_qkv(x, layer, config), linear_out_part, scan),
+    if mesh is not None and int(mesh.shape.get("pipeline", 1)) > 1:
+        mesh = None  # as `scan`: no second shard_map inside the pipeline's region
+    return {LINEAR: (lambda x, layer: linear_qkv(x, layer, config, mesh), linear_out_part, scan),
             FULL: (full_qkv, full_out)}
 
 
-def pattern(config: OlmoHybridConfig) -> Pattern:
+def pattern(config: OlmoHybridConfig, mesh=None) -> Pattern:
     period = config.period
-    return Pattern(_kinds(config), period, config.n_layer // len(period))
+    return Pattern(_kinds(config, mesh), period, config.n_layer // len(period))
 
 
 def forward(
@@ -317,7 +326,7 @@ def forward(
     cdt = config.dtype
     with jax.named_scope("embed"):
         x = params["embed"].astype(cdt)[tokens]
-    x, _ = apply_stack(params["blocks"], x, config, pattern=pattern(config), attention_fn=attention_fn,
+    x, _ = apply_stack(params["blocks"], x, config, pattern=pattern(config, mesh), attention_fn=attention_fn,
                        mesh=mesh, num_microbatches=num_microbatches)
     logits = lm_head(x, lambda x: rms_norm(x, params["final_norm"], config.norm_eps), params["head"], cdt)
     if return_aux:

@@ -28,6 +28,7 @@ Case names:
     masked:BxHxKVxSxDxBLOCK     both flash kernels under `BlockDiffusion(S / 2, BLOCK)`, a mask by structure
     indexer:BxHxKVxSxDxIHxIDxK  `select` and `index_loss` of `ops/lightning_indexer.py`
     gdn:BxHxSxDKxDV             `gdn_fwd` and `gdn_bwd` of `ops/gated_delta_rule.py`, the call and its gradient
+    short_conv:BxSxHEADSxDxNORM `short_conv_bwd` of `ops/short_conv.py` under the XLA chain it is the gradient of
     row_movers:TOKENS           `gather_rows` and `sum_rows` over a held prefix
     held_experts                an LFM2 step whose expert layer holds 2 of 16 experts
     lower:MESH, compile:MESH    gpt2_small's step over d1, d4 or d2t2, lowered or compiled
@@ -305,6 +306,24 @@ def _gdn_case(topo, batch, heads, seq, dk, dv):
             "states": sorted(set(re.findall(r"f32\[%d,\d+,%d,%d\]" % (batch * heads, dk, dv), text)))}
 
 
+def _short_conv_case(topo, batch, seq, heads, d, normalize):
+    """The short convolution of a linear layer's q (or k, or v) and its gradient, one device."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.ops import short_conv as sc
+
+    one = jax.sharding.SingleDeviceSharding(topo.devices[0])
+    z, taps = (jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+               for shape, dtype in (((batch, seq, heads * d), jnp.bfloat16), ((4, heads * d), jnp.float32)))
+    loss = lambda z, taps: sc.short_conv(  # noqa: E731
+        z, taps, heads, scale=d ** -0.5, normalize=bool(normalize), backend="pallas").astype(jnp.float32).sum()
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(z, taps).compile().as_text()
+    return {"mosaic_calls": text.count("tpu_custom_call"),
+            "kernels": sorted(set(re.findall(r"(short_conv_\w+?)[.\d]* = ", text))),
+            "plans": sorted(set("/".join(found) for found in re.findall(r"\b(tile_\d+)\)*/(rows_\d+)\b", text)))}
+
+
 def _row_movers_case(topo, tokens, k=4, width=2048, n_experts=64, held=8):
     """`gather_rows` and `sum_rows` over the prefix of a layer that holds `held`
     of `n_experts` experts, at a cell's shapes, one device."""
@@ -426,6 +445,10 @@ def _step_case(topo, cell):
         "phases": sorted({phase(n) for n in scopes.values()}),
         "recomputed": sum("rematted_computation" in n.split("/") for n in scopes.values()),
     }
+    if c["model"] == "olmo_hybrid":  # the short convolutions' chain (scope `gdn_conv`): forward, and made again?
+        conv = [n.split("/") for n in scopes.values() if "gdn_conv" in n.split("/") and "pallas_call" not in n]
+        out["conv_chain_forward"] = sum(phase("/".join(parts)) == "forward" for parts in conv)
+        out["conv_chain_recomputed"] = sum("rematted_computation" in parts for parts in conv)
     out["gather_minor_dims"], out["gathered_weight_copies"] = block_weight_gathers(text, scopes)
     if "num_experts_per_tok" in c:
         out["sorted_rows_moved"], out["backward_scatter_adds"] = sorted_row_traffic(
@@ -449,6 +472,8 @@ def _case(topo, case):
         return _indexer_case(topo, *numbers())
     if name == "gdn":
         return _gdn_case(topo, *numbers())
+    if name == "short_conv":
+        return _short_conv_case(topo, *numbers())
     if name == "row_movers":
         return _row_movers_case(topo, int(rest))
     if case == "held_experts":

@@ -12,16 +12,18 @@ from benchmark.harness.program_trace import PHASES, phase
 
 # The cell's step (PR 51): two periods of (linear, linear, linear, full) in one scan under `fsdp=4`, a row a chip.
 # A linear layer's scan is two Mosaic calls inside a shard_map (XLA cannot partition one), the full layer's the two
-# flash kernels at OLMoE's head (30 heads of 4,096 x 128).
+# flash kernels at OLMoE's head (30 heads of 4,096 x 128). Since PR 54 the gradient of a linear layer's short
+# convolutions (q, k, v) is a Mosaic call each, `short_conv_bwd`, inside a shard_map of its own.
 OLMO_HYBRID = "olmo-hybrid-7b-fsdp4"
 GDN_4K = "gdn:1x30x4096x96x192"
+CONV_QK, CONV_V = "short_conv:1x4096x30x96x1", "short_conv:1x4096x30x192x0"
 V5E_HBM_BYTES = 16_909_336_064
 PARAMETERS = 2_435_748_072
 
 
 @pytest.fixture(scope="module")
 def aot():
-    return aot_v5e.Cases([GDN_4K], ["step:" + OLMO_HYBRID])
+    return aot_v5e.Cases([GDN_4K, CONV_QK, CONV_V], ["step:" + OLMO_HYBRID])
 
 
 def test_the_scans_kernels_compile_for_the_v5e_at_the_cells_widths(aot):
@@ -40,9 +42,36 @@ def test_the_scans_kernels_compile_for_the_v5e_at_the_cells_widths(aot):
     assert got["states"] == [f"f32[30,{4096 // gdn.CHUNK},96,192]"]
 
 
+@pytest.mark.parametrize("case", [CONV_QK, CONV_V], ids=["q_or_k", "v"])
+def test_the_short_convolutions_gradient_kernel_compiles_for_the_v5e_at_the_cells_widths(aot, case):
+    """(1, 4096, 2880) with the L2 norm over heads of 96 (7.5 tiles of 384 channels: the last one passes the
+    array's end) and (1, 4096, 5760) in heads of 192 without it, bf16, the cotangent heads-first: a program holds a
+    row's 4,096 positions of a tile, three blocks of 3-4 MiB with two buffers each, over Mosaic's default VMEM.
+    Forward there is no kernel: the chain is XLA's."""
+    from ray_tpu.ops import short_conv as sc
+
+    got = aot[case]
+    assert got["mosaic_calls"] == 1 and got["kernels"] == ["short_conv_bwd"]
+    assert got["plans"] == [f"tile_{sc.tile_of(96)}/rows_4096"]
+
+
+def test_the_short_convolutions_gradient_is_nine_calls_a_period_and_the_chain_is_not_recomputed(aot):
+    """Three linear places a period x (q, k, v), all in the backward pass, each under `gdn/gdn_conv` (what
+    `gdn.conv_ms` reads) in a shard_map, its scope naming the tile and the rows a program holds. The kernel keeps z
+    alone of the forward pass, so the remat of the layer's first part has nothing of the chain to make again."""
+    got = aot["step:" + OLMO_HYBRID]
+    calls = [n.split("/") for n in got["mosaic_scopes"] if n.split("/")[-2] == "short_conv_bwd"]
+    assert len(calls) == 9
+    for parts in calls:
+        assert parts[-4:-2] == ["tile_384", "rows_4096"] and parts[-5] == "shard_map", parts
+        assert "gdn" in parts and "gdn_conv" in parts and "rematted_computation" not in parts
+        assert phase("/".join(parts)) == "backward"
+    assert got["conv_chain_recomputed"] == 0 and got["conv_chain_forward"] > 0
+
+
 def test_the_step_runs_each_kernel_once_a_layer_and_never_again_in_the_backward_pass(aot):
     got = aot["step:" + OLMO_HYBRID]
-    kernels = [(n.split("/")[-2], n) for n in got["mosaic_scopes"]]
+    kernels = [(n.split("/")[-2], n) for n in got["mosaic_scopes"] if n.split("/")[-2] != "short_conv_bwd"]
     count = lambda name: sum(k == name for k, _ in kernels)  # noqa: E731
     # One period's layers are unrolled inside the scan over the two periods: three linear places, one full.
     assert (count("gdn_fwd"), count("gdn_bwd"), count("flash_fwd"), count("flash_bwd")) == (3, 3, 1, 1)
