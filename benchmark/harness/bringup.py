@@ -15,6 +15,8 @@ from typing import Any, Dict, List, Optional, Tuple
 ROOT = "ray_tpu.train.fit"
 BRINGUP = "ray_tpu.train.bringup."
 WORKER = "ray_tpu.train.worker."
+CHIP_OPEN = "device_touch"  # the worker span round the first `jax.local_devices()`: libtpu opens the chip
+CHIP_OPEN_SPAN = WORKER + CHIP_OPEN
 TOP_FUNCTIONS = 5
 
 
@@ -83,6 +85,13 @@ class Bringup:
     @property
     def device_touch_s(self) -> Optional[float]:
         return self.slowest("import_jax", "device_touch")
+
+    @property
+    def chip_open_s(self) -> Optional[float]:
+        """The slowest rank's `device_touch` alone: libtpu opening the chip,
+        which `setup_s` leaves out (`driver.set_up`). Nothing where no rank
+        opened the span: a lone CPU worker."""
+        return self.slowest(CHIP_OPEN)
 
     @property
     def gang_join_s(self) -> Optional[float]:

@@ -14,7 +14,7 @@ import tempfile
 import time
 from typing import Any, Dict, List, Optional
 
-from benchmark.harness import procs
+from benchmark.harness import bringup, procs
 from benchmark.harness.manifest import Manifest
 
 RUN_TIMEOUT_S = 1100.0  # a cold first run may take 1200 s; a hang must not
@@ -133,8 +133,56 @@ def run(argv: List[str], t_start: float, manifest: Manifest) -> Dict[str, Any]:
     return record
 
 
+def set_up(run: Dict[str, Any]) -> Dict[str, float]:
+    """The three numbers of a run's set-up. `whole_s`: process start to the
+    first timed step, which is how long the chips were held before they
+    trained. `chip_open_s`: the seconds the slowest rank spent in the
+    program's `device_touch` span, the first `jax.local_devices()`, where
+    libtpu opens the chip: one call that no change to this repository moves
+    and that differs by 7-16 s between two runs of one tree. `setup_s`: the
+    first less the second, everything a change can move and nothing it
+    cannot. One name never holds two quantities: a chip run with no such span
+    has no `setup_s`. A rehearsal takes out what there is: a lone CPU worker
+    opens no span."""
+    whole = run["summary"]["window_wall_start"] - run["parent"]["t_start_wall"]
+    program = bringup.of(run)
+    opened = program.chip_open_s if program is not None else None
+    if opened is None:
+        if not run["rehearse"]:
+            raise Failed(f"no rank's `{bringup.CHIP_OPEN_SPAN}` span is in the report this process kept of the "
+                         f"run's fit(): `setup_s` is process start -> first timed step ({whole:.2f}s) "
+                         f"less that span, and stands for nothing else")
+        opened = 0.0
+    return {"whole_s": whole, "chip_open_s": opened, "setup_s": whole - opened}
+
+
+def compared(s: Dict[str, Any], rehearse: bool) -> Dict[str, Any]:
+    """Every number `correct` is decided from, beside its limit, as `[reading,
+    limit]`: the reference check's (a limit named as its reading stands beside
+    it, leaf by leaf where it is one a leaf; what is left of either side
+    follows as it is), then the window's own."""
+    check = dict(s["check"])
+    limits = dict(check.pop("limits", {}))
+    out: Dict[str, Any] = {}
+    for name in [n for n in limits if n in check]:
+        reading, limit = check.pop(name), limits.pop(name)
+        if isinstance(reading, dict):
+            per_leaf = limit if isinstance(limit, dict) else dict.fromkeys(reading, limit)
+            out.update({f"{name}.{leaf}": [value, per_leaf.get(leaf)] for leaf, value in reading.items()})
+        else:
+            out[name] = [reading, limit]
+    out["check"] = check
+    if limits:
+        out["limits"] = limits
+    out["steps_completed_of_dispatched"] = [s["completed"], s["attempted"]]
+    out["steps_outside_loss_band"] = [s["failed"], 0]
+    out["compiles_in_window"] = [s["compiles_in_window"]["count"], 0]
+    out["mosaic_calls_at_least"] = [s["compiled_step"]["mosaic_calls"], 0 if rehearse else 2]
+    return out
+
+
 def result_line(record: Dict[str, Any], manifest: Manifest) -> Dict[str, Any]:
-    """The contract's last line from the run's record."""
+    """The contract's last line from the run's record, its set-up read."""
     from benchmark.harness import xplane
     from benchmark.harness.peaks import peaks_for
 
@@ -156,7 +204,7 @@ def result_line(record: Dict[str, Any], manifest: Manifest) -> Dict[str, Any]:
     else:
         mine = {
             "tokens_per_s_per_chip": s["tokens_per_s"] / chips,
-            "setup_s": s["window_wall_start"] - record["parent"]["t_start_wall"],
+            "setup_s": record["setup"]["setup_s"],
         }
         for entry in manifest.metrics_for(cell["name"], "end_to_end"):
             values[entry["name"]] = {"value": mine[entry["name"]], "unit": entry["unit"]}
@@ -183,6 +231,8 @@ def result_line(record: Dict[str, Any], manifest: Manifest) -> Dict[str, Any]:
             "device_ops": [[n, sec] for n, sec in trace.top_ops(10)],
             "idle_gaps": [[n, sec] for n, sec in trace.idle_by_host_span()[:10]],
         }
+    # Last: the record of a run that is not correct keeps the end of the line.
+    line["compared"] = dict(compared(s, rehearse), trace_read_if_asked=[trace is not None, bool(record["trace"])])
     return line
 
 
@@ -192,7 +242,9 @@ def describe(record: Dict[str, Any]) -> None:
     p = record["parent"]
     steps = s["completed"]
     print(f"[run] device {s['device']} mesh {s['mesh']}")
-    print(f"[run] set-up: process start -> fit() {p['t_fit_wall'] - p['t_start_wall']:.2f}s "
+    print(f"[run] set-up: process start -> first timed step {record['setup']['whole_s']:.2f}s less the "
+          f"chip's open {record['setup']['chip_open_s']:.2f}s = setup_s {record['setup']['setup_s']:.2f}s; "
+          f"process start -> fit() {p['t_fit_wall'] - p['t_start_wall']:.2f}s "
           f"(of which traffic {p['prepare_s']:.2f}s), fit() -> loop entered "
           f"{s['t_loop_wall'] - p['t_fit_wall']:.2f}s, in the loop {json.dumps(s['setup_spans_s'])}; "
           f"compiles in set-up {json.dumps(s['compiles_setup'])}, in the window "
@@ -217,8 +269,12 @@ def main(argv: List[str], t_start: float) -> int:
     try:
         manifest = Manifest()
         record = run(argv, t_start, manifest)
+        # What the readers are handed: the record, and beside it what is read
+        # once for all of them (the report's `Bringup` first, traced or not).
+        readings = dict(record)
+        record["setup"] = readings["setup"] = set_up(readings)
         describe(record)
-        line = result_line(record, manifest)
+        line = result_line(readings, manifest)
         if record["rehearse"]:
             from benchmark.harness import rehearsal_names  # goes with three tests outside `paths`
             rehearsal_names.add_names_before_the_fold(line, manifest, record["cell"])
@@ -231,5 +287,6 @@ def main(argv: List[str], t_start: float) -> int:
     with open(out, "w") as fh:
         json.dump({**record, "line": line}, fh)
     sys.stdout.flush()
+    print("compared, [reading, limit]: " + json.dumps(line["compared"]), file=sys.stderr, flush=True)
     print(json.dumps(line))
     return 0

@@ -182,6 +182,11 @@ class WorkerRun:
             self.checked["attention_path"] = path
             if not self.rehearse and path != "pallas":
                 self.checked["ok"] = False
+            # Each limit beside its reading, for the line's last key: the model's own where its
+            # `check` gives them, else its module's `*_TOL` under the configuration's own.
+            self.checked.setdefault("limits", {
+                **{k: v for k, v in vars(self.model).items() if k.endswith("_TOL")},
+                **self.model_config.get("check_tolerances", {})})
         self.log(f"check {json.dumps(self.checked)}")
 
     def inspect_step(self, batch) -> None:
@@ -223,6 +228,10 @@ class WorkerRun:
                 if i == 0:
                     jax.block_until_ready(out)
                     self.first_step_wall = time.time()
+                    # The timed step's own first loss and gradient norm, beside the check's: where the step's
+                    # rows are the check's (a gang's one row a chip), the same numbers by another program.
+                    if isinstance(out, dict):
+                        self.checked["first_step"] = {k: float(out[k]) for k in ("loss", "grad_norm") if k in out}
                 self.end_vote(self.begin_vote(False))
             jax.block_until_ready(out)
 

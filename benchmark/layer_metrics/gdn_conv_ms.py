@@ -1,6 +1,6 @@
 """Device time per step under the scope `gdn_conv` of `models/olmo_hybrid.py` (the causal depthwise convolutions of
-q, k and v with their SiLU and the move to heads-first), forward, recomputation and backward together:
-`scope_trace.scope_ms`."""
+q, k and v with their SiLU, since PR 54 q and k's L2 norms in the same pass, and the move to heads-first), forward,
+recomputation and backward together: `scope_trace.scope_ms`."""
 
 from benchmark.harness import scope_trace
 

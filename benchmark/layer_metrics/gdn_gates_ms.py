@@ -1,6 +1,6 @@
-"""Device time per step under the scope `gdn_gates` of `models/olmo_hybrid.py` (the L2 norms of q and k, `beta` and
-the log decay `g` with their two projections of 30 columns), forward, recomputation and backward together:
-`scope_trace.scope_ms`."""
+"""Device time per step under the scope `gdn_gates` of `models/olmo_hybrid.py` (`beta` and the log decay `g` with
+their two projections of 30 columns; until PR 54 the L2 norms of q and k too, which `gdn.conv_ms` holds since),
+forward, recomputation and backward together: `scope_trace.scope_ms`."""
 
 from benchmark.harness import scope_trace
 

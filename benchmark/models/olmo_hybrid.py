@@ -386,6 +386,12 @@ def reference_loss(params, tokens, c: Dict[str, Any], dtype=None):
 # `tests/test_olmo_hybrid.py`: 60 times those tests' limit), and PERF.md
 # section 7 has the row. No comparison of losses can see parameters kept in
 # bf16: the parameters' and the optimizer moments' dtype is checked by name.
+# PR 56, second session (PERF.md sections 6 and 7, first row): sixteen more seeds of the cell's own runs after PR 52
+# and PR 54 read loss 5.7e-6..4.43e-3, gradient norm 1.2e-5..2.8e-3, wk 1.4e-4..2.6e-3, w_a 4.3e-4..3.63e-3, A_log
+# 1.9e-3..0.147, every one inside the limits, which stand as PR 51 set them. Seed 797891267 reads gradient norm 0.1123
+# and wk 0.0587 (loss 3.0e-3, w_a 2.5e-3, A_log 2.0e-2), the same in three runs and in the timed step's own first
+# gradient norm (`first_step`, which `worker.warmup` puts beside these): forty times every other seed's, by its tokens
+# and not by its parameters. That is no reading of a sound run and sets no limit: no limit is moved for it.
 LOSS_ABS_TOL = 6e-3
 GRAD_NORM_REL_TOL = 1e-2
 LEAF_GRAD_NORM_REL_TOL = {"wk": 2e-2, "w_a": 2e-2, "A_log": 0.25}
@@ -501,6 +507,7 @@ def check(system: System, tokens, *, loss_tol: Optional[float] = None, grad_tol:
         "gdn.neg_eigval_share": float(stats["neg_eigval_share"]),
         "gdn.decay_min": float(stats["decay_min"]),
         "state_dtypes_other_than_stated": wrong_dtype,
+        "limits": {"loss_abs_err": loss_tol, "grad_norm_rel_err": grad_tol, "leaf_grad_norm_rel_err": leaf_tol},
     }
     out["ok"] = bool(
         all(map(math.isfinite, got)) and out["loss_abs_err"] <= loss_tol

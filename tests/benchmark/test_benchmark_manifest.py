@@ -21,7 +21,7 @@ from benchmark.harness.manifest import Manifest, problems, reduced_problems  # n
 from widened_manifest import manifest_root, widened  # noqa: E402,F401  (fixtures)
 
 SEED_CELLS = {"gpt2-medium.resident": 1, "gpt2-medium.fed": 1, "gpt2-xl-fsdp4.fed": 4}  # PR 22's
-CONTRACT_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+CONTRACT_KEYS = {"correct", "attempted", "failed", "metrics", "device", "compared"}
 
 
 def run_benchmark(root, *args, env=None, timeout=300):

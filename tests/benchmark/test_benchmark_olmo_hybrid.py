@@ -1,8 +1,7 @@
 """What the Olmo-Hybrid configuration brings to the benchmark: its file against the catalog row, its cell and entries
 appended and held to the contract, its readers on a recorded trace, the floors' arithmetic by hand. The cell is the second
-of four chips, which `max(1, 9 // 4)` lets in and which five older assertions of this directory do not expect (four say
-the four-chip cells are `["gpt2-xl-fsdp4.fed"]`, one that a four-chip cell is on `entry.gang_join_s`'s own list): a
-`model_config` PR edits no file the benchmark has, so they fail until a `benchmark` PR re-points them (ROADMAP C8 (xi)).
+of four chips, which `max(1, 9 // 4)` lets in. It brought the eight listed readings it reports as `<metric>.<config>`
+copies (PR 51); PR 56 put it on those entries' own lists and deleted the copies (`listed_readings.TABLE`).
 (The cell's CPU rehearsal is `tests/test_olmo_hybrid_rehearsal.py`: it costs a minute and a half, and this directory's
 tests are run a second time inside `test_benchmark_widening.py`.)"""
 
@@ -16,6 +15,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)
 sys.path.insert(0, REPO)
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
+import listed_readings  # noqa: E402
 from benchmark.harness.manifest import Manifest, problems, reduced_problems  # noqa: E402
 from benchmark.models import olmo_hybrid  # noqa: E402
 from widened_manifest import named_run, widen  # noqa: E402,F401  (fixture)
@@ -26,11 +26,11 @@ ROWS, SEQ, CHIPS = 4, 4096, 4
 PERIOD = ["linear_attention"] * 3 + ["full_attention"]
 NEW = ("gdn.mixer_ms", "gdn.conv_ms", "gdn.gates_ms", "kernels.gdn_fwd_ms", "kernels.gdn_bwd_ms", "kernels.gdn_ms",
        "kernels.gdn_roofline")
-# The listed readings a four-worker fed cell with a dense SwiGLU reports. Not `data.fetch_block_ms`: a worker eats a
+# The listed readings a four-worker fed cell with a dense SwiGLU reports (the ranks' least exposed time since PR 56). Not `data.fetch_block_ms`: a worker eats a
 # row a step and a block holds 16, so a pull falls into one window of 8 steps in two. Not `host.stall_pct`: 34 steps
 # a window leave no three readings a position clear of the traced ones.
 LISTED = ("data.wait_ms", "host.h2d_ms", "host.report_ms", "host.report_put_ms", "collectives.total_ms",
-          "collectives.exposed_ms", "entry.gang_join_s", "step.dense_mlp_ms")
+          "collectives.exposed_ms", "collectives.exposed_min_ms", "entry.gang_join_s", "step.dense_mlp_ms")
 
 
 @pytest.fixture(scope="module")
@@ -51,30 +51,22 @@ def test_the_manifest_holds_the_cell_appended_and_meets_the_contract():
     assert os.path.isfile(os.path.join(m.dir, "models", m.config(CONFIG)["model"] + ".py"))
     assert all(1 <= len(e["why"]) <= 200 for e in m.data["configs"] + m.data["workloads"])
     assert os.path.getsize(os.path.join(REPO, "BENCHMARK.json")) < 64 * 1024
-    # One run of fifteen after the 59 entries PR 50 left: the new readings, then a copy of each listed one.
+    # One run of seven after the 59 entries PR 50 left: the new readings (the eight copies that followed went in PR 56).
     names = [e["name"] for e in m.data["per_layer"]]
-    assert names[59:74] == list(NEW) + [f"{name}.{CONFIG}" for name in LISTED] and len(NEW) + len(LISTED) == 15
+    assert names[59:66] == list(NEW) and not [name for name in names if name.endswith("." + CONFIG)]
 
 
-def test_the_cell_reports_the_new_readings_a_copy_of_each_listed_one_and_every_unlisted_one():
+def test_the_cell_reports_the_new_readings_each_listed_one_and_every_unlisted_one():
     m = Manifest()
     readers = m.layer_readers()
-    by_name = {e["name"]: e for e in m.data["per_layer"]}
-    mine = {e["name"] for e in m.metrics_for(CELL, "per_layer")}
-    unlisted = {e["name"] for e in m.data["per_layer"] if "workloads" not in e}
-    assert mine == set(NEW) | {f"{name}.{CONFIG}" for name in LISTED} | unlisted
+    by_name, _ = listed_readings.holds_for(CELL, LISTED, NEW)
     for name in NEW:
-        assert by_name[name]["workloads"] == [CELL] and by_name[name]["moves"] == "tokens_per_s_per_chip"
+        assert by_name[name]["moves"] == "tokens_per_s_per_chip"
         assert readers[name].META == {k: v for k, v in by_name[name].items() if k != "workloads"}
     assert by_name["kernels.gdn_roofline"]["unit"] == "%" and by_name["kernels.gdn_roofline"]["better"] == "higher"
     assert {by_name[n]["layer"] for n in NEW[:3]} == {"linear attention"} and by_name[NEW[3]]["layer"] == "kernels"
-    for name in LISTED:
-        copied, listed = by_name[f"{name}.{CONFIG}"], by_name[name]
-        assert CELL not in listed["workloads"] and copied["workloads"] == [CELL]
-        assert {k: copied[k] for k in copied if k not in ("name", "workloads")} == {
-            k: listed[k] for k in listed if k not in ("name", "workloads")}
-        # The listed reader's own `read` (each file is a module loaded by its path, so by its code, not its identity).
-        assert readers[copied["name"]].read.__code__.co_code == readers[name].read.__code__.co_code
+    # One reader file a listed reading, and none under the configuration's name.
+    assert not [f for f in os.listdir(os.path.join(m.dir, "layer_metrics")) if CONFIG in f]
     # Every end-to-end metric the cell reports is one the benchmark has, under its bound.
     assert [e["name"] for e in m.metrics_for(CELL, "end_to_end")] == ["tokens_per_s_per_chip", "setup_s"]
 
@@ -157,14 +149,14 @@ def test_the_attention_path_is_both_sets_of_kernels_on_the_chip(config):
 
 def test_the_new_readers_return_nothing_on_a_program_without_the_scopes_or_the_kernels(named_run):
     """The parent's program: a traced run of it reads no `gdn` scope and no `gdn_*` kernel, and its line leaves the
-    entries out without raising. The copies read what the listed readers read."""
+    entries out without raising."""
     readers = Manifest().layer_readers()
     run = dict(named_run, config={"model": "olmo_hybrid", "batch": {"global_rows": ROWS, "seq": SEQ}},
                summary={**named_run["summary"], "device": {"count": CHIPS}, "span_ms_per_step": {"data_wait": 0.25}},
                peaks={"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9})
     assert [readers[name].read(run) for name in NEW] == [None] * len(NEW)
-    assert readers[f"data.wait_ms.{CONFIG}"].read(run) == 0.25
-    assert readers[f"step.dense_mlp_ms.{CONFIG}"].read(run) is None  # GPT-2 has no scope `dense_mlp`
+    assert readers["data.wait_ms"].read(run) == 0.25
+    assert readers["step.dense_mlp_ms"].read(run) is None  # GPT-2 has no scope `dense_mlp`
 
 
 def test_the_roofline_divides_the_larger_floor_by_the_kernels_time(config, monkeypatch):

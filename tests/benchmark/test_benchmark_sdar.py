@@ -49,9 +49,9 @@ def config():
 def test_the_manifest_has_no_problem_with_the_new_entries():
     m = Manifest()
     assert problems(m) == []
-    # The eighth cell (a later one may follow it): `max(1, cells // 4)` opens a second four-chip slot, still unused.
+    # The eighth cell (a later one may follow it): `max(1, cells // 4)` opens a second four-chip slot (PR 51 took it).
     assert [w["name"] for w in m.data["workloads"]][7] == CELL
-    assert [w["name"] for w in m.data["workloads"] if w["chips"] == 4] == ["gpt2-xl-fsdp4.fed"]
+    assert [w["name"] for w in m.data["workloads"] if w["chips"] == 4][0] == "gpt2-xl-fsdp4.fed"
     entry = next(c for c in m.data["configs"] if c["name"] == CONFIG)
     assert reduced_problems(entry, m.config(CONFIG)) == []
     assert os.path.isfile(os.path.join(m.dir, "models", m.config(CONFIG)["model"] + ".py"))

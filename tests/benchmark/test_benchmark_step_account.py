@@ -343,13 +343,21 @@ def test_the_other_ranks_are_read_as_rank_0_is_each_on_its_own_clock(tmp_path, r
 
 # ------------------------------------------------------------- the manifest
 def test_the_seven_entries_are_appended_after_the_74_each_with_its_one_file(readers):
+    """74 when PR 53 appended them; 66 since PR 56 folded the eight copies that stood before them."""
     m = Manifest()
     entries = m.data["per_layer"]
-    assert [e["name"] for e in entries[74:81]] == list(ENTRIES)
+    first = [e["name"] for e in entries].index(ENTRIES[0])
+    assert first >= 66 and [e["name"] for e in entries[first:first + 7]] == list(ENTRIES)
     files = {os.path.basename(r.__file__) for name, r in readers.items() if name in ENTRIES}
     assert files == {name.replace(".", "_") + ".py" for name in ENTRIES}
-    for e in entries[74:81]:
-        assert readers[e["name"]].META == e  # no `workloads`: every traced cell, as `step.device_ms`
+    for e in entries[first:first + 7]:
+        # No `workloads`: every traced cell, as `step.device_ms`. But the ranks' least exposed time, which read
+        # 0.0 where one chip is the gang: it lists four-chip cells since PR 56, the first of them GPT-2's.
+        assert readers[e["name"]].META == {k: v for k, v in e.items() if k != "workloads"}
+        if e["name"] == "collectives.exposed_min_ms":
+            assert e["workloads"][0] == "gpt2-xl-fsdp4.fed" and all(m.cell(c)["chips"] == 4 for c in e["workloads"])
+        else:
+            assert "workloads" not in e
         assert (e["unit"], e["better"], e["source"], e["moves"]) == (
             "ms/step", "lower", "device_trace", "tokens_per_s_per_chip")
         assert e["layer"] == ("collectives" if e["name"].startswith("collectives.") else "step")
