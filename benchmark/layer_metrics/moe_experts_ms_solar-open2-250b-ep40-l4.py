@@ -1,0 +1,8 @@
+"""`moe.experts_ms` in `solar-open2-250b-ep40-l4.fed4k`: that entry lists its cells and a later cell cannot
+append itself, so the cell brings the same reading under a name of its own, until a
+`benchmark` PR puts the cell on that entry's list and deletes this file."""
+
+from benchmark.layer_metrics import moe_experts_ms as listed
+
+META = {**listed.META, "name": "moe.experts_ms.solar-open2-250b-ep40-l4"}
+read = listed.read

@@ -4470,11 +4470,15 @@ class Scheduler:
         return self._introspect_token
 
     def _start_stack_collection(self, respond: Callable[[dict], None],
-                                timeout_s=None, targets=None) -> None:
+                                timeout_s=None, targets=None,
+                                oob: bool = True) -> None:
         from ray_tpu._private import introspection
 
         timeout_s = float(timeout_s or self.config.introspection_timeout_s)
         coll = _Introspection("stacks", respond, time.time() + timeout_s)
+        # oob=False: a target silent past the in-band deadline is recorded
+        # "unavailable" — no SIGUSR1 escalation (see _capture_flight_recorder).
+        coll.oob_fired = not oob
         if targets is None:
             # Full-cluster dump: include this (head) process directly — its
             # threads ARE the control plane (scheduler loop, acceptors,
@@ -4639,7 +4643,17 @@ class Scheduler:
     def _capture_flight_recorder(self, key: str, handle, desc,
                                  store: Callable[[dict], None]) -> None:
         """SUSPECT-transition hook: single-target stack collection whose
-        result lands on the worker/node entry instead of a caller."""
+        result lands on the worker/node entry instead of a caller.
+
+        In-band only. A worker's SUSPECT verdict is observational, and this
+        capture nobody asked for must be too: the SIGUSR1 faulthandler dump
+        walks every thread's frames from inside a signal handler while the
+        threads run, and has killed the worker it looked at (SIGSEGV in
+        _Py_DumpTracebackThreads). A worker goes SUSPECT and then misses the
+        in-band deadline whenever one native call holds the GIL for ~5 s —
+        jax writing a large executable to its compile cache does — so the
+        escalation fired in ordinary training runs. An explicit
+        dump_stacks request still escalates: there somebody chose to look."""
         def respond(results: dict) -> None:
             store({
                 "trigger": "SUSPECT",
@@ -4651,6 +4665,7 @@ class Scheduler:
             respond,
             timeout_s=min(float(self.config.introspection_timeout_s), 3.0),
             targets=[(key, handle, desc)],
+            oob=False,
         )
 
     def _cmd_dump_stacks(self, payload):

@@ -54,6 +54,21 @@ bf16 array itself is the operand, a row scaling stands on the product's other
 side (`(q eg) S = eg (q S)`, `(k to_end)^T N = k^T (to_end N)`). `K K^T` and
 `Q K^T` multiply the operands as they come (bf16 in a bf16 model, f32
 accumulation). With f32 operands every product is six passes.
+
+The rule with a decay a channel (`ops/kda.py`, Kimi Delta Attention) is a
+file beside this one that imports this one's helpers (`_mm`,
+`_unit_lower_inverses`, `heads_per_program`, `_col`, `_row`). What the scalar
+decay lets this file do is take it out of every contraction over `d_k`:
+`exp(gamma_i - gamma_j)` multiplies `K K^T` and `Q K^T` after the product, and
+`exp(gamma)` is a row's scale that can stand on a product's other side, so q, k
+and do reach the MXU as they come and a pairwise term is one product of C x d_k
+x C. A vector decay sits inside the contraction: each of the two pairwise terms
+becomes `log2 C` masked products of that shape, both operands scaled by an
+exponential and therefore f32 (7 x 6 passes at C = 128 for one, and as many for
+each of its two gradients), and no product against the state has a bf16
+operand left. So the two rules share the doubling, the plan and the walk, and
+not `_chunk_fwd` / `_chunk_bwd`: a family of two, not one operator with an
+option (ROADMAP B7).
 """
 
 from __future__ import annotations

@@ -28,6 +28,7 @@ Case names:
     masked:BxHxKVxSxDxBLOCK     both flash kernels under `BlockDiffusion(S / 2, BLOCK)`, a mask by structure
     indexer:BxHxKVxSxDxIHxIDxK  `select` and `index_loss` of `ops/lightning_indexer.py`
     gdn:BxHxSxDKxDV             `gdn_fwd` and `gdn_bwd` of `ops/gated_delta_rule.py`, the call and its gradient
+    kda:BxHxSxDKxDV             `kda_fwd` and `kda_bwd` of `ops/kda.py`, the call and its gradient
     short_conv:BxSxHEADSxDxNORM `short_conv_bwd` of `ops/short_conv.py` under the XLA chain it is the gradient of
     row_movers:TOKENS           `gather_rows` and `sum_rows` over a held prefix
     held_experts                an LFM2 step whose expert layer holds 2 of 16 experts
@@ -306,6 +307,26 @@ def _gdn_case(topo, batch, heads, seq, dk, dv):
             "states": sorted(set(re.findall(r"f32\[%d,\d+,%d,%d\]" % (batch * heads, dk, dv), text)))}
 
 
+def _kda_case(topo, batch, heads, seq, dk, dv):
+    """The channel-wise delta rule's two kernels at a linear layer's shapes, one device."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.ops import kda
+
+    one = jax.sharding.SingleDeviceSharding(topo.devices[0])
+    sd = lambda shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(shape, dtype, sharding=one)  # noqa: E731
+    wide = (batch, heads, seq)
+    loss = lambda *a: kda.kimi_delta_rule(*a, backend="pallas").astype(jnp.float32).sum()  # noqa: E731
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        sd((*wide, dk)), sd((*wide, dk)), sd((*wide, dv)), sd((*wide, dk), jnp.float32),
+        sd(wide, jnp.float32)).compile().as_text()
+    return {"mosaic_calls": text.count("tpu_custom_call"),
+            "kernels": sorted(set(re.findall(r"(kda_fwd|kda_bwd)[.\d]* = ", text))),
+            "plans": sorted(set(re.findall(r"\b(chunk_\d+\)*/heads_\d+of\d+)\b", text))),
+            "states": sorted(set(re.findall(r"f32\[%d,\d+,%d,%d\]" % (batch * heads, dk, dv), text)))}
+
+
 def _short_conv_case(topo, batch, seq, heads, d, normalize):
     """The short convolution of a linear layer's q (or k, or v) and its gradient, one device."""
     import jax
@@ -472,6 +493,8 @@ def _case(topo, case):
         return _indexer_case(topo, *numbers())
     if name == "gdn":
         return _gdn_case(topo, *numbers())
+    if name == "kda":
+        return _kda_case(topo, *numbers())
     if name == "short_conv":
         return _short_conv_case(topo, *numbers())
     if name == "row_movers":

@@ -257,6 +257,47 @@ def test_flight_recorder_captured_on_worker_suspect():
         ray_tpu.shutdown()
 
 
+def test_flight_recorder_never_signals_the_worker_it_looks_at():
+    """A SUSPECT worker that misses the in-band deadline as well (a long
+    native call holding the GIL: here its reader is delayed) is recorded
+    "unavailable". The capture nobody asked for does not escalate to SIGUSR1:
+    the faulthandler dump walks running threads' frames from a signal handler
+    and has killed workers mid-compile. The worker's stack file stays empty,
+    and an explicit request still takes the out-of-band path."""
+    os.environ["RAY_TPU_health_check_period_ms"] = "200"
+    os.environ["RAY_TPU_introspection_timeout_s"] = "1.0"
+    os.environ["RAY_TPU_FAILPOINTS"] = (
+        "worker.heartbeat=drop@always;conn.recv=delay:6@always")
+    try:
+        ray_tpu.init(num_cpus=1)
+
+        @ray_tpu.remote
+        def noop():
+            return 1
+
+        assert ray_tpu.get(noop.remote(), timeout=60) == 1
+        found = None
+        deadline = time.time() + 25
+        while time.time() < deadline and found is None:
+            for n in state.list_nodes():
+                for w in n.get("workers", ()):
+                    if w.get("flight_recorder"):
+                        found = w
+            if found is None:
+                time.sleep(0.1)
+        assert found is not None, "no flight recorder captured"
+        assert found["flight_recorder"]["dump"]["transport"] == "unavailable"
+        assert ray_tpu.get(noop.remote(), timeout=60) == 1  # and it lives
+        workers = {k: v for k, v in state.stacks().items()
+                   if k.startswith("worker:")}
+        assert [v["transport"] for v in workers.values()] == ["oob"]
+    finally:
+        os.environ.pop("RAY_TPU_FAILPOINTS", None)
+        os.environ.pop("RAY_TPU_health_check_period_ms", None)
+        os.environ.pop("RAY_TPU_introspection_timeout_s", None)
+        ray_tpu.shutdown()
+
+
 # ------------------------------------------------------ log-drop satellite
 def test_log_shipper_drop_counter_exported(ray_start_regular):
     """_LogShipper overflow increments the module counter that
