@@ -64,7 +64,11 @@ def block(x, layer, config, qkv_part: Optional[Callable], out_part: Callable,
     more memory); under "save_attn" the two parts are remat'ed each on its own
     while the attention call between them is not: its residuals (q/k/v/o and
     the kernel's lse) are saved, so the backward pass never re-runs the
-    attention kernel, the most expensive op per byte saved.
+    attention kernel, the most expensive op per byte saved. They are the very
+    arrays `qkv_part` yields and `out_part` takes (`ops/flash_attention.py`
+    keeps the caller's own), so the layer scan stacks each once: `out_part`'s
+    checkpoint saves its `o` too, and a kernel that kept a copy under another
+    shape would have the scan carry that value twice.
 
     A block with no attention in its middle (`qkv_part` None) is `out_part`
     alone, with `o` None: under any remat all of it is recomputed from its

@@ -66,6 +66,14 @@ Design (pallas_guide.md playbook):
    kernels are the programs they were (PERF.md section 6, PR 48).
  - matmuls run on the MXU with preferred_element_type=float32; inputs can be
    bfloat16.
+ - what is kept for the backward pass is the caller's own arrays: both
+   `custom_vjp`s take and return (batch, heads, seq, d) and flatten to the
+   kernels' (batch * heads, seq, d) inside their rules, so the `o` a rule
+   saves is the array it returns. A layer scan stacks every residual a layer,
+   and an `o` saved under a shape of its own beside the one the caller's next
+   checkpoint saves was one value stacked twice (640 MiB at five layers of 32
+   x 16,384 x 128, the bytes over which XLA's rematerialization made three
+   projections a second time: PERF.md section 6, PR 60).
 
 The reference repo has no attention kernels at all (it is a distributed-systems
 layer); this file exists because long-context is first-class in the TPU build
@@ -1192,19 +1200,28 @@ def blockwise_attention(q, k, v, causal=True, sm_scale: Optional[float] = None,
 
 
 # --------------------------------------------------------------------------- public entry
+# Both `custom_vjp`s flatten inside their rules, not round them: the residuals are then the caller's own arrays, and
+# a layer scan stacks `o` once (the module's docstring says what a reshape outside cost).
+def _flat(x):
+    """(batch, heads, seq, d) as the kernels take it, (batch * heads, seq, d): a bitcast."""
+    return x.reshape(-1, *x.shape[2:])
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
 def _flash_bhsd(q, k, v, causal, sm_scale, plan, interpret):
-    o, _ = _fwd(q, k, v, causal, sm_scale, plan, interpret)
-    return o
+    return _flash_fwd_rule(q, k, v, causal, sm_scale, plan, interpret)[0]
 
 
 def _flash_fwd_rule(q, k, v, causal, sm_scale, plan, interpret):
-    o, lse = _fwd(q, k, v, causal, sm_scale, plan, interpret)
+    o, lse = _fwd(_flat(q), _flat(k), _flat(v), causal, sm_scale, plan, interpret)
+    o = o.reshape(q.shape)
     return o, (q, k, v, o, lse)
 
 
 def _flash_bwd_rule(causal, sm_scale, plan, interpret, res, g):
-    return _bwd(causal, sm_scale, plan, interpret, res, g)
+    q, k, v, o, lse = res
+    dq, dk, dv = _bwd(causal, sm_scale, plan, interpret, (_flat(q), _flat(k), _flat(v), _flat(o), lse), _flat(g))
+    return dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape)
 
 
 _flash_bhsd.defvjp(_flash_fwd_rule, _flash_bwd_rule)
@@ -1212,28 +1229,29 @@ _flash_bhsd.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
 def _flash_pairs(q, k, v, keep, causal, sm_scale, plan, interpret):
-    """Both passes a (Q tile, K tile) pair a program: (o, lse) of q (batch *
-    heads, seq, d) on k, v (batch * kv heads, seq, d) under `keep` (batch, seq,
+    """Both passes a (Q tile, K tile) pair a program: (o, lse) of q (batch,
+    heads, seq, d) on k, v (batch, kv heads, seq, d) under `keep` (batch, seq,
     spans * 128; None: every key). `lse` (batch * heads, seq, 1) carries no
     gradient: it is for whoever needs the probabilities again."""
-    return _fwd_pairs(q, k, v, keep, causal, sm_scale, plan, interpret)
+    return _flash_pairs_fwd(q, k, v, keep, causal, sm_scale, plan, interpret)[0]
 
 
 def _flash_pairs_fwd(q, k, v, keep, causal, sm_scale, plan, interpret):
-    o, lse = _fwd_pairs(q, k, v, keep, causal, sm_scale, plan, interpret)
+    o, lse = _fwd_pairs(_flat(q), _flat(k), _flat(v), keep, causal, sm_scale, plan, interpret)
+    o = o.reshape(q.shape)
     return (o, lse), (q, k, v, keep, o, lse)
 
 
 def _flash_pairs_bwd(causal, sm_scale, plan, interpret, res, g):
     q, k, v, keep, o, lse = res
-    do = g[0]
+    do, o = _flat(g[0]), _flat(o)
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)[..., None]
-    dq, dk, dv = _bwd_pairs(q, k, v, do, lse, delta, causal, sm_scale, plan, interpret, keep)
-    group = q.shape[0] // k.shape[0]
+    dq, dk, dv = _bwd_pairs(_flat(q), _flat(k), _flat(v), do, lse, delta, causal, sm_scale, plan, interpret, keep)
+    group = q.shape[1] // k.shape[1]
     if group > 1:  # a key/value head's gradient is its query heads' sum
-        dk, dv = (x.reshape(k.shape[0], group, *x.shape[1:]).sum(axis=1, dtype=jnp.float32).astype(x.dtype)
+        dk, dv = (x.reshape(-1, group, *x.shape[1:]).sum(axis=1, dtype=jnp.float32).astype(x.dtype)
                   for x in (dk, dv))
-    return dq, dk, dv, None
+    return dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape), None
 
 
 _flash_pairs.defvjp(_flash_pairs_fwd, _flash_pairs_bwd)
@@ -1409,10 +1427,7 @@ def flash_attention(
         return _pairs_call(q, k, v, keep, causal, sm_scale, plan, interpret, mesh, return_lse)
 
     def kernel(q, k, v):
-        b, h, s, d = q.shape
-        flat = lambda x: x.reshape(b * h, s, d)
-        o = _flash_bhsd(flat(q), flat(k), flat(v), causal, sm_scale, plan, interpret)
-        return o.reshape(b, h, s, d)
+        return _flash_bhsd(q, k, v, causal, sm_scale, plan, interpret)
 
     if mesh is not None and mesh.size > 1:
         from ray_tpu.parallel import ShardingRules
@@ -1434,11 +1449,8 @@ def _pairs_call(q, k, v, keep, causal, sm_scale, plan, interpret, mesh, return_l
     `flash_attention` partitions its other kernels (a selection goes with its
     row's batch)."""
     def kernel(q, k, v, *keep):
-        b, h, s, d = q.shape
-        flat = lambda x: x.reshape(-1, s, d)
-        o, lse = _flash_pairs(flat(q), flat(k), flat(v), keep[0] if keep else None,
-                              causal, sm_scale, plan, interpret)
-        return o.reshape(b, h, s, d), jax.lax.stop_gradient(lse).reshape(b, h, s)
+        o, lse = _flash_pairs(q, k, v, keep[0] if keep else None, causal, sm_scale, plan, interpret)
+        return o, jax.lax.stop_gradient(lse).reshape(q.shape[:3])
 
     operands = (q, k, v) + (() if keep is None else (keep,))
     if mesh is not None and mesh.size > 1:

@@ -37,6 +37,7 @@ Case names:
 """
 
 import json
+import math
 import os
 import queue
 import re
@@ -83,6 +84,12 @@ CALLED = re.compile(r"^\s+(?:ROOT )?%?([\w.\-]+) = .*?(?:calls|to_apply)=%?([\w.
 CALLED_ANYHOW = re.compile(r"(?:calls|to_apply|body|condition|true_computation|false_computation)=%?([\w.\-]+)"
                            r"|branch_computations=\{([^}]*)\}")
 
+# `%while.165 = (s32[], bf16[5,32,16384,128]{...}, ...) while(%tuple.813), condition=%c, body=%b, metadata={op_name=
+# ".../transpose(jvp(blocks))/layer_scan/while" ...}`: result (the loop's operands), condition, op_name.
+WHILE = re.compile(r"^\s+(?:ROOT )?%?[\w.\-]+ = (\(.*?\)) while\(.*?condition=%?([\w.\-]+).*?op_name=\"([^\"]*)\"", re.M)
+ARRAY = re.compile(r"(\w+)\[([\d,]+)\]")
+ITEM_BYTES = {"pred": 1, "s8": 1, "u8": 1, "bf16": 2, "f16": 2, "s16": 2, "u16": 2, "f32": 4, "s32": 4, "u32": 4}
+
 
 # ------------------------------------------------------- reading a compiled program's text
 def by_computation(text):
@@ -92,6 +99,45 @@ def by_computation(text):
         if line.endswith("{") and " = " not in line:
             computation = line.split()[1 if line.startswith("ENTRY") else 0].lstrip("%")
         yield computation, line
+
+
+def remat_products(text):
+    """The products XLA's own rematerialization makes a second time: the instructions `HloRematerialization`
+    cloned (`.remat` in their name) that are a `convolution`, or a fusion whose computation holds one. Not
+    `jax.checkpoint`'s recomputation (that is under `rematted_computation`, once): a clone is the same
+    instruction again, scheduled just before its users because the program did not fit otherwise."""
+    holds_product = {c for c, line in by_computation(text) if " convolution(" in line}
+    clones = []
+    for _, line in by_computation(text):
+        m = RESULT.match(line)
+        if m and ".remat" in m.group(1):
+            fused = FUSED.search(line)
+            if m.group(3) == "convolution" or (fused and fused.group(1) in holds_product):
+                clones.append(m.group(1))
+    return clones
+
+
+def layer_stacks(text):
+    """({shape: how many}, their bytes) of what the backward layer `while` is handed with the scanned layers
+    as its leading dimension: the stacked parameters, their gradients, and every residual the forward scan
+    saved a layer at a time. The number of layers is the loop's own bound (the constant of its condition).
+    Nothing where the program holds no such loop: XLA unrolls a scan of one trip (OLMoE's one layer, LFM2's
+    one period)."""
+    loops = [(m.group(1), m.group(2)) for m in WHILE.finditer(text)
+             if m.group(3).endswith("transpose(jvp(blocks))/layer_scan/while")]
+    if not loops:
+        return {}, 0
+    ((operands, condition),) = loops
+    (layers,) = {int(n) for c, line in by_computation(text) if c == condition
+                 for n in re.findall(r" s32\[\]\S* constant\((\d+)\)", line)}
+    stacks, total = {}, 0
+    for dtype, dims in ARRAY.findall(operands):
+        dims = [int(d) for d in dims.split(",")]
+        if dims[0] == layers and len(dims) > 1:
+            shape = f"{dtype}[{','.join(map(str, dims))}]"
+            stacks[shape] = stacks.get(shape, 0) + 1
+            total += ITEM_BYTES[dtype] * math.prod(dims)
+    return stacks, total
 
 
 def sorted_row_traffic(text, scopes, rows, width):
@@ -465,7 +511,9 @@ def _step_case(topo, cell):
         "mosaic_scopes": [scopes.get(INSTRUCTION.match(line).group(1), "") for line in mosaic],
         "phases": sorted({phase(n) for n in scopes.values()}),
         "recomputed": sum("rematted_computation" in n.split("/") for n in scopes.values()),
+        "remat_products": len(remat_products(text)),
     }
+    out["stacks"], out["stacked_bytes"] = layer_stacks(text)
     if c["model"] == "olmo_hybrid":  # the short convolutions' chain (scope `gdn_conv`): forward, and made again?
         conv = [n.split("/") for n in scopes.values() if "gdn_conv" in n.split("/") and "pallas_call" not in n]
         out["conv_chain_forward"] = sum(phase("/".join(parts)) == "forward" for parts in conv)
@@ -656,6 +704,11 @@ def is_the_pinned_program(got, cell, pinned, temp_limit):
     with open(os.path.join(REPO, "benchmark", "configs", cell + ".json")) as fh:
         recorded = json.load(fh)["memory_analysis_v5e_bytes"]
     assert got["argument"] <= recorded["arguments"]
+
+
+def stacks_ending(got, tail):
+    """{shape: how many} of a step case's `stacks` whose shape ends in `tail` (",32,16384,128]": a head's size)."""
+    return {shape: n for shape, n in got["stacks"].items() if shape.endswith(tail)}
 
 
 def has_one_flash_kernel_a_pass_and_all_phases(got, kernels):

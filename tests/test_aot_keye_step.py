@@ -43,3 +43,16 @@ def test_the_keye_step_hands_mosaic_the_selection_the_streamed_kernels_and_the_l
         recorded = json.load(fh)["memory_analysis_v5e_bytes"]
     assert got["argument"] == recorded["arguments"] and got["temp"] <= recorded["temporaries"]
     assert got["phases"] == sorted(PHASES)
+
+
+def test_the_keye_step_holds_no_clone_of_a_product_and_its_scan_stacks_o_once(aot):
+    """What XLA's own rematerialization makes a second time (`aot_v5e.remat_products`): nothing. Until PR 60 it
+    cloned q's and k's projections and `W_o`'s (`fusion.727.remat`, `.733.remat`, `.716.remat`: 10,083 instructions,
+    a peak of 14,886,214,656 B, temporaries 12,938,390,016) to fit a backward loop that was handed o twice: the
+    flash kernel's own `bf16[5,32,16384,128]` beside the `bf16[5,1,32,16384,128]` that `out_part`'s checkpoint
+    saves, 640 MiB of one value. The loop carries q and o now, each once and in the caller's shape."""
+    got = aot(KEYE)
+    assert got["remat_products"] == 0
+    assert aot_v5e.stacks_ending(got, ",32,16384,128]") == {"bf16[5,1,32,16384,128]": 2}, got["stacks"]
+    assert aot_v5e.stacks_ending(got, ",4,16384,128]") == {"bf16[5,1,4,16384,128]": 2}  # k and v on their own four heads
+    assert got["stacked_bytes"] == 5_092_980_480  # 5,764,069,120 with the second o

@@ -38,3 +38,13 @@ def test_the_sdar_step_fits_the_chip_and_names_its_phases_and_the_draw(aot):
     assert got["phases"] == sorted(PHASES)
     moe = sorted({n.split("/")[-2] for n in got["mosaic_scopes"]} - {"flash_fwd", "flash_bwd"})
     assert moe == ["gmm_dlhs", "gmm_drhs", "gmm_fwd", "sum_rows"]  # (XLA's gather out of 16,384 rows: `moe._rows_by`)
+
+
+def test_the_sdar_step_holds_no_clone_of_a_product_and_its_scan_stacks_o_once(aot):
+    """As the Keye step (`tests/test_aot_keye_step.py`): until PR 60 the backward loop was handed o twice (the flash
+    kernel's `bf16[5,32,16384,128]` beside `out_part`'s `bf16[5,1,32,16384,128]`) and XLA's rematerialization made
+    q's projection again to fit it (`fusion.618.remat`; 10,230 instructions, a peak of 14,565,869,568 B)."""
+    got = aot(SDAR)
+    assert got["remat_products"] == 0
+    assert aot_v5e.stacks_ending(got, ",32,16384,128]") == {"bf16[5,1,32,16384,128]": 2}, got["stacks"]
+    assert got["stacked_bytes"] == 4_695_173_120  # 5,366,261,760 with the second o

@@ -7,6 +7,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import flash_residuals
 from attention_yardsticks import TOLERANCE, _kernel_against_xla
 from ray_tpu.ops.flash_attention import flash_attention, kernel_plan, xla_attention
 
@@ -186,3 +187,34 @@ def test_dropout_applied_and_deterministic_eval():
     tr2 = forward(params, toks, cfg, dropout_rng=jax.random.PRNGKey(2))
     assert np.abs(np.asarray(tr1) - np.asarray(tr2)).max() > 1e-6  # stochastic
     assert np.abs(np.asarray(tr1) - np.asarray(eval1)).max() > 1e-6
+
+
+# ------------------------------------------------------------------ what the kernels keep for their backward pass (PR 60)
+@pytest.mark.parametrize("batch,fsdp,blocks", [
+    (1, None, {}), (2, None, {}), (2, None, {"block_q": 128, "block_k": 128}), (2, 2, {})],
+    ids=["b1", "b2", "b2-loops-128", "b2-fsdp2"])
+def test_the_whole_head_forms_give_bit_for_bit_what_they_gave_with_the_reshapes_outside(batch, fsdp, blocks):
+    """`_flash_bhsd` takes and returns the caller's (batch, heads, seq, d) and flattens inside its rules: the
+    same kernels on the same operands, so o and the three gradients are what the boundary before gave, to the
+    bit, for one row and two, unrolled and in loops, and in a `shard_map` that gives each device a row."""
+    from ray_tpu.parallel import MeshSpec
+
+    keys = jax.random.split(jax.random.PRNGKey(60 + batch), 4)
+    q, k, v, do = (jax.random.normal(kk, (batch, 2, 256, 64), jnp.float32).astype(jnp.bfloat16) for kk in keys)
+    mesh = MeshSpec(fsdp=fsdp).build(jax.devices()[:fsdp]) if fsdp else None
+    (now, grads), (before, grads_before) = flash_residuals.both_boundaries(q, k, v, do, mesh=mesh, **blocks)
+    for got, want in zip(now + list(grads), before + list(grads_before)):
+        assert got.shape == want.shape and got.dtype == want.dtype
+        np.testing.assert_array_equal(np.asarray(got, np.float32), np.asarray(want, np.float32))
+    assert [g.shape for g in grads] == [q.shape, k.shape, v.shape] and np.abs(np.asarray(grads[0], np.float32)).max() > 0
+
+
+def test_a_layer_scan_stacks_the_whole_head_forms_output_once():
+    """Two layers of `stack.block` under "save_attn": the forward scan of `jax.grad` stacks q, k, v and o, each
+    once and in the caller's shape. With the reshapes outside the `custom_vjp` it stacked o twice, the rule's
+    (batch * heads, seq, d) and the (batch, heads, seq, d) that `out_part`'s checkpoint saves: five, not four."""
+    shape = (2, 4, 256, 64)
+    stacked = flash_residuals.stacked_by_the_forward_scan(
+        lambda q, k, v: flash_attention(q, k, v, backend="pallas", interpret=True), shape, kv_heads=4)
+    heads = [s for s in stacked if s[-2:] == shape[-2:]]
+    assert heads == [(2, *shape)] * 4, stacked

@@ -7,6 +7,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import flash_residuals
 from attention_yardsticks import TOLERANCE, _dense_masked, _dense_yardstick, _kernel_against_xla, _selection
 from ray_tpu.ops.flash_attention import flash_attention, kernel_plan, xla_attention
 
@@ -264,3 +265,48 @@ def test_a_pair_scored_over_its_live_span_alone_gives_what_xla_gives(seq, d, til
     np.testing.assert_allclose(np.asarray(lse), np.asarray(want_lse), atol=2e-5)
     for a, b in zip(got, want):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-4)
+
+
+# ------------------------------------------------------------------ what the kernels keep for their backward pass (PR 60)
+# (batch, heads, key/value heads, a selection, return_lse, fsdp)
+@pytest.mark.parametrize("batch,heads,kv_heads,selected,return_lse,fsdp", [
+    (1, 4, 2, False, False, None), (2, 4, 2, False, False, None), (2, 4, 2, True, True, None),
+    (1, 2, 2, True, False, None), (2, 2, 2, False, True, None), (2, 4, 1, True, True, 2), (2, 2, 2, False, True, 2)],
+    ids=["b1-group2", "b2-group2", "b2-group2-keep-lse", "b1-equal-keep", "b2-equal-lse", "b2-group4-keep-lse-fsdp2",
+         "b2-equal-lse-fsdp2"])
+def test_the_pair_forms_give_bit_for_bit_what_they_gave_with_the_reshapes_outside(batch, heads, kv_heads, selected,
+                                                                                  return_lse, fsdp):
+    """`_flash_pairs` takes and returns the caller's (batch, heads, seq, d), k and v with their own head count,
+    and flattens inside its rules: o, lse and the three gradients (dk and dv after the sum over a group) are
+    what the boundary before gave, to the bit, in every form the entry takes and in a `shard_map` a row a device."""
+    from ray_tpu.ops.flash_attention import pack_keep
+    from ray_tpu.parallel import MeshSpec
+
+    q, k, v, mask = _selection(batch, heads, kv_heads, 64)
+    q, k, v = (x.astype(jnp.bfloat16) for x in (q, k, v))
+    do = jax.random.normal(jax.random.PRNGKey(60), q.shape, jnp.float32).astype(jnp.bfloat16)
+    mesh = MeshSpec(fsdp=fsdp).build(jax.devices()[:fsdp]) if fsdp else None
+    call = dict(keep=pack_keep(mask) if selected else None, return_lse=return_lse, mesh=mesh, block_q=128, block_k=128)
+    (now, grads), (before, grads_before) = flash_residuals.both_boundaries(q, k, v, do, **call)
+    assert len(now) == 1 + return_lse and (not return_lse or now[1].shape == q.shape[:3])
+    for got, want in zip(now + list(grads), before + list(grads_before)):
+        assert got.shape == want.shape and got.dtype == want.dtype
+        np.testing.assert_array_equal(np.asarray(got, np.float32), np.asarray(want, np.float32))
+    assert [g.shape for g in grads] == [q.shape, k.shape, v.shape] and np.abs(np.asarray(grads[1], np.float32)).max() > 0
+
+
+@pytest.mark.parametrize("kv_heads,return_lse,of_os_size", [(2, False, 2), (4, True, 4)], ids=["group2", "equal-lse"])
+def test_a_layer_scan_stacks_the_pair_forms_output_once(kv_heads, return_lse, of_os_size):
+    """Two layers of `stack.block` under "save_attn" on grouped heads, the Keye and SDAR cells' form: beside q
+    the forward scan of `jax.grad` stacks exactly one array of o's size, and both in the caller's shape. With
+    the reshapes outside the `custom_vjp` it stacked two: the rule's (batch * heads, seq, d) and the (batch,
+    heads, seq, d) that `out_part`'s checkpoint saves, 640 MiB at the cells' five layers of 32 x 16,384 x 128."""
+    shape = (2, 4, 256, 64)
+
+    def attention(q, k, v):
+        out = flash_attention(q, k, v, backend="pallas", interpret=True, return_lse=return_lse)
+        return out[0] if return_lse else out
+
+    stacked = flash_residuals.stacked_by_the_forward_scan(attention, shape, kv_heads)
+    assert [s for s in stacked if s[-2:] == shape[-2:] and s[2] == shape[1]] == [(2, *shape)] * of_os_size, stacked
+    assert len([s for s in stacked if s[-2:] == shape[-2:]]) == 4  # q, k, v, o: nothing of a head's size beside them
