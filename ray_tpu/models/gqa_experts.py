@@ -1,4 +1,5 @@
-"""The layer the 30B-A3B family's models share (`keye_vl2.py`, `sdar.py`), in
+"""The layer the 30B-A3B family's models share (`keye_vl2.py`, `sdar.py`; `trinity.py` takes its shapes and its
+projections), in
 the parts a model builds its own block from: grouped-query attention's
 projections with an RMSNorm over each head's own dimensions on q and on k and a
 rotation by tables the model supplies, the output projection, and a
@@ -106,13 +107,15 @@ def by_batch(table):
 def qkv_heads(h, layer, cos, sin, config):
     """q (B, heads, S, hd), k and v (B, kv heads, S, hd) of the normed input h
     (B, S, D): the three projections, the norm over each head's own dimensions
-    on q and on k, the rotation by `cos`, `sin` (B | 1, 1, S, hd / 2)."""
+    on q and on k, the rotation by `cos`, `sin` (B | 1, 1, S, hd / 2), or none
+    where they are None (a layer without positions: `trinity.py`'s full kind)."""
     cdt, eps = config.dtype, config.norm_eps
     q = jnp.einsum("bsd,dnh->bnsh", h, layer["wq"].astype(cdt))
     k = jnp.einsum("bsd,dnh->bnsh", h, layer["wk"].astype(cdt))
     v = jnp.einsum("bsd,dnh->bnsh", h, layer["wv"].astype(cdt))
-    q = apply_rope(rms_norm(q, layer["q_norm"], eps).astype(cdt), cos, sin)
-    k = apply_rope(rms_norm(k, layer["k_norm"], eps).astype(cdt), cos, sin)
+    rotated = (lambda x: x) if cos is None else (lambda x: apply_rope(x, cos, sin))
+    q = rotated(rms_norm(q, layer["q_norm"], eps).astype(cdt))
+    k = rotated(rms_norm(k, layer["k_norm"], eps).astype(cdt))
     return q, k, v
 
 

@@ -123,10 +123,15 @@ def apply_stack(
     seq_streams: tuple = (),
     layers_rng=None,
     attend: Optional[Callable] = None,  # `block`'s: in place of the attention dispatch, in every layer whose kind brings none
+    aux_per_layer: bool = False,  # every layer's aux as it is, laid out as a `Pattern`'s parameters are
 ) -> Tuple[Any, Any]:
     """Returns (activations, aux_sum): `block` over every layer, `out_part`'s
-    scalar aux summed. `seq_streams` are per-position arrays (leading dim S,
-    e.g. RoPE cos/sin tables) handed to `qkv_part`; they shard with the
+    scalar aux summed. With `aux_per_layer` nothing is summed: the second is
+    `{"leading": [a layer's aux, ...], "period": [for each place in the period
+    its layers' aux, stacked over the periods], "trailing": [...]}`, any tree a
+    layer (a router's counts for a rule outside the loss, nothing for a layer
+    that has none), for a model that reads its layers' statistics one by one.
+    `seq_streams` are per-position arrays (leading dim S, e.g. RoPE cos/sin tables) handed to `qkv_part`; they shard with the
     sequence under context parallelism: inside the pipeline's manual region
     each rank receives its own slice, so global positions stay correct. With
     `layers_rng` (a model with dropout, training), `out_part` gets a key of
@@ -156,10 +161,11 @@ def apply_stack(
             index = first_layer + (idx if per_period == 1 else idx * per_period + j)
             x, aux = one(kind, layer, index, attn, mb_idx, streams, x)
             auxs.append(aux)
-        return x, functools.reduce(jnp.add, auxs)
+        return x, tuple(auxs) if aux_per_layer else functools.reduce(jnp.add, auxs)
 
     n_pipeline = int(mesh.shape.get("pipeline", 1)) if mesh is not None else 1
     if n_pipeline > 1:
+        assert not aux_per_layer, "the pipeline's schedule sums the layers' aux"
         if not uniform:
             raise NotImplementedError(
                 "a stack whose layers differ in kind (stack.Pattern) cannot be cut into pipeline "
@@ -208,11 +214,14 @@ def apply_stack(
                 x,
                 (blocks["period"], jnp.arange(pattern.n_periods)),
             )
-        auxs.append(jnp.sum(of_periods))
+        if not aux_per_layer:
+            auxs.append(jnp.sum(of_periods))
         first_trailing = n_leading + pattern.n_periods * per_period
         for i, (kind, layer) in enumerate(zip(pattern.trailing, blocks["trailing"])):
             x, aux = one(kind, layer, first_trailing + i, attention_fn, None, seq_streams, x)
             auxs.append(aux)
+        if aux_per_layer:
+            return x, {"leading": auxs[:n_leading], "period": list(of_periods), "trailing": auxs[n_leading:]}
         return x, functools.reduce(jnp.add, auxs)
 
 

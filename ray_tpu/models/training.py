@@ -29,7 +29,12 @@ def model_for(config):
     TrainState/step factory needs of any model. A model that keeps buffers
     among its parameters (a router's selection bias, ...) also defines
     `frozen_params(config)`, a tree of bools like the parameters', True where
-    no optimizer step may change the leaf (`make_train_step`)."""
+    no optimizer step may change the leaf (`make_train_step`). One whose
+    source states a rule that moves such a buffer outside the loss defines
+    `update_buffers(params, stats, config)` beside it: its `loss_fn` then
+    returns `(loss, stats)`, and the step applies the rule to the updated
+    parameters after the optimizer (`trinity.py`: the routers' selection bias,
+    from the step's own counts)."""
     module = sys.modules[type(config).__module__]
     missing = [name for name in ("init_params", "param_logical_axes", "loss_fn")
                if not callable(getattr(module, name, None))]
@@ -103,6 +108,7 @@ def make_train_step(
 
     base_rng = jax.random.PRNGKey(0x5eed)
     frozen = getattr(model_for(config), "frozen_params", None)
+    update_buffers = getattr(model_for(config), "update_buffers", None)
 
     def step_fn(state: TrainState, batch):
         # The step's key, for whatever the model draws anew each step (dropout's
@@ -117,13 +123,19 @@ def make_train_step(
 
         import optax
 
-        loss, grads = jax.value_and_grad(loss_of)(state.params)
+        if update_buffers is None:
+            loss, grads = jax.value_and_grad(loss_of)(state.params)
+        else:  # the loss's statistics come out of the gradient pass beside it
+            (loss, stats), grads = jax.value_and_grad(loss_of, has_aux=True)(state.params)
         with jax.named_scope("optimizer"):
             updates, new_opt = optimizer.update(grads, state.opt_state, state.params)
             if frozen is not None:  # a buffer takes no update, a decoupled weight decay's neither
                 updates = jax.tree.map(lambda u, is_buffer: jnp.zeros_like(u) if is_buffer else u,
                                        updates, frozen(config))
             new_params = optax.apply_updates(state.params, updates)
+        if update_buffers is not None:  # neither a gradient's nor the optimizer's: the model's own rule
+            with jax.named_scope("buffers"):
+                new_params = update_buffers(new_params, stats, config)
         new_state = TrainState(
             params=new_params, opt_state=new_opt, step=state.step + 1
         )

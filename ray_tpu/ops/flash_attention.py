@@ -44,21 +44,28 @@ Design (pallas_guide.md playbook):
    of 2,048 keys in 16,384 leaves none empty (`dsa.live_tiles_share` 1.0,
    PERF.md section 6, PR 42).
  - a mask by structure (`causal=` a hashable description in place of True:
-   `BlockDiffusion`): a static function of two positions, which costs no
-   operand. The pair kernels' schedules ask it of every tile pair whether it
+   `BlockDiffusion`, `SlidingWindow`): a static function of two positions,
+   which costs no operand. The pair kernels' schedules ask it of every tile pair whether it
    is empty, whole or crossed (`_tiles_under`) and walk the empty ones not at
    all; a crossed pair's mask is made from iotas inside the kernel
    (`_kept_in_pair`). The whole-head forms know the causal diagonal alone, so
    any other mask runs both passes a pair a program. Block diffusion's row of
    16,384 (two copies of 8,192, blocks of 4) walks 160 of 512 pairs of 512 x
-   1,024 where the causal diagonal walks 272 (PERF.md section 6, PR 47).
+   1,024 where the causal diagonal walks 272 (PERF.md section 6, PR 47); a
+   window of 2,048 keys on the same row walks 90, the band and nothing else
+   (PR 61).
  - a crossed pair's live span: both pair-streamed schedules name, for every
    pair the mask crosses, the run of 128-key blocks of its K tile outside which
    the mask keeps no score of any query of its Q tile (`_live_span`, asked of
    the mask itself, whichever it is), and the kernels' products, mask and
    exponentials run over that run alone: the forward's loop over key blocks
    takes its bounds from the schedule, the backward slices k, v, dk and dv.
-   What is skipped is what the mask masks, so no output changes. At 512 x
+   What is skipped is what the mask masks, so no output changes. The span is
+   one run wherever it lies: a pair the diagonal crosses has its live keys at
+   the start of its K tile, one that a window's lower edge crosses at the end
+   (the span's first block is then not 0, which both kernels read from the
+   schedule), and one crossed on both sides, a window narrower than a tile,
+   in the middle; the mask made inside the span is the same two compares. At 512 x
    1,024 half of the causal diagonal's crossed pairs and two thirds of block
    diffusion's hold their live scores in half their keys (`keys_<scored>of
    <walked>` beside `tiles_...` in the call's scope: 2,112 of 2,176 blocks and
@@ -297,6 +304,38 @@ class BlockDiffusion(NamedTuple):
                          jax.lax.broadcasted_iota(jnp.int32, (queries, keys), 1))
 
 
+class SlidingWindow(NamedTuple):
+    """A causal window: query i keeps key j where `0 <= i - j < window` (the
+    query's own position and the `window - 1` before it; transformers' sliding
+    mask). The band under the diagonal, `window` keys wide: a tile pair is cut
+    by the diagonal above, by the window's edge below, or (a window narrower
+    than a tile) by both. Every query keeps its own key. `window >= seq` is
+    the causal diagonal, to the bit. Hashable and static, taken where
+    `BlockDiffusion` is."""
+
+    window: int
+
+    def kept(self, rows, cols):
+        """bool: whether query `rows` attends to key `cols` (int arrays of one shape, numpy's or jax's)."""
+        return (rows >= cols) & (rows - cols < self.window)
+
+    def tile_class(self, r0: int, r1: int, c0: int, c1: int) -> int:
+        """EMPTY, WHOLE or CROSSED: what `kept` keeps of queries [r0, r1) x keys
+        [c0, c1), from the rectangle's corners: the last query against the
+        first key is the pair the diagonal drops last and the window first."""
+        if c0 > r1 - 1 or r0 - (c1 - 1) >= self.window:  # all above the diagonal, or all behind the window
+            return EMPTY
+        if c1 - 1 <= r0 and (r1 - 1) - c0 < self.window:
+            return WHOLE
+        return CROSSED
+
+    def dense(self, queries: int, keys: int):
+        """The (queries, keys) bool of a whole row, for the XLA forms."""
+        assert queries == keys, f"a window over {queries} queries x {keys} keys: self-attention of one row alone"
+        return self.kept(jax.lax.broadcasted_iota(jnp.int32, (queries, keys), 0),
+                         jax.lax.broadcasted_iota(jnp.int32, (queries, keys), 1))
+
+
 def _dense_mask(causal, queries: int, keys: int):
     """(queries, keys) bool of `causal` (True: the diagonal, the last query on the last key), None for False."""
     if causal is False:
@@ -338,7 +377,7 @@ def _repeat_kv(q, k, v):
 def xla_attention(q, k, v, causal=True, sm_scale: Optional[float] = None, keep=None,
                   return_lse: bool = False):
     """Plain-XLA attention (fused well by the compiler; O(S^2) memory). `causal`:
-    True, False or a mask by structure (`BlockDiffusion`). `keep`
+    True, False or a mask by structure (`BlockDiffusion`, `SlidingWindow`). `keep`
     (batch, queries, spans * 128), packed (`pack_keep`): the keys each query may
     attend to, the same for every head. With `return_lse` also each row's
     log-sum-exp of the scaled scores it attends to, (batch, heads, queries) f32."""
@@ -1381,7 +1420,7 @@ def flash_attention(
     hold fewer heads, each shared by a group of consecutive query heads.
 
     causal: True (the diagonal), False (every key), or a mask by structure, a
-      static hashable description (`BlockDiffusion`): the kernels' schedules
+      static hashable description (`BlockDiffusion`, `SlidingWindow`): the kernels' schedules
       skip the tile pairs it leaves empty and make a crossed pair's mask from
       iotas, so it costs no operand.
     keep: (batch, seq, spans * 128) int32, a selection of keys for every query,

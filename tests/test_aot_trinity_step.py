@@ -1,0 +1,52 @@
+"""The Trinity cell's kernels under the window and its whole train step, compiled ahead of time for a `v5e:2x2`
+(`tests/aot_v5e.py`, a process a list), beside `tests/test_aot_keye_step.py`."""
+
+import json
+import os
+
+import pytest
+
+import aot_v5e
+from benchmark.harness.program_trace import PHASES, phase
+
+TRINITY = "trinity-mini-ep16-l5"
+V5E_HBM_BYTES = 16_909_336_064
+PARAMETERS = 504_147_712
+
+
+@pytest.fixture(scope="module")
+def aot():
+    return aot_v5e.steps(TRINITY)
+
+
+def test_the_step_hands_mosaic_four_band_calls_and_one_triangle_each_pass(aot):
+    """A dense window layer and one period in one scan: both flash kernels once a layer, under the stack's scope
+    `attention` and the kind's own, the window kinds' walking 90 of 512 tile pairs and scoring 600 of 720 key blocks,
+    the full kind's the triangle's 272 and 2,112 of 2,176; never again in the backward pass (`save_attn`)."""
+    got = aot(TRINITY)
+    flash = [n.split("/") for n in got["mosaic_scopes"] if n.split("/")[-2] in ("flash_fwd", "flash_bwd")]
+    by = lambda kernel, kind: [p for p in flash if p[-2] == kernel and kind in p]  # noqa: E731
+    for kernel in ("flash_fwd", "flash_bwd"):
+        assert (len(by(kernel, "dense_window")), len(by(kernel, "window")), len(by(kernel, "full"))) == (1, 3, 1)
+        for parts in by(kernel, "window") + by(kernel, "dense_window"):
+            assert "tiles_90of512" in parts and "keys_600of720" in parts and "attention" in parts
+        (parts,) = by(kernel, "full")
+        assert "tiles_272of512" in parts and "keys_2112of2176" in parts and "attention" in parts
+    for parts in flash:
+        assert phase("/".join(parts)) == ("backward" if parts[-2] == "flash_bwd" else "forward")
+        assert "rematted_computation" not in parts and ("group_8" in parts) == (parts[-2] == "flash_fwd")
+    # The expert layers run the grouped-matmul kernels over the held prefix in both forms of the layer.
+    assert {"gmm_fwd", "gmm_dlhs", "gmm_drhs", "sum_rows"} <= {n.split("/")[-2] for n in got["mosaic_scopes"]}
+    assert got["phases"] == sorted(PHASES)
+    assert got["element_moves"]["scalars"] == [] and got["backward_scatter_adds"] == []
+
+
+def test_the_step_fits_the_chip_with_a_gigabyte_and_a_half_to_spare(aot):
+    """504.1 M parameters x 12 B are the arguments (the f32 gradient is a temporary), XLA's peak is at most 15.5 GB of
+    the chip's 16.91 (ISSUE 61's headroom) and over the contract's floor, and the file records what this compile gave."""
+    got = aot(TRINITY)
+    assert 0 <= got["argument"] - PARAMETERS * 12 < 16 << 20  # beside the state: step, counts, the batch
+    assert got["peak"] is not None and 0.25 * V5E_HBM_BYTES < got["peak"] <= 15.5e9
+    with open(os.path.join(aot_v5e.REPO, "benchmark", "configs", TRINITY + ".json")) as fh:
+        recorded = json.load(fh)["memory_analysis_v5e_bytes"]
+    assert got["argument"] == recorded["arguments"] and got["peak"] <= recorded["peak_memory"] * 1.01

@@ -6,9 +6,15 @@ parsed and printed again without locations before the hash. Run it from two chec
 
     python3 tools/lowered_fingerprint.py            # this checkout
     python3 tools/lowered_fingerprint.py <root>     # another one (`git archive <commit> | tar -x -C <root>`)
+    python3 tools/lowered_fingerprint.py <root> sdar-nano keye-vl2-nano ...   # and the steps of these configurations
+
+Further arguments name files of `benchmark/configs/`: each one's whole step is lowered as its cell builds it and hashed
+the same way (PR 61 compared fifteen, every accepted model's nano and full-size step, across a change to `stack.py`,
+`gqa_experts.py` and `training.py`: a full-size step lowers in 5-20 s, nothing is compiled).
 """
 import base64
 import hashlib
+import importlib
 import json
 import os
 import re
@@ -42,7 +48,6 @@ def main():
     jax.config.update("jax_enable_compilation_cache", False)
     topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
     import aot_v5e
-    from benchmark.models import olmo_hybrid
     from ray_tpu.ops import gated_delta_rule as gdn
     from ray_tpu.ops.short_conv import short_conv
 
@@ -59,11 +64,13 @@ def main():
         "heads_per_program": gdn.heads_per_program(30, 4096, gdn.CHUNK, 96, 192, 2),
         "short_conv": digest(jax.jit(jax.grad(conv, argnums=(0, 1))).lower(sd((1, 4096, 2880)), sd((4, 2880), f32))),
     }
-    for name in ("olmo-hybrid-7b-fsdp4", "olmo-hybrid-nano"):
+    for name in ("olmo-hybrid-7b-fsdp4", "olmo-hybrid-nano", *sys.argv[2:]):
         with open(os.path.join("benchmark", "configs", name + ".json")) as fh:
             c = json.load(fh)
+        model = importlib.import_module("benchmark.models." + c["model"])
+        (to_config,) = [f for attr, f in vars(model).items() if attr.endswith("_config")]  # as `aot_v5e._step_case`
         out["step:" + name] = digest(aot_v5e._lowered_step(
-            topo, c["layout"]["mesh"], olmo_hybrid.olmo_hybrid_config(c), c["batch"]["global_rows"], c["batch"]["seq"],
+            topo, c["layout"]["mesh"] or {"data": 1}, to_config(c), c["batch"]["global_rows"], c["batch"]["seq"],
             c["learning_rate"]))
     print("LOWERED " + json.dumps(out))
 
