@@ -20,8 +20,10 @@ the patterned stack (leading layers, then a scan over periods; remat,
 attention dispatch, head, loss) is `stack.py`'s, the expert layer is
 `moe.moe_mlp`, which is told which experts this chip holds
 (`n_experts_held`): the router scores all `n_experts`, and the layer returns
-the part of the sum that its own experts give. The embedding is tied to the
-head. There is no auxiliary loss: the source balances by moving `expert_bias`
+the part of the sum that its own experts give; what stands between the
+convolution's two projections is `ops/short_conv.py gated_short_conv` (its
+gradient one Mosaic kernel where the step is compiled for a TPU, PR 62). The
+embedding is tied to the head. There is no auxiliary loss: the source balances by moving `expert_bias`
 outside the loss; that rule is not in its `config.json`, and here the bias is
 a seeded buffer that no optimizer step changes (`frozen_params`).
 """
@@ -39,6 +41,7 @@ import jax.numpy as jnp
 from ray_tpu.models.llama import apply_rope, rms_norm, rope_tables
 from ray_tpu.models.moe import moe_mlp, swiglu
 from ray_tpu.models.stack import Pattern, apply_stack, block, lm_head, lm_loss
+from ray_tpu.ops.short_conv import gated_short_conv
 
 CONV, ATTENTION = "conv", "full_attention"
 PUBLISHED_LAYER_TYPES = (CONV, CONV) + (ATTENTION, CONV, CONV, CONV) * 9 + (ATTENTION, CONV)
@@ -265,32 +268,27 @@ def frozen_params(config: LFM2Config) -> Dict[str, Any]:
 
 
 # --------------------------------------------------------------------------- forward
-def _shifted(z, n: int):
-    """z (B, S, D) moved `n` positions later, zeros before the row's first."""
-    return z if n == 0 else jnp.pad(z, ((0, 0), (n, 0), (0, 0)))[:, :z.shape[1]]
-
-
-def short_conv(h, layer, config: LFM2Config):
+def short_conv(h, layer, config: LFM2Config, mesh=None):
     """The gated short convolution on the normed h (B, S, D). `conv_mix` holds
     what is no matmul: the gate `B * u`, the taps and the gate `C * c`, in
-    float32 inside one fusion, rounded once."""
-    cdt, d, taps = config.dtype, config.d_model, config.conv_kernel
+    float32 inside one fusion, rounded once (`ops/short_conv.py
+    gated_short_conv`; where the step is compiled for a TPU, chosen by the
+    mesh's platform, its gradient is one Mosaic kernel that keeps `bcu` alone)."""
+    cdt = config.dtype
     with jax.named_scope("short_conv"):
         bcu = jnp.einsum("bsd,de->bse", h, layer["conv_in"].astype(cdt))
         with jax.named_scope("conv_mix"):
-            b, c, u = (bcu[..., i * d:(i + 1) * d].astype(jnp.float32) for i in range(3))
-            z, w = b * u, layer["conv_w"].astype(jnp.float32)
-            mixed = sum(w[j] * _shifted(z, taps - 1 - j) for j in range(taps))
-            y = (c * mixed).astype(cdt)
+            y = gated_short_conv(bcu, layer["conv_w"], mesh=mesh)
         return jnp.einsum("bsd,de->bse", y, layer["conv_out"].astype(cdt))
 
 
-def _kinds(config: LFM2Config, stats: bool = False):
+def _kinds(config: LFM2Config, stats: bool = False, mesh=None):
     """`stack.Pattern.kinds`: the parts of each kind of layer. x: (B, S, D);
     cos/sin: this rank's rows of the rotary tables. An `out_part` returns
     (x, aux): a zero, or with `stats` what `moe_mlp` reports of the layer
     (nothing for a dense one). The scope names are read from the compiled
-    program's `op_name`s (PERF.md, "names")."""
+    program's `op_name`s (PERF.md, "names"). `mesh`: `forward`'s, for the one
+    part that may hold a Mosaic call and is handed no mesh by the stack."""
     cdt, eps = config.dtype, config.norm_eps
 
     def qkv_part(x, layer, cos, sin):
@@ -314,7 +312,7 @@ def _kinds(config: LFM2Config, stats: bool = False):
         del o  # no attention in this kind's middle
         with jax.named_scope("conv_norm"):  # the operator's norm: `short_conv` opens its scope after it
             h = rms_norm(x, layer["op_norm"], eps).astype(cdt)
-        return x + short_conv(h, layer, config)
+        return x + short_conv(h, layer, config, mesh)
 
     def dense_ffn(x, layer):
         with jax.named_scope("dense_mlp"):
@@ -344,9 +342,9 @@ def _kinds(config: LFM2Config, stats: bool = False):
             for ffn_name, ffn in (("dense", dense_ffn), ("moe", moe_ffn))}
 
 
-def pattern(config: LFM2Config, stats: bool = False) -> Pattern:
+def pattern(config: LFM2Config, stats: bool = False, mesh=None) -> Pattern:
     leading, period, n_periods, trailing = layout(config)
-    return Pattern(_kinds(config, stats), period, n_periods, leading, trailing)
+    return Pattern(_kinds(config, stats, mesh), period, n_periods, leading, trailing)
 
 
 def forward(
@@ -366,7 +364,7 @@ def forward(
     with jax.named_scope("embed"):
         x = params["embed"].astype(cdt)[tokens]
     x, _ = apply_stack(
-        params["blocks"], x, config, pattern=pattern(config), attention_fn=attention_fn, mesh=mesh,
+        params["blocks"], x, config, pattern=pattern(config, mesh=mesh), attention_fn=attention_fn, mesh=mesh,
         num_microbatches=num_microbatches,
         seq_streams=rope_tables(tokens.shape[1], config.head_dim, config.rope_theta),
     )

@@ -30,6 +30,7 @@ Case names:
     gdn:BxHxSxDKxDV             `gdn_fwd` and `gdn_bwd` of `ops/gated_delta_rule.py`, the call and its gradient
     kda:BxHxSxDKxDV             `kda_fwd` and `kda_bwd` of `ops/kda.py`, the call and its gradient
     short_conv:BxSxHEADSxDxNORM `short_conv_bwd` of `ops/short_conv.py` under the XLA chain it is the gradient of
+    gated_conv:BxSxDxTAPS       `gated_conv_bwd` of `ops/short_conv.py` under the XLA chain it is the gradient of
     row_movers:TOKENS           `gather_rows` and `sum_rows` over a held prefix
     held_experts                an LFM2 step whose expert layer holds 2 of 16 experts
     lower:MESH, compile:MESH    gpt2_small's step over d1, d4 or d2t2, lowered or compiled
@@ -115,6 +116,19 @@ def remat_products(text):
             if m.group(3) == "convolution" or (fused and fused.group(1) in holds_product):
                 clones.append(m.group(1))
     return clones
+
+
+def written_under(text, scopes, scope, shape):
+    """{phase: [name]} of the instructions that write an array of `shape` (`f32[8,4096,2048]`) under `scope`: a
+    result outside every fused computation, a fusion's own among them, is an array in HBM."""
+    fused = {m.group(1) for m in re.finditer(r" fusion\(.*? calls=%?([\w.\-]+)", text)}
+    found = {}
+    for computation, line in by_computation(text):
+        m = RESULT.match(line)
+        if (m and computation not in fused and m.group(3) not in MOVES_NOTHING and shape in m.group(2)
+                and scope in scopes.get(m.group(1), "").split("/")):
+            found.setdefault(phase(scopes[m.group(1)]), []).append(m.group(1))
+    return found
 
 
 def layer_stacks(text):
@@ -373,6 +387,13 @@ def _kda_case(topo, batch, heads, seq, dk, dv):
             "states": sorted(set(re.findall(r"f32\[%d,\d+,%d,%d\]" % (batch * heads, dk, dv), text)))}
 
 
+def _walk_kernels(text, prefix):
+    """Of a compiled short convolution and its gradient: the Mosaic calls, their names, the plan in their scope."""
+    return {"mosaic_calls": text.count("tpu_custom_call"),
+            "kernels": sorted(set(re.findall(r"(%s\w+?)[.\d]* = " % prefix, text))),
+            "plans": sorted(set("/".join(found) for found in re.findall(r"\b(tile_\d+)\)*/(rows_\d+)\b", text)))}
+
+
 def _short_conv_case(topo, batch, seq, heads, d, normalize):
     """The short convolution of a linear layer's q (or k, or v) and its gradient, one device."""
     import jax
@@ -385,10 +406,21 @@ def _short_conv_case(topo, batch, seq, heads, d, normalize):
                for shape, dtype in (((batch, seq, heads * d), jnp.bfloat16), ((4, heads * d), jnp.float32)))
     loss = lambda z, taps: sc.short_conv(  # noqa: E731
         z, taps, heads, scale=d ** -0.5, normalize=bool(normalize), backend="pallas").astype(jnp.float32).sum()
-    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(z, taps).compile().as_text()
-    return {"mosaic_calls": text.count("tpu_custom_call"),
-            "kernels": sorted(set(re.findall(r"(short_conv_\w+?)[.\d]* = ", text))),
-            "plans": sorted(set("/".join(found) for found in re.findall(r"\b(tile_\d+)\)*/(rows_\d+)\b", text)))}
+    return _walk_kernels(jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(z, taps).compile().as_text(), "short_conv_")
+
+
+def _gated_conv_case(topo, batch, seq, d, taps):
+    """The gated short convolution of an LFM2 layer's bcu and its gradient, one device."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.ops import short_conv as sc
+
+    one = jax.sharding.SingleDeviceSharding(topo.devices[0])
+    bcu, w = (jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+              for shape, dtype in (((batch, seq, 3 * d), jnp.bfloat16), ((taps, d), jnp.float32)))
+    loss = lambda bcu, w: sc.gated_short_conv(bcu, w, backend="pallas").astype(jnp.float32).sum()  # noqa: E731
+    return _walk_kernels(jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(bcu, w).compile().as_text(), "gated_conv_")
 
 
 def _row_movers_case(topo, tokens, k=4, width=2048, n_experts=64, held=8):
@@ -512,12 +544,15 @@ def _step_case(topo, cell):
         "phases": sorted({phase(n) for n in scopes.values()}),
         "recomputed": sum("rematted_computation" in n.split("/") for n in scopes.values()),
         "remat_products": len(remat_products(text)),
+        "remat_clones": sorted(m.group(1) for m in RESULT.finditer(text) if ".remat" in m.group(1)),
     }
     out["stacks"], out["stacked_bytes"] = layer_stacks(text)
     if c["model"] == "olmo_hybrid":  # the short convolutions' chain (scope `gdn_conv`): forward, and made again?
         conv = [n.split("/") for n in scopes.values() if "gdn_conv" in n.split("/") and "pallas_call" not in n]
         out["conv_chain_forward"] = sum(phase("/".join(parts)) == "forward" for parts in conv)
         out["conv_chain_recomputed"] = sum("rematted_computation" in parts for parts in conv)
+    if c["model"] == "lfm2":  # what the short convolutions' `conv_mix` writes in float32 at the size of a layer's y
+        out["conv_mix_f32"] = written_under(text, scopes, "conv_mix", "f32[%d,%d,%d]" % (rows, seq, c["hidden_size"]))
     out["gather_minor_dims"], out["gathered_weight_copies"] = block_weight_gathers(text, scopes)
     if "num_experts_per_tok" in c:
         out["sorted_rows_moved"], out["backward_scatter_adds"] = sorted_row_traffic(
@@ -545,6 +580,8 @@ def _case(topo, case):
         return _kda_case(topo, *numbers())
     if name == "short_conv":
         return _short_conv_case(topo, *numbers())
+    if name == "gated_conv":
+        return _gated_conv_case(topo, *numbers())
     if name == "row_movers":
         return _row_movers_case(topo, int(rest))
     if case == "held_experts":

@@ -108,8 +108,34 @@ def test_the_lfm2_step_makes_nothing_again_that_it_kept_before(aot):
     recompute three layers' routers, sorts and short convolutions, 16 ms of
     440 on the chip (1,427 instructions under `rematted_computation` for the
     142 that the dense layer's and the attention layer's recomputation hold;
-    PERF.md section 6, PR 40). A PR that changes what a layer keeps sees it here."""
-    assert aot(LFM2)["recomputed"] <= 142
+    PERF.md section 6, PR 40). A PR that changes what a layer keeps sees it here.
+    Pinned again at PR 62, on purpose: the short convolutions' `conv_mix` keeps
+    `bcu` alone, so the 15 instructions that made its chain again are gone (127)."""
+    assert aot(LFM2)["recomputed"] <= 127
+
+
+# The LFM2 step's XLA peak before PR 62 (`memory_analysis().peak_memory_in_bytes`, this installation): the
+# float32 z = b u and the taps' sum were residuals of `conv_mix`, a 268 MB array each a conv layer.
+LFM2_PEAK_AT_PR61 = 13_512_126_464
+
+
+def test_the_short_convolutions_mix_is_one_pass_forward_and_one_kernel_backward(aot):
+    """PR 62. Under `conv_mix` (what `conv.mix_ms` and `conv.mix_roofline` read) the step holds four calls of
+    `gated_conv_bwd`, one a conv layer, all in the backward pass and none under a remat; forward a layer's chain is
+    one fusion that reads `bcu` and writes y in bf16, so nothing of a layer's size is written in float32 in any
+    phase (until PR 62: the gate `b u` and the taps' sum forward, four of the latter cloned by XLA's own
+    rematerialization, and four more in the gradient). XLA clones no `multiply_add_fusion` and one product
+    fewer (`remat_products` 7 until then), and the peak is under the parent's."""
+    got = aot(LFM2)
+    calls = [n.split("/") for n in got["mosaic_scopes"] if n.split("/")[-2] == "gated_conv_bwd"]
+    assert len(calls) == 4
+    for parts in calls:
+        assert parts[-4:-2] == ["tile_512", "rows_4096"] and "conv_mix" in parts and "short_conv" in parts, parts
+        assert phase("/".join(parts)) == "backward" and "rematted_computation" not in parts
+    assert got["conv_mix_f32"] == {}
+    assert not [n for n in got["remat_clones"] if n.startswith("multiply_add_fusion")], got["remat_clones"]
+    assert got["remat_products"] <= 6 and len(got["remat_clones"]) <= 7
+    assert got["peak"] is not None and got["peak"] <= LFM2_PEAK_AT_PR61 - (100 << 20)
 
 
 @pytest.mark.parametrize("kernel", sorted(PREFIX_KERNELS))

@@ -27,6 +27,8 @@ LONGER_HEADS = ("kernel:2x32x8192x64", "kernel:1x16x8192x128")  # what failed in
 # The expert layers of the lfm2 and glm-4.7-flash cells: 8 x 4,096 and 2 x 4,096 tokens, 4 of 64 experts a
 # token, 8 held: a prefix of 32,768 and of 8,192 sorted rows of 2,048.
 ROW_MOVERS = ("row_movers:32768", "row_movers:8192")
+# A conv layer of the lfm2 cell (PR 62): bcu of 8 x 4,096 x (3 x 2,048) bf16, three taps, the gradient's kernel.
+GATED_CONV_4K = "gated_conv:8x4096x2048x3"
 # The Keye cell's attention (PR 42): one row, 32 query heads on 4 key/value heads of 16,384 x 128, a
 # packed selection; and the two kernels of `ops/lightning_indexer.py` at its indexer's 16 heads of 64.
 SELECTED_16K = "selected:1x32x4x16384x128"
@@ -51,7 +53,7 @@ BETWEEN_HEADS = ("kernel:2x4x3072x256", "kernel:2x4x3584x256", "kernel:2x4x6144x
 def aot():
     return aot_v5e.Cases(
         ["kernel", LONG_HEAD_64, WIDE_HEAD_256, *LONGER_HEADS, *BETWEEN_HEADS, *STREAMED_HEADS, "lower:d4", "lower:d2t2"],
-        ["held_experts", *ROW_MOVERS],
+        ["held_experts", *ROW_MOVERS, GATED_CONV_4K],
         [SELECTED_16K, INDEXER_16K, GROUPED_2K, MASKED_16K])
 
 
@@ -201,6 +203,18 @@ def test_the_row_movers_of_the_prefix_form_compile_for_v5e_at_the_cells_shapes(a
     got = aot[case]
     assert {k: got[k] for k in got if k != "seconds"} == {
         "rows": rows, "chunk_rows": [256, 128], "kernels": ["gather_rows", "sum_rows"]}
+
+
+def test_the_gated_short_convolutions_gradient_kernel_compiles_for_v5e_at_the_cells_shape(aot):
+    """(8, 4096, 6144) bf16 and three taps: a program holds a row's 4,096 positions of a 512-channel tile of b, c, u
+    and the cotangent and of db, dc, du, seven blocks of 4 MiB with two buffers each (56 of the 64 MiB the kernel
+    allows itself, over Mosaic's default), and copies its three results into the one `dbcu` itself. Forward
+    there is no kernel: the chain is XLA's."""
+    from ray_tpu.ops import short_conv as sc
+
+    got = aot[GATED_CONV_4K]
+    assert got["mosaic_calls"] == 1 and got["kernels"] == ["gated_conv_bwd"]
+    assert got["plans"] == [f"tile_{sc.gated_tile(4096, 2048, 2)}/rows_4096"] == ["tile_512/rows_4096"]
 
 
 @pytest.mark.parametrize("mesh", ["d4", "d2t2"])

@@ -345,6 +345,50 @@ def test_the_patterned_stack_is_its_layers_applied_one_by_one():
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
 
 
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_the_conv_layers_mix_is_the_written_arithmetic_and_takes_the_kernels_gradient(monkeypatch, dtype):
+    """`lfm2.short_conv` at a width the gradient kernel takes (128 channels: the nano model's 64 are no lane row),
+    against the operator written out as the model had it until PR 62 (the shifts of z = b u): the value bit for
+    bit, and with `gated_short_conv` told to take the kernel (interpret mode) the gradients to h and to every
+    weight of the layer as jax's own of the written chain; on this platform the model itself asks for no kernel."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models import LFM2Config, lfm2
+    from ray_tpu.ops import short_conv as sc
+
+    cfg = LFM2Config.nano(dtype=jnp.dtype(dtype))  # the operator reads the dtype alone: the widths are the operands'
+    keys = jax.random.split(jax.random.PRNGKey(5), 5)
+    layer = {"conv_in": jax.random.normal(keys[0], (128, 384)) * 0.1, "conv_w": jax.random.normal(keys[1], (3, 128)) * 0.5,
+             "conv_out": jax.random.normal(keys[2], (128, 128)) * 0.1}
+    h, weights = jax.random.normal(keys[3], (2, 48, 128)).astype(cfg.dtype), jax.random.normal(keys[4], (2, 48, 128))
+
+    def written(h, layer):
+        bcu = jnp.einsum("bsd,de->bse", h, layer["conv_in"].astype(cfg.dtype))
+        b, c, u = (bcu[..., i * 128:(i + 1) * 128].astype(jnp.float32) for i in range(3))
+        z, w = b * u, layer["conv_w"].astype(jnp.float32)
+        late = lambda x, n: x if n == 0 else jnp.pad(x, ((0, 0), (n, 0), (0, 0)))[:, :x.shape[1]]  # noqa: E731
+        y = (c * sum(w[j] * late(z, 2 - j) for j in range(3))).astype(cfg.dtype)
+        return jnp.einsum("bsd,de->bse", y, layer["conv_out"].astype(cfg.dtype))
+
+    def both(f):
+        loss = lambda h, layer: (f(h, layer).astype(jnp.float32) * weights).sum()  # noqa: E731
+        return jax.jit(lambda h, layer: (f(h, layer), jax.grad(loss, argnums=(0, 1))(h, layer)))(h, layer)
+
+    model = lambda h, layer: lfm2.short_conv(h, layer, cfg)  # noqa: E731
+    assert "pallas_call" not in str(jax.make_jaxpr(jax.grad(lambda h: model(h, layer).astype(jnp.float32).sum()))(h))
+    want, want_grads = both(written)
+    monkeypatch.setattr(lfm2, "gated_short_conv", functools.partial(sc.gated_short_conv, backend="pallas", interpret=True))
+    assert "gated_conv_bwd" in str(jax.make_jaxpr(jax.grad(lambda h: model(h, layer).astype(jnp.float32).sum()))(h))
+    got, grads = both(model)
+    np.testing.assert_array_equal(np.asarray(got, np.float32), np.asarray(want, np.float32))
+    far = lambda a, b: float(jnp.abs(a.astype(jnp.float32) - b.astype(jnp.float32)).max() / jnp.abs(b.astype(jnp.float32)).max())  # noqa: E731
+    worst = max(jax.tree.leaves(jax.tree.map(far, grads, want_grads)))
+    assert worst < (5e-6 if dtype == "float32" else 2 ** -6), jax.tree.map(far, grads, want_grads)
+
+
 def test_a_patterned_stack_under_a_pipeline_axis_says_so():
     import jax
     import jax.numpy as jnp

@@ -45,7 +45,8 @@ OWN = {"gpt": ("out_mlp",), "llama": ("out_mlp",),
        # PR 42: the indexer's projections in `qkv`, the selection and the indexer's loss in `attention`.
        "keye": ("attn_out", "moe", "router", "dispatch", "experts", "combine",
                 "indexer", "select", "index_loss")}
-# For lfm2 also what a block with no attention in its middle holds: all of it is recomputed.
+# For lfm2 also what a block with no attention in its middle holds: all of it is recomputed (off the TPU; in a step
+# compiled for one, `conv_mix`'s chain is no one's residual and is not made again: `tests/test_aot_expert_steps.py`, PR 62).
 HALVES = {"gpt": {"qkv", "out_mlp"}, "llama": {"qkv", "out_mlp"},
           "olmoe": {"qkv", "attn_out", "moe", "router", "experts"},
           "lfm2": {"qkv", "attn_out", "moe", "router", "experts", "short_conv", "conv_mix", "dense_mlp"},

@@ -3,7 +3,8 @@
 float32, a row's first three positions (the zeros before it) against a convolution written out; the gradient
 kernel `short_conv_bwd` in interpret mode against jax's gradient of the chain, at widths that fill lane rows (heads
 of 96 and of 192), at a last tile that passes the array's end, at the nano model's widths, under a mesh; and which
-form a call takes."""
+form a call takes. The gated form (LFM2's, PR 62) likewise: its chain against `models/lfm2.py`'s lines at PR 61 bit
+for bit, its kernel `gated_conv_bwd` against jax's gradient of that chain, and which form a call takes."""
 
 import os
 import sys
@@ -151,17 +152,83 @@ def test_the_form_is_chosen_by_the_platform_and_the_shapes():
         call("q_768", backend="pallas")(z[:, :40], taps)
 
 
+# The gated form: (D, S, taps). 1,024 channels take the widest tile (512 divides them), 768 one of 384 and 640 one of
+# 128 (512 does not divide them); 32 positions are one step of a program's walk, 160 five, 48 three of 16.
+GATED = {"d1024_one_step": (1024, 32, 3), "d768_five_steps": (768, 160, 3), "d640_two_taps": (640, 48, 2),
+         "d256_two_programs_a_row": (256, 64, 3)}
+
+
+def old_gated_arithmetic(bcu, taps):
+    """`models/lfm2.py short_conv`'s lines under `conv_mix` at PR 61: the shifts are of the product z = b u."""
+    d, n = bcu.shape[2] // 3, taps.shape[0]
+    b, c, u = (bcu[..., i * d:(i + 1) * d].astype(jnp.float32) for i in range(3))
+    z, w = b * u, taps.astype(jnp.float32)
+    return (c * sum(w[j] * sc._shifted(z, n - 1 - j) for j in range(n))).astype(bcu.dtype)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+@pytest.mark.parametrize("name", list(GATED))
+def test_the_gated_chain_is_the_models_arithmetic_and_its_kernels_gradient_is_the_chains(name, dtype):
+    """Forward the chain shifts `bcu` where the model shifted b u: the same float32 products, bit for bit. The
+    kernel's gradient against jax's of the old chain (float32 between bcu and y, as the kernel): to f32's rounding
+    on f32 operands, to the one rounding of dbcu on bf16 ones; the taps' gradient stays f32."""
+    d, seq, n = GATED[name]
+    keys = jax.random.split(jax.random.PRNGKey(1), 3)
+    bcu = jax.random.normal(keys[0], (3, seq, 3 * d)).astype(dtype)
+    taps, weights = jax.random.normal(keys[1], (n, d)) * 0.5, jax.random.normal(keys[2], (3, seq, d))
+    assert sc.gated_tile(seq, d, bcu.dtype.itemsize) == {1024: 512, 768: 384, 640: 128, 256: 256}[d]
+    (out, (dbcu, dw)), (chain_out, _), (want, (want_dbcu, want_dw)) = (
+        value_and_grads(f, bcu, taps, weights) for f in (
+            lambda bcu, taps: sc.gated_short_conv(bcu, taps, backend="pallas", interpret=True),
+            lambda bcu, taps: sc.gated_short_conv(bcu, taps, backend="xla"), old_gated_arithmetic))
+    assert out.shape == (3, seq, d) and out.dtype == dbcu.dtype == dtype and dw.dtype == taps.dtype
+    for got in (out, chain_out):
+        np.testing.assert_array_equal(np.asarray(got, np.float32), np.asarray(want, np.float32))
+    assert far(dbcu, want_dbcu) < (2e-6 if dtype == jnp.float32 else 2 ** -7)
+    assert far(dw, want_dw) < 2e-6 and all(far(dw[j], want_dw[j]) < 5e-6 for j in range(n))
+    # each third by itself (db, dc, du are column blocks D apart of one array), and the row's first positions
+    for k in range(3):
+        third = slice(k * d, (k + 1) * d)
+        assert far(dbcu[..., third], want_dbcu[..., third]) < (5e-6 if dtype == jnp.float32 else 2 ** -7)
+    assert far(dbcu[:, :2], want_dbcu[:, :2]) < (5e-6 if dtype == jnp.float32 else 2 ** -6)
+
+
+def test_the_gated_form_is_chosen_by_the_platform_and_the_shapes():
+    # The cell's layer fits at the widest tile; a row of 8,192 or 16,384 takes a narrower one; one of 65,536 none.
+    assert sc.gated_fits((8, 4096, 6144), 3, 2) and sc.gated_tile(4096, 2048, 2) == sc.GATED_TILE == 512
+    assert (sc.gated_tile(8192, 2048, 2), sc.gated_tile(16384, 2048, 2), sc.gated_tile(65536, 2048, 2)) == (256, 128, 0)
+    assert not sc.gated_fits((1, 65536, 6144), 3, 2)
+    # The nano model's 64 channels are no lane row; 40 positions no whole steps; ten taps pass the halo.
+    assert not sc.gated_fits((2, 64, 192), 3, 4) and not sc.gated_fits((2, 40, 768), 3, 4)
+    assert not sc.gated_fits((2, 64, 768), 10, 4) and sc.gated_fits((2, 64, 768), 3, 4)
+    bcu, taps = jnp.zeros((2, 64, 768), jnp.bfloat16), jnp.ones((3, 256))
+    grad = lambda bcu=bcu, **how: str(jax.make_jaxpr(jax.grad(  # noqa: E731
+        lambda bcu, taps: sc.gated_short_conv(bcu, taps, **how).astype(jnp.float32).sum(), argnums=(0, 1)))(bcu, taps))
+    # On this platform a call is the chain alone; asked for, the gradient holds the kernel and keeps bcu and the taps.
+    assert "pallas_call" not in grad() and "gated_conv_bwd" in grad(backend="pallas")
+    assert "pallas_call" not in grad(mesh=jax.sharding.Mesh(np.array(jax.devices()[:2]), ("data",)))
+    with pytest.raises(ValueError, match="neither"):
+        sc.gated_short_conv(bcu, taps, backend="mosaic")
+    with pytest.raises(ValueError, match="takes no bcu"):
+        sc.gated_short_conv(bcu[:, :40], taps, backend="pallas")
+
+
 def test_the_bench_tool_walks_every_form_off_the_chip():
-    """`tools/short_conv_bench.py --rehearse`: the old chain, the module and the gated norm's chain at a small
-    shape in interpret mode, no timing; the module's line carries its distance from the old chain."""
+    """`tools/short_conv_bench.py --rehearse`: the old chain, the module, the gated norm's chain and LFM2's gated
+    form (its old chain, the module) at small shapes in interpret mode, no timing; a module's line carries its
+    distance from its old chain."""
     import json
     import subprocess
 
     done = subprocess.run(
         [sys.executable, os.path.join(REPO, "tools", "short_conv_bench.py"), "--rehearse", "--shape", "1x32x4x96x192",
-         "--form", "chain,module,xla,out_chain"], capture_output=True, text=True, timeout=240,
+         "--gated-shape", "2x64x256x3", "--form", "chain,module,xla,out_chain,gated_chain,gated"],
+        capture_output=True, text=True, timeout=240,
         env={**os.environ, "JAX_PLATFORMS": "cpu"})
     assert done.returncode == 0, done.stderr[-2000:]
     lines = {line["form"]: line for line in map(json.loads, done.stdout.strip().splitlines())}
-    assert list(lines) == ["chain", "module", "xla", "out_chain"] and all(line["rehearsal"] for line in lines.values())
+    assert list(lines) == ["chain", "module", "xla", "out_chain", "gated_chain", "gated"]
+    assert all(line["rehearsal"] for line in lines.values())
     assert max(lines["module"]["check"].values()) < 1e-5 and max(lines["xla"]["check"].values()) == 0.0
+    assert lines["gated"]["check"]["out"] == 0.0 and max(lines["gated"]["check"].values()) < 1e-5
+    assert lines["gated"]["shape"] == "2x64x256x3" and lines["module"]["shape"] == "1x32x4x96x192"

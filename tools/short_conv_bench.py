@@ -1,9 +1,11 @@
 """The linear mixer's elementwise passes alone, on the chip: the chain of XLA operations the model ran until PR 54
-beside `ops/short_conv.py`, forward and with the gradient.
+beside `ops/short_conv.py`, forward and with the gradient; and LFM2's gated short convolution likewise (PR 62).
 
     chiprun -- python3 tools/short_conv_bench.py
     chiprun -- python3 tools/short_conv_bench.py --form chain,module --set ROWS_NORMALIZED=64 --set ROWS_PLAIN=16
+    chiprun -- python3 tools/short_conv_bench.py --form gated_chain,gated --set ROWS_GATED=64 --set GATED_TILE=256
     python3 tools/short_conv_bench.py --rehearse --shape 2x64x4x96x192
+    python3 tools/short_conv_bench.py --rehearse --form gated_chain,gated --gated-shape 2x64x256x3
 
 `--shape BATCHxSEQxHEADSxDKxDV` (one linear layer of the Olmo-Hybrid cell by default: a row of 4,096, 30 heads of 96 /
 192, so z = [W_q x | W_k x | W_v x] is 1 x 4,096 x 11,520 bf16). Inputs from `--seed`: the three bf16 projections,
@@ -16,6 +18,14 @@ f32 taps, bf16 cotangents heads-first as `gdn_bwd` hands them.
   xla        `short_conv(backend="xla")`: the module's form off the TPU (the chain, differentiated by jax)
 and `out_chain`, `linear_out`'s gated norm at (B, S, H x DV) as it stands (o heads-first and W_z x in, the gated
 product out): measured, not replaced (PERF.md section 6, PR 54).
+
+`--gated-shape BATCHxSEQxDxTAPS` (one conv layer of the LFM2 cell by default: 8 x 4,096 x 2,048, 3 taps, so bcu =
+W_in h is 8 x 4,096 x 6,144 bf16), what takes bcu to y (B, S, D) bf16:
+  gated_chain  `models/lfm2.py short_conv`'s lines under `conv_mix` as they stood at PR 61, kept here alone: widen,
+               z = b u, three shifted products of z, the gate c, the cast, and jax's gradient of that
+  gated        `gated_short_conv(backend="pallas")`: the chain with `bcu` shifted forward, `gated_conv_bwd` for the
+               gradient; `--set ROWS_GATED=` / `--set GATED_TILE=` stand the rows a step and the widest tile in
+Their `needed_mb` is 4 + 7 passes of B x S x D x 2 B (bcu read and y written; bcu and dy read, dbcu written).
 
 A JSON line a form, on stdout and in `chiprun_out/short_conv_bench.jsonl`: `fwd_us`, the device's busy time of one
 call of the forward program, and `vjp_us`, of the program that makes the gradients to the projections and the taps
@@ -46,7 +56,7 @@ import time
 from statistics import median
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-FORMS = ("chain", "module", "xla", "out_chain")
+FORMS = ("chain", "module", "xla", "out_chain", "gated_chain", "gated")
 
 
 def _device_ops(trace_dir):
@@ -87,6 +97,20 @@ def forms(jax, jnp, sc, heads, dk, interpret):
     return {"chain": chain, "module": module("pallas"), "xla": module("xla")}
 
 
+def gated_forms(jax, jnp, sc, interpret):
+    """{form: f(bcu, taps) -> y}."""
+    f32 = jnp.float32
+
+    def gated_chain(bcu, taps):
+        d, n = bcu.shape[2] // 3, taps.shape[0]
+        b, c, u = (bcu[..., i * d:(i + 1) * d].astype(f32) for i in range(3))
+        z, w = b * u, taps.astype(f32)
+        return (c * sum(w[j] * sc._shifted(z, n - 1 - j) for j in range(n))).astype(bcu.dtype)
+
+    return {"gated_chain": gated_chain,
+            "gated": functools.partial(sc.gated_short_conv, backend="pallas", interpret=interpret)}
+
+
 def out_chain(jax, jnp, eps=1e-6):
     """`linear_out`'s gated norm as it stands: (o (B, H, S, dv), z (B, S, H dv), scale (dv,)) -> (B, S, H dv)."""
     from ray_tpu.models.llama import rms_norm
@@ -101,6 +125,7 @@ def out_chain(jax, jnp, eps=1e-6):
 def main():
     p = argparse.ArgumentParser(prog="tools/short_conv_bench.py")
     p.add_argument("--shape", default="1x4096x30x96x192")
+    p.add_argument("--gated-shape", default="8x4096x2048x3")
     p.add_argument("--form", default="chain,module,out_chain")
     p.add_argument("--set", action="append", default=[], metavar="NAME=INT", help="stand a constant of ops/short_conv.py in")
     p.add_argument("--seed", type=int, default=0)
@@ -125,7 +150,8 @@ def main():
     os.makedirs(out_dir, exist_ok=True)
 
     def say(**line):
-        line = {"device": jax.devices()[0].device_kind, "shape": args.shape, "set": args.set, **line}
+        shape = args.gated_shape if line["form"].startswith("gated") else args.shape
+        line = {"device": jax.devices()[0].device_kind, "shape": shape, "set": args.set, **line}
         print(json.dumps(line), flush=True)
         with open(os.path.join(out_dir, "short_conv_bench.jsonl"), "a") as fh:
             fh.write(json.dumps(line) + "\n")
@@ -141,28 +167,41 @@ def main():
     by_form["out_chain"] = out_chain(jax, jnp)
     out_operands = (first(keys[9], dv), wide(keys[10], dv), 1.0 + 0.1 * jax.random.normal(keys[11], (dv,)))
     out_cotangent = wide(keys[6], dv)
+    g_batch, g_seq, g_d, g_taps = (int(n) for n in args.gated_shape.split("x"))
+    by_form.update(gated_forms(jax, jnp, sc, args.rehearse))
+    gated_operands = (jax.random.normal(keys[0], (g_batch, g_seq, 3 * g_d)).astype(bf16),
+                      jax.random.normal(keys[3], (g_taps, g_d)) * 0.5)
+    gated_cotangent = (jax.random.normal(keys[6], (g_batch, g_seq, g_d)) * 0.1).astype(bf16)
+    references = {"module": "chain", "xla": "chain", "gated": "gated_chain"}
+
+    def operands_of(form):
+        """(operands, cotangents) of a form."""
+        return ((out_operands, out_cotangent) if form == "out_chain" else
+                (gated_operands, gated_cotangent) if form.startswith("gated") else (operands, cotangents))
 
     def programs(form):
         """(forward, vjp, operands, cotangents) of a form: the vjp returns the gradients to every operand."""
         f = by_form[form]
-        ops, cts = (out_operands, out_cotangent) if form == "out_chain" else (operands, cotangents)
-        return jax.jit(f), jax.jit(lambda ops, cts: jax.vjp(f, *ops)[1](cts)), ops, cts
+        return jax.jit(f), jax.jit(lambda ops, cts: jax.vjp(f, *ops)[1](cts)), *operands_of(form)
 
     def distance(form):
-        """The form's outputs and gradients against `chain`'s on f32 operands (so both round nothing at the end)."""
-        f32 = lambda xs: tuple(x.astype(jnp.float32) for x in xs)  # noqa: E731
+        """The form's outputs and gradients against its chain's on f32 operands (so both round nothing at the end)."""
+        f32 = lambda xs: jax.tree.map(lambda x: x.astype(jnp.float32), xs)  # noqa: E731
+        ops, cts = operands_of(form)
         both = []
-        for f in (by_form["chain"], by_form[form]):
-            both.append(jax.jit(lambda ops, cts, f=f: (f(*ops), jax.vjp(f, *ops)[1](cts)))(f32(operands), f32(cotangents)))
+        for f in (by_form[references[form]], by_form[form]):
+            both.append(jax.jit(lambda ops, cts, f=f: (f(*ops), jax.vjp(f, *ops)[1](cts)))(f32(ops), f32(cts)))
         far = lambda a, b: float(jnp.abs(a - b).max() / jnp.abs(b).max())  # noqa: E731
         (o, g), (o_ref, g_ref) = both[1], both[0]
-        return {"out": max(map(far, o, o_ref)), "dz": max(map(far, g[:3], g_ref[:3])), "dtaps": max(map(far, g[3:], g_ref[3:]))}
+        wide = len(g) // 2  # the gradients to the wide operands, then to their taps
+        return {"out": max(jax.tree.leaves(jax.tree.map(far, o, o_ref))), "dz": max(map(far, g[:wide], g_ref[:wide])),
+                "dtaps": max(map(far, g[wide:], g_ref[wide:]))}
 
     z_bytes = batch * seq * heads * (2 * dk + dv) * 2
     for form in args.form.split(","):
         assert form in FORMS, form
         line = {"form": form}
-        if form in ("module", "xla"):
+        if form in references:
             line["check"] = distance(form)
         fwd, vjp, ops, cts = programs(form)
         t = time.perf_counter()
@@ -189,7 +228,8 @@ def main():
             times[name] = median(sum(us for _, us in call) for call in calls)
             line[name + "_ops"] = sorted(((n, round(us, 1)) for n, us in calls[-1]), key=lambda x: -x[1])[:12]
             line[name + "_n_ops"] = per_call
-        needed = (5 * z_bytes) if form != "out_chain" else 10 * batch * seq * heads * dv * 2
+        needed = (10 * batch * seq * heads * dv * 2 if form == "out_chain" else
+                  11 * g_batch * g_seq * g_d * 2 if form.startswith("gated") else 5 * z_bytes)
         say(**line, fwd_us=times["fwd"], vjp_us=times["vjp"], call_us=median(clock), needed_mb=needed / 1e6,
             gbps=needed / (times["fwd"] + times["vjp"]) / 1e3)
 
