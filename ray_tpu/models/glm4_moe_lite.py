@@ -46,8 +46,9 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.models.llama import apply_rope, rms_norm, rope_tables
-from ray_tpu.models.moe import moe_mlp, shared_expert, swiglu
-from ray_tpu.models.stack import Pattern, apply_stack, block, causal_lm_loss, lm_head, lm_loss
+from ray_tpu.models.moe import moe_mlp, routing_report, shared_expert, swiglu
+from ray_tpu.models.stack import (Pattern, apply_stack, block, causal_lm_loss, draw, draw_layer, lm_head, lm_loss,
+                                  lm_tree, per_leaf)
 
 DENSE, MOE = "latent_dense", "latent_moe"
 
@@ -173,16 +174,16 @@ def train_flops_per_token(config: GLM4MoELiteConfig, seq_len: int) -> float:
 
 # --------------------------------------------------------------------------- init
 def _layer_shapes(config: GLM4MoELiteConfig, kind: str):
-    """{name: (shape, init std or the constant 1.0, logical axes)} of one layer of `kind`."""
+    """{name: (shape, init: a normal's std or "ones", logical axes)} of one layer of `kind`."""
     d, nh, f = config.d_model, config.n_head, config.d_expert
     ql, kvl, rope = config.q_lora_rank, config.kv_lora_rank, config.qk_rope_head_dim
     std, out_std = 0.02, 0.02 / math.sqrt(2 * config.n_layer)
     shapes: Dict[str, Any] = {
-        "attn_norm": ((d,), 1.0, (None,)), "ffn_norm": ((d,), 1.0, (None,)),
-        "wq_a": ((d, ql), std, ("embed", None)), "q_a_norm": ((ql,), 1.0, (None,)),
+        "attn_norm": ((d,), "ones", (None,)), "ffn_norm": ((d,), "ones", (None,)),
+        "wq_a": ((d, ql), std, ("embed", None)), "q_a_norm": ((ql,), "ones", (None,)),
         "wq_b": ((ql, nh, config.head_dim), std, (None, "heads", None)),
         # Columns: the latent, then the one rotary key every head shares.
-        "wkv_a": ((d, kvl + rope), std, ("embed", None)), "kv_a_norm": ((kvl,), 1.0, (None,)),
+        "wkv_a": ((d, kvl + rope), std, ("embed", None)), "kv_a_norm": ((kvl,), "ones", (None,)),
         # Per head: the key's part that takes no rotation, then the value.
         "wkv_b": ((kvl, nh, config.qk_nope_head_dim + config.v_head_dim), std, (None, "heads", None)),
         "wo": ((nh, config.v_head_dim, d), out_std, ("heads", None, "embed")),
@@ -208,27 +209,16 @@ def _layer_shapes(config: GLM4MoELiteConfig, kind: str):
     return shapes
 
 
-_is_shape = lambda x: isinstance(x, tuple) and len(x) == 3 and isinstance(x[0], tuple)  # noqa: E731
-
-
-def _tree(config: GLM4MoELiteConfig, layers: Callable, leaf: Callable):
-    """A tree like the parameters': `layers(kind, i, stack)` for the layer (or,
-    with `stack` = (n,), the n stacked expert layers) that begins at layer i,
-    the prediction module's layer as layer `n_layer`; `leaf(name, shape, std,
-    axes)` for every array outside a layer."""
+def _tree(config: GLM4MoELiteConfig, leaf: Callable, layers: Optional[Callable] = None):
+    """`stack.lm_tree` of this model, a tree like the parameters' (`leaf(name,
+    shape, init, axes)` for every leaf, a layer's through `layers(kind, i,
+    stack)` where `init_params` brings it), and the prediction module beside
+    it, its layer as layer `n_layer`."""
     d, n_moe = config.d_model, config.n_layer - config.n_dense_layers
-    norm = lambda name: leaf(name, (d,), 1.0, (None,))  # noqa: E731
-    tree = {
-        "embed": leaf("embed", (config.vocab_size, d), 0.02, ("vocab", "embed")),
-        "blocks": {
-            # Lists: an empty tuple would read as a leaf of the logical axes' tree.
-            "leading": [layers(DENSE, i, ()) for i in range(config.n_dense_layers)],
-            "period": [layers(MOE, config.n_dense_layers, (n_moe,))],
-            "trailing": [],
-        },
-        "final_norm": norm("final_norm"),
-        "lm_head": leaf("lm_head", (config.vocab_size, d), 0.02, ("vocab", "embed")),
-    }
+    shapes = functools.partial(_layer_shapes, config)
+    layers = layers or (lambda kind, i, stack: per_leaf(shapes(kind), leaf, stack))  # the prediction module's too
+    norm = lambda name: leaf(name, (d,), "ones", (None,))  # noqa: E731
+    tree = lm_tree(config, ((DENSE,) * config.n_dense_layers, (MOE,), n_moe, ()), shapes, leaf, layers, head="lm_head")
     if config.n_predict_layers:
         tree["mtp"] = {
             "h_norm": norm("h_norm"), "e_norm": norm("e_norm"),
@@ -243,37 +233,21 @@ def _tree(config: GLM4MoELiteConfig, layers: Callable, leaf: Callable):
 def init_params(config: GLM4MoELiteConfig, key) -> Dict[str, Any]:
     pd = config.param_dtype
     k_leaves, k_layers = jax.random.split(key)
-
-    def array(k, shape, std):
-        return jnp.ones(shape, pd) if std == 1.0 else (jax.random.normal(k, shape) * std).astype(pd)
-
-    def layers(kind, i, stack: Tuple[int, ...]):
-        leaves, tree = jax.tree.flatten(_layer_shapes(config, kind), is_leaf=_is_shape)
-        keys = jax.random.split(jax.random.fold_in(k_layers, i), len(leaves))
-        return jax.tree.unflatten(
-            tree, [array(k, stack + shape, std) for k, (shape, std, _) in zip(keys, leaves)])
-
     keys = (jax.random.fold_in(k_leaves, n) for n in itertools.count())  # one a leaf, in `_tree`'s order
-    return _tree(config, layers, lambda name, shape, std, axes: array(next(keys), shape, std))
-
-
-def _per_leaf(config: GLM4MoELiteConfig, one: Callable):
-    """A tree like the parameters': `one(name, axes, stacked)` for every leaf."""
-    def layers(kind, i, stack):
-        paths, tree = jax.tree.flatten_with_path(_layer_shapes(config, kind), is_leaf=_is_shape)
-        return jax.tree.unflatten(tree, [one(path[-1].key, axes, bool(stack)) for path, (_, _, axes) in paths])
-
-    return _tree(config, layers, lambda name, shape, std, axes: one(name, axes, False))
+    return _tree(
+        config,
+        lambda name, shape, init, axes: draw(next(keys), shape, init, pd),
+        lambda kind, i, stack: draw_layer(jax.random.fold_in(k_layers, i), _layer_shapes(config, kind), stack, pd))
 
 
 def param_logical_axes(config: GLM4MoELiteConfig) -> Dict[str, Any]:
-    return _per_leaf(config, lambda name, axes, stacked: (("layers",) if stacked else ()) + axes)
+    return _tree(config, lambda name, shape, init, axes: axes)
 
 
 def frozen_params(config: GLM4MoELiteConfig) -> Dict[str, Any]:
     """True at the leaves that are buffers and no parameters (`expert_bias`):
     `make_train_step` applies no update to them, weight decay included."""
-    return _per_leaf(config, lambda name, axes, stacked: name == "expert_bias")
+    return _tree(config, lambda name, shape, init, axes: name == "expert_bias")
 
 
 # --------------------------------------------------------------------------- forward
@@ -425,14 +399,10 @@ loss_fn = functools.partial(lm_loss, forward)
 def routing_stats(params: Dict[str, Any], tokens, config: GLM4MoELiteConfig) -> Dict[str, Any]:
     """What the routers did with `tokens` (B, S + 1), a batch's rows as
     `loss_fn` takes them, per expert layer (leading axis, in the published
-    order, the prediction module's layer last): `experts` (L, B * S, k), each
-    token's choices among all `n_experts`; `tokens_per_expert` (L, E);
-    `load_max_over_mean` (L,); `held_pairs` (L,), the (token, expert) pairs
-    whose expert this share holds, and `elsewhere_pairs`, the others;
-    `dropped` (L,): the held pairs less the rows their experts processed
-    (`moe_mlp`'s count, made in the form of the layer that ran: 0, counted and
-    not assumed); `compact` (L,) bool: the layer ran over the prefix of the
-    sort that the held pairs fill (`moe.held_row_bound`)."""
+    order, the prediction module's layer last): `moe.routing_report`'s
+    `experts` (L, B * S, k), `tokens_per_expert` (L, E), `load_max_over_mean`,
+    `held_pairs`, `elsewhere_pairs`, `dropped` (counted, not assumed: 0) and
+    `compact` (L,)."""
     inputs, targets = tokens[:, :-1], tokens[:, 1:]
     x = params["embed"].astype(config.dtype)[inputs]
     streams = _streams(inputs.shape[1], config)
@@ -443,16 +413,7 @@ def routing_stats(params: Dict[str, Any], tokens, config: GLM4MoELiteConfig) -> 
     def through(x, kind, layer):
         x, aux = block(x, layer, config, *walked.kinds[kind], streams=streams)
         if aux is not None:
-            counts = aux["tokens_per_expert"]
-            per_layer.append({
-                "experts": aux["experts"],
-                "tokens_per_expert": counts,
-                "load_max_over_mean": counts.max() / counts.mean(),
-                "held_pairs": aux["held_pairs"],
-                "elsewhere_pairs": pairs - aux["held_pairs"],
-                "dropped": aux["held_pairs"] - aux["rows_processed"],
-                "compact": aux["compact"],
-            })
+            per_layer.append(routing_report(aux, pairs))
         return x
 
     for kind, layer in walked.layers(params["blocks"]):

@@ -21,7 +21,8 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.models.llama import apply_rope, rms_norm
-from ray_tpu.models.moe import moe_mlp
+from ray_tpu.models.moe import moe_mlp, routing_report
+from ray_tpu.models.stack import draw, per_leaf
 
 
 # --------------------------------------------------------------------------- sizes
@@ -45,9 +46,6 @@ def layer_shapes(config) -> Dict[str, Any]:
     }
 
 
-_is_shape = lambda x: isinstance(x, tuple) and len(x) == 3 and isinstance(x[0], tuple)  # noqa: E731
-
-
 def matmul_params(config) -> int:
     """One layer's parameters that every position meets as an operand of a product: W_q, W_k, W_v, W_o, the router."""
     d, hd = config.d_model, config.head_dim
@@ -61,9 +59,9 @@ def layer_params(config) -> int:
 
 
 # --------------------------------------------------------------------------- init
-def tree(config, shapes: Dict[str, Any], layer_leaf: Callable, leaf: Callable):
-    """A tree like the parameters': `layer_leaf(shape, init, axes)` for a layer's
-    leaves (`shapes`, stacked over the layers), `leaf(shape, init, axes)` for the others.
+def tree(config, shapes: Dict[str, Any], leaf: Callable):
+    """A tree like the parameters': `leaf(name, shape, init, axes)` for every leaf, a layer's
+    (`shapes`) stacked over the layers (`stack.per_leaf`).
 
     The embedding's rows are N(0, 1), `torch.nn.Embedding`'s own: at 0.02 a
     token's row (norm 0.9) is outweighed after one layer by the running mean of
@@ -74,28 +72,22 @@ def tree(config, shapes: Dict[str, Any], layer_leaf: Callable, leaf: Callable):
     even share by the draw (PERF.md section 6, PR 42)."""
     d = config.d_model
     return {
-        "embed": leaf((config.vocab_size, d), 1.0, ("vocab", "embed")),
-        "blocks": jax.tree.map(lambda spec: layer_leaf(*spec), shapes, is_leaf=_is_shape),
-        "final_norm": leaf((d,), "ones", (None,)),
-        "lm_head": leaf((config.vocab_size, d), 0.02, ("vocab", "embed")),
+        "embed": leaf("embed", (config.vocab_size, d), 1.0, ("vocab", "embed")),
+        "blocks": per_leaf(shapes, leaf, (config.n_layer,)),
+        "final_norm": leaf("final_norm", (d,), "ones", (None,)),
+        "lm_head": leaf("lm_head", (config.vocab_size, d), 0.02, ("vocab", "embed")),
     }
 
 
 def init_params(config, key, shapes: Dict[str, Any]) -> Dict[str, Any]:
-    pd, counter = config.param_dtype, iter(range(1 << 30))
-
-    def array(stack):
-        def make(shape, init, axes):
-            if isinstance(init, str):
-                return jnp.full(stack + shape, {"ones": 1.0, "zeros": 0.0}[init], pd)
-            return (jax.random.normal(jax.random.fold_in(key, next(counter)), stack + shape) * init).astype(pd)
-        return make
-
-    return tree(config, shapes, array((config.n_layer,)), array(()))
+    """One counter over the leaves a normal draws, in the tree's order: `key` folded with it."""
+    counter = iter(range(1 << 30))
+    return tree(config, shapes, lambda name, shape, init, axes: draw(
+        None if isinstance(init, str) else jax.random.fold_in(key, next(counter)), shape, init, config.param_dtype))
 
 
 def param_logical_axes(config, shapes: Dict[str, Any]) -> Dict[str, Any]:
-    return tree(config, shapes, lambda shape, init, axes: ("layers",) + axes, lambda shape, init, axes: axes)
+    return tree(config, shapes, lambda name, shape, init, axes: axes)
 
 
 # --------------------------------------------------------------------------- forward
@@ -138,16 +130,6 @@ def out_and_experts(x, o, layer, config):
 
 def routing_stats(aux: Dict[str, Any], pairs: int) -> Dict[str, Any]:
     """What the routers did, per layer (leading axis), from `moe_mlp`'s reports
-    of the layers and the (token, expert) pairs a layer routes: as
-    `glm4_moe_lite.routing_stats` reports it, the load-balancing term beside."""
-    counts = aux["tokens_per_expert"]
-    return {
-        "experts": aux["experts"],
-        "tokens_per_expert": counts,
-        "load_max_over_mean": counts.max(axis=-1) / counts.mean(axis=-1),
-        "load_balance": aux["load_balance"],
-        "held_pairs": aux["held_pairs"],
-        "elsewhere_pairs": pairs - aux["held_pairs"],
-        "dropped": aux["held_pairs"] - aux["rows_processed"],
-        "compact": aux["compact"],
-    }
+    of the layers and the (token, expert) pairs a layer routes:
+    `moe.routing_report`, the load-balancing term beside."""
+    return {**routing_report(aux, pairs), "load_balance": aux["load_balance"]}

@@ -39,8 +39,8 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.models.llama import apply_rope, rms_norm, rope_tables
-from ray_tpu.models.moe import moe_mlp, swiglu
-from ray_tpu.models.stack import Pattern, apply_stack, block, lm_head, lm_loss
+from ray_tpu.models.moe import moe_mlp, routing_report, swiglu
+from ray_tpu.models.stack import Pattern, apply_stack, block, draw, draw_layer, lm_head, lm_loss, lm_tree
 from ray_tpu.ops.short_conv import gated_short_conv
 
 CONV, ATTENTION = "conv", "full_attention"
@@ -171,11 +171,11 @@ def train_flops_per_token(config: LFM2Config, seq_len: int) -> float:
 
 # --------------------------------------------------------------------------- init
 def _layer_shapes(config: LFM2Config, kind: str):
-    """{name: (shape, init std or the constant 1.0, logical axes)} of one layer of `kind`."""
+    """{name: (shape, init: a normal's std or "ones", logical axes)} of one layer of `kind`."""
     d, nh, nkv, hd = config.d_model, config.n_head, config.n_kv_head, config.head_dim
     std, out_std = 0.02, 0.02 / math.sqrt(2 * config.n_layer)
     op, ffn = kind.rsplit("_", 1)
-    shapes: Dict[str, Any] = {"op_norm": ((d,), 1.0, (None,)), "ffn_norm": ((d,), 1.0, (None,))}
+    shapes: Dict[str, Any] = {"op_norm": ((d,), "ones", (None,)), "ffn_norm": ((d,), "ones", (None,))}
     if op == CONV:
         shapes.update({
             "conv_in": ((d, 3 * d), std, ("embed", "mlp")),
@@ -188,8 +188,8 @@ def _layer_shapes(config: LFM2Config, kind: str):
             "wq": ((d, nh, hd), std, ("embed", "heads", None)),
             "wk": ((d, nkv, hd), std, ("embed", "kv_heads", None)),
             "wv": ((d, nkv, hd), std, ("embed", "kv_heads", None)),
-            "q_norm": ((hd,), 1.0, (None,)),
-            "k_norm": ((hd,), 1.0, (None,)),
+            "q_norm": ((hd,), "ones", (None,)),
+            "k_norm": ((hd,), "ones", (None,)),
             "wo": ((nh, hd, d), out_std, ("heads", None, "embed")),
         })
     if ffn == "dense":
@@ -210,61 +210,28 @@ def _layer_shapes(config: LFM2Config, kind: str):
     return shapes
 
 
-_is_shape = lambda x: isinstance(x, tuple) and len(x) == 3 and isinstance(x[0], tuple)  # noqa: E731
-
-
-def _by_layout(config: LFM2Config, layers: Callable):
-    """The `blocks` tree of `stack.Pattern`: `layers(kind, i, ())` for layer i
-    where it leads or trails, `layers(kind, i, (n_periods,))` for the stack of
-    the layers (one a period) at the place of the period that layer i opens."""
-    leading, period, n_periods, trailing = layout(config)
-    first_trailing = len(leading) + n_periods * len(period)
-    return {
-        # Lists: an empty tuple would read as a leaf of the logical axes' tree.
-        "leading": [layers(kind, i, ()) for i, kind in enumerate(leading)],
-        "period": [layers(kind, len(leading) + j, (n_periods,)) for j, kind in enumerate(period)],
-        "trailing": [layers(kind, first_trailing + i, ()) for i, kind in enumerate(trailing)],
-    }
+def _tree(config: LFM2Config, leaf: Callable, layers: Optional[Callable] = None):
+    """`stack.lm_tree` of this model: a tree like the parameters', the head tied to the embedding."""
+    return lm_tree(config, layout(config), functools.partial(_layer_shapes, config), leaf, layers)
 
 
 def init_params(config: LFM2Config, key) -> Dict[str, Any]:
     pd = config.param_dtype
     k_embed, k_layers = jax.random.split(key)
-
-    def make(kind, i, stack: Tuple[int, ...]):
-        leaves, tree = jax.tree.flatten(_layer_shapes(config, kind), is_leaf=_is_shape)
-        keys = jax.random.split(jax.random.fold_in(k_layers, i), len(leaves))
-        return jax.tree.unflatten(tree, [
-            jnp.ones(stack + shape, pd) if std == 1.0
-            else (jax.random.normal(k, stack + shape) * std).astype(pd)
-            for k, (shape, std, _) in zip(keys, leaves)])
-
-    return {
-        "embed": (jax.random.normal(k_embed, (config.vocab_size, config.d_model)) * 0.02).astype(pd),
-        "blocks": _by_layout(config, make),
-        "final_norm": jnp.ones((config.d_model,), pd),
-    }
-
-
-def _per_leaf(config: LFM2Config, one: Callable):
-    """A tree like the parameters': `one(name, axes, stacked)` for every leaf."""
-    def of(kind, i, stack):
-        paths, tree = jax.tree.flatten_with_path(_layer_shapes(config, kind), is_leaf=_is_shape)
-        return jax.tree.unflatten(tree, [one(path[-1].key, axes, bool(stack)) for path, (_, _, axes) in paths])
-
-    return {"embed": one("embed", ("vocab", "embed"), False),
-            "blocks": _by_layout(config, of),
-            "final_norm": one("final_norm", (None,), False)}
+    return _tree(
+        config,
+        lambda name, shape, init, axes: draw(k_embed, shape, init, pd),  # the embedding: the one leaf that reads it
+        lambda kind, i, stack: draw_layer(jax.random.fold_in(k_layers, i), _layer_shapes(config, kind), stack, pd))
 
 
 def param_logical_axes(config: LFM2Config) -> Dict[str, Any]:
-    return _per_leaf(config, lambda name, axes, stacked: (("layers",) if stacked else ()) + axes)
+    return _tree(config, lambda name, shape, init, axes: axes)
 
 
 def frozen_params(config: LFM2Config) -> Dict[str, Any]:
     """True at the leaves that are buffers and no parameters (`expert_bias`):
     `make_train_step` applies no update to them, weight decay included."""
-    return _per_leaf(config, lambda name, axes, stacked: name == "expert_bias")
+    return _tree(config, lambda name, shape, init, axes: name == "expert_bias")
 
 
 # --------------------------------------------------------------------------- forward
@@ -382,15 +349,9 @@ loss_fn = functools.partial(lm_loss, forward)
 
 def routing_stats(params: Dict[str, Any], tokens, config: LFM2Config) -> Dict[str, Any]:
     """What the routers did with `tokens` (B, S), per expert layer (leading
-    axis, in the published order): `experts` (L, B * S, k), each token's
-    choices among all `n_experts`; `tokens_per_expert` (L, E);
-    `load_max_over_mean` (L,); `held_pairs` (L,), the (token, expert) pairs
-    whose expert this share holds, and `elsewhere_pairs`, the others;
-    `dropped` (L,): the held pairs less the rows their experts processed
-    (`moe_mlp`'s count, made in the form of the layer that ran). The layer is
-    dropless, so `dropped` is 0; it is counted, not assumed. `compact` (L,)
-    bool: the layer ran over the prefix of the sort that the held pairs fill,
-    not over every pair (`moe.held_row_bound`)."""
+    axis, in the published order): `moe.routing_report`'s `experts` (L, B * S,
+    k), `tokens_per_expert` (L, E), `load_max_over_mean`, `held_pairs`,
+    `elsewhere_pairs`, `dropped` (counted, not assumed: 0) and `compact` (L,)."""
     x = params["embed"].astype(config.dtype)[tokens]
     streams = rope_tables(tokens.shape[1], config.head_dim, config.rope_theta)
     pairs = tokens.size * config.experts_per_token
@@ -399,14 +360,5 @@ def routing_stats(params: Dict[str, Any], tokens, config: LFM2Config) -> Dict[st
     for kind, layer in walked.layers(params["blocks"]):
         x, aux = block(x, layer, config, *walked.kinds[kind], streams=streams)
         if aux is not None:
-            counts = aux["tokens_per_expert"]
-            per_layer.append({
-                "experts": aux["experts"],
-                "tokens_per_expert": counts,
-                "load_max_over_mean": counts.max() / counts.mean(),
-                "held_pairs": aux["held_pairs"],
-                "elsewhere_pairs": pairs - aux["held_pairs"],
-                "dropped": aux["held_pairs"] - aux["rows_processed"],
-                "compact": aux["compact"],
-            })
+            per_layer.append(routing_report(aux, pairs))
     return jax.tree.map(lambda *leaves: jnp.stack(leaves), *per_layer)

@@ -319,6 +319,26 @@ def moe_mlp(
     return out.reshape(B, S, D), aux
 
 
+def routing_report(aux: Dict[str, Any], pairs: int) -> Dict[str, Any]:
+    """What a router did, for whoever reads it, from `moe_mlp`'s `aux` of a layer (or of a stack of them, on a
+    leading axis) and the (token, expert) `pairs` a layer routes: `experts` (tokens, k), each token's choices among
+    all the router's experts; `tokens_per_expert` (E,); `load_max_over_mean`; `held_pairs`, the pairs whose expert
+    this share holds, and `elsewhere_pairs`, the others; `dropped`, the held pairs less the rows their experts
+    processed (`moe_mlp`'s count, made in the form of the layer that ran: the layer is dropless, so 0, counted and
+    not assumed); `compact`, whether the layer ran over the prefix of the sort that the held pairs fill and not over
+    every pair (`held_row_bound`). A model's `routing_stats` walks its own layers and stacks these."""
+    counts = aux["tokens_per_expert"]
+    return {
+        "experts": aux["experts"],
+        "tokens_per_expert": counts,
+        "load_max_over_mean": counts.max(axis=-1) / counts.mean(axis=-1),
+        "held_pairs": aux["held_pairs"],
+        "elsewhere_pairs": pairs - aux["held_pairs"],
+        "dropped": aux["held_pairs"] - aux["rows_processed"],
+        "compact": aux["compact"],
+    }
+
+
 def swiglu(x, w_gate, w_up, w_down):
     """`W_down (silu(W_gate x) * W_up x)` of x (B, S, D) with (D, F), (D, F),
     (F, D): operands in x's dtype, the gate in float32, rounded once."""

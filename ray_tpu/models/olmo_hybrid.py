@@ -53,7 +53,7 @@ import jax.numpy as jnp
 
 from ray_tpu.models.llama import rms_norm
 from ray_tpu.models.moe import swiglu
-from ray_tpu.models.stack import Pattern, apply_stack, lm_head, lm_loss
+from ray_tpu.models.stack import Pattern, apply_stack, draw, draw_layer, lm_head, lm_loss, lm_tree
 from ray_tpu.ops import gated_delta_rule as gdn
 from ray_tpu.ops.short_conv import short_conv
 
@@ -140,12 +140,12 @@ def train_flops_per_token(config: OlmoHybridConfig, seq_len: int) -> float:
 # --------------------------------------------------------------------------- init
 def _layer_shapes(config: OlmoHybridConfig, kind: str):
     """{name: (shape, how it starts, logical axes)} of one layer of `kind`. A start is a
-    normal's std, 1.0 for a norm's scale, or the name of a gate's own draw."""
+    normal's std, "ones" for a norm's scale, or the name of a gate's own draw (`stack.draw`)."""
     d, h, taps = config.d_model, config.linear_heads, config.conv_kernel
     keys, values = h * config.linear_key_dim, h * config.linear_value_dim
     std, out_std = 0.02, 0.02 / math.sqrt(2 * config.n_layer)
     shapes: Dict[str, Any] = {
-        "mixer_norm": ((d,), 1.0, (None,)), "mlp_norm": ((d,), 1.0, (None,)),
+        "mixer_norm": ((d,), "ones", (None,)), "mlp_norm": ((d,), "ones", (None,)),
         "w_gate": ((d, config.d_ff), std, ("embed", "mlp")),
         "w_up": ((d, config.d_ff), std, ("embed", "mlp")),
         "w_down": ((config.d_ff, d), out_std, ("mlp", "embed")),
@@ -161,60 +161,35 @@ def _layer_shapes(config: OlmoHybridConfig, kind: str):
             "conv_v": ((taps, values), taps ** -0.5, (None, None)),
             "w_a": ((d, h), std, ("embed", None)), "w_b": ((d, h), std, ("embed", None)),
             "A_log": ((h,), "A_log", (None,)), "dt_bias": ((h,), "dt_bias", (None,)),
-            "o_norm": ((config.linear_value_dim,), 1.0, (None,)),
+            "o_norm": ((config.linear_value_dim,), "ones", (None,)),
         })
     else:
         shapes.update({
             "wq": ((d, d), std, ("embed", "heads")), "wk": ((d, d), std, ("embed", "heads")),
             "wv": ((d, d), std, ("embed", "heads")), "wo": ((d, d), out_std, ("heads", "embed")),
-            "q_norm": ((d,), 1.0, (None,)), "k_norm": ((d,), 1.0, (None,)),
+            "q_norm": ((d,), "ones", (None,)), "k_norm": ((d,), "ones", (None,)),
         })
     return shapes
 
 
-def _draw(key, shape, start):
-    if start == 1.0:
-        return jnp.ones(shape)
-    if start == "A_log":  # the layer's released initialisation: A ~ U(0, 16), kept off zero
-        return jnp.log(jax.random.uniform(key, shape, minval=1e-3, maxval=16.0))
-    if start == "dt_bias":  # the inverse softplus of dt ~ exp U(log 1e-3, log 1e-1)
-        dt = jnp.exp(jax.random.uniform(key, shape, minval=math.log(1e-3), maxval=math.log(1e-1)))
-        return dt + jnp.log(-jnp.expm1(-dt))
-    return jax.random.normal(key, shape) * start
-
-
-_is_shape = lambda x: isinstance(x, tuple) and len(x) == 3 and isinstance(x[0], tuple)  # noqa: E731
-
-
-def _per_place(config: OlmoHybridConfig, one: Callable):
-    """The `blocks` tree of `stack.Pattern`: for each place in the period `one(kind, place, shapes)`."""
-    period = config.period
-    return {"leading": [], "trailing": [],
-            "period": [one(kind, j, _layer_shapes(config, kind)) for j, kind in enumerate(period)]}
+def _tree(config: OlmoHybridConfig, leaf: Callable, layers: Optional[Callable] = None):
+    """`stack.lm_tree` of this model: a tree like the parameters', a place of the period a stack over the periods."""
+    layout = ((), config.period, config.n_layer // len(config.period), ())
+    return lm_tree(config, layout, functools.partial(_layer_shapes, config), leaf, layers, head="head")
 
 
 def init_params(config: OlmoHybridConfig, key) -> Dict[str, Any]:
     pd = config.param_dtype
-    k_embed, k_head, k_layers = jax.random.split(key, 3)
-    n_periods = config.n_layer // len(config.period)
-
-    def make(kind, place, shapes):
-        leaves, tree = jax.tree.flatten(shapes, is_leaf=_is_shape)
-        keys = jax.random.split(jax.random.fold_in(k_layers, place), len(leaves))
-        return jax.tree.unflatten(tree, [_draw(k, (n_periods,) + shape, start).astype(pd)
-                                         for k, (shape, start, _) in zip(keys, leaves)])
-
-    table = lambda k: (jax.random.normal(k, (config.vocab_size, config.d_model)) * 0.02).astype(pd)  # noqa: E731
-    return {"embed": table(k_embed), "blocks": _per_place(config, make),
-            "final_norm": jnp.ones((config.d_model,), pd), "head": table(k_head)}
+    keys = dict(zip(("embed", "head", "layers"), jax.random.split(key, 3)))
+    return _tree(
+        config,
+        lambda name, shape, init, axes: draw(keys.get(name), shape, init, pd),
+        lambda kind, place, stack: draw_layer(
+            jax.random.fold_in(keys["layers"], place), _layer_shapes(config, kind), stack, pd))
 
 
 def param_logical_axes(config: OlmoHybridConfig) -> Dict[str, Any]:
-    def of(kind, place, shapes):
-        return jax.tree.map(lambda leaf: ("layers",) + leaf[2], shapes, is_leaf=_is_shape)
-
-    return {"embed": ("vocab", "embed"), "blocks": _per_place(config, of),
-            "final_norm": (None,), "head": ("vocab", "embed")}
+    return _tree(config, lambda name, shape, init, axes: axes)
 
 
 # --------------------------------------------------------------------------- forward

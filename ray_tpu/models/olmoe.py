@@ -20,7 +20,7 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.models.llama import apply_rope, rms_norm, rope_tables
-from ray_tpu.models.moe import init_moe_params, moe_mlp, moe_param_logical_axes
+from ray_tpu.models.moe import init_moe_params, moe_mlp, moe_param_logical_axes, routing_report
 from ray_tpu.models.stack import apply_stack, block, lm_head, lm_loss
 
 
@@ -244,14 +244,8 @@ def routing_stats(params: Dict[str, Any], tokens, config: OLMoEConfig) -> Dict[s
 
     def layer_stats(x, layer):
         x, aux = block(x, layer, config, *parts, streams=streams)
-        counts = aux["tokens_per_expert"]
-        return x, {
-            "experts": aux["experts"],
-            "tokens_per_expert": counts,
-            "load_max_over_mean": counts.max() / counts.mean(),
-            "load_balance": aux["load_balance"],
-            "z": aux["z"],
-            "dropped": pairs - aux["rows_processed"],
-        }
+        report = routing_report(aux, pairs)  # the layer holds every expert: its `held_pairs` are all the pairs
+        return x, {**{name: report[name] for name in ("experts", "tokens_per_expert", "load_max_over_mean", "dropped")},
+                   "load_balance": aux["load_balance"], "z": aux["z"]}
 
     return jax.lax.scan(layer_stats, x, params["blocks"])[1]

@@ -49,8 +49,8 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.models.llama import rms_norm
-from ray_tpu.models.moe import moe_mlp, shared_expert
-from ray_tpu.models.stack import Pattern, apply_stack, block, lm_head, lm_loss
+from ray_tpu.models.moe import moe_mlp, routing_report, shared_expert
+from ray_tpu.models.stack import Pattern, apply_stack, block, draw, draw_layer, lm_head, lm_loss, lm_tree
 from ray_tpu.ops import kda
 from ray_tpu.ops.short_conv import short_conv
 
@@ -156,12 +156,12 @@ def train_flops_per_token(config: SolarOpen2Config, seq_len: int) -> float:
 
 # --------------------------------------------------------------------------- init
 def _layer_shapes(config: SolarOpen2Config, kind: str):
-    """{name: (shape, how it starts, logical axes)} of one layer of `kind`. A start is a normal's std, 1.0 for
-    a norm's scale, 0.0 for a bias, or the name of a gate's own draw."""
+    """{name: (shape, how it starts, logical axes)} of one layer of `kind`. A start is a normal's std, "ones" for
+    a norm's scale, "zeros" for a bias, or the name of a gate's own draw (`stack.draw`)."""
     d, taps, rank, f = config.d_model, config.conv_kernel, config.gate_rank, config.d_expert
     std, out_std = 0.02, 0.02 / math.sqrt(2 * config.n_layer)
     shapes: Dict[str, Any] = {
-        "mixer_norm": ((d,), 1.0, (None,)), "moe_norm": ((d,), 1.0, (None,)),
+        "mixer_norm": ((d,), "ones", (None,)), "moe_norm": ((d,), "ones", (None,)),
         "moe": {
             "router_w": ((d, config.n_experts), std, ("embed", None)),
             "w_gate": ((config.held, d, f), std, ("expert", "embed", "mlp")),
@@ -186,8 +186,8 @@ def _layer_shapes(config: SolarOpen2Config, kind: str):
             "A_log": ((h,), "A_log", (None,)), "dt_bias": ((keys,), "dt_bias", (None,)),
             "w_b": ((d, h), std, ("embed", None)),
             "w_g_down": ((d, rank), std, ("embed", None)), "w_g_up": ((rank, values), std, (None, "heads")),
-            "b_g": ((values,), 0.0, (None,)),
-            "o_norm": ((config.linear_value_dim,), 1.0, (None,)),
+            "b_g": ((values,), "zeros", (None,)),
+            "o_norm": ((config.linear_value_dim,), "ones", (None,)),
         })
     else:
         nh, nkv, hd = config.n_head, config.n_kv_head, config.head_dim
@@ -201,51 +201,27 @@ def _layer_shapes(config: SolarOpen2Config, kind: str):
     return shapes
 
 
-def _draw(key, shape, start):
-    if start in (0.0, 1.0):
-        return jnp.full(shape, start)
-    if start == "A_log":  # the layer's released initialisation: A ~ U(0, 16), kept off zero
-        return jnp.log(jax.random.uniform(key, shape, minval=1e-3, maxval=16.0))
-    if start == "dt_bias":  # the inverse softplus of dt ~ exp U(log 1e-3, log 1e-1)
-        dt = jnp.exp(jax.random.uniform(key, shape, minval=math.log(1e-3), maxval=math.log(1e-1)))
-        return dt + jnp.log(-jnp.expm1(-dt))
-    return jax.random.normal(key, shape) * start
-
-
-_is_shape = lambda x: isinstance(x, tuple) and len(x) == 3 and isinstance(x[0], tuple)  # noqa: E731
-
-
-def _per_place(config: SolarOpen2Config, one: Callable):
-    """The `blocks` tree of `stack.Pattern`: for each place in the period `one(kind, place, shapes)`."""
-    return {"leading": [], "trailing": [],
-            "period": [one(kind, j, _layer_shapes(config, kind)) for j, kind in enumerate(config.period)]}
+def _tree(config: SolarOpen2Config, leaf: Callable, layers: Optional[Callable] = None):
+    """`stack.lm_tree` of this model: a tree like the parameters', a place of the period a stack over the periods.
+    The embedding's rows are N(0, 1), `torch.nn.Embedding`'s own, as `gqa_experts.tree` draws them and for
+    its reason: the first layer's group of query heads on one key/value head adds the running mean of the
+    values up coherently, and at 0.02 the routers behind it would see one input for every token."""
+    layout = ((), config.period, config.n_layer // len(config.period), ())
+    return lm_tree(config, layout, functools.partial(_layer_shapes, config), leaf, layers, head="head", embed=1.0)
 
 
 def init_params(config: SolarOpen2Config, key) -> Dict[str, Any]:
-    """The embedding's rows are N(0, 1), `torch.nn.Embedding`'s own, as `gqa_experts.tree` draws them and for
-    its reason: the first layer's group of query heads on one key/value head adds the running mean of the
-    values up coherently, and at 0.02 the routers behind it would see one input for every token."""
     pd = config.param_dtype
-    k_embed, k_head, k_layers = jax.random.split(key, 3)
-    n_periods = config.n_layer // len(config.period)
-
-    def make(kind, place, shapes):
-        leaves, tree = jax.tree.flatten(shapes, is_leaf=_is_shape)
-        keys = jax.random.split(jax.random.fold_in(k_layers, place), len(leaves))
-        return jax.tree.unflatten(tree, [_draw(k, (n_periods,) + shape, start).astype(pd)
-                                         for k, (shape, start, _) in zip(keys, leaves)])
-
-    table = lambda k, std: (jax.random.normal(k, (config.vocab_size, config.d_model)) * std).astype(pd)  # noqa: E731
-    return {"embed": table(k_embed, 1.0), "blocks": _per_place(config, make),
-            "final_norm": jnp.ones((config.d_model,), pd), "head": table(k_head, 0.02)}
+    keys = dict(zip(("embed", "head", "layers"), jax.random.split(key, 3)))
+    return _tree(
+        config,
+        lambda name, shape, init, axes: draw(keys.get(name), shape, init, pd),
+        lambda kind, place, stack: draw_layer(
+            jax.random.fold_in(keys["layers"], place), _layer_shapes(config, kind), stack, pd))
 
 
 def param_logical_axes(config: SolarOpen2Config) -> Dict[str, Any]:
-    def of(kind, place, shapes):
-        return jax.tree.map(lambda leaf: ("layers",) + leaf[2], shapes, is_leaf=_is_shape)
-
-    return {"embed": ("vocab", "embed"), "blocks": _per_place(config, of),
-            "final_norm": (None,), "head": ("vocab", "embed")}
+    return _tree(config, lambda name, shape, init, axes: axes)
 
 
 # --------------------------------------------------------------------------- forward
@@ -386,8 +362,8 @@ loss_fn = functools.partial(lm_loss, forward)
 
 
 def routing_stats(params: Dict[str, Any], tokens, config: SolarOpen2Config) -> Dict[str, Any]:
-    """What the routers did with `tokens` (B, S), per layer (leading axis, in the published order), as
-    `lfm2.routing_stats` reports it: `experts` (L, B * S, k), `tokens_per_expert` (L, E), `load_max_over_mean`,
+    """What the routers did with `tokens` (B, S), per layer (leading axis, in the published order):
+    `moe.routing_report`'s `experts` (L, B * S, k), `tokens_per_expert` (L, E), `load_max_over_mean`,
     `held_pairs`, `elsewhere_pairs`, `dropped` (counted, not assumed: 0) and `compact` (L,)."""
     x = params["embed"].astype(config.dtype)[tokens]
     pairs = tokens.size * config.experts_per_token
@@ -396,14 +372,5 @@ def routing_stats(params: Dict[str, Any], tokens, config: SolarOpen2Config) -> D
     for kind, layer in walked.layers(params["blocks"]):
         qkv, out, *own = walked.kinds[kind]
         x, aux = block(x, layer, config, qkv, out, attend=own[0] if own else None)
-        counts = aux["tokens_per_expert"]
-        per_layer.append({
-            "experts": aux["experts"],
-            "tokens_per_expert": counts,
-            "load_max_over_mean": counts.max() / counts.mean(),
-            "held_pairs": aux["held_pairs"],
-            "elsewhere_pairs": pairs - aux["held_pairs"],
-            "dropped": aux["held_pairs"] - aux["rows_processed"],
-            "compact": aux["compact"],
-        })
+        per_layer.append(routing_report(aux, pairs))
     return jax.tree.map(lambda *leaves: jnp.stack(leaves), *per_layer)

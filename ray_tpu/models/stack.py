@@ -27,6 +27,7 @@ periods. A stack of identical blocks is the pattern of one kind.
 from __future__ import annotations
 
 import functools
+import math
 from typing import Any, Callable, Dict, List, NamedTuple, Optional, Tuple
 
 import jax
@@ -38,7 +39,9 @@ class Pattern(NamedTuple):
     [one tree a layer, ...], "period": [for each place in the period, the tree
     of the layers at that place, stacked over the periods on a leading axis],
     "trailing": [one tree a layer, ...]}`: the scan slices every place's
-    stack by the period and nothing is indexed inside its body."""
+    stack by the period and nothing is indexed inside its body.
+    `pattern_blocks` below writes that layout, for the parameters and for
+    every tree laid out like them."""
     kinds: Dict[str, tuple]  # name -> (qkv_part | None, out_part[, the kind's own `block(attend=)`])
     period: Tuple[str, ...]  # the kinds of one period's layers
     n_periods: int
@@ -53,6 +56,73 @@ class Pattern(NamedTuple):
             out += [(kind, jax.tree.map(lambda a: a[p], place))
                     for kind, place in zip(self.period, blocks["period"])]
         return out + list(zip(self.trailing, blocks["trailing"]))
+
+
+# --------------------------------------------------------------------------- a stack's parameters
+# A leaf of a layer's shape table `{name: (shape, init, logical axes)}`, nested as the layer's parameters are. `init`:
+# what `draw` reads.
+is_shape = lambda x: isinstance(x, tuple) and len(x) == 3 and isinstance(x[0], tuple)  # noqa: E731
+
+
+def pattern_blocks(leading, period, n_periods: int, trailing, layers: Callable):
+    """A tree laid out as a `Pattern`'s parameters are, from the kinds as `Pattern` takes them:
+    `layers(kind, i, ())` for layer i where it leads or trails, `layers(kind, i, (n_periods,))` for the stack of
+    the layers (one a period) at the place of the period that layer i opens."""
+    first_trailing = len(leading) + n_periods * len(period)
+    return {
+        # Lists: an empty tuple would read as a leaf of the logical axes' tree.
+        "leading": [layers(kind, i, ()) for i, kind in enumerate(leading)],
+        "period": [layers(kind, len(leading) + j, (n_periods,)) for j, kind in enumerate(period)],
+        "trailing": [layers(kind, first_trailing + i, ()) for i, kind in enumerate(trailing)],
+    }
+
+
+def per_leaf(shapes, one: Callable, stack: Tuple[int, ...] = ()):
+    """A tree like a layer's parameters: `one(name, shape, init, axes)` for every leaf of its shape table, in the
+    order jax flattens them. With `stack` = (n,) the leaves are those of n such layers stacked: `shape` with n and
+    `axes` with "layers" in front."""
+    paths, tree = jax.tree.flatten_with_path(shapes, is_leaf=is_shape)
+    lead = ("layers",) if stack else ()
+    return jax.tree.unflatten(tree, [one(path[-1].key, stack + shape, init, lead + axes)
+                                     for path, (shape, init, axes) in paths])
+
+
+def lm_tree(config, layout, layer_shapes: Callable, leaf: Callable, layers: Optional[Callable] = None, *,
+            embed=0.02, head: Optional[str] = None):
+    """A tree like the parameters of a language model over a patterned stack, `leaf(name, shape, init, axes)` for
+    every leaf: the embedding (`embed`, its init), the layers' (`layer_shapes(kind)`'s table through `per_leaf`, laid
+    out by `pattern_blocks(*layout, ...)`), the final norm's scale and, where `head` names one, an untied head.
+    `layers(kind, i, stack)`: the caller's own way to a layer in `per_leaf`'s place (an `init_params`: a key a layer)."""
+    d = config.d_model
+    layers = layers or (lambda kind, i, stack: per_leaf(layer_shapes(kind), leaf, stack))
+    tree = {"embed": leaf("embed", (config.vocab_size, d), embed, ("vocab", "embed")),
+            "blocks": pattern_blocks(*layout, layers),
+            "final_norm": leaf("final_norm", (d,), "ones", (None,))}
+    if head:
+        tree[head] = leaf(head, (config.vocab_size, d), 0.02, ("vocab", "embed"))
+    return tree
+
+
+def draw(key, shape, init, dtype):
+    """A leaf's first value. `init`: a normal's std; "ones" or "zeros" (a norm's scale, a bias: `key` is not read);
+    or the name of a linear-attention gate's released initialisation, "A_log" and "dt_bias"."""
+    if init in ("ones", "zeros"):
+        return jnp.full(shape, float(init == "ones"), dtype)
+    if init == "A_log":  # A ~ U(0, 16), kept off zero
+        value = jnp.log(jax.random.uniform(key, shape, minval=1e-3, maxval=16.0))
+    elif init == "dt_bias":  # the inverse softplus of dt ~ exp U(log 1e-3, log 1e-1)
+        dt = jnp.exp(jax.random.uniform(key, shape, minval=math.log(1e-3), maxval=math.log(1e-1)))
+        value = dt + jnp.log(-jnp.expm1(-dt))
+    else:
+        value = jax.random.normal(key, shape) * init
+    return value.astype(dtype)
+
+
+def draw_layer(key, shapes, stack: Tuple[int, ...], dtype):
+    """A layer's parameters (with `stack` = (n,), n layers' stacked) from its shape table: `key`, the layer's own
+    from its model's scheme, split a leaf."""
+    keys = iter(jax.random.split(key, len(jax.tree.leaves(shapes, is_leaf=is_shape))))
+    return per_leaf(shapes, lambda name, shape, init, axes: draw(next(keys), shape, init, dtype), stack)
 
 
 def block(x, layer, config, qkv_part: Optional[Callable], out_part: Callable,

@@ -37,6 +37,7 @@ term is written for scores that sum to one, which sigmoids do not.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Any, Callable, Dict, Optional, Tuple
 
@@ -45,8 +46,8 @@ import jax.numpy as jnp
 
 from ray_tpu.models import gqa_experts
 from ray_tpu.models.llama import rms_norm, rope_tables
-from ray_tpu.models.moe import moe_mlp, shared_expert, swiglu
-from ray_tpu.models.stack import Pattern, apply_stack, block, causal_lm_loss, lm_head
+from ray_tpu.models.moe import moe_mlp, routing_report, shared_expert, swiglu
+from ray_tpu.models.stack import Pattern, apply_stack, block, causal_lm_loss, draw, draw_layer, lm_head, lm_tree
 
 WINDOW, FULL = "window", "full"  # the softmax kinds; a dense layer's kind is `dense_<kind>`
 SOURCE_KINDS = {"sliding_attention": WINDOW, "full_attention": FULL}  # the source's `layer_types`
@@ -195,27 +196,13 @@ def _layer_shapes(config: TrinityConfig, kind: str) -> Dict[str, Any]:
     return shapes
 
 
-_is_shape = gqa_experts._is_shape
-
-
-def _tree(config: TrinityConfig, layers: Callable, leaf: Callable):
-    """A tree like the parameters': `layers(kind, i, stack)` for the layer (or, with `stack` = (n,), the n layers
-    of one place in the period, stacked) that begins at layer i; `leaf(name, shape, init, axes)` outside a layer.
+def _tree(config: TrinityConfig, leaf: Callable, layers: Optional[Callable] = None):
+    """`stack.lm_tree` of this model: a tree like the parameters'.
     The embedding starts at 0.02: times sqrt(d_model) its rows enter the stack at an RMS of 0.9, the unit rows
     `gqa_experts.tree` draws for the family's other models, and for their reason."""
-    d = config.d_model
     n_lead, period = split(config)
-    n_periods = (config.n_layer - n_lead) // len(period)
-    return {
-        "embed": leaf("embed", (config.vocab_size, d), 0.02, ("vocab", "embed")),
-        "blocks": {
-            "leading": [layers(kind, i, ()) for i, kind in enumerate(config.kinds[:n_lead])],
-            "period": [layers(kind, n_lead + j, (n_periods,)) for j, kind in enumerate(period)],
-            "trailing": [],
-        },
-        "final_norm": leaf("final_norm", (d,), "ones", (None,)),
-        "lm_head": leaf("lm_head", (config.vocab_size, d), 0.02, ("vocab", "embed")),
-    }
+    layout = (config.kinds[:n_lead], period, (config.n_layer - n_lead) // len(period), ())
+    return lm_tree(config, layout, functools.partial(_layer_shapes, config), leaf, layers, head="lm_head")
 
 
 def init_params(config: TrinityConfig, key) -> Dict[str, Any]:
@@ -223,39 +210,21 @@ def init_params(config: TrinityConfig, key) -> Dict[str, Any]:
     `expert_bias` 0."""
     pd = config.param_dtype
     k_leaves, k_layers = jax.random.split(key)
-
-    def array(k, shape, init):
-        if isinstance(init, str):
-            return jnp.full(shape, {"ones": 1.0, "zeros": 0.0}[init], pd)
-        return (jax.random.normal(k, shape) * init).astype(pd)
-
-    def layers(kind, i, stack: Tuple[int, ...]):
-        leaves, tree = jax.tree.flatten(_layer_shapes(config, kind), is_leaf=_is_shape)
-        keys = jax.random.split(jax.random.fold_in(k_layers, i), len(leaves))
-        return jax.tree.unflatten(tree, [array(k, stack + shape, init) for k, (shape, init, _) in zip(keys, leaves)])
-
     names = ("embed", "final_norm", "lm_head")
-    return _tree(config, layers, lambda name, shape, init, axes: array(
-        jax.random.fold_in(k_leaves, names.index(name)), shape, init))
-
-
-def _per_leaf(config: TrinityConfig, one: Callable):
-    """A tree like the parameters': `one(name, axes, stacked)` for every leaf."""
-    def layers(kind, i, stack):
-        paths, tree = jax.tree.flatten_with_path(_layer_shapes(config, kind), is_leaf=_is_shape)
-        return jax.tree.unflatten(tree, [one(path[-1].key, axes, bool(stack)) for path, (_, _, axes) in paths])
-
-    return _tree(config, layers, lambda name, shape, init, axes: one(name, axes, False))
+    return _tree(
+        config,
+        lambda name, shape, init, axes: draw(jax.random.fold_in(k_leaves, names.index(name)), shape, init, pd),
+        lambda kind, i, stack: draw_layer(jax.random.fold_in(k_layers, i), _layer_shapes(config, kind), stack, pd))
 
 
 def param_logical_axes(config: TrinityConfig) -> Dict[str, Any]:
-    return _per_leaf(config, lambda name, axes, stacked: (("layers",) if stacked else ()) + axes)
+    return _tree(config, lambda name, shape, init, axes: axes)
 
 
 def frozen_params(config: TrinityConfig) -> Dict[str, Any]:
     """True at `expert_bias`: a buffer that no optimizer step changes, weight decay included (`make_train_step`);
     `update_buffers` is what moves it."""
-    return _per_leaf(config, lambda name, axes, stacked: name == "expert_bias")
+    return _tree(config, lambda name, shape, init, axes: name == "expert_bias")
 
 
 # --------------------------------------------------------------------------- the buffer's rule
@@ -417,8 +386,8 @@ def loss_fn(params, batch, config: TrinityConfig, attention_fn=None, step_rng=No
 
 
 def routing_stats(params: Dict[str, Any], tokens, config: TrinityConfig) -> Dict[str, Any]:
-    """What the routers did with `tokens` (B, S), per expert layer (leading axis, in the published order), as
-    `lfm2.routing_stats` reports it: `experts` (L, B * S, k), `tokens_per_expert` (L, E), `load_max_over_mean`,
+    """What the routers did with `tokens` (B, S), per expert layer (leading axis, in the published order):
+    `moe.routing_report`'s `experts` (L, B * S, k), `tokens_per_expert` (L, E), `load_max_over_mean`,
     `held_pairs`, `elsewhere_pairs`, `dropped` (counted, not assumed: 0), `compact` (L,); and `bias_abs_max`
     (L,), the largest |b| of the layer's selection bias: how far the rule has moved it."""
     x = _embed(params, tokens, config)
@@ -429,17 +398,7 @@ def routing_stats(params: Dict[str, Any], tokens, config: TrinityConfig) -> Dict
     for kind, layer in walked.layers(params["blocks"]):
         qkv, out, own = walked.kinds[kind]
         x, aux = block(x, layer, config, qkv, out, streams=streams, attend=own)
-        if aux is None:
-            continue
-        counts = aux["tokens_per_expert"]
-        per_layer.append({
-            "experts": aux["experts"],
-            "tokens_per_expert": counts,
-            "load_max_over_mean": counts.max() / counts.mean(),
-            "held_pairs": aux["held_pairs"],
-            "elsewhere_pairs": pairs - aux["held_pairs"],
-            "dropped": aux["held_pairs"] - aux["rows_processed"],
-            "compact": aux["compact"],
-            "bias_abs_max": jnp.abs(layer["moe"]["expert_bias"]).max(),
-        })
+        if aux is not None:
+            per_layer.append({**routing_report(aux, pairs),
+                              "bias_abs_max": jnp.abs(layer["moe"]["expert_bias"]).max()})
     return jax.tree.map(lambda *leaves: jnp.stack(leaves), *per_layer)

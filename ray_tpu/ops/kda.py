@@ -30,26 +30,23 @@ matrix: `T` by the same doubling (`_unit_lower_inverses`), the products against 
 precision (`_mm`), the state every chunk starts from written out for the backward pass, G heads a
 program side by side (`heads_per_program`). No exponent above 0 is evaluated.
 
-`_chunk_fwd` and `_chunk_bwd` are that mathematics for one chunk of one head on plain two-dimensional
-arrays: the XLA form maps `_chunk_fwd` over batch and heads inside a scan over the chunks (what runs off
-the TPU, differentiated by jax, and what the kernels are held to); the Mosaic kernels `kda_fwd` and
-`kda_bwd` call the two functions on their blocks, the chunks along a sequential grid axis with the state
-(`dS` in the reverse walk) in VMEM scratch.
+`_chunk_gates`, `_chunk_fwd` and `_chunk_bwd` are that mathematics for one chunk of one head on plain
+two-dimensional arrays, and all of the rule that is its own: `ops/chunked_scan.py` walks them over a row, in
+the XLA form (what runs off the TPU, differentiated by jax, and what the kernels are held to) and in the
+Mosaic kernels `kda_fwd` and `kda_bwd`, and `kimi_delta_rule` is that walk applied to `RULE`. A head's blocks
+and working set are the scalar rule's with a gate as wide as the keys: `heads_per_program` is asked at f32 items.
 """
 
 from __future__ import annotations
 
-import functools
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-from ray_tpu.ops.gated_delta_rule import (BF16, CHUNK, F32, NT, TN, _chunked, _col, _compiler_params, _gates_by_chunk,
-                                          _iotas, _mm, _once, _row, _unit_lower_inverses, heads_per_program,
-                                          select_backend)
+from ray_tpu.ops.chunked_scan import (BF16, CHUNK, F32, NT, TN, Rule, _col, _iotas, _mm, _row, _unit_lower_inverses,
+                                      chunked_scan)
+from ray_tpu.ops.chunked_scan import select_backend  # noqa: F401  (the benchmark reads it from this module)
 
 
 # --------------------------------------------------------------------------- the pairwise terms
@@ -191,69 +188,6 @@ def _chunk_bwd(q, k, v, gam, beta, s, do, ds_new, first=None):
     return dq, dk, dv, dgam, _row(dbeta_c), ds
 
 
-# --------------------------------------------------------------------------- the XLA form
-def _xla_kda(q, k, v, gam, beta, chunk: int):
-    """The chunked form on whole arrays: q, k (B, H, S, d_k), v (B, H, S, d_v), `gam` (B, H, S, d_k) and
-    `beta` (B, H, S) f32, S a whole number of chunks."""
-    over_heads = jax.vmap(jax.vmap(_chunk_fwd))
-
-    def one_chunk(s, xs):
-        qc, kc, vc, gc, bc = xs
-        o, s = over_heads(qc, kc, vc, gc, bc[:, :, None], s)
-        return s, o
-
-    b, h, _, dk = k.shape
-    s0 = jnp.zeros((b, h, dk, v.shape[-1]), F32)
-    _, o = jax.lax.scan(one_chunk, s0, tuple(_chunked(x, chunk) for x in (q, k, v, gam, beta)))
-    return jnp.moveaxis(o, 0, 2).reshape(v.shape).astype(v.dtype)
-
-
-# --------------------------------------------------------------------------- the kernels
-def _heads_of_a_program(k_ref, gam_ref, beta_ref, at):
-    """[(k, gam, beta, `_chunk_gates`' parts and the inverse `t`)] of chunk `at`, one a head of the program: the
-    heads' doublings are independent chains, made together level by level (`gated_delta_rule.py`)."""
-    heads = [(k_ref[h], gam_ref[h], beta_ref[h, pl.ds(at, 1), :]) for h in range(k_ref.shape[0])]
-    firsts = [_once(_chunk_gates)(*head) for head in heads]
-    for first, t in zip(firsts, _once(_unit_lower_inverses)([first["a"] for first in firsts])):
-        first["t"] = t
-    return [(*head, first) for head, first in zip(heads, firsts)]
-
-
-def _fwd_kernel(q_ref, k_ref, v_ref, gam_ref, beta_ref, o_ref, states_ref, s_ref):
-    i = pl.program_id(1)
-
-    @pl.when(i == 0)
-    def _():
-        s_ref[...] = jnp.zeros_like(s_ref)
-
-    for h, (k, gam, beta, first) in enumerate(_heads_of_a_program(k_ref, gam_ref, beta_ref, i)):
-        s = s_ref[h]
-        states_ref[h, 0] = s
-        o, s_new = _once(_chunk_fwd)(q_ref[h], k, v_ref[h], gam, beta, s, first)
-        o_ref[h] = o.astype(o_ref.dtype)
-        s_ref[h] = s_new
-
-
-def _bwd_kernel(q_ref, k_ref, v_ref, gam_ref, beta_ref, states_ref, do_ref,
-                dq_ref, dk_ref, dv_ref, dgam_ref, dbeta_ref, ds_ref):
-    i = pl.program_id(1)
-    at = pl.num_programs(1) - 1 - i  # the chunk: the walk is from the row's end
-
-    @pl.when(i == 0)
-    def _():
-        ds_ref[...] = jnp.zeros_like(ds_ref)
-
-    for h, (k, gam, beta, first) in enumerate(_heads_of_a_program(k_ref, gam_ref, beta_ref, at)):
-        dq, dk, dv, dgam, dbeta, ds = _once(_chunk_bwd)(
-            q_ref[h], k, v_ref[h], gam, beta, states_ref[h, 0], do_ref[h], ds_ref[h], first)
-        dq_ref[h] = dq.astype(dq_ref.dtype)
-        dk_ref[h] = dk.astype(dk_ref.dtype)
-        dv_ref[h] = dv.astype(dv_ref.dtype)
-        dgam_ref[h] = dgam
-        dbeta_ref[h, pl.ds(at, 1), :] = dbeta
-        ds_ref[h] = ds
-
-
 def mxu_passes(chunk: int, dk: int, dv: int, backward: bool = False) -> float:
     """MXU passes of 128^3 multiply-adds the kernels issue for one chunk of one head: six for a product of two
     f32 arrays, three where one operand is a bf16 0/1 matrix."""
@@ -274,93 +208,10 @@ def chunk_flops(chunk: int, dk: int, dv: int, backward: bool = False) -> int:
     return int(2 * 128 ** 3 * mxu_passes(chunk, dk, dv, backward))
 
 
-def _plan(k, v, chunk):
-    """(G, the two scopes that name the plan: `chunk_128`, `heads_2of8`). A head's blocks and working set are
-    the scalar rule's with a gate as wide as the keys: `heads_per_program` is asked at f32 items."""
-    bh, seq, dk = k.shape
-    g = heads_per_program(bh, seq, chunk, dk, v.shape[-1], 4)
-    return g, f"chunk_{chunk}", f"heads_{g}of{bh}"
-
-
-def _fwd(q, k, v, gam, beta, chunk, interpret):
-    """Flat heads: q, k (BH, S, d_k), v (BH, S, d_v), gam (BH, S, d_k) and beta (BH, S) f32."""
-    bh, seq, dk = k.shape
-    dv, n = v.shape[-1], seq // chunk
-    g, chunk_scope, heads_scope = _plan(k, v, chunk)
-    per_chunk = lambda d: pl.BlockSpec((g, chunk, d), lambda h, i: (h, i, 0))  # noqa: E731
-    per_head = pl.BlockSpec((g, n, chunk), lambda h, i: (h, 0, 0))
-    with jax.named_scope(chunk_scope), jax.named_scope(heads_scope):
-        return pl.pallas_call(
-            _fwd_kernel,
-            grid=(bh // g, n),
-            in_specs=[per_chunk(dk), per_chunk(dk), per_chunk(dv), per_chunk(dk), per_head],
-            out_specs=[per_chunk(dv), pl.BlockSpec((g, 1, dk, dv), lambda h, i: (h, i, 0, 0))],
-            out_shape=[jax.ShapeDtypeStruct(v.shape, v.dtype),
-                       jax.ShapeDtypeStruct((bh, n, dk, dv), F32)],
-            scratch_shapes=[pltpu.VMEM((g, dk, dv), F32)],
-            interpret=interpret,
-            name="kda_fwd",
-            compiler_params=_compiler_params(interpret),
-            cost_estimate=pl.CostEstimate(
-                flops=bh * n * chunk_flops(chunk, dk, dv),
-                bytes_accessed=bh * (seq * (2 * dk + 2 * dv) * q.dtype.itemsize + n * dk * dv * 4
-                                     + seq * (dk + 1) * 4),
-                transcendentals=bh * n * chunk * dk * (chunk.bit_length() + 1)),
-        )(q, k, v, gam, _gates_by_chunk(beta, chunk))
-
-
-def _bwd(q, k, v, gam, beta, states, do, chunk, interpret):
-    bh, seq, dk = k.shape
-    dv, n = v.shape[-1], seq // chunk
-    g, chunk_scope, heads_scope = _plan(k, v, chunk)
-    per_chunk = lambda d: pl.BlockSpec((g, chunk, d), lambda h, i: (h, n - 1 - i, 0))  # noqa: E731
-    per_head = pl.BlockSpec((g, n, chunk), lambda h, i: (h, 0, 0))
-    with jax.named_scope(chunk_scope), jax.named_scope(heads_scope):
-        dq, dk_, dv_, dgam, dbeta = pl.pallas_call(
-            _bwd_kernel,
-            grid=(bh // g, n),
-            in_specs=[per_chunk(dk), per_chunk(dk), per_chunk(dv), per_chunk(dk), per_head,
-                      pl.BlockSpec((g, 1, dk, dv), lambda h, i: (h, n - 1 - i, 0, 0)), per_chunk(dv)],
-            out_specs=[per_chunk(dk), per_chunk(dk), per_chunk(dv), per_chunk(dk), per_head],
-            out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype), jax.ShapeDtypeStruct(k.shape, k.dtype),
-                       jax.ShapeDtypeStruct(v.shape, v.dtype), jax.ShapeDtypeStruct(gam.shape, F32),
-                       jax.ShapeDtypeStruct((bh, n, chunk), F32)],
-            scratch_shapes=[pltpu.VMEM((g, dk, dv), F32)],
-            interpret=interpret,
-            name="kda_bwd",
-            compiler_params=_compiler_params(interpret),
-            cost_estimate=pl.CostEstimate(
-                flops=bh * n * chunk_flops(chunk, dk, dv, backward=True),
-                bytes_accessed=bh * (seq * (4 * dk + 4 * dv) * q.dtype.itemsize + n * dk * dv * 4
-                                     + 2 * seq * (dk + 1) * 4),
-                transcendentals=bh * n * chunk * dk * (chunk.bit_length() + 1)),
-        )(q, k, v, gam, _gates_by_chunk(beta, chunk), states, do)
-    return dq, dk_, dv_, dgam, dbeta.reshape(bh, seq)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
-def _kernels(q, k, v, gam, beta, chunk, interpret):
-    return _fwd(q, k, v, gam, beta, chunk, interpret)[0]
-
-
-def _kernels_fwd(q, k, v, gam, beta, chunk, interpret):
-    o, states = _fwd(q, k, v, gam, beta, chunk, interpret)
-    return o, (q, k, v, gam, beta, states)
-
-
-def _kernels_bwd(chunk, interpret, res, do):
-    return _bwd(*res, do, chunk, interpret)
-
-
-_kernels.defvjp(_kernels_fwd, _kernels_bwd)
-
-
-# --------------------------------------------------------------------------- the call
-def _running_sum_of_rows(g, chunk: int):
-    """`gated_delta_rule._running_sum` with a channel axis behind the positions: the sum of g (..., S, d_k)
-    from its chunk's first position on."""
-    *lead, s, d = g.shape
-    return jnp.cumsum(g.reshape(*lead, s // chunk, chunk, d), axis=-2).reshape(g.shape)
+RULE = Rule(name="kimi_delta_rule", kernels="kda", gate_a_channel=True, itemsize=4,
+            functions=lambda: (_chunk_gates, _chunk_fwd, _chunk_bwd),
+            chunk_flops=lambda chunk, dk, dv, dtype, backward: chunk_flops(chunk, dk, dv, backward),
+            transcendentals=lambda chunk, dk: chunk * dk * (chunk.bit_length() + 1))
 
 
 def kimi_delta_rule(q, k, v, g, beta, mesh=None, *, chunk: int = CHUNK,
@@ -372,34 +223,4 @@ def kimi_delta_rule(q, k, v, g, beta, mesh=None, *, chunk: int = CHUNK,
     need not be a whole number of chunks. `backend` and `mesh` as `gated_delta_rule`'s: the kernels on a TPU
     ("pallas"), inside a shard_map on more than one device (batch over (data, fsdp), heads over tensor), the
     XLA form elsewhere."""
-    if chunk & (chunk - 1) or chunk < 8:
-        raise ValueError(f"kimi_delta_rule: chunk {chunk} is no power of two of at least 8")
-    if backend is None:
-        backend = select_backend(mesh.devices.flat[0].platform if mesh is not None else None)
-    seq = q.shape[2]
-    pad = -seq % chunk
-    if pad:  # beta 0, g 0: no write, no decay
-        q, k, v, g = (jnp.pad(x, ((0, 0), (0, 0), (0, pad), (0, 0))) for x in (q, k, v, g))
-        beta = jnp.pad(beta, ((0, 0), (0, 0), (0, pad)))
-    gam, beta = _running_sum_of_rows(g.astype(F32), chunk), beta.astype(F32)
-    if backend == "xla":
-        o = _xla_kda(q, k, v, gam, beta, chunk)
-    elif backend == "pallas":
-        def kernels(q, k, v, gam, beta):
-            b, h = q.shape[:2]
-            flat = lambda x: x.reshape(b * h, *x.shape[2:])  # noqa: E731
-            o = _kernels(flat(q), flat(k), flat(v), flat(gam), flat(beta), chunk, interpret)
-            return o.reshape(b, h, *o.shape[1:])
-
-        if mesh is not None and mesh.size > 1:
-            from ray_tpu.parallel import ShardingRules
-
-            rules = ShardingRules()
-            wide = rules.mesh_axes(("batch", "heads", None, None), mesh=mesh, shape=q.shape)
-            gates = rules.mesh_axes(("batch", "heads", None), mesh=mesh, shape=beta.shape)
-            kernels = jax.shard_map(kernels, mesh=mesh, in_specs=(wide, wide, wide, wide, gates),
-                                    out_specs=wide, check_vma=False)
-        o = kernels(q, k, v, gam, beta)
-    else:
-        raise ValueError(f"kimi_delta_rule: backend {backend!r} is neither 'pallas' nor 'xla'")
-    return o[:, :, :seq] if pad else o
+    return chunked_scan(RULE, q, k, v, g, beta, mesh, chunk=chunk, backend=backend, interpret=interpret)

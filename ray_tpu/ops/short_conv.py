@@ -70,7 +70,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ray_tpu.ops.gated_delta_rule import select_backend
+from ray_tpu.ops.chunked_scan import select_backend
 
 F32 = jnp.float32
 EPS = 1e-6  # under the root of a head's sum of squares

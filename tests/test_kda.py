@@ -18,6 +18,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
 from benchmark.models.solar_open2 import kda_recurrence  # noqa: E402
+from ray_tpu.ops import chunked_scan as walk  # noqa: E402
 from ray_tpu.ops import gated_delta_rule as gdn  # noqa: E402
 from ray_tpu.ops import kda  # noqa: E402
 
@@ -79,7 +80,7 @@ def test_a_chunk_of_strong_decays_would_overflow_the_naive_factors(case):
     """The case is what the halving is for: over a chunk of 32 the running sum passes -88 in some channel, where
     `exp(-gamma)` is infinite in f32; the forms above are finite there and right."""
     g = case[0][3]
-    gam = kda._running_sum_of_rows(jnp.pad(g, ((0, 0), (0, 0), (0, 28), (0, 0))), 32)
+    gam = walk._running_sum(jnp.pad(g, ((0, 0), (0, 0), (0, 28), (0, 0))), 32)
     assert float(gam.min()) < -100.0 and not bool(jnp.isfinite(jnp.exp(-gam)).all())
 
 
@@ -139,7 +140,7 @@ def test_bf16_operands_and_two_heads_a_program():
     for name, a, b in zip(("q", "k", "v", "g", "beta"), grads, want_grads):
         assert far(a, b.astype(jnp.float32)) < 2e-2, name
     flat = [x.reshape(8, *x.shape[2:]) for x in args[:3]]
-    assert kda._plan(flat[1], flat[2], 32)[1:] == ("chunk_32", "heads_2of8")
+    assert walk.plan(kda.RULE, flat[1], flat[2], 32)[1:] == ("chunk_32", "heads_2of8")
     text = jax.jit(f("pallas")).lower(*args).as_text(debug_info=True)
     assert "kda_fwd" in text and "chunk_32/heads_2of8" in text
 
@@ -154,8 +155,8 @@ def test_a_bf16_state_or_decay_is_told(case, what, monkeypatch):
         real = kda._chunk_fwd
         monkeypatch.setattr(kda, "_chunk_fwd", lambda *a, **kw: (lambda o, s: (o, rounded(s)))(*real(*a, **kw)))
     else:
-        real = kda._running_sum_of_rows
-        monkeypatch.setattr(kda, "_running_sum_of_rows", lambda g, chunk: rounded(real(g, chunk)))
+        real = walk._running_sum
+        monkeypatch.setattr(walk, "_running_sum", lambda g, chunk: rounded(real(g, chunk)))
     got = kda.kimi_delta_rule(*args, chunk=32, backend="xla")
     assert far(got, want) > 40 * 2e-5
 

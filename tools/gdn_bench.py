@@ -127,22 +127,29 @@ def passes_issued(jax, jnp, gdn, chunk, dk, dv):
 
 
 def stub(gdn, jnp, part, heads):
-    """Stand the part's stub and the heads a program in the module; returns what puts the module back."""
-    kept = {name: getattr(gdn, name)
-            for name in ("_unit_lower_inverses", "_chunk_fwd", "_chunk_bwd", "_mm", "heads_per_program")}
+    """Stand the part's stub and the heads a program in the module, and in the walk's (`ops/chunked_scan.py`, where a
+    checkout has it: since PR 63 the plan, the doubling and `_mm` live there and the rule's module reads them from
+    it); returns what puts them back."""
+    homes = [gdn] + [m for m in (sys.modules.get("ray_tpu.ops.chunked_scan"),) if m is not None]
+    names = ("_unit_lower_inverses", "_chunk_fwd", "_chunk_bwd", "_mm", "heads_per_program")
+    kept = {name: getattr(gdn, name) for name in names}
+    stubs = {}
     if heads != "plan":
-        gdn.heads_per_program = lambda *_: int(heads)
+        stubs["heads_per_program"] = lambda *_: int(heads)
     if part == "six_pass":
         f32 = lambda x, other: x.astype(jnp.float32) if other.dtype == jnp.float32 else x  # noqa: E731
-        gdn._mm = lambda a, b, dims=gdn.NN: kept["_mm"](f32(a, b), f32(b, a), dims)
+        stubs["_mm"] = lambda a, b, dims=gdn.NN: kept["_mm"](f32(a, b), f32(b, a), dims)
     elif part == "no_inverse":
         eye = lambda a: (gdn._iotas(a.shape[0])[0] == gdn._iotas(a.shape[0])[1]).astype(a.dtype)  # noqa: E731
-        gdn._unit_lower_inverses = lambda mats: [eye(a) - a for a in mats]
+        stubs["_unit_lower_inverses"] = lambda mats: [eye(a) - a for a in mats]
     elif part == "empty":
         f32 = lambda x: x.astype(jnp.float32)  # noqa: E731
-        gdn._chunk_fwd = lambda q, k, v, gam, beta, s, first=None: (f32(v), s)
-        gdn._chunk_bwd = lambda q, k, v, gam, beta, s, do, ds, first=None: (f32(q), f32(k), f32(do), gam, beta, ds + s)
-    return lambda: [setattr(gdn, name, f) for name, f in kept.items()]
+        stubs["_chunk_fwd"] = lambda q, k, v, gam, beta, s, first=None: (f32(v), s)
+        stubs["_chunk_bwd"] = lambda q, k, v, gam, beta, s, do, ds, first=None: (
+            f32(q), f32(k), f32(do), gam, beta, ds + s)
+    put = lambda fs: [setattr(home, name, f) for home in homes for name, f in fs.items() if hasattr(home, name)]  # noqa: E731
+    put(stubs)
+    return lambda: put(kept)
 
 
 def main():

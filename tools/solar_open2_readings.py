@@ -54,26 +54,28 @@ def planted(fault):
     """The chunk's mathematics with `fault` while the block runs: the XLA form and both kernels call these."""
     import jax.numpy as jnp
 
+    from ray_tpu.ops import chunked_scan as walk
     from ray_tpu.ops import kda
 
-    kept = {name: getattr(kda, name) for name in ("_chunk_fwd", "_chunk_bwd", "_chunk_gates", "_running_sum_of_rows")}
+    kept = {(kda, name): getattr(kda, name) for name in ("_chunk_fwd", "_chunk_bwd", "_chunk_gates")}
+    kept[walk, "_running_sum"] = walk._running_sum
     bf16 = lambda x: x.astype(jnp.bfloat16).astype(jnp.float32)  # noqa: E731
     if fault == "state_bf16":
-        kda._chunk_fwd = lambda q, k, v, gam, beta, s, *made: kept["_chunk_fwd"](q, k, v, gam, beta, bf16(s), *made)
-        kda._chunk_bwd = lambda q, k, v, gam, beta, s, do, ds, *made: kept["_chunk_bwd"](
+        kda._chunk_fwd = lambda q, k, v, gam, beta, s, *made: kept[kda, "_chunk_fwd"](q, k, v, gam, beta, bf16(s), *made)
+        kda._chunk_bwd = lambda q, k, v, gam, beta, s, do, ds, *made: kept[kda, "_chunk_bwd"](
             q, k, v, gam, beta, bf16(s), do, bf16(ds), *made)
     elif fault == "decay_bf16":
-        kda._chunk_gates = lambda k, gam, beta: kept["_chunk_gates"](k, bf16(gam), beta)
+        kda._chunk_gates = lambda k, gam, beta: kept[kda, "_chunk_gates"](k, bf16(gam), beta)
     elif fault == "decay_scalar":
-        kda._running_sum_of_rows = lambda g, chunk: kept["_running_sum_of_rows"](
+        walk._running_sum = lambda g, chunk: kept[walk, "_running_sum"](
             jnp.broadcast_to(g.mean(-1, keepdims=True), g.shape), chunk)
     else:
         raise ValueError(f"no such fault: {fault}")
     try:
         yield
     finally:
-        for name, f in kept.items():
-            setattr(kda, name, f)
+        for (module, name), f in kept.items():
+            setattr(module, name, f)
 
 
 def main():
