@@ -8,11 +8,27 @@ the DP gradient all-reduce / FSDP all-gathers / TP collectives from the sharding
 annotations alone. Loss-parity note: this is the exact computation a bare-JAX
 script would run; the framework adds no per-step Python between device
 dispatches (the reference's "Ray adds ~0% overhead over DDP" property).
+
+The compute copy. Parameters are `param_dtype` (float32), products are
+`dtype` (bf16). XLA's own matmuls convert in their operand read; a Pallas call
+takes a materialised operand, so the grouped-matmul kernels' matrices were
+walked in float32 and written in bf16 once forward and once backward, every
+step, for weights that change once a step. `TrainState.compute` holds those
+leaves in `dtype` beside the float32 master: the loss is differentiated at a
+view of the parameters in which they stand (`w.astype(dtype)` of a bf16 array
+is the array), their gradient is widened to the master's dtype before the
+optimizer sees it (what the transpose of `astype` did), and the optimizer's
+pass writes the next copy from the value it has just made. `params` and
+`opt_state` stay `param_dtype` in every leaf: the master, the moments and the
+update are the values they were, and the products' operand is too. The copy is
+derived: whatever saves a state may drop it (`compute={}`), and the first step
+serves such a state as it always was served and hands back one that has it.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import functools
 import sys
 from typing import Any, Callable, Dict, Optional, Tuple
 
@@ -51,6 +67,34 @@ class TrainState:
     params: Any
     opt_state: Any
     step: jax.Array
+    # {key path of a parameter leaf: that leaf in `config.dtype`} (`compute_copy`); empty for a model
+    # that feeds no kernel's grouped operand, and for a state that arrives without one.
+    compute: Any = dataclasses.field(default_factory=dict)
+
+
+def _is_axes(x) -> bool:
+    return isinstance(x, tuple) and all(a is None or isinstance(a, str) for a in x)
+
+
+def leaves_by_path(tree, is_leaf=None) -> Dict[str, Any]:
+    """{`keystr` of a leaf's path: the leaf}: how `TrainState.compute` names a parameter."""
+    leaves, _ = jax.tree_util.tree_flatten_with_path(tree, is_leaf=is_leaf)
+    return {jax.tree_util.keystr(path): leaf for path, leaf in leaves}
+
+
+def compute_copy(config, params) -> Dict[str, Any]:
+    """{key path: leaf.astype(config.dtype)} of the parameters that are a kernel's grouped operand: a leaf
+    whose logical axes name `expert` (the routed experts' three matrices; not the router, not a shared
+    expert, whose `swiglu` is XLA's and converts in its operand read) and that is not `config.dtype` already.
+    Read from the tree: a model with no such leaf has an empty copy."""
+    axes = leaves_by_path(model_for(config).param_logical_axes(config), _is_axes)
+    return {path: p.astype(config.dtype) for path, p in leaves_by_path(params).items()
+            if "expert" in axes[path] and p.dtype != config.dtype}
+
+
+def _with_copies(params, compute):
+    """The parameters as the loss reads them: a leaf that has a compute copy is the copy."""
+    return jax.tree_util.tree_map_with_path(lambda path, p: compute.get(jax.tree_util.keystr(path), p), params)
 
 
 def param_shardings(config, mesh, rules: ShardingRules):
@@ -61,9 +105,7 @@ def param_shardings(config, mesh, rules: ShardingRules):
         lambda ax, s: rules.sharding(mesh, ax, shape=s.shape),
         axes,
         shapes,
-        is_leaf=lambda x: isinstance(x, tuple) and all(
-            a is None or isinstance(a, str) for a in x
-        ),
+        is_leaf=_is_axes,
     )
 
 
@@ -74,27 +116,35 @@ def create_train_state(
     mesh=None,
     rules: Optional[ShardingRules] = None,
 ) -> TrainState:
-    """Initialize params and optimizer state, both sharded, under jit."""
+    """Initialize params and optimizer state, both sharded, under jit, and the
+    compute copy (`compute_copy`), each leaf sharded as its master."""
     init_params = lambda k: model_for(config).init_params(config, k)  # noqa: E731
-    if mesh is None:
-        params = jax.jit(init_params)(key)
-        opt_state = jax.jit(optimizer.init)(params)
-    else:
-        shardings = param_shardings(config, mesh, rules or ShardingRules())
-        params = jax.jit(init_params, out_shardings=shardings)(key)
-        # Whatever in the optimizer's state is laid out like the parameters
-        # (AdamW's mu and nu) is sharded like them, the rest (counts) is whole
-        # everywhere. Propagation alone leaves the moments whole on every
-        # device: 12.4 GB a chip for gpt2-xl, for as long as it takes to lay
-        # them out again (PERF.md section 7, the cold run's margin).
-        structure = jax.tree.structure(params)
-        like_params = lambda x: jax.tree.structure(x) == structure  # noqa: E731
-        whole = jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec())
-        opt_shardings = jax.tree.map(
-            lambda x: shardings if like_params(x) else whole,
-            jax.eval_shape(optimizer.init, params), is_leaf=like_params)
-        opt_state = jax.jit(optimizer.init, out_shardings=opt_shardings)(params)
-    return TrainState(params=params, opt_state=opt_state, step=jnp.zeros((), jnp.int32))
+    copy = functools.partial(compute_copy, config)
+    copied = jax.eval_shape(lambda k: copy(init_params(k)), key)
+    copy_bytes = sum(x.size * x.dtype.itemsize for x in copied.values())
+    with annotate("ray_tpu.train.create_state", compute_copy_bytes=copy_bytes, leaves=len(copied)):
+        if mesh is None:
+            params = jax.jit(init_params)(key)
+            opt_state = jax.jit(optimizer.init)(params)
+            copy_shardings = None
+        else:
+            shardings = param_shardings(config, mesh, rules or ShardingRules())
+            params = jax.jit(init_params, out_shardings=shardings)(key)
+            # Whatever in the optimizer's state is laid out like the parameters
+            # (AdamW's mu and nu) is sharded like them, the rest (counts) is whole
+            # everywhere. Propagation alone leaves the moments whole on every
+            # device: 12.4 GB a chip for gpt2-xl, for as long as it takes to lay
+            # them out again (PERF.md section 7, the cold run's margin).
+            structure = jax.tree.structure(params)
+            like_params = lambda x: jax.tree.structure(x) == structure  # noqa: E731
+            whole = jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec())
+            opt_shardings = jax.tree.map(
+                lambda x: shardings if like_params(x) else whole,
+                jax.eval_shape(optimizer.init, params), is_leaf=like_params)
+            opt_state = jax.jit(optimizer.init, out_shardings=opt_shardings)(params)
+            copy_shardings = {path: s for path, s in leaves_by_path(shardings).items() if path in copied}
+        compute = jax.jit(copy, out_shardings=copy_shardings)(params) if copied else {}
+    return TrainState(params=params, opt_state=opt_state, step=jnp.zeros((), jnp.int32), compute=compute)
 
 
 def make_train_step(
@@ -123,11 +173,16 @@ def make_train_step(
 
         import optax
 
+        # Differentiated where a kernel's operand is its compute copy: nothing is left to convert in
+        # the loss, forward or backward, and the copy's gradient comes back in the copy's dtype.
+        seen = _with_copies(state.params, state.compute)
         if update_buffers is None:
-            loss, grads = jax.value_and_grad(loss_of)(state.params)
+            loss, grads = jax.value_and_grad(loss_of)(seen)
         else:  # the loss's statistics come out of the gradient pass beside it
-            (loss, stats), grads = jax.value_and_grad(loss_of, has_aux=True)(state.params)
+            (loss, stats), grads = jax.value_and_grad(loss_of, has_aux=True)(seen)
         with jax.named_scope("optimizer"):
+            # ... and is widened to its master's, as the transpose of `astype` widened it.
+            grads = jax.tree.map(lambda g, p: g.astype(p.dtype), grads, state.params)
             updates, new_opt = optimizer.update(grads, state.opt_state, state.params)
             if frozen is not None:  # a buffer takes no update, a decoupled weight decay's neither
                 updates = jax.tree.map(lambda u, is_buffer: jnp.zeros_like(u) if is_buffer else u,
@@ -136,8 +191,10 @@ def make_train_step(
         if update_buffers is not None:  # neither a gradient's nor the optimizer's: the model's own rule
             with jax.named_scope("buffers"):
                 new_params = update_buffers(new_params, stats, config)
+        with jax.named_scope("optimizer"):  # one more output of the pass that holds the new value
+            new_compute = compute_copy(config, new_params)
         new_state = TrainState(
-            params=new_params, opt_state=new_opt, step=state.step + 1
+            params=new_params, opt_state=new_opt, step=state.step + 1, compute=new_compute
         )
         with jax.named_scope("grad_norm"):
             gnorm = optax.global_norm(grads)

@@ -118,16 +118,43 @@ def remat_products(text):
     return clones
 
 
+def fused_computations(text):
+    """The computations a `fusion` calls: a result inside one is no array in HBM, the fusion's own is."""
+    return {m.group(1) for m in re.finditer(r" fusion\(.*? calls=%?([\w.\-]+)", text)}
+
+
 def written_under(text, scopes, scope, shape):
     """{phase: [name]} of the instructions that write an array of `shape` (`f32[8,4096,2048]`) under `scope`: a
     result outside every fused computation, a fusion's own among them, is an array in HBM."""
-    fused = {m.group(1) for m in re.finditer(r" fusion\(.*? calls=%?([\w.\-]+)", text)}
+    fused = fused_computations(text)
     found = {}
     for computation, line in by_computation(text):
         m = RESULT.match(line)
         if (m and computation not in fused and m.group(3) not in MOVES_NOTHING and shape in m.group(2)
                 and scope in scopes.get(m.group(1), "").split("/")):
             found.setdefault(phase(scopes[m.group(1)]), []).append(m.group(1))
+    return found
+
+
+def operand_converts(text, scopes, shapes):
+    """{"optimizer": [name], "elsewhere": [name]} of the instructions that make a bf16 array of one of `shapes`
+    (an expert leaf's: `(4, 8, 2048, 768)`, as a whole, a layer's slice of it or a period's) by a `convert`,
+    alone or as a fusion that holds one with that result: the pass that rounds a kernel's grouped operand from
+    its float32 master. A result outside every fused computation is an array in HBM (`written_under`). With the
+    compute copy (`models/training.py`) the optimizer's pass is the one place that writes it."""
+    dims = {",".join(map(str, (*lead, *shape[-3:]))) for shape in shapes for lead in ((), (1,), shape[:-3])}
+    rounded = re.compile(r" = bf16\[(?:%s)\]\S* convert\(" % "|".join(map(re.escape, sorted(dims))))
+    fused = fused_computations(text)
+    rounds = {c for c, line in by_computation(text) if rounded.search(line)}
+    found = {"optimizer": [], "elsewhere": []}
+    for computation, line in by_computation(text):
+        m = RESULT.match(line)
+        if not m or computation in fused or not any(f"bf16[{d}]" in m.group(2) for d in dims):
+            continue
+        calls = FUSED.search(line)
+        if m.group(3) == "convert" or (m.group(3) == "fusion" and calls and calls.group(1) in rounds):
+            inside = "optimizer" in re.split(r"[/()]", scopes.get(m.group(1), ""))
+            found["optimizer" if inside else "elsewhere"].append(m.group(1))
     return found
 
 
@@ -456,7 +483,7 @@ def _lowered_step(topo, axes, cfg, rows, seq, learning_rate=3e-4):
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from ray_tpu.models import default_optimizer, make_train_step
-    from ray_tpu.models.training import TrainState, model_for, param_shardings
+    from ray_tpu.models.training import TrainState, compute_copy, leaves_by_path, model_for, param_shardings
     from ray_tpu.parallel import MeshSpec, ShardingRules, batch_spec
 
     spec = MeshSpec(**axes)
@@ -471,8 +498,12 @@ def _lowered_step(topo, axes, cfg, rows, seq, learning_rate=3e-4):
     def abstract(s, sharding):
         return jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sharding)
 
+    master = leaves_by_path(shardings)
     state = TrainState(
         params=jax.tree.map(abstract, shapes, shardings),
+        # A kernel's grouped operand in the compute dtype, laid out like its master.
+        compute={path: abstract(s, master[path]) for path, s in jax.eval_shape(
+            lambda p: compute_copy(cfg, p), shapes).items()},
         # Adam moments are laid out like their parameter; counters replicate.
         opt_state=jax.tree.map(
             lambda s: abstract(s, by_shape.get(s.shape, replicated)),
@@ -517,21 +548,27 @@ def _held_experts_case(topo):
     return {"wide_by_branch": by_branch, "wide_outside": outside, "kernels": kernels}
 
 
-def _step_case(topo, cell):
-    """`make_train_step` at a cell's shapes, laid out as `create_train_state` lays out real state: the
-    compiled program's size and memory, what it hands to Mosaic and under which scope, and how the
-    expert layer's rows and scalars move."""
+def configuration(cell):
+    """(`benchmark/configs/CELL.json`'s dict, the model's configuration as the harness builds it): the
+    benchmark's module for `c["model"]` has one `<family>_config(c)` (its `build` wants a device)."""
     import importlib
 
     with open(os.path.join(REPO, "benchmark", "configs", cell + ".json")) as fh:
         c = json.load(fh)
-    # The cell's configuration as the harness builds it: the benchmark's module for
-    # `c["model"]` has one `<family>_config(c)` (its `build` wants a device).
     model = importlib.import_module("benchmark.models." + c["model"])
     (to_config,) = [f for name, f in vars(model).items() if name.endswith("_config")]
+    return c, to_config(c)
+
+
+def _step_case(topo, cell):
+    """`make_train_step` at a cell's shapes, laid out as `create_train_state` lays out real state: the
+    compiled program's size and memory, what it hands to Mosaic and under which scope, and how the
+    expert layer's rows and scalars move."""
+    c, cfg = configuration(cell)
     rows, seq = c["batch"]["global_rows"], c["batch"]["seq"]
-    compiled = _lowered_step(topo, c["layout"]["mesh"] or {"data": 1}, to_config(c), rows, seq,
-                             c["learning_rate"]).compile()
+    lowered = _lowered_step(topo, c["layout"]["mesh"] or {"data": 1}, cfg, rows, seq, c["learning_rate"])
+    copies = lowered.args_info[0][0].compute.values()  # the abstract state's, as `create_train_state` lays it out
+    compiled = lowered.compile()
     text, mem = compiled.as_text(), compiled.memory_analysis()
     scopes = scope_map(text)
     mosaic = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
@@ -547,6 +584,8 @@ def _step_case(topo, cell):
         "remat_clones": sorted(m.group(1) for m in RESULT.finditer(text) if ".remat" in m.group(1)),
     }
     out["stacks"], out["stacked_bytes"] = layer_stacks(text)
+    out["compute_copy_bytes"] = sum(math.prod(x.shape) * x.dtype.itemsize for x in copies)
+    out["operand_converts"] = operand_converts(text, scopes, [x.shape for x in copies])
     if c["model"] == "olmo_hybrid":  # the short convolutions' chain (scope `gdn_conv`): forward, and made again?
         conv = [n.split("/") for n in scopes.values() if "gdn_conv" in n.split("/") and "pallas_call" not in n]
         out["conv_chain_forward"] = sum(phase("/".join(parts)) == "forward" for parts in conv)
@@ -735,12 +774,22 @@ def steps(*cells):
 # ------------------------------------------------------------------ what two files assert of a step
 def is_the_pinned_program(got, cell, pinned, temp_limit):
     """Same instruction count and `memory_analysis()` as pinned, temporaries under `temp_limit`, arguments
-    under what the cell's file records."""
+    under what the cell's file records and the compute copy beside them (PR 64)."""
     assert {k: got[k] for k in pinned} == pinned
     assert got["temp"] <= temp_limit
     with open(os.path.join(REPO, "benchmark", "configs", cell + ".json")) as fh:
         recorded = json.load(fh)["memory_analysis_v5e_bytes"]
-    assert got["argument"] <= recorded["arguments"]
+    assert got["argument"] - got["compute_copy_bytes"] <= recorded["arguments"]
+
+
+def rounds_the_experts_matrices_in_the_optimizer_alone(got, parents):
+    """PR 64: of a step case, no pass outside the scope `optimizer` writes a bf16 array of an expert leaf's shape by a
+    convert (`operand_converts`), and one pass a leaf inside it does; `parents` is what the parent's step held outside
+    (this reader on the parent's compiled text: a cast a matrix forward, and again wherever the backward pass or a
+    second form of the layer wanted it)."""
+    found = got["operand_converts"]
+    assert found["elsewhere"] == [], f"{len(found['elsewhere'])} outside `optimizer` (the parent's step: {parents}): {found}"
+    assert got["compute_copy_bytes"] > 0 and len(found["optimizer"]) >= 3, found
 
 
 def stacks_ending(got, tail):

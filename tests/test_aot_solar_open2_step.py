@@ -17,6 +17,7 @@ SOLAR = "solar-open2-250b-ep40-l4"
 KDA_4K = "kda:1x8x4096x128x128"
 V5E_HBM_BYTES = 16_909_336_064
 PARAMETERS = 840_874_392
+HELD_EXPERT_PARAMETERS = 503_316_480
 
 
 @pytest.fixture(scope="module")
@@ -58,11 +59,22 @@ def test_the_step_runs_each_kernel_once_a_layer_and_never_again_in_the_backward_
 
 
 def test_the_step_fits_the_chip_with_nine_tenths_of_a_gigabyte_to_spare(aot):
-    """840.9 M parameters x 12 B are the arguments (the f32 gradient is a temporary), XLA's peak is under 16.0 GB
-    of the chip's 16.91 and over the contract's floor, and the file records what this compile gave."""
+    """840.9 M parameters x 12 B and the 503.3 M held expert parameters' bf16 copy (PR 64) are the arguments (the
+    gradient is a temporary), XLA's peak is under 16.0 GB of the chip's 16.91 and over the contract's floor, and
+    what the file records is this compile's less the copy. Pinned again at PR 64, on purpose: the step is unrolled,
+    so the copy's 1,006,632,960 B are paid back by the casts that are no temporaries any more and by nothing else:
+    the peak is 14,820,838,912 where the parent's was 14,065,864,192."""
     got = aot["step:" + SOLAR]
-    assert 0 <= got["argument"] - PARAMETERS * 12 < 16 << 20  # beside the state: step, counts, the batch
+    assert got["compute_copy_bytes"] == HELD_EXPERT_PARAMETERS * 2
+    state = got["argument"] - got["compute_copy_bytes"]
+    assert 0 <= state - PARAMETERS * 12 < 16 << 20  # beside the state: step, counts, the batch
     assert got["peak"] is not None and 0.25 * V5E_HBM_BYTES < got["peak"] <= 16.0e9
     with open(os.path.join(aot_v5e.REPO, "benchmark", "configs", SOLAR + ".json")) as fh:
         recorded = json.load(fh)["memory_analysis_v5e_bytes"]
-    assert got["argument"] <= recorded["arguments"] and got["peak"] <= recorded["peak"] * 1.01
+    assert state <= recorded["arguments"] and got["peak"] - got["compute_copy_bytes"] <= recorded["peak"]
+    assert got["remat_products"] == 0 and got["recomputed"] <= 358  # the parent's
+
+
+def test_no_pass_rounds_an_expert_matrix_outside_the_optimizer(aot):
+    """The parent's step cast each of the twelve matrices forward and again backward, in both forms of a layer."""
+    aot_v5e.rounds_the_experts_matrices_in_the_optimizer_alone(aot["step:" + SOLAR], 48)

@@ -17,7 +17,7 @@ import optax
 
 from benchmark.models import trinity as bench
 from ray_tpu.models import create_train_state, default_optimizer, make_train_step, trinity
-from ray_tpu.models.training import TrainState, model_for
+from ray_tpu.models.training import TrainState, _with_copies, compute_copy, model_for
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 SEQ = 64  # four windows of the nano's 16
@@ -199,7 +199,8 @@ def test_the_train_step_moves_the_bias_by_the_rule_and_no_other_buffer_or_model(
 
 
 def _parents_step(config, optimizer):
-    """`make_train_step` as the parent of PR 61 wrote it (its body, word for word)."""
+    """`make_train_step` as the parent of PR 61 wrote it (its body, word for word), with what PR 64 put round the
+    loss and the optimizer since (the compute copy: `models/training.py`): the step that knows no `update_buffers`."""
     base_rng = jax.random.PRNGKey(0x5eed)
     frozen = getattr(model_for(config), "frozen_params", None)
 
@@ -209,14 +210,17 @@ def _parents_step(config, optimizer):
         def loss_of(p):
             return model_for(config).loss_fn(p, batch, config, None, step_rng, mesh=None)
 
-        loss, grads = jax.value_and_grad(loss_of)(state.params)
+        loss, grads = jax.value_and_grad(loss_of)(_with_copies(state.params, state.compute))
         with jax.named_scope("optimizer"):
+            grads = jax.tree.map(lambda g, p: g.astype(p.dtype), grads, state.params)
             updates, new_opt = optimizer.update(grads, state.opt_state, state.params)
             if frozen is not None:
                 updates = jax.tree.map(lambda u, is_buffer: jnp.zeros_like(u) if is_buffer else u,
                                        updates, frozen(config))
             new_params = optax.apply_updates(state.params, updates)
-        new_state = TrainState(params=new_params, opt_state=new_opt, step=state.step + 1)
+        with jax.named_scope("optimizer"):
+            new_compute = compute_copy(config, new_params)
+        new_state = TrainState(params=new_params, opt_state=new_opt, step=state.step + 1, compute=new_compute)
         with jax.named_scope("grad_norm"):
             gnorm = optax.global_norm(grads)
         return new_state, {"loss": loss, "grad_norm": gnorm, "step": new_state.step}

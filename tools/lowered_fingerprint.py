@@ -13,7 +13,9 @@ the call and its gradient), of the short convolution's gradient kernel, and of e
 cell builds it, for a `v5e:2x2` described without a chip. A Mosaic call's payload is MLIR bytecode that carries file
 paths and line numbers, so a docstring edit or another checkout directory changes the lowered text's bytes: each
 payload is parsed and printed again without locations before the hash. A full-size step lowers in 5-20 s, nothing is
-compiled (nineteen configurations: about 4 min).
+compiled (nineteen configurations: about 4 min). The step is lowered from the abstract state `aot_v5e._lowered_step`
+lays out as the root's own `create_train_state` does: since PR 64 with `TrainState.compute`, so an expert
+configuration's step differs from a root's of before, and a dense one's does not.
 
 PARAMS: for each configuration `shapes`, a hash of `jax.eval_shape(init_params)` (paths, shapes, dtypes) with the
 trees `param_logical_axes` and `frozen_params` return, leaf by leaf; for a nano configuration also `seed0` and

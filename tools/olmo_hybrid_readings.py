@@ -39,7 +39,7 @@ def light_system(bench, c, mesh, seed):
     system.c, system.mesh, system.cfg = c, mesh, bench.olmo_hybrid_config(c)
     shardings = param_shardings(system.cfg, mesh, ShardingRules())
     params = jax.jit(lambda key: program.init_params(system.cfg, key), out_shardings=shardings)(jax.random.PRNGKey(seed))
-    system.state = TrainState(params=params, opt_state=(), step=0)
+    system.state = TrainState(params=params, opt_state=(), step=0)  # no compute copy: `check` differentiates at `params`
     return system
 
 

@@ -78,7 +78,7 @@ def light_system(bench, c, seed):
     system = bench.System.__new__(bench.System)
     system.c, system.mesh, system.cfg = c, None, bench.model_config(c)
     params = jax.jit(lambda key: program.init_params(system.cfg, key))(jax.random.PRNGKey(seed))
-    system.state = TrainState(params=params, opt_state=(), step=0)
+    system.state = TrainState(params=params, opt_state=(), step=0)  # no compute copy: `check` differentiates at `params`
     if c.get("router_init_tiles", 1) > 1:
         system.state = bench.tile_routers(system.state, c["router_init_tiles"])
     return system

@@ -35,7 +35,7 @@ def light_system(bench, c, seed):
     system = bench.System.__new__(bench.System)
     system.c, system.mesh, system.cfg = c, None, bench.solar_open2_config(c)
     params = jax.jit(lambda key: program.init_params(system.cfg, key))(jax.random.PRNGKey(seed))
-    system.state = TrainState(params=params, opt_state=(), step=0)
+    system.state = TrainState(params=params, opt_state=(), step=0)  # no compute copy: `check` differentiates at `params`
     return system
 
 
