@@ -1,10 +1,10 @@
 """The readings that list their cells, as the accepted benchmark holds them (PR 50; PR 56 put the
-Olmo-Hybrid cell on them): one entry and one reader file a reading, its `workloads` every accepted
-cell that reports it. An entry that lists
+Olmo-Hybrid cell on them, PR 65 Solar-Open2's and Trinity-Mini's): one entry and one reader file a
+reading, its `workloads` every accepted cell that reports it. An entry that lists
 its cells takes no later cell and only a `benchmark` PR may edit it, so a later configuration brings
 such a reading as a copy, `<metric>.<configuration>` (`widened_manifest.widen()` rehearses that), and
 the next `benchmark` PR folds the copies accepted since into these lists. A helper, imported by name
-into the five test files of the cells on the lists: a `conftest.py` here would shadow `tests/conftest.py`."""
+into the test files of the cells on the lists: a `conftest.py` here would shadow `tests/conftest.py`."""
 
 import os
 import sys
@@ -17,8 +17,9 @@ from benchmark.harness.manifest import Manifest  # noqa: E402
 MEDIUM_RESIDENT, MEDIUM_FED, XL = "gpt2-medium.resident", "gpt2-medium.fed", "gpt2-xl-fsdp4.fed"
 OLMOE, LFM2, GLM = "olmoe-1b-7b-l1.fed4k", "lfm2-24b-a2b-ep8-l5.fed4k", "glm-4.7-flash-ep8-l5.fed4k"
 KEYE, SDAR, OLMO_HYBRID = "keye-vl-2.0-30b-a3b-ep8.fed16k", "sdar-30b-a3b-chat-ep8.fed8k", "olmo-hybrid-7b-fsdp4.fed4k"
-FED = [MEDIUM_FED, XL, OLMOE, LFM2, GLM, KEYE, SDAR, OLMO_HYBRID]
-EXPERTS = [OLMOE, LFM2, GLM, KEYE, SDAR]
+SOLAR, TRINITY = "solar-open2-250b-ep40-l4.fed4k", "trinity-mini-ep16-l5.fed16k"
+FED = [MEDIUM_FED, XL, OLMOE, LFM2, GLM, KEYE, SDAR, OLMO_HYBRID, SOLAR, TRINITY]
+EXPERTS = [OLMOE, LFM2, GLM, KEYE, SDAR, SOLAR, TRINITY]
 GANGS = [XL, OLMO_HYBRID]  # the four-chip cells: four one-chip workers joined by `jax.distributed`
 TABLE = {
     **dict.fromkeys(("data.wait_ms", "host.h2d_ms", "host.report_ms", "host.report_put_ms"), FED),
@@ -31,9 +32,10 @@ TABLE = {
     "host.stall_pct": [MEDIUM_RESIDENT, MEDIUM_FED, OLMOE],
     **dict.fromkeys(("moe.router_ms", "moe.dispatch_ms", "moe.experts_ms", "moe.experts_roofline",
                      "moe.load_max_over_mean", "kernels.gmm_ms", "kernels.gmm_roofline"), EXPERTS),
-    "step.dense_mlp_ms": [LFM2, GLM, OLMO_HYBRID],
-    "moe.held_pairs_share": [LFM2, GLM, KEYE],  # SDAR's is 0.125 by construction: no reading
-    "moe.issued_over_held": [LFM2, GLM, KEYE, SDAR],
+    "step.dense_mlp_ms": [LFM2, GLM, OLMO_HYBRID, TRINITY],
+    "moe.held_pairs_share": [LFM2, GLM, KEYE, SOLAR, TRINITY],  # SDAR's is 0.125 by construction: no reading
+    "moe.issued_over_held": [LFM2, GLM, KEYE, SDAR, SOLAR, TRINITY],
+    "moe.shared_ms": [GLM, SOLAR, TRINITY],  # the cells whose expert layers hold an expert every token meets
     # What exists only across chips. `collectives.exposed_min_ms` read 0.0 in the seven one-chip cells until PR 56.
     **dict.fromkeys(("collectives.total_ms", "collectives.exposed_ms", "collectives.exposed_min_ms",
                      "entry.gang_join_s"), GANGS),
@@ -56,4 +58,5 @@ def holds_for(cell, listed, new):
     assert unlisted <= mine and len(mine) == len(listed) + len(new) + len(unlisted)
     accepted = {m.cell(c)["config"] for cells in TABLE.values() for c in cells}
     assert not [n for n in by_name for c in accepted if n.endswith("." + c)]
+    assert not [f for f in os.listdir(os.path.join(m.dir, "layer_metrics")) for c in accepted if c in f]  # nor a reader file
     return by_name, unlisted

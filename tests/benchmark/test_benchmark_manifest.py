@@ -18,7 +18,7 @@ sys.path[:0] = [REPO, os.path.dirname(os.path.abspath(__file__))]
 
 from benchmark.harness import driver, peaks, rehearsal_names  # noqa: E402
 from benchmark.harness.manifest import Manifest, problems, reduced_problems  # noqa: E402
-from widened_manifest import manifest_root, widened  # noqa: E402,F401  (fixtures)
+from widened_manifest import hold_the_room, manifest_root, rehearsals_own, room, widened  # noqa: E402,F401  (fixtures)
 
 SEED_CELLS = {"gpt2-medium.resident": 1, "gpt2-medium.fed": 1, "gpt2-xl-fsdp4.fed": 4}  # PR 22's
 CONTRACT_KEYS = {"correct", "attempted", "failed", "metrics", "device", "compared"}
@@ -49,20 +49,42 @@ def test_every_entry_has_one_reader_file_and_every_reader_file_one_entry(manifes
 
 
 def test_per_layer_keeps_the_widening_rehearsal_under_the_contracts_cap(widened):
-    """The contract caps `per_layer` at 128 and `test_benchmark_program_trace.py` holds a copy widened
-    twice to it, so what a later PR may append is 128 less the live entries less two widenings. Only
-    that cap is held here: a `model_config` PR may edit nothing in this directory, so a margin asserted
-    on the live manifest would refuse the very PR the room is for. A configuration with a new kernel
-    and new layers brings about twenty entries (its own and a copy of each listed reading its cell
-    reports: thirteen for a fed expert cell); how many such the room takes is PERF.md's to say."""
-    live, appended = len(Manifest().data["per_layer"]), len(widened.metrics)
-    room = 128 - live - 2 * appended
-    assert room >= 0, (
-        f"`per_layer` holds {live} entries and the widening rehearsal appends 2 x {appended}: {-room} over the cap "
-        f"of 128, so the next configuration may append none. No `model_config` PR can make room: a `benchmark` PR "
-        f"does, by folding the `<metric>.<configuration>` copies accepted since the last one into the listed "
-        f"entries' `workloads` and deleting their reader files (PERF.md section 4, "
-        f"`tests/benchmark/listed_readings.py`)")
+    """The contract caps `per_layer` at 128 and `test_benchmark_olmo_hybrid.py` widens a copy twice, so the live list
+    may hold 128 - 2 x 7 = 114 entries: `widened_manifest.room`, the one count, which leaves a rehearsal's own
+    entries out and so reads the same here and where `test_benchmark_widening.py` runs this directory's tests inside a
+    copy widened before. Only that bound is held, and here alone: a `model_config` PR may edit nothing in this
+    directory, so a margin asserted on the live manifest would refuse the very PR the room is for. With 86 entries
+    held (PR 65) a `model_config` PR may append **28**; a fed expert cell with latent attention brings sixteen copies
+    of listed readings beside its own."""
+    hold_the_room(Manifest().data["per_layer"], len(widened.metrics))
+
+
+def _held(live, rehearsed):
+    """A `per_layer` of `live` entries, and with `rehearsed` the seven more a copy widened before holds."""
+    entries = [{"name": f"reading.{n}", "workloads": ["some-cell.fed"]} for n in range(live)]
+    return entries + [{"name": f"throwaway.{n}", "workloads": ["throwaway-cut.short"]} for n in range(7 * rehearsed)]
+
+
+@pytest.mark.parametrize("rehearsed", [False, True], ids=["live", "widened_before"])
+@pytest.mark.parametrize("live, left", [(86, 28), (113, 1), (114, 0), (115, -1)])
+def test_an_appended_entry_passes_at_113_held_and_fails_at_115_in_either_run(live, left, rehearsed):
+    per_layer = _held(live, rehearsed)
+    assert room(per_layer, 7) == left
+    if left >= 0:
+        hold_the_room(per_layer, 7)
+    else:
+        with pytest.raises(AssertionError, match="holds 115 .* may hold 128 - 2 x 7 = 114.* 1 over"):
+            hold_the_room(per_layer, 7)
+
+
+def test_the_bound_is_one_number_on_the_live_manifest_and_on_a_copy_widened_before(widened):
+    """What `test_benchmark_widening.py`'s nested run stands on: there `Manifest()` is a copy widened once, and its
+    count of the room is this manifest's, the rehearsal's seven entries left out."""
+    here, there = Manifest().data["per_layer"], Manifest(widened.root).data["per_layer"]
+    appended = len(widened.metrics)
+    assert len(there) == len(here) + appended and len(rehearsals_own(there)) == len(rehearsals_own(here)) + appended
+    assert [e["name"] for e in rehearsals_own(there)][-appended:] == widened.metrics
+    assert room(there, appended) == room(here, appended)
 
 
 def test_the_names_before_the_fold_go_on_a_rehearsals_line_and_on_no_other():

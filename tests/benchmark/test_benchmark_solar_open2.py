@@ -1,7 +1,8 @@
 """What the Solar-Open2 configuration brings to the benchmark: its file against the catalog row, its cell and entries
 appended and held to the contract, its readers on a recorded trace, the floors' arithmetic and the parameter count by
-hand. A one-chip cell (both four-chip slots are taken). The fourteen listed readings it reports come as
-`<metric>.<configuration>` copies until a `benchmark` PR folds them into the listed entries' own lists.
+hand. A one-chip cell (both four-chip slots are taken). It brought the fourteen listed readings it reports as
+`<metric>.<configuration>` copies (PR 59); PR 65 put it on those entries' own lists and deleted the copies
+(`listed_readings.TABLE`).
 (The cell's CPU rehearsal is `tests/test_solar_open2_rehearsal.py`: this directory's tests are run a second time
 inside `test_benchmark_widening.py`.)"""
 
@@ -29,7 +30,7 @@ NEW = ("kda.mixer_ms", "kda.conv_ms", "kda.gates_ms", "kernels.kda_fwd_ms", "ker
 # `data.fetch_block_ms` (a block of 16 rows lasts 16 steps of a row: a window of 8 traced steps holds a pull one time
 # in two), not `host.stall_pct` (about 160 steps a window: fewer than three readings a position clear of the traced
 # ones), not `step.dense_mlp_ms` (no layer is dense).
-COPIED = ("data.wait_ms", "host.h2d_ms", "host.report_ms", "host.report_put_ms", "moe.router_ms", "moe.dispatch_ms",
+LISTED = ("data.wait_ms", "host.h2d_ms", "host.report_ms", "host.report_put_ms", "moe.router_ms", "moe.dispatch_ms",
           "moe.experts_ms", "moe.experts_roofline", "moe.load_max_over_mean", "kernels.gmm_ms", "kernels.gmm_roofline",
           "moe.held_pairs_share", "moe.issued_over_held", "moe.shared_ms")
 REDUCED = ["num_hidden_layers", "gqa_layers", "n_routed_experts", "num_attention_heads", "num_key_value_heads",
@@ -52,34 +53,26 @@ def test_the_manifest_holds_the_cell_appended_and_meets_the_contract():
     assert os.path.isfile(os.path.join(m.dir, "models", m.config(CONFIG)["model"] + ".py"))
     assert all(1 <= len(e["why"]) <= 200 for e in m.data["configs"] + m.data["workloads"])
     assert os.path.getsize(os.path.join(REPO, "BENCHMARK.json")) < 64 * 1024
-    # One run of twenty-one after the 73 entries PR 56 left: the seven new readings, then the fourteen copies.
+    # One run of seven after the 73 entries PR 56 left: the new readings (the fourteen copies that followed went in
+    # PR 65, so PR 60's `step.xla_remat_ms` stands next).
     names = [e["name"] for e in m.data["per_layer"]]
-    assert names[73:80] == list(NEW) and names[80:94] == [f"{name}.{CONFIG}" for name in COPIED]
+    assert names[73:80] == list(NEW) and names[80] == "step.xla_remat_ms"
+    assert not [name for name in names if name.endswith("." + CONFIG)]
     # The mix is the one that was there, unedited: rows of 4,097 out of 16-row blocks.
     assert m.traffic("fed4k") == {**m.traffic("fed4k"), "loop": "fed", "block_rows": 16, "supply_factor": 4}
 
 
-def test_the_cell_reports_the_new_readings_the_copies_and_every_unlisted_one():
+def test_the_cell_reports_the_new_readings_each_listed_one_and_every_unlisted_one():
     m = Manifest()
     readers = m.layer_readers()
-    by_name = {e["name"]: e for e in m.data["per_layer"]}
-    mine = {e["name"] for e in m.metrics_for(CELL, "per_layer")}
-    unlisted = {e["name"] for e in m.data["per_layer"] if "workloads" not in e}
-    assert mine == set(NEW) | {f"{name}.{CONFIG}" for name in COPIED} | unlisted and len(unlisted) >= 30
+    by_name, unlisted = listed_readings.holds_for(CELL, LISTED, NEW)
+    assert len(unlisted) >= 30
     for name in NEW:
-        assert by_name[name]["workloads"] == [CELL] and by_name[name]["moves"] == "tokens_per_s_per_chip"
+        assert by_name[name]["moves"] == "tokens_per_s_per_chip"
         assert readers[name].META == {k: v for k, v in by_name[name].items() if k != "workloads"}
     assert by_name["kernels.kda_roofline"]["unit"] == "%" and by_name["kernels.kda_roofline"]["better"] == "higher"
     assert {by_name[n]["layer"] for n in NEW[:3]} == {"linear attention"} and by_name[NEW[3]]["layer"] == "kernels"
-    for name in COPIED:  # a copy is the listed entry under the cell's name, read by the listed reader
-        copy, listed = by_name[f"{name}.{CONFIG}"], by_name[name]
-        assert copy == {**listed, "name": copy["name"], "workloads": [CELL]} and CELL not in listed["workloads"]
-        assert listed["workloads"] == listed_readings.TABLE.get(name, listed["workloads"])  # the table holds the shared ones
-        assert readers[copy["name"]].read.__code__.co_filename == readers[name].__file__  # `read = listed.read`
-        assert readers[copy["name"]].META == {k: v for k, v in copy.items() if k != "workloads"}
     assert [e["name"] for e in m.metrics_for(CELL, "end_to_end")] == ["tokens_per_s_per_chip", "setup_s"]
-    # 94 with this cell: 34 left under the cap of 128, under twenty beside a rehearsal's 2 x 7 (a widened copy holds more)
-    assert 94 <= len(m.data["per_layer"]) <= 128
 
 
 def test_the_file_holds_every_published_key_and_cuts_counts_and_no_width(config):
@@ -200,8 +193,8 @@ def test_the_new_readers_return_nothing_on_a_program_without_the_scopes_or_the_k
                summary={**named_run["summary"], "device": {"count": CHIPS}, "span_ms_per_step": {"data_wait": 0.25}},
                peaks={"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9})
     assert [readers[name].read(run) for name in NEW] == [None] * len(NEW)
-    assert readers[f"data.wait_ms.{CONFIG}"].read(run) == 0.25
-    assert readers[f"moe.shared_ms.{CONFIG}"].read(run) is None  # GPT-2 has no scope `shared_expert`
+    assert readers["data.wait_ms"].read(run) == 0.25
+    assert readers["moe.shared_ms"].read(run) is None  # GPT-2 has no scope `shared_expert`
 
 
 def test_the_roofline_divides_the_larger_floor_by_the_kernels_time(config, monkeypatch):

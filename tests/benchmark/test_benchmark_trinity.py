@@ -1,8 +1,8 @@
 """What the Trinity-Mini configuration brings to the benchmark: its file against the catalog row, its cell and entries
 appended and held to the contract, its readers on a recorded trace, the kernels' floors and the parameter count by hand.
-A one-chip cell (both four-chip slots are taken). Seven of the listed readings it could report come as
-`<metric>.<configuration>` copies until a `benchmark` PR folds them into the listed entries' own lists; the others are
-left out for room (`per_layer` holds 107 of 128 with this cell, and the widening rehearsal appends 3 x 7 at its deepest).
+A one-chip cell (both four-chip slots are taken). It brought seven of the listed readings it reports as
+`<metric>.<configuration>` copies (PR 61); PR 65 put it on those entries' own lists, deleted the copies, and put it on
+the lists of the eight more that it reads in every traced run (`listed_readings.TABLE`).
 (The cell's CPU rehearsal is `tests/test_trinity_rehearsal.py`: this directory's tests are run a second time inside
 `test_benchmark_widening.py`.)"""
 
@@ -27,16 +27,15 @@ CELL = CONFIG + ".fed16k"
 ROWS, SEQ, CHIPS = 1, 16384, 1
 NEW = ("attn.window_ms", "attn.full_ms", "kernels.flash_window_ms", "kernels.flash_window_roofline",
        "swa.walked_over_live_blocks")
-# The listed readings of this cell's expert layers, dense layer and grouped kernels, as copies. ISSUE 61 named thirteen;
-# seven fit: this directory's tests run once more inside a copy widened once (`test_benchmark_widening.py`), where
-# `test_benchmark_olmo_hybrid.py` widens twice more, so the live list may hold 128 - 3 x 7 = 107 entries, twelve more
-# than the 95 PR 60 left, and no file that is there may be edited. Left out, each read by the accepted fed or expert
-# cells already: `data.wait_ms`, `host.report_ms`, `host.h2d_ms`, `host.report_put_ms`, `moe.router_ms`,
-# `moe.dispatch_ms`, `moe.held_pairs_share`, `moe.issued_over_held` (the run's record and `tools/scope_table.py` still
-# give them); `data.fetch_block_ms` and `host.stall_pct` cannot read in every traced run of this cell (8-row blocks last
-# 8 steps of a row; about 44 steps a window).
-COPIED = ("moe.experts_ms", "moe.experts_roofline", "moe.load_max_over_mean", "kernels.gmm_ms", "kernels.gmm_roofline",
-          "moe.shared_ms", "step.dense_mlp_ms")
+# The listed readings a one-chip fed cell with a leading dense layer, experts and a shared one reports in every traced
+# run: the seven it brought as copies, then the eight it joined at PR 65 (`JOINED`: three traced runs of the cell on
+# the chip read each, `benchmark/testdata/trinity_traced_lines.json`). Not `data.fetch_block_ms` and not
+# `host.stall_pct`: 8-row blocks last 8 steps of a row, and a window holds about 44 steps.
+BROUGHT = ("moe.experts_ms", "moe.experts_roofline", "moe.load_max_over_mean", "kernels.gmm_ms", "kernels.gmm_roofline",
+           "moe.shared_ms", "step.dense_mlp_ms")
+JOINED = ("data.wait_ms", "host.h2d_ms", "host.report_ms", "host.report_put_ms", "moe.router_ms", "moe.dispatch_ms",
+          "moe.held_pairs_share", "moe.issued_over_held")
+LISTED = BROUGHT + JOINED
 REDUCED = ["num_hidden_layers", "num_dense_layers", "layer_types", "num_experts", "vocab_size"]
 V5E_HBM_BYTES = 16_909_336_064
 PEAKS = {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
@@ -57,17 +56,19 @@ def test_the_manifest_holds_the_cell_appended_and_meets_the_contract():
     assert os.path.isfile(os.path.join(m.dir, "models", m.config(CONFIG)["model"] + ".py"))
     assert all(1 <= len(e["why"]) <= 200 for e in m.data["configs"] + m.data["workloads"])
     assert os.path.getsize(os.path.join(REPO, "BENCHMARK.json")) < 64 * 1024
-    # One run of twelve after the 95 entries PR 60 left: the five new readings, then the seven copies.
+    # One run of five after the 81 entries PR 60 left less Solar-Open2's fourteen copies: the new readings (the seven
+    # copies that followed went in PR 65).
     names = [e["name"] for e in m.data["per_layer"]]
-    assert names[95:100] == list(NEW) and names[100:107] == [f"{name}.{CONFIG}" for name in COPIED]
+    assert names[81:86] == list(NEW) and not [name for name in names if name.endswith("." + CONFIG)]
     # The mix is the one that was there, unedited: rows of 16,385 out of 8-row blocks.
     assert m.traffic("fed16k") == {**m.traffic("fed16k"), "loop": "fed", "block_rows": 8, "supply_factor": 4}
     assert m.traffic("fed16k")["documents"] == {"median_tokens": 1000, "sigma": 1.6, "min_tokens": 8, "max_tokens": 32768}
 
 
-def test_the_cell_reports_the_new_readings_the_copies_and_every_unlisted_one():
-    by_name, unlisted = listed_readings.holds_for(CELL, [], list(NEW) + [f"{name}.{CONFIG}" for name in COPIED])
-    readers = Manifest().layer_readers()
+def test_the_cell_reports_the_new_readings_each_listed_one_and_every_unlisted_one():
+    m = Manifest()
+    by_name, unlisted = listed_readings.holds_for(CELL, LISTED, NEW)
+    readers = m.layer_readers()
     assert len(unlisted) >= 30 and {"step.mfu_pct", "kernels.flash_ms", "kernels.flash_roofline", "step.product_floor_ms",
                                     "step.xla_remat_ms", "compile.traces"} <= unlisted
     for name in NEW:
@@ -76,16 +77,26 @@ def test_the_cell_reports_the_new_readings_the_copies_and_every_unlisted_one():
     assert by_name["kernels.flash_window_roofline"]["unit"] == "%" and by_name["kernels.flash_window_roofline"]["better"] == "higher"
     assert {by_name[n]["layer"] for n in (NEW[0], NEW[1], NEW[4])} == {"window attention"}
     assert by_name[NEW[2]]["layer"] == by_name[NEW[3]]["layer"] == "kernels"
-    for name in COPIED:  # a copy is the listed entry under the cell's name, read by the listed reader
-        copy, listed = by_name[f"{name}.{CONFIG}"], by_name[name]
-        assert copy == {**listed, "name": copy["name"], "workloads": [CELL]} and CELL not in listed["workloads"]
-        assert listed["workloads"] == listed_readings.TABLE.get(name, listed["workloads"])
-        assert readers[copy["name"]].read.__code__.co_filename == readers[name].__file__  # `read = listed.read`
-        assert readers[copy["name"]].META == {k: v for k, v in copy.items() if k != "workloads"}
-    m = Manifest()
     assert [e["name"] for e in m.metrics_for(CELL, "end_to_end")] == ["tokens_per_s_per_chip", "setup_s"]
-    # 107 with this cell: what a copy widened once and then twice more holds is the cap's 128 (a widened copy holds more)
-    assert 107 <= len(m.data["per_layer"]) <= 128
+
+
+@pytest.mark.parametrize("name", JOINED)
+def test_a_reading_the_cell_joined_reads_a_number_in_every_recorded_traced_run(name):
+    """An entry lists a cell only where its reader returns a value in every traced run of that cell (a listed reading
+    that comes back `null` refuses the next `benchmark` PR): the lines of three traced runs of this cell on the chip,
+    and what the span and counter readers read there, through the readers as they stand."""
+    with open(os.path.join(REPO, "benchmark", "testdata", "trinity_traced_lines.json")) as fh:
+        recorded = json.load(fh)
+    m = Manifest()
+    reader, mine = m.layer_readers()[name], [e["name"] for e in m.metrics_for(CELL, "per_layer") if "workloads" in e]
+    assert recorded["cell"] == CELL and name in mine and len({run["seed"] for run in recorded["runs"]}) >= 3
+    for run in recorded["runs"]:
+        line = run["metrics"]
+        assert run["correct"] is True and run["device"]["platform"] == "tpu"
+        assert isinstance(line[name], float) and 0 < line[name] < float("inf")
+        assert set(mine) <= set(line) and not [n for n in line if n.endswith("." + CONFIG)]
+        if name not in ("host.report_put_ms", "moe.router_ms", "moe.dispatch_ms"):  # those three read the raw trace
+            assert reader.read({"summary": run["summary"], "device_trace": None}) == line[name]
 
 
 def test_the_file_holds_every_published_key_and_cuts_counts_and_no_width(config):
@@ -202,8 +213,8 @@ def test_the_new_readers_return_nothing_on_a_program_without_the_scopes(named_ru
                summary={**named_run["summary"], "device": {"count": CHIPS}, "span_ms_per_step": {"data_wait": 0.25}},
                peaks=PEAKS)
     assert [readers[name].read(run) for name in NEW] == [None] * len(NEW)
-    assert readers[f"moe.shared_ms.{CONFIG}"].read(run) is None  # GPT-2 has no scope `shared_expert`
-    assert readers[f"step.dense_mlp_ms.{CONFIG}"].read(run) is None
+    assert readers["moe.shared_ms"].read(run) is None  # GPT-2 has no scope `shared_expert`
+    assert readers["step.dense_mlp_ms"].read(run) is None
     untraced = dict(run, device_trace=None)
     untraced.pop("program_trace", None)
     assert [readers[name].read(untraced) for name in NEW] == [None] * len(NEW)

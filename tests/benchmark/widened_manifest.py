@@ -74,6 +74,36 @@ def _metric(name, unit, better, source, layer):
             "moves": "tokens_per_s_per_chip"}
 
 
+PER_LAYER_CAP = 128  # the contract's, on the `per_layer` of the live `BENCHMARK.json`
+REHEARSALS_TAG = "throwaway"  # what the name of everything `widen` appends starts with, its cell's too
+
+
+def rehearsals_own(per_layer):
+    """The entries a rehearsal appended, nobody's readings: those that list a `throwaway*` cell."""
+    return [e for e in per_layer if any(cell.startswith(REHEARSALS_TAG) for cell in e.get("workloads", ()))]
+
+
+def room(per_layer, appended):
+    """How many entries a `model_config` PR may still append; under 0, how many are over. The one count of it: the
+    entries that are not a rehearsal's own, with two rehearsals of `appended` entries each beside them
+    (`test_benchmark_olmo_hybrid.py` widens twice), against the contract's cap. So it is one number on the live
+    manifest and on a copy widened before, where `test_benchmark_widening.py` runs this directory's tests again:
+    128 - 2 x 7 = 114 may be held."""
+    return PER_LAYER_CAP - 2 * appended - (len(per_layer) - len(rehearsals_own(per_layer)))
+
+
+def hold_the_room(per_layer, appended):
+    """The one bound on `per_layer`, held by `test_benchmark_manifest.py` alone."""
+    left, may_hold = room(per_layer, appended), PER_LAYER_CAP - 2 * appended
+    assert left >= 0, (
+        f"`per_layer` holds {may_hold - left} entries (a rehearsal's own `{REHEARSALS_TAG}*` entries not counted) and "
+        f"may hold {PER_LAYER_CAP} - 2 x {appended} = {may_hold}: the contract's cap of {PER_LAYER_CAP} less the two "
+        f"widenings of {appended} entries each that `test_benchmark_olmo_hybrid.py` rehearses; {-left} over, so a "
+        f"`model_config` PR may append none. No `model_config` PR can make room: a `benchmark` PR does, by folding the "
+        f"`<metric>.<configuration>` copies accepted since the last one into the listed entries' `workloads` and "
+        f"deleting their reader files (PERF.md section 4, `tests/benchmark/listed_readings.py`)")
+
+
 def files_under(root):
     return sorted(os.path.join(d, f) for d, _, fs in os.walk(root) for f in fs)
 
@@ -96,7 +126,7 @@ def widen(root, base=REPO):
     os.symlink(os.path.join(REPO, "ray_tpu"), os.path.join(root, "ray_tpu"))
     bench = os.path.join(root, "benchmark")
     before = {p: open(p, "rb").read() for p in files_under(bench)}
-    tag = next(t for t in ("throwaway", "throwaway2", "throwaway3")
+    tag = next(t for t in (REHEARSALS_TAG, REHEARSALS_TAG + "2", REHEARSALS_TAG + "3")
                if not os.path.exists(os.path.join(bench, "configs", t + "-cut.json")))
     name, mix_name, model, cell = f"{tag}-cut", f"{tag}-short-docs", f"{tag}_moe", f"{tag}-cut.short"
     added = set()
