@@ -1,0 +1,8 @@
+"""`step.dense_mlp_ms` in `xing4-29b-a4b-ep8-l5.fed4k`: that entry lists its cells and a later cell cannot
+append itself, so the cell brings the same reading under a name of its own, until a
+`benchmark` PR puts the cell on that entry's list and deletes this file."""
+
+from benchmark.layer_metrics import step_dense_mlp_ms as listed
+
+META = {**listed.META, "name": "step.dense_mlp_ms.xing4-29b-a4b-ep8-l5"}
+read = listed.read

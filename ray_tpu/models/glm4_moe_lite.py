@@ -30,8 +30,7 @@ the source; weights here are random). There is no auxiliary loss: the source
 balances by moving `expert_bias` outside the loss (`topk_method` `noaux_tc`);
 that rule is not in its `config.json`, and here the bias is a seeded buffer
 that no optimizer step changes (`frozen_params`). Key and value heads are both
-256 wide here, which is what the attention kernels take (ROADMAP B4: widths
-that differ).
+256 wide here; `xing4.py` runs this attention with keys of 192 and values of 128.
 """
 
 from __future__ import annotations
@@ -89,7 +88,6 @@ class GLM4MoELiteConfig:
 
     def __post_init__(self):
         assert self.n_predict_layers in (0, 1), "one prediction module is the only depth written"
-        assert self.head_dim == self.v_head_dim, "keys and values of one width (ROADMAP B4)"
 
     @property
     def head_dim(self) -> int:
@@ -157,8 +155,8 @@ def train_flops_per_token(config: GLM4MoELiteConfig, seq_len: int) -> float:
     """6 FLOPs per matmul parameter a token meets here (of its
     `experts_per_token` experts the share `held / n_experts` that this chip
     computes, in expectation; the head once more for the prediction module)
-    plus full-square attention at the heads' full width in every attention
-    call, as `gpt.py` counts."""
+    plus full-square attention in every attention call, q . k at the keys'
+    width and p . v at the values', as `gpt.py` counts where they are one."""
     per_expert = 3 * config.d_model * config.d_expert
     pairs_here = config.experts_per_token * config.held / config.n_experts
     moe = _kind_params(config, MOE)["matmul"] + pairs_here * per_expert
@@ -169,7 +167,7 @@ def train_flops_per_token(config: GLM4MoELiteConfig, seq_len: int) -> float:
     if config.n_predict_layers:
         active += 2 * config.d_model * config.d_model + moe + head
         calls += 1
-    return 6.0 * active + 12.0 * calls * config.n_head * config.head_dim * seq_len
+    return 6.0 * active + 6.0 * calls * config.n_head * (config.head_dim + config.v_head_dim) * seq_len
 
 
 # --------------------------------------------------------------------------- init
@@ -209,13 +207,15 @@ def _layer_shapes(config: GLM4MoELiteConfig, kind: str):
     return shapes
 
 
-def _tree(config: GLM4MoELiteConfig, leaf: Callable, layers: Optional[Callable] = None):
+def _tree(config: GLM4MoELiteConfig, leaf: Callable, layers: Optional[Callable] = None,
+          layer_shapes: Callable = _layer_shapes):
     """`stack.lm_tree` of this model, a tree like the parameters' (`leaf(name,
     shape, init, axes)` for every leaf, a layer's through `layers(kind, i,
     stack)` where `init_params` brings it), and the prediction module beside
-    it, its layer as layer `n_layer`."""
+    it, its layer as layer `n_layer`. `layer_shapes`: a model's own table of a
+    layer's leaves where it adds some to this one's (`xing4.py`)."""
     d, n_moe = config.d_model, config.n_layer - config.n_dense_layers
-    shapes = functools.partial(_layer_shapes, config)
+    shapes = functools.partial(layer_shapes, config)
     layers = layers or (lambda kind, i, stack: per_leaf(shapes(kind), leaf, stack))  # the prediction module's too
     norm = lambda name: leaf(name, (d,), "ones", (None,))  # noqa: E731
     tree = lm_tree(config, ((DENSE,) * config.n_dense_layers, (MOE,), n_moe, ()), shapes, leaf, layers, head="lm_head")
@@ -230,87 +230,109 @@ def _tree(config: GLM4MoELiteConfig, leaf: Callable, layers: Optional[Callable] 
     return tree
 
 
-def init_params(config: GLM4MoELiteConfig, key) -> Dict[str, Any]:
+def init_params(config: GLM4MoELiteConfig, key, layer_shapes: Callable = _layer_shapes) -> Dict[str, Any]:
     pd = config.param_dtype
     k_leaves, k_layers = jax.random.split(key)
     keys = (jax.random.fold_in(k_leaves, n) for n in itertools.count())  # one a leaf, in `_tree`'s order
     return _tree(
         config,
         lambda name, shape, init, axes: draw(next(keys), shape, init, pd),
-        lambda kind, i, stack: draw_layer(jax.random.fold_in(k_layers, i), _layer_shapes(config, kind), stack, pd))
+        lambda kind, i, stack: draw_layer(jax.random.fold_in(k_layers, i), layer_shapes(config, kind), stack, pd),
+        layer_shapes)
 
 
-def param_logical_axes(config: GLM4MoELiteConfig) -> Dict[str, Any]:
-    return _tree(config, lambda name, shape, init, axes: axes)
+def param_logical_axes(config: GLM4MoELiteConfig, layer_shapes: Callable = _layer_shapes) -> Dict[str, Any]:
+    return _tree(config, lambda name, shape, init, axes: axes, layer_shapes=layer_shapes)
 
 
-def frozen_params(config: GLM4MoELiteConfig) -> Dict[str, Any]:
+def frozen_params(config: GLM4MoELiteConfig, layer_shapes: Callable = _layer_shapes) -> Dict[str, Any]:
     """True at the leaves that are buffers and no parameters (`expert_bias`):
     `make_train_step` applies no update to them, weight decay included."""
-    return _tree(config, lambda name, shape, init, axes: name == "expert_bias")
+    return _tree(config, lambda name, shape, init, axes: name == "expert_bias", layer_shapes=layer_shapes)
 
 
 # --------------------------------------------------------------------------- forward
+# A block's four sublayers as functions of the sublayer's input alone, with no residual sum: this model adds each to
+# its one stream, `xing4.py` hands each to a mix of four (`ops/hyper_connections.py`). `config` is either model's.
+def latent_qkv(x, layer, config, cos, sin):
+    """Latent attention up to the kernel on x (B, S, D), not yet normed: its norm, both down-projections, their
+    norms, both up-projections, the rotation and the broadcast of the shared rotary key: q, k (B, heads, S, nope +
+    rope) and v (B, heads, S, `v_head_dim`). The up-projections' columns are sliced on the weights, so no activation
+    is split off a lane boundary."""
+    cdt, eps = config.dtype, config.norm_eps
+    nope, kvl = config.qk_nope_head_dim, config.kv_lora_rank
+    h = rms_norm(x, layer["attn_norm"], eps).astype(cdt)
+    c_q = jnp.einsum("bsd,dr->bsr", h, layer["wq_a"].astype(cdt))
+    c_q = rms_norm(c_q, layer["q_a_norm"], eps).astype(cdt)
+    wq_b = layer["wq_b"].astype(cdt)
+    q_n = jnp.einsum("bsr,rnh->bnsh", c_q, wq_b[..., :nope])
+    q_r = jnp.einsum("bsr,rnh->bnsh", c_q, wq_b[..., nope:])
+    kv = jnp.einsum("bsd,dr->bsr", h, layer["wkv_a"].astype(cdt))
+    c_kv = rms_norm(kv[..., :kvl], layer["kv_a_norm"], eps).astype(cdt)
+    k_r = apply_rope(kv[:, None, :, kvl:], cos, sin)  # (B, 1, S, rope): one for all heads
+    wkv_b = layer["wkv_b"].astype(cdt)
+    k_n = jnp.einsum("bsr,rnh->bnsh", c_kv, wkv_b[..., :nope])
+    v = jnp.einsum("bsr,rnh->bnsh", c_kv, wkv_b[..., nope:])
+    q = jnp.concatenate([q_n, apply_rope(q_r, cos, sin)], axis=-1)
+    k = jnp.concatenate([k_n, jnp.broadcast_to(k_r, q_r.shape)], axis=-1)
+    return q, k, v
+
+
+def attention_out(o, layer, config):
+    """W_o on the heads' output o (B, heads, S, `v_head_dim`): (B, S, D)."""
+    return jnp.einsum("bnsh,nhd->bsd", o.astype(config.dtype), layer["wo"].astype(config.dtype))
+
+
+def dense_ffn(x, layer, config):
+    """The leading layers' feed-forward on x (B, S, D), not yet normed: its norm and one SwiGLU of `d_ff`."""
+    h = rms_norm(x, layer["ffn_norm"], config.norm_eps).astype(config.dtype)
+    return swiglu(h, layer["w_gate"], layer["w_up"], layer["w_down"])
+
+
+def moe_ffn(x, layer, config):
+    """An expert layer's feed-forward on x (B, S, D), not yet normed: (the held routed experts' part, the shared
+    expert's, what `moe_mlp` reports of the routing)."""
+    h = rms_norm(x, layer["ffn_norm"], config.norm_eps).astype(config.dtype)
+    moe = layer["moe"]
+    routed, aux = moe_mlp(
+        h, moe["router_w"], moe["w_gate"], moe["w_up"], moe["w_down"],
+        k=config.experts_per_token, norm_topk_prob=config.norm_topk_prob,
+        router_bias=moe["expert_bias"], weight_scale=config.routed_scaling_factor,
+        held_from=config.first_expert_held)
+    shared = shared_expert(h, moe["shared_gate"], moe["shared_up"], moe["shared_down"])
+    return routed, shared, aux
+
+
 def _kinds(config: GLM4MoELiteConfig, stats: bool = False):
     """`stack.Pattern.kinds`: the parts of each kind of layer. x: (B, S, D);
     cos/sin: this rank's rows of the rotary tables (over `qk_rope_head_dim`).
     An `out_part` returns (x, aux): a zero, or with `stats` what `moe_mlp`
     reports of the layer (nothing for a dense one). The scope names are read
     from the compiled program's `op_name`s (PERF.md, "names")."""
-    cdt, eps = config.dtype, config.norm_eps
-    nope, kvl = config.qk_nope_head_dim, config.kv_lora_rank
 
     def qkv_part(x, layer, cos, sin):
-        """Both down-projections, their norms, both up-projections, the
-        rotation and the broadcast of the shared rotary key: q, k, v (B, heads,
-        S, 256). The up-projections' columns are sliced on the weights, so no
-        activation is split off a lane boundary."""
         with jax.named_scope("mla_latent"):
-            h = rms_norm(x, layer["attn_norm"], eps).astype(cdt)
-            c_q = jnp.einsum("bsd,dr->bsr", h, layer["wq_a"].astype(cdt))
-            c_q = rms_norm(c_q, layer["q_a_norm"], eps).astype(cdt)
-            wq_b = layer["wq_b"].astype(cdt)
-            q_n = jnp.einsum("bsr,rnh->bnsh", c_q, wq_b[..., :nope])
-            q_r = jnp.einsum("bsr,rnh->bnsh", c_q, wq_b[..., nope:])
-            kv = jnp.einsum("bsd,dr->bsr", h, layer["wkv_a"].astype(cdt))
-            c_kv = rms_norm(kv[..., :kvl], layer["kv_a_norm"], eps).astype(cdt)
-            k_r = apply_rope(kv[:, None, :, kvl:], cos, sin)  # (B, 1, S, rope): one for all heads
-            wkv_b = layer["wkv_b"].astype(cdt)
-            k_n = jnp.einsum("bsr,rnh->bnsh", c_kv, wkv_b[..., :nope])
-            v = jnp.einsum("bsr,rnh->bnsh", c_kv, wkv_b[..., nope:])
-            q = jnp.concatenate([q_n, apply_rope(q_r, cos, sin)], axis=-1)
-            k = jnp.concatenate([k_n, jnp.broadcast_to(k_r, q_r.shape)], axis=-1)
-            return q, k, v
+            return latent_qkv(x, layer, config, cos, sin)
 
-    def attention_out(x, o, layer):
-        with jax.named_scope("attn_out"):
-            return x + jnp.einsum("bnsh,nhd->bsd", o.astype(cdt), layer["wo"].astype(cdt))
-
-    def dense_ffn(x, layer):
+    def dense(x, layer):
         with jax.named_scope("dense_mlp"):
-            h = rms_norm(x, layer["ffn_norm"], eps).astype(cdt)
-            return x + swiglu(h, layer["w_gate"], layer["w_up"], layer["w_down"]), None
+            return x + dense_ffn(x, layer, config), None
 
-    def moe_ffn(x, layer):
+    def moe(x, layer):
         with jax.named_scope("moe"):
-            h = rms_norm(x, layer["ffn_norm"], eps).astype(cdt)
-            moe = layer["moe"]
-            routed, aux = moe_mlp(
-                h, moe["router_w"], moe["w_gate"], moe["w_up"], moe["w_down"],
-                k=config.experts_per_token, norm_topk_prob=config.norm_topk_prob,
-                router_bias=moe["expert_bias"], weight_scale=config.routed_scaling_factor,
-                held_from=config.first_expert_held)
-            shared = shared_expert(h, moe["shared_gate"], moe["shared_up"], moe["shared_down"])
+            routed, shared, aux = moe_ffn(x, layer, config)
             return x + routed + shared, aux
 
     def out_part(ffn):
         def part(x, o, layer, rng):
             del rng  # no dropout
-            x, aux = ffn(attention_out(x, o, layer), layer)
+            with jax.named_scope("attn_out"):
+                x = x + attention_out(o, layer, config)
+            x, aux = ffn(x, layer)
             return x, aux if stats else jnp.zeros((), jnp.float32)
         return part
 
-    return {DENSE: (qkv_part, out_part(dense_ffn)), MOE: (qkv_part, out_part(moe_ffn))}
+    return {DENSE: (qkv_part, out_part(dense)), MOE: (qkv_part, out_part(moe))}
 
 
 def pattern(config: GLM4MoELiteConfig, stats: bool = False) -> Pattern:

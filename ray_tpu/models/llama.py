@@ -16,7 +16,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import math
-from typing import Any, Callable, Dict, Optional
+from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -154,16 +154,64 @@ def rms_norm(x, scale, eps):
     return xf * rms * scale
 
 
-def rope_tables(seq_len: int, head_dim: int, theta: float):
+class Yarn(NamedTuple):
+    """YaRN's scaling of the rotary frequencies (Peng et al., arXiv:2309.00071, as DeepSeek-V3's modelling code has
+    it; the names are `rope_scaling`'s keys)."""
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+    @staticmethod
+    def get_mscale(factor: float, mscale: float) -> float:
+        return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+    @property
+    def table_scale(self) -> float:
+        """What cos and sin are multiplied by."""
+        return self.get_mscale(self.factor, self.mscale) / self.get_mscale(self.factor, self.mscale_all_dim)
+
+    @property
+    def softmax_scale(self) -> float:
+        """What the softmax's `head_dim^-1/2` is multiplied by."""
+        return self.get_mscale(self.factor, self.mscale_all_dim) ** 2
+
+    def correction_range(self, dim: int, theta: float) -> Tuple[int, int]:
+        """(low, high): the pairs of columns between which the ramp runs, those that turn `beta_fast` and
+        `beta_slow` times over the original context."""
+        def pair_of(rotations):
+            return dim * math.log(self.original_max_position_embeddings / (rotations * 2 * math.pi)) / (2 * math.log(theta))
+        return max(math.floor(pair_of(self.beta_fast)), 0), min(math.ceil(pair_of(self.beta_slow)), dim - 1)
+
+    def frequencies(self, dim: int, theta: float):
+        """(dim / 2,): the published frequencies (extrapolation) below `low`, those over `factor` (interpolation)
+        above `high`, blended by a linear ramp between."""
+        half = dim // 2
+        extra = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+        low, high = self.correction_range(dim, theta)
+        ramp = jnp.clip((jnp.arange(half, dtype=jnp.float32) - low) / ((high if high != low else high + 0.001) - low), 0, 1)
+        return extra / self.factor * ramp + extra * (1 - ramp)
+
+
+def rope_tables(seq_len: int, head_dim: int, theta: float, yarn: Optional[Yarn] = None):
     """Precomputed (S, head_dim/2) cos/sin tables with GLOBAL positions —
     computed once per forward and passed through the stack as sequence
     streams, so context-parallel shards rotate with their true positions (a
     locally-indexed arange inside the block would restart every CP shard at
-    position 0) and the tables aren't rebuilt per layer under remat."""
+    position 0) and the tables aren't rebuilt per layer under remat. With
+    `yarn`, its frequencies and its scale of both tables."""
     half = head_dim // 2
-    freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    if yarn is None:
+        freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    else:
+        freqs = yarn.frequencies(head_dim, theta)
     angles = jnp.arange(seq_len, dtype=jnp.float32)[:, None] * freqs[None, :]
-    return jnp.cos(angles), jnp.sin(angles)
+    cos, sin = jnp.cos(angles), jnp.sin(angles)
+    if yarn is not None and yarn.table_scale != 1.0:
+        cos, sin = cos * yarn.table_scale, sin * yarn.table_scale
+    return cos, sin
 
 
 def apply_rope(x, cos, sin):

@@ -296,23 +296,27 @@ def apply_stack(
 
 
 def resolve_attention(q, k, v, attention_mode: str, attention_fn: Optional[Callable],
-                      mesh=None):
+                      mesh=None, sm_scale: Optional[float] = None):
     """One attention-backend dispatch for every model family: caller-injected
     fn (ring/Ulysses wrappers) wins; "xla" forces the plain-XLA form; "auto"
     and "flash" take `flash_attention`'s own choice for this platform and
     shape (`ops.flash_attention.select_backend` says which), with the kernel
-    partitioned over `mesh`."""
+    partitioned over `mesh`. `sm_scale`: a model's own scale of the scores in
+    place of `head_dim^-1/2` (a rotation scaled to a longer context, from a
+    kind's own `attend`); a caller's fn takes none."""
     if attention_fn is not None:
+        if sm_scale is not None:
+            raise NotImplementedError("a caller's attention function (ring, Ulysses) takes no softmax scale of the model's")
         return attention_fn(q, k, v)
     from ray_tpu.ops.flash_attention import flash_attention, xla_attention
 
     if attention_mode == "xla":
-        return xla_attention(q, k, v, causal=True)
+        return xla_attention(q, k, v, causal=True, sm_scale=sm_scale)
     if mesh is not None and int(mesh.shape.get("pipeline", 1)) > 1:
         # Inside the pipeline's manual region a second shard_map cannot
         # reopen the mesh; the kernel runs unpartitioned there.
         mesh = None
-    return flash_attention(q, k, v, causal=True, mesh=mesh)
+    return flash_attention(q, k, v, causal=True, sm_scale=sm_scale, mesh=mesh)
 
 
 def lm_head(x, norm: Callable, table, dtype):

@@ -539,7 +539,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, sm_scale, causal, tile_q
     diagonal, unmasked ones first. Unrolled, one program holds a whole
     (batch, head) and visits its Q tiles in turn; in the loop form a program
     is one Q tile (grid axis 1) and `q_ref`, `o_ref`, `lse_ref` are that tile."""
-    seq, d = k_ref.shape[1], k_ref.shape[2]
+    seq, d = k_ref.shape[1], v_ref.shape[2]  # the accumulator is as wide as the values
     n_q, n_k = seq // tile_q, seq // tile_k
     keep = _causal_mask(tile_q, tile_k) if causal else None
 
@@ -614,6 +614,7 @@ def _compiler_params(interpret, *semantics):
 
 def _fwd(q, k, v, causal, sm_scale, plan, interpret):
     bh, seq, d = q.shape
+    dv = v.shape[-1]  # the values' width, and the output's: the keys' own where they differ (`flash_attention`)
     kernel = functools.partial(
         _fwd_kernel, sm_scale=sm_scale, causal=causal,
         tile_q=plan.tile_q, tile_k=plan.tile_k, static=plan.unrolled,
@@ -623,12 +624,12 @@ def _fwd(q, k, v, causal, sm_scale, plan, interpret):
         o, lse = pl.pallas_call(
             kernel,
             grid=(bh, *tiles),
-            in_specs=[mine(d), head(d), head(d)],
+            in_specs=[mine(d), head(d), head(dv)],
             # (bh, seq, 1): TPU block specs constrain the last two dims, so the
             # per-row stats carry a trailing unit dim to stay tileable.
-            out_specs=[mine(d), mine(1)],
+            out_specs=[mine(dv), mine(1)],
             out_shape=[
-                jax.ShapeDtypeStruct((bh, seq, d), q.dtype),
+                jax.ShapeDtypeStruct((bh, seq, dv), q.dtype),
                 jax.ShapeDtypeStruct((bh, seq, 1), jnp.float32),
             ],
             interpret=interpret,
@@ -638,8 +639,8 @@ def _fwd(q, k, v, causal, sm_scale, plan, interpret):
             # told steers its schedule round the call, on four chips visibly,
             # so it changes in a PR of its own (PERF.md section 7).
             cost_estimate=pl.CostEstimate(
-                flops=4 * seq * seq * d,
-                bytes_accessed=3 * seq * d * q.dtype.itemsize + seq * d * q.dtype.itemsize,
+                flops=2 * seq * seq * (d + dv),
+                bytes_accessed=2 * seq * (d + dv) * q.dtype.itemsize,
                 transcendentals=seq * seq,
             ),
         )(q, k, v)
@@ -1123,6 +1124,7 @@ def _bwd_pairs(q, k, v, do, lse, delta, causal, sm_scale, plan, interpret, keep=
     """k and v may hold fewer heads than q (batch * kv heads, seq, d): dk and dv
     still come back a query head each, for the caller to sum over the group."""
     bh, seq, d = q.shape
+    dv = v.shape[-1]  # v, do and dv in the values' own width
     steps = _pair_schedule(seq, plan, causal)
     scopes, short_spans = _walk_scope(steps, seq, plan.tile_q, plan.tile_k), _short_spans(steps, plan.tile_k)
     if not short_spans:  # the kernel reads the rows it read before a schedule named a span
@@ -1130,7 +1132,9 @@ def _bwd_pairs(q, k, v, do, lse, delta, causal, sm_scale, plan, interpret, keep=
     tile = lambda size, width, row: pl.BlockSpec(
         (1, size, width), lambda b, t, steps: (b, steps[row, t], 0))
     q_tile, k_tile, stat = tile(plan.tile_q, d, 0), tile(plan.tile_k, d, 1), tile(plan.tile_q, 1, 0)
+    do_tile, v_tile = tile(plan.tile_q, dv, 0), tile(plan.tile_k, dv, 1)
     kv_tile = k_tile if k.shape[0] == bh else _pair_specs(plan, d, bh // k.shape[0], 0, 1)[1]
+    assert dv == d or k.shape[0] == bh, "values of a width of their own under grouped key/value heads: not written"
     extra = [] if keep is None else [keep]
     keep_specs = [_keep_spec(bh // keep.shape[0], plan.tile_q, plan.tile_k, 0, 1)] if extra else []
     with jax.named_scope(scopes[0]), jax.named_scope(scopes[1]):
@@ -1140,13 +1144,13 @@ def _bwd_pairs(q, k, v, do, lse, delta, causal, sm_scale, plan, interpret, keep=
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=1,
                 grid=(bh, steps.shape[1]),
-                in_specs=[q_tile, kv_tile, kv_tile, q_tile, stat, stat] + keep_specs,
-                out_specs=[tile(plan.tile_q, d, 2), k_tile, k_tile],
+                in_specs=[q_tile, kv_tile, kv_tile if dv == d else v_tile, do_tile, stat, stat] + keep_specs,
+                out_specs=[tile(plan.tile_q, d, 2), k_tile, v_tile],
                 scratch_shapes=[pltpu.VMEM((seq, d), jnp.float32),
                                 pltpu.VMEM((plan.tile_k, d), jnp.float32),
-                                pltpu.VMEM((plan.tile_k, d), jnp.float32)],
+                                pltpu.VMEM((plan.tile_k, dv), jnp.float32)],
             ),
-            out_shape=[jax.ShapeDtypeStruct((bh, seq, d), q.dtype)] * 3,
+            out_shape=[jax.ShapeDtypeStruct((bh, seq, width), q.dtype) for width in (d, d, dv)],
             interpret=interpret,
             name="flash_bwd",
             compiler_params=_compiler_params(interpret, "parallel", "arbitrary"),
@@ -1157,11 +1161,12 @@ def _bwd(causal, sm_scale, plan, interpret, res, g):
     q, k, v, o, lse = res
     do = g
     bh, seq, d = q.shape
+    dv = v.shape[-1]  # v, do and dv in the values' own width
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)[..., None]  # (bh, seq, 1)
     if not plan.unrolled and _streamed_head(seq, d, q.dtype.itemsize):
         return _bwd_pairs(q, k, v, do, lse, delta, causal, sm_scale, plan, interpret)
     tiles, head, mine = _specs(seq, plan, plan.tile_k)
-    whole, stats = head(d), head(1)
+    whole, stats = head, head(1)
     if not plan.unrolled and _long_head(seq, d, q.dtype.itemsize):
         # A head's q, do and row statistics change only when the grid moves to
         # the next head; (seq, 1) in f32 takes a whole lane tile a row in
@@ -1170,25 +1175,24 @@ def _bwd(causal, sm_scale, plan, interpret, res, g):
         # (two buffers: 16.2-16.8 MB inside a step, PR 28).
         once = lambda width: pl.BlockSpec((1, seq, width), lambda b, *_: (b, 0, 0),
                                           pipeline_mode=pl.Buffered(1))
-        whole, stats = once(d), once(1)
+        whole, stats = once, once(1)
     with jax.named_scope(plan.scope):
-        dq, dk, dv = pl.pallas_call(
+        return pl.pallas_call(
             functools.partial(
                 _bwd_kernel, sm_scale=sm_scale, causal=causal,
                 tile_q=plan.tile_q, tile_k=plan.tile_k, static=plan.unrolled,
             ),
             grid=(bh, *tiles),
-            in_specs=[whole, mine(d), mine(d), whole, stats, stats],
+            in_specs=[whole(d), mine(d), mine(dv), whole(dv), stats, stats],
             # In the loop form dq is revisited by every K tile of a head (its
             # index map ignores the tile) and written back when the grid moves on.
-            out_specs=[head(d), mine(d), mine(d)],
-            out_shape=[jax.ShapeDtypeStruct((bh, seq, d), q.dtype)] * 3,
+            out_specs=[head(d), mine(d), mine(dv)],
+            out_shape=[jax.ShapeDtypeStruct((bh, seq, width), q.dtype) for width in (d, d, dv)],
             scratch_shapes=[pltpu.VMEM((seq, d), jnp.float32)],
             interpret=interpret,
             name="flash_bwd",
             compiler_params=_compiler_params(interpret, "parallel", *["arbitrary"] * len(tiles)),
         )(q, k, v, do, lse, delta)
-    return dq, dk, dv
 
 
 # --------------------------------------------------------------------------- blockwise (long-seq XLA)
@@ -1253,7 +1257,7 @@ def _flash_bhsd(q, k, v, causal, sm_scale, plan, interpret):
 
 def _flash_fwd_rule(q, k, v, causal, sm_scale, plan, interpret):
     o, lse = _fwd(_flat(q), _flat(k), _flat(v), causal, sm_scale, plan, interpret)
-    o = o.reshape(q.shape)
+    o = o.reshape(*q.shape[:-1], v.shape[-1])
     return o, (q, k, v, o, lse)
 
 
@@ -1417,7 +1421,10 @@ def flash_attention(
     return_lse: bool = False,
 ):
     """Multi-head attention, (batch, heads, seq, head_dim) layout; k and v may
-    hold fewer heads, each shared by a group of consecutive query heads.
+    hold fewer heads, each shared by a group of consecutive query heads. v's
+    last dimension may be another than q's and k's (latent attention with keys
+    of 192 and values of 128): o then has v's, in the whole-head forward and
+    both backward forms; the plan and the default `sm_scale` are by q's.
 
     causal: True (the diagonal), False (every key), or a mask by structure, a
       static hashable description (`BlockDiffusion`, `SlidingWindow`): the kernels' schedules
@@ -1463,6 +1470,11 @@ def flash_attention(
             "block of at least 128 dividing it; the kernel cannot tile it"
         )
     if pairs:
+        if v.shape[-1] != q.shape[-1]:
+            raise NotImplementedError(
+                f"flash_attention: values {v.shape[-1]} wide under keys of {q.shape[-1]} where the forward pass "
+                "runs a pair a program (a selection, grouped heads, a mask by structure, a head past "
+                "`MAX_HEAD_BYTES`): that form holds one width")
         return _pairs_call(q, k, v, keep, causal, sm_scale, plan, interpret, mesh, return_lse)
 
     def kernel(q, k, v):
