@@ -124,13 +124,15 @@ frozen_params = functools.partial(glm.frozen_params, layer_shapes=_layer_shapes)
 
 
 # --------------------------------------------------------------------------- forward
-def _kinds(config: Xing4Config, stats: bool = False):
+def _kinds(config: Xing4Config, stats: bool = False, mesh=None):
     """`stack.Pattern.kinds`: (qkv_part, out_part, attend) of each kind of layer. x: the streams (B, n, S, D);
     cos/sin: this rank's rows of the rotary tables. An `out_part` returns (x, aux): a zero, or with `stats`
     `{"routing": what `moe_mlp` reports (an expert layer's), "res_sum_err": the largest distance from 1 of a row or
-    column sum of either sublayer's H_res}`."""
+    column sum of either sublayer's H_res}`. `mesh`: `hidden`'s, for the parts that may hold a Mosaic call (the
+    mixes' backward rules) and are handed no mesh by the stack."""
     maps = functools.partial(mhc.maps, norm_eps=config.norm_eps, rounds=config.hc_sinkhorn_iters,
-                             eps=config.hc_eps, clamp=config.hc_clamp)
+                             eps=config.hc_eps, clamp=config.hc_clamp, mesh=mesh)
+    post_res_mix = functools.partial(mhc.post_res_mix, mesh=mesh)
 
     def qkv_part(x, layer, cos, sin):
         h_pre, h_post, h_res = maps(x, **layer["hc_attn"])
@@ -160,11 +162,11 @@ def _kinds(config: Xing4Config, stats: bool = False):
             del rng  # no dropout
             with jax.named_scope("attn_out"):
                 y = glm.attention_out(o, layer, config)
-            x = mhc.post_res_mix(x, y, h_post, h_res)
+            x = post_res_mix(x, y, h_post, h_res)
             err = sum_err(h_res) if stats else None
             h_pre, h_post, h_res = maps(x, **layer["hc_ffn"])
             y, aux = ffn(mhc.pre_mix(x, h_pre), layer)
-            x = mhc.post_res_mix(x, y, h_post, h_res)
+            x = post_res_mix(x, y, h_post, h_res)
             if stats:
                 return x, {"routing": aux, "res_sum_err": jnp.maximum(err, sum_err(h_res))}
             return x, jnp.zeros((), jnp.float32)
@@ -173,8 +175,8 @@ def _kinds(config: Xing4Config, stats: bool = False):
     return {DENSE: (qkv_part, out_part(dense), attend), MOE: (qkv_part, out_part(moe), attend)}
 
 
-def pattern(config: Xing4Config, stats: bool = False) -> Pattern:
-    return Pattern(_kinds(config, stats), (MOE,), config.n_layer - config.n_dense_layers,
+def pattern(config: Xing4Config, stats: bool = False, mesh=None) -> Pattern:
+    return Pattern(_kinds(config, stats, mesh), (MOE,), config.n_layer - config.n_dense_layers,
                    (DENSE,) * config.n_dense_layers)
 
 
@@ -194,7 +196,7 @@ def hidden(params, tokens, config: Xing4Config, attention_fn=None, mesh=None,
            num_microbatches: Optional[int] = None):
     """The streams' sum after the last layer (B, S, D) f32 for `tokens` (B, S), before the final norm."""
     x, _ = apply_stack(
-        params["blocks"], streams_in(params, tokens, config), config, pattern=pattern(config),
+        params["blocks"], streams_in(params, tokens, config), config, pattern=pattern(config, mesh=mesh),
         attention_fn=attention_fn, mesh=mesh, num_microbatches=num_microbatches,
         seq_streams=_streams(tokens.shape[1], config))
     with jax.named_scope("mhc"), jax.named_scope("out"):
