@@ -336,9 +336,16 @@ def lm_head(x, norm: Callable, table, dtype):
 
 def cross_entropy(logits, targets):
     """Each position's cross entropy (B, S) f32, fused: logsumexp - logit[target], one reduction over V
-    instead of materializing the (B, S, V) log-softmax (saves ~2x V-sized HBM traffic)."""
+    instead of materializing the (B, S, V) log-softmax (saves ~2x V-sized HBM traffic). The target's logit
+    is picked by a compare with the vocabulary's iota and a sum, and by no gather: a gather's gradient is a
+    scatter into zeros of the logits' shape, which costs a step of one row a device four logits-sized arrays
+    (PR 68), and this one's is a `where` that XLA fuses into the head's backward products. A target outside
+    [0, V) hits no column and reads a logit of 0, so the entropy is `lse` and the logits' gradient the
+    softmax alone (the gather counted a negative one from the end and read NaN past either end; no mix of the
+    benchmark draws one)."""
     lse = jax.scipy.special.logsumexp(logits, axis=-1)
-    return lse - jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
+    hit = jax.lax.broadcasted_iota(targets.dtype, logits.shape, logits.ndim - 1) == targets[..., None]
+    return lse - jnp.sum(jnp.where(hit, logits, 0), axis=-1)
 
 
 def causal_lm_loss(logits, targets, mask=None, weights=None):

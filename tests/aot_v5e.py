@@ -102,12 +102,17 @@ def by_computation(text):
         yield computation, line
 
 
+def product_computations(text):
+    """The computations that hold a `convolution`: a fusion that calls one is a product."""
+    return {c for c, line in by_computation(text) if " convolution(" in line}
+
+
 def remat_products(text):
     """The products XLA's own rematerialization makes a second time: the instructions `HloRematerialization`
     cloned (`.remat` in their name) that are a `convolution`, or a fusion whose computation holds one. Not
     `jax.checkpoint`'s recomputation (that is under `rematted_computation`, once): a clone is the same
     instruction again, scheduled just before its users because the program did not fit otherwise."""
-    holds_product = {c for c, line in by_computation(text) if " convolution(" in line}
+    holds_product = product_computations(text)
     clones = []
     for _, line in by_computation(text):
         m = RESULT.match(line)
@@ -134,6 +139,31 @@ def written_under(text, scopes, scope, shape):
                 and scope in scopes.get(m.group(1), "").split("/")):
             found.setdefault(phase(scopes[m.group(1)]), []).append(m.group(1))
     return found
+
+
+def logits_sized(text, elements):
+    """The arrays of `elements` elements (a device's rows x tokens x V: its logits' size, whatever their layout)
+    that a compiled step writes to HBM: "<dtype> <opcode>" of every such result outside the fused computations,
+    a fusion's own among them, " product" behind a fusion that holds a `convolution` (the head's own, the
+    logits). Not one with the dimensions of an argument of the step or of what a `while` carries, which happen
+    to be as long: Solar-Open2's tables and their gradients (24,576 x 4,096 beside 4,096 x 24,576 logits),
+    Xing4.0's stacks of a layer's heads (`bf16[4,1,32,4096,128]` beside 4,096 x 16,384). Beside the logits stood,
+    where a device holds one row, the float32 d logits, their relayout for the scatter that was the gradient of
+    the loss's gather, the scatter's result and the bf16 copy turned over for the two backward products (PR 68)."""
+    fused, holds_product = fused_computations(text), product_computations(text)
+    entry = re.search(r"^ENTRY %?([\w.\-]+)", text, re.M).group(1)
+    results = [(c, m) for c, m in ((c, RESULT.match(line)) for c, line in by_computation(text)) if m]
+    held = {dims for m in WHILE.finditer(text) for _, dims in ARRAY.findall(m.group(1))}
+    held |= {dims for c, m in results if c == entry and m.group(3) == "parameter" for _, dims in ARRAY.findall(m.group(2))}
+    found = []
+    for computation, m in results:
+        if computation in fused or m.group(3) in MOVES_NOTHING:
+            continue
+        calls = FUSED.search(m.string)
+        product = m.group(3) == "convolution" or bool(calls and calls.group(1) in holds_product)
+        found += [f"{dtype} {m.group(3)}" + " product" * product for dtype, dims in ARRAY.findall(m.group(2))
+                  if dims not in held and math.prod(int(d) for d in dims.split(",")) == elements]
+    return sorted(found)
 
 
 def operand_converts(text, scopes, shapes):
@@ -583,6 +613,10 @@ def _step_case(topo, cell):
         "remat_products": len(remat_products(text)),
         "remat_clones": sorted(m.group(1) for m in RESULT.finditer(text) if ".remat" in m.group(1)),
     }
+    from ray_tpu.parallel import batch_spec
+
+    devices = math.prod((c["layout"]["mesh"] or {}).get(axis, 1) for axis in batch_spec()[0])  # the rows' axes
+    out["logits_sized"] = logits_sized(text, rows // devices * seq * cfg.vocab_size)
     out["stacks"], out["stacked_bytes"] = layer_stacks(text)
     out["compute_copy_bytes"] = sum(math.prod(x.shape) * x.dtype.itemsize for x in copies)
     out["operand_converts"] = operand_converts(text, scopes, [x.shape for x in copies])
@@ -790,6 +824,14 @@ def rounds_the_experts_matrices_in_the_optimizer_alone(got, parents):
     found = got["operand_converts"]
     assert found["elsewhere"] == [], f"{len(found['elsewhere'])} outside `optimizer` (the parent's step: {parents}): {found}"
     assert got["compute_copy_bytes"] > 0 and len(found["optimizer"]) >= 3, found
+
+
+def holds_the_logits_alone(got):
+    """PR 68: of a step case whose device holds one row, nothing of the logits' size stands beside the logits, the
+    head's own float32 product (`logits_sized`). The parent's steps held four more, in every one of these cells
+    `["bf16 reshape", "f32 copy", "f32 fusion", "f32 fusion"]`: d logits in float32, their relayout, the scatter's
+    result (the gradient of the loss's gather) and the bf16 copy turned over for the two backward products."""
+    assert got["logits_sized"] == ["f32 fusion product"], got["logits_sized"]
 
 
 def stacks_ending(got, tail):

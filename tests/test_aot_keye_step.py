@@ -65,6 +65,12 @@ def test_the_keye_step_holds_no_clone_of_a_product_and_its_scan_stacks_o_once(ao
     assert got["peak"] <= 13_726_358_016 + (8 << 20) and got["recomputed"] <= 480
 
 
+def test_nothing_of_the_logits_size_stands_beside_the_logits(aot):
+    """16,384 x 18,992: until PR 68 `fusion.435`, `copy.578` (6.49 ms a step on the chip: PERF.md section 5, PR 53),
+    `fusion.11` and `reshape.2166` beside the head's product; 9,932 instructions for 10,028, the peak the same."""
+    aot_v5e.holds_the_logits_alone(aot(KEYE))
+
+
 def test_no_pass_rounds_an_expert_matrix_outside_the_optimizer(aot):
     """The parent's step cast the three stacked matrices once, hoisted out of the layer loop."""
     aot_v5e.rounds_the_experts_matrices_in_the_optimizer_alone(aot(KEYE), 3)

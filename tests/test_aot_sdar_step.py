@@ -57,6 +57,12 @@ def test_the_sdar_step_holds_no_clone_of_a_product_and_its_scan_stacks_o_once(ao
     assert got["recomputed"] <= 407
 
 
+def test_nothing_of_the_logits_size_stands_beside_the_logits(aot):
+    """8,192 noised positions x 18,992 through `causal_lm_loss(weights=)`: until PR 68 `fusion.322`, `copy.524`,
+    `fusion.9` and `reshape.1703` beside the head's product; 10,114 instructions for 10,180, the peak the same."""
+    aot_v5e.holds_the_logits_alone(aot(SDAR))
+
+
 def test_no_pass_rounds_an_expert_matrix_outside_the_optimizer(aot):
     """The parent's step cast the three stacked matrices once, hoisted out of the layer loop."""
     aot_v5e.rounds_the_experts_matrices_in_the_optimizer_alone(aot(SDAR), 3)

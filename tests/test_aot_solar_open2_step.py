@@ -75,6 +75,13 @@ def test_the_step_fits_the_chip_with_nine_tenths_of_a_gigabyte_to_spare(aot):
     assert got["remat_products"] == 0 and got["recomputed"] <= 358  # the parent's
 
 
+def test_nothing_of_the_logits_size_stands_beside_the_logits(aot):
+    """4,096 x 24,576: until PR 68 `fusion.491`, `copy.811`, `fusion.63` and `reshape.697` beside the head's product;
+    26,287 instructions for 26,396, the peak the same. (The tables and their gradients, 24,576 x 4,096, are as long and
+    are not the loss's: `aot_v5e.logits_sized` leaves an argument's dimensions out; ISSUE 68's one bf16 array.)"""
+    aot_v5e.holds_the_logits_alone(aot["step:" + SOLAR])
+
+
 def test_no_pass_rounds_an_expert_matrix_outside_the_optimizer(aot):
     """The parent's step cast each of the twelve matrices forward and again backward, in both forms of a layer."""
     aot_v5e.rounds_the_experts_matrices_in_the_optimizer_alone(aot["step:" + SOLAR], 48)

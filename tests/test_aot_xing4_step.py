@@ -54,7 +54,11 @@ def test_the_mixes_backward_rules_are_two_mosaic_kernels_a_sublayer(aot):
 def test_the_step_fits_the_chip_without_a_memory_lever(aot):
     """759.3 M parameters x 12 B and the 352.3 M held expert parameters' bf16 copy are the arguments (the gradient is a
     temporary); XLA's peak stands inside ISSUE 66's 15.5 GB of the chip's 16.91 with every head and `save_attn`, and
-    since PR 67 under 15.0 (the rules' backward keeps no float32 copy of the streams: 14,987,682,816 B)."""
+    since PR 67 under 15.0 (the rules' backward keeps no float32 copy of the streams: 14,987,682,816 B). Restated at
+    PR 68, on purpose: without the loss's four arrays XLA's rematerialization clones one product where it cloned three
+    (`fusion.1392.remat` alone: the head's `fusion.2183.remat`, 3.33 ms a step, and `fusion.1469.remat` are gone), and
+    what it no longer makes twice it holds: 15,004,393,472 B, 4.4 MB over 15.0e9 and under the recorded 15,146,253,312;
+    so the line holds the clones to one and the peak to 15.01e9."""
     got = aot(XING4)
     assert got["compute_copy_bytes"] == HELD_EXPERT_PARAMETERS * 2 == 704_643_072
     state = got["argument"] - got["compute_copy_bytes"]
@@ -63,8 +67,8 @@ def test_the_step_fits_the_chip_without_a_memory_lever(aot):
     with open(os.path.join(aot_v5e.REPO, "benchmark", "configs", XING4 + ".json")) as fh:
         recorded = json.load(fh)["memory_analysis_v5e_bytes"]
     assert got["argument"] == recorded["arguments"] and got["peak"] <= recorded["peak_memory"]
-    assert got["peak"] <= 15.0e9
-    assert got["remat_products"] <= 4 and len(got["remat_clones"]) <= 16
+    assert got["peak"] <= 15.01e9
+    assert got["remat_products"] <= 1 and len(got["remat_clones"]) <= 16
 
 
 def test_a_layer_saves_the_streams_once_and_attentions_operands_at_their_own_widths(aot):
@@ -76,6 +80,12 @@ def test_a_layer_saves_the_streams_once_and_attentions_operands_at_their_own_wid
     assert stacks["bf16[4,1,32,4096,192]"] == 2 and stacks["bf16[4,1,32,4096,128]"] == 2
     assert stacks["f32[4,32,4096,1]"] == 1 and stacks["f32[4,4,1,4096]"] == 1 and stacks["f32[4,4,4,1,4096]"] == 1
     assert aot(XING4)["stacked_bytes"] < 3.6e9
+
+
+def test_nothing_of_the_logits_size_stands_beside_the_logits(aot):
+    """4,096 x 16,384: until PR 68 d logits, their relayout, the scatter's `f32[67108864]`, the bf16 copy and the
+    head's clone `fusion.2183.remat` beside the head's `fusion.2183`."""
+    aot_v5e.holds_the_logits_alone(aot(XING4))
 
 
 def test_no_pass_rounds_an_expert_matrix_outside_the_optimizer(aot):

@@ -65,10 +65,14 @@ SCOPES = SHARED + OWN["gpt"] + ("grad_norm",)
 # handed four stacks of a head's size (q, k, v, o) and not five (o again under the kernels' own (batch * heads,
 # seq, d)). Until then 3,174 instructions / 9,234,833,920 B of temporaries (a peak of 10,620,071,936 B; now
 # 10,234,195,968) and, on four chips, 3,710 / 9,007,949,312 (11,844,459,520; now 10,586,168,320).
+# Pinned again at PR 68, on purpose: the loss picks the target's logit by a compare and a sum where it gathered it, so
+# the gather, its gradient's scatter and what fed them are fewer and smaller fusions: 3,096 -> 2,995 instructions and
+# - 258,048 B of temporaries, on four chips 3,686 -> 3,590 and - 64,512 B; both peaks to the byte (several rows a
+# device: XLA never made the scatter's logits-sized arrays here, `logits_sized` reads the logits alone on both sides).
 PARENT = {
-    "gpt2-medium": {"instructions": 3096, "argument": 4259378176, "temp": 8461565952,
+    "gpt2-medium": {"instructions": 2995, "argument": 4259378176, "temp": 8461307904,
                     "output": 4259343360, "alias": 4259341312},
-    "gpt2-xl-fsdp4": {"instructions": 3686, "argument": 4714580992, "temp": 7749559296,
+    "gpt2-xl-fsdp4": {"instructions": 3590, "argument": 4714580992, "temp": 7749494784,
                       "output": 4714564608, "alias": 4714562560},
 }
 # The residuals of a head's size that each cell's backward layer loop carries (`aot_v5e.layer_stacks`): q, k, v and

@@ -12,9 +12,11 @@ CELL = CONFIG + ".fed4k"
 
 
 def test_the_cells_cpu_rehearsal_prints_the_contracts_line():
+    # A window of 4 s: four worker processes beside the suite's six take a second a step (PR 68's whole run counted 2
+    # steps in 2 s where the file alone counts 4, on the parent's tree too), and the line wants more than two.
     proc = subprocess.run(
         [sys.executable, "benchmark/run.py", "--workload", CELL, "--seed", "2147493039",
-         "--seconds", "2", "--trace", "1", "--rehearse-cpu"],
+         "--seconds", "4", "--trace", "1", "--rehearse-cpu"],
         cwd=REPO, capture_output=True, text=True, timeout=400)
     assert proc.returncode == 0, proc.stdout[-3000:] + proc.stderr[-3000:]
     line = json.loads(proc.stdout.strip().splitlines()[-1])
