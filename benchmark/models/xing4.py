@@ -86,6 +86,21 @@ def mhc_mix_bytes_per_step(c: Dict[str, Any], rows: int, seq: int) -> float:
     return 3.0 * forward * sublayers(c)
 
 
+def mhc_bwd_bytes_per_step(c: Dict[str, Any], rows: int, seq: int) -> float:
+    """Bytes the mixes' two backward kernels must move in a step, every operand read once and every result written once,
+    the streams, y and their gradients in `dtype`'s 2 B, a token's map values and Phi in float32. `mhc_post_bwd` (all of
+    `post_res_mix`'s gradient) reads the cotangent of X', X and y and writes dX and dy, 3 n + 2 planes, beside a token's
+    n + n^2 values of H_post and H_res in and their gradients out; `mhc_pre_bwd` (the gradient of the maps' product and
+    norm) reads X and writes dX, 2 n planes, beside a token's n^2 + 2 n columns of r dm and its norm's coefficient in,
+    and Phi in and dPhi out once a call. Not `pre_mix`'s rule nor the sum of the streams' three cotangents, which are
+    XLA's: of the 1.23 GB a sublayer's backward moves (PR 67) the kernels' part is 0.65 GB."""
+    n, d = c["hc_mult"], c["hidden_size"]
+    tokens, maps = rows * seq, n * n + 2 * n
+    post = (3 * n + 2) * tokens * d * 2 + 2 * tokens * (n + n * n) * 4
+    pre = 2 * n * tokens * d * 2 + tokens * (maps + 1) * 4 + 2 * maps * n * d * 4
+    return float(post + pre) * sublayers(c)
+
+
 # ---------------------------------------------------------------------- system
 def xing4_config(c: Dict[str, Any]):
     import jax.numpy as jnp

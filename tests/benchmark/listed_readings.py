@@ -1,5 +1,5 @@
 """The readings that list their cells, as the accepted benchmark holds them (PR 50; PR 56 put the
-Olmo-Hybrid cell on them, PR 65 Solar-Open2's and Trinity-Mini's): one entry and one reader file a
+Olmo-Hybrid cell on them, PR 65 Solar-Open2's and Trinity-Mini's, PR 69 Xing4.0's): one entry and one reader file a
 reading, its `workloads` every accepted cell that reports it. An entry that lists
 its cells takes no later cell and only a `benchmark` PR may edit it, so a later configuration brings
 such a reading as a copy, `<metric>.<configuration>` (`widened_manifest.widen()` rehearses that), and
@@ -17,9 +17,9 @@ from benchmark.harness.manifest import Manifest  # noqa: E402
 MEDIUM_RESIDENT, MEDIUM_FED, XL = "gpt2-medium.resident", "gpt2-medium.fed", "gpt2-xl-fsdp4.fed"
 OLMOE, LFM2, GLM = "olmoe-1b-7b-l1.fed4k", "lfm2-24b-a2b-ep8-l5.fed4k", "glm-4.7-flash-ep8-l5.fed4k"
 KEYE, SDAR, OLMO_HYBRID = "keye-vl-2.0-30b-a3b-ep8.fed16k", "sdar-30b-a3b-chat-ep8.fed8k", "olmo-hybrid-7b-fsdp4.fed4k"
-SOLAR, TRINITY = "solar-open2-250b-ep40-l4.fed4k", "trinity-mini-ep16-l5.fed16k"
-FED = [MEDIUM_FED, XL, OLMOE, LFM2, GLM, KEYE, SDAR, OLMO_HYBRID, SOLAR, TRINITY]
-EXPERTS = [OLMOE, LFM2, GLM, KEYE, SDAR, SOLAR, TRINITY]
+SOLAR, TRINITY, XING4 = "solar-open2-250b-ep40-l4.fed4k", "trinity-mini-ep16-l5.fed16k", "xing4-29b-a4b-ep8-l5.fed4k"
+FED = [MEDIUM_FED, XL, OLMOE, LFM2, GLM, KEYE, SDAR, OLMO_HYBRID, SOLAR, TRINITY, XING4]
+EXPERTS = [OLMOE, LFM2, GLM, KEYE, SDAR, SOLAR, TRINITY, XING4]
 GANGS = [XL, OLMO_HYBRID]  # the four-chip cells: four one-chip workers joined by `jax.distributed`
 TABLE = {
     **dict.fromkeys(("data.wait_ms", "host.h2d_ms", "host.report_ms", "host.report_put_ms"), FED),
@@ -32,10 +32,11 @@ TABLE = {
     "host.stall_pct": [MEDIUM_RESIDENT, MEDIUM_FED, OLMOE],
     **dict.fromkeys(("moe.router_ms", "moe.dispatch_ms", "moe.experts_ms", "moe.experts_roofline",
                      "moe.load_max_over_mean", "kernels.gmm_ms", "kernels.gmm_roofline"), EXPERTS),
-    "step.dense_mlp_ms": [LFM2, GLM, OLMO_HYBRID, TRINITY],
-    "moe.held_pairs_share": [LFM2, GLM, KEYE, SOLAR, TRINITY],  # SDAR's is 0.125 by construction: no reading
-    "moe.issued_over_held": [LFM2, GLM, KEYE, SDAR, SOLAR, TRINITY],
-    "moe.shared_ms": [GLM, SOLAR, TRINITY],  # the cells whose expert layers hold an expert every token meets
+    "step.dense_mlp_ms": [LFM2, GLM, OLMO_HYBRID, TRINITY, XING4],
+    "moe.held_pairs_share": [LFM2, GLM, KEYE, SOLAR, TRINITY, XING4],  # SDAR's is 0.125 by construction: no reading
+    "moe.issued_over_held": [LFM2, GLM, KEYE, SDAR, SOLAR, TRINITY, XING4],
+    "moe.shared_ms": [GLM, SOLAR, TRINITY, XING4],  # the cells whose expert layers hold an expert every token meets
+    "mla.latent_ms": [GLM, XING4],  # GLM's alone until PR 69: the cells whose queries, keys and values come through latents
     # What exists only across chips. `collectives.exposed_min_ms` read 0.0 in the seven one-chip cells until PR 56.
     **dict.fromkeys(("collectives.total_ms", "collectives.exposed_ms", "collectives.exposed_min_ms",
                      "entry.gang_join_s"), GANGS),

@@ -162,18 +162,19 @@ def test_the_reference_walks_the_tree_in_the_published_order(nano):
 
 
 # ------------------------------------------------------------------ readers
-NEW = ("mla.latent_ms", "mtp.ms")
-# `moe.shared_ms` came with this cell and listed it alone until PR 65 put Solar-Open2's and Trinity-Mini's cells beside it.
+NEW = ("mtp.ms",)
+# `moe.shared_ms` came with this cell and listed it alone until PR 65 put Solar-Open2's and Trinity-Mini's cells beside it,
+# `mla.latent_ms` until PR 69 put Xing4.0's.
 LISTED = ("data.wait_ms", "host.h2d_ms", "host.report_ms", "host.report_put_ms",
           "moe.router_ms", "moe.dispatch_ms", "moe.experts_ms", "moe.experts_roofline",
           "moe.load_max_over_mean", "kernels.gmm_ms", "kernels.gmm_roofline", "step.dense_mlp_ms",
-          "moe.held_pairs_share", "moe.issued_over_held", "moe.shared_ms")
+          "moe.held_pairs_share", "moe.issued_over_held", "moe.shared_ms", "mla.latent_ms")
 
 
 def test_the_cell_is_on_the_list_of_each_listed_reading_it_reports_and_the_new_ones_list_the_cell():
     by_name, unlisted = listed_readings.holds_for(CELL, LISTED, NEW)
-    assert {by_name[name]["layer"] for name in NEW} == {"latent attention", "prediction module"}
-    assert by_name["moe.shared_ms"]["layer"] == "expert layer"
+    assert {by_name[name]["layer"] for name in NEW} == {"prediction module"}
+    assert (by_name["moe.shared_ms"]["layer"], by_name["mla.latent_ms"]["layer"]) == ("expert layer", "latent attention")
     # Every unlisted reading of the accepted benchmark is the cell's too: the four `kernels.flash_*` among them.
     assert {"kernels.flash_ms", "kernels.flash_fwd_ms", "kernels.flash_bwd_ms", "kernels.flash_roofline"} <= unlisted
     # No stall reading and no block-pull reading: an entry lists a cell only where every traced line
@@ -186,7 +187,7 @@ def test_the_new_readers_return_nothing_on_a_program_without_the_scopes(named_ru
     readers = Manifest().layer_readers()
     run = dict(named_run, summary={**named_run["summary"], "check": {}}, peaks={
         "bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9})
-    names = NEW + ("moe.shared_ms", "step.dense_mlp_ms", "moe.held_pairs_share", "moe.issued_over_held")
+    names = NEW + ("mla.latent_ms", "moe.shared_ms", "step.dense_mlp_ms", "moe.held_pairs_share", "moe.issued_over_held")
     assert [readers[name].read(run) for name in names] == [None] * len(names)  # gpt2: no such scope
 
 

@@ -48,15 +48,15 @@ def test_every_entry_has_one_reader_file_and_every_reader_file_one_entry(manifes
     assert set(readers) == set(entries), set(readers) ^ set(entries)
 
 
-def test_per_layer_keeps_the_widening_rehearsal_under_the_contracts_cap(widened):
-    """The contract caps `per_layer` at 128 and `test_benchmark_olmo_hybrid.py` widens a copy twice, so the live list
-    may hold 128 - 2 x 7 = 114 entries: `widened_manifest.room`, the one count, which leaves a rehearsal's own
-    entries out and so reads the same here and where `test_benchmark_widening.py` runs this directory's tests inside a
-    copy widened before. Only that bound is held, and here alone: a `model_config` PR may edit nothing in this
-    directory, so a margin asserted on the live manifest would refuse the very PR the room is for. With 86 entries
-    held (PR 65) a `model_config` PR may append **28**; a fed expert cell with latent attention brings sixteen copies
-    of listed readings beside its own."""
-    hold_the_room(Manifest().data["per_layer"], len(widened.metrics))
+def test_per_layer_of_the_live_list_is_held_to_the_contracts_cap():
+    """The contract caps the `per_layer` of the live `BENCHMARK.json` at 128, and that is what the list may hold:
+    `widened_manifest.room`, the one count, which leaves a rehearsal's own entries out and so reads the same here and
+    where `test_benchmark_widening.py` runs this directory's tests inside a copy widened before. Nothing is set aside
+    for the rehearsals' copies, which no driver reads and nothing holds to the cap. Only that bound is held, and here
+    alone: a `model_config` PR may edit nothing in this directory, so a margin asserted on the live manifest would
+    refuse the very PR the room is for. With 93 entries held (PR 69) a `model_config` PR may append **35**; a fed
+    expert cell with a window brings eighteen copies of listed readings beside its own."""
+    hold_the_room(Manifest().data["per_layer"])
 
 
 def _held(live, rehearsed):
@@ -66,15 +66,15 @@ def _held(live, rehearsed):
 
 
 @pytest.mark.parametrize("rehearsed", [False, True], ids=["live", "widened_before"])
-@pytest.mark.parametrize("live, left", [(86, 28), (113, 1), (114, 0), (115, -1)])
-def test_an_appended_entry_passes_at_113_held_and_fails_at_115_in_either_run(live, left, rehearsed):
+@pytest.mark.parametrize("live, left", [(93, 35), (127, 1), (128, 0), (129, -1)])
+def test_an_appended_entry_passes_at_127_held_and_fails_at_129_in_either_run(live, left, rehearsed):
     per_layer = _held(live, rehearsed)
-    assert room(per_layer, 7) == left
+    assert room(per_layer) == left
     if left >= 0:
-        hold_the_room(per_layer, 7)
+        hold_the_room(per_layer)
     else:
-        with pytest.raises(AssertionError, match="holds 115 .* may hold 128 - 2 x 7 = 114.* 1 over"):
-            hold_the_room(per_layer, 7)
+        with pytest.raises(AssertionError, match="holds 129 .* may hold 128, the contract's cap.* 1 over"):
+            hold_the_room(per_layer)
 
 
 def test_the_bound_is_one_number_on_the_live_manifest_and_on_a_copy_widened_before(widened):
@@ -84,7 +84,7 @@ def test_the_bound_is_one_number_on_the_live_manifest_and_on_a_copy_widened_befo
     appended = len(widened.metrics)
     assert len(there) == len(here) + appended and len(rehearsals_own(there)) == len(rehearsals_own(here)) + appended
     assert [e["name"] for e in rehearsals_own(there)][-appended:] == widened.metrics
-    assert room(there, appended) == room(here, appended)
+    assert room(there) == room(here)
 
 
 def test_the_names_before_the_fold_go_on_a_rehearsals_line_and_on_no_other():

@@ -1,7 +1,8 @@
 """What the Xing4.0-29B-A4B configuration brings to the benchmark: its file against the catalog row, its cell and entries
 appended and held to the contract, its readers on a recorded trace and on a made one, the kernels' floors and the
-parameter count by hand. A one-chip cell. Sixteen of the listed readings come as `<metric>.<configuration>` copies until a
-`benchmark` PR folds them into the listed entries' own lists (`per_layer` holds 107 of the 114 it may hold with this cell).
+parameter count by hand. A one-chip cell. It brought sixteen of the listed readings as `<metric>.<configuration>` copies
+(PR 66); PR 69 put it on those entries' own lists (`listed_readings.TABLE`), deleted the copies, and gave the mixes' two
+backward kernels of PR 67 their readings.
 (The cell's CPU rehearsal is `tests/test_xing4_rehearsal.py`: this directory's tests are run a second time inside
 `test_benchmark_widening.py`.)"""
 
@@ -24,8 +25,14 @@ from widened_manifest import named_run  # noqa: E402,F401  (fixture)
 CONFIG = "xing4-29b-a4b-ep8-l5"
 CELL = CONFIG + ".fed4k"
 ROWS, SEQ, CHIPS = 1, 4096, 1
-NEW = ("mhc.mix_ms", "mhc.maps_ms", "mhc.sinkhorn_ms", "mhc.mix_roofline", "mhc.res_sum_err")
-COPIED = ("data.wait_ms", "host.h2d_ms", "host.report_ms", "host.report_put_ms", "moe.router_ms", "moe.dispatch_ms",
+STREAMS = ("mhc.mix_ms", "mhc.maps_ms", "mhc.sinkhorn_ms", "mhc.mix_roofline", "mhc.res_sum_err")
+KERNELS = ("kernels.mhc_bwd_ms", "kernels.mhc_bwd_roofline")  # PR 69: `mhc_post_bwd` + `mhc_pre_bwd`, and their bytes' floor
+NEW = STREAMS + KERNELS
+# The listed readings a one-chip fed cell with a leading dense layer, held experts, a shared one and latent attention reports in
+# every traced run: it brought them as copies and joined the lists at PR 69 (three traced runs of the cell on the chip read
+# each, `benchmark/testdata/xing4_traced_lines.json`). Not `data.fetch_block_ms` and not `host.stall_pct`: a 16-row block
+# lasts 16 steps of a row, and a window holds about 93 steps, under three readings at each of the clock's 8 positions.
+LISTED = ("data.wait_ms", "host.h2d_ms", "host.report_ms", "host.report_put_ms", "moe.router_ms", "moe.dispatch_ms",
           "moe.experts_ms", "moe.experts_roofline", "moe.load_max_over_mean", "kernels.gmm_ms", "kernels.gmm_roofline",
           "moe.held_pairs_share", "moe.issued_over_held", "moe.shared_ms", "step.dense_mlp_ms", "mla.latent_ms")
 REDUCED = ["num_hidden_layers", "first_k_dense_replace", "n_routed_experts", "vocab_size", "num_nextn_predict_layers"]
@@ -50,34 +57,60 @@ def test_the_manifest_holds_the_cell_appended_and_meets_the_contract():
     for said in ("1 x 4,096", "four streams", "20 Sinkhorn rounds", "keys 192 / values 128", "8 of 64 experts", "759 M"):
         assert said in m.cell(CELL)["why"], said
     assert os.path.getsize(os.path.join(REPO, "BENCHMARK.json")) < 64 * 1024
-    # One run of twenty-one after the 86 entries PR 65 left: the five new readings, then the sixteen copies.
+    # One run of five after the 86 entries PR 65 left, the streams' readings (the sixteen copies that followed went in
+    # PR 69), then the two kernels' readings that PR 69 appended.
     names = [e["name"] for e in m.data["per_layer"]]
-    assert names[86:91] == list(NEW) and names[91:107] == [f"{name}.{CONFIG}" for name in COPIED]
+    assert names[86:91] == list(STREAMS) and names[91:93] == list(KERNELS)
+    assert not [name for name in names if name.endswith("." + CONFIG)]
     assert sum(w["chips"] == 4 for w in m.data["workloads"]) == 2 and len(cells) >= 12  # a third four-chip slot is open
     # The mix is the one that was there, unedited: rows of 4,097 out of 16-row blocks.
     assert m.traffic("fed4k") == {**m.traffic("fed4k"), "loop": "fed", "block_rows": 16, "supply_factor": 4}
     assert m.traffic("fed4k")["documents"] == {"median_tokens": 400, "sigma": 1.2, "min_tokens": 8, "max_tokens": 8192}
 
 
-def test_the_cell_reports_the_new_readings_the_copies_and_every_unlisted_one():
-    by_name, unlisted = listed_readings.holds_for(CELL, [], list(NEW) + [f"{name}.{CONFIG}" for name in COPIED])
-    readers = Manifest().layer_readers()
+def test_the_cell_reports_the_new_readings_each_listed_one_and_every_unlisted_one():
+    m = Manifest()
+    by_name, unlisted = listed_readings.holds_for(CELL, LISTED, NEW)
+    readers = m.layer_readers()
     assert len(unlisted) >= 30 and {"step.mfu_pct", "kernels.flash_ms", "kernels.flash_roofline", "step.product_floor_ms",
                                     "step.xla_remat_ms", "step.unowned_ms", "compile.traces"} <= unlisted
     for name in NEW:
-        assert by_name[name]["moves"] == "tokens_per_s_per_chip" and by_name[name]["layer"] == "residual streams"
+        assert by_name[name]["moves"] == "tokens_per_s_per_chip"
+        assert by_name[name]["layer"] == ("residual streams" if name in STREAMS else "kernels")
         assert readers[name].META == {k: v for k, v in by_name[name].items() if k != "workloads"}
     assert (by_name["mhc.mix_roofline"]["unit"], by_name["mhc.mix_roofline"]["better"]) == ("%", "higher")
     assert by_name["mhc.res_sum_err"]["source"] == "program_counter"
-    for name in COPIED:  # a copy is the listed entry under the cell's name, read by the listed reader
-        copy, listed = by_name[f"{name}.{CONFIG}"], by_name[name]
-        assert copy == {**listed, "name": copy["name"], "workloads": [CELL]} and CELL not in listed["workloads"]
-        assert listed["workloads"] == listed_readings.TABLE.get(name, listed["workloads"])
-        assert readers[copy["name"]].read.__code__.co_filename == readers[name].__file__  # `read = listed.read`
-        assert readers[copy["name"]].META == {k: v for k, v in copy.items() if k != "workloads"}
-    m = Manifest()
+    assert [(by_name[n]["unit"], by_name[n]["better"], by_name[n]["source"]) for n in KERNELS] == [
+        ("ms/step", "lower", "device_trace"), ("%", "higher", "device_trace")]
     assert [e["name"] for e in m.metrics_for(CELL, "end_to_end")] == ["tokens_per_s_per_chip", "setup_s"]
-    assert len(m.data["per_layer"]) >= 107
+    # the 52 it had, sixteen now under the listed names, and the two kernels'; an unlisted reading appended later joins them
+    assert len(m.metrics_for(CELL, "per_layer")) == len(LISTED) + len(NEW) + len(unlisted) >= 54
+
+
+@pytest.fixture(scope="module")
+def recorded():
+    with open(os.path.join(REPO, "benchmark", "testdata", "xing4_traced_lines.json")) as fh:
+        return json.load(fh)
+
+
+@pytest.mark.parametrize("name", LISTED + KERNELS)
+def test_a_reading_the_cell_joined_reads_a_number_in_every_recorded_traced_run(name, recorded):
+    """An entry lists a cell only where its reader returns a value in every traced run of that cell (a listed reading
+    that comes back `null` refuses the next `benchmark` PR): the lines of three traced runs of this cell on the chip,
+    and what the span and counter readers read there, through the readers as they stand."""
+    m = Manifest()
+    reader, mine = m.layer_readers()[name], [e["name"] for e in m.metrics_for(CELL, "per_layer") if "workloads" in e]
+    assert recorded["cell"] == CELL and name in mine and len({run["seed"] for run in recorded["runs"]}) >= 3
+    for run in recorded["runs"]:
+        line = run["metrics"]
+        assert run["correct"] is True and run["failed"] == 0 and run["device"]["platform"] == "tpu"
+        assert isinstance(line[name], float) and 0 < line[name] < float("inf")
+        assert set(mine) <= set(line) and not [n for n in line if n.endswith("." + CONFIG)]
+        if name in ("data.wait_ms", "host.h2d_ms", "host.report_ms", "moe.load_max_over_mean", "moe.held_pairs_share",
+                    "moe.issued_over_held"):  # the others read the raw trace
+            assert reader.read({"summary": run["summary"], "device_trace": None}) == line[name]
+        if name in KERNELS:  # the two kernels are part of the scope's time, and no share of a floor passes 100 %
+            assert line["kernels.mhc_bwd_ms"] < line["mhc.mix_ms"] and line["kernels.mhc_bwd_roofline"] < 100
 
 
 def test_the_file_holds_every_published_key_and_cuts_counts_and_no_width(config):
@@ -154,6 +187,18 @@ def test_the_arithmetic_by_hand(config):
     plane = SEQ * d * 2
     assert xing4.mhc_mix_bytes_per_step(config, ROWS, SEQ) == 3 * 14 * plane * 10 == 12_331_253_760
     assert xing4.mhc_mix_bytes_per_step(config, ROWS, SEQ) / 819e9 == pytest.approx(15.06e-3, rel=1e-3)
+    # the mixes' two backward kernels, a sublayer: `mhc_post_bwd` reads g, X (4 planes each) and y and writes dX (4) and dy,
+    # 14 planes, beside a token's 4 + 16 map values in and their gradients out (f32); `mhc_pre_bwd` reads X and writes
+    # dX, 8 planes, beside a token's 24 columns of r dm and one coefficient in (f32), Phi (24 x 14,336 f32) in, dPhi out
+    post = 14 * plane + 2 * SEQ * 20 * 4
+    pre = 8 * plane + SEQ * 25 * 4 + 2 * 24 * 14336 * 4
+    assert (post, pre) == (411_697_152, 238_043_136)
+    assert xing4.mhc_bwd_bytes_per_step(config, ROWS, SEQ) == 10 * (post + pre) == 6_497_402_880
+    assert xing4.mhc_bwd_bytes_per_step(config, ROWS, SEQ) / 819e9 == pytest.approx(7.933e-3, rel=1e-3)
+    # of the 1.23 GB a sublayer's whole backward moves (PR 67) the kernels' part is 0.65; the forward is the scope's alone
+    assert (post + pre) / 1.23e9 == pytest.approx(0.528, abs=2e-3)
+    assert xing4.mhc_bwd_bytes_per_step(config, ROWS, SEQ) < xing4.mhc_mix_bytes_per_step(config, ROWS, SEQ)
+    assert xing4.mhc_bwd_bytes_per_step(config, 2, SEQ) == 10 * (2 * post + 2 * pre - 2 * 24 * 14336 * 4)  # Phi once a call
 
 
 def test_the_programs_own_count_agrees(config):
@@ -181,8 +226,8 @@ def test_the_new_readers_return_nothing_on_a_program_without_the_scopes(named_ru
     run = dict(named_run, config={"model": "xing4", "batch": {"global_rows": ROWS, "seq": SEQ}},
                summary={**named_run["summary"], "device": {"count": CHIPS}, "check": {}}, peaks=PEAKS)
     assert [readers[name].read(run) for name in NEW] == [None] * len(NEW)
-    assert readers[f"moe.shared_ms.{CONFIG}"].read(run) is None  # GPT-2 has no scope `shared_expert`
-    assert readers[f"mla.latent_ms.{CONFIG}"].read(run) is None
+    assert readers["moe.shared_ms"].read(run) is None  # GPT-2 has no scope `shared_expert`
+    assert readers["mla.latent_ms"].read(run) is None
     untraced = dict(run, device_trace=None)
     untraced.pop("program_trace", None)
     assert [readers[name].read(untraced) for name in NEW] == [None] * len(NEW)
@@ -215,6 +260,23 @@ def test_the_stream_readers_pick_their_scopes_and_the_roofline_divides_the_bytes
     assert readers["mhc.mix_roofline"].read({**run, "peaks": None}) is None
     assert readers["mhc.res_sum_err"].read(run) == 0.0178
     assert xplane.measure(xplane.union([(0, 3), (2, 5)])) == 5  # the union the readers take
+
+
+def test_the_kernel_readers_add_the_two_kernels_up_and_divide_their_bytes_floor_by_that(config, monkeypatch):
+    from benchmark.harness import program_trace
+
+    readers = Manifest().layer_readers()
+    times = {"mhc_post_bwd": 5.89, "mhc_pre_bwd": 3.79, "flash_bwd": 19.9}  # PR 67's trace: ten calls of 0.59 and of 0.38
+    monkeypatch.setattr(program_trace, "of", lambda run: SimpleNamespace(kernel=run["kernels"].get))
+    run = {"config": config, "summary": {"device": {"count": CHIPS}}, "peaks": PEAKS, "kernels": times}
+    assert readers["kernels.mhc_bwd_ms"].read(run) == pytest.approx(9.68)
+    assert readers["kernels.mhc_bwd_roofline"].read(run) == pytest.approx(100 * 6_497_402_880 / 819e9 * 1e3 / 9.68, rel=1e-9)
+    assert readers["kernels.mhc_bwd_roofline"].read(run) == pytest.approx(81.96, abs=0.01)
+    assert readers["kernels.mhc_bwd_roofline"].read({**run, "peaks": None}) is None
+    one_alone = {**run, "kernels": {"mhc_post_bwd": 5.89}}  # a later PR that takes a kernel off the path: silent, not smaller
+    assert [readers[name].read(one_alone) for name in KERNELS] == [None, None]
+    monkeypatch.setattr(program_trace, "of", lambda run: None)  # an untraced run
+    assert [readers[name].read(run) for name in KERNELS] == [None, None]
 
 
 def test_the_reference_walks_the_tree_in_the_published_order(config):
