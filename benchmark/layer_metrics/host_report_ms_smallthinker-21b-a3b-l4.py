@@ -1,0 +1,8 @@
+"""`host.report_ms` in `smallthinker-21b-a3b-l4.fed16k`: that entry lists its cells and a later cell cannot
+append itself, so the cell brings the same reading under a name of its own, until a
+`benchmark` PR puts the cell on that entry's list and deletes this file."""
+
+from benchmark.layer_metrics import host_report_ms as listed
+
+META = {**listed.META, "name": "host.report_ms.smallthinker-21b-a3b-l4"}
+read = listed.read

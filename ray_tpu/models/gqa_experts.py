@@ -99,16 +99,17 @@ def by_batch(table):
 def qkv_heads(h, layer, cos, sin, config):
     """q (B, heads, S, hd), k and v (B, kv heads, S, hd) of the normed input h
     (B, S, D): the three projections, the norm over each head's own dimensions
-    on q and on k, the rotation by `cos`, `sin` (B | 1, 1, S, hd / 2), or none
-    where they are None (a layer without positions: `trinity.py`'s full kind)."""
+    on q and on k where the layer has its scales (`q_norm`, `k_norm`:
+    `smallthinker.py`'s has none), the rotation by `cos`, `sin` (B | 1, 1, S,
+    hd / 2), or none where they are None (a layer without positions:
+    `trinity.py`'s full kind)."""
     cdt, eps = config.dtype, config.norm_eps
     q = jnp.einsum("bsd,dnh->bnsh", h, layer["wq"].astype(cdt))
     k = jnp.einsum("bsd,dnh->bnsh", h, layer["wk"].astype(cdt))
     v = jnp.einsum("bsd,dnh->bnsh", h, layer["wv"].astype(cdt))
     rotated = (lambda x: x) if cos is None else (lambda x: apply_rope(x, cos, sin))
-    q = rotated(rms_norm(q, layer["q_norm"], eps).astype(cdt))
-    k = rotated(rms_norm(k, layer["k_norm"], eps).astype(cdt))
-    return q, k, v
+    normed = lambda x, name: rms_norm(x, layer[name], eps).astype(cdt) if name in layer else x  # noqa: E731
+    return rotated(normed(q, "q_norm")), rotated(normed(k, "k_norm")), v
 
 
 def out_and_experts(x, o, layer, config):

@@ -44,6 +44,16 @@ The keys of every sort are all different (`order` is a permutation; the ids
 sort with the pair's position as second key), so none is asked to be stable:
 XLA takes three times as long to compile a stable sort of this length.
 
+The layer is two halves. `route_and_sort` reads the router's input and makes
+the choice and the plan of the sorted form (the sort, its inverse, the runs);
+`experts_of` reads the experts' input and that plan and does everything that is
+a row long. `moe_mlp` is both on one tensor, which is every model's layer but
+one: SmallThinker's router reads the layer's normed input, before attention,
+and its experts the state after it (`models/smallthinker.py` calls the halves
+apart). The gate's activation is `act`, SiLU unless the model hands another
+(ReGLU: `jax.nn.relu`), in the sorted form, its held prefix and that prefix's
+hand-written backward rule alike.
+
 Expert weights carry the `expert` logical axis, so a mesh with an `expert`
 axis shards them; the sorted form is partitioned by XLA from the sharding
 annotations alone (correct on any mesh).
@@ -75,7 +85,7 @@ from __future__ import annotations
 
 import functools
 import math
-from typing import Any, Dict, Tuple
+from typing import Any, Callable, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -263,35 +273,29 @@ def _under_the_current_abstract_mesh(f):
 
 
 @_under_the_current_abstract_mesh
-def moe_mlp(
-    x,  # (B, S, D) activations, config.dtype
+def route_and_sort(
+    tokens,  # (T, D): what the router reads
     router_w,  # (D, E)
-    w_gate,  # (E, D, F), or (H, D, F) where the layer holds H of the E experts
-    w_up,  # likewise
-    w_down,  # (E, F, D), or (H, F, D)
+    n_held: int,  # the experts the layer holds: E, or H of them from `held_from` on
     *,
     k: int,
     norm_topk_prob: bool = False,
     router_bias=None,  # (E,): `route`'s other scoring
     weight_scale: float = 1.0,
-    held_from: int = 0,  # the first expert held, where H < E
-) -> Tuple[Any, Dict[str, Any]]:
-    """Returns (out (B, S, D), aux): `out = sum over the token's k experts of
-    p_e * W_down,e (silu(W_gate,e h) * W_up,e h)`, `aux` as `route` gives it
-    plus `experts` (tokens, k), each token's choices, `rows_processed`,
-    the rows of the sorted form that their own expert takes, `held_pairs`,
-    the (token, expert) pairs whose expert this layer holds, and `compact`,
-    whether the sorted form was the held prefix of the sort and not all of it.
-    The scopes are read from a device trace by the benchmark's `moe.*_ms`.
-
-    Where the expert weights hold fewer experts than the router scores, they
-    are experts `held_from .. held_from + H` and the sum runs over those of
-    the token's k that are among them (the module's docstring): `out` is this
-    share's partial sum."""
-    B, S, D = x.shape
-    n_experts, n_held = router_w.shape[-1], w_gate.shape[0]
+    held_from: int = 0,
+) -> Tuple[Tuple, Dict[str, Any]]:
+    """The layer's first half, which reads the router's input and no expert's:
+    the choice (`route`, scope `router`) and the plan of the sorted form (scope
+    `dispatch`: the one sort by expert, its inverse, the runs each block of
+    tokens' rows lie in). -> (routing, aux): `routing` is what `experts_of`
+    takes, `(sorted weights, sizes, ids, order, inverse, runs)`, and `aux` what
+    `route` gives plus `experts` (tokens, k), each token's choices, and
+    `held_pairs`. A layer whose router reads another tensor than its experts
+    (SmallThinker's: the layer's normed input, before attention) calls the two
+    halves itself, the first wherever that tensor is; `moe_mlp` is the two on
+    one tensor. Both take the tokens as rows, (T, D)."""
+    n_experts = router_w.shape[-1]
     partial = n_held < n_experts
-    tokens = x.reshape(B * S, D)
     with jax.named_scope("router"):
         weights, experts, aux = route(tokens, router_w, k, norm_topk_prob,
                                       bias=router_bias, scale=weight_scale)
@@ -307,16 +311,75 @@ def moe_mlp(
         ids, order, inverse, weights = expert_order(experts, weights)
         runs = sorted_runs(experts, n_held, partial)  # where each block of tokens' rows lie
         aux["held_pairs"] = jnp.sum(sizes)
-    pairs = order.shape[0]
+    return (weights, sizes, ids, order, inverse, runs), aux
+
+
+@_under_the_current_abstract_mesh
+def experts_of(
+    tokens,  # (T, D) activations, config.dtype: what the experts read
+    routing,  # `route_and_sort`'s, of the same T tokens
+    w_gate,  # (E, D, F), or (H, D, F) where the layer holds H of the E experts
+    w_up,  # likewise
+    w_down,  # (E, F, D), or (H, F, D)
+    *,
+    k: int,
+    n_experts: int,  # the router's width
+    act: Callable = jax.nn.silu,  # the gate's activation, in float32 (ReGLU: `jax.nn.relu`)
+) -> Tuple[Any, Dict[str, Any]]:
+    """The layer's second half: `out = sum over the token's k experts of p_e *
+    W_down,e (act(W_gate,e h) * W_up,e h)` (T, D) over the experts held, by
+    the sorted form or its held prefix, and `rows_processed`, the rows of the
+    sorted form that their own expert took, with `compact`, whether the sorted
+    form was the held prefix of the sort and not all of it."""
+    weights, *plan = routing
+    n_held = w_gate.shape[0]
+    partial = n_held < n_experts
+    pairs = plan[2].shape[0]
     bound = held_row_bound(pairs, n_held, n_experts)
     operands = (tokens, weights, w_gate, w_up, w_down)
-    routing = (sizes, ids, order, inverse, runs)
     if bound == pairs:
-        out, aux["rows_processed"] = _sorted_form(pairs, k, partial, *operands, *routing)
-        aux["compact"] = jnp.zeros((), bool)
+        out, processed = _sorted_form(pairs, k, partial, act, *operands, *plan)
+        compact = jnp.zeros((), bool)
     else:
-        out, aux["rows_processed"], aux["compact"] = _prefix_or_whole_jit(k, bound, operands, routing)
-    return out.reshape(B, S, D), aux
+        out, processed, compact = _prefix_or_whole_jit(k, bound, act, operands, tuple(plan))
+    return out, {"rows_processed": processed, "compact": compact}
+
+
+@_under_the_current_abstract_mesh
+def moe_mlp(
+    x,  # (B, S, D) activations, config.dtype
+    router_w,  # (D, E)
+    w_gate,  # (E, D, F), or (H, D, F) where the layer holds H of the E experts
+    w_up,  # likewise
+    w_down,  # (E, F, D), or (H, F, D)
+    *,
+    k: int,
+    norm_topk_prob: bool = False,
+    router_bias=None,  # (E,): `route`'s other scoring
+    weight_scale: float = 1.0,
+    held_from: int = 0,  # the first expert held, where H < E
+    act: Callable = jax.nn.silu,
+) -> Tuple[Any, Dict[str, Any]]:
+    """Returns (out (B, S, D), aux): `out = sum over the token's k experts of
+    p_e * W_down,e (act(W_gate,e h) * W_up,e h)` (SwiGLU unless the model hands
+    another `act`), `aux` as `route` gives it
+    plus `experts` (tokens, k), each token's choices, `rows_processed`,
+    the rows of the sorted form that their own expert takes, `held_pairs`,
+    the (token, expert) pairs whose expert this layer holds, and `compact`,
+    whether the sorted form was the held prefix of the sort and not all of it.
+    The scopes are read from a device trace by the benchmark's `moe.*_ms`.
+    The layer's two halves, `route_and_sort` and `experts_of`, on one tensor.
+
+    Where the expert weights hold fewer experts than the router scores, they
+    are experts `held_from .. held_from + H` and the sum runs over those of
+    the token's k that are among them (the module's docstring): `out` is this
+    share's partial sum."""
+    B, S, D = x.shape
+    tokens = x.reshape(B * S, D)
+    routing, aux = route_and_sort(tokens, router_w, w_gate.shape[0], k=k, norm_topk_prob=norm_topk_prob,
+                                  router_bias=router_bias, weight_scale=weight_scale, held_from=held_from)
+    out, report = experts_of(tokens, routing, w_gate, w_up, w_down, k=k, n_experts=router_w.shape[-1], act=act)
+    return out.reshape(B, S, D), {**aux, **report}
 
 
 def routing_report(aux: Dict[str, Any], pairs: int) -> Dict[str, Any]:
@@ -339,14 +402,15 @@ def routing_report(aux: Dict[str, Any], pairs: int) -> Dict[str, Any]:
     }
 
 
-def swiglu(x, w_gate, w_up, w_down):
-    """`W_down (silu(W_gate x) * W_up x)` of x (B, S, D) with (D, F), (D, F),
-    (F, D): operands in x's dtype, the gate in float32, rounded once."""
+def swiglu(x, w_gate, w_up, w_down, act: Callable = jax.nn.silu):
+    """`W_down (act(W_gate x) * W_up x)` of x (B, S, D) with (D, F), (D, F),
+    (F, D), SwiGLU unless the model hands another `act`: operands in x's dtype,
+    the gate in float32, rounded once."""
     cdt = x.dtype
     gate = jnp.einsum("bsd,df->bsf", x, w_gate.astype(cdt))
     up = jnp.einsum("bsd,df->bsf", x, w_up.astype(cdt))
-    act = (jax.nn.silu(gate.astype(jnp.float32)) * up.astype(jnp.float32)).astype(cdt)
-    return jnp.einsum("bsf,fd->bsd", act, w_down.astype(cdt))
+    hidden = (act(gate.astype(jnp.float32)) * up.astype(jnp.float32)).astype(cdt)
+    return jnp.einsum("bsf,fd->bsd", hidden, w_down.astype(cdt))
 
 
 def shared_expert(x, w_gate, w_up, w_down):
@@ -367,12 +431,13 @@ def held_row_bound(pairs: int, n_held: int, n_experts: int) -> int:
     return min(pairs, math.ceil(HELD_ROWS_OVER_EVEN * even / ROW_TILE) * ROW_TILE)
 
 
-def _sorted_form(n: int, k: int, partial: bool, tokens, weights, w_gate, w_up, w_down,
+def _sorted_form(n: int, k: int, partial: bool, act: Callable, tokens, weights, w_gate, w_up, w_down,
                  sizes, ids, order, inverse, runs):
     """Everything of the layer that is as long as the sorted form, over its first
     `n` rows: (the tokens' sums (T, D), the rows that their own expert took).
     `n` is every pair, or with `partial` at least the held pairs, which the sort
-    put first. `weights` and `ids` are in sorted order (`expert_order`)."""
+    put first. `weights` and `ids` are in sorted order (`expert_order`); `act`
+    is the gate's activation (`experts_of`)."""
     cdt = tokens.dtype
     with jax.named_scope("dispatch"):
         order = order[:n]
@@ -396,10 +461,10 @@ def _sorted_form(n: int, k: int, partial: bool, tokens, weights, w_gate, w_up, w
             # write nothing there): zeros go on, and zeros come back for the gradients.
             gate, up = _held_rows(gate, held), _held_rows(up, held)
         # The weighting rides in SwiGLU's own pass, in float32, rounded once.
-        act = jax.nn.silu(gate.astype(jnp.float32)) * up.astype(jnp.float32) * row_weights[:, None]
+        hidden = act(gate.astype(jnp.float32)) * up.astype(jnp.float32) * row_weights[:, None]
         if partial:
-            act = _held_rows(act, held)
-        rows = grouped_matmul(act.astype(cdt), w_down.astype(cdt), sizes, short=partial)
+            hidden = _held_rows(hidden, held)
+        rows = grouped_matmul(hidden.astype(cdt), w_down.astype(cdt), sizes, short=partial)
         if partial and runs is None:
             rows = _held_rows(rows, held)  # the XLA sum reads every row; the kernel only held ones
     with jax.named_scope("combine"):
@@ -415,8 +480,8 @@ def _either_form(bound: int, routing, branch, *operands):
     return compact, jax.lax.cond(compact, branch(bound), branch(order.shape[0]), *operands)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
-def _prefix_or_whole(k: int, bound: int, operands, routing):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
+def _prefix_or_whole(k: int, bound: int, act: Callable, operands, routing):
     """`_sorted_form` over the first `bound` rows where they hold every held
     pair, else over every pair: both dropless, the same function of a row
     count; with the count of processed rows, whether it was the prefix.
@@ -426,18 +491,18 @@ def _prefix_or_whole(k: int, bound: int, operands, routing):
     sets (the LFM2 step then wants 12.6 GB of temporaries for 10.3, and does
     not compile for a v5e: PERF.md section 6, PR 36). The backward pass makes
     the branch's forward pass again instead."""
-    return _prefix_or_whole_fwd(k, bound, operands, routing)[0]
+    return _prefix_or_whole_fwd(k, bound, act, operands, routing)[0]
 
 
-def _prefix_or_whole_fwd(k, bound, operands, routing):
+def _prefix_or_whole_fwd(k, bound, act, operands, routing):
     def forward(n):
-        return lambda operands, routing: _sorted_form(n, k, True, *operands, *routing)
+        return lambda operands, routing: _sorted_form(n, k, True, act, *operands, *routing)
 
     compact, (out, processed) = _either_form(bound, routing, forward, operands, routing)
     return (out, processed, compact), (operands, routing)
 
 
-def _prefix_or_whole_bwd(k, bound, res, g):
+def _prefix_or_whole_bwd(k, bound, act, res, g):
     operands, routing = res
 
     def backward(n):
@@ -446,7 +511,7 @@ def _prefix_or_whole_bwd(k, bound, res, g):
                 # `jax.vjp` wraps the first scope opened under it (`jvp(sorted_form)`,
                 # `transpose(jvp(sorted_form))`): the layer's own stay whole path components.
                 with jax.named_scope("sorted_form"):
-                    return _sorted_form(n, k, True, *operands, *routing)[0]
+                    return _sorted_form(n, k, True, act, *operands, *routing)[0]
 
             return jax.vjp(forward, *operands)[1](g)
         return branch
@@ -457,7 +522,7 @@ def _prefix_or_whole_bwd(k, bound, res, g):
 _prefix_or_whole.defvjp(_prefix_or_whole_fwd, _prefix_or_whole_bwd)
 # A function of its own in the program, as `sum_rows._pallas_sum_rows`: a model's layers of one
 # shape trace both forms and their backward passes once, and lower their kernels once.
-_prefix_or_whole_jit = jax.jit(_prefix_or_whole, static_argnums=(0, 1))
+_prefix_or_whole_jit = jax.jit(_prefix_or_whole, static_argnums=(0, 1, 2))
 
 
 def _held_rows(rows, held):

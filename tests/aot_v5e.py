@@ -627,9 +627,10 @@ def _step_case(topo, cell):
     if c["model"] == "lfm2":  # what the short convolutions' `conv_mix` writes in float32 at the size of a layer's y
         out["conv_mix_f32"] = written_under(text, scopes, "conv_mix", "f32[%d,%d,%d]" % (rows, seq, c["hidden_size"]))
     out["gather_minor_dims"], out["gathered_weight_copies"] = block_weight_gathers(text, scopes)
-    if "num_experts_per_tok" in c:
+    per_token = getattr(cfg, "experts_per_token", None)  # every expert family's configuration has it, under one name
+    if per_token:
         out["sorted_rows_moved"], out["backward_scatter_adds"] = sorted_row_traffic(
-            text, scopes, rows * seq * c["num_experts_per_tok"], c["hidden_size"])
+            text, scopes, rows * seq * per_token, c["hidden_size"])
         out["element_moves"] = element_moves(text, scopes)
     return out
 

@@ -221,3 +221,69 @@ def test_the_kernels_row_statistics_under_the_window_are_the_xla_forms(seq, wind
     _, got = flash_attention(q, k, v, causal=mask, backend="pallas", interpret=True, block_q=tiles[0], block_k=tiles[1],
                              return_lse=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
+
+
+# ------------------------------------------------------------------ groups of 7 under a window of 4,096 (PR 70)
+def test_the_smallthinker_cells_row_walks_140_of_512_tile_pairs_and_scores_1008_of_1120_key_blocks():
+    """(28 on 4, 16,384, 128) under a window of 4,096 at 512 x 1,024 tiles. By hand: Q tile i needs keys 512 i - 4,095
+    .. 512 i + 511, which lie in K tiles floor((512 i - 4,095) / 1,024) .. floor(i / 2): one for i = 0, 1, two for 2, 3,
+    three for 4, 5, four for 6, 7 and five from i = 8 on: 2 + 4 + 6 + 8 + 24 x 5 = 140. Of a Q tile's five the first is
+    cut by the window's edge (odd i: its last 511 keys, four blocks; even i: all but its first key, every block), the
+    three in the middle are whole, and the last is cut by the diagonal (even i: four blocks; odd i: every block): 12 x
+    (8 + 24 + 4) + 12 x (4 + 24 + 8) + the first eight Q tiles' 4 + 8 + 12 + 16 + 20 + 24 + 28 + 32 = 1,008 of the 140 x
+    8 = 1,120 blocks walked: 1.11 walked a block scored where Trinity-Mini's window of 2,048 reads 1.2, and 84 of the 140
+    pairs whole where 30 of its 90 are. The group of 7: one key/value head's whole group a program, 4 programs a call."""
+    mask = SlidingWindow(4096)
+    plan = kernel_plan((1, 28, 16384, 128), mask, kv_heads=4)
+    assert plan[:3] == (512, 1024, 140) and plan.scope == "tiles_140of512"
+    assert plan.tiles_masked == 56  # crossed: every Q tile's diagonal pair (32) and, from i = 8 on, the edge's (24)
+    forward, backward = fa._fwd_schedule(16384, plan, mask), fa._pair_schedule(16384, plan, mask)
+    assert "/".join(fa._walk_scope(forward, 16384, 512, 1024)) == "tiles_140of512/keys_1008of1120"
+    assert "/".join(fa._walk_scope(backward, 16384, 512, 1024)) == "tiles_140of512/keys_1008of1120"
+    want = {(i, j) for i in range(32) for j in range(16) if 1024 * j <= 512 * i + 511 and 1024 * j + 1023 >= 512 * i - 4095}
+    assert set(zip(*forward[:2])) == want and len(want) == 140
+    spans = {(i, j): (first, count) for i, j, first, count in forward[[0, 1, 5, 6]].T}
+    assert spans[9, 0] == (4, 4) and spans[9, 1] == spans[9, 2] == spans[9, 3] == spans[9, 4] == (0, 8)
+    assert spans[8, 0] == spans[8, 3] == (0, 8) and spans[8, 4] == (0, 4)
+    assert sum(count for _, count in spans.values()) == 1008
+    kept = 4096 * 4097 // 2 + (16384 - 4096) * 4096
+    assert kept == 58_722_304 and kept / (16384 * 16385 // 2) == pytest.approx(0.4375, abs=1e-4)
+    # the forward's program: 28, 14, 7 or 1 heads are admitted at group 7, and the whole group is what fits
+    assert fa._fwd_pairs_plan(7, 28, 128, 2, plan) == (7, 512)
+    assert fa._fwd_pairs_plan(7, 28, 128, 2, kernel_plan((1, 28, 16384, 128), True, kv_heads=4)) == (7, 512)
+
+
+@pytest.mark.parametrize("check", [test_both_schedules_walk_the_band_and_nothing_else,
+                                   test_every_live_span_holds_every_kept_score_of_its_pair_and_no_block_more])
+def test_the_schedules_hold_at_a_window_of_4096_on_16384(check):
+    check(16384, 4096, 512, 1024)
+    check(16384, 4096, 256, 1024)
+
+
+# heads, key/value heads, and the VMEM a forward program may hold (None: the module's), which decides the heads a
+# program takes among those admitted at group 7: a key/value head's whole group, two groups with their two key/value
+# heads, or a head a program beside six others on the same key/value head (`parts` = 7).
+@pytest.mark.parametrize("heads,kv_heads,vmem,planned,window,tiles", [
+    (7, 1, None, 7, 200, (128, 128)), (14, 2, None, 14, None, (128, 256)), (14, 2, 4_000_000, 7, 200, (128, 128)),
+    (14, 2, 2_000_000, 1, None, (128, 256)), (28, 4, None, 28, 200, (128, 128))])
+def test_groups_of_seven_are_the_dense_masked_softmax_forward_and_in_dq_dk_dv(monkeypatch, heads, kv_heads, vmem, planned,
+                                                                             window, tiles):
+    """28 on 4 as it is: no key/value head repeated, no group padded to 8. `dk`, `dv` of a key/value head are its seven
+    query heads' sums (`_flash_pairs_bwd`'s reshape by the group), whatever the forward's program took."""
+    seq = 512
+    if vmem is not None:
+        monkeypatch.setattr(fa, "FWD_PAIRS_VMEM_BYTES", vmem)
+    mask = True if window is None else SlidingWindow(window)
+    plan = kernel_plan((1, heads, seq, 32), mask, *tiles, kv_heads=kv_heads)
+    assert fa._fwd_pairs_plan(7, heads, 32, 4, plan)[0] == planned
+    q, k, v, w = _operands(seq, heads, kv_heads, 32, seed=5)
+    want, want_g = _value_and_grads(_dense_softmax(seq if window is None else window), q, k, v, w)
+    f = lambda q, k, v: flash_attention(q, k, v, causal=mask, backend="pallas", interpret=True,  # noqa: E731
+                                        block_q=tiles[0], block_k=tiles[1])
+    got, got_g = _value_and_grads(f, q, k, v, w)
+    assert got_g[1].shape == got_g[2].shape == (1, kv_heads, seq, 32)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
+    for mine, theirs in zip(got_g, want_g):
+        np.testing.assert_allclose(np.asarray(mine), np.asarray(theirs), atol=1e-4)
+    text = jax.jit(f).lower(q, k, v).as_text(debug_info=True)
+    assert f"group_{planned}/flash_fwd" in text

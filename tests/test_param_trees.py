@@ -22,6 +22,7 @@ DRAWN_BEFORE = {
     "lfm2-nano": "1d61c5948566f75b", "olmo-hybrid-nano": "7f11c542d0ce6481", "olmoe-nano": "5d790fe0d7854f89",
     "sdar-nano": "5806c8772689c752", "solar-open2-nano": "d31cc99197298c4c", "trinity-nano": "dc19f5da3c133fe5",
     "xing4-nano": "02b3e5cc84f92fe8",  # PR 66: GLM's tree and key scheme with two `hc_*` groups a layer
+    "smallthinker-nano": "7c2de87a54adfe30",  # PR 70: `stack.lm_tree` with no leading layer, `gqa_experts`' leaves less the head norms
 }
 
 
