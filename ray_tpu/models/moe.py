@@ -99,40 +99,56 @@ from ray_tpu.ops.sum_rows import gather_rows, sorted_runs, sum_rows
 # the pairs where the even share is 0.125 (PERF.md section 6, PR 35 and PR 36).
 HELD_ROWS_OVER_EVEN = 2
 ROW_TILE = 512  # the longest row tile a kernel takes (`grouped_matmul.DRHS_ROW_TILES`)
-GATHER_SOURCE_BYTES = 128 * 2 ** 20  # from here on a prefix is gathered by the kernel (`_rows_by`)
+GATHER_SOURCE_BYTES = 128 * 2 ** 20  # the v5e's VMEM: a prefix out of a source with no room there is the kernel's
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
-def _gather_rows(x, order, inverse, runs, k: int):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def _gather_rows(x, order, inverse, runs, k: int, beside_tokens: bool = False):
     """Row `order[i] // k` of `x` for every i: the tokens in expert order.
     `order` is the sort of the `tokens * k` pairs, or its first rows where
     they hold every pair of a held expert, and `inverse` undoes the whole
     sort, so the gradient sums each token's `k` rows where they lie
     (`_sum_rows`; `runs` says where, `ops/sum_rows.py sorted_runs`), where the
-    transpose jax would derive is a scatter-add of `tokens * k` rows."""
-    return _rows_by(x, order, inverse, runs, k)
+    transpose jax would derive is a scatter-add of `tokens * k` rows.
+    `beside_tokens`: `x` is a cotangent of the tokens' size, gathered in a
+    backward pass that gathers the tokens too (`_rows_by`)."""
+    return _rows_by(x, order, inverse, runs, k, beside_tokens)
 
 
-def _rows_by(x, order, inverse, runs, k):
-    """Which gather runs, read off the shapes. XLA's gather writes 4 KB rows
-    at 14-24 ns a row out of a source of 33-100 MB (OLMoE's 65,536 rows out of
-    8,192 tokens at 650-730 GB/s, at its floor) and at 36 ns a row, a copy
-    descriptor's price, out of one of 128 MiB, which is also the v5e's VMEM:
-    so a prefix of the sort (`order` shorter than `inverse`: half of its rows
-    are nobody's) out of a source that large goes through `ops/sum_rows.py
-    gather_rows`, the kernel that writes the owned rows alone a block of tokens
-    at a time (0.63 ms for 1.19 at LFM2's 32,768 x 2,048; 0.23 for 0.16 at
-    GLM's 8,192, 0.37 for 0.38 at 16,384: PERF.md section 6, PR 40)."""
-    if order.shape[0] < inverse.shape[0] and x.size * x.dtype.itemsize >= GATHER_SOURCE_BYTES:
+def _rows_by(x, order, inverse, runs, k, beside_tokens=False):
+    """Which gather runs, read off the shapes. What XLA's gather costs a row
+    goes by where its source lies. Out of VMEM (128 MiB on the v5e; XLA's
+    memory-space assignment marks the operand `S(1)` and copies it in first)
+    it writes 4-5 KB rows at 7-24 ns a row (OLMoE's 65,536 rows out of 8,192
+    tokens at 650-730 GB/s, at its floor; SmallThinker's 49,152 out of 16,384 x
+    2,560 in 0.39 ms). Out of HBM it pays a copy descriptor's price, 36-41 ns a
+    row: a source of 128 MiB, which has no room there (LFM2's), and in a
+    backward pass a cotangent of more than half of that, because the layer's
+    tokens, as large, already lie in VMEM for the `dispatch` gather made again
+    (SmallThinker's 80 MiB: 2.02 ms for 49,152 rows, 0.83 through the kernel;
+    PERF.md section 6, PR 72). So a prefix of the sort (`order` shorter than `inverse`: half of its
+    rows are nobody's) out of such a source goes through `ops/sum_rows.py
+    gather_rows`, the kernel that reads the source once a block of tokens and
+    writes the owned rows alone (0.63 ms for 1.19 at LFM2's 32,768 x 2,048;
+    0.23 for 0.16 at GLM's 8,192, 0.37 for 0.38 at 16,384: PERF.md section 6,
+    PR 40). At exactly half of VMEM no shape tells where XLA leaves a cotangent
+    (SDAR's and Keye's in HBM, Trinity-Mini's in VMEM: PERF.md section 7), and
+    XLA's gather stays. The kernel leaves the rows behind the owned ones (and
+    the zeros to the next row tile) unwritten: a cotangent's go into
+    `grouped_matmul`'s backward rule under `short=True` and `_held_rows`'
+    selects (`_sorted_form`), which read no such row."""
+    source = x.size * x.dtype.itemsize
+    no_room = 2 * source > GATHER_SOURCE_BYTES if beside_tokens else source >= GATHER_SOURCE_BYTES
+    if order.shape[0] < inverse.shape[0] and no_room:
         return gather_rows(x, order, inverse, runs, k)
     return x[order // k]
 
 
-def _gather_rows_fwd(x, order, inverse, runs, k):
-    return _rows_by(x, order, inverse, runs, k), (order, inverse, runs)
+def _gather_rows_fwd(x, order, inverse, runs, k, beside_tokens):
+    return _rows_by(x, order, inverse, runs, k, beside_tokens), (order, inverse, runs)
 
 
-def _gather_rows_bwd(k, res, g):
+def _gather_rows_bwd(k, beside_tokens, res, g):
     return _sum_rows(g, *res, k), None, None, None
 
 
@@ -148,7 +164,8 @@ def _sum_rows_fwd(rows, order, inverse, runs, k):
 
 
 def _sum_rows_bwd(k, res, g):
-    return _gather_rows(g, *res, k), None, None, None
+    # The cotangent's gather stands beside the tokens' own, made again for the backward pass.
+    return _gather_rows(g, *res, k, True), None, None, None
 
 
 def _iota(like):

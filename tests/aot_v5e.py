@@ -271,6 +271,45 @@ def element_moves(text, scopes):
     return found
 
 
+def row_gathers(text, scopes, per_token):
+    """Of a compiled step's text: every XLA gather of whole rows (`slice_sizes={1,width}`) out of a two-dimensional
+    source under the expert layer's `dispatch` or `combine`, one entry an instruction that runs (the fusion that
+    calls the gather's computation, else the gather itself): `phase` (the dispatch gather that the backward `cond`
+    makes again reads `backward`), `scope` (`dispatch`: the tokens' gather; `combine`: its transpose's, the
+    cotangent's), `branch` (`whole`: `per_token` rows a token of the source, every pair; else `compact`, the held
+    prefix), `rows` gathered out of `source_rows`, and `in_vmem`: whether the source's layout carries `S(1)`, the
+    memory space XLA's assignment gives an operand it copies into VMEM first. From there a row costs the v5e 8 ns,
+    from HBM a copy descriptor's 36-41 (PERF.md section 6, PR 72). A gather that `gather_rows` replaced is a
+    Mosaic call and stands in `mosaic_scopes`, not here."""
+    fused = fused_computations(text)
+    result, gathers, callers = {}, [], {}
+    for computation, line in by_computation(text):
+        m = RESULT.match(line)
+        if not m:
+            continue
+        result[computation, m.group(1)] = m.group(2)
+        called = FUSED.search(line)
+        if m.group(3) == "fusion" and called:
+            callers.setdefault(called.group(1), []).append(m.group(1))
+        sliced = m.group(3) == "gather" and re.search(r"slice_sizes=\{1,(\d+)\}", line)
+        if sliced:
+            source = m.group(4).split(",")[0].strip().lstrip("%")
+            gathers.append((computation, m.group(1), source, int(sliced.group(1))))
+    found = []
+    for computation, name, source, width in gathers:
+        layout = result.get((computation, source), "")
+        shape = re.match(r"\w+\[(\d+),%d\]" % width, layout)
+        rows = re.match(r"\w+\[(\d+),%d\]" % width, result[computation, name])
+        for runs in (callers.get(computation, []) if computation in fused else [name]):
+            parts = re.split(r"[/()]", scopes.get(runs, ""))
+            if shape and rows and {"dispatch", "combine"} & set(parts):
+                found.append({
+                    "phase": phase(scopes[runs]), "scope": "combine" if "combine" in parts else "dispatch",
+                    "branch": "whole" if int(rows.group(1)) == per_token * int(shape.group(1)) else "compact",
+                    "rows": int(rows.group(1)), "source_rows": int(shape.group(1)), "in_vmem": "S(1)" in layout})
+    return found
+
+
 def block_weight_gathers(text, scopes):
     """Of a compiled step's text: the minor dimension of every all-gather
     under the `blocks` scope (the scanned layers' weights: nothing else is
@@ -632,6 +671,7 @@ def _step_case(topo, cell):
         out["sorted_rows_moved"], out["backward_scatter_adds"] = sorted_row_traffic(
             text, scopes, rows * seq * per_token, c["hidden_size"])
         out["element_moves"] = element_moves(text, scopes)
+        out["row_gathers"] = row_gathers(text, scopes, per_token)
     return out
 
 
@@ -833,6 +873,27 @@ def holds_the_logits_alone(got):
     `["bf16 reshape", "f32 copy", "f32 fusion", "f32 fusion"]`: d logits in float32, their relayout, the scatter's
     result (the gradient of the loss's gather) and the bf16 copy turned over for the two backward products."""
     assert got["logits_sized"] == ["f32 fusion product"], got["logits_sized"]
+
+
+def rows_gathered_by_xla(got, branch="compact"):
+    """PR 72: of a step case, {(phase, scope): [whether the source lay in VMEM, a gather]} of the XLA row gathers under
+    `dispatch` / `combine` in `branch` of the expert layer (`row_gathers`)."""
+    found = {}
+    for g in got["row_gathers"]:
+        if g["branch"] == branch:
+            found.setdefault((g["phase"], g["scope"]), []).append(g["in_vmem"])
+    return found
+
+
+def prefix_form_calls(got, kernel="gather_rows"):
+    """Of a step case, the calls of a row mover (`gather_rows`, `sum_rows`) by form of the expert layer: (the sorted
+    (phase, `dispatch` or `combine`, whether in the forward pass made again inside the backward `cond`) of those in
+    the branch over the held prefix, `jit(_prefix_or_whole)/cond/branch_1_fun`; how many stand anywhere else)."""
+    scopes = [n for n in got["mosaic_scopes"] if n.split("/")[-2] == kernel]
+    prefix = [n for n in scopes if "branch_1_fun" in n.split("jit(_prefix_or_whole)/cond/")[1].split("/")[0]]
+    where = sorted((phase(n), *({"dispatch", "combine"} & set(n.split("/"))), "jvp(sorted_form)" in n.split("/"))
+                   for n in prefix)
+    return where, len(scopes) - len(prefix)
 
 
 def stacks_ending(got, tail):

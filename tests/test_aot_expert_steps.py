@@ -166,12 +166,9 @@ def test_the_prefix_form_moves_its_rows_through_the_two_kernels(aot, kernel):
     (`combine`, and `dispatch`'s transpose): no XLA gather of rows is left in
     it (`ROW_GATHERS` counts the whole-length branch's three a layer), and the
     whole-length branch calls no `gather_rows`."""
-    scopes = [n for n in aot(LFM2)["mosaic_scopes"] if n.split("/")[-2] == kernel]
-    prefix = [n for n in scopes if "branch_1_fun" in n.split("jit(_prefix_or_whole)/cond/")[1].split("/")[0]]
-    where = sorted((phase(n), *({"dispatch", "combine"} & set(n.split("/"))), "jvp(sorted_form)" in n.split("/"))
-                   for n in prefix)
+    where, elsewhere = aot_v5e.prefix_form_calls(aot(LFM2), kernel)
     assert where == sorted(PREFIX_KERNELS[kernel] * 4), where
-    assert len(scopes) - len(prefix) == {"gather_rows": 0, "sum_rows": 8}[kernel]
+    assert elsewhere == {"gather_rows": 0, "sum_rows": 8}[kernel]
 
 
 @pytest.mark.parametrize("cell", sorted(ROW_GATHERS))

@@ -57,6 +57,20 @@ def test_the_sdar_step_holds_no_clone_of_a_product_and_its_scan_stacks_o_once(ao
     assert got["recomputed"] <= 407
 
 
+def test_xla_gathers_the_cotangent_of_the_held_prefix_out_of_hbm(aot):
+    """What stands today (PR 72), pinned so that a libtpu that flips it is seen; the open item of PERF.md section 7,
+    not a wish: at 16,384 x 2,048 (64 MiB, half of the v5e's VMEM) and 32,768 rows asked, the layer `while`'s body
+    gathers the tokens out of a source marked `S(1)`, forward and again for the backward pass, and the cotangent
+    (`combine`'s transpose) out of HBM, at a copy descriptor's price a row: about 3.5 ms a step over the five
+    layers by SmallThinker's prices (not measured here). `moe._rows_by` keeps XLA's gather at this size because
+    Trinity-Mini's cotangent of the same size lies in VMEM (`tests/test_aot_trinity_step.py`)."""
+    got = aot(SDAR)
+    assert aot_v5e.prefix_form_calls(got) == ([], 0)
+    assert aot_v5e.rows_gathered_by_xla(got) == {
+        ("forward", "dispatch"): [True], ("backward", "dispatch"): [True], ("backward", "combine"): [False]}
+    assert {(g["rows"], g["source_rows"]) for g in got["row_gathers"]} == {(32768, 16384), (131072, 16384)}
+
+
 def test_nothing_of_the_logits_size_stands_beside_the_logits(aot):
     """8,192 noised positions x 18,992 through `causal_lm_loss(weights=)`: until PR 68 `fusion.322`, `copy.524`,
     `fusion.9` and `reshape.1703` beside the head's product; 10,114 instructions for 10,180, the peak the same."""

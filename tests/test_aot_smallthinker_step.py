@@ -53,13 +53,31 @@ def test_the_experts_half_stands_behind_attention_and_its_kernels_under_the_kind
     assert {kind for p in kernels for kind in ("window", "full") if kind in p} == {"window", "full"}
 
 
+def test_the_cotangents_gather_is_the_kernels_and_the_tokens_is_xlas_out_of_vmem(aot):
+    """PR 72: in the branch over the held prefix a layer gathers its 16,384 x 2,560 tokens (80 MiB) twice, forward and
+    again in the backward `cond`, by XLA's gather out of a source that memory-space assignment marked `S(1)`, VMEM
+    (0.39 ms for 49,152 rows on the chip); the cotangent of the same size has no room beside them, and XLA's gather
+    read it from HBM (`fusion.5/.7/.11/.15`, 2.02 ms each) until `moe._rows_by` sent it through `gather_rows`: four
+    calls under backward `combine`, no XLA row gather left there. The whole-length branch is as it was, its
+    cotangent's gather out of HBM among it."""
+    got = aot(SMALLTHINKER)
+    assert aot_v5e.prefix_form_calls(got) == ([("backward", "combine", False)] * 4, 0)
+    assert aot_v5e.rows_gathered_by_xla(got) == {("forward", "dispatch"): [True] * 4, ("backward", "dispatch"): [True] * 4}
+    assert aot_v5e.rows_gathered_by_xla(got, "whole") == {
+        ("forward", "dispatch"): [True] * 4, ("backward", "dispatch"): [True] * 4, ("backward", "combine"): [False] * 4}
+    assert {(g["rows"], g["source_rows"]) for g in got["row_gathers"]} == {(49152, 16384), (98304, 16384)}
+
+
 def test_the_step_fits_the_chip_with_four_gigabytes_to_spare(aot):
     """559.3 M parameters x 12 B and the 377.5 M held expert parameters' bf16 copy (PR 64) are the arguments (the
     gradient is a temporary); XLA's peak is 12,515,543,552 B, 74 % of the chip's 16.91 GB, with sixteen experts held a
     layer, every head and `save_attn`: ISSUE 70 expected 15-16 GB (Trinity-Mini's 14.62 holds a dense layer, an output
     gate and sandwich norms beside its five layers) and its fall-back of eight experts was not needed. Two rows
     compile to 16,388,031,488 B, which leaves nothing beside the program. The file records this compile as it is, the
-    copy among the arguments."""
+    copy among the arguments. Since PR 72 the cotangent's four gathers are `gather_rows` calls and XLA's peak is
+    12,515,545,088 B, 1,536 B over the file's reading of PR 70 (temporaries 5,872,948,736 for 5,872,114,688; the
+    252 MB result buffer is the same and no residual is new): the file is the benchmark's and stays as it is, so the
+    peak is held to it within a mebibyte."""
     got = aot(SMALLTHINKER)
     assert got["compute_copy_bytes"] == HELD_EXPERT_PARAMETERS * 2 == 754_974_720
     state = got["argument"] - got["compute_copy_bytes"]
@@ -67,7 +85,7 @@ def test_the_step_fits_the_chip_with_four_gigabytes_to_spare(aot):
     with open(os.path.join(aot_v5e.REPO, "benchmark", "configs", SMALLTHINKER + ".json")) as fh:
         recorded = json.load(fh)["memory_analysis_v5e_bytes"]
     assert got["argument"] == recorded["arguments"] and got["compute_copy_bytes"] == recorded["compute_copy_bytes"]
-    assert got["peak"] is not None and 0.25 * V5E_HBM_BYTES < got["peak"] <= recorded["peak_memory"] <= 12.52e9
+    assert got["peak"] is not None and 0.25 * V5E_HBM_BYTES < got["peak"] <= recorded["peak_memory"] + (1 << 20) <= 12.52e9
     assert recorded["peak_memory"] + (1 << 29) < V5E_HBM_BYTES < recorded["two_rows_peak_memory"] + (1 << 29)
     assert got["remat_products"] == 0 and got["remat_clones"] == [] and got["recomputed"] <= 140
 

@@ -42,6 +42,18 @@ def test_the_step_hands_mosaic_four_band_calls_and_one_triangle_each_pass(aot):
     assert got["element_moves"]["scalars"] == [] and got["backward_scatter_adds"] == []
 
 
+def test_xla_holds_both_gathered_sources_of_the_held_prefix_in_vmem(aot):
+    """What stands today (PR 72): at 16,384 x 2,048 (64 MiB, half of the v5e's VMEM) and 16,384 rows asked, the tokens'
+    gathers and the cotangent's all read a source marked `S(1)`, so `moe._rows_by` keeps XLA's gather for all twelve:
+    the kernel would cost this cell about 0.5 ms a step. SDAR's and Keye's cotangent of the same size lies in HBM
+    (`tests/test_aot_sdar_step.py`): no shape tells them apart (PERF.md section 7)."""
+    got = aot(TRINITY)
+    assert aot_v5e.prefix_form_calls(got) == ([], 0)
+    assert aot_v5e.rows_gathered_by_xla(got) == {
+        ("forward", "dispatch"): [True] * 4, ("backward", "dispatch"): [True] * 4, ("backward", "combine"): [True] * 4}
+    assert {(g["rows"], g["source_rows"]) for g in got["row_gathers"]} == {(16384, 16384), (131072, 16384)}
+
+
 def test_the_step_fits_the_chip_with_a_gigabyte_and_four_tenths_to_spare(aot):
     """504.1 M parameters x 12 B and the 201.3 M held expert parameters' bf16 copy (PR 64) are the arguments (the
     gradient is a temporary), and what the file records is this compile's less the copy. Pinned again at PR 64, on

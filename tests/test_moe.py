@@ -205,16 +205,21 @@ def test_the_bound_is_twice_the_even_share_in_whole_row_tiles(pairs, n_held, n_e
 
 
 @functools.lru_cache(maxsize=None)
-def _share_at_kernel_shapes(through_the_kernels):
+def _share_at_kernel_shapes(through_the_kernels, cotangent_through_gather_rows=False):
     """Output, gradients and `aux` of a layer that holds expert 5 of 8 at
     shapes the Pallas kernels take (512 tokens of 128, two a token: 1,024
     pairs, a bound of 512 rows), through the kernels in interpret mode or
-    through the XLA forms."""
+    through the XLA forms. `cotangent_through_gather_rows`: VMEM's size is
+    patched down to just under twice the tokens' bytes, so that the cotangent's
+    gather is `gather_rows` and the tokens' stays XLA's (`moe._rows_by`); what
+    that kernel does not write is NaN, and `aux["gathered_by_the_kernel"]`
+    lists the sources it was handed."""
     import jax
+    from jax.experimental.pallas import tpu as pltpu
 
     from ray_tpu.models import moe
     from ray_tpu.ops.grouped_matmul import grouped_matmul
-    from ray_tpu.ops.sum_rows import sum_rows
+    from ray_tpu.ops.sum_rows import gather_rows, sum_rows
 
     tokens, d, f = 512, 128, 128
     keys = jax.random.split(jax.random.PRNGKey(3), 6)
@@ -226,8 +231,17 @@ def _share_at_kernel_shapes(through_the_kernels):
     with pytest.MonkeyPatch.context() as patch:
         patch.setattr(moe, "sum_rows", functools.partial(sum_rows, **backend))
         patch.setattr(moe, "grouped_matmul", functools.partial(grouped_matmul, **backend))
+        # A jit of its own: `moe._prefix_or_whole_jit` would hand back the trace of whichever of these ran first.
+        patch.setattr(moe, "_prefix_or_whole_jit", jax.jit(
+            lambda *operands: moe._prefix_or_whole(*operands), static_argnums=(0, 1, 2)))
+        gathered = []
+        if cotangent_through_gather_rows:
+            unwritten_is_nan = pltpu.InterpretParams(uninitialized_memory="nan")
+            patch.setattr(moe, "gather_rows", lambda x, *plan: gathered.append(x.shape) or gather_rows(
+                x, *plan, backend="pallas", interpret=unwritten_is_nan))
+            patch.setattr(moe, "GATHER_SOURCE_BYTES", 2 * tokens * d * x.dtype.itemsize - 1)
         out, vjp, aux = jax.vjp(lambda x, *w: moe.moe_mlp(x, *w, k=2, held_from=5), x, *weights, has_aux=True)
-        return [np.asarray(a) for a in (out, *vjp(cotangent))], aux
+        return [np.asarray(a) for a in (out, *vjp(cotangent))], {**aux, "gathered_by_the_kernel": gathered}
 
 
 @pytest.mark.parametrize("what", range(6), ids=("out", "x") + WEIGHTS)

@@ -352,3 +352,44 @@ def test_which_gather_runs_is_read_off_the_shapes(tokens, rows, kernel):
                              *(jax.ShapeDtypeStruct(*s) for s in (((tokens, 2048), jnp.bfloat16), ((rows,), jnp.int32),
                                                                  ((tokens * k,), jnp.int32))))
     assert out.shape == (rows, 2048) and bool(called) == kernel
+
+
+# ------------------------------------------- the cotangent's gather: beside the tokens, half of VMEM is the bound
+def _kernels_by_scope(jaxpr, above=""):
+    """(a `pallas_call`'s name, the named scopes it stands under), for every one in `jaxpr` and whatever it calls."""
+    for eqn in jaxpr.eqns:
+        here = f"{above}/{eqn.source_info.name_stack}"
+        if eqn.primitive.name == "pallas_call":
+            yield eqn.params["name"], here
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (tuple, list)) else [value]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _kernels_by_scope(sub, here)
+
+
+@pytest.mark.parametrize("tokens, k, d, f, n_experts, held, scopes", [
+    (16384, 6, 2560, 768, 64, 16, ["transpose(jvp(combine))"]), (16384, 8, 2048, 1024, 128, 8, []),
+    (16384, 8, 2048, 768, 128, 16, []), (8192, 4, 2048, 1536, 64, 8, []),
+    (32768, 4, 2048, 1536, 64, 8, ["jvp(dispatch)", "transpose(jvp(combine))"])],
+    ids=("smallthinker", "trinity-mini", "sdar", "glm-4.7-flash", "lfm2"))
+def test_a_cotangent_over_half_of_vmem_is_gathered_by_the_kernel_and_the_tokens_are_not(
+        tokens, k, d, f, n_experts, held, scopes):
+    """`moe._rows_by` under `_sorted_form` and its backward pass at the cells' shapes, traced from abstract
+    operands (nothing is computed): SmallThinker's 80 MiB of tokens are XLA's gather forward (they fit VMEM) and
+    the cotangent's, which has no room beside them, the kernel's; at 64 MiB and under both are XLA's (no shape says
+    where XLA leaves SDAR's cotangent and where Trinity-Mini's: PERF.md section 7); LFM2's 128 MiB both the kernel's."""
+    sd, pairs = jax.ShapeDtypeStruct, tokens * k
+    n = moe.held_row_bound(pairs, held, n_experts)
+    assert n < pairs
+    operands = (sd((tokens, d), jnp.bfloat16), sd((pairs,), jnp.float32), sd((held, d, f), jnp.bfloat16),
+                sd((held, d, f), jnp.bfloat16), sd((held, f, d), jnp.bfloat16))
+    plan = (sd((held,), jnp.int32), *(sd((pairs,), jnp.int32),) * 3,
+            jax.eval_shape(lambda experts: sr.sorted_runs(experts, held, True), sd((tokens, k), jnp.int32)))
+
+    def both_passes(operands, plan, g):
+        return jax.vjp(lambda *o: moe._sorted_form(n, k, True, jax.nn.silu, *o, *plan)[0], *operands)[1](g)
+
+    kernels = list(_kernels_by_scope(jax.make_jaxpr(both_passes)(operands, plan, operands[0]).jaxpr))
+    assert sorted(where.strip("/").split("/")[0] for name, where in kernels if name == "gather_rows") == scopes
+    assert [name for name, _ in kernels].count("sum_rows") == 2  # `combine` forward, `dispatch`'s gradient

@@ -4,9 +4,10 @@
     chiprun -- python3 tools/sum_rows_bench.py --shapes 8192x8x2048x64 --held 0
 
 For each shape (tokens x k x width x experts [x held], bf16; the defaults are
-the three expert cells': LFM2's and GLM-4.7-Flash's layers, which hold 8 of 64
-experts, and OLMoE's, which holds all) two things run alone, `--calls`
-back-to-back dispatches closed by `block_until_ready`, median of `--rounds`:
+four expert cells': LFM2's and GLM-4.7-Flash's layers, which hold 8 of 64
+experts, SmallThinker's, which holds 16 of 64, and OLMoE's, which holds all)
+two things run alone, `--calls` back-to-back dispatches closed by
+`block_until_ready`, median of `--rounds`:
 
 - the sum, `out[t] = sum of rows[inverse[t * k : t * k + k]]` over rows in
   expert order: `sum_rows` (the Pallas kernel `ray_tpu/models/moe.py` runs as
@@ -16,6 +17,16 @@ back-to-back dispatches closed by `block_until_ready`, median of `--rounds`:
   `gather_rows`, the kernel the prefix form runs (no line where the shape holds
   every expert: `models/moe.py` keeps XLA's gather there). With `--sweep`, XLA's
   gather once more for 8,192 / 32,768 / 65,536 indices out of each source.
+  `xla_gather_of_two` is the same gather of two sources of one size in one
+  program, what a backward pass does (the tokens for `dispatch` made again, the
+  cotangent for `combine`'s transpose): `in_vmem` says, gather by gather in the
+  compiled program's order, whether XLA's memory-space assignment marked the
+  source `S(1)` (copied into VMEM first: 7-8 ns a row) or left it in HBM (a copy
+  descriptor's 36-41 ns a row), and `us_over_one` what the second gather cost
+  over `xla_gather` alone. A gather alone gets VMEM for any source under the
+  v5e's 128 MiB, so its time says nothing of the step's (PERF.md section 6,
+  PR 72); where the step's own schedule leaves a source is in its compiled text
+  (`tests/aot_v5e.py row_gathers`).
 
 Where a layer holds `held` of the experts (`--held N` for shapes that do not
 say), the pairs of the others sort behind the held ones and the sorted rows
@@ -53,8 +64,9 @@ from statistics import median
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 # The expert layers of `lfm2-24b-a2b-ep8-l5` (8 x 4,096 tokens, 4 of 64 experts a token, 8 held),
-# `glm-4.7-flash-ep8-l5` (2 x 4,096, likewise) and `olmoe-1b-7b-l1` (2 x 4,096, 8 of 64, all held).
-DEFAULT_SHAPES = "32768x4x2048x64x8,8192x4x2048x64x8,8192x8x2048x64x0"
+# `glm-4.7-flash-ep8-l5` (2 x 4,096, likewise), `smallthinker-21b-a3b-l4` (1 x 16,384 of 2,560, 6 of 64, 16 held)
+# and `olmoe-1b-7b-l1` (2 x 4,096, 8 of 64, all held).
+DEFAULT_SHAPES = "32768x4x2048x64x8,8192x4x2048x64x8,16384x6x2560x64x16,8192x8x2048x64x0"
 HBM_BYTES_PER_S = {"TPU v5 lite": 819e9}
 HELD_ROWS_OVER_EVEN, ROW_TILE = 2, 512  # `models/moe.py held_row_bound`, for a checkout without it
 
@@ -69,6 +81,18 @@ def draw_experts(tokens: int, k: int, experts: int, skewed: bool, seed: int = 0)
         logits[0] = np.log(6.0)
     noise = np.random.default_rng(seed).gumbel(size=(tokens, experts))
     return np.argsort(-(logits + noise), axis=1)[:, :k].astype(np.int32)
+
+
+def sources_in_vmem(compiled_text: str):
+    """For every row gather of a compiled program's text, in its order: whether the layout of the gather's
+    source, the first parameter of the fused computation it stands in, carries `S(1)`."""
+    marks, source = [], ""
+    for line in compiled_text.splitlines():
+        if " parameter(0)" in line:
+            source = line
+        elif " gather(" in line:
+            marks.append("S(1)" in source.split(" parameter(0)")[0])
+    return marks
 
 
 def main(argv=None):
@@ -197,6 +221,17 @@ def main(argv=None):
                     except Exception as e:  # a shape the compiler refuses: say so, go on
                         line["error"] = f"{type(e).__name__}: {e}"[:300]
                     emit(line)
+            if routing == "even":  # a second source of the same size beside the first, in one program
+                one, two = jax.jit(lambda x, index: x[index]), jax.jit(lambda x, g, index: (x[index], g[index]))
+                index, g = order[:n] // k, x + jnp.ones((), x.dtype)
+                us_one, us_two = timed(one, x, index), timed(two, x, g, index)
+                emit({**base, "implementation": "xla_gather_of_two", "us": round(us_two, 1),
+                      "us_over_one": round(us_two - us_one, 1),
+                      "ns_a_row_of_the_second": round((us_two - us_one) * 1e3 / n, 2),
+                      "in_vmem": sources_in_vmem(two.lower(x, g, index).compile().as_text()),
+                      "alone_in_vmem": sources_in_vmem(one.lower(x, index).compile().as_text()),
+                      "source_mib": tokens * width * 2 / 2 ** 20, "rounds": args.rounds, "calls": args.calls,
+                      "device": device})
             if args.sweep and routing == "even":
                 gather = jax.jit(lambda x, index: x[index])
                 for count in (8192, 32768, 65536):
