@@ -1,19 +1,32 @@
-"""The walk of a chunked recurrent scan, once for every rule that has one (`ops/gated_delta_rule.py`, `ops/kda.py`):
-a recurrence whose state is a matrix (d_k, d_v) a head, rewritten over chunks of C positions as matrix products, with
-the state a chunk hands to the next in VMEM scratch.
+"""The walk of a chunked recurrent scan, once for every rule that has one (`ops/gated_delta_rule.py`, `ops/kda.py`,
+`ops/ssd.py`): a recurrence whose state is a matrix (d_k, d_v) a head, rewritten over chunks of C positions as matrix
+products, with the state a chunk hands to the next in VMEM scratch.
 
 A rule (`Rule`) brings the mathematics of one chunk of one head on plain two-dimensional arrays, three functions of
 its own module: `chunk_gates(k, gam, beta)`, all that stands before the inverse of `I + A` (a dict with `a`, the
 strictly lower triangular `A`, among whatever the other two read); `chunk_fwd(q, k, v, gam, beta, s, first=None)` ->
 (o, the state after the chunk); `chunk_bwd(q, k, v, gam, beta, s, do, ds_new, first=None)` -> (dq, dk, dv, dgam,
 dbeta, ds), where `first` is `chunk_gates`' dict with the inverse `t` added. `gam` is the running sum of the log decay
-inside the chunk, `beta` a row (1, C). This module does the rest, the same for every rule:
+inside the chunk, `beta` a row (1, C) (or None, and `dbeta` None with it). This module does the rest, the same for
+every rule:
 
 The XLA form (`_xla_form`) maps `chunk_fwd` over batch and heads inside a `lax.scan` over the chunks: what runs off
 the TPU, differentiated by jax, and what the kernels are held to. The Mosaic kernels `<rule>_fwd` and `<rule>_bwd`
 call the same functions on their blocks, the chunks along a sequential grid axis with the state (`dS` in the
 reverse walk, which starts from the row's end) in VMEM scratch. The forward kernel writes out the state every chunk
 starts from, (B, H, S / C, d_k, d_v) f32, for the backward pass, which makes `T` and `N` again.
+
+What a rule may leave out. The inverse: a rule with no solve inside a chunk (a plain decayed outer-product write,
+`ops/ssd.py`) says `inverse=False`; its `chunk_gates` brings no `a`, the walk makes no `T` and `first` has no `t`.
+`beta`: a call without one (`chunked_scan(..., beta=None)`) hands the three functions `None` in its place, pads a row
+with zero values (no write), and the kernels have neither the operand nor its gradient.
+
+A group's q and k. Where q and k hold fewer heads than v, (B, H / n, S, d_k) beside (B, H, S, d_v), each is shared by
+the n consecutive heads of its group, and no copy a head is made: a program walks G heads of one group (G divides n)
+and its q and k blocks are the group's, found by the block's index map. The grid is then (groups, chunks, programs a
+group) with the last axis innermost, every head of the group keeps its state in scratch between its chunks, and in
+the reverse walk dq and dk are summed over the group's heads in f32 scratch and written once a chunk. The XLA form
+repeats q and k a head (small arrays, off the TPU) and jax sums their gradients.
 
 A program walks G heads, unrolled in one body (`heads_per_program`: a divisor of the heads the call holds, by the
 VMEM they need; the kernels' scope says which, `chunk_128/heads_3of30`). The doubling that makes `T`
@@ -33,6 +46,7 @@ and on more than one device runs the kernels inside a `shard_map` (XLA cannot pa
 
 from __future__ import annotations
 
+import contextlib
 import functools
 from typing import Callable, NamedTuple, Optional
 
@@ -66,6 +80,9 @@ class Rule(NamedTuple):
     chunk_flops: Callable  # (chunk, dk, dv, dtype, backward) -> FLOP of one chunk of one head: what XLA is told
     transcendentals: Callable  # (chunk, dk) -> exponentials of one chunk of one head, the same
     itemsize: Optional[int] = None  # the item size `heads_per_program` is asked at; None: the keys' own
+    # False: no solve inside a chunk. `chunk_gates`' dict has no `a`, the walk makes no inverse and `first` no `t`.
+    inverse: bool = True
+    max_heads: Optional[int] = None  # heads a program at most; None: `MAX_HEADS`, what the inverse's chain asks for
 
 
 def _bf16_parts(x):
@@ -138,17 +155,21 @@ def _chunked(x, chunk: int):
 
 def _xla_form(rule: Rule, q, k, v, gam, beta, chunk: int):
     """The chunked form on whole arrays: q, k (B, H, S, d_k), v (B, H, S, d_v), `gam` (B, H, S[, d_k]) and `beta`
-    (B, H, S) f32, S a whole number of chunks."""
+    (B, H, S) f32 or None, S a whole number of chunks. A group's q and k (fewer heads than v) are repeated a head."""
     over_heads = jax.vmap(jax.vmap(rule.functions()[1]))
+    share = v.shape[1] // k.shape[1]
+    if share > 1:
+        q, k = (jnp.repeat(x, share, axis=1) for x in (q, k))
 
     def one_chunk(s, xs):
         qc, kc, vc, gc, bc = xs
-        o, s = over_heads(qc, kc, vc, gc if rule.gate_a_channel else gc[:, :, None], bc[:, :, None], s)
+        o, s = over_heads(qc, kc, vc, gc if rule.gate_a_channel else gc[:, :, None],
+                          None if bc is None else bc[:, :, None], s)
         return s, o
 
     b, h, _, dk = k.shape
     s0 = jnp.zeros((b, h, dk, v.shape[-1]), F32)
-    _, o = jax.lax.scan(one_chunk, s0, tuple(_chunked(x, chunk) for x in (q, k, v, gam, beta)))
+    _, o = jax.lax.scan(one_chunk, s0, tuple(None if x is None else _chunked(x, chunk) for x in (q, k, v, gam, beta)))
     return jnp.moveaxis(o, 0, 2).reshape(v.shape).astype(v.dtype)
 
 
@@ -165,50 +186,92 @@ def _gate_at(rule: Rule, h: int, at):
     return (h,) if rule.gate_a_channel else (h, pl.ds(at, 1), slice(None))
 
 
-def _heads_of_a_program(rule: Rule, k_ref, gam_ref, beta_ref, at):
-    """[(k, gam, beta, `chunk_gates`' parts and the inverse `t`)] of chunk `at`, one a head of the program. The
-    heads share nothing, so their doublings are independent chains: made together, level by level."""
-    heads = [(k_ref[h], gam_ref[_gate_at(rule, h, at)], beta_ref[h, pl.ds(at, 1), :])
-             for h in range(k_ref.shape[0])]
+def _heads_of_a_program(rule: Rule, k_ref, gam_ref, beta_ref, at, heads: int):
+    """[(k, gam, beta, `chunk_gates`' parts and, where the rule has one, the inverse `t`)] of chunk `at`, one a head
+    of the program's `heads`; k is the head's own row of the block, or its group's where the block holds fewer. The
+    heads share nothing past their keys, so their doublings are independent chains: made together, level by level."""
+    of = lambda h: h * k_ref.shape[0] // heads  # noqa: E731
+    heads = [(k_ref[of(h)], gam_ref[_gate_at(rule, h, at)], None if beta_ref is None else beta_ref[h, pl.ds(at, 1), :])
+             for h in range(heads)]
     firsts = [_once(rule.functions()[0])(*head) for head in heads]
-    for first, t in zip(firsts, _once(_unit_lower_inverses)([first["a"] for first in firsts])):
-        first["t"] = t
+    if rule.inverse:
+        for first, t in zip(firsts, _once(_unit_lower_inverses)([first["a"] for first in firsts])):
+            first["t"] = t
     return [(*head, first) for head, first in zip(heads, firsts)]
 
 
-def _fwd_kernel(rule: Rule, q_ref, k_ref, v_ref, gam_ref, beta_ref, o_ref, states_ref, s_ref):
-    i = pl.program_id(1)
+def _slots(share: int, g: int):
+    """Where a program's g heads keep their state in scratch: the scratch is the program's own (`share` 1: a head a
+    q and k), or the group's `share` heads' and this program's run of it (the grid's last axis counts the runs)."""
+    if share == 1:
+        return slice(None), lambda h: h
+    first = pl.program_id(2) * g
+    return pl.ds(first, g), lambda h: first + h
+
+
+def _fwd_kernel(rule: Rule, share: int, q_ref, k_ref, v_ref, gam_ref, *rest):
+    *beta_ref, o_ref, states_ref, s_ref = rest
+    i, g = pl.program_id(1), v_ref.shape[0]
+    mine, slot = _slots(share, g)
 
     @pl.when(i == 0)
     def _():
-        s_ref[...] = jnp.zeros_like(s_ref)
+        s_ref[mine] = jnp.zeros((g, *s_ref.shape[1:]), F32)
 
-    for h, (k, gam, beta, first) in enumerate(_heads_of_a_program(rule, k_ref, gam_ref, beta_ref, i)):
-        s = s_ref[h]
+    walked = _heads_of_a_program(rule, k_ref, gam_ref, beta_ref[0] if beta_ref else None, i, g)
+    for h, (k, gam, beta, first) in enumerate(walked):
+        s = s_ref[slot(h)]
         states_ref[h, 0] = s
-        o, s_new = _once(rule.functions()[1])(q_ref[h], k, v_ref[h], gam, beta, s, first)
+        o, s_new = _once(rule.functions()[1])(q_ref[h * q_ref.shape[0] // g], k, v_ref[h], gam, beta, s, first)
         o_ref[h] = o.astype(o_ref.dtype)
-        s_ref[h] = s_new
+        s_ref[slot(h)] = s_new
 
 
-def _bwd_kernel(rule: Rule, q_ref, k_ref, v_ref, gam_ref, beta_ref, states_ref, do_ref,
-                dq_ref, dk_ref, dv_ref, dgam_ref, dbeta_ref, ds_ref):
-    i = pl.program_id(1)
+def _bwd_kernel(rule: Rule, share: int, has_beta: bool, q_ref, k_ref, v_ref, gam_ref, *rest):
+    rest = list(rest)
+    beta_ref = rest.pop(0) if has_beta else None
+    states_ref, do_ref, dq_ref, dk_ref, dv_ref, dgam_ref = rest[:6]
+    dbeta_ref = rest[6] if has_beta else None
+    ds_ref, *sums_ref = rest[6 + has_beta:]
+    i, g = pl.program_id(1), v_ref.shape[0]
     at = pl.num_programs(1) - 1 - i  # the chunk: the walk is from the row's end
+    mine, slot = _slots(share, g)
 
     @pl.when(i == 0)
     def _():
-        ds_ref[...] = jnp.zeros_like(ds_ref)
+        ds_ref[mine] = jnp.zeros((g, *ds_ref.shape[1:]), F32)
 
-    for h, (k, gam, beta, first) in enumerate(_heads_of_a_program(rule, k_ref, gam_ref, beta_ref, at)):
+    of_group = []  # (dq, dk) of every head of the program, where they are a group's to add up
+    for h, (k, gam, beta, first) in enumerate(_heads_of_a_program(rule, k_ref, gam_ref, beta_ref, at, g)):
         dq, dk, dv, dgam, dbeta, ds = _once(rule.functions()[2])(
-            q_ref[h], k, v_ref[h], gam, beta, states_ref[h, 0], do_ref[h], ds_ref[h], first)
-        dq_ref[h] = dq.astype(dq_ref.dtype)
-        dk_ref[h] = dk.astype(dk_ref.dtype)
+            q_ref[h * q_ref.shape[0] // g], k, v_ref[h], gam, beta, states_ref[h, 0], do_ref[h], ds_ref[slot(h)], first)
+        if share == 1:
+            dq_ref[h] = dq.astype(dq_ref.dtype)
+            dk_ref[h] = dk.astype(dk_ref.dtype)
+        else:
+            of_group.append((dq, dk))
         dv_ref[h] = dv.astype(dv_ref.dtype)
         dgam_ref[_gate_at(rule, h, at)] = dgam
-        dbeta_ref[h, pl.ds(at, 1), :] = dbeta
-        ds_ref[h] = ds
+        if has_beta:
+            dbeta_ref[h, pl.ds(at, 1), :] = dbeta
+        ds_ref[slot(h)] = ds
+    if share > 1:  # dq, dk of the group's chunk: summed over its programs in f32, written by the last
+        (sums,), run = sums_ref, pl.program_id(2)
+        dq, dk = (functools.reduce(jnp.add, parts) for parts in zip(*of_group))
+
+        @pl.when(run == 0)
+        def _():
+            sums[0], sums[1] = dq, dk
+
+        @pl.when(run > 0)
+        def _():
+            sums[0] += dq
+            sums[1] += dk
+
+        @pl.when(run == pl.num_programs(2) - 1)
+        def _():
+            dq_ref[0] = sums[0].astype(dq_ref.dtype)
+            dk_ref[0] = sums[1].astype(dk_ref.dtype)
 
 
 def _lanes(d: int) -> int:
@@ -224,68 +287,94 @@ VMEM_BUDGET = 12 << 20
 MAX_HEADS = 3
 
 
-def heads_per_program(heads: int, seq: int, chunk: int, dk: int, dv: int, itemsize: int) -> int:
+def heads_per_program(heads: int, seq: int, chunk: int, dk: int, dv: int, itemsize: int, share: int = 1,
+                      most: Optional[int] = None) -> int:
     """G, the heads one program walks side by side: the largest divisor of the `heads` the call holds (batch x
-    heads on this device), at most `MAX_HEADS`, whose blocks, scratch and working set in the backward kernel (the
-    larger one) fit `VMEM_BUDGET`; 1 where nothing divides them. Counted for a gate a row a head; a rule whose gate
-    is as wide as the keys asks at f32 items (`Rule.itemsize`)."""
+    heads on this device), at most `most` (`MAX_HEADS`), whose blocks, scratch and working set in the backward kernel
+    (the larger one) fit `VMEM_BUDGET`; 1 where nothing divides them. Counted for a gate a row a head; a rule whose gate
+    is as wide as the keys asks at f32 items (`Rule.itemsize`). With `share` heads on one q and k, G divides `share`
+    (a program's heads are of one group) and what the group keeps in scratch for all its heads comes off the budget."""
     n = seq // chunk
     # q, k, dq, dk; v, do, dv; the chunk's state; the two gates and their gradients, a whole row of them a head
     blocks = (4 * chunk * _lanes(dk) + 3 * chunk * _lanes(dv)) * itemsize + dk * _lanes(dv) * 4 + 4 * n * chunk * 4
     working = (8 * chunk * _lanes(chunk) + 4 * chunk * (_lanes(dk) + _lanes(dv))) * 4  # f32 values live at once
     a_head = 2 * blocks + dk * _lanes(dv) * 4 + working  # every block has two buffers; dS in scratch
-    fit = max(1, min(MAX_HEADS, VMEM_BUDGET // a_head))
+    room = VMEM_BUDGET
+    if share > 1:  # every head's dS and the group's sums of dq and dk
+        heads, room = share, room - (share * dk * _lanes(dv) + 2 * chunk * _lanes(dk)) * 4
+    fit = max(1, min(most or MAX_HEADS, room // a_head))
     return max(g for g in range(1, fit + 1) if heads % g == 0)
 
 
 def plan(rule: Rule, k, v, chunk: int):
-    """(G, the two scopes that name the plan: `chunk_128`, `heads_3of30`) for flat heads k (BH, S, d_k), v."""
-    bh, seq, dk = k.shape
-    g = heads_per_program(bh, seq, chunk, dk, v.shape[-1], rule.itemsize or k.dtype.itemsize)
-    return g, f"chunk_{chunk}", f"heads_{g}of{bh}"
+    """(G, the scopes that name the plan: `chunk_128`, `heads_3of30` and, where `share` heads read one q and k,
+    `group_<share>`) for flat heads k (BH / share, S, d_k), v (BH, S, d_v)."""
+    (bh, seq, dv), dk = v.shape, k.shape[-1]
+    share = bh // k.shape[0]
+    g = heads_per_program(bh, seq, chunk, dk, dv, rule.itemsize or k.dtype.itemsize, share, rule.max_heads)
+    return (g, f"chunk_{chunk}", f"heads_{g}of{bh}") + ((f"group_{share}",) if share > 1 else ())
 
 
 def _call(rule: Rule, backward: bool, operands, chunk: int, interpret: bool):
-    """One of the two `pallas_call`s on flat heads. Forward: `operands` (q, k, v, gam, beta), q, k (BH, S, d_k), v
-    (BH, S, d_v), gam (BH, S[, d_k]) and beta (BH, S) f32 -> (o, the state every chunk starts from (BH, S / C, d_k,
-    d_v) f32). The reverse walk: (q, k, v, gam, beta, states, do) -> the five gradients, each laid out like what it is
-    the gradient of. The grid is (programs of G heads, chunks), the second axis sequential: chunk i forward, chunk
-    n - 1 - i in the reverse walk."""
+    """One of the two `pallas_call`s on flat heads. Forward: `operands` (q, k, v, gam, beta), q, k (BH / share, S,
+    d_k), v (BH, S, d_v), gam (BH, S[, d_k]) and beta (BH, S) f32 or None -> (o, the state every chunk starts from
+    (BH, S / C, d_k, d_v) f32). The reverse walk: (q, k, v, gam, beta, states, do) -> the five gradients, each laid out
+    like what it is the gradient of (None for no beta). The grid is (programs of G heads, chunks), the second axis
+    sequential: chunk i forward, chunk n - 1 - i in the reverse walk; with `share` > 1 heads on one q and k it is
+    (groups, chunks, programs a group), the last two sequential."""
     q, k, v, gam, beta = operands[:5]
-    bh, seq, dk = k.shape
-    dv, n = v.shape[-1], seq // chunk
-    g, chunk_scope, heads_scope = plan(rule, k, v, chunk)
+    (bh, seq, dv), dk = v.shape, k.shape[-1]
+    n, share = seq // chunk, bh // k.shape[0]
+    g, *scopes = plan(rule, k, v, chunk)
     at = (lambda i: n - 1 - i) if backward else (lambda i: i)
-    per_chunk = lambda d: pl.BlockSpec((g, chunk, d), lambda h, i: (h, at(i), 0))  # noqa: E731
-    per_head = pl.BlockSpec((g, n, chunk), lambda h, i: (h, 0, 0))
-    states = pl.BlockSpec((g, 1, dk, dv), lambda h, i: (h, at(i), 0, 0))
+    if share == 1:
+        grid, head_at, keys_at = (bh // g, n), (lambda h, i: h), (g, lambda h, i: h)
+    else:
+        runs = share // g
+        grid, head_at, keys_at = (bh // share, n, runs), (lambda p, i, j: p * runs + j), (1, lambda p, i, j: p)
+    per_chunk = lambda d, where=(g, head_at): pl.BlockSpec(  # noqa: E731
+        (where[0], chunk, d), lambda *ids: (where[1](*ids), at(ids[1]), 0))
+    per_head = pl.BlockSpec((g, n, chunk), lambda *ids: (head_at(*ids), 0, 0))
+    states = pl.BlockSpec((g, 1, dk, dv), lambda *ids: (head_at(*ids), at(ids[1]), 0, 0))
     by_chunk = lambda x: x.reshape(bh, n, chunk)  # noqa: E731  (a row of gates a head, a chunk a sublane row)
     if rule.gate_a_channel:
-        gate, gate_width, gates = per_chunk(dk), dk, (gam, by_chunk(beta))
+        gate, gate_width, gates = per_chunk(dk), dk, [gam]
     else:
-        gate, gate_width, gates = per_head, 1, (by_chunk(gam), by_chunk(beta))
-    qkv_gates = [per_chunk(dk), per_chunk(dk), per_chunk(dv), gate, per_head]
+        gate, gate_width, gates = per_head, 1, [by_chunk(gam)]
+    qkv_gates = [per_chunk(dk, keys_at), per_chunk(dk, keys_at), per_chunk(dv), gate]
+    if beta is not None:
+        gates, qkv_gates = gates + [by_chunk(beta)], qkv_gates + [per_head]
     like = lambda x, dtype=None: jax.ShapeDtypeStruct(x.shape, dtype or x.dtype)  # noqa: E731
     passes = 2 if backward else 1  # over q, k, v, o and over the gates: read, and in the reverse walk written too
-    with jax.named_scope(chunk_scope), jax.named_scope(heads_scope):
+    kernel = (functools.partial(_bwd_kernel, rule, share, beta is not None) if backward
+              else functools.partial(_fwd_kernel, rule, share))
+    scratch = [pltpu.VMEM((g if share == 1 else share, dk, dv), F32)]
+    if backward and share > 1:
+        scratch.append(pltpu.VMEM((2, chunk, dk), F32))
+    with contextlib.ExitStack() as named:
+        for scope in scopes:
+            named.enter_context(jax.named_scope(scope))
         out = pl.pallas_call(
-            functools.partial(_bwd_kernel if backward else _fwd_kernel, rule),
-            grid=(bh // g, n),
+            kernel,
+            grid=grid,
             in_specs=qkv_gates + ([states, per_chunk(dv)] if backward else []),
             out_specs=qkv_gates if backward else [per_chunk(dv), states],
-            out_shape=([like(q), like(k), like(v), like(gates[0], F32), like(gates[1], F32)] if backward
+            out_shape=([like(q), like(k), like(v)] + [like(x, F32) for x in gates] if backward
                        else [like(v), jax.ShapeDtypeStruct((bh, n, dk, dv), F32)]),
-            scratch_shapes=[pltpu.VMEM((g, dk, dv), F32)],
+            scratch_shapes=scratch,
             interpret=interpret,
             name=rule.kernels + ("_bwd" if backward else "_fwd"),
-            compiler_params=None if interpret else pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary")),
+            compiler_params=None if interpret else pltpu.CompilerParams(
+                dimension_semantics=("parallel",) + ("arbitrary",) * (len(grid) - 1)),
             cost_estimate=pl.CostEstimate(
                 flops=bh * n * rule.chunk_flops(chunk, dk, dv, k.dtype, backward),
-                bytes_accessed=bh * (seq * passes * (2 * dk + 2 * dv) * q.dtype.itemsize + n * dk * dv * 4
-                                     + passes * seq * (gate_width + 1) * 4),
+                bytes_accessed=(seq * passes * (2 * dk * (bh // share) + 2 * dv * bh) * q.dtype.itemsize
+                                + bh * (n * dk * dv * 4 + passes * seq * (gate_width + len(gates) - 1) * 4)),
                 transcendentals=bh * n * rule.transcendentals(chunk, dk)),
         )(q, k, v, *gates, *operands[5:])
-    return (*out[:3], out[3].reshape(gam.shape), out[4].reshape(beta.shape)) if backward else out
+    if not backward:
+        return out
+    return (*out[:3], out[3].reshape(gam.shape), None if beta is None else out[4].reshape(beta.shape))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 6, 7))
@@ -318,35 +407,39 @@ def _running_sum(g, chunk: int):
     return jnp.cumsum(g.reshape(b, h, s // chunk, chunk, *g.shape[3:]), axis=3).reshape(g.shape)
 
 
-def chunked_scan(rule: Rule, q, k, v, g, beta, mesh=None, *, chunk: int = CHUNK,
+def chunked_scan(rule: Rule, q, k, v, g, beta=None, mesh=None, *, chunk: int = CHUNK,
                  backend: Optional[str] = None, interpret: bool = False):
-    """o (B, H, S, d_v), in v's type, of `rule`'s recurrence: what its entry point documents."""
+    """o (B, H, S, d_v), in v's type, of `rule`'s recurrence: what its entry point documents. q and k may hold a
+    divisor of v's heads (a group's q and k: the top of the file), and `beta` may be None."""
+    if v.shape[1] % k.shape[1] or q.shape[:3] != k.shape[:3]:
+        raise ValueError(f"{rule.name}: q {q.shape} and k {k.shape} are no group's of v's {v.shape[1]} heads")
     if chunk & (chunk - 1) or chunk < 8:
         raise ValueError(f"{rule.name}: chunk {chunk} is no power of two of at least 8")
     if backend is None:
         backend = select_backend(mesh.devices.flat[0].platform if mesh is not None else None)
     seq = q.shape[2]
     pad = -seq % chunk
-    if pad:  # beta 0, g 0: no write, no decay
-        along_seq = lambda x: jnp.pad(x, ((0, 0), (0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 3))  # noqa: E731
+    if pad:  # beta 0 (v 0 where there is none), g 0: no write, no decay
+        along_seq = lambda x: None if x is None else jnp.pad(  # noqa: E731
+            x, ((0, 0), (0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 3))
         q, k, v, g, beta = (along_seq(x) for x in (q, k, v, g, beta))
-    gam, beta = _running_sum(g.astype(F32), chunk), beta.astype(F32)
+    gam, beta = _running_sum(g.astype(F32), chunk), None if beta is None else beta.astype(F32)
     if backend == "xla":
         o = _xla_form(rule, q, k, v, gam, beta, chunk)
     elif backend == "pallas":
         def kernels(*operands):
-            b, h = operands[0].shape[:2]
-            o = _kernels(rule, *(x.reshape(b * h, *x.shape[2:]) for x in operands), chunk, interpret)
-            return o.reshape(b, h, *o.shape[1:])
+            flat = [x.reshape(x.shape[0] * x.shape[1], *x.shape[2:]) for x in operands] + [None] * (5 - len(operands))
+            o = _kernels(rule, *flat, chunk, interpret)
+            return o.reshape(*operands[2].shape[:2], *o.shape[1:])
 
-        operands = (q, k, v, gam, beta)
+        operands = (q, k, v, gam) + (() if beta is None else (beta,))
         if mesh is not None and mesh.size > 1:
             from ray_tpu.parallel import ShardingRules
 
             spec = lambda x: ShardingRules().mesh_axes(  # noqa: E731
                 ("batch", "heads") + (None,) * (x.ndim - 2), mesh=mesh, shape=x.shape)
             kernels = jax.shard_map(kernels, mesh=mesh, in_specs=tuple(spec(x) for x in operands),
-                                    out_specs=spec(q), check_vma=False)
+                                    out_specs=spec(v), check_vma=False)
         o = kernels(*operands)
     else:
         raise ValueError(f"{rule.name}: backend {backend!r} is neither 'pallas' nor 'xla'")

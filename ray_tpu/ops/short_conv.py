@@ -6,6 +6,7 @@ and a norm over heads): the caller does, by the function it calls.
 (Olmo-Hybrid, Solar-Open2):
 
     pre_t = sum_j taps[j] z_{t - (n - 1) + j}      causal, depthwise, n taps, zeros before the row's first position
+            (+ bias, a number a channel, where the layer has one: a Mamba-2 mixer's convolution over x, B and C)
     y     = silu(pre)                               cut into heads, heads first: (B, S, H d) -> (B, H, S, d)
     out   = y * rsqrt(sum over a head's channels of y^2 + 1e-6) * scale       (`normalize`; else out = y * scale)
 
@@ -96,11 +97,12 @@ def _shifted(z, n: int):
     return z if n == 0 else jnp.pad(z, ((0, 0), (n, 0), (0, 0)))[:, :z.shape[1]]
 
 
-def _xla_short_conv(z, taps, heads: int, scale: float, normalize: bool):
+def _xla_short_conv(z, taps, heads: int, scale: float, normalize: bool, bias=None):
     """The chain as XLA operations on whole arrays, in float32, rounded once at its end."""
     (b, s, _), n = z.shape, taps.shape[0]
     zf, w = z.astype(F32), taps.astype(F32)
-    y = jax.nn.silu(sum(w[j] * _shifted(zf, n - 1 - j) for j in range(n)))
+    pre = sum(w[j] * _shifted(zf, n - 1 - j) for j in range(n))
+    y = jax.nn.silu(pre if bias is None else pre + bias.astype(F32))
     y = y.reshape(b, s, heads, -1).transpose(0, 2, 1, 3)  # heads first
     if normalize:
         y = y * jax.lax.rsqrt(jnp.sum(y * y, axis=-1, keepdims=True) + EPS)
@@ -178,14 +180,15 @@ def _taps_sum(w, xs):
 
 # Under `jax.jit` the step is a jaxpr kept by its operands' types, which Python walks once a process and not once
 # a call and a trace of the train step (`ops/gated_delta_rule.py _once`, PR 52).
-@functools.partial(jax.jit, static_argnames=("head", "scale", "normalize"))
-def _step_bwd(before, cur, w, dout, ahead, *, head, scale, normalize):
+@functools.partial(jax.jit, static_argnames=("head", "scale", "normalize", "bias"))
+def _step_bwd(before, cur, w, dout, ahead, *, head, scale, normalize, bias=False):
     """A step's positions of a tile, f32: `cur` (rows, tile) of z with the HALO rows `before` it, the cotangent
-    `dout` of these positions, and `ahead`, the dpre of the HALO positions after them. Returns (dz (rows, tile),
-    [the taps' gradients of these positions, summed to (8, tile)], dpre's first HALO rows)."""
-    n, tile = w.shape[0], cur.shape[1]
+    `dout` of these positions, and `ahead`, the dpre of the HALO positions after them. With `bias` the last row of
+    `w` is the bias, behind the taps. Returns (dz (rows, tile), [the taps' gradients of these positions (and behind
+    them the bias's), summed to (8, tile)], dpre's first HALO rows)."""
+    n, tile = w.shape[0] - bias, cur.shape[1]
     earlier = _earlier(before, cur, n)
-    pre = _taps_sum(w, earlier)
+    pre = _taps_sum(w[:n], earlier) + w[n:] if bias else _taps_sum(w, earlier)
     s = jax.nn.sigmoid(pre)
     dy = dout * scale if scale != 1.0 else dout
     if normalize:  # out = u scale, u = y r, r = (sum y^2 + eps)^-1/2: dy = r (g - u sum(g u))
@@ -194,8 +197,10 @@ def _step_bwd(before, cur, w, dout, ahead, *, head, scale, normalize):
         u = y * r
         dy = r * (dy - u * _spread(_head_sums(dy * u, head), head, tile))
     dpre = dy * (s * (1.0 + pre * (1.0 - s)))
-    dz = _taps_sum(w, _later(dpre, ahead, n))  # dz_t = sum_j w[j] dpre_{t + (n - 1 - j)}
+    dz = _taps_sum(w[:n] if bias else w, _later(dpre, ahead, n))  # dz_t = sum_j w[j] dpre_{t + (n - 1 - j)}
     dtaps = [(dpre * earlier[n - 1 - j]).reshape(-1, 8, tile).sum(axis=0) for j in range(n)]
+    if bias:
+        dtaps.append(dpre.reshape(-1, 8, tile).sum(axis=0))
     return dz, dtaps, dpre[:HALO]
 
 
@@ -229,7 +234,8 @@ def _rows_at(ref, at, rows: int):
 def _walk(w_ref, dw_ref, seq: int, tile: int, rows: int, step):
     """A program's walk of its row from the end, `rows` positions a step: `step(at, w, ahead) -> (the taps'
     gradients of the positions from `at`, each (8, tile); what the step before needs of this one, (HALO, tile))`
-    reads and writes its own blocks; the taps' gradients are summed over the row into `dw_ref`."""
+    reads and writes its own blocks; the taps' gradients are summed over the row into `dw_ref`. `w_ref` is the
+    taps, or the taps with further rows a channel behind them (`short_conv_bwd`'s bias): a gradient a row."""
     w = w_ref[...].astype(F32)
     n, steps = w.shape[0], seq // rows
 
@@ -282,9 +288,10 @@ def _block_bytes(seq: int, head: int, itemsize: int) -> int:
     return 2 * seq * (2 * tile + tile // head * -(-head // LANES) * LANES) * itemsize
 
 
-def _bwd(z, taps, dout, head, scale, normalize, interpret):
+def _bwd(z, taps, dout, head, scale, normalize, interpret, bias=False):
     """(dz (B, S, C), dtaps (n, C)) of z, taps and the cotangent `dout` (B, heads, S, head), heads first as it is
-    handed back: a program takes its tile's heads each as a block and lays them side by side along lanes."""
+    handed back: a program takes its tile's heads each as a block and lays them side by side along lanes. With
+    `bias` the last row of `taps`, and of the gradient, is the bias's."""
     b, seq, channels = z.shape
     n, tile = taps.shape[0], tile_of(head)
     wide = pl.BlockSpec((1, seq, tile), lambda i, c: (i, 0, c))
@@ -293,7 +300,7 @@ def _bwd(z, taps, dout, head, scale, normalize, interpret):
         vmem_limit_bytes=_block_bytes(seq, head, z.dtype.itemsize) + (16 << 20))  # and a step's arrays
     with jax.named_scope(f"tile_{tile}"), jax.named_scope(f"rows_{seq}"):
         dz, dw = pl.pallas_call(
-            functools.partial(_bwd_kernel, head=head, scale=scale, normalize=normalize),
+            functools.partial(_bwd_kernel, head=head, scale=scale, normalize=normalize, bias=bias),
             grid=(b, pl.cdiv(channels, tile)),
             in_specs=[wide, pl.BlockSpec((n, tile), lambda i, c: (0, c)),
                       pl.BlockSpec((1, tile // head, seq, head), lambda i, c: (i, c, 0, 0))],
@@ -307,18 +314,20 @@ def _bwd(z, taps, dout, head, scale, normalize, interpret):
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4, 5, 6))
-def _kernel_gradient(z, taps, heads, scale, normalize, mesh, interpret):
-    return _xla_short_conv(z, taps, heads, scale, normalize)
+def _kernel_gradient(z, taps, heads, scale, normalize, mesh, interpret, bias=None):
+    return _xla_short_conv(z, taps, heads, scale, normalize, bias)
 
 
-def _kernel_gradient_fwd(z, taps, heads, scale, normalize, mesh, interpret):
-    return _xla_short_conv(z, taps, heads, scale, normalize), (z, taps)
+def _kernel_gradient_fwd(z, taps, heads, scale, normalize, mesh, interpret, bias=None):
+    return _xla_short_conv(z, taps, heads, scale, normalize, bias), (z, taps, bias)
 
 
 def _kernel_gradient_bwd(heads, scale, normalize, mesh, interpret, res, dout):
-    z, taps = res
+    z, taps, bias = res
     head = z.shape[2] // heads
-    kernel = lambda z, taps, dout: _bwd(z, taps, dout, head, scale, normalize, interpret)  # noqa: E731
+    if bias is not None:  # a further row behind the taps, to the kernel and in its gradient
+        taps = jnp.concatenate([taps, bias[None].astype(taps.dtype)])
+    kernel = lambda z, taps, dout: _bwd(z, taps, dout, head, scale, normalize, interpret, bias is not None)  # noqa: E731
     if mesh is not None and mesh.size > 1:
         from jax.sharding import PartitionSpec
 
@@ -336,7 +345,9 @@ def _kernel_gradient_bwd(heads, scale, normalize, mesh, interpret, res, dout):
 
         kernel = jax.shard_map(kernel, mesh=mesh, in_specs=(wide, narrow, first), out_specs=(wide, narrow), check_vma=False)
     dz, dtaps = kernel(z, taps, dout)
-    return dz, dtaps.astype(taps.dtype)
+    if bias is None:
+        return dz, dtaps.astype(taps.dtype), None
+    return dz, dtaps[:-1].astype(taps.dtype), dtaps[-1].astype(bias.dtype)
 
 
 _kernel_gradient.defvjp(_kernel_gradient_fwd, _kernel_gradient_bwd)
@@ -464,11 +475,13 @@ def _backend(name: str, backend: Optional[str], mesh, fits: bool) -> str:
     return backend
 
 
-def short_conv(z, taps, heads: int, *, scale: float = 1.0, normalize: bool = False, mesh=None,
+def short_conv(z, taps, heads: int, *, scale: float = 1.0, normalize: bool = False, bias=None, mesh=None,
                backend: Optional[str] = None, interpret: bool = False):
     """`out` (B, heads, S, C / heads) in z's type, of z (B, S, C) and `taps` (n, C): the top of the file.
 
-    heads: C is `heads` heads of consecutive channels.
+    heads: C is `heads` heads of consecutive channels. Channels that are no head's to the caller (a Mamba-2 mixer's
+      B and C behind its x) are further heads of the same width here, and the caller's to put together again.
+    bias: (C,) added to the convolution before the SiLU, or None.
     normalize: L2-normalise y over each head; scale: a factor on the result.
     backend: "pallas" (the gradient by the kernel) | "xla" | None (`select_backend` for the platform the
       computation is compiled for, the mesh's where there is one; "xla" where the shapes do not fit the kernel).
@@ -476,10 +489,10 @@ def short_conv(z, taps, heads: int, *, scale: float = 1.0, normalize: bool = Fal
       a shard_map."""
     fits = mosaic_fits(z.shape, z.shape[2] // heads, taps.shape[0], z.dtype.itemsize, normalize)
     if _backend("short_conv", backend, mesh, fits) == "xla":
-        return _xla_short_conv(z, taps, heads, float(scale), normalize)
+        return _xla_short_conv(z, taps, heads, float(scale), normalize, bias)
     if z.shape[1] % PACK:
         raise ValueError(f"short_conv: the kernel walks a row {PACK} positions at a time at least, not {z.shape[1]}")
-    return _kernel_gradient(z, taps, heads, float(scale), normalize, mesh, interpret)
+    return _kernel_gradient(z, taps, heads, float(scale), normalize, mesh, interpret, bias)
 
 
 def gated_short_conv(bcu, taps, *, mesh=None, backend: Optional[str] = None, interpret: bool = False):

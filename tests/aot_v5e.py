@@ -29,6 +29,7 @@ Case names:
     indexer:BxHxKVxSxDxIHxIDxK  `select` and `index_loss` of `ops/lightning_indexer.py`
     gdn:BxHxSxDKxDV             `gdn_fwd` and `gdn_bwd` of `ops/gated_delta_rule.py`, the call and its gradient
     kda:BxHxSxDKxDV             `kda_fwd` and `kda_bwd` of `ops/kda.py`, the call and its gradient
+    ssd:BxHxGxSxNxP             `ssd_fwd` and `ssd_bwd` of `ops/ssd.py`: H heads of P on G B and C of N, the call and its gradient
     short_conv:BxSxHEADSxDxNORM `short_conv_bwd` of `ops/short_conv.py` under the XLA chain it is the gradient of
     gated_conv:BxSxDxTAPS       `gated_conv_bwd` of `ops/short_conv.py` under the XLA chain it is the gradient of
     row_movers:TOKENS           `gather_rows` and `sum_rows` over a held prefix
@@ -483,6 +484,28 @@ def _kda_case(topo, batch, heads, seq, dk, dv):
             "states": sorted(set(re.findall(r"f32\[%d,\d+,%d,%d\]" % (batch * heads, dk, dv), text)))}
 
 
+def _ssd_case(topo, batch, heads, groups, seq, n, p):
+    """The state-space duality scan's two kernels at a mamba layer's shapes, one device: `heads` heads of `p` on
+    `groups` B and C of `n`."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.ops import ssd
+
+    one = jax.sharding.SingleDeviceSharding(topo.devices[0])
+    sd = lambda shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(shape, dtype, sharding=one)  # noqa: E731
+    per_head = lambda dtype=jnp.float32: sd((heads,), dtype)  # noqa: E731
+    loss = lambda *a: ssd.ssd(*a, backend="pallas").sum()  # noqa: E731
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4, 5))).lower(
+        sd((batch, heads, seq, p)), sd((batch, groups, seq, n)), sd((batch, groups, seq, n)),
+        sd((batch, heads, seq), jnp.float32), per_head(), per_head()).compile().as_text()
+    return {"mosaic_calls": text.count("tpu_custom_call"),
+            "kernels": sorted(set(re.findall(r"(ssd_fwd|ssd_bwd)[.\d]* = ", text))),
+            "plans": sorted(set(re.findall(r"\b(chunk_\d+\)*/heads_\d+of\d+\)*/group_\d+)\b", text))),
+            "states": sorted(set(re.findall(r"f32\[%d,\d+,%d,%d\]" % (batch * heads, n, p), text))),
+            "keys_a_head": sorted(set(re.findall(r"\w+\[%d,%d,%d\]" % (batch * heads, seq, n), text)))}
+
+
 def _walk_kernels(text, prefix):
     """Of a compiled short convolution and its gradient: the Mosaic calls, their names, the plan in their scope."""
     return {"mosaic_calls": text.count("tpu_custom_call"),
@@ -692,6 +715,8 @@ def _case(topo, case):
         return _gdn_case(topo, *numbers())
     if name == "kda":
         return _kda_case(topo, *numbers())
+    if name == "ssd":
+        return _ssd_case(topo, *numbers())
     if name == "short_conv":
         return _short_conv_case(topo, *numbers())
     if name == "gated_conv":

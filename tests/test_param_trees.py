@@ -23,6 +23,7 @@ DRAWN_BEFORE = {
     "sdar-nano": "5806c8772689c752", "solar-open2-nano": "d31cc99197298c4c", "trinity-nano": "dc19f5da3c133fe5",
     "xing4-nano": "02b3e5cc84f92fe8",  # PR 66: GLM's tree and key scheme with two `hc_*` groups a layer
     "smallthinker-nano": "7c2de87a54adfe30",  # PR 70: `stack.lm_tree` with no leading layer, `gqa_experts`' leaves less the head norms
+    "granite-hybrid-nano": "e1fa16fbabd769f7",  # PR 73: `stack.lm_tree` with no head of its own (the table is tied)
 }
 
 

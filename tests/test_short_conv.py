@@ -118,6 +118,36 @@ def test_a_row_of_many_steps_carries_the_gradient_across_them(name):
     assert far(dz, want_dz) < 2e-6 and far(dtaps, want_dtaps) < 2e-6
 
 
+@pytest.mark.parametrize("heads,seq,dtype", [(6, 48, jnp.float32), (12, 96, jnp.bfloat16)],
+                         ids=["x_4_heads_b_c_1_each_of_64_f32", "three_steps_of_32_bf16"])
+def test_a_bias_a_channel_before_the_silu_and_its_gradient(heads, seq, dtype):
+    """A Mamba-2 mixer's convolution (PR 73): a bias a channel under the SiLU, B's and C's channels further heads of
+    64 behind x's, two heads a lane row. The chain against the convolution written out; the kernel's gradient
+    (the bias's a further row of the taps' block, a column sum of dpre) against jax's of the chain."""
+    keys = jax.random.split(jax.random.PRNGKey(7), 4)
+    z = jax.random.normal(keys[0], (2, seq, heads * 64)).astype(dtype)
+    taps, bias = jax.random.normal(keys[1], (4, heads * 64)) * 0.5, jax.random.normal(keys[2], (heads * 64,))
+    weights = jax.random.normal(keys[3], (2, heads, seq, 64))
+
+    def grads(backend):
+        f = lambda z, taps, bias: sc.short_conv(z, taps, heads, bias=bias, backend=backend, interpret=True)  # noqa: E731
+        loss = lambda *a: (f(*a).astype(jnp.float32) * weights).sum()  # noqa: E731
+        return jax.jit(lambda *a: (f(*a), jax.grad(loss, argnums=(0, 1, 2))(*a)))(z, taps, bias)
+
+    (out, (dz, dtaps, dbias)), (want, (want_dz, want_dtaps, want_dbias)) = grads("pallas"), grads("xla")
+    zf = jnp.pad(z.astype(jnp.float32), ((0, 0), (3, 0), (0, 0)))
+    written_out = jax.nn.silu(sum(taps[j] * zf[:, j:j + seq] for j in range(4)) + bias)
+    assert far(want, written_out.reshape(2, seq, heads, 64).transpose(0, 2, 1, 3)) < (1e-6 if dtype == jnp.float32 else 2 ** -8)
+    assert out.dtype == dtype and far(out, want) < (1e-6 if dtype == jnp.float32 else 2 ** -8)
+    assert dz.dtype == dtype and dtaps.shape == taps.shape and dbias.shape == bias.shape and dbias.dtype == bias.dtype
+    assert far(dz, want_dz) < (2e-6 if dtype == jnp.float32 else 2 ** -7)
+    assert far(dtaps, want_dtaps) < 2e-6 and far(dbias, want_dbias) < 2e-6
+    # without a bias the call is what it was: no further row, no third gradient
+    plain = jax.grad(lambda z, taps: sc.short_conv(z, taps, heads, backend="pallas", interpret=True).astype(jnp.float32).sum(),
+                     argnums=(0, 1))(z, taps)
+    assert len(plain) == 2 and plain[1].shape == taps.shape
+
+
 def test_under_a_mesh_the_taps_gradient_is_the_sum_over_every_devices_rows():
     from ray_tpu.parallel import MeshSpec
 
